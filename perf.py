@@ -1215,8 +1215,9 @@ def _chipspeed_jax():
 
 def _measure_chipspeed(S: float, arm: str, steps: int) -> dict:
     """One fresh-jit run of the tiny-config dp=4 CPU train loop for one
-    knob combination (``arm``: '+'-joined subset of splash/quant/zero, or
-    'off').  Fixed seed and fixed batch schedule so arms are comparable
+    knob combination (``arm``: '+'-joined subset of quant/zero, or 'off';
+    the tiny config's head_dim 16 cannot tile the splash kernel, and an
+    explicit ``attention_impl="splash"`` raises there since PR 22).  Fixed seed and fixed batch schedule so arms are comparable
     numerically, not just in time."""
     import numpy as np
 
@@ -1226,9 +1227,6 @@ def _measure_chipspeed(S: float, arm: str, steps: int) -> dict:
                                   init_zero_state, make_mesh, make_train_step)
 
     cfg = mcfg.tiny()
-    if "splash" in arm:
-        cfg = mcfg.TransformerConfig(
-            **{**cfg.__dict__, "attention_impl": "splash"})
     mesh = make_mesh(4, dp=4, fsdp=1)
     spec = OptimizerSpec(total_steps=1000, warmup_steps=5)
     opt = spec.build()
@@ -1267,9 +1265,6 @@ def run_ab_chipspeed(S: float, pairs: int) -> dict:
       replicated arm (same seed/batches, fp32); the int8 quantized
       round-trip stays inside the analytical amax/254-per-rank bound;
       splash interpret-mode forward parity vs ops/flash_attention.
-    - <= 5% no-TPU overhead discipline: ``attention_impl="splash"`` on a
-      box with no usable kernel must fall back to an identical compiled
-      graph — its steps/s within 5% of the off arm.
 
     The quant/zero arms change the computation by design, so they get
     numerics bounds, not overhead bounds; their steps/s ratios are
@@ -1281,7 +1276,7 @@ def run_ab_chipspeed(S: float, pairs: int) -> dict:
     import jax.numpy as jnp
 
     steps = max(int(10 * S), 6)
-    arms = ("off", "splash", "splash+quant+zero")
+    arms = ("off", "quant+zero")
     runs = {a: [] for a in arms}
     for i in range(pairs):
         for a in arms:
@@ -1315,27 +1310,23 @@ def run_ab_chipspeed(S: float, pairs: int) -> dict:
     quant_ok = bool(jnp.all(jnp.abs(back - x).reshape(64, 16, 256) <= bound))
     quant_max_err = float(jnp.max(jnp.abs(back - x)))
 
-    # numerics gate 3: splash interpret-mode forward parity (recorded even
-    # though the timed splash arm falls back on the tiny head_dim)
+    # numerics gate 3: splash interpret-mode forward parity
     from ray_tpu.ops.splash_attention import splash_mha
     from ray_tpu.ops.flash_attention import flash_attention
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     qq = jax.random.normal(ks[0], (1, 256, 4, 128), jnp.float32)
     kk = jax.random.normal(ks[1], (1, 256, 2, 128), jnp.float32)
     vv = jax.random.normal(ks[2], (1, 256, 2, 128), jnp.float32)
-    sp = splash_mha(qq, kk, vv, causal=True)
-    splash_err = (float(jnp.max(jnp.abs(
-        sp - flash_attention(qq, kk, vv, causal=True))))
-        if sp is not None else None)
-    splash_ok = splash_err is not None and splash_err < 1e-4
+    splash_err = float(jnp.max(jnp.abs(
+        splash_mha(qq, kk, vv, causal=True)
+        - flash_attention(qq, kk, vv, causal=True))))
+    splash_ok = splash_err < 1e-4
 
-    overhead_ok = ratio["splash"] >= 0.95
     strip = lambda r: {k: v for k, v in r.items()  # noqa: E731
                        if k != "_losses"}
-    return {"pairs_on": [strip(r) for r in runs["splash+quant+zero"]],
+    return {"pairs_on": [strip(r) for r in runs["quant+zero"]],
             "pairs_off": [strip(r) for r in runs["off"]],
-            "pairs_splash_fallback": [strip(r) for r in runs["splash"]],
-            "ratio_on_off": {"steps_per_s": ratio["splash+quant+zero"]},
+            "ratio_on_off": {"steps_per_s": ratio["quant+zero"]},
             "gate": {"zero_allclose_rtol": 1e-5,
                      "zero_max_rel_err": round(zero_err, 9),
                      "zero_allclose": zero_ok,
@@ -1343,11 +1334,7 @@ def run_ab_chipspeed(S: float, pairs: int) -> dict:
                      "quant_bounded": quant_ok,
                      "splash_fwd_max_err": splash_err,
                      "splash_parity": splash_ok,
-                     "max_overhead": 0.05,
-                     "splash_fallback_ratio": ratio["splash"],
-                     "overhead_ok": overhead_ok,
-                     "passed": bool(zero_ok and quant_ok and splash_ok
-                                    and overhead_ok)}}
+                     "passed": bool(zero_ok and quant_ok and splash_ok)}}
 
 
 def run_profile_submit(S: float) -> dict:

@@ -15,6 +15,7 @@ import asyncio
 import atexit
 import inspect
 import os
+import tempfile
 import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
@@ -92,7 +93,7 @@ def init(address: Optional[str] = None,
     from .chaos import reset as _reset_chaos
     _reset_chaos()
     session_dir = os.path.join(
-        "/tmp/raytpu", f"session-{int(time.time() * 1000)}-{os.getpid()}")
+        tempfile.gettempdir(), "raytpu", f"session-{int(time.time() * 1000)}-{os.getpid()}")
     os.makedirs(os.path.join(session_dir, "logs"), exist_ok=True)
     _state.session_dir = session_dir
 
@@ -233,7 +234,8 @@ def shutdown():
         _state.worker = None
     if _state.node_agent is not None:
         try:
-            run_async(_state.node_agent.stop(), timeout=5,
+            # bounded wait for the workers' exits (see NodeAgent.stop)
+            run_async(_state.node_agent.stop(), timeout=30,
                       lane=_state.agent_lane)
         except Exception:
             pass

@@ -299,14 +299,33 @@ class GetTimeoutError(RayTpuError, TimeoutError):
 # Resources
 # ---------------------------------------------------------------------------
 
+def count_tpu_chips(dev: str = "/dev") -> int:
+    """TPU chips attached to this host, from their device nodes: one
+    ``/dev/accel<N>`` per chip where the accel driver exposes them, else one
+    numbered ``/dev/vfio/<N>`` group per chip (how a v5e host shows its
+    chips; ``/dev/vfio/vfio`` is the control node, not a chip)."""
+    import os
+
+    def ls(path):
+        try:
+            return os.listdir(path)
+        except OSError:
+            return []
+
+    accel = [d for d in ls(dev) if d.startswith("accel")]
+    return len(accel) or len([d for d in ls(os.path.join(dev, "vfio"))
+                              if d.isdigit()])
+
+
 def detect_node_resources(num_cpus: Optional[float] = None,
                           num_tpus: Optional[float] = None,
                           resources: Optional[Dict[str, float]] = None) -> Dict[str, float]:
     """Autodetect CPU / TPU resources for a node.
 
     TPU detection follows the reference's approach
-    (``python/ray/_private/accelerator.py:35-42,153`` — counts ``/dev/accel*`` chips,
-    honours ``TPU_VISIBLE_CHIPS``) without importing jax.
+    (``python/ray/_private/accelerator.py:35-42,153``) without importing jax,
+    so asking never claims a chip: ``TPU_VISIBLE_CHIPS`` if set, else the
+    chips' device nodes (``count_tpu_chips``).
     """
     import os
     out: Dict[str, float] = dict(resources or {})
@@ -318,10 +337,7 @@ def detect_node_resources(num_cpus: Optional[float] = None,
         if visible:
             num_tpus = len([c for c in visible.split(",") if c.strip()])
         else:
-            try:
-                num_tpus = len([d for d in os.listdir("/dev") if d.startswith("accel")])
-            except OSError:
-                num_tpus = 0
+            num_tpus = count_tpu_chips()
     if num_tpus:
         out["TPU"] = float(num_tpus)
     try:

@@ -414,8 +414,14 @@ class NodeAgent:
             self._chaos_kill_task.cancel()
         for t in self._bg:
             t.cancel()
-        for w in list(self.workers.values()):
+        victims = list(self.workers.values())
+        for w in victims:
             await self._kill_worker_proc(w)
+        # A killed worker keeps what it held (a TPU chip above all) until its
+        # process is gone: return after the exits, so that whoever starts the
+        # next session on this host does not race them.
+        await asyncio.gather(*(w.proc.wait() for w in victims
+                               if w.proc is not None))
         await self.worker_clients.close_all()
         await self.agent_clients.close_all()
         if self.gcs:

@@ -22,18 +22,21 @@ PEAK_BF16_FLOPS = {
     "v4": 275e12,
     "v6 lite": 918e12,   # trillium
     "v6e": 918e12,
-    "cpu": 1e12,         # nominal, for CI runs only
+    "cpu": 1e12,         # nominal; an explicit row so CPU tests have an MFU
 }
 
 
 def detect_peak_flops(device) -> float:
-    """Peak bf16 FLOP/s of one device, keyed on ``device_kind`` (falls
-    back to the nominal CPU figure for CI runs)."""
-    kind = getattr(device, "device_kind", "cpu").lower()
+    """Peak bf16 FLOP/s of one device, keyed on ``device_kind``.  A device
+    that is not in the table raises: an MFU over an assumed peak is a
+    number about no machine."""
+    kind = str(device.device_kind).lower()
     for key, val in PEAK_BF16_FLOPS.items():
         if key in kind:
             return val
-    return PEAK_BF16_FLOPS["cpu"]
+    raise ValueError(
+        f"no peak bf16 FLOP/s known for device_kind={device.device_kind!r}; "
+        f"add it to PEAK_BF16_FLOPS (known: {sorted(PEAK_BF16_FLOPS)})")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,10 +64,11 @@ class TransformerConfig:
     # attention
     causal: bool = True
     attn_logit_softcap: float = 0.0
-    #: "auto" (mha dispatcher: flash on TPU, plain elsewhere), "plain",
-    #: "flash" (ops/flash_attention), or "splash" (the pallas splash kernel
-    #: with explicit backward block sizes; degrades to "auto" with one
-    #: RuntimeWarning when unavailable or the shape doesn't qualify).
+    #: "auto" (mha dispatcher: the flash kernel on TPU where the shape
+    #: tiles, plain elsewhere), "plain", "flash" (ops/flash_attention), or
+    #: "splash" (the pallas splash kernel with explicit backward block
+    #: sizes).  An explicit "flash"/"splash" that cannot run for the shape
+    #: raises; only "auto" chooses.
     attention_impl: str = "auto"
 
     @property
@@ -126,17 +130,19 @@ def llama3_70b(max_seq_len: int = 8192) -> TransformerConfig:
 
 
 def llama_1b(max_seq_len: int = 2048) -> TransformerConfig:
-    """~1.2B Llama-style model: fits one chip with optimizer state; used as the
-    single-chip bench config."""
+    """~0.89B Llama-style model (``num_params()`` = 889M).  With f32 Adam its
+    train state alone is ~14 GB, so it trains sharded over several chips
+    (the four-chip config of chip_smoke.py); one 16 GB chip holds it for
+    inference."""
     return TransformerConfig(
         vocab_size=32768, num_layers=16, hidden_size=2048, num_heads=16,
         num_kv_heads=8, mlp_size=5632, max_seq_len=max_seq_len)
 
 
 def llama_400m(max_seq_len: int = 2048) -> TransformerConfig:
-    """~0.4B Llama-style model: fits a single 16 GB chip *with* f32 Adam state
-    and remat headroom (llama-1b's state alone is ~16 GB — see bench.py's
-    memory model). The single-chip bench config."""
+    """~0.41B Llama-style model: fits a single 16 GB chip *with* f32 Adam state
+    (llama-1b's state alone is ~14 GB — see bench.py's memory model).  The
+    single-chip bench config."""
     return TransformerConfig(
         vocab_size=32768, num_layers=12, hidden_size=1536, num_heads=12,
         num_kv_heads=6, mlp_size=4096, max_seq_len=max_seq_len)

@@ -208,23 +208,27 @@ def _attention_block(x, p, cfg: TransformerConfig, positions, pctx: ParallelCont
                              causal=cfg.causal, batch_axes=pctx.batch_axes,
                              logit_softcap=cfg.attn_logit_softcap)
     else:
-        out = None
-        impl = getattr(cfg, "attention_impl", "auto")
+        # Kernels are shard_mapped over the batch on a multi-device mesh,
+        # except inside a region that is already manual (pipeline, zero).
+        kmesh = None if pctx.manual_collectives else pctx.mesh
+        impl = cfg.attention_impl
         if impl == "splash":
             from ..ops.splash_attention import splash_mha
             out = splash_mha(q, k, v, causal=cfg.causal,
                              logit_softcap=cfg.attn_logit_softcap,
-                             mesh=pctx.mesh, batch_axes=pctx.batch_axes,
-                             manual=pctx.manual_collectives)
+                             mesh=kmesh, batch_axes=pctx.batch_axes)
         elif impl == "plain":
             out = attend(q, k, v, causal=cfg.causal,
                          logit_softcap=cfg.attn_logit_softcap)
-        elif impl == "flash" and cfg.attn_logit_softcap == 0.0:
-            from ..ops.flash_attention import flash_attention
-            out = flash_attention(q, k, v, causal=cfg.causal)
-        if out is None:  # "auto", or splash/flash declined this call
+        elif impl in ("flash", "auto"):
+            # explicit "flash" raises where the kernel cannot run (softcap,
+            # a shape that does not tile); only "auto" chooses
             out = mha(q, k, v, causal=cfg.causal,
-                      logit_softcap=cfg.attn_logit_softcap)
+                      logit_softcap=cfg.attn_logit_softcap,
+                      use_flash=True if impl == "flash" else None,
+                      mesh=kmesh, batch_axes=pctx.batch_axes)
+        else:
+            raise ValueError(f"unknown attention_impl {impl!r}")
     out = checkpoint_name(out, "attn_out")
     out = out.reshape(b, s, nh * hd) @ p["wo"].astype(cast)
     if "bo" in p:

@@ -11,7 +11,7 @@ attention (``ring_attention.py``).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,17 +92,26 @@ def finalize_blockwise(m, l, o):
 
 
 def mha(q, k, v, causal: bool = True, logit_softcap: float = 0.0,
-        use_flash: Optional[bool] = None):
-    """Dispatch between the Pallas flash kernel (TPU, long seq) and plain XLA."""
+        use_flash: Optional[bool] = None, mesh=None,
+        batch_axes: Tuple[str, ...] = ("dp", "fsdp")):
+    """Dispatch between the Pallas flash kernel (TPU, long seq) and plain XLA.
+
+    ``use_flash=None`` chooses from what it can observe: the backend and the
+    shape.  ``mesh``/``batch_axes`` go to the kernel, which must be
+    shard_mapped by hand on a multi-device mesh (see ``flash_attention``)."""
     if use_flash is None:
-        # The flash kernel does not implement logit softcap; fall back when set.
+        from .flash_attention import flash_supported
+        # The flash kernel does not implement logit softcap.
         use_flash = (jax.default_backend() == "tpu" and q.shape[1] >= 1024
-                     and q.shape[-1] in (64, 128, 256) and logit_softcap == 0.0)
+                     and q.shape[-1] in (64, 128, 256) and logit_softcap == 0.0
+                     and flash_supported(q.shape[1], k.shape[1], q.shape[2],
+                                         k.shape[2]) is None)
     if use_flash:
         if logit_softcap > 0.0:
             raise ValueError("flash_attention does not implement logit_softcap;"
                              " use use_flash=False (or leave it None to"
-                             " auto-fall-back)")
+                             " let the dispatcher choose)")
         from .flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, mesh=mesh,
+                               batch_axes=batch_axes)
     return attend(q, k, v, causal=causal, logit_softcap=logit_softcap)

@@ -29,12 +29,13 @@ the PV matmul, matching ``attention.attend``.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -406,27 +407,86 @@ def _flash_vjp_bwd(causal, block_q, block_kv, interpret, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+#: How many times each Pallas kernel wrapper was traced in interpret mode in
+#: this process, keyed by kernel name ("flash", "splash").  Interpret mode is
+#: the CPU stand-in for a kernel; a test reads this to see that a path took
+#: it, and on a TPU it stays empty.
+INTERPRET_TRACES: Dict[str, int] = {}
+
+
+def resolve_interpret(interpret: Optional[bool], kernel: str) -> bool:
+    """Whether a Pallas kernel runs interpreted: when the caller says so, or
+    (``interpret=None``) when the backend is the CPU.  Every interpreted trace
+    is counted in ``INTERPRET_TRACES``.  Never on a TPU: there the kernel
+    compiles or the call fails."""
+    backend = jax.default_backend()
+    if interpret is None:
+        interpret = backend == "cpu"
+    if interpret:
+        if backend == "tpu":
+            raise ValueError(
+                f"{kernel} attention: interpret=True on a TPU backend; the "
+                f"interpreter is the CPU stand-in for the kernel")
+        INTERPRET_TRACES[kernel] = INTERPRET_TRACES.get(kernel, 0) + 1
+    return bool(interpret)
+
+
+def flash_supported(seq_q: int, seq_kv: int, num_heads: int,
+                    num_kv_heads: int, block_q: int = 512,
+                    block_kv: int = 512) -> Optional[str]:
+    """None when the shape tiles for the flash kernel, else the reason."""
+    bq, bkv = min(block_q, seq_q), min(block_kv, seq_kv)
+    if seq_q % bq or seq_kv % bkv:
+        return (f"seq ({seq_q}, {seq_kv}) not a multiple of the blocks "
+                f"({bq}, {bkv})")
+    if num_kv_heads < 1 or num_heads % num_kv_heads:
+        return f"heads {num_heads} not a multiple of kv heads {num_kv_heads}"
+    return None
+
+
+def kernel_batch_spec(mesh, batch_axes) -> Optional[P]:
+    """The [B, ...] PartitionSpec of a shard_map around a kernel call, or None
+    when ``mesh`` is None or one device.  Mosaic kernels cannot be partitioned
+    by the compiler, so on a mesh of more than one device the call is wrapped
+    by hand: batch over ``batch_axes``, everything else replicated."""
+    if mesh is None or mesh.size <= 1:
+        return None
+    axes = tuple(a for a in batch_axes if a in mesh.axis_names)
+    return P(axes or None, None, None, None)
+
+
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
                     block_kv: int = 512,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Optional[bool] = None, mesh=None,
+                    batch_axes: Tuple[str, ...] = ("dp", "fsdp")
+                    ) -> jnp.ndarray:
     """Flash attention. q: [B, Sq, H, D], k/v: [B, Skv, KV, D] -> [B, Sq, H, D].
 
     Layout matches ``attention.attend``; internally transposed to [B, H, S, D]
     (the kernel wants the sequence on the sublane dim and D=64/128 on lanes).
-    Sequence lengths must be multiples of the block sizes (the model layer
-    guarantees power-of-two seq; dispatch falls back to plain otherwise).
+    Sequence lengths must be multiples of the block sizes: a shape that does
+    not tile raises (``flash_supported`` says why; the ``mha`` dispatcher asks
+    it before choosing this kernel).
+
+    With a ``mesh`` of more than one device the call is wrapped in a
+    shard_map over ``batch_axes``, each device on its local batch shard.
+    Pass no mesh from inside a manual region (a shard_map body).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     b, sq, h, d = q.shape
-    skv = k.shape[1]
+    reason = flash_supported(sq, k.shape[1], h, k.shape[2], block_q, block_kv)
+    if reason is not None:
+        raise ValueError(f"flash attention cannot run this shape: {reason}")
+    interpret = resolve_interpret(interpret, "flash")
     block_q = min(block_q, sq)
-    block_kv = min(block_kv, skv)
-    if sq % block_q or skv % block_kv or h % k.shape[2]:
-        from .attention import attend
-        return attend(q, k, v, causal=causal)
-    qt = q.swapaxes(1, 2)
-    kt = k.swapaxes(1, 2)
-    vt = v.swapaxes(1, 2)
-    out = _flash(qt, kt, vt, causal, block_q, block_kv, interpret)
-    return out.swapaxes(1, 2)
+    block_kv = min(block_kv, k.shape[1])
+
+    def local(q, k, v):
+        out = _flash(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+                     causal, block_q, block_kv, interpret)
+        return out.swapaxes(1, 2)
+
+    spec = kernel_batch_spec(mesh, batch_axes)
+    if spec is None:
+        return local(q, k, v)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..util import jax_compat
 
 from .attention import attend_blockwise, finalize_blockwise
 
@@ -70,7 +69,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
     """
     batch_spec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0],
                    axis_name, None, None)
-    fn = jax_compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attn_shard, axis_name=axis_name, causal=causal,
                           logit_softcap=logit_softcap),
         mesh=mesh,
@@ -112,5 +111,5 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
     if kv_heads % sp != 0:
         # GQA with fewer KV heads than the sp degree: fall back to ring.
         return ring_attention(q, k, v, mesh, axis_name, causal, batch_axes)
-    return jax_compat.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)

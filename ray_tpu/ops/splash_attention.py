@@ -17,26 +17,27 @@ Layout matches the rest of ``ops/``: q ``[B, S, H, D]``, k/v
 per-batch ``[H, S, D]`` with a pre-scaled q, so the wrapper transposes and
 vmaps over batch.
 
-Dispatch contract (`splash_mha`): returns the attention output, or **None**
-when splash cannot run here (pallas ops missing, shape doesn't tile, kernel
-construction failed) after emitting one RuntimeWarning per process — the
-caller then falls back to the ``mha`` dispatcher.  Never raises ImportError.
+Dispatch contract (`splash_mha`): returns the attention output, or raises
+``ValueError`` with the reason when the shape does not tile
+(``splash_supported``).  It never degrades to another implementation: a
+caller that asked for splash gets splash or an error.
 
-Off TPU the kernel runs in pallas interpret mode, which is numerically
-faithful (tier-1 pins parity against ``ops/flash_attention`` on GQA+causal
-shapes) but slow — interpret mode is for correctness gates, not benchmarks.
+On the CPU backend the kernel runs in pallas interpret mode (counted in
+``flash_attention.INTERPRET_TRACES``), which is numerically faithful (tier-1
+pins parity against ``ops/flash_attention`` on GQA+causal shapes) but slow —
+interpret mode is for correctness gates, not benchmarks.  On a TPU it never
+runs interpreted.
 """
 
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..util import jax_compat
+from .flash_attention import kernel_batch_spec, resolve_interpret
 
 __all__ = ["splash_mha", "splash_supported", "DEFAULT_BLOCK"]
 
@@ -44,19 +45,6 @@ __all__ = ["splash_mha", "splash_supported", "DEFAULT_BLOCK"]
 #: sweet spot measured for the in-repo flash kernel on v5e (PROFILE_CORE.md);
 #: splash shrinks it to the largest 128-multiple that divides the sequence.
 DEFAULT_BLOCK = 512
-
-_warned = False
-
-
-def _warn_once(reason: str) -> None:
-    global _warned
-    if not _warned:
-        _warned = True
-        warnings.warn(
-            "splash attention unavailable (%s); falling back to the "
-            "flash/plain attention path" % reason,
-            RuntimeWarning, stacklevel=3)
-
 
 def _pick_block(seq: int, cap: int) -> int:
     """Largest multiple of 128 that is <= cap and divides seq."""
@@ -72,8 +60,6 @@ def _pick_block(seq: int, cap: int) -> int:
 def splash_supported(seq_q: int, seq_kv: int, num_heads: int,
                      num_kv_heads: int, head_dim: int) -> Optional[str]:
     """None when the shape tiles for the splash kernel, else the reason."""
-    if not jax_compat.has_splash_attention():
-        return "pallas splash ops not importable in this jax"
     if head_dim % 128 != 0:
         return f"head_dim={head_dim} not a multiple of 128"
     if seq_q % 128 != 0 or seq_kv % 128 != 0:
@@ -106,27 +92,29 @@ def _get_kernel(num_q_heads: int, seq_q: int, seq_kv: int, causal: bool,
         block_q_dkv=block_q_bwd, block_kv_dkv=block_kv_bwd,
         block_kv_dkv_compute=block_kv_bwd,
         block_q_dq=block_q_bwd, block_kv_dq=block_kv_bwd)
-    return sak.make_splash_mha(
-        mask, block_sizes=block_sizes, head_shards=1, q_seq_shards=1,
-        attn_logits_soft_cap=(float(softcap) if softcap else None),
-        interpret=interpret)
+    # The kernel object outlives the trace that first asks for it (the
+    # cache above), so its mask-info arrays must be concrete: built inside a
+    # shard_map or remat trace they would be tracers of that trace.
+    with jax.ensure_compile_time_eval():
+        return sak.make_splash_mha(
+            mask, block_sizes=block_sizes, head_shards=1, q_seq_shards=1,
+            attn_logits_soft_cap=(float(softcap) if softcap else None),
+            interpret=interpret)
 
 
-def _shard_map_call(kernel, qs, ks, vs, mesh, batch_axes):
-    """TPU multi-device path: batch-shard the kernel call via shard_map.
+def _shard_map_call(kernel, qs, ks, vs, mesh, spec):
+    """Multi-device path: batch-shard the kernel call via shard_map.
 
-    Under plain jit XLA treats the pallas call as an opaque custom call and
-    would gather the batch onto every device; shard_map keeps each device on
-    its local batch shard (the SNIPPETS.md maxtext recipe).  head_shards and
-    q_seq_shards stay 1 — batch is the only sharded dim here, so the
-    kernel's manual_sharding_spec is the replicated spec.
+    Under plain jit the compiler refuses to partition the Mosaic call;
+    shard_map keeps each device on its local batch shard (the SNIPPETS.md
+    maxtext recipe).  head_shards and q_seq_shards stay 1 — batch is the
+    only sharded dim here, so the kernel's manual_sharding_spec is the
+    replicated spec.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    axes = tuple(a for a in batch_axes if a in mesh.axis_names)
-    spec = P(axes if axes else None, None, None, None)
     kernel_spec = kernel.manual_sharding_spec(
         NamedSharding(mesh, P(None, None)))
-    fn = jax_compat.shard_map(
+    fn = jax.shard_map(
         lambda kern, q, k, v: jax.vmap(kern)(q, k, v),
         mesh=mesh, in_specs=(kernel_spec, spec, spec, spec),
         out_specs=spec, check_vma=False)
@@ -136,48 +124,39 @@ def _shard_map_call(kernel, qs, ks, vs, mesh, batch_axes):
 def splash_mha(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                causal: bool = True, logit_softcap: float = 0.0,
                mesh=None, batch_axes: Tuple[str, ...] = ("dp", "fsdp"),
-               manual: bool = False, interpret: Optional[bool] = None,
+               interpret: Optional[bool] = None,
                block_q: int = DEFAULT_BLOCK, block_kv: int = DEFAULT_BLOCK,
                block_q_bwd: Optional[int] = None,
-               block_kv_bwd: Optional[int] = None) -> Optional[jnp.ndarray]:
+               block_kv_bwd: Optional[int] = None) -> jnp.ndarray:
     """Splash attention over [B, S, H, D] q and [B, S, KV, D] k/v.
 
-    Returns None (after one RuntimeWarning per process) when splash cannot
-    serve this call — the caller is expected to fall back to ``mha``.
+    Raises ``ValueError`` when the shape does not tile for the kernel.
 
-    ``manual=True`` means we are already inside a manually-partitioned
-    region (shard_map body) and operands are per-device local: call the
-    kernel directly.  Otherwise, with a multi-device ``mesh`` on TPU the
-    call is batch-sharded via shard_map; on CPU (interpret mode) the direct
-    call stays auto-partitionable because interpret lowers to plain HLO.
+    With a ``mesh`` of more than one device the call is batch-sharded via
+    shard_map (same rule as ``flash_attention``).  Pass no mesh from inside
+    a manually-partitioned region (a shard_map body), where operands are
+    per-device local already.
     """
     b, seq_q, num_heads, head_dim = q.shape
     seq_kv, num_kv = k.shape[1], k.shape[2]
     reason = splash_supported(seq_q, seq_kv, num_heads, num_kv, head_dim)
     if reason is not None:
-        _warn_once(reason)
-        return None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        raise ValueError(f"splash attention cannot run this shape: {reason}")
+    interpret = resolve_interpret(interpret, "splash")
     bq = _pick_block(seq_q, block_q)
     bkv = _pick_block(seq_kv, block_kv)
     bq_bwd = _pick_block(seq_q, block_q_bwd or block_q)
     bkv_bwd = _pick_block(seq_kv, block_kv_bwd or block_kv)
-    try:
-        kernel = _get_kernel(num_heads, seq_q, seq_kv, bool(causal),
-                             float(logit_softcap), bq, bkv, bq_bwd, bkv_bwd,
-                             bool(interpret))
-    except Exception as exc:  # mask/kernel construction failed
-        _warn_once(f"kernel construction failed: {exc!r}")
-        return None
+    kernel = _get_kernel(num_heads, seq_q, seq_kv, bool(causal),
+                         float(logit_softcap), bq, bkv, bq_bwd, bkv_bwd,
+                         interpret)
     # kernel applies no softmax scale itself; fold 1/sqrt(D) into q
     qs = (q * (head_dim ** -0.5)).swapaxes(1, 2)   # [B, H, Sq, D]
     ks = k.swapaxes(1, 2)                          # [B, KV, Skv, D]
     vs = v.swapaxes(1, 2)
-    use_shard_map = (mesh is not None and not manual and not interpret
-                     and any(mesh.shape.get(a, 1) > 1 for a in batch_axes))
-    if use_shard_map:
-        out = _shard_map_call(kernel, qs, ks, vs, mesh, batch_axes)
+    spec = kernel_batch_spec(mesh, batch_axes)
+    if spec is not None:
+        out = _shard_map_call(kernel, qs, ks, vs, mesh, spec)
     else:
         out = jax.vmap(kernel)(qs, ks, vs)
     return out.swapaxes(1, 2).astype(q.dtype)

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..util import jax_compat
 from ..models import sharding as shard_rules
 from ..models import transformer
 from ..models.config import TransformerConfig
@@ -265,7 +264,7 @@ def pipeline_loss_fn(cfg: TransformerConfig, mesh: Mesh,
     if auto_axes:
         manual = {pp_axis} | set(dp_axes or ()) | ({sp} if sp else set())
         smap_kwargs["axis_names"] = manual
-    smapped = jax_compat.shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs, batch_spec, batch_spec),
         out_specs=(P(), P()),
@@ -392,7 +391,7 @@ def interleaved_pipeline_loss_fn(cfg: TransformerConfig, mesh: Mesh,
     if auto_axes:
         smap_kwargs["axis_names"] = ({pp_axis} | set(dp_axes or ())
                                      | ({sp} if sp else set()))
-    smapped = jax_compat.shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec_tree, batch_spec, batch_spec),
         out_specs=(P(), P()),

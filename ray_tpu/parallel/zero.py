@@ -24,10 +24,9 @@ per rank.  This module implements the two knobs that change it, per
   int8 block-scaled over the wire (``quant_collectives``), ~4x fewer
   gradient bytes where DCN/ICI bandwidth bounds the dp step.
 
-Both knobs build one full-manual shard_map over the whole step body: the
-0.4.x CPU partitioner rejects partial-auto shard_map (see
-``jax_compat.has_native_shard_map``), and full-manual is also what makes
-the collective schedule explicit instead of compiler-chosen.  The step
+Both knobs build one full-manual shard_map over the whole step body:
+full-manual is what makes the collective schedule explicit instead of
+compiler-chosen.  The step
 requires every mesh axis except dp (and a size-1 fsdp) to be trivial —
 these knobs target the data-parallel axis, compose with tp/pp elsewhere
 is future work.
@@ -49,7 +48,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models import transformer
 from ..models.config import TransformerConfig
 from ..models.transformer import ParallelContext
-from ..util import jax_compat
 from .quant_collectives import (DEFAULT_BLOCK, quantized_all_gather,
                                 quantized_psum_scatter)
 from .train_step import TrainState
@@ -294,7 +292,7 @@ def make_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
     state_specs = jax.tree.map(lambda s: s.spec, state_sh, is_leaf=is_sh)
     batch_spec = P(tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names),
                    None)
-    sharded = jax_compat.shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_specs, batch_spec),
         out_specs=(state_specs, P()),

@@ -70,13 +70,6 @@ class LearnerWorker:
         import jax
 
         if world > 1:
-            import os
-
-            from ray_tpu.util import jax_compat
-            if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-                # CPU-only learner groups (CI) need gloo collectives
-                # selected before the backend exists.
-                jax_compat.enable_cpu_multiprocess_collectives()
             jax.distributed.initialize(coordinator_address=coordinator,
                                        num_processes=world, process_id=rank)
         from .learner import Learner

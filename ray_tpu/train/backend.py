@@ -63,11 +63,6 @@ def _setup_jax_distributed(coordinator: str, num_processes: int,
                            process_id: int) -> None:
     import jax
 
-    from ray_tpu.util import jax_compat
-    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        # CPU-only namespaces (CI, local smoke runs) need the gloo
-        # collectives implementation selected before the backend exists.
-        jax_compat.enable_cpu_multiprocess_collectives()
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

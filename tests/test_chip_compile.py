@@ -1,0 +1,215 @@
+"""Compile the main path's device programs for a described TPU v5e, no chip.
+
+The TPU's compiler is installed where the tests run, and it compiles for a
+chip that is described and not attached (``jax.experimental.topologies``).
+Interpret mode, which every other kernel test here uses, lowers a Pallas
+kernel to plain HLO and so cannot see what the chip's compiler refuses: a
+block that does not tile, too much VMEM, a Mosaic call left to the SPMD
+partitioner.  Each case below is one compile with ``interpret=False`` at the
+real widths of a model the repo ships, a few seconds apiece.  A compile that
+passes is not a run: nothing here says anything about results or speed.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load libtpu, and every xdist worker imports this file).
+All cases stay in this one file so they land on the one worker that holds
+the library.  Where dispatch asks ``jax.default_backend()`` the test steers
+it (``as_tpu``); the program has no option for that.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import config as mcfg
+from ray_tpu.models import decode, paged_decode, speculative, transformer
+
+LLAMA_400M = mcfg.llama_400m()
+KERNEL = "tpu_custom_call"   # how a compiled Pallas kernel shows in the HLO
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or it is held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Dispatch that asks the backend sees the chip the program is compiled
+    for, not the CPU the test runs on."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _on(sharding, tree):
+    """Shapes of ``tree`` placed by ``sharding`` (one sharding, or a tree)."""
+    if isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+    return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=s), tree, sharding)
+
+
+def _compile(fn, *args, **jit_kw):
+    compiled = jax.jit(fn, **jit_kw).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+def _qkv(sharding, b, s, h, kv, d):
+    return (jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=sharding),
+            jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=sharding),
+            jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=sharding))
+
+
+# --------------------------------------------------------------- kernels
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", [
+    pytest.param((8, 2048, 12, 6, 128), id="llama-400m"),
+    pytest.param((8, 1024, 12, 12, 64), id="gpt2-124m"),
+])
+def test_flash_attention_compiles(one_chip, shape, direction):
+    from ray_tpu.ops.flash_attention import flash_attention
+    fn = functools.partial(flash_attention, causal=True, interpret=False)
+    if direction == "bwd":
+        fwd = fn
+        fn = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                      argnums=(0, 1, 2))
+    _, text = _compile(fn, *_qkv(one_chip, *shape))
+    # forward: one kernel; backward: forward + dq + dkv kernels
+    assert text.count(KERNEL) >= (1 if direction == "fwd" else 3)
+
+
+def test_splash_attention_compiles_fwd_bwd(one_chip):
+    from ray_tpu.ops.splash_attention import splash_mha
+
+    def loss(q, k, v):
+        return splash_mha(q, k, v, causal=True,
+                          interpret=False).astype(jnp.float32).sum()
+
+    _, text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                       *_qkv(one_chip, 4, 2048, 16, 8, 128))   # llama-1b heads
+    assert KERNEL in text
+
+
+# ------------------------------------------------------ the serve programs
+
+SLOTS, MAX_LEN, STEPS = 17, 1024, 8      # 16 slots + the scratch slot
+
+
+def _serve_shapes(one_chip, cfg, paged):
+    params = jax.eval_shape(lambda: transformer.init_params(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
+    if paged:
+        cache = jax.eval_shape(lambda: paged_decode.init_paged_cache(
+            cfg, SLOTS * (MAX_LEN // 64) // 2, 64, SLOTS, MAX_LEN // 64))
+    else:
+        cache = jax.eval_shape(lambda: decode.init_kv_cache(
+            cfg, SLOTS, MAX_LEN))
+    state = jax.eval_shape(lambda: decode.init_decode_state(
+        SLOTS, jax.random.PRNGKey(1)))
+    return _on(one_chip, params), _on(one_chip, cache), _on(one_chip, state)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_decode_state_loop_compiles(one_chip, paged):
+    """The engine's one decode dispatch: 8 steps, cache and state donated."""
+    loop = (paged_decode.paged_decode_state_loop if paged
+            else decode.decode_state_loop)
+    params, cache, state = _serve_shapes(one_chip, LLAMA_400M, paged)
+    compiled, _ = _compile(
+        lambda p, c, st: loop(p, c, st, STEPS, LLAMA_400M, 0, jnp.bfloat16),
+        params, cache, state, donate_argnums=(1, 2))
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_dense_prefill_1024_takes_the_flash_kernel(one_chip, as_tpu):
+    """The engine's admit program at the 1024 bucket, batch 8: dense prefill
+    reaches the flash kernel through the ``mha`` dispatcher."""
+    params, cache, state = _serve_shapes(one_chip, LLAMA_400M, paged=False)
+    b = 8
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,  # noqa: E731
+                                              sharding=one_chip)
+    args = (i32(b, 1024), i32(b), i32(b),
+            jax.ShapeDtypeStruct((b,), jnp.float32, sharding=one_chip),
+            i32(b), i32(b),
+            jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=one_chip))
+    _, text = _compile(
+        lambda p, c, st, *a: decode.prefill_admit(
+            p, c, st, *a, LLAMA_400M, 0, jnp.bfloat16),
+        params, cache, state, *args, donate_argnums=(1, 2))
+    assert KERNEL in text, "prefill at seq 1024 compiled plain attention"
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_speculative_verify_window_compiles(one_chip, paged):
+    """The target's k+1-token verify step of speculative decode (k=4)."""
+    verify = (paged_decode.paged_verify_window if paged
+              else speculative.verify_window)
+    params, cache, _ = _serve_shapes(one_chip, LLAMA_400M, paged)
+    _compile(
+        lambda p, c, t, a: verify(p, c, t, a, LLAMA_400M, jnp.bfloat16),
+        params, cache,
+        jax.ShapeDtypeStruct((SLOTS, 5), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one_chip),
+        donate_argnums=(1,))
+
+
+# ------------------------------------------------- the sharded train step
+
+@pytest.mark.parametrize("impl", ["auto", "splash"])
+def test_sharded_train_step_compiles(topo, as_tpu, impl):
+    """llama-1b widths, depth cut to 2 layers, ``MeshSpec(fsdp=-1)`` over the
+    four chips.  ``attention_impl="auto"``, the default, is the README quick
+    start: the flash kernel must sit in a shard_map, or the compiler refuses
+    the step with "Mosaic kernels cannot be automatically partitioned".
+    ``"splash"`` takes the same wrap."""
+    import dataclasses
+
+    from ray_tpu.parallel import MeshSpec, make_optimizer, make_train_step
+    from ray_tpu.parallel.train_step import TrainState, state_shardings
+
+    assert mcfg.llama_1b().attention_impl == "auto"
+    cfg = dataclasses.replace(mcfg.llama_1b(), num_layers=2,
+                              attention_impl=impl)
+    mesh = MeshSpec(fsdp=-1).build(topo.devices)
+    assert isinstance(mesh, Mesh) and mesh.size == 4
+    opt = make_optimizer()
+
+    def init():
+        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+        return TrainState(params=params, opt_state=opt.init(params),
+                          step=jnp.zeros((), jnp.int32))
+
+    shapes = jax.eval_shape(init)
+    sh = state_shardings(cfg, mesh, opt, shapes)
+    step = make_train_step(cfg, mesh, opt, sh, remat="save_acts")
+    tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32,
+                               sharding=step.batch_sharding)
+    compiled = step._jitted.lower(
+        _on(sh, shapes), {"tokens": tok, "targets": tok}).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) >= 3, "no attention kernel in the sharded step"
+    for collective in ("all-gather", "reduce-scatter"):
+        assert collective in text, f"fsdp step without {collective}"
+    # fsdp shards the big weights: each device holds about a quarter
+    wq = sh.params["blocks"]["attn"]["wq"]
+    assert isinstance(wq, NamedSharding) and wq.spec != P()
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    total = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                for x in jax.tree.leaves(shapes))
+    assert per_device < 0.3 * total + 1e6

@@ -12,16 +12,9 @@ them:
 * ZeRO-sharded update allclose to the replicated update over 10 steps
   (same seed, fp32) — AdamW is elementwise, so sharding the update must
   not change the math,
-* ``has_splash_attention`` degrades to flash with ONE RuntimeWarning on
-  a jax with no pallas ops — never an ImportError (stub-jax subprocess,
-  the test_bench_skip pattern).
+* an explicit ``attention_impl`` that cannot run raises (no degrading), and
+  interpret mode is taken only on the CPU backend, visibly.
 """
-
-import pathlib
-import subprocess
-import sys
-import textwrap
-import warnings
 
 import numpy as np
 import pytest
@@ -35,7 +28,6 @@ from ray_tpu.parallel import (OptimizerSpec, init_sharded_state,  # noqa: E402
 from ray_tpu.parallel.quant_collectives import (  # noqa: E402
     dequantize_int8_block, quantize_int8_block)
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _qkv(b=2, s=256, h=4, kv=2, d=128, seed=0):
@@ -57,7 +49,6 @@ def test_splash_interpret_parity_with_flash(causal):
     q, k, v = _qkv()
     ref = flash_attention(q, k, v, causal=causal)
     out = splash_mha(q, k, v, causal=causal)
-    assert out is not None, "splash declined a supported shape"
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-4
 
     def loss(fn):
@@ -73,10 +64,11 @@ def test_splash_interpret_parity_with_flash(causal):
 
 def test_splash_through_model_and_fallback_warning():
     """attention_impl="splash" matches the default impl through the full
-    model (logits-level), and an unsupported shape (head_dim 16) degrades
-    to the mha path with exactly one RuntimeWarning per process."""
-    import ray_tpu.ops.splash_attention as sa
+    model (logits-level) in interpret mode, which the CPU backend takes and
+    records; an explicit impl that cannot run the shape (head_dim 16)
+    raises and names the reason — it never degrades to another path."""
     from ray_tpu.models import transformer
+    from ray_tpu.ops.flash_attention import INTERPRET_TRACES
 
     base = mcfg.TransformerConfig(
         vocab_size=128, num_layers=2, hidden_size=512, num_heads=4,
@@ -87,52 +79,39 @@ def test_splash_through_model_and_fallback_warning():
                                      dtype=jnp.float32)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 128)
     ref = transformer.apply(params, toks, base, compute_dtype=jnp.float32)[0]
+    before = INTERPRET_TRACES.get("splash", 0)
     out = transformer.apply(params, toks, splash_cfg,
                             compute_dtype=jnp.float32)[0]
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-3
+    assert INTERPRET_TRACES["splash"] > before  # the CPU run said so
 
-    tiny_splash = mcfg.TransformerConfig(
-        **{**mcfg.tiny().__dict__, "attention_impl": "splash"})
-    p2 = transformer.init_params(jax.random.PRNGKey(0), tiny_splash,
-                                 dtype=jnp.float32)
     t2 = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 256)
-    sa._warned = False  # fresh per-process warning latch
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        transformer.apply(p2, t2, tiny_splash, compute_dtype=jnp.float32)
-        transformer.apply(p2, t2, tiny_splash, compute_dtype=jnp.float32)
-    splash_warnings = [w for w in caught
-                       if issubclass(w.category, RuntimeWarning)
-                       and "splash" in str(w.message)]
-    assert len(splash_warnings) == 1, splash_warnings
+    for impl, why in (("splash", "head_dim=16"), ("flash", "softcap")):
+        extra = {"attn_logit_softcap": 30.0} if impl == "flash" else {}
+        cfg = mcfg.TransformerConfig(
+            **{**mcfg.tiny().__dict__, "attention_impl": impl, **extra})
+        p2 = transformer.init_params(jax.random.PRNGKey(0), cfg,
+                                     dtype=jnp.float32)
+        with pytest.raises(ValueError, match=why):
+            transformer.apply(p2, t2, cfg, compute_dtype=jnp.float32)
 
 
-def test_has_splash_attention_degrades_without_pallas(tmp_path):
-    """util/jax_compat.has_splash_attention() on a jax that has no pallas
-    ops tree: False, no ImportError escape (stub-jax subprocess — the
-    test_bench_skip pattern, loading jax_compat standalone so the stub
-    only has to satisfy jax_compat's imports)."""
-    pkg = tmp_path / "jax"
-    pkg.mkdir()
-    (pkg / "__init__.py").write_text("")  # no pallas anywhere
-    script = tmp_path / "probe.py"
-    script.write_text(textwrap.dedent(f"""
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "jax_compat", {str(REPO / 'ray_tpu/util/jax_compat.py')!r})
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert mod.has_splash_attention() is False
-        assert mod.has_splash_attention() is False  # cached re-probe
-        print("DEGRADED_OK")
-    """))
-    proc = subprocess.run(
-        [sys.executable, str(script)], capture_output=True, text=True,
-        timeout=120,
-        env={"PATH": "/usr/bin:/bin", "PYTHONPATH": str(tmp_path),
-             "HOME": "/tmp"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "DEGRADED_OK" in proc.stdout
+def test_interpret_mode_is_never_taken_on_a_tpu(monkeypatch):
+    """The kernels pick interpret mode by themselves only on the CPU
+    backend; where the backend says "tpu" an interpreted run is refused
+    and the automatic choice is the compiled kernel."""
+    from ray_tpu.ops import flash_attention as fa
+
+    assert fa.resolve_interpret(None, "flash") is True      # CPU here
+    assert fa.resolve_interpret(False, "flash") is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert fa.resolve_interpret(None, "flash") is False
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        fa.resolve_interpret(True, "splash")
+    q, k, v = _qkv(s=192)   # 192 is no multiple of the 128-wide tiles
+    with pytest.raises(ValueError, match="seq"):
+        fa.flash_attention(q[:, :, :, :64], k[:, :, :, :64], v[:, :, :, :64],
+                           block_q=128, block_kv=128)
 
 
 # --------------------------------------------------------------- quant reduce
@@ -173,8 +152,6 @@ def test_quantized_psum_scatter_bounded_and_deterministic():
     """The wire collective inside a real dp=4 shard_map: result within the
     declared bound of the exact fp32 reduce-scatter, chunk placement
     identical to lax.psum_scatter, and bitwise repeatable."""
-    from ray_tpu.util import jax_compat
-
     mesh = make_mesh(4, dp=4, fsdp=1)
     dp, n = 4, 4096
     x = jax.random.normal(jax.random.PRNGKey(7), (dp, n), jnp.float32)
@@ -188,10 +165,10 @@ def test_quantized_psum_scatter_bounded_and_deterministic():
         return exact[None], quant[None]
 
     from jax.sharding import PartitionSpec as P
-    fn = jax_compat.shard_map(body, mesh=mesh,
-                              in_specs=P(("dp",), None),
-                              out_specs=(P(("dp",), None), P(("dp",), None)),
-                              check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=P(("dp",), None),
+                       out_specs=(P(("dp",), None), P(("dp",), None)),
+                       check_vma=False)
     exact1, quant1 = fn(x)
     _, quant2 = fn(x)
     assert jnp.array_equal(quant1, quant2)

@@ -78,12 +78,25 @@ def test_flash_pallas_backward_matches_plain(causal, kv_heads):
         assert float(jnp.max(jnp.abs(a - b))) / scale < 1e-4
 
 
-def test_flash_uneven_seq_falls_back():
-    """Non-block-divisible shapes take the plain path, still correct."""
+def test_flash_uneven_seq_raises_and_auto_takes_plain(monkeypatch):
+    """A shape that does not tile: the kernel asked for by name raises and
+    says why; the ``mha`` dispatcher, which chooses, takes the plain path
+    (also where the backend is a TPU) and is still correct."""
+    from ray_tpu.ops.attention import mha
+    from ray_tpu.ops.flash_attention import flash_supported
+
     q, k, v = _qkv(S=48)
-    ref = attend(q, k, v)
-    out = flash_attention(q, k, v, block_q=32, block_kv=32)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    with pytest.raises(ValueError, match="not a multiple of the blocks"):
+        flash_attention(q, k, v, block_q=32, block_kv=32)
+    assert flash_supported(1536, 1536, 4, 2) is None
+    assert "seq" in flash_supported(1100, 1100, 4, 2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    long_q = jnp.zeros((1, 1100, 4, 64))    # >= 1024 but no multiple of 512
+    long_kv = jnp.zeros((1, 1100, 2, 64))
+    out = mha(long_q, long_kv, long_kv)     # would raise if it chose flash
+    assert out.shape == long_q.shape
+    np.testing.assert_allclose(np.asarray(mha(q, k, v)),
+                               np.asarray(attend(q, k, v)), atol=2e-5)
 
 
 def test_chunked_cross_entropy_matches_full():
