@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import TransformerConfig
-from .transformer import Params, _norm, _rope, lm_head_weight
+from .transformer import Params, _norm, _rope, lm_head_logits
 
 KVCache = Dict[str, jnp.ndarray]
 
@@ -59,6 +59,7 @@ def cache_bytes(cfg: TransformerConfig, num_slots: int, max_len: int,
 # Shared per-layer attention pieces
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn")
 def _qkv(x, p, cfg: TransformerConfig, positions):
     """x: [B, S, H] -> q [B,S,NH,D], k/v [B,S,NKV,D] with RoPE applied."""
     b, s, _ = x.shape
@@ -92,6 +93,7 @@ def _rope_per_row(x: jnp.ndarray, positions: jnp.ndarray,
     return out.astype(x.dtype)
 
 
+@jax.named_scope("mlp")
 def _mlp(y, p, cfg: TransformerConfig):
     cast = y.dtype
     if cfg.num_experts > 1:
@@ -109,6 +111,7 @@ def _mlp(y, p, cfg: TransformerConfig):
     return h @ mp["w_out"].astype(cast) + mp["b_out"].astype(cast)
 
 
+@jax.named_scope("attn")
 def _proj_out(attn, p, cast):
     out = attn @ p["wo"].astype(cast)
     if "bo" in p:
@@ -143,14 +146,16 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
         lp, k_lay, v_lay = layer        # k/v_lay: [slots, max_len, NKV, D]
         y = _norm(x, lp["attn_norm"], cfg)
         q, k, v = _qkv(y, lp["attn"], cfg, positions)
-        attn = mha(q, k, v, causal=True,
-                   logit_softcap=cfg.attn_logit_softcap)
+        with jax.named_scope("attn"):
+            attn = mha(q, k, v, causal=True,
+                       logit_softcap=cfg.attn_logit_softcap)
         x = x + _proj_out(attn.reshape(b, s, -1), lp["attn"], cast)
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
         # write this layer's K/V into the slots (padded tail included;
         # decode's length mask keeps it unread)
-        k_lay = k_lay.at[slot_ids, :s].set(k.astype(k_lay.dtype))
-        v_lay = v_lay.at[slot_ids, :s].set(v.astype(v_lay.dtype))
+        with jax.named_scope("kv_write"):
+            k_lay = k_lay.at[slot_ids, :s].set(k.astype(k_lay.dtype))
+            v_lay = v_lay.at[slot_ids, :s].set(v.astype(v_lay.dtype))
         return x, (k_lay, v_lay)
 
     x, (k_new, v_new) = jax.lax.scan(
@@ -159,7 +164,7 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
     # logits of each prompt's *last real token* (next-token distribution)
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]  # [B, H]
-    logits = (last @ lm_head_weight(params, cfg, cast)).astype(jnp.float32)
+    logits = lm_head_logits(params, last, cfg)
     cache = {
         "k": k_new, "v": v_new,
         "length": cache["length"].at[slot_ids].set(lengths),
@@ -200,22 +205,25 @@ def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
         y = _norm(x, lp["attn_norm"], cfg)
         q, k, v = _qkv(y, lp["attn"], cfg, positions)  # q:[S,1,NH,D] k/v:[S,1,NKV,D]
         # append at position `length` (one row per slot)
-        k_lay = k_lay.at[jnp.arange(n_slots), lengths].set(
-            k[:, 0].astype(k_lay.dtype))
-        v_lay = v_lay.at[jnp.arange(n_slots), lengths].set(
-            v[:, 0].astype(v_lay.dtype))
+        with jax.named_scope("kv_write"):
+            k_lay = k_lay.at[jnp.arange(n_slots), lengths].set(
+                k[:, 0].astype(k_lay.dtype))
+            v_lay = v_lay.at[jnp.arange(n_slots), lengths].set(
+                v[:, 0].astype(v_lay.dtype))
         # attention over the cache row
-        qh = q[:, 0].reshape(n_slots, cfg.num_kv_heads, reps, cfg.head_dim)
-        scores = jnp.einsum("sgrd,smgd->sgrm", qh.astype(jnp.float32),
-                            k_lay.astype(jnp.float32)) * scale
-        if cfg.attn_logit_softcap:
-            c = cfg.attn_logit_softcap
-            scores = c * jnp.tanh(scores / c)
-        scores = jnp.where(pos_mask[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("sgrm,smgd->sgrd", probs,
-                          v_lay.astype(jnp.float32))
-        attn = attn.reshape(n_slots, 1, cfg.num_heads * cfg.head_dim)
+        with jax.named_scope("kv_read"):
+            qh = q[:, 0].reshape(n_slots, cfg.num_kv_heads, reps,
+                                 cfg.head_dim)
+            scores = jnp.einsum("sgrd,smgd->sgrm", qh.astype(jnp.float32),
+                                k_lay.astype(jnp.float32)) * scale
+            if cfg.attn_logit_softcap:
+                c = cfg.attn_logit_softcap
+                scores = c * jnp.tanh(scores / c)
+            scores = jnp.where(pos_mask[:, None, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            attn = jnp.einsum("sgrm,smgd->sgrd", probs,
+                              v_lay.astype(jnp.float32))
+            attn = attn.reshape(n_slots, 1, cfg.num_heads * cfg.head_dim)
         x = x + _proj_out(attn.astype(cast), lp["attn"], cast)
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
         return x, (k_lay, v_lay)
@@ -223,7 +231,7 @@ def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["blocks"], cache["k"], cache["v"]))
     x = _norm(x, params["final_norm"], cfg)
-    logits = (x[:, 0] @ lm_head_weight(params, cfg, cast)).astype(jnp.float32)
+    logits = lm_head_logits(params, x[:, 0], cfg)
     cache = {
         "k": k_new, "v": v_new,
         "length": jnp.where(active, jnp.minimum(lengths + 1, max_len),

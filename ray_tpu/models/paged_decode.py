@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import TransformerConfig
-from .transformer import Params, _norm, lm_head_weight
+from .transformer import Params, _norm, lm_head_logits
 
 from .decode import (_mlp, _proj_out, _qkv, sample_per_slot)
 
@@ -149,7 +149,7 @@ def paged_prefill(params: Params, cache: PagedKVCache, tokens: jnp.ndarray,
     x = _norm(x, params["final_norm"], cfg)
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
-    logits = (last @ lm_head_weight(params, cfg, cast)).astype(jnp.float32)
+    logits = lm_head_logits(params, last, cfg)
     new_len = start_pos + lengths
     cache = {
         "k": k_new, "v": v_new,
@@ -213,7 +213,7 @@ def paged_decode_step(params: Params, cache: PagedKVCache,
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["blocks"], cache["k"], cache["v"]))
     x = _norm(x, params["final_norm"], cfg)
-    logits = (x[:, 0] @ lm_head_weight(params, cfg, cast)).astype(jnp.float32)
+    logits = lm_head_logits(params, x[:, 0], cfg)
     cache = {
         "k": k_new, "v": v_new,
         "block_table": cache["block_table"],
@@ -298,7 +298,7 @@ def paged_verify_window(params: Params, cache: PagedKVCache,
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["blocks"], cache["k"], cache["v"]))
     x = _norm(x, params["final_norm"], cfg)
-    logits = (x @ lm_head_weight(params, cfg, cast)).astype(jnp.float32)
+    logits = lm_head_logits(params, x, cfg)
     cache = {
         "k": k_new, "v": v_new,
         "block_table": bt,

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from .config import TransformerConfig
 from .decode import (KVCache, Params, _mlp, _norm, _proj_out, _qkv,
-                     decode_step, lm_head_weight, sample_per_slot)
+                     decode_step, lm_head_logits, sample_per_slot)
 
 __all__ = ["verify_window", "speculative_round", "speculative_decode_loop",
            "spec_state_round", "spec_decode_state_loop", "make_draft_params",
@@ -98,7 +98,7 @@ def verify_window(params: Params, cache: KVCache, tokens: jnp.ndarray,
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["blocks"], cache["k"], cache["v"]))
     x = _norm(x, params["final_norm"], cfg)
-    logits = (x @ lm_head_weight(params, cfg, cast)).astype(jnp.float32)
+    logits = lm_head_logits(params, x, cfg)
     cache = {
         "k": k_new, "v": v_new,
         "length": jnp.where(active, jnp.minimum(lengths + k, max_len),

@@ -155,7 +155,13 @@ def init_params(key: jax.Array, cfg: TransformerConfig,
 # ---------------------------------------------------------------------------
 # Building blocks
 # ---------------------------------------------------------------------------
+#
+# Each part of a block is written once and carries a ``jax.named_scope``
+# (attn, mlp, norm here; kv_write, kv_read, lm_head in decode.py; loss
+# below; optimizer in the train step): compile-time metadata on its
+# operations, which a profiler's op view groups by.
 
+@jax.named_scope("norm")
 def _norm(x, p, cfg: TransformerConfig):
     x32 = x.astype(jnp.float32)
     if cfg.use_rmsnorm:
@@ -180,6 +186,7 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     return out.astype(x.dtype)
 
 
+@jax.named_scope("attn")
 def _attention_block(x, p, cfg: TransformerConfig, positions, pctx: ParallelContext):
     b, s, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -236,6 +243,7 @@ def _attention_block(x, p, cfg: TransformerConfig, positions, pctx: ParallelCont
     return out
 
 
+@jax.named_scope("mlp")
 def _mlp_block(x, p, cfg: TransformerConfig):
     cast = x.dtype
     if cfg.use_swiglu:
@@ -323,6 +331,14 @@ def lm_head_weight(params: Params, cfg: TransformerConfig, dtype) -> jnp.ndarray
     return params["lm_head"].astype(dtype)
 
 
+@jax.named_scope("lm_head")
+def lm_head_logits(params: Params, x: jnp.ndarray,
+                   cfg: TransformerConfig) -> jnp.ndarray:
+    """Hidden states [..., H] -> f32 logits [..., V], the head applied in
+    the hidden states' own dtype."""
+    return (x @ lm_head_weight(params, cfg, x.dtype)).astype(jnp.float32)
+
+
 def apply(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
           pctx: ParallelContext = ParallelContext(),
           compute_dtype=jnp.bfloat16,
@@ -330,10 +346,10 @@ def apply(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
           ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """tokens: [B, S] int32 -> (logits [B, S, V] f32, aux dict)."""
     x, aux = apply_trunk(params, tokens, cfg, pctx, compute_dtype, remat=remat)
-    logits = x @ lm_head_weight(params, cfg, x.dtype)
-    return logits.astype(jnp.float32), aux
+    return lm_head_logits(params, x, cfg), aux
 
 
+@jax.named_scope("loss")
 def chunked_cross_entropy(x: jnp.ndarray, w: jnp.ndarray,
                           targets: jnp.ndarray, chunk: int) -> jnp.ndarray:
     """Blockwise LM-head + softmax cross entropy: peak memory O(B*chunk*V)
@@ -454,9 +470,11 @@ def causal_lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
         w = lm_head_weight(params, cfg, x.dtype)
         nll = chunked_cross_entropy(x, w, targets, min(loss_chunk, s))
     else:
-        logits = (x @ lm_head_weight(params, cfg, x.dtype)).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        logits = lm_head_logits(params, x, cfg)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1)[..., 0]
     mask = batch.get("loss_mask")
     if mask is None:
         loss = nll.mean()

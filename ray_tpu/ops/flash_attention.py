@@ -122,6 +122,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
             jax.ShapeDtypeStruct((b, h, 8, s), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -339,6 +340,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, gf, lse4, dlt4)
 
     dk, dv = pl.pallas_call(
@@ -371,6 +373,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
         scratch_shapes=[pltpu.VMEM((bkv, d), jnp.float32),
                         pltpu.VMEM((bkv, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, gf, lse4, dlt4)
     return dq, dk, dv
 

@@ -22,6 +22,7 @@ from ..models import sharding as shard_rules
 from ..models import transformer
 from ..models.config import TransformerConfig
 from ..models.transformer import ParallelContext
+from ..util.profiler import PROGRAM_TRAIN_STEP, named_jit
 from .mesh import named_sharding
 
 
@@ -138,16 +139,18 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh,
     def step_fn(state: TrainState, batch: Dict[str, jnp.ndarray]):
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state,
+                                                state.params)
+            new_params = optax.apply_updates(state.params, updates)
         gnorm = optax.global_norm(grads)
         metrics = dict(metrics)
         metrics["grad_norm"] = gnorm
         metrics["total_loss"] = loss
         return TrainState(new_params, new_opt, state.step + 1), metrics
 
-    jitted = jax.jit(
-        step_fn,
+    jitted = named_jit(
+        PROGRAM_TRAIN_STEP, step_fn,
         in_shardings=(state_sh, None),  # batch sharding from the arrays
         out_shardings=(state_sh, None),
         donate_argnums=(0,))

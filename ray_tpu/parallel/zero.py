@@ -48,6 +48,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models import transformer
 from ..models.config import TransformerConfig
 from ..models.transformer import ParallelContext
+from ..util.profiler import PROGRAM_TRAIN_STEP, named_jit
 from .quant_collectives import (DEFAULT_BLOCK, quantized_all_gather,
                                 quantized_psum_scatter)
 from .train_step import TrainState
@@ -263,9 +264,11 @@ def make_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
             g_shard = jax.lax.select(
                 gnorm < opt_spec.grad_clip, g_shard,
                 (g_shard / gnorm) * opt_spec.grad_clip)
-            updates, new_opt = opt_spec.adamw().update(
-                {"p": g_shard}, state.opt_state, {"p": p_shard})
-            new_p_shard = optax.apply_updates({"p": p_shard}, updates)["p"]
+            with jax.named_scope("optimizer"):
+                updates, new_opt = opt_spec.adamw().update(
+                    {"p": g_shard}, state.opt_state, {"p": p_shard})
+                new_p_shard = optax.apply_updates({"p": p_shard},
+                                                  updates)["p"]
             new_flat = jax.lax.all_gather(new_p_shard, "dp", tiled=True)
             new_params = unravel_p(new_flat[:n].astype(flat_p.dtype))
         else:
@@ -276,9 +279,10 @@ def make_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
             else:
                 flat_mean = jax.lax.all_gather(g_shard, "dp", tiled=True)
             grads_mean = unravel(flat_mean[:n])
-            updates, new_opt = optimizer.update(grads_mean, state.opt_state,
-                                                state.params)
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = optimizer.update(
+                    grads_mean, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
 
         metrics = dict(metrics)
         metrics["total_loss"] = loss
@@ -297,8 +301,9 @@ def make_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
         in_specs=(state_specs, batch_spec),
         out_specs=(state_specs, P()),
         check_vma=False)
-    jitted = jax.jit(sharded, in_shardings=(state_sh, None),
-                     out_shardings=(state_sh, None), donate_argnums=(0,))
+    jitted = named_jit(PROGRAM_TRAIN_STEP, sharded,
+                       in_shardings=(state_sh, None),
+                       out_shardings=(state_sh, None), donate_argnums=(0,))
 
     batch_sh = NamedSharding(mesh, batch_spec)
     multiprocess = len({d.process_index for d in mesh.devices.flat}) > 1

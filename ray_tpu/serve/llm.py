@@ -35,6 +35,10 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
+from ray_tpu.util.profiler import (ENGINE_PHASES, PROGRAM_DECODE,
+                                   PROGRAM_DRAFT_PREFILL, PROGRAM_PREFILL,
+                                   PROGRAM_SPEC_DECODE, host_span, named_jit)
+
 from . import observability as obs
 from .deployment import deployment as serve_deployment
 
@@ -44,10 +48,9 @@ _FLUSH = object()
 
 class GenRequest:
     __slots__ = ("tokens", "max_tokens", "temperature", "top_k", "eos_id",
-                 "out", "slot", "generated", "submitted_at", "first_token_at",
-                 "pages", "prompt_len", "deployment", "trace_ctx",
-                 "submitted_wall", "admitted_wall", "first_token_wall",
-                 "span_parent")
+                 "out", "slot", "generated", "submitted_at", "admitted_at",
+                 "emit_times", "pages", "prompt_len", "deployment",
+                 "trace_ctx", "span_parent")
 
     def __init__(self, tokens: List[int], max_tokens: int,
                  temperature: float, top_k: int, eos_id: Optional[int]):
@@ -61,19 +64,45 @@ class GenRequest:
         self.pages: List[int] = []
         self.generated = 0
         self.prompt_len = len(tokens)
+        # one monotonic stamp per stage: submit (the caller's thread), the
+        # dispatch of the admit that carries the request, and every token's
+        # _emit (engine thread; emit_times[0] is the first token's).  The
+        # stage counters difference them; the task-event spans get their
+        # wall time from the engine's one offset (LLMEngine._wall).
         self.submitted_at = time.monotonic()
-        self.first_token_at: Optional[float] = None
+        self.admitted_at: Optional[float] = None
+        self.emit_times: List[float] = []
         # observability: who/what this request belongs to (the replica's
         # deployment tag + the caller's trace context, captured at submit
-        # on the caller's thread) and the wall-clock stage stamps the
-        # engine thread turns into batch_wait/prefill/decode spans
+        # on the caller's thread)
         self.deployment = "-"
         self.trace_ctx: Optional[tuple] = None
-        self.submitted_wall = time.time()
-        self.admitted_wall: Optional[float] = None
-        self.first_token_wall: Optional[float] = None
         #: previous stage's span id — batch_wait -> prefill -> decode chain
         self.span_parent: Optional[str] = None
+
+
+class _Phase:
+    """One interval of one phase of the engine thread: a host span in a
+    profiler capture and the phase's cumulative seconds and count."""
+    __slots__ = ("eng", "name", "span", "t0")
+
+    def __init__(self, eng: "LLMEngine", name: str, **args):
+        self.eng, self.name = eng, name
+        self.span = host_span("engine." + name, **args)
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = time.monotonic()
+        self.eng._phase_open = (self.name, self.t0)
+        return self.span
+
+    def __exit__(self, *exc):
+        now = time.monotonic()
+        self.span.__exit__(*exc)
+        eng = self.eng
+        eng._phase_open = None
+        eng.loop_s[self.name] += now - self.t0
+        eng.loop_n[self.name] += 1
 
 
 class LLMEngine:
@@ -171,18 +200,13 @@ class LLMEngine:
         # Compiled programs: one decode dispatch (cache + state donated —
         # the multi-GB cache must be updated in place, not copied), one
         # prefill per bucket (lazy unless warmup_buckets).
-        if paged:
-            self._decode_fn = jax.jit(
-                lambda p, c, st: self._pdec.paged_decode_state_loop(
-                    p, c, st, self.steps_per_dispatch, cfg, top_k,
-                    self.compute_dtype),
-                donate_argnums=(1, 2))
-        else:
-            self._decode_fn = jax.jit(
-                lambda p, c, st: dec.decode_state_loop(
-                    p, c, st, self.steps_per_dispatch, cfg, top_k,
-                    self.compute_dtype),
-                donate_argnums=(1, 2))
+        loop_fn = (self._pdec.paged_decode_state_loop if paged
+                   else dec.decode_state_loop)
+        self._decode_fn = named_jit(
+            PROGRAM_DECODE,
+            lambda p, c, st: loop_fn(p, c, st, self.steps_per_dispatch, cfg,
+                                     top_k, self.compute_dtype),
+            donate_argnums=(1, 2))
         self._prefill_fns: Dict[int, Any] = {}
 
         # Speculative decoding (spec_decode_enabled=False => today's path
@@ -243,6 +267,24 @@ class LLMEngine:
         self.admit_batches = 0
         self.admit_rows_real = 0
         self.admit_rows_padded = 0
+        # the same per token: prompt tokens prefilled, and every other
+        # position of the [prefill_batch, bucket] arrays that carried them
+        self.admit_tokens_real = 0
+        self.admit_tokens_padded = 0
+        # request stages (engine thread): submit -> dispatch of the admit,
+        # that dispatch -> first token on the request's queue
+        self.admitted_requests = 0
+        self.queue_wait_s = 0.0
+        self.first_tokens = 0
+        self.first_token_wait_s = 0.0
+        # where the engine thread's time goes (_Phase): cumulative seconds
+        # and intervals per phase, the interval open now, passes of _loop
+        self.loop_s = dict.fromkeys(ENGINE_PHASES, 0.0)
+        self.loop_n = dict.fromkeys(ENGINE_PHASES, 0)
+        self.loop_iterations = 0
+        self._phase_open: Optional[tuple] = None
+        # monotonic -> wall, taken once: the task-event spans want wall time
+        self._wall = time.time() - time.monotonic()
         self._obs_dep = "-"  # deployment tag, learned from first request
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
@@ -342,6 +384,32 @@ class LLMEngine:
             }
         return out
 
+    def counters(self) -> dict:
+        """Cumulative counts a reader differences between two calls (always
+        on, written by the engine thread alone): admitted tokens, request
+        stages, and the engine thread's seconds and intervals per phase.
+        The interval open at the call counts up to ``t_mono``, so the five
+        ``loop_*_s`` add up to the thread's wall time but for the moments
+        between phases."""
+        now = time.monotonic()
+        loop_s, open_ = dict(self.loop_s), self._phase_open
+        if open_ is not None:
+            loop_s[open_[0]] += max(0.0, now - open_[1])
+        out = {
+            "t_mono": now,
+            "loop_iterations": self.loop_iterations,
+            "admitted_requests": self.admitted_requests,
+            "queue_wait_s": self.queue_wait_s,
+            "first_tokens": self.first_tokens,
+            "first_token_wait_s": self.first_token_wait_s,
+            "admit_tokens_real": self.admit_tokens_real,
+            "admit_tokens_padded": self.admit_tokens_padded,
+        }
+        for ph in ENGINE_PHASES:
+            out[f"loop_{ph}_s"] = loop_s[ph]
+            out[f"loop_{ph}_n"] = self.loop_n[ph]
+        return out
+
     def prefix_digest(self, cap: int = 32) -> Optional[dict]:
         """Bounded digest of this engine's hot first-page prefix chunks
         for cache-aware routing: ``{"page": page_size, "blocks": [8-hex
@@ -405,42 +473,50 @@ class LLMEngine:
 
     # ----------------------------------------------------- observability
 
-    def _obs_admit(self, reqs: List[GenRequest]):
-        """One successful admit batch: padding accounting, occupancy +
-        queue-wait metrics, batch_wait span per request (chained under
-        the request's trace), KV/slot gauges.  Engine-thread side; every
-        metric call is a precomputed-key observe behind one enabled()
-        check."""
+    def _obs_admit(self, reqs: List[GenRequest], bucket: int,
+                   tokens_real: int):
+        """One admit batch, just dispatched: padding accounting (rows and
+        tokens), queue wait per request; then, behind one enabled() check,
+        occupancy + queue-wait metrics, batch_wait span per request
+        (chained under the request's trace), KV/slot gauges.  Engine-thread
+        side; every metric call is a precomputed-key observe."""
+        now = time.monotonic()
         self.admit_batches += 1
         self.admit_rows_real += len(reqs)
         self.admit_rows_padded += self.prefill_batch - len(reqs)
+        self.admit_tokens_real += tokens_real
+        self.admit_tokens_padded += self.prefill_batch * bucket - tokens_real
+        self.admitted_requests += len(reqs)
+        for r in reqs:
+            r.admitted_at = now
+            self.queue_wait_s += now - r.submitted_at
         if not obs.enabled():
             return
-        now = time.time()
         dep = self._obs_dep
         obs.record_batch(dep, len(reqs), self.prefill_batch,
-                         waits_s=[now - r.submitted_wall for r in reqs])
+                         waits_s=[now - r.submitted_at for r in reqs])
         self._obs_gauges()
         for r in reqs:
-            r.admitted_wall = now
             r.span_parent = obs.stamp_span(
-                "batch_wait", r.submitted_wall, now - r.submitted_wall,
+                "batch_wait", self._wall + r.submitted_at,
+                now - r.submitted_at,
                 trace_id=r.trace_ctx[0] if r.trace_ctx else None,
                 parent_id=r.trace_ctx[1] if r.trace_ctx else None,
                 deployment=r.deployment)
 
-    def _obs_first_token(self, r: GenRequest, now_mono: float):
-        """Prefill finished for one request: engine-level TTFT (the rolling
-        SLO window takes the replica-level sample instead — one per
-        request) + the ``prefill`` span, chained under batch_wait."""
+    def _obs_first_token(self, r: GenRequest, now: float):
+        """One request's first token is being emitted (``now``): the stage
+        counter; then engine-level TTFT (the rolling SLO window takes
+        the replica-level sample instead — one per request) + the
+        ``prefill`` span, chained under batch_wait."""
+        self.first_tokens += 1
+        self.first_token_wait_s += now - r.admitted_at
         if not obs.enabled():
             return
-        r.first_token_wall = time.time()
-        obs.observe_ttft(r.deployment, now_mono - r.submitted_at,
+        obs.observe_ttft(r.deployment, now - r.submitted_at,
                          stage="engine", window=False)
-        t0 = r.admitted_wall or r.submitted_wall
         r.span_parent = obs.stamp_span(
-            "prefill", t0, r.first_token_wall - t0,
+            "prefill", self._wall + r.admitted_at, now - r.admitted_at,
             trace_id=r.trace_ctx[0] if r.trace_ctx else None,
             parent_id=r.span_parent,
             deployment=r.deployment, prompt_len=r.prompt_len)
@@ -451,17 +527,14 @@ class LLMEngine:
         if not obs.enabled():
             return
         obs.add_tokens(r.deployment, "out", r.generated)
-        now = time.time()
-        if r.first_token_wall is not None:
-            obs.stamp_span(
-                "decode", r.first_token_wall, now - r.first_token_wall,
-                trace_id=r.trace_ctx[0] if r.trace_ctx else None,
-                parent_id=r.span_parent,
-                deployment=r.deployment, tokens=r.generated)
-        if r.generated > 1 and r.first_token_at is not None:
-            obs.observe_tpot(r.deployment,
-                             (time.monotonic() - r.first_token_at)
-                             / (r.generated - 1))
+        first, last = r.emit_times[0], r.emit_times[-1]
+        obs.stamp_span(
+            "decode", self._wall + first, last - first,
+            trace_id=r.trace_ctx[0] if r.trace_ctx else None,
+            parent_id=r.span_parent,
+            deployment=r.deployment, tokens=r.generated)
+        if r.generated > 1:
+            obs.observe_tpot(r.deployment, (last - first) / (r.generated - 1))
         self._obs_gauges()
 
     def _obs_gauges(self):
@@ -505,7 +578,7 @@ class LLMEngine:
                         p, c, st, t, ln, sl, tmp, bud, eos, real_mask, cfg,
                         tk, dt)
 
-            fn = self._jax.jit(admit_fn, donate_argnums=(1, 2))
+            fn = named_jit(PROGRAM_PREFILL, admit_fn, donate_argnums=(1, 2))
             self._prefill_fns[bucket] = fn
         return fn
 
@@ -523,7 +596,7 @@ class LLMEngine:
             def f(p, c, t, ln, sl):
                 return dec.prefill(p, c, t, ln, sl, dcfg, dt)[0]
 
-            fn = self._jax.jit(f, donate_argnums=(1,))
+            fn = named_jit(PROGRAM_DRAFT_PREFILL, f, donate_argnums=(1,))
             self._draft_prefill_fns[bucket] = fn
         return fn
 
@@ -580,31 +653,39 @@ class LLMEngine:
                 return spec.spec_decode_state_loop(
                     tp, tc, dp, dc, st, k, rounds, cfg, dcfg, paged, tk, dt)
 
-            ent = (self._jax.jit(run, donate_argnums=(1, 3, 4)), rounds)
+            ent = (named_jit(PROGRAM_SPEC_DECODE, run,
+                             donate_argnums=(1, 3, 4)), rounds)
             self._spec_fns[k] = ent
         return ent
 
     def _loop(self):
+        # Every stretch of this thread's time belongs to one of five phases
+        # (_Phase: admit, dispatch, fetch, emit, idle), one span and one
+        # counted interval per stretch, never one per token.
         while not self._stop:
+            self.loop_iterations += 1
             did_work = False
             # admit: batch pending prompts of the same bucket into one prefill
-            admits: List[GenRequest] = []
-            bucket = None
-            while (len(admits) < len(self._free_slots)
-                   and len(admits) < self.prefill_batch
-                   and not self._pending.empty()):
-                nxt = self._pending.queue[0]
-                b = self._bucket_for(len(nxt.tokens))
-                if bucket is None:
-                    bucket = b
-                if b != bucket:
-                    break
-                admits.append(self._pending.get())
-            if admits:
-                self._admit(admits, bucket)
+            if self._free_slots and not self._pending.empty():
+                with _Phase(self, "admit") as span:
+                    admits: List[GenRequest] = []
+                    bucket = None
+                    while (len(admits) < len(self._free_slots)
+                           and len(admits) < self.prefill_batch
+                           and not self._pending.empty()):
+                        nxt = self._pending.queue[0]
+                        b = self._bucket_for(len(nxt.tokens))
+                        if bucket is None:
+                            bucket = b
+                        if b != bucket:
+                            break
+                        admits.append(self._pending.get())
+                    span.set_metadata(bucket=bucket, rows=len(admits))
+                    self._admit(admits, bucket)
                 did_work = True
             if self._active:
-                self._dispatch_step()
+                with _Phase(self, "dispatch"):
+                    self._dispatch_step()
                 did_work = True
             # fetch completed steps once the pipeline is `fetch_lag` deep
             # (device computes step N+1 while the host reads back step N)
@@ -613,8 +694,9 @@ class LLMEngine:
                 self._drain_one()
                 did_work = True
             if not did_work:
-                self._wake.wait(timeout=0.02)
-                self._wake.clear()
+                with _Phase(self, "idle"):
+                    self._wake.wait(timeout=0.02)
+                    self._wake.clear()
 
     def _admit_arrays(self, reqs: List[GenRequest], bucket: int,
                       slots: List[int], starts: Optional[List[int]] = None):
@@ -669,7 +751,7 @@ class LLMEngine:
             self._draft_prefill(reqs, slots)
         self._unfetched.append((first, snapshot, slots))
         self.steps += 1
-        self._obs_admit(reqs)
+        self._obs_admit(reqs, bucket, int(lengths[:len(reqs)].sum()))
 
     def _plan_pages(self, r: GenRequest):
         """Reserve pages for one request: reuse cached prefix pages, allocate
@@ -751,7 +833,8 @@ class LLMEngine:
             self._draft_prefill(preqs, slots)
         self._unfetched.append((first, snapshot, slots))
         self.steps += 1
-        self._obs_admit(preqs)
+        # tokens prefilled: each prompt's uncached suffix (after `start`)
+        self._obs_admit(preqs, sbucket, int(lengths[:len(preqs)].sum()))
 
     def _dispatch_step(self):
         if self._spec is not None:
@@ -774,16 +857,24 @@ class LLMEngine:
         self.steps += self.steps_per_dispatch
 
     def _drain_spec(self, payload, snapshot):
-        """Fetch one speculative dispatch: emit each slot's accepted
-        window and fold the per-round emit counts into the acceptance
-        tallies (a round's emit_count e in 1..k means e-1 drafts accepted
-        + one verified correction; the k-1-e rejected drafts are the
-        rollback)."""
+        """Fetch one speculative dispatch, then emit it."""
         import numpy as np
         tokens_dev, counts_dev, round_counts_dev, k = payload
-        tokens = np.asarray(tokens_dev)   # blocks until the dispatch ran
-        counts = np.asarray(counts_dev)
-        rounds = np.asarray(round_counts_dev)  # [num_rounds, slots]
+        with _Phase(self, "fetch"):
+            tokens = np.asarray(tokens_dev)   # blocks until the dispatch ran
+            counts = np.asarray(counts_dev)
+            rounds = np.asarray(round_counts_dev)  # [num_rounds, slots]
+        with _Phase(self, "emit") as span:
+            before = self.tokens_out
+            self._emit_spec(tokens, counts, rounds, k, snapshot)
+            span.set_metadata(tokens=self.tokens_out - before)
+
+    def _emit_spec(self, tokens, counts, rounds, k: int, snapshot):
+        """Emit each slot's accepted window and fold the per-round emit
+        counts into the acceptance tallies (a round's emit_count e in 1..k
+        means e-1 drafts accepted + one verified correction; the k-1-e
+        rejected drafts are the rollback)."""
+        import numpy as np
         d_tok = d_round = d_draft = d_acc = 0
         for row in rounds:
             act = int((row > 0).sum())
@@ -800,15 +891,12 @@ class LLMEngine:
         if d_round:
             obs.record_spec_dispatch(self._obs_dep, d_round, d_tok,
                                      d_draft, d_acc)
-        now = time.monotonic()
         for s, r in snapshot.items():
             if r.slot != s or self._active.get(s) is not r:
                 continue
             for j in range(int(counts[s])):
                 if self._active.get(s) is not r:
                     break
-                if r.first_token_at is None:
-                    r.first_token_at = now
                 self._emit(r, int(tokens[s, j]))
 
     def _drain_one(self):
@@ -817,26 +905,32 @@ class LLMEngine:
         if prefill_slots == "spec":
             self._drain_spec(tokens_dev, snapshot)
             return
-        tokens = np.asarray(tokens_dev)   # blocks until the step finished
-        now = time.monotonic()
-        if prefill_slots is not None:
-            # prefill entry: tokens is [len(slots)] in admit order
-            for i, s in enumerate(prefill_slots):
-                r = snapshot[s]
-                r.first_token_at = now
-                self._obs_first_token(r, now)
-                self._emit(r, int(tokens[i]))
-        else:
-            # decode entry: [steps_per_dispatch, slots]
-            for k in range(tokens.shape[0]):
-                for s, r in snapshot.items():
-                    if r.slot == s and self._active.get(s) is r:
-                        self._emit(r, int(tokens[k, s]))
+        with _Phase(self, "fetch"):
+            tokens = np.asarray(tokens_dev)   # blocks until the step finished
+        with _Phase(self, "emit") as span:
+            before = self.tokens_out
+            if prefill_slots is not None:
+                # prefill entry: tokens is [len(slots)] in admit order
+                for i, s in enumerate(prefill_slots):
+                    self._emit(snapshot[s], int(tokens[i]))
+            else:
+                # decode entry: [steps_per_dispatch, slots]
+                for k in range(tokens.shape[0]):
+                    for s, r in snapshot.items():
+                        if r.slot == s and self._active.get(s) is r:
+                            self._emit(r, int(tokens[k, s]))
+            span.set_metadata(tokens=self.tokens_out - before)
 
     def _emit(self, r: GenRequest, token: int):
         r.tokens.append(token)
         r.generated += 1
         self.tokens_out += 1
+        now = time.monotonic()
+        if not r.emit_times:
+            self._obs_first_token(r, now)
+        # stamped before the put: the consumer that got token n finds
+        # emit_times[n] (LLMServer.__call__ reads its deliver lag from it)
+        r.emit_times.append(now)
         r.out.put(token)
         done = (r.generated >= r.max_tokens
                 or (r.eos_id is not None and token == r.eos_id)
@@ -885,6 +979,14 @@ class LLMServer:
         self.engine = LLMEngine(cfg, num_slots=num_slots, max_len=max_len,
                                 seed=seed, **(engine_kwargs or {}))
 
+    #: tokens yielded to callers and, summed over them, the seconds from a
+    #: token's _emit on the engine thread to its yield on the replica's
+    #: loop (the queue, the executor thread's wake-up, the loop's turn);
+    #: written by the replica's loop alone.  Class attributes, so that a
+    #: subclass with its own constructor counts from zero too.
+    delivered_tokens = 0
+    deliver_lag_s = 0.0
+
     async def __call__(self, request):
         """Async generator: polls the engine's token queue off-loop so one
         stream never blocks the replica's event loop (other streams, health
@@ -898,15 +1000,24 @@ class LLMServer:
             temperature=float(body.get("temperature", 0.0)),
             eos_id=body.get("eos_id"))
         loop = asyncio.get_event_loop()
+        delivered = 0
         while True:
             item = await loop.run_in_executor(None, req.out.get)
             if not isinstance(item, int):
                 if isinstance(item, BaseException):
                     raise item
                 return  # _FLUSH
+            # each token against its own emit stamp: one "last emit" stamp
+            # would under-read exactly when delivery falls a dispatch behind
+            self.deliver_lag_s += (time.monotonic()
+                                   - req.emit_times[delivered])
+            self.delivered_tokens += 1
+            delivered += 1
             yield item
 
     def stats(self) -> dict:
+        """Cumulative counters and the serving picture.  Two calls give a
+        reader rates over the time between them (``t_mono``)."""
         # where this replica runs, as its own JAX reports it: the process
         # that owns the chip is the only one that can say
         devices = self.engine._jax.devices()
@@ -918,6 +1029,9 @@ class LLMServer:
                 "device_kind": devices[0].device_kind,
                 "device_count": len(devices),
                 "prefill_buckets": sorted(self.engine._prefill_fns),
+                "delivered_tokens": self.delivered_tokens,
+                "deliver_lag_s": self.deliver_lag_s,
+                **self.engine.counters(),
                 **self.engine.breakdown()}
 
     def prefix_digest(self) -> Optional[dict]:

@@ -20,12 +20,67 @@ the whole capture window).
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
 import threading
 import time
 from typing import Dict, List, Tuple
+
+# ------------------------------------------------- names a capture shows
+#
+# What a jax.profiler capture (``raytpu profile --node``, or the
+# benchmark's ``--trace 1``) calls the program's own pieces.  The
+# benchmark's readers match the program names by substring in the device
+# planes' ``XLA Modules`` line (``benchmark/lib/readers.py`` and
+# ``benchmark/layer_metrics/*.py``): renaming one here empties their
+# metrics, so tests/test_trace_names.py pins them.
+
+#: prefill + sample + merge of one admit batch, one program per bucket
+#: (``jit_admit_fn``)
+PROGRAM_PREFILL = "admit_fn"
+#: ``steps_per_dispatch`` decode steps, dense or paged
+PROGRAM_DECODE = "engine_decode"
+#: one speculative dispatch (draft, verify, accept)
+PROGRAM_SPEC_DECODE = "engine_spec_decode"
+#: the draft model's prefill of an admit batch
+PROGRAM_DRAFT_PREFILL = "engine_draft_prefill"
+#: forward + backward + optimizer update (train_step.py and zero.py)
+PROGRAM_TRAIN_STEP = "train_step"
+#: every host span of the program starts with this
+SPAN_PREFIX = "raytpu:"
+#: the phases that partition the LLM engine thread's time, each a host
+#: span ``raytpu:engine.<phase>`` and a pair of ``loop_<phase>_s`` /
+#: ``loop_<phase>_n`` counters in ``LLMServer.stats()``
+ENGINE_PHASES = ("admit", "dispatch", "fetch", "emit", "idle")
+
+_TraceAnnotation = None
+
+
+def host_span(name: str, **args):
+    """A host span ``raytpu:<name>`` in whatever jax.profiler session is
+    capturing this process: it lands in the same ``.xplane.pb``, on the
+    same clock, as the device's operations.  Outside a session the
+    ``with`` is a flag check.  ``args`` become the event's stats; more can
+    follow through the returned object's ``set_metadata(**args)``."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(SPAN_PREFIX + name, **args)
+
+
+def named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` as the program ``jit_<name>``, whatever ``fn`` is
+    called (a lambda would be ``jit__lambda_``)."""
+    import jax
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kwargs)
 
 
 def _jax_tpu_ready() -> bool:

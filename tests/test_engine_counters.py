@@ -1,0 +1,246 @@
+"""The serve path measured from inside: the counters ``LLMEngine`` /
+``LLMServer`` keep where the work happens (admitted tokens, request stages,
+engine-loop phases, deliver lag), which the benchmark's per-layer readers
+difference over a window.  Tiny preset on the CPU: counts, never speeds."""
+
+import asyncio
+import time
+
+import pytest
+
+from ray_tpu.util.profiler import ENGINE_PHASES
+
+
+@pytest.fixture(scope="module")
+def tiny_cfg():
+    from ray_tpu.models import config as mcfg
+    return mcfg.tiny()
+
+
+def _engine(cfg, **kw):
+    from ray_tpu.serve.llm import LLMEngine
+    kw.setdefault("num_slots", 4)
+    kw.setdefault("max_len", 128)
+    kw.setdefault("buckets", (16, 32, 64))
+    return LLMEngine(cfg, **kw)
+
+
+def _spy_admits(eng):
+    """Every admit batch as ``(rows, bucket, real tokens)``."""
+    seen, orig = [], eng._obs_admit
+
+    def spy(reqs, bucket, tokens_real):
+        seen.append((len(reqs), bucket, tokens_real))
+        orig(reqs, bucket, tokens_real)
+
+    eng._obs_admit = spy
+    return seen
+
+
+def _run(eng, prompts, max_tokens=3):
+    reqs = [eng.submit(p, max_tokens=max_tokens) for p in prompts]
+    from ray_tpu.serve.llm import _FLUSH
+    for r in reqs:
+        while r.out.get(timeout=120) is not _FLUSH:
+            pass
+    return reqs
+
+
+PROMPT_LENS = (5, 20, 40, 7, 33, 12)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_admitted_tokens_are_counted_real_and_padded(tiny_cfg, paged):
+    kw = dict(paged=True, page_size=8, num_pages=96) if paged else {}
+    eng = _engine(tiny_cfg, **kw)
+    try:
+        seen = _spy_admits(eng)
+        prompts = [[1 + (i + j) % 50 for j in range(n)]
+                   for i, n in enumerate(PROMPT_LENS)]
+        _run(eng, prompts)
+        c = eng.counters()
+        assert c["admit_tokens_real"] == sum(PROMPT_LENS)
+        assert c["admit_tokens_real"] == sum(real for _, _, real in seen)
+        # every position of every [prefill_batch, bucket] array is counted
+        # once, as a prompt token or as padding
+        assert c["admit_tokens_real"] + c["admit_tokens_padded"] == sum(
+            eng.prefill_batch * bucket for _, bucket, _ in seen)
+        assert c["admit_tokens_padded"] > 0
+        assert c["admitted_requests"] == c["first_tokens"] == len(prompts)
+        assert sum(rows for rows, _, _ in seen) == len(prompts)
+        # the row accounting the committed readers use is untouched
+        assert eng.breakdown()["admit_batches"] == len(seen)
+    finally:
+        eng.shutdown()
+
+
+def test_prefix_hit_counts_only_the_prefilled_suffix(tiny_cfg):
+    eng = _engine(tiny_cfg, paged=True, page_size=8, num_pages=96)
+    try:
+        seen = _spy_admits(eng)
+        shared = [3 + i % 40 for i in range(32)]          # four full pages
+        first, second = shared + [7, 8, 9], shared + [11, 12, 13, 14, 15]
+        _run(eng, [first])
+        before = eng.counters()
+        _run(eng, [second])
+        after = eng.counters()
+        reused = eng.prefix.stats()["tokens_reused"]
+        assert reused == 32
+        assert (after["admit_tokens_real"] - before["admit_tokens_real"]
+                == len(second) - reused)
+        rows, bucket, real = seen[-1]
+        assert (rows, real) == (1, len(second) - reused)
+        assert bucket == eng._bucket_for(len(second) - reused)
+        assert (after["admit_tokens_real"] + after["admit_tokens_padded"]
+                == sum(eng.prefill_batch * b for _, b, _ in seen))
+    finally:
+        eng.shutdown()
+
+
+def test_request_stages_sum_waits_per_request(tiny_cfg):
+    eng = _engine(tiny_cfg)
+    try:
+        t0 = time.monotonic()
+        reqs = _run(eng, [[1, 2, 3, 4]] * 5, max_tokens=4)
+        wall = time.monotonic() - t0
+        c = eng.counters()
+        assert c["admitted_requests"] == c["first_tokens"] == 5
+        # submit -> dispatch of the admit, that dispatch -> first token:
+        # the two stages of every request, in order, inside the run
+        for r in reqs:
+            assert r.submitted_at <= r.admitted_at <= r.emit_times[0]
+            assert len(r.emit_times) == r.generated == 4
+            assert r.emit_times == sorted(r.emit_times)
+        assert c["queue_wait_s"] == pytest.approx(
+            sum(r.admitted_at - r.submitted_at for r in reqs))
+        assert c["first_token_wait_s"] == pytest.approx(
+            sum(r.emit_times[0] - r.admitted_at for r in reqs))
+        assert 0 < c["first_token_wait_s"] <= 5 * wall
+        assert 0 <= c["queue_wait_s"] <= 5 * wall
+    finally:
+        eng.shutdown()
+
+
+def test_gen_request_keeps_one_clock_per_stage():
+    from ray_tpu.serve.llm import GenRequest
+    clocks = [s for s in GenRequest.__slots__
+              if s.endswith(("_at", "_wall", "_times"))]
+    assert sorted(clocks) == ["admitted_at", "emit_times", "submitted_at"]
+
+
+def test_phases_partition_the_engine_threads_time(tiny_cfg):
+    eng = _engine(tiny_cfg)
+    try:
+        eng.warmup(16)                       # compile outside the interval
+        c0 = eng.counters()
+        _run(eng, [[1, 2, 3]] * 6, max_tokens=12)
+        time.sleep(0.15)                     # some idle passes too
+        c1 = eng.counters()
+        wall = c1["t_mono"] - c0["t_mono"]
+        deltas = {ph: c1[f"loop_{ph}_s"] - c0[f"loop_{ph}_s"]
+                  for ph in ENGINE_PHASES}
+        counts = {ph: c1[f"loop_{ph}_n"] - c0[f"loop_{ph}_n"]
+                  for ph in ENGINE_PHASES}
+        assert all(d >= 0 for d in deltas.values()), deltas
+        assert 0.9 * wall <= sum(deltas.values()) <= wall * 1.0001, (
+            deltas, wall)
+        # one interval per stretch, never one per token: 72 tokens came
+        # out of far fewer emit intervals, each drain one fetch + one emit
+        assert counts["fetch"] == counts["emit"] > 0
+        assert counts["emit"] < 72
+        assert counts["admit"] >= 1 and counts["dispatch"] >= 1
+        assert counts["idle"] >= 1 and deltas["idle"] > 0
+        assert c1["loop_iterations"] > c0["loop_iterations"]
+    finally:
+        eng.shutdown()
+
+
+def test_open_phase_counts_up_to_the_snapshot(tiny_cfg):
+    """A snapshot taken while the thread sits in one long interval (here:
+    idle) includes the part of it already spent."""
+    eng = _engine(tiny_cfg)
+    try:
+        time.sleep(0.05)
+        c0 = eng.counters()
+        time.sleep(0.3)
+        c1 = eng.counters()
+        wall = c1["t_mono"] - c0["t_mono"]
+        assert c1["loop_idle_s"] - c0["loop_idle_s"] >= 0.9 * wall
+    finally:
+        eng.shutdown()
+
+
+async def _consume(server, body):
+    return [tok async for tok in server(body)]
+
+
+def test_server_counts_delivered_tokens_and_their_lag():
+    from ray_tpu.serve.llm import LLMServer
+    server = LLMServer("tiny", num_slots=4, max_len=64,
+                       engine_kwargs={"buckets": (16, 32)})
+    try:
+        async def three():
+            return await asyncio.gather(*(
+                _consume(server, {"tokens": [1, 2, 3 + i], "max_tokens": 6})
+                for i in range(3)))
+        outs = asyncio.run(three())
+        assert [len(o) for o in outs] == [6, 6, 6]
+        st = server.stats()
+        assert st["delivered_tokens"] == st["tokens_out"] == 18
+        assert 0 <= st["deliver_lag_s"] < 18 * 5.0
+        # what the committed readers difference keeps its names
+        for key in ("steps", "tokens_out", "admit_batches",
+                    "padding_fraction", "batch_occupancy", "num_slots",
+                    "active", "free_slots", "prefill_buckets"):
+            assert key in st
+        # and the new keys ride along
+        for key in ["t_mono", "loop_iterations", "admitted_requests",
+                    "queue_wait_s", "first_tokens", "first_token_wait_s",
+                    "admit_tokens_real", "admit_tokens_padded"] + [
+                        f"loop_{ph}_{k}" for ph in ENGINE_PHASES
+                        for k in ("s", "n")]:
+            assert key in st, key
+        assert st["admitted_requests"] == st["first_tokens"] == 3
+        assert abs(st["t_mono"] - time.monotonic()) < 5
+    finally:
+        server.engine.shutdown()
+
+
+@pytest.mark.parametrize("enabled", [False, True],
+                         ids=["metrics-off", "metrics-on"])
+def test_counters_do_not_depend_on_the_metrics_switch(tiny_cfg, enabled,
+                                                      monkeypatch):
+    """``serve_metrics_enabled=False`` sheds the operator's spans and
+    series; the counters are plain numbers and still count.  With the
+    switch on, the three stage spans keep wall-clock starts (derived from
+    the one monotonic stamp per stage and the engine's offset)."""
+    from ray_tpu.core.config import Config, reset_config, set_config
+    from ray_tpu.util import tracing
+
+    spans = []
+    monkeypatch.setattr(
+        tracing, "record_span",
+        lambda name, t0, dur, **kw: spans.append((name, t0, dur)) or "sid")
+    try:
+        set_config(Config(serve_metrics_enabled=enabled))
+        eng = _engine(tiny_cfg)
+        try:
+            t_wall = time.time()
+            _run(eng, [[1, 2, 3, 4, 5]] * 2, max_tokens=3)
+            c = eng.counters()
+        finally:
+            eng.shutdown()
+        assert c["admitted_requests"] == c["first_tokens"] == 2
+        assert c["admit_tokens_real"] == 10 and eng.tokens_out == 6
+        assert c["loop_admit_n"] >= 1 and c["loop_emit_n"] >= 1
+        if not enabled:
+            assert spans == []
+            return
+        assert sorted(n for n, _, _ in spans) == [
+            "batch_wait", "batch_wait", "decode", "decode", "prefill",
+            "prefill"]
+        for _name, t0, dur in spans:
+            assert dur >= 0
+            assert t_wall - 1 <= t0 <= time.time() + 1
+    finally:
+        reset_config()

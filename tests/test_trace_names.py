@@ -1,0 +1,208 @@
+"""What a profiler capture calls the program's pieces: the jitted programs'
+names (the benchmark's readers match them in the device planes' ``XLA
+Modules`` line), the named scopes on the parts of a block, the Pallas
+kernels' names, and the engine thread's ``raytpu:engine.*`` host spans.
+Compile-time text and a CPU capture: names, never speeds."""
+
+import dataclasses
+import glob
+import re
+import time
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.util import profiler
+
+
+@pytest.fixture(scope="module")
+def tiny_cfg():
+    from ray_tpu.models import config as mcfg
+    return mcfg.tiny()
+
+
+def _engine(cfg, **kw):
+    from ray_tpu.serve.llm import LLMEngine
+    return LLMEngine(cfg, num_slots=4, max_len=64, buckets=(16, 32), **kw)
+
+
+def _module_name(lowered) -> str:
+    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+def _scopes(lowered) -> set:
+    """Every word of every operation's name stack in the lowered text,
+    debug info on (``jit(train_step)/transpose(jvp(attn))/dot_general``
+    gives jit, train_step, transpose, jvp, attn, dot_general)."""
+    out = set()
+    for name in re.findall(r'loc\("([^"]+)"', lowered.as_text(
+            debug_info=True)):
+        out.update(re.findall(r"\w+", name))
+    return out
+
+
+def test_the_pinned_names():
+    """The benchmark's committed readers find prefill by ``admit_fn``; the
+    new ones find decode and the train step by these."""
+    assert profiler.PROGRAM_PREFILL == "admit_fn"
+    assert profiler.PROGRAM_DECODE == "engine_decode"
+    assert profiler.PROGRAM_SPEC_DECODE == "engine_spec_decode"
+    assert profiler.PROGRAM_DRAFT_PREFILL == "engine_draft_prefill"
+    assert profiler.PROGRAM_TRAIN_STEP == "train_step"
+    assert profiler.SPAN_PREFIX == "raytpu:"
+    assert profiler.ENGINE_PHASES == ("admit", "dispatch", "fetch", "emit",
+                                      "idle")
+    # no program's name contains another's: a substring match tells them
+    # apart
+    names = [profiler.PROGRAM_PREFILL, profiler.PROGRAM_DECODE,
+             profiler.PROGRAM_SPEC_DECODE, profiler.PROGRAM_DRAFT_PREFILL,
+             profiler.PROGRAM_TRAIN_STEP]
+    for a in names:
+        assert not any(a in b for b in names if b is not a)
+    readers = pytest.importorskip("benchmark.lib.readers")
+    assert re.search(readers.PREFILL_PROGRAM, "jit_admit_fn(123)")
+    assert not re.search(readers.PREFILL_PROGRAM, "jit_engine_decode(123)")
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_serve_programs_lower_under_their_names(tiny_cfg, paged):
+    kw = dict(paged=True, page_size=8, num_pages=64) if paged else {}
+    eng = _engine(tiny_cfg, **kw)
+    try:
+        decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
+        assert _module_name(decode) == "jit_engine_decode"
+        assert {"attn", "mlp", "norm", "lm_head"} <= _scopes(decode)
+        if not paged:
+            assert {"kv_write", "kv_read"} <= _scopes(decode)
+            admit = eng._prefill_fn(16).lower(
+                eng.params, eng.cache, eng._state,
+                *eng._admit_arrays([], 16, []))
+            assert _module_name(admit) == "jit_admit_fn"
+            assert {"attn", "mlp", "norm", "kv_write",
+                    "lm_head"} <= _scopes(admit)
+        # run one request: the programs the engine really compiled
+        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
+        assert all(f.__name__ == "admit_fn"
+                   for f in eng._prefill_fns.values())
+        assert eng._decode_fn.__name__ == "engine_decode"
+    finally:
+        eng.shutdown()
+
+
+def test_speculative_programs_lower_under_their_names(tiny_cfg):
+    cfg = dataclasses.replace(tiny_cfg, num_layers=2)
+    eng = _engine(cfg, spec_decode_enabled=True, spec_k=2,
+                  spec_draft_layers=1)
+    try:
+        assert len(eng.generate([1, 2, 3, 4], max_tokens=5)) == 5
+        assert {fn.__name__ for fn, _rounds in eng._spec_fns.values()} == {
+            "engine_spec_decode"}
+        assert {fn.__name__ for fn in eng._draft_prefill_fns.values()} == {
+            "engine_draft_prefill"}
+        fn, _rounds = next(iter(eng._spec_fns.values()))
+        low = fn.lower(eng.params, eng.cache, eng._draft_params,
+                       eng._draft_cache, eng._state)
+        assert _module_name(low) == "jit_engine_spec_decode"
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("zero", [False, True], ids=["train_step", "zero"])
+def test_train_step_lowers_under_its_name(tiny_cfg, zero):
+    from ray_tpu.parallel import MeshSpec, make_optimizer, make_train_step
+    from ray_tpu.parallel.train_step import init_sharded_state
+
+    mesh = (MeshSpec(dp=2) if zero else MeshSpec(fsdp=2)).build(
+        jax.devices()[:2])
+    opt = make_optimizer()
+    state, sh = init_sharded_state(tiny_cfg, mesh, opt)
+    step = make_train_step(tiny_cfg, mesh, opt, sh, remat=False,
+                           grad_quant_enabled=zero)
+    tok = jnp.zeros((4, 32), jnp.int32)
+    low = step._jitted.lower(state, {"tokens": tok, "targets": tok})
+    assert _module_name(low) == "jit_train_step"
+    assert {"attn", "mlp", "norm", "lm_head", "loss",
+            "optimizer"} <= _scopes(low)
+
+
+def test_chunked_loss_carries_its_scope(tiny_cfg):
+    from ray_tpu.models import transformer
+
+    x = jnp.ones((2, 16, tiny_cfg.hidden_size), jnp.float32)
+    w = jnp.ones((tiny_cfg.hidden_size, tiny_cfg.vocab_size), jnp.float32)
+    t = jnp.zeros((2, 16), jnp.int32)
+    low = jax.jit(lambda x, w, t: transformer.chunked_cross_entropy(
+        x, w, t, 8).sum()).lower(x, w, t)
+    assert "loss" in _scopes(low)
+
+
+def test_flash_kernels_are_named():
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=True).sum(),
+        argnums=(0, 1, 2)))(q, q, q)
+    assert {"flash_fwd", "flash_dq", "flash_dkv"} <= set(
+        re.findall(r"flash_\w+", str(jaxpr)))
+
+
+def test_named_jit_names_a_lambda():
+    fn = profiler.named_jit("some_program", lambda x, y: x + y,
+                            donate_argnums=(0,))
+    low = fn.lower(jnp.ones(3), jnp.ones(3))
+    assert _module_name(low) == "jit_some_program"
+    assert float(fn(jnp.ones(3), jnp.ones(3))[0]) == 2.0
+
+
+def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
+    """An engine run inside ``jax.profiler.trace``: the five phases are
+    events of a host line of the same ``.xplane.pb`` the device's
+    operations go to, and their seconds are the counters' seconds."""
+    from jax.profiler import ProfileData
+
+    eng = _engine(tiny_cfg)
+    try:
+        eng.warmup(16)
+        with jax.profiler.trace(str(tmp_path)):
+            c0 = eng.counters()
+            outs = [eng.generate([1, 2, 3 + i], max_tokens=9)
+                    for i in range(3)]
+            c1 = eng.counters()
+            time.sleep(0.1)              # idle passes inside the capture
+        assert [len(o) for o in outs] == [9, 9, 9]
+    finally:
+        eng.shutdown()
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert files, "the profiler wrote no .xplane.pb"
+    events = [(plane.name, ev.name, ev.duration_ns, dict(ev.stats))
+              for plane in ProfileData.from_file(files[0]).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("raytpu:")]
+    if not events:
+        pytest.skip("the CPU profiler recorded no host TraceMe events here")
+    assert {plane for plane, *_ in events} == {"/host:CPU"}
+    by_name = {}
+    for _plane, name, dur, stats in events:
+        by_name.setdefault(name, []).append((dur, stats))
+    assert set(by_name) == {f"raytpu:engine.{ph}"
+                            for ph in profiler.ENGINE_PHASES}
+    admits = by_name["raytpu:engine.admit"]
+    assert len(admits) == 3
+    assert all(st.get("bucket") == 16 and st.get("rows") == 1
+               for _d, st in admits)
+    assert sum(st["tokens"] for _d, st in by_name["raytpu:engine.emit"]) \
+        == 27
+    # the capture brackets the two snapshots, so it holds at least the
+    # intervals the counters counted between them, and about their seconds
+    for ph in ("admit", "dispatch", "fetch", "emit"):
+        n = c1[f"loop_{ph}_n"] - c0[f"loop_{ph}_n"]
+        assert n <= len(by_name[f"raytpu:engine.{ph}"]) <= n + 2, ph
+    counted = sum(c1[f"loop_{ph}_s"] - c0[f"loop_{ph}_s"]
+                  for ph in ("admit", "dispatch", "fetch", "emit"))
+    spanned = sum(d for ph in ("admit", "dispatch", "fetch", "emit")
+                  for d, _st in by_name[f"raytpu:engine.{ph}"]) / 1e9
+    assert spanned == pytest.approx(counted, rel=0.2, abs=0.02)
