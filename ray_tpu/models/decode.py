@@ -11,8 +11,13 @@ TPU-first design:
   admits/retires sequences by slot index — tensor shapes never change, so jit
   compiles exactly two programs (one prefill per length bucket, one decode
   step) and reuses them forever.
-* **Scan over layers** with the cache as scan-carried state: compile time is
-  depth-independent, matching ``apply_trunk``.
+* **Scan over layers**: compile time is depth-independent, matching
+  ``apply_trunk``.  The stacked cache never goes through the scan as xs/ys
+  (that slices every layer out and restacks all of it, three passes over the
+  whole cache a step).  Decode carries it and scatters one row per slot at
+  ``[layer, slot, length]``; prefill returns each layer's new K/V as ys and
+  writes them after the scan.  Either way the donated buffer is updated in
+  place and the only bytes written are the new rows.
 * **Prefill** runs the normal causal forward over a right-padded [B, bucket]
   block and writes K/V for every position; padding beyond a sequence's length
   is never *read* because decode masks by per-slot length (causality makes
@@ -142,8 +147,7 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
 
     from ..ops.attention import mha
 
-    def body(x, layer):
-        lp, k_lay, v_lay = layer        # k/v_lay: [slots, max_len, NKV, D]
+    def body(x, lp):
         y = _norm(x, lp["attn_norm"], cfg)
         q, k, v = _qkv(y, lp["attn"], cfg, positions)
         with jax.named_scope("attn"):
@@ -151,15 +155,14 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
                        logit_softcap=cfg.attn_logit_softcap)
         x = x + _proj_out(attn.reshape(b, s, -1), lp["attn"], cast)
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
-        # write this layer's K/V into the slots (padded tail included;
-        # decode's length mask keeps it unread)
-        with jax.named_scope("kv_write"):
-            k_lay = k_lay.at[slot_ids, :s].set(k.astype(k_lay.dtype))
-            v_lay = v_lay.at[slot_ids, :s].set(v.astype(v_lay.dtype))
-        return x, (k_lay, v_lay)
+        return x, (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype))
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
+    x, (k_rows, v_rows) = jax.lax.scan(body, x, params["blocks"])
+    # write every layer's K/V into the slots, in place on the donated cache
+    # (padded tail included; decode's length mask keeps it unread)
+    with jax.named_scope("kv_write"):
+        k_new = cache["k"].at[:, slot_ids, :s].set(k_rows)
+        v_new = cache["v"].at[:, slot_ids, :s].set(v_rows)
     x = _norm(x, params["final_norm"], cfg)
     # logits of each prompt's *last real token* (next-token distribution)
     last = jnp.take_along_axis(
@@ -199,19 +202,23 @@ def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
     reps = cfg.num_heads // cfg.num_kv_heads
     # mask over cache positions: <= current length (the new token's position)
     pos_mask = (jnp.arange(max_len)[None] <= lengths[:, None])  # [slots, max_len]
+    slot_idx = jnp.arange(n_slots)
 
-    def body(x, layer):
-        lp, k_lay, v_lay = layer
+    def body(carry, layer):
+        x, k_all, v_all = carry         # k/v_all: [L, slots, max_len, NKV, D]
+        lp, i = layer
         y = _norm(x, lp["attn_norm"], cfg)
         q, k, v = _qkv(y, lp["attn"], cfg, positions)  # q:[S,1,NH,D] k/v:[S,1,NKV,D]
-        # append at position `length` (one row per slot)
+        # append at position `length` (one row per slot of this layer)
         with jax.named_scope("kv_write"):
-            k_lay = k_lay.at[jnp.arange(n_slots), lengths].set(
-                k[:, 0].astype(k_lay.dtype))
-            v_lay = v_lay.at[jnp.arange(n_slots), lengths].set(
-                v[:, 0].astype(v_lay.dtype))
+            k_all = k_all.at[i, slot_idx, lengths].set(
+                k[:, 0].astype(k_all.dtype))
+            v_all = v_all.at[i, slot_idx, lengths].set(
+                v[:, 0].astype(v_all.dtype))
         # attention over the cache row
         with jax.named_scope("kv_read"):
+            k_lay = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+            v_lay = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
             qh = q[:, 0].reshape(n_slots, cfg.num_kv_heads, reps,
                                  cfg.head_dim)
             scores = jnp.einsum("sgrd,smgd->sgrm", qh.astype(jnp.float32),
@@ -226,10 +233,11 @@ def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
             attn = attn.reshape(n_slots, 1, cfg.num_heads * cfg.head_dim)
         x = x + _proj_out(attn.astype(cast), lp["attn"], cast)
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
-        return x, (k_lay, v_lay)
+        return (x, k_all, v_all), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
+    (x, k_new, v_new), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cache["k"].shape[0])))
     x = _norm(x, params["final_norm"], cfg)
     logits = lm_head_logits(params, x[:, 0], cfg)
     cache = {

@@ -17,6 +17,7 @@ it (``as_tpu``); the program has no option for that.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,18 +111,28 @@ def test_splash_attention_compiles_fwd_bwd(one_chip):
 SLOTS, MAX_LEN, STEPS = 17, 1024, 8      # 16 slots + the scratch slot
 
 
-def _serve_shapes(one_chip, cfg, paged):
+def _serve_shapes(one_chip, cfg, paged, slots=SLOTS, max_len=MAX_LEN):
     params = jax.eval_shape(lambda: transformer.init_params(
         jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
     if paged:
         cache = jax.eval_shape(lambda: paged_decode.init_paged_cache(
-            cfg, SLOTS * (MAX_LEN // 64) // 2, 64, SLOTS, MAX_LEN // 64))
+            cfg, slots * (max_len // 64) // 2, 64, slots, max_len // 64))
     else:
         cache = jax.eval_shape(lambda: decode.init_kv_cache(
-            cfg, SLOTS, MAX_LEN))
+            cfg, slots, max_len))
     state = jax.eval_shape(lambda: decode.init_decode_state(
-        SLOTS, jax.random.PRNGKey(1)))
+        slots, jax.random.PRNGKey(1)))
     return _on(one_chip, params), _on(one_chip, cache), _on(one_chip, state)
+
+
+def _admit_rows(one_chip, bucket, b=8):
+    """The engine's admit batch after (params, cache, state): tokens,
+    lengths, slot ids, temperatures, budgets, eos ids, real-row mask."""
+    row = lambda dt, *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        (b,) + shape, dt, sharding=one_chip)
+    return (row(jnp.int32, bucket), row(jnp.int32), row(jnp.int32),
+            row(jnp.float32), row(jnp.int32), row(jnp.int32),
+            row(jnp.bool_))
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
@@ -141,17 +152,11 @@ def test_dense_prefill_1024_takes_the_flash_kernel(one_chip, as_tpu):
     """The engine's admit program at the 1024 bucket, batch 8: dense prefill
     reaches the flash kernel through the ``mha`` dispatcher."""
     params, cache, state = _serve_shapes(one_chip, LLAMA_400M, paged=False)
-    b = 8
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,  # noqa: E731
-                                              sharding=one_chip)
-    args = (i32(b, 1024), i32(b), i32(b),
-            jax.ShapeDtypeStruct((b,), jnp.float32, sharding=one_chip),
-            i32(b), i32(b),
-            jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=one_chip))
     _, text = _compile(
         lambda p, c, st, *a: decode.prefill_admit(
             p, c, st, *a, LLAMA_400M, 0, jnp.bfloat16),
-        params, cache, state, *args, donate_argnums=(1, 2))
+        params, cache, state, *_admit_rows(one_chip, 1024),
+        donate_argnums=(1, 2))
     assert KERNEL in text, "prefill at seq 1024 compiled plain attention"
 
 
@@ -167,6 +172,86 @@ def test_speculative_verify_window_compiles(one_chip, paged):
         jax.ShapeDtypeStruct((SLOTS, 5), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one_chip),
         donate_argnums=(1,))
+
+
+# ------------------- the serve programs at the benchmark cell's size
+#
+# Mistral-7B-v0.3 widths, 14 layers, 32 slots + the scratch slot x 2048: what
+# ``serve-chat-steady`` and ``serve-decode-saturated`` run.  The stacked cache
+# is 1.94 GB each for K and V; a program that passes it through a scan as
+# xs/ys slices, restacks and copies it every step and keeps a second copy
+# among its temporaries (5.1 GB; 16 layers were refused at 16.26 GiB).
+
+CELL_SLOTS, CELL_MAX_LEN = 33, 2048
+HBM_GIB = 15.75          # what the compiler allows a program on a v5e
+TEMP_GB = {"decode": 1.5, "prefill-256": 0.6, "prefill-2048": 3.0}
+_cell_compiled = {}
+
+
+def _cell_cfg(layers):
+    return mcfg.TransformerConfig(
+        vocab_size=32768, num_layers=layers, hidden_size=4096, num_heads=32,
+        num_kv_heads=8, mlp_size=14336, max_seq_len=32768, rope_theta=1e6,
+        norm_eps=1e-5, tied_embeddings=False, use_rope=True, use_rmsnorm=True,
+        use_swiglu=True, use_qkv_bias=False)
+
+
+def _cell_program(one_chip, program, layers):
+    """One of the engine's programs at the cell's size, cache and state
+    donated as the engine donates them; compiled once per module."""
+    if (program, layers) not in _cell_compiled:
+        cfg = _cell_cfg(layers)
+        args = _serve_shapes(one_chip, cfg, False, CELL_SLOTS, CELL_MAX_LEN)
+        if program == "decode":
+            fn = lambda p, c, st: decode.decode_state_loop(  # noqa: E731
+                p, c, st, STEPS, cfg, 0, jnp.bfloat16)
+        else:
+            args += _admit_rows(one_chip, int(program.split("-")[1]))
+            fn = lambda p, c, st, *a: decode.prefill_admit(  # noqa: E731
+                p, c, st, *a, cfg, 0, jnp.bfloat16)
+        _cell_compiled[program, layers] = _compile(
+            fn, *args, donate_argnums=(1, 2))
+    return _cell_compiled[program, layers]
+
+
+cell_programs = pytest.mark.parametrize("program", list(TEMP_GB))
+
+
+@cell_programs
+def test_cell_program_temporaries(one_chip, as_tpu, program):
+    """No second copy of the cache among the temporaries."""
+    compiled, _ = _cell_program(one_chip, program, 14)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_GB[program] * 1e9, f"{temp / 1e9:.2f} GB"
+
+
+@cell_programs
+def test_cell_program_updates_the_cache_in_place(one_chip, as_tpu, program):
+    """Nothing copies the stacked cache and nothing restacks a layer's slab
+    into it, in a loop body or outside one: the only writes to the stack are
+    scatters and row-sized ``dynamic-update-slice``s, which alias it."""
+    _, text = _cell_program(one_chip, program, 14)
+    stack = f"bf16[14,{CELL_SLOTS},{CELL_MAX_LEN},8,128]"
+    slab = CELL_SLOTS * CELL_MAX_LEN * 8 * 128
+    dims_of = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    assert stack in text
+    writes = re.findall(
+        r"%([\w.\-]+) = " + re.escape(stack)
+        + r"\S* (copy|dynamic-update-slice)\(%[\w.\-]+(?:, %([\w.\-]+))?",
+        text)
+    for name, op, update in writes:
+        assert op != "copy", f"%{name} copies the stacked cache"
+        assert np.prod(np.int64(dims_of[update].split(","))) < slab, (
+            f"%{name} writes [{dims_of[update]}] into the stacked cache")
+
+
+@cell_programs
+def test_cell_program_fits_at_16_layers(one_chip, as_tpu, program):
+    compiled, _ = _cell_program(one_chip, program, 16)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_GIB * 2**30, f"{total / 2**30:.2f} GiB"
 
 
 # ------------------------------------------------- the sharded train step
