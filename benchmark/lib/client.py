@@ -23,7 +23,12 @@ Protocol: one JSON object per line.  In: ``{"i", "p", "o", "x", "t",
 "epoch"}`` (index, prompt and output length, prefix index, start relative
 to the epoch or null for "now").  Out: ``{"i", "t_start", "t_fired",
 "times", "t_end", "err"}``, times relative to the epoch on the host's
-monotonic clock, which all processes of a machine share.
+monotonic clock, which all processes of a machine share.  ``{"warm": true}``
+sends one 8-token request for 2 tokens and answers ``{"warmed", "err"}``: a
+process's first request fires 25 ms late and its first call to the replica
+takes tens of ms more now and then, which a user of a long-lived ingress
+never sees, and the first request of a pre-roll sets the phase of every
+dispatch after it (PERF.md, PR 28).
 """
 
 from __future__ import annotations
@@ -80,6 +85,16 @@ def main(argv) -> int:
         print(json.dumps({"ready": True}), flush=True)
         for line in sys.stdin:
             cmd = json.loads(line)
+            if cmd.get("warm"):
+                err = ""
+                try:
+                    for _tok in stream(payloads.make(loadgen.Planned(
+                            None, 8, 2, -1, 0)), float(timeout_s)):
+                        pass
+                except Exception as e:  # noqa: BLE001 — the parent raises it
+                    err = repr(e)
+                print(json.dumps({"warmed": not err, "err": err}), flush=True)
+                continue
             epoch = cmd["epoch"]
             req = loadgen.Planned(cmd["t"], cmd["p"], cmd["o"], cmd["x"],
                                   cmd["i"])

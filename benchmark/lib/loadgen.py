@@ -265,16 +265,32 @@ class ClientPool:
         for p in self.procs:
             self.idle.put(p)
 
-    def fire(self, req: Planned, t_start: Optional[float]) -> Sample:
-        p = self.idle.get()
-        p.stdin.write(json.dumps({
-            "i": req.index, "p": req.prompt_len, "o": req.output_len,
-            "x": req.prefix_index, "t": t_start, "epoch": self.epoch}) + "\n")
+    @staticmethod
+    def _ask(p: subprocess.Popen, cmd: dict) -> dict:
+        """One command to a caller and its one line of answer."""
+        p.stdin.write(json.dumps(cmd) + "\n")
         p.stdin.flush()
         line = p.stdout.readline()
         if not line:
             raise RuntimeError(f"caller process {p.pid} ended mid-request")
-        r = json.loads(line)
+        return json.loads(line)
+
+    def warm(self, at_once: int = 16):
+        """One small request from every caller through the ingress the mix
+        names (``lib/client.py`` says why), ``at_once`` at a time."""
+        def one(p):
+            r = self._ask(p, {"warm": True})
+            if not r["warmed"]:
+                raise RuntimeError(f"caller process {p.pid} could not send "
+                                   f"its warm-up request: {r['err']}")
+        with ThreadPoolExecutor(max_workers=at_once) as pool:
+            list(pool.map(one, self.procs))
+
+    def fire(self, req: Planned, t_start: Optional[float]) -> Sample:
+        p = self.idle.get()
+        r = self._ask(p, {
+            "i": req.index, "p": req.prompt_len, "o": req.output_len,
+            "x": req.prefix_index, "t": t_start, "epoch": self.epoch})
         self.idle.put(p)
         return Sample(r["t_start"], r["t_fired"], r["times"], req.output_len,
                       req.prompt_len, r["t_end"], r["err"])

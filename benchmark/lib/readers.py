@@ -6,7 +6,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from . import costs, trace
+from . import trace
 
 #: the engine's prefill programs are jitted from a function of this name
 #: (``LLMEngine._prefill_fn``); everything else a serving replica runs in
@@ -65,7 +65,8 @@ def decode_step_roofline(ctx: dict) -> Optional[float]:
     time less the prefill programs', divided by the decode steps the engine
     counted between the span's two ends.  The context a step attends over
     is the mean over the requests in flight during the span of prompt plus
-    half the output."""
+    half the output.  Bytes and FLOPs are the block kind's own
+    (``ctx["model"]``, the file the configuration's ``model_type`` names)."""
     prefill = prefill_seconds(ctx)
     steps = span_delta(ctx, "steps") - span_delta(ctx, "admit_batches")
     if prefill is None or steps <= 0 or ctx["peaks"] is None:
@@ -77,10 +78,10 @@ def decode_step_roofline(ctx: dict) -> Optional[float]:
     context = sum(s.prompt_len + len(s.token_times) / 2 for s in live) \
         / len(live)
     active = span_delta(ctx, "tokens_out") / span_delta(ctx, "steps")
-    doc, peaks = ctx["config"], ctx["peaks"]
+    doc, peaks, model = ctx["config"], ctx["peaks"], ctx["model"]
     least = max(
-        costs.decode_step_bytes(doc, active * context)
+        model.decode_step_bytes(doc, active, active * context)
         / peaks["hbm_bytes_per_s"],
-        costs.decode_step_flops(doc, active, active * context)
+        model.decode_step_flops(doc, active, active * context)
         / peaks["bf16_flops_per_s"])
     return 100.0 * least / measured

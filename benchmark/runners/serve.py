@@ -93,7 +93,8 @@ class Replica:
                                    else {}),
                 **dep_cfg.get("deployment_options", {}))
             self.h = h = serve.run(
-                dep.bind(config_path=cell.config_path, seed=self.seed,
+                dep.bind(config_path=cell.config_path,
+                         model_path=cell.model_path, seed=self.seed,
                          chips=cell.chips), timeout_s=900.0)
             self.pids = [w.pid
                          for w in api._state.node_agent.workers.values()]
@@ -123,16 +124,9 @@ class Replica:
         return getattr(self.h, method).remote(*args).result(
             timeout_s=timeout_s)
 
-    def warm(self, traffic: dict):
-        lens = warm_lengths(traffic, self.cell.config["serve"])
-        say(f"warmed prompt lengths {lens}: "
-            + compact(self.call("warm", lens, timeout_s=1200)))
-        # one request through the whole path (router, stream polling)
-        list(self.h.stream({"tokens": [1] * 8, "max_tokens": 2},
-                           timeout_s=120))
-
     def callers(self, traffic: dict, seed: int) -> "loadgen.ClientPool":
-        """The caller processes of one traffic mix, attached and idle."""
+        """The caller processes of one traffic mix, started: they attach to
+        the cluster while ``warm`` compiles, which waits for them."""
         from ..lib import client
         ingress = traffic.get("ingress", "native_generator")
         if ingress not in client.INGRESS:
@@ -140,15 +134,24 @@ class Replica:
                              f"lib/client.py has {sorted(client.INGRESS)}")
         n = int(traffic["clients"] if traffic["loop"] == "closed"
                 else traffic.get("callers", 48))
-        t0 = time.monotonic()
-        pool = loadgen.ClientPool(
+        return loadgen.ClientPool(
             n, os.environ["RAYTPU_GCS_ADDRESS"], "bench-llm", traffic,
             self.cell.config["vocab_size"], seed,
             float(traffic.get("request_timeout_s", 300.0)))
+
+    def warm(self, traffic: dict, pool: "loadgen.ClientPool"):
+        """The programs the mix's prompts reach, then one request from every
+        caller through the whole path (router, replica, ingress)."""
+        lens = warm_lengths(traffic, self.cell.config["serve"])
+        say(f"warmed prompt lengths {lens}: "
+            + compact(self.call("warm", lens, timeout_s=1200)))
+        t0 = time.monotonic()
         pool.wait_ready()
-        say(f"{n} caller processes attached in "
-            f"{time.monotonic() - t0:.1f}s")
-        return pool
+        t1 = time.monotonic()
+        pool.warm()
+        say(f"{len(pool.procs)} caller processes attached {t1 - t0:.1f}s "
+            f"after that and sent their first request in "
+            f"{time.monotonic() - t1:.1f}s")
 
     def window(self, traffic: dict, seconds: float, seed: int, trace: bool,
                pool: "loadgen.ClientPool", on_epoch=None) -> dict:
@@ -225,7 +228,7 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
 def _measure(rep: Replica, pool, cell: manifest.Cell, seed: int,
              seconds: float, trace: bool, t_process_start: float, dump_path):
     traffic = cell.traffic
-    rep.warm(traffic)
+    rep.warm(traffic, pool)
     setup = {}
 
     def on_epoch(epoch):
@@ -276,7 +279,7 @@ def _measure(rep: Replica, pool, cell: manifest.Cell, seed: int,
         ctx = {
             "cell": cell.entry, "config": cell.config, "traffic": traffic,
             "seconds": seconds, "roll": roll, "samples": win["samples"],
-            "trace": summary,
+            "trace": summary, "model": cell.model,
             "span": {"t0": tr["t0"] - epoch, "t1": tr["t1"] - epoch,
                      "stats0": tr["stats0"], "stats1": tr["stats1"]},
             "stats0": win["stats0"], "stats1": win["stats1"],

@@ -10,7 +10,7 @@ import math
 import os
 import time
 
-from ..lib import costs, loadgen, manifest, modelcfg, rollup
+from ..lib import loadgen, manifest, rollup
 from ..lib.peaks import peaks_for
 from .common import CellFailed, compact, dump, result_line, say
 
@@ -22,6 +22,7 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
         t_process_start: float, dump_path=None):
     want = cell.config.get("platform", "tpu")
     doc, tr, job = cell.config, cell.config["train"], cell.traffic
+    model = cell.model
 
     from ray_tpu.utils.compile_cache import cache_entries, place_compile_cache
     cache_dir = place_compile_cache()
@@ -32,11 +33,9 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     import numpy as np
     from jax.profiler import TraceAnnotation
 
-    from ray_tpu.models import transformer
     from ray_tpu.parallel import MeshSpec, make_optimizer, make_train_step
     from ray_tpu.parallel.train_step import TrainState, state_shardings
 
-    from ..lib import reference
     from ..lib import trace as trace_lib
 
     devs = jax.devices()
@@ -47,7 +46,7 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
                          f"device(s); jax.devices() found {found}")
     say(f"devices: {found}")
     seed = loadgen.fold_seed(seed)
-    cfg = modelcfg.transformer_config(doc)
+    cfg = model.program_config(doc)
     batch_size, seq = int(tr["global_batch"]), int(tr["sequence_length"])
     tokens_per_step = batch_size * seq
 
@@ -59,7 +58,7 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     # it, the seed is closed over, a constant of the jitted init, so every
     # new --seed would compile it anew (PERF.md, Open questions)
     def init_fn(key):
-        params = transformer.init_params(key, cfg, dtype=jnp.float32)
+        params = model.init_params(key, cfg, jnp.float32)
         return TrainState(params=params, opt_state=opt.init(params),
                           step=jnp.zeros((), jnp.int32))
 
@@ -69,7 +68,7 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     step = make_train_step(cfg, mesh, opt, sh, remat=tr["remat"])
     jax.block_until_ready(state)
     say(f"state on the mesh {dict(mesh.shape)} in "
-        f"{time.monotonic() - t0:.1f}s; params={costs.num_params(doc)}")
+        f"{time.monotonic() - t0:.1f}s; params={model.num_params(doc)}")
 
     def host_batch(i: int) -> dict:
         if job["data"] != "uniform_tokens":
@@ -87,7 +86,7 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     t0 = time.monotonic()
     first = host_batch(0)
     ref_loss = float(jax.jit(
-        lambda p, t: jax.vmap(lambda s: reference.loss(p, s, doc))(t).mean(),
+        lambda p, t: jax.vmap(lambda s: model.loss(p, s, doc))(t).mean(),
         in_shardings=(sh.params, step.batch_sharding))(
             state.params, first["_all"]))
     say(f"reference loss on the first batch {ref_loss:.6f} in "
@@ -168,7 +167,7 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     try:
         peak = peaks_for(devs[0].device_kind)
         roll["mfu"] = (roll["train_tokens_per_s_per_chip"]
-                       * costs.train_flops_per_token(doc, seq)
+                       * model.train_flops_per_token(doc, seq)
                        / peak["bf16_flops_per_s"])
     except KeyError:
         peak = None
@@ -214,7 +213,7 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
         breakdown = trace_lib.breakdown(summary)
         ctx = {"cell": cell.entry, "config": doc, "traffic": job,
                "seconds": seconds, "roll": roll, "trace": summary,
-               "span": {"steps": traced["steps"],
+               "model": model, "span": {"steps": traced["steps"],
                         "seconds": traced["t1"] - traced["t0"]},
                "step_ends": step_ends, "device": device, "peaks": peak,
                "chips": cell.chips}

@@ -1,7 +1,8 @@
 """The benchmark's deployment class: ``LLMServer`` with a constructor that
-builds the model from a configuration file instead of
-``models/config.PRESETS`` (which has no Mistral preset, and which a
-benchmark PR may not touch).  Everything a request touches is the program's
+builds the model from a configuration file and its block kind's file
+(``models/<model_type>.py``, resolved by the parent and loaded here by
+path) instead of ``models/config.PRESETS``, which a benchmark PR may not
+touch.  Everything a request touches is the program's
 own: ``serve.deployment`` / ``serve.run``, the router, the replica actor,
 ``LLMServer.__call__`` and the unmodified ``LLMEngine``.
 
@@ -26,16 +27,15 @@ import time
 
 from ray_tpu.serve.llm import LLMEngine, LLMServer
 
-from benchmark.lib import loadgen, modelcfg
+from benchmark.lib import loadgen, manifest
 
 
 class BenchLLMServer(LLMServer):
 
-    def __init__(self, config_path: str, seed: int, chips: int):
+    def __init__(self, config_path: str, model_path: str, seed: int,
+                 chips: int):
         import jax
         import jax.numpy as jnp
-
-        from ray_tpu.models import transformer
 
         t0 = time.monotonic()
         with open(config_path) as f:
@@ -48,13 +48,14 @@ class BenchLLMServer(LLMServer):
                 f"found platform={devs[0].platform} "
                 f"kind={devs[0].device_kind!r} count={len(devs)}")
         self.seed = loadgen.fold_seed(seed)
-        cfg = modelcfg.transformer_config(self.doc)
+        self.model = model = manifest.load_model(model_path)
+        cfg = model.program_config(self.doc)
         dep = self.doc["serve"]
         # the weights: one jitted call from the seed, on the device, in the
         # type they are served in.  The key is an argument: closed over, the
         # seed is a constant of the program and every seed compiles anew.
-        params = jax.jit(lambda key: transformer.init_params(
-            key, cfg, dtype=jnp.bfloat16))(jax.random.PRNGKey(self.seed))
+        params = jax.jit(lambda key: model.init_params(
+            key, cfg, jnp.bfloat16))(jax.random.PRNGKey(self.seed))
         jax.block_until_ready(params)
         t1 = time.monotonic()
         self.engine = LLMEngine(
@@ -152,27 +153,24 @@ class BenchLLMServer(LLMServer):
         import jax.numpy as jnp
         import numpy as np
 
-        from ray_tpu.models import decode as dec
-
-        from benchmark.lib import reference
-
         t0 = time.monotonic()
         chk = self.doc["serve"]["check"]
         n_prompt, n_dec = chk["prompt_len"], chk["decode_steps"]
         cfg, doc, params = self.engine.cfg, self.doc, self.engine.params
+        model = self.model
         toks = np.random.default_rng([self.seed, 7]).integers(
             1, cfg.vocab_size, size=n_prompt + n_dec).astype(np.int32)
         pos = jnp.arange(n_prompt - 1, n_prompt + n_dec)
-        ref = np.asarray(jax.jit(lambda p, t: reference.logits(
+        ref = np.asarray(jax.jit(lambda p, t: model.logits(
             p, t, doc, pos))(params, toks))
         cache_len = -(-(n_prompt + n_dec + 1) // 128) * 128
-        cache = dec.init_kv_cache(cfg, 1, cache_len, self.engine.compute_dtype)
-        cache, lg = jax.jit(lambda p, c, t, ln, sl: dec.prefill(
+        cache = model.init_cache(cfg, 1, cache_len, self.engine.compute_dtype)
+        cache, lg = jax.jit(lambda p, c, t, ln, sl: model.prefill(
             p, c, t, ln, sl, cfg))(params, cache, toks[None, :n_prompt],
                                    np.array([n_prompt], np.int32),
                                    np.array([0], np.int32))
         got = [np.asarray(lg)[0]]
-        step = jax.jit(lambda p, c, t, a: dec.decode_step(p, c, t, a, cfg))
+        step = jax.jit(lambda p, c, t, a: model.decode_step(p, c, t, a, cfg))
         for i in range(n_dec):
             cache, lg = step(params, cache,
                              toks[n_prompt + i:n_prompt + i + 1],

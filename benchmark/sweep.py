@@ -29,7 +29,7 @@ def main(argv=None) -> int:
     with Replica(cell, args.seed) as rep:
         pool = rep.callers(cell.traffic, args.seed)
         try:
-            rep.warm(cell.traffic)
+            rep.warm(cell.traffic, pool)
             rows = []
             for rate in (float(r) for r in args.rates.split(",")):
                 traffic = dict(cell.traffic, rate_per_s=rate)

@@ -43,8 +43,11 @@ def last_json(p):
 @pytest.mark.parametrize("workload", ["tiny-open", "tiny-closed"])
 def test_serve_runner_end_to_end(workload):
     manifest = json.load(open(os.path.join(TINY, "BENCHMARK.json")))
-    out = last_json(run_cell(os.path.join(TINY, "BENCHMARK.json"), workload,
-                             seed=3_000_000_019))
+    p = run_cell(os.path.join(TINY, "BENCHMARK.json"), workload,
+                 seed=3_000_000_019)
+    out = last_json(p)
+    # no measured request is a caller process's first
+    assert "sent their first request" in p.stdout
     want = {m["name"] for m in manifest["end_to_end"]
             if workload in m.get("workloads", [workload])}
     assert set(out["metrics"]) == want and "setup_s" in want
