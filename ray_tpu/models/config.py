@@ -9,7 +9,7 @@ explicit sharding rules (see ``ray_tpu/models/sharding.py``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 #: Per-chip peak bf16 matmul FLOP/s by device kind — the denominator of
 #: every MFU number this repo reports (bench.py headline, the runtime
@@ -70,15 +70,75 @@ class TransformerConfig:
     #: sizes).  An explicit "flash"/"splash" that cannot run for the shape
     #: raises; only "auto" chooses.
     attention_impl: str = "auto"
+    # layers of two kinds (models/hybrid.py).  ``layer_pattern`` is one
+    # period of kinds, "linear" (a gated-delta-rule mixer with a recurrent
+    # state per sequence) or "full" (the dense attention above); the model
+    # is ``num_layers / len(layer_pattern)`` such periods.  Empty: every
+    # layer is a dense block, the path of every other preset.
+    layer_pattern: Tuple[str, ...] = ()
+    linear_num_heads: int = 0       # key heads = value heads of the mixer
+    linear_key_dim: int = 0         # per head
+    linear_value_dim: int = 0       # per head
+    linear_conv_width: int = 4      # causal depthwise convolution over time
+    linear_neg_eigval: bool = False  # beta in (0, 2) instead of (0, 1)
+    qk_norm: bool = False           # RMSNorm over the whole q and k rows
+    norm_on_output: bool = False    # x + norm(f(x)), the hybrid blocks' wiring
+    no_positions: bool = False      # neither rotary nor learned positions
+
+    def __post_init__(self):
+        pat = self.layer_pattern
+        if not pat:
+            if self.qk_norm or self.norm_on_output:
+                raise ValueError("qk_norm and norm_on_output are wired for "
+                                 "a layer_pattern only (models/hybrid.py)")
+            return
+        if not self.norm_on_output:
+            raise ValueError("a layer_pattern's blocks are wired x + norm(f(x)) "
+                             "(models/hybrid.py): set norm_on_output")
+        if set(pat) - {"linear", "full"}:
+            raise ValueError(f"layer_pattern {pat}: kinds are 'linear' and "
+                             "'full'")
+        if self.num_layers % len(pat):
+            raise ValueError(f"num_layers {self.num_layers} is not whole "
+                             f"periods of {pat}")
+        if "linear" in pat and not (self.linear_num_heads
+                                    and self.linear_key_dim
+                                    and self.linear_value_dim):
+            raise ValueError("a 'linear' layer needs linear_num_heads, "
+                             "linear_key_dim and linear_value_dim")
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
 
+    @property
+    def learned_positions(self) -> bool:
+        return not self.use_rope and not self.no_positions
+
+    @property
+    def num_periods(self) -> int:
+        return self.num_layers // len(self.layer_pattern)
+
+    @property
+    def linear_layers(self) -> int:
+        return (self.num_periods * self.layer_pattern.count("linear")
+                if self.layer_pattern else 0)
+
+    @property
+    def full_layers(self) -> int:
+        return self.num_layers - self.linear_layers
+
     def num_params(self) -> int:
         """Approximate parameter count (for MFU math)."""
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         attn = h * h + 2 * h * (self.num_kv_heads * self.head_dim) + h * h
+        if self.layer_pattern:
+            kd = self.linear_num_heads * self.linear_key_dim
+            vd = self.linear_num_heads * self.linear_value_dim
+            mixer = (h * (2 * kd + 2 * vd) + vd * h
+                     + 2 * h * self.linear_num_heads)
+            return (self.linear_layers * mixer + self.full_layers * attn
+                    + L * 3 * h * self.mlp_size + 2 * v * h)
         if self.num_experts > 1:
             mlp = self.num_experts * 3 * h * self.mlp_size + h * self.num_experts
         else:
@@ -89,6 +149,14 @@ class TransformerConfig:
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Training FLOPs/token ≈ 6*N_active + attention quadratic term."""
         h, L = self.hidden_size, self.num_layers
+        if self.layer_pattern:
+            # the quadratic term for the full layers only; the mixer's state
+            # update and read are 4 * key_dim * value_dim a head a token
+            s = seq_len or self.max_seq_len
+            state = (self.linear_layers * self.linear_num_heads * 4
+                     * self.linear_key_dim * self.linear_value_dim)
+            return (6.0 * (self.num_params() - self.vocab_size * h)
+                    + 6.0 * self.full_layers * 2 * s * h + 3.0 * state)
         attn = L * (h * h + 2 * h * self.num_kv_heads * self.head_dim + h * h)
         if self.num_experts > 1:
             mlp = L * self.experts_per_token * 3 * h * self.mlp_size

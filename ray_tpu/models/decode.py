@@ -44,7 +44,12 @@ KVCache = Dict[str, jnp.ndarray]
 
 def init_kv_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
                   dtype=jnp.bfloat16) -> KVCache:
-    """Allocate the HBM cache: K/V per layer per slot, plus per-slot lengths."""
+    """Allocate the HBM cache: K/V per layer per slot, plus per-slot lengths.
+    A model with a ``layer_pattern`` keeps K/V for its full-attention layers
+    only and a recurrent state for the others (``hybrid.init_cache``)."""
+    if cfg.layer_pattern:
+        from . import hybrid
+        return hybrid.init_cache(cfg, num_slots, max_len, dtype)
     shape = (cfg.num_layers, num_slots, max_len, cfg.num_kv_heads,
              cfg.head_dim)
     return {
@@ -58,6 +63,22 @@ def cache_bytes(cfg: TransformerConfig, num_slots: int, max_len: int,
                 dtype_bytes: int = 2) -> int:
     return (2 * cfg.num_layers * num_slots * max_len * cfg.num_kv_heads
             * cfg.head_dim * dtype_bytes)
+
+
+def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
+    """What a cache tree holds, by kind of state: bytes of keys and values
+    (per token) and of everything else a slot keeps (per sequence: a
+    recurrent state, a convolution tail), and the layers of each kind."""
+    def nbytes(a):
+        return int(a.size) * jnp.dtype(a.dtype).itemsize
+
+    control = ("length", "block_table")
+    kv = sum(nbytes(a) for n, a in cache.items() if n in ("k", "v"))
+    state = sum(nbytes(a) for n, a in cache.items()
+                if n not in ("k", "v") + control)
+    return {"cache_kv_bytes": kv, "cache_state_bytes": state,
+            "linear_layers": cfg.linear_layers,
+            "full_layers": cfg.full_layers}
 
 
 # ---------------------------------------------------------------------------
@@ -76,6 +97,9 @@ def _qkv(x, p, cfg: TransformerConfig, positions):
         q = q + p["bq"].astype(cast)
         k = k + p["bk"].astype(cast)
         v = v + p["bv"].astype(cast)
+    if cfg.qk_norm:                 # over the whole row, before the heads
+        q = _norm(q, p["q_norm"], cfg)
+        k = _norm(k, p["k_norm"], cfg)
     q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
@@ -128,6 +152,18 @@ def _proj_out(attn, p, cast):
 # Prefill
 # ---------------------------------------------------------------------------
 
+def prefill_attention(y, ap, cfg: TransformerConfig, positions):
+    """One layer's causal attention over whole right-padded rows.  y: [B, S,
+    H] -> (attention after its output projection [B, S, H], this layer's
+    k and v [B, S, NKV, D] for the cache)."""
+    from ..ops.attention import mha
+    b, s, _ = y.shape
+    q, k, v = _qkv(y, ap, cfg, positions)
+    with jax.named_scope("attn"):
+        attn = mha(q, k, v, causal=True, logit_softcap=cfg.attn_logit_softcap)
+    return _proj_out(attn.reshape(b, s, -1), ap, y.dtype), k, v
+
+
 def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
             lengths: jnp.ndarray, slot_ids: jnp.ndarray,
             cfg: TransformerConfig,
@@ -138,22 +174,21 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
     lengths: [B] true prompt lengths; slot_ids: [B] cache rows to fill.
     Returns (cache, last-token logits [B, V] f32).
     """
+    if cfg.layer_pattern:
+        from . import hybrid
+        return hybrid.prefill(params, cache, tokens, lengths, slot_ids, cfg,
+                              compute_dtype)
     b, s = tokens.shape
     cast = compute_dtype
     x = params["embed"]["tokens"][tokens].astype(cast)
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         x = x + params["embed"]["pos"][:s][None].astype(cast)
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
-    from ..ops.attention import mha
-
     def body(x, lp):
-        y = _norm(x, lp["attn_norm"], cfg)
-        q, k, v = _qkv(y, lp["attn"], cfg, positions)
-        with jax.named_scope("attn"):
-            attn = mha(q, k, v, causal=True,
-                       logit_softcap=cfg.attn_logit_softcap)
-        x = x + _proj_out(attn.reshape(b, s, -1), lp["attn"], cast)
+        out, k, v = prefill_attention(_norm(x, lp["attn_norm"], cfg),
+                                      lp["attn"], cfg, positions)
+        x = x + out
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
         return x, (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype))
 
@@ -179,6 +214,80 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
 # Decode step
 # ---------------------------------------------------------------------------
 
+def decode_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths):
+    """One layer's attention for one new token a slot.  y: [slots, 1, H];
+    k_all, v_all: the stacked cache [layers, slots, max_len, NKV, D] (or
+    with heads and head size merged, [layers, slots, max_len, NKV * D]:
+    ``_attend_merged``), of which this is layer ``i``.  Appends the token's
+    K/V at ``[i, slot, length]`` in place and attends over the layer's rows
+    up to it.  Returns (attention after its output projection [slots, 1,
+    H], k_all, v_all)."""
+    n_slots, cast = y.shape[0], y.dtype
+    max_len = k_all.shape[2]
+    reps = cfg.num_heads // cfg.num_kv_heads
+    # mask over cache positions: <= current length (the new token's position)
+    pos_mask = (jnp.arange(max_len)[None] <= lengths[:, None])  # [slots, max_len]
+    slot_idx = jnp.arange(n_slots)
+    q, k, v = _qkv(y, ap, cfg, lengths[:, None])  # q:[S,1,NH,D] k/v:[S,1,NKV,D]
+    row = k_all.shape[3:]             # (NKV, D), or (NKV * D,) merged
+    # append at position `length` (one row per slot of this layer)
+    with jax.named_scope("kv_write"):
+        k_all = k_all.at[i, slot_idx, lengths].set(
+            k[:, 0].reshape((n_slots,) + row).astype(k_all.dtype))
+        v_all = v_all.at[i, slot_idx, lengths].set(
+            v[:, 0].reshape((n_slots,) + row).astype(v_all.dtype))
+    # attention over the cache row
+    with jax.named_scope("kv_read"):
+        k_lay = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+        v_lay = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
+        if len(row) == 1:
+            attn = _attend_merged(q[:, 0], k_lay, v_lay, pos_mask, cfg)
+        else:
+            qh = q[:, 0].reshape(n_slots, cfg.num_kv_heads, reps,
+                                 cfg.head_dim)
+            scores = jnp.einsum("sgrd,smgd->sgrm", qh.astype(jnp.float32),
+                                k_lay.astype(jnp.float32)) \
+                * cfg.head_dim ** -0.5
+            if cfg.attn_logit_softcap:
+                c = cfg.attn_logit_softcap
+                scores = c * jnp.tanh(scores / c)
+            scores = jnp.where(pos_mask[:, None, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            attn = jnp.einsum("sgrm,smgd->sgrd", probs,
+                              v_lay.astype(jnp.float32))
+        attn = attn.reshape(n_slots, 1, cfg.num_heads * cfg.head_dim)
+    return _proj_out(attn.astype(cast), ap, cast), k_all, v_all
+
+
+def _attend_merged(q, k_lay, v_lay, pos_mask, cfg: TransformerConfig):
+    """Decode attention over cache rows that hold all KV heads side by side,
+    ``k_lay``, ``v_lay`` [slots, max_len, NKV * D]; q [slots, NH, D].  A head
+    count that is no multiple of the 8-row tile (30) cannot sit before the
+    head size without padding, and the TPU compiler then holds the stack in
+    another layout at a program's edge than inside it and transposes all of
+    it in and out of every dispatch (sandbox compile for a described v5e,
+    PR 29).  Merged rows have one layout.  Each head's scores are a matmul
+    of the whole row with its query placed in its own head's channels and
+    zeros elsewhere: NKV times the needed FLOPs on the MXU, none of them
+    felt beside the read of the rows, and no reshape of the rows.
+    Returns [slots, NH, D] float32."""
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    own = (jnp.arange(nkv * hd)[None, :] // hd
+           == jnp.arange(nh)[:, None] // (nh // nkv))         # [NH, NKV * D]
+    q_row = jnp.where(own[None], jnp.tile(q, (1, 1, nkv)), 0)
+    scores = jnp.einsum("shc,smc->shm", q_row.astype(k_lay.dtype), k_lay,
+                        preferred_element_type=jnp.float32) * hd ** -0.5
+    if cfg.attn_logit_softcap:
+        c = cfg.attn_logit_softcap
+        scores = c * jnp.tanh(scores / c)
+    scores = jnp.where(pos_mask[:, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    full = jnp.einsum("shm,smc->shc", probs.astype(v_lay.dtype), v_lay,
+                      preferred_element_type=jnp.float32)
+    return jnp.where(own[None], full, 0.0).reshape(
+        q.shape[0], nh, nkv, hd).sum(axis=2)
+
+
 def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
                 active: jnp.ndarray, cfg: TransformerConfig,
                 compute_dtype=jnp.bfloat16) -> Tuple[KVCache, jnp.ndarray]:
@@ -189,49 +298,25 @@ def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
     Returns (cache, logits [slots, V] f32).  Appends K/V at position `length`
     and increments `length` for active slots.
     """
-    n_slots = tokens.shape[0]
+    if cfg.layer_pattern:
+        from . import hybrid
+        return hybrid.decode_step(params, cache, tokens, active, cfg,
+                                  compute_dtype)
     max_len = cache["k"].shape[2]
     cast = compute_dtype
     lengths = cache["length"]                                  # [slots]
     x = params["embed"]["tokens"][tokens][:, None].astype(cast)  # [S,1,H]
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         x = x + params["embed"]["pos"][jnp.minimum(
             lengths, cfg.max_seq_len - 1)][:, None].astype(cast)
-    positions = lengths[:, None]                               # [slots, 1]
-    scale = cfg.head_dim ** -0.5
-    reps = cfg.num_heads // cfg.num_kv_heads
-    # mask over cache positions: <= current length (the new token's position)
-    pos_mask = (jnp.arange(max_len)[None] <= lengths[:, None])  # [slots, max_len]
-    slot_idx = jnp.arange(n_slots)
 
     def body(carry, layer):
         x, k_all, v_all = carry         # k/v_all: [L, slots, max_len, NKV, D]
         lp, i = layer
-        y = _norm(x, lp["attn_norm"], cfg)
-        q, k, v = _qkv(y, lp["attn"], cfg, positions)  # q:[S,1,NH,D] k/v:[S,1,NKV,D]
-        # append at position `length` (one row per slot of this layer)
-        with jax.named_scope("kv_write"):
-            k_all = k_all.at[i, slot_idx, lengths].set(
-                k[:, 0].astype(k_all.dtype))
-            v_all = v_all.at[i, slot_idx, lengths].set(
-                v[:, 0].astype(v_all.dtype))
-        # attention over the cache row
-        with jax.named_scope("kv_read"):
-            k_lay = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
-            v_lay = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
-            qh = q[:, 0].reshape(n_slots, cfg.num_kv_heads, reps,
-                                 cfg.head_dim)
-            scores = jnp.einsum("sgrd,smgd->sgrm", qh.astype(jnp.float32),
-                                k_lay.astype(jnp.float32)) * scale
-            if cfg.attn_logit_softcap:
-                c = cfg.attn_logit_softcap
-                scores = c * jnp.tanh(scores / c)
-            scores = jnp.where(pos_mask[:, None, None, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            attn = jnp.einsum("sgrm,smgd->sgrd", probs,
-                              v_lay.astype(jnp.float32))
-            attn = attn.reshape(n_slots, 1, cfg.num_heads * cfg.head_dim)
-        x = x + _proj_out(attn.astype(cast), lp["attn"], cast)
+        out, k_all, v_all = decode_attention(
+            _norm(x, lp["attn_norm"], cfg), lp["attn"], cfg, k_all, v_all,
+            i, lengths)
+        x = x + out
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
         return (x, k_all, v_all), None
 

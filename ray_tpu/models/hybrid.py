@@ -1,0 +1,356 @@
+"""Layers of two kinds in one model: gated-delta-rule linear-attention
+layers beside full-attention layers (``TransformerConfig.layer_pattern``),
+on the serving path.  ``decode.init_kv_cache`` / ``prefill`` / ``decode_step``
+come here when the configuration has a pattern, so ``serve/llm.py`` runs the
+same three calls on a cache tree of two kinds of state:
+
+* ``k``, ``v``: [full_layers, slots, max_len, NKV * D], the dense cache of
+  ``decode.py`` with rows for the full-attention layers only, written and
+  read by ``decode.prefill_attention`` / ``decode_attention``, a position's
+  heads side by side in one row (``decode._attend_merged`` says why);
+* ``state``: [linear_layers, slots, heads, key_dim, value_dim] float32, the
+  delta rule's state (``ops/gated_delta.py``), constant in the context;
+* ``conv``: [linear_layers, slots, conv_width - 1, channels], the last
+  inputs of the mixer's causal convolution;
+* ``length``: [slots].
+
+A linear layer's mixer, for input ``x`` (``linear_*`` sizes of the config)::
+
+    [q; k; v] = silu(causal_conv(W_qkv x));  q = l2norm(q) / sqrt(dk)
+    k = l2norm(k);  beta = (2 if neg_eigval else 1) * sigmoid(w_b x)
+    g = -exp(A_log) * softplus(w_a x + dt_bias)        (alpha = exp(g))
+    o = gated_delta_rule(q, k, v, g, beta)
+    y = W_o [rmsnorm_head(o) * silu(W_g x)]
+
+Blocks are wired ``h = x + norm(mixer(x)); out = h + norm(mlp(h))``
+(``norm_on_output``) and nothing adds positions (``no_positions``): the
+recurrences and convolutions carry them.
+
+Parameters are stacked per kind with leading dims [periods, layers of the
+kind in a period], so one ``lax.scan`` over periods traces one period's
+layers whatever the depth.  Right padding is harmless by construction, not
+by causality: a position at or beyond a row's length has ``beta = 0, g =
+0`` (the state passes through) and the convolution tail is gathered at the
+row's length, so the state a prefill leaves is each row's as of its true
+length.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import gated_delta
+from .config import TransformerConfig
+from .decode import (KVCache, _mlp, decode_attention, prefill_attention)
+from .transformer import Params, _norm, lm_head_logits
+
+L2_EPS = 1e-6
+
+
+def _counts(cfg: TransformerConfig) -> Tuple[int, int, int]:
+    """(periods, linear layers a period, full layers a period)."""
+    n_lin = cfg.layer_pattern.count("linear")
+    return cfg.num_periods, n_lin, len(cfg.layer_pattern) - n_lin
+
+
+def _channels(cfg: TransformerConfig) -> Tuple[int, int]:
+    """(all key channels, all value channels) of the mixer."""
+    return (cfg.linear_num_heads * cfg.linear_key_dim,
+            cfg.linear_num_heads * cfg.linear_value_dim)
+
+
+# ---------------------------------------------------------------------------
+# Parameters and cache
+# ---------------------------------------------------------------------------
+
+def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
+    """``params["blocks"]`` of a model with a layer pattern: ``{"linear":
+    ..., "full": ...}``, leaves [periods, layers of the kind a period, ...].
+
+    The decay's parameters are drawn so that ``alpha`` spreads over about
+    (0.9, 1) across heads (``-log alpha`` log-uniform in 0.002..0.08): a
+    state that decays to nothing within a few tokens would make every check
+    of it vacuous.  The gate projections ``w_a`` / ``w_b`` are small for the
+    same reason: they see the residual stream, whose scale grows with depth
+    under norm-on-output wiring."""
+    h, m, hd = cfg.hidden_size, cfg.mlp_size, cfg.head_dim
+    nh, nkv, lh = cfg.num_heads, cfg.num_kv_heads, cfg.linear_num_heads
+    kd, vd = _channels(cfg)
+    periods, n_lin, n_full = _counts(cfg)
+    keys = iter(jax.random.split(key, 24))
+
+    def dense(lead, shape, fan_in, gain=1.0):
+        return (jax.random.normal(next(keys), lead + shape, dtype)
+                * (gain * fan_in ** -0.5)).astype(dtype)
+
+    def ones(lead, n):
+        return {"scale": jnp.ones(lead + (n,), dtype)}
+
+    def mlp(lead):
+        return {"w_gate": dense(lead, (h, m), h), "w_in": dense(lead, (h, m), h),
+                "w_out": dense(lead, (m, h), m)}
+
+    blocks: Params = {}
+    if n_lin:
+        lead = (periods, n_lin)
+        rate = jnp.exp(jax.random.uniform(
+            next(keys), lead + (lh,), jnp.float32,
+            jnp.log(0.002), jnp.log(0.08)))
+        blocks["linear"] = {
+            "mixer": {
+                "w_qkv": dense(lead, (h, 2 * kd + vd), h),
+                "conv_w": dense(lead, (cfg.linear_conv_width, 2 * kd + vd),
+                                cfg.linear_conv_width),
+                "w_a": dense(lead, (h, lh), h, 0.1),
+                "w_b": dense(lead, (h, lh), h, 0.5),
+                "A_log": jnp.zeros(lead + (lh,), dtype),
+                # softplus^-1(rate), so that alpha = exp(-rate) at w_a x = 0
+                "dt_bias": jnp.log(jnp.expm1(rate)).astype(dtype),
+                "w_g": dense(lead, (h, vd), h),
+                "o_norm": ones(lead, cfg.linear_value_dim),
+                "w_o": dense(lead, (vd, h), vd),
+            },
+            "mixer_norm": ones(lead, h),
+            "mlp": mlp(lead),
+            "mlp_norm": ones(lead, h),
+        }
+    if n_full:
+        lead = (periods, n_full)
+        attn = {
+            "wq": dense(lead, (h, nh * hd), h),
+            "wk": dense(lead, (h, nkv * hd), h),
+            "wv": dense(lead, (h, nkv * hd), h),
+            "wo": dense(lead, (nh * hd, h), nh * hd),
+        }
+        if cfg.qk_norm:
+            attn["q_norm"] = ones(lead, nh * hd)
+            attn["k_norm"] = ones(lead, nkv * hd)
+        blocks["full"] = {"attn": attn, "attn_norm": ones(lead, h),
+                          "mlp": mlp(lead), "mlp_norm": ones(lead, h)}
+    return blocks
+
+
+def init_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
+               dtype=jnp.bfloat16) -> KVCache:
+    kd, vd = _channels(cfg)
+    kv = (cfg.full_layers, num_slots, max_len, cfg.num_kv_heads * cfg.head_dim)
+    return {
+        "k": jnp.zeros(kv, dtype),
+        "v": jnp.zeros(kv, dtype),
+        "state": jnp.zeros((cfg.linear_layers, num_slots, cfg.linear_num_heads,
+                            cfg.linear_key_dim, cfg.linear_value_dim),
+                           jnp.float32),
+        "conv": jnp.zeros((cfg.linear_layers, num_slots,
+                           cfg.linear_conv_width - 1, 2 * kd + vd), dtype),
+        "length": jnp.zeros((num_slots,), jnp.int32),
+    }
+
+
+# ---------------------------------------------------------------------------
+# The linear mixer's pieces
+# ---------------------------------------------------------------------------
+
+@jax.named_scope("gdn")
+def _gates(x, mp, cfg: TransformerConfig, live=None):
+    """x [..., H] -> (g, beta) [..., heads] float32; where ``live`` is given
+    and false the step is the identity on the state (g 0, beta 0)."""
+    cast = x.dtype
+    a = (x @ mp["w_a"].astype(cast)).astype(jnp.float32)
+    g = -jnp.exp(mp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+        a + mp["dt_bias"].astype(jnp.float32))
+    beta = jax.nn.sigmoid((x @ mp["w_b"].astype(cast)).astype(jnp.float32))
+    if cfg.linear_neg_eigval:
+        beta = 2.0 * beta
+    if live is None:
+        return g, beta
+    return jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
+
+
+@jax.named_scope("gdn")
+def _split_heads(y, cfg: TransformerConfig):
+    """Convolved channels [..., 2 kd + vd] -> q, k [..., heads, dk], v [...,
+    heads, dv]: q and k l2-normalised per head, q scaled by dk^-0.5."""
+    kd, _ = _channels(cfg)
+    nh, dk = cfg.linear_num_heads, cfg.linear_key_dim
+    lead = y.shape[:-1]
+    q = y[..., :kd].reshape(lead + (nh, dk)).astype(jnp.float32)
+    k = y[..., kd:2 * kd].reshape(lead + (nh, dk)).astype(jnp.float32)
+    v = y[..., 2 * kd:].reshape(lead + (nh, cfg.linear_value_dim))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + L2_EPS)
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + L2_EPS)
+    return (q * dk ** -0.5).astype(y.dtype), k.astype(y.dtype), v
+
+
+@jax.named_scope("gdn")
+def _mixer_out(o, x, mp, cfg: TransformerConfig):
+    """o [..., heads, dv], x [..., H] -> W_o [rmsnorm_head(o) * silu(W_g x)]."""
+    cast = x.dtype
+    gate = jax.nn.silu(x @ mp["w_g"].astype(cast))
+    y = _norm(o, mp["o_norm"], cfg).astype(cast).reshape(gate.shape) * gate
+    return y @ mp["w_o"].astype(cast)
+
+
+def _mlp_branch(x, lp, cfg: TransformerConfig):
+    return x + _norm(_mlp(x, lp, cfg), lp["mlp_norm"], cfg)
+
+
+def _layer(tree, j: int):
+    return jax.tree.map(lambda a: a[j], tree)
+
+
+# ---------------------------------------------------------------------------
+# Prefill
+# ---------------------------------------------------------------------------
+
+def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
+            lengths: jnp.ndarray, slot_ids: jnp.ndarray,
+            cfg: TransformerConfig,
+            compute_dtype=jnp.bfloat16) -> Tuple[KVCache, jnp.ndarray]:
+    """``decode.prefill`` for a model with a layer pattern (same arguments
+    and results).  ``tokens`` [B, S] may have any S."""
+    b, s = tokens.shape
+    cast = compute_dtype
+    width = cfg.linear_conv_width
+    x = params["embed"]["tokens"][tokens].astype(cast)
+    positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    # where each row's convolution tail sits: its last width-1 inputs
+    tail_pos = lengths[:, None] - (width - 1) + jnp.arange(width - 1)[None]
+
+    def linear_layer(x, lp):
+        mp = lp["mixer"]
+        with jax.named_scope("gdn"):
+            proj = x @ mp["w_qkv"].astype(cast)                 # [B, S, C]
+        with jax.named_scope("gdn_conv"):
+            padded = jnp.pad(proj, ((0, 0), (width - 1, 0), (0, 0)))
+            conv = sum(padded[:, j:j + s] * mp["conv_w"][j].astype(cast)
+                       for j in range(width))
+            conv = jax.nn.silu(conv)
+            tail = jnp.take_along_axis(
+                proj, jnp.maximum(tail_pos, 0)[..., None], axis=1)
+            tail = jnp.where((tail_pos >= 0)[..., None], tail, 0)
+        q, k, v = _split_heads(conv, cfg)
+        g, beta = _gates(x, mp, cfg)
+        with jax.named_scope("gdn"):
+            # positions at or beyond a row's length leave its state alone
+            o, state = gated_delta.gdn_chunk_fwd(q, k, v, g, beta, lengths)
+        x = x + _norm(_mixer_out(o, x, mp, cfg), lp["mixer_norm"], cfg)
+        return _mlp_branch(x, lp, cfg), (state, tail)
+
+    def full_layer(x, lp):
+        out, k, v = prefill_attention(x, lp["attn"], cfg, positions)
+        x = x + _norm(out, lp["attn_norm"], cfg)
+        return _mlp_branch(x, lp, cfg), (k, v)
+
+    def period(x, pp):
+        rows = {"linear": [], "full": []}
+        at = {"linear": 0, "full": 0}
+        for kind in cfg.layer_pattern:
+            layer = linear_layer if kind == "linear" else full_layer
+            x, out = layer(x, _layer(pp[kind], at[kind]))
+            rows[kind].append(out)
+            at[kind] += 1
+        # per kind, the layers' (a, b) pairs stacked: ([n, ...], [n, ...])
+        stacked = {kind: jax.tree.map(lambda *a: jnp.stack(a), *outs)
+                   if outs else (None, None) for kind, outs in rows.items()}
+        return x, stacked["linear"] + stacked["full"]
+
+    x, (states, tails, k_rows, v_rows) = jax.lax.scan(
+        period, x, params["blocks"])
+    merge = lambda a: a.reshape((-1,) + a.shape[2:])             # noqa: E731
+    new = dict(cache)
+    # every layer's rows into the slots, in place on the donated cache (the
+    # K/V of the padded tail included; decode's length mask keeps it unread)
+    if k_rows is not None:
+        with jax.named_scope("kv_write"):
+            rows = lambda a: merge(a).reshape(            # noqa: E731
+                (-1, b, s, cache["k"].shape[-1])).astype(cache["k"].dtype)
+            new["k"] = cache["k"].at[:, slot_ids, :s].set(rows(k_rows))
+            new["v"] = cache["v"].at[:, slot_ids, :s].set(rows(v_rows))
+    if states is not None:
+        with jax.named_scope("state_write"):
+            new["state"] = cache["state"].at[:, slot_ids].set(merge(states))
+            new["conv"] = cache["conv"].at[:, slot_ids].set(
+                merge(tails).astype(cache["conv"].dtype))
+    new["length"] = cache["length"].at[slot_ids].set(lengths)
+    x = _norm(x, params["final_norm"], cfg)
+    last = jnp.take_along_axis(
+        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
+    return new, lm_head_logits(params, last, cfg)
+
+
+# ---------------------------------------------------------------------------
+# Decode step
+# ---------------------------------------------------------------------------
+
+def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
+                active: jnp.ndarray, cfg: TransformerConfig,
+                compute_dtype=jnp.bfloat16) -> Tuple[KVCache, jnp.ndarray]:
+    """``decode.decode_step`` for a model with a layer pattern.  An inactive
+    slot's recurrent state and convolution tail stay as they were."""
+    cast = compute_dtype
+    width = cfg.linear_conv_width
+    max_len = cache["k"].shape[2]
+    lengths = cache["length"]
+    _, n_lin, n_full = _counts(cfg)
+    x = params["embed"]["tokens"][tokens][:, None].astype(cast)  # [slots,1,H]
+    live = active[:, None]                                       # [slots, 1]
+
+    def linear_layer(x, lp, li, state, conv):
+        mp = lp["mixer"]
+        y = x[:, 0]                                              # [slots, H]
+        with jax.named_scope("gdn"):
+            proj = y @ mp["w_qkv"].astype(cast)                  # [slots, C]
+        with jax.named_scope("state_read"):
+            tail = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
+        with jax.named_scope("gdn_conv"):
+            window = jnp.concatenate([tail.astype(cast), proj[:, None]], 1)
+            mixed = jax.nn.silu(sum(window[:, j] * mp["conv_w"][j].astype(cast)
+                                    for j in range(width)))
+        with jax.named_scope("state_write"):
+            conv = jax.lax.dynamic_update_index_in_dim(
+                conv, jnp.where(live[..., None], window[:, 1:].astype(
+                    conv.dtype), tail), li, 0)
+        q, k, v = _split_heads(mixed, cfg)
+        g, beta = _gates(y, mp, cfg, live)
+        with jax.named_scope("gdn"):
+            state, o = gated_delta.gdn_recurrent_step(state, li, q, k, v, g,
+                                                      beta)
+        out = _mixer_out(o, y, mp, cfg)[:, None]
+        x = x + _norm(out, lp["mixer_norm"], cfg)
+        return _mlp_branch(x, lp, cfg), state, conv
+
+    def full_layer(x, lp, fi, k_all, v_all):
+        out, k_all, v_all = decode_attention(x, lp["attn"], cfg, k_all, v_all,
+                                             fi, lengths)
+        x = x + _norm(out, lp["attn_norm"], cfg)
+        return _mlp_branch(x, lp, cfg), k_all, v_all
+
+    def period(carry, xs):
+        x, k_all, v_all, state, conv = carry
+        pp, p = xs
+        at = {"linear": 0, "full": 0}
+        for kind in cfg.layer_pattern:
+            lp = _layer(pp[kind], at[kind])
+            if kind == "linear":
+                x, state, conv = linear_layer(
+                    x, lp, p * n_lin + at[kind], state, conv)
+            else:
+                x, k_all, v_all = full_layer(
+                    x, lp, p * n_full + at[kind], k_all, v_all)
+            at[kind] += 1
+        return (x, k_all, v_all, state, conv), None
+
+    (x, k_new, v_new, state, conv), _ = jax.lax.scan(
+        period, (x, cache["k"], cache["v"], cache["state"], cache["conv"]),
+        (params["blocks"], jnp.arange(cfg.num_periods)))
+    x = _norm(x, params["final_norm"], cfg)
+    logits = lm_head_logits(params, x[:, 0], cfg)
+    cache = {
+        "k": k_new, "v": v_new, "state": state, "conv": conv,
+        "length": jnp.where(active, jnp.minimum(lengths + 1, max_len),
+                            lengths),
+    }
+    return cache, logits

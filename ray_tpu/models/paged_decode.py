@@ -92,7 +92,7 @@ def paged_prefill(params: Params, cache: PagedKVCache, tokens: jnp.ndarray,
     cast = compute_dtype
     x = params["embed"]["tokens"][tokens].astype(cast)
     positions = start_pos[:, None] + jnp.arange(s)[None]        # [B, S]
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         x = x + params["embed"]["pos"][
             jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
     bt = cache["block_table"][slot_ids]                          # [B, MP]
@@ -172,7 +172,7 @@ def paged_decode_step(params: Params, cache: PagedKVCache,
     lengths = cache["length"]
     bt = cache["block_table"]                                    # [S, MP]
     x = params["embed"]["tokens"][tokens][:, None].astype(cast)
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         x = x + params["embed"]["pos"][
             jnp.minimum(lengths, cfg.max_seq_len - 1)][:, None].astype(cast)
     positions = lengths[:, None]
@@ -251,7 +251,7 @@ def paged_verify_window(params: Params, cache: PagedKVCache,
     bt = cache["block_table"]                                    # [S, MP]
     x = params["embed"]["tokens"][tokens].astype(cast)           # [S,k,H]
     positions = lengths[:, None] + jnp.arange(kwin)[None]        # [S,k]
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         x = x + params["embed"]["pos"][
             jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
     scale = cfg.head_dim ** -0.5

@@ -73,7 +73,7 @@ def logical_param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "blocks": blocks,
         "final_norm": norm_spec(False),
     }
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         specs["embed"]["pos"] = P(None, "fsdp")
     if not cfg.tied_embeddings:
         specs["lm_head"] = P("fsdp", "tp")

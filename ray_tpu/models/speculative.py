@@ -63,7 +63,7 @@ def verify_window(params: Params, cache: KVCache, tokens: jnp.ndarray,
     lengths = cache["length"]                                   # [slots]
     x = params["embed"]["tokens"][tokens].astype(cast)          # [S,k,H]
     positions = lengths[:, None] + jnp.arange(k)[None]          # [S,k]
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         x = x + params["embed"]["pos"][
             jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
     scale = cfg.head_dim ** -0.5

@@ -93,6 +93,20 @@ def init_params(key: jax.Array, cfg: TransformerConfig,
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape, dtype) * (fan_in ** -0.5)).astype(dtype)
 
+    if cfg.layer_pattern:
+        # layers of two kinds: blocks stacked per kind (models/hybrid.py)
+        from . import hybrid
+        hybrid_params: Params = {
+            "embed": {"tokens": jax.random.normal(
+                next(keys), (cfg.vocab_size, h), dtype) * 0.02},
+            "blocks": hybrid.init_blocks(next(keys), cfg, dtype),
+            "final_norm": {"scale": jnp.ones((h,), dtype)},
+        }
+        if not cfg.tied_embeddings:
+            hybrid_params["lm_head"] = dense(next(keys),
+                                             (h, cfg.vocab_size), h)
+        return hybrid_params
+
     def norm_p():
         p = {"scale": jnp.ones((L, h), dtype)}
         if not cfg.use_rmsnorm:
@@ -142,7 +156,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig,
         "blocks": blocks,
         "final_norm": {"scale": jnp.ones((h,), dtype)},
     }
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         params["embed"]["pos"] = (
             jax.random.normal(next(keys), (cfg.max_seq_len, h), dtype) * 0.01)
     if not cfg.use_rmsnorm:
@@ -285,10 +299,20 @@ def embed_tokens(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
                  compute_dtype=jnp.bfloat16) -> jnp.ndarray:
     """Token (+ learned positional) embedding: [B, S] -> [B, S, H]."""
     x = params["embed"]["tokens"][tokens].astype(compute_dtype)
-    if not cfg.use_rope:
+    if cfg.learned_positions:
         s = tokens.shape[1]
         x = x + params["embed"]["pos"][:s][None].astype(compute_dtype)
     return x
+
+
+def refuse_layer_pattern(cfg: TransformerConfig, what: str):
+    """The training path has no layers of two kinds: the gated-delta-rule
+    kernels have no backward and ``apply_trunk`` scans one block kind."""
+    if cfg.layer_pattern:
+        raise NotImplementedError(
+            f"{what}: layer_pattern {cfg.layer_pattern} is served "
+            "(models/hybrid.py: prefill, decode_step), not trained: the "
+            "linear-attention kernels have no backward")
 
 
 def apply_trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
@@ -300,6 +324,7 @@ def apply_trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
 
     The trunk stops before the LM head so losses can run the head blockwise
     (see ``chunked_cross_entropy``) without ever materializing [B, S, V]."""
+    refuse_layer_pattern(cfg, "apply_trunk")
     b, s = tokens.shape
     x = embed_tokens(params, tokens, cfg, compute_dtype)
     # Positions are global sequence positions; under jit with a sequence-sharded

@@ -121,6 +121,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh,
     block-scaled wire payloads (see parallel/zero.py).  Both knobs off —
     the default — is byte-for-byte today's path.
     """
+    transformer.refuse_layer_pattern(cfg, "make_train_step")
     if grad_quant_enabled or zero_sharded_update:
         from . import zero
         return zero.make_dp_train_step(

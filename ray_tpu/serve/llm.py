@@ -126,6 +126,20 @@ class LLMEngine:
         from ray_tpu.models import transformer
 
         self.cfg = cfg
+        if cfg.layer_pattern:
+            # a cache of two kinds of state (models/hybrid.py) is dense,
+            # unsharded and decoded one token a step
+            for on, what in ((paged, "paged=True: the page arena holds keys "
+                              "and values only"),
+                             (spec_decode_enabled, "spec_decode_enabled: a "
+                              "rejected draft cannot be rolled out of a "
+                              "recurrent state"),
+                             (tp > 1, f"tp={tp}: no sharding rule covers the "
+                              "recurrent state or its kernels")):
+                if on:
+                    raise ValueError(
+                        f"layer_pattern {cfg.layer_pattern} does not run "
+                        f"with {what}")
         self.max_len = max_len or cfg.max_seq_len
         self.num_slots = num_slots
         self.buckets = tuple(b for b in buckets if b <= self.max_len)
@@ -168,6 +182,9 @@ class LLMEngine:
         else:
             self.cache = dec.init_kv_cache(cfg, num_slots + 1, self.max_len,
                                            self.compute_dtype)
+        # bytes by kind of per-slot state and layers by kind: shapes, so
+        # read once (the cache's arrays are donated at every dispatch)
+        self._cache_gauges = dec.cache_gauges(cfg, self.cache)
         # In-replica tensor parallelism: place params + cache with tp
         # shardings; jit propagates them, XLA inserts the collectives.
         self.tp = tp
@@ -354,6 +371,7 @@ class LLMEngine:
             else 0.0,
             "active_slots": len(self._active),
             "num_slots": self.num_slots,
+            **self._cache_gauges,
         }
         if self.paged:
             # total = ALLOCATABLE pages (page 0 is the reserved null page),

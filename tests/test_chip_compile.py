@@ -254,6 +254,80 @@ def test_cell_program_fits_at_16_layers(one_chip, as_tpu, program):
     assert total < HBM_GIB * 2**30, f"{total / 2**30:.2f} GiB"
 
 
+# ------------- layers of two kinds at the benchmark cell's size (PR 29)
+#
+# Olmo-Hybrid-7B widths, 12 layers (9 gated-delta-rule + 3 full attention),
+# 24 slots + the scratch slot x 4096: what ``serve-hybrid-longgen-closed``
+# runs.  K and V are 2.36 GB each and the float32 state 0.5 GB (0.66 GB with
+# 192 lanes padded to 256); none of them may be copied, and the state may not
+# be sliced a layer at a time either.
+
+HYBRID_SLOTS, HYBRID_MAX_LEN = 25, 4096
+
+
+def _hybrid_cfg():
+    return mcfg.TransformerConfig(
+        vocab_size=100352, num_layers=12, hidden_size=3840, num_heads=30,
+        num_kv_heads=30, mlp_size=11008, max_seq_len=65536, norm_eps=1e-6,
+        use_rope=False, no_positions=True, qk_norm=True, norm_on_output=True,
+        layer_pattern=("linear", "linear", "linear", "full"),
+        linear_num_heads=30, linear_key_dim=96, linear_value_dim=192,
+        linear_conv_width=4, linear_neg_eigval=True)
+
+
+@pytest.mark.parametrize("kernel", ["chunk_fwd", "recurrent_step"])
+def test_gdn_kernels_compile_at_published_head_sizes(one_chip, kernel):
+    """30 heads of 96 / 192: neither a multiple of the 128 lanes."""
+    from ray_tpu.ops import gated_delta as gd
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    nh, dk, dv, bf = 30, 96, 192, jnp.bfloat16
+    if kernel == "chunk_fwd":
+        b, t = 2, 2048
+        _, text = _compile(
+            lambda *a: gd.gdn_chunk_fwd(*a, use_kernel=True, interpret=False),
+            S((b, t, nh, dk), bf), S((b, t, nh, dk), bf), S((b, t, nh, dv), bf),
+            S((b, t, nh), jnp.float32), S((b, t, nh), jnp.float32),
+            S((b,), jnp.int32))
+    else:
+        slots = HYBRID_SLOTS
+        compiled, text = _compile(
+            lambda *a: gd.gdn_recurrent_step(*a, use_kernel=True,
+                                             interpret=False),
+            S((9, slots, nh, dk, dv), jnp.float32), S((), jnp.int32),
+            S((slots, nh, dk), bf), S((slots, nh, dk), bf),
+            S((slots, nh, dv), bf), S((slots, nh), jnp.float32),
+            S((slots, nh), jnp.float32), donate_argnums=(0,))
+        # in place: the donated stack is the output, nothing beside it
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+    assert KERNEL in text
+
+
+def test_hybrid_decode_program_holds_both_states_in_place(one_chip, as_tpu):
+    cfg = _hybrid_cfg()
+    args = _serve_shapes(one_chip, cfg, False, HYBRID_SLOTS, HYBRID_MAX_LEN)
+    compiled, text = _compile(
+        lambda p, c, st: decode.decode_state_loop(p, c, st, STEPS, cfg, 0,
+                                                  jnp.bfloat16),
+        *args, donate_argnums=(1, 2))
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+    # two K/V slabs of one layer (0.79 GB each) and no more
+    assert mem.temp_size_in_bytes < 1.8e9, mem.temp_size_in_bytes / 1e9
+    assert text.count(KERNEL) == 3          # one recurrent step a linear layer
+    kv = f"bf16[3,{HYBRID_SLOTS},{HYBRID_MAX_LEN},3840]"
+    state = f"f32[9,{HYBRID_SLOTS},30,96,192]"
+    assert kv in text and state in text
+    for stack in (kv, state):
+        assert not re.search(r"= " + re.escape(stack) + r"\S* copy\(", text)
+    # no layer's [slots, 30, 96, 192] slab is sliced out of the state stack
+    assert not re.search(
+        rf"= f32\[(1,)?{HYBRID_SLOTS},30,96,192\]\S* (dynamic-slice|copy)\(",
+        text)
+
+
 # ------------------------------------------------- the sharded train step
 
 @pytest.mark.parametrize("impl", ["auto", "splash"])

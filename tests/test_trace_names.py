@@ -206,3 +206,68 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
     spanned = sum(d for ph in ("admit", "dispatch", "fetch", "emit")
                   for d, _st in by_name[f"raytpu:engine.{ph}"]) / 1e9
     assert spanned == pytest.approx(counted, rel=0.2, abs=0.02)
+
+
+# ------------------------------------------------ layers of two kinds (PR 29)
+
+@pytest.fixture(scope="module")
+def hybrid_cfg():
+    from ray_tpu.models.config import TransformerConfig
+    return TransformerConfig(
+        vocab_size=256, num_layers=4, hidden_size=64, num_heads=4,
+        num_kv_heads=4, mlp_size=192, max_seq_len=64, use_rope=False,
+        no_positions=True, qk_norm=True, norm_on_output=True,
+        layer_pattern=("linear", "linear", "linear", "full"),
+        linear_num_heads=4, linear_key_dim=8, linear_value_dim=16,
+        linear_neg_eigval=True)
+
+
+def test_the_gdn_kernels_are_named():
+    """The names a device trace shows (``gdn_chunk_fwd [pallas]``,
+    ``gdn_recurrent_step [pallas]``), which the benchmark's gdn_* readers
+    spell out for themselves."""
+    import importlib.util
+    import os
+
+    from ray_tpu.ops import gated_delta as gd
+
+    assert gd.KERNEL_CHUNK_FWD == "gdn_chunk_fwd"
+    assert gd.KERNEL_RECURRENT_STEP == "gdn_recurrent_step"
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "layer_metrics", "_gdn.py")
+    spec = importlib.util.spec_from_file_location("_gdn_readers", path)
+    readers = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(readers)
+    assert (readers.CHUNK_FWD, readers.RECURRENT_STEP) == (
+        gd.KERNEL_CHUNK_FWD, gd.KERNEL_RECURRENT_STEP)
+    q = jnp.ones((1, 64, 2, 8), jnp.float32)
+    v = jnp.ones((1, 64, 2, 16), jnp.float32)
+    g = jnp.zeros((1, 64, 2), jnp.float32)
+    chunk = jax.make_jaxpr(lambda *a: gd.gdn_chunk_fwd(
+        *a, interpret=True))(q, q, v, g, g)
+    assert "gdn_chunk_fwd" in str(chunk)
+    step = jax.make_jaxpr(lambda *a: gd.gdn_recurrent_step(
+        *a, interpret=True))(jnp.zeros((2, 1, 2, 8, 16)), jnp.int32(1),
+                             q[:, 0], q[:, 0], v[:, 0], g[:, 0], g[:, 0])
+    assert "gdn_recurrent_step" in str(step)
+
+
+def test_hybrid_serve_programs_carry_their_scopes(hybrid_cfg):
+    eng = _engine(hybrid_cfg, prefill_batch=2)
+    try:
+        decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
+        assert _module_name(decode) == "jit_engine_decode"
+        assert {"attn", "mlp", "norm", "lm_head", "kv_write", "kv_read",
+                "gdn", "gdn_conv", "state_read",
+                "state_write"} <= _scopes(decode)
+        admit = eng._prefill_fn(16).lower(
+            eng.params, eng.cache, eng._state, *eng._admit_arrays([], 16, []))
+        assert _module_name(admit) == "jit_admit_fn"
+        assert {"attn", "mlp", "norm", "lm_head", "kv_write", "gdn",
+                "gdn_conv", "state_write"} <= _scopes(admit)
+        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
+        stats = eng.breakdown()
+        assert {"cache_kv_bytes", "cache_state_bytes", "linear_layers",
+                "full_layers"} <= set(stats)
+    finally:
+        eng.shutdown()
