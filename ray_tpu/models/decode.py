@@ -6,8 +6,10 @@ snapshot; ``@serve.batch`` is the primitive) — this is greenfield TPU-first
 code backing ``ray_tpu.serve.llm``.
 
 TPU-first design:
-* **Static shapes.**  The cache is a fixed [L, slots, max_len, KV, D] HBM
-  tensor; a "slot" is one sequence's reserved cache row.  Continuous batching
+* **Static shapes.**  The cache is a fixed [L, slots, max_len, KV * D] HBM
+  tensor (a position's KV heads side by side in one row: one layout for 8
+  heads and for 30, ``ops/decode_attention.py``); a "slot" is one
+  sequence's reserved cache row.  Continuous batching
   admits/retires sequences by slot index — tensor shapes never change, so jit
   compiles exactly two programs (one prefill per length bucket, one decode
   step) and reuses them forever.
@@ -23,8 +25,10 @@ TPU-first design:
   is never *read* because decode masks by per-slot length (causality makes
   the writes at pad positions harmless: real positions never attend to them).
 * **Decode** is one token per active slot: q at position `len`, attention
-  over the cache row masked to positions <= len.  The [slots, H, max_len]
-  score tensor is tiny; XLA fuses the mask+softmax into the two matmuls.
+  over the slot's rows up to it, read where they lie in the stack by one
+  kernel that takes the layer index and the live lengths
+  (``ops.decode_attention.decode_attn``): no layer's slab is sliced out,
+  and blocks past a slot's length, or of an inactive slot, are not fetched.
 
 No torch, no dynamic shapes, no per-request Python in the hot loop.
 """
@@ -50,8 +54,8 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
     if cfg.layer_pattern:
         from . import hybrid
         return hybrid.init_cache(cfg, num_slots, max_len, dtype)
-    shape = (cfg.num_layers, num_slots, max_len, cfg.num_kv_heads,
-             cfg.head_dim)
+    shape = (cfg.num_layers, num_slots, max_len,
+             cfg.num_kv_heads * cfg.head_dim)
     return {
         "k": jnp.zeros(shape, dtype),
         "v": jnp.zeros(shape, dtype),
@@ -190,7 +194,8 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
                                       lp["attn"], cfg, positions)
         x = x + out
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
-        return x, (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype))
+        return x, (k.reshape(b, s, -1).astype(cache["k"].dtype),
+                   v.reshape(b, s, -1).astype(cache["v"].dtype))
 
     x, (k_rows, v_rows) = jax.lax.scan(body, x, params["blocks"])
     # write every layer's K/V into the slots, in place on the donated cache
@@ -214,78 +219,33 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
 # Decode step
 # ---------------------------------------------------------------------------
 
-def decode_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths):
+def decode_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
+                     active):
     """One layer's attention for one new token a slot.  y: [slots, 1, H];
-    k_all, v_all: the stacked cache [layers, slots, max_len, NKV, D] (or
-    with heads and head size merged, [layers, slots, max_len, NKV * D]:
-    ``_attend_merged``), of which this is layer ``i``.  Appends the token's
-    K/V at ``[i, slot, length]`` in place and attends over the layer's rows
-    up to it.  Returns (attention after its output projection [slots, 1,
-    H], k_all, v_all)."""
+    k_all, v_all: the stacked cache [layers, slots, max_len, NKV * D], of
+    which this is layer ``i``.  Appends the token's K/V at ``[i, slot,
+    length]`` in place and attends over the layer's rows up to it, of the
+    ``active`` slots only (an inactive slot keeps a stale length; it is
+    read as length 0 and its output is zeros).  Returns (attention after
+    its output projection [slots, 1, H], k_all, v_all)."""
+    from ..ops.decode_attention import decode_attn
     n_slots, cast = y.shape[0], y.dtype
     max_len = k_all.shape[2]
-    reps = cfg.num_heads // cfg.num_kv_heads
-    # mask over cache positions: <= current length (the new token's position)
-    pos_mask = (jnp.arange(max_len)[None] <= lengths[:, None])  # [slots, max_len]
     slot_idx = jnp.arange(n_slots)
     q, k, v = _qkv(y, ap, cfg, lengths[:, None])  # q:[S,1,NH,D] k/v:[S,1,NKV,D]
-    row = k_all.shape[3:]             # (NKV, D), or (NKV * D,) merged
     # append at position `length` (one row per slot of this layer)
     with jax.named_scope("kv_write"):
         k_all = k_all.at[i, slot_idx, lengths].set(
-            k[:, 0].reshape((n_slots,) + row).astype(k_all.dtype))
+            k.reshape(n_slots, -1).astype(k_all.dtype))
         v_all = v_all.at[i, slot_idx, lengths].set(
-            v[:, 0].reshape((n_slots,) + row).astype(v_all.dtype))
-    # attention over the cache row
+            v.reshape(n_slots, -1).astype(v_all.dtype))
+    # positions that count: up to and with the new token's
+    live = jnp.where(active, jnp.minimum(lengths + 1, max_len), 0)
     with jax.named_scope("kv_read"):
-        k_lay = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
-        v_lay = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
-        if len(row) == 1:
-            attn = _attend_merged(q[:, 0], k_lay, v_lay, pos_mask, cfg)
-        else:
-            qh = q[:, 0].reshape(n_slots, cfg.num_kv_heads, reps,
-                                 cfg.head_dim)
-            scores = jnp.einsum("sgrd,smgd->sgrm", qh.astype(jnp.float32),
-                                k_lay.astype(jnp.float32)) \
-                * cfg.head_dim ** -0.5
-            if cfg.attn_logit_softcap:
-                c = cfg.attn_logit_softcap
-                scores = c * jnp.tanh(scores / c)
-            scores = jnp.where(pos_mask[:, None, None, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            attn = jnp.einsum("sgrm,smgd->sgrd", probs,
-                              v_lay.astype(jnp.float32))
-        attn = attn.reshape(n_slots, 1, cfg.num_heads * cfg.head_dim)
+        attn = decode_attn(q[:, 0], k_all, v_all, i, live, cfg.num_kv_heads,
+                           cfg.attn_logit_softcap)
+    attn = attn.reshape(n_slots, 1, cfg.num_heads * cfg.head_dim)
     return _proj_out(attn.astype(cast), ap, cast), k_all, v_all
-
-
-def _attend_merged(q, k_lay, v_lay, pos_mask, cfg: TransformerConfig):
-    """Decode attention over cache rows that hold all KV heads side by side,
-    ``k_lay``, ``v_lay`` [slots, max_len, NKV * D]; q [slots, NH, D].  A head
-    count that is no multiple of the 8-row tile (30) cannot sit before the
-    head size without padding, and the TPU compiler then holds the stack in
-    another layout at a program's edge than inside it and transposes all of
-    it in and out of every dispatch (sandbox compile for a described v5e,
-    PR 29).  Merged rows have one layout.  Each head's scores are a matmul
-    of the whole row with its query placed in its own head's channels and
-    zeros elsewhere: NKV times the needed FLOPs on the MXU, none of them
-    felt beside the read of the rows, and no reshape of the rows.
-    Returns [slots, NH, D] float32."""
-    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    own = (jnp.arange(nkv * hd)[None, :] // hd
-           == jnp.arange(nh)[:, None] // (nh // nkv))         # [NH, NKV * D]
-    q_row = jnp.where(own[None], jnp.tile(q, (1, 1, nkv)), 0)
-    scores = jnp.einsum("shc,smc->shm", q_row.astype(k_lay.dtype), k_lay,
-                        preferred_element_type=jnp.float32) * hd ** -0.5
-    if cfg.attn_logit_softcap:
-        c = cfg.attn_logit_softcap
-        scores = c * jnp.tanh(scores / c)
-    scores = jnp.where(pos_mask[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    full = jnp.einsum("shm,smc->shc", probs.astype(v_lay.dtype), v_lay,
-                      preferred_element_type=jnp.float32)
-    return jnp.where(own[None], full, 0.0).reshape(
-        q.shape[0], nh, nkv, hd).sum(axis=2)
 
 
 def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
@@ -311,11 +271,11 @@ def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
             lengths, cfg.max_seq_len - 1)][:, None].astype(cast)
 
     def body(carry, layer):
-        x, k_all, v_all = carry         # k/v_all: [L, slots, max_len, NKV, D]
+        x, k_all, v_all = carry         # k/v_all: [L, slots, max_len, NKV*D]
         lp, i = layer
         out, k_all, v_all = decode_attention(
             _norm(x, lp["attn_norm"], cfg), lp["attn"], cfg, k_all, v_all,
-            i, lengths)
+            i, lengths, active)
         x = x + out
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
         return (x, k_all, v_all), None

@@ -6,8 +6,7 @@ same three calls on a cache tree of two kinds of state:
 
 * ``k``, ``v``: [full_layers, slots, max_len, NKV * D], the dense cache of
   ``decode.py`` with rows for the full-attention layers only, written and
-  read by ``decode.prefill_attention`` / ``decode_attention``, a position's
-  heads side by side in one row (``decode._attend_merged`` says why);
+  read by ``decode.prefill_attention`` / ``decode_attention``;
 * ``state``: [linear_layers, slots, heads, key_dim, value_dim] float32, the
   delta rule's state (``ops/gated_delta.py``), constant in the context;
 * ``conv``: [linear_layers, slots, conv_width - 1, channels], the last
@@ -197,8 +196,16 @@ def _mlp_branch(x, lp, cfg: TransformerConfig):
     return x + _norm(_mlp(x, lp, cfg), lp["mlp_norm"], cfg)
 
 
-def _layer(tree, j: int):
-    return jax.tree.map(lambda a: a[j], tree)
+def _layer_params(blocks: Params, kind: str, index):
+    """Layer ``index`` (traced) of a kind's stack [periods, layers a period,
+    ...], by one dynamic index of the stack flattened: a slice its matmul
+    reads where it lies.  A period's slice taken first (the stack as a
+    scan's xs) is copied out whole: every weight of the linear layers once
+    a decode step, 35% of the hybrid cell's chip (PERF.md, PR 30)."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(
+            a.reshape((-1,) + a.shape[2:]), index, 0, keepdims=False),
+        blocks[kind])
 
 
 # ---------------------------------------------------------------------------
@@ -244,12 +251,15 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
         x = x + _norm(out, lp["attn_norm"], cfg)
         return _mlp_branch(x, lp, cfg), (k, v)
 
-    def period(x, pp):
+    per_period = dict(zip(("linear", "full"), _counts(cfg)[1:]))
+
+    def period(x, p):
         rows = {"linear": [], "full": []}
         at = {"linear": 0, "full": 0}
         for kind in cfg.layer_pattern:
             layer = linear_layer if kind == "linear" else full_layer
-            x, out = layer(x, _layer(pp[kind], at[kind]))
+            x, out = layer(x, _layer_params(
+                params["blocks"], kind, p * per_period[kind] + at[kind]))
             rows[kind].append(out)
             at[kind] += 1
         # per kind, the layers' (a, b) pairs stacked: ([n, ...], [n, ...])
@@ -258,7 +268,7 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
         return x, stacked["linear"] + stacked["full"]
 
     x, (states, tails, k_rows, v_rows) = jax.lax.scan(
-        period, x, params["blocks"])
+        period, x, jnp.arange(cfg.num_periods))
     merge = lambda a: a.reshape((-1,) + a.shape[2:])             # noqa: E731
     new = dict(cache)
     # every layer's rows into the slots, in place on the donated cache (the
@@ -324,28 +334,28 @@ def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
 
     def full_layer(x, lp, fi, k_all, v_all):
         out, k_all, v_all = decode_attention(x, lp["attn"], cfg, k_all, v_all,
-                                             fi, lengths)
+                                             fi, lengths, active)
         x = x + _norm(out, lp["attn_norm"], cfg)
         return _mlp_branch(x, lp, cfg), k_all, v_all
 
-    def period(carry, xs):
+    per_period = {"linear": n_lin, "full": n_full}
+
+    def period(carry, p):
         x, k_all, v_all, state, conv = carry
-        pp, p = xs
         at = {"linear": 0, "full": 0}
         for kind in cfg.layer_pattern:
-            lp = _layer(pp[kind], at[kind])
+            index = p * per_period[kind] + at[kind]   # in its kind's stack
+            lp = _layer_params(params["blocks"], kind, index)
             if kind == "linear":
-                x, state, conv = linear_layer(
-                    x, lp, p * n_lin + at[kind], state, conv)
+                x, state, conv = linear_layer(x, lp, index, state, conv)
             else:
-                x, k_all, v_all = full_layer(
-                    x, lp, p * n_full + at[kind], k_all, v_all)
+                x, k_all, v_all = full_layer(x, lp, index, k_all, v_all)
             at[kind] += 1
         return (x, k_all, v_all, state, conv), None
 
     (x, k_new, v_new, state, conv), _ = jax.lax.scan(
         period, (x, cache["k"], cache["v"], cache["state"], cache["conv"]),
-        (params["blocks"], jnp.arange(cfg.num_periods)))
+        jnp.arange(cfg.num_periods))
     x = _norm(x, params["final_norm"], cfg)
     logits = lm_head_logits(params, x[:, 0], cfg)
     cache = {

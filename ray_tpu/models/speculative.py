@@ -77,19 +77,23 @@ def verify_window(params: Params, cache: KVCache, tokens: jnp.ndarray,
         lp, k_lay, v_lay = layer
         y = _norm(x, lp["attn_norm"], cfg)
         q, kk, vv = _qkv(y, lp["attn"], cfg, positions)  # [S,k,N*,D]
-        # append the whole window's K/V rows (scatter at length..length+k-1)
-        k_lay = k_lay.at[row, positions].set(kk.astype(k_lay.dtype))
-        v_lay = v_lay.at[row, positions].set(vv.astype(v_lay.dtype))
+        # append the whole window's K/V rows (scatter at length..length+k-1);
+        # the cache's rows hold the KV heads side by side
+        k_lay = k_lay.at[row, positions].set(
+            kk.reshape(n_slots, k, -1).astype(k_lay.dtype))
+        v_lay = v_lay.at[row, positions].set(
+            vv.reshape(n_slots, k, -1).astype(v_lay.dtype))
+        heads = (n_slots, max_len, cfg.num_kv_heads, cfg.head_dim)
         qh = q.reshape(n_slots, k, cfg.num_kv_heads, reps, cfg.head_dim)
         scores = jnp.einsum("skgrd,smgd->skgrm", qh.astype(jnp.float32),
-                            k_lay.astype(jnp.float32)) * scale
+                            k_lay.reshape(heads).astype(jnp.float32)) * scale
         if cfg.attn_logit_softcap:
             c = cfg.attn_logit_softcap
             scores = c * jnp.tanh(scores / c)
         scores = jnp.where(pos_mask[:, :, None, None, :], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1)
         attn = jnp.einsum("skgrm,smgd->skgrd", probs,
-                          v_lay.astype(jnp.float32))
+                          v_lay.reshape(heads).astype(jnp.float32))
         attn = attn.reshape(n_slots, k, cfg.num_heads * cfg.head_dim)
         x = x + _proj_out(attn.astype(cast), lp["attn"], cast)
         x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)

@@ -1,0 +1,258 @@
+"""Decode attention over the stacked KV cache where it lies: one new token a
+slot against that slot's rows of one layer, as a Pallas TPU kernel and its
+plain twin.
+
+The cache is ``[layers, slots, max_len, NKV * D]``, a position's KV heads
+side by side in one row (one layout for 8 heads and for 30: a head is a
+slice of ``D`` lanes).  ``decode_attn`` takes the layer index and each
+slot's live length by scalar prefetch, so that
+
+* no layer's slab leaves the stack: the block index map picks ``[layer,
+  slot, block]`` itself (what ``gdn_recurrent_step`` does for the recurrent
+  state);
+* blocks beyond a slot's live length, and every block of a slot of length
+  0 (inactive), are neither fetched nor computed: the grid walks a list of
+  the live blocks alone (``_plan``), slot after slot and as long as the
+  list, so that each step's fetch runs under the step before it.
+
+One grid axis, the work list, sequential; online softmax over a slot's
+blocks with the running max, sum and accumulator in float32 VMEM scratch;
+operands as stored (bf16) on the MXU with float32 accumulation, softmax in
+float32.  Each head's scores are a matmul of the whole row with
+its query placed in its own head's channels and zeros elsewhere (NKV times
+the needed FLOPs, none of them felt beside the read of the rows, and no
+reshape of the rows); the accumulator keeps whole rows and a head's own
+channels are picked once, at the end.
+
+The twin reshapes a layer's rows to heads and is plain einsums in the same
+precisions.  It is the CPU path, and the path under a mesh of more than one
+device: the compiler cannot partition a Mosaic kernel, and the twin's
+einsums split by KV heads as the cache does.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import resolve_interpret
+
+#: the kernel's name as a device trace shows it (``decode_attn [pallas]``);
+#: pinned by tests/test_trace_names.py, read by the benchmark's
+#: ``decode_attn_roofline``
+KERNEL_DECODE_ATTN = "decode_attn"
+
+NEG = -1e30
+F32 = jnp.float32
+#: bytes of one K (or V) block in VMEM, at most; K and V, double-buffered,
+#: take four of them
+BLOCK_BYTES = 2 << 20
+#: positions a block, at most
+BLOCK_LEN = 512
+#: the kernel's VMEM: four blocks, the float32 accumulator and the
+#: products of one block, with room; the chip has 128 MiB
+VMEM_LIMIT = 48 << 20
+
+
+def block_len(max_len: int, row_bytes: int) -> int:
+    """Positions one grid step reads of a slot: the largest divisor of
+    ``max_len`` that is a multiple of 16 (a bf16 tile's rows), at most
+    ``BLOCK_LEN`` and at most ``BLOCK_BYTES`` of rows ``row_bytes`` wide;
+    the whole row where ``max_len`` has no such divisor (tiny caches)."""
+    cap = min(BLOCK_LEN, BLOCK_BYTES // row_bytes, max_len)
+    fits = [t for t in range(16, cap + 1, 16) if max_len % t == 0]
+    return max(fits) if fits else max_len
+
+
+def _softcap(s, softcap: float):
+    return softcap * jnp.tanh(s / softcap) if softcap else s
+
+
+# ---------------------------------------------------------------------------
+# The twin: plain attention over one layer's rows, heads apart
+# ---------------------------------------------------------------------------
+
+def decode_attn_jnp(q, k_all, v_all, layer, live, num_kv_heads: int,
+                    softcap: float = 0.0):
+    """The twin of ``decode_attn``; shapes as there."""
+    slots, nh, hd = q.shape
+    max_len = k_all.shape[2]
+    k, v = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            .reshape(slots, max_len, num_kv_heads, hd)
+            for a in (k_all, v_all))
+    qh = q.reshape(slots, num_kv_heads, nh // num_kv_heads, hd)
+    s = jnp.einsum("sgrd,smgd->sgrm", qh.astype(k.dtype), k,
+                   preferred_element_type=F32) * hd ** -0.5
+    seen = jnp.arange(max_len)[None, :] < live[:, None]      # [slots, max_len]
+    s = jnp.where(seen[:, None, None, :], _softcap(s, softcap), NEG)
+    p = jnp.where(seen[:, None, None, :],
+                  jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
+    o = jnp.einsum("sgrm,smgd->sgrd", p.astype(v.dtype), v,
+                   preferred_element_type=F32)
+    o = o / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    return o.reshape(slots, nh, hd).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The kernel
+# ---------------------------------------------------------------------------
+
+def _block_update(q_row, k, v, start, live, m, l, acc, *, scale: float,
+                  softcap: float):
+    """One block of one slot into the running softmax.  q_row [NH', C]
+    (each head's query in its own channels); k, v [T, C], rows ``start`` to
+    ``start + T`` of the slot, of which those before ``live`` count; m, l
+    [NH', 1], acc [NH', C] float32.  Returns (m, l, acc)."""
+    s = jax.lax.dot_general(q_row, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32) * scale   # [NH', T]
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(pos < live, _softcap(s, softcap), NEG)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+    acc = alpha * acc + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=F32)
+    return m_new, l, acc
+
+
+def _kernel(layer_ref, live_ref, slot_ref, block_ref, total_ref, q_ref,
+            k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, block: int,
+            heads_a_group: int, num_kv_heads: int, scale: float,
+            softcap: float):
+    del layer_ref                               # the index maps' only
+    ti = pl.program_id(0)
+    si, bi = slot_ref[ti], block_ref[ti]
+
+    @pl.when(ti < total_ref[0])
+    def _item():
+        @pl.when(bi == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        m, l, acc = _block_update(
+            q_ref[0], k_ref[0, 0], v_ref[0, 0], bi * block, live_ref[si],
+            m_ref[...], l_ref[...], acc_ref[...], scale=scale,
+            softcap=softcap)
+        m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
+
+        @pl.when(bi == (live_ref[si] - 1) // block)   # the slot's last
+        def _flush():
+            rows = acc / l                                    # [NH', C]
+            hd = o_ref.shape[2]
+            group = jax.lax.broadcasted_iota(
+                jnp.int32, (rows.shape[0], hd), 0) // heads_a_group
+            o = jnp.zeros((rows.shape[0], hd), F32)
+            for g in range(num_kv_heads):       # a head's own channels
+                o = o + jnp.where(group == g, rows[:, g * hd:(g + 1) * hd],
+                                  0.0)
+            o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _plan(live, block: int, num_blocks: int):
+    """The grid's work list, from the live lengths [slots]: the live blocks
+    of the slots that have any, slot after slot (``slot_of``, ``block_of``
+    [slots * num_blocks], of which the first ``total`` count), so that the
+    grid is as long as the list and every step but the last fetches the
+    next one while it computes."""
+    work = -(-live // block)                 # live blocks a slot
+    ends = jnp.cumsum(work)
+    total = ends[-1]
+    item = jnp.minimum(jnp.arange(live.shape[0] * num_blocks, dtype=jnp.int32),
+                       jnp.maximum(total - 1, 0))
+    slot_of = jnp.minimum(jnp.searchsorted(ends, item, side="right"),
+                          live.shape[0] - 1).astype(jnp.int32)
+    return slot_of, (item - (ends - work)[slot_of]).astype(jnp.int32), total
+
+
+def _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads: int,
+                        softcap: float, interpret: bool):
+    slots, nh, hd = q.shape
+    max_len, chan = k_all.shape[2:]
+    reps = nh // num_kv_heads
+    nhp = -(-nh // 16) * 16
+    block = block_len(max_len, chan * k_all.dtype.itemsize)
+    num_blocks = max_len // block
+    live = live.astype(jnp.int32)
+    # each head's query in its own head's channels, zeros elsewhere; rows
+    # past NH belong to no head
+    own = (jnp.arange(chan)[None, :] // hd
+           == jnp.arange(nhp)[:, None] // reps)               # [NH', C]
+    q_row = jnp.where(own[None], jnp.tile(jnp.pad(
+        q, ((0, 0), (0, nhp - nh), (0, 0))), (1, 1, num_kv_heads)),
+        0).astype(k_all.dtype)
+    slot_of, block_of, total = _plan(live, block, num_blocks)
+
+    def rows(ti, layer, live, slot_of, block_of, total):
+        return (layer[0], slot_of[ti], block_of[ti], 0)
+
+    def per_slot(ti, layer, live, slot_of, block_of, total):
+        return (slot_of[ti], 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_kernel, block=block, heads_a_group=reps,
+                          num_kv_heads=num_kv_heads, scale=hd ** -0.5,
+                          softcap=softcap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            # as long as the work list; with nothing live, one step that
+            # does nothing
+            grid=(jnp.maximum(total, 1),),
+            in_specs=[
+                pl.BlockSpec((1, nhp, chan), per_slot),
+                pl.BlockSpec((1, 1, block, chan), rows),
+                pl.BlockSpec((1, 1, block, chan), rows),
+            ],
+            out_specs=pl.BlockSpec((1, nhp, hd), per_slot),
+            scratch_shapes=[pltpu.VMEM((nhp, 1), F32),
+                            pltpu.VMEM((nhp, 1), F32),
+                            pltpu.VMEM((nhp, chan), F32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((slots, nhp, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=KERNEL_DECODE_ATTN,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), live, slot_of, block_of,
+      jnp.reshape(total, (1,)).astype(jnp.int32), q_row, k_all, v_all)
+    # a slot with nothing live is on no step of the grid: its rows of the
+    # output were never written
+    return jnp.where(live[:, None, None] > 0, out[:, :nh], 0)
+
+
+def decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
+                softcap: float = 0.0, use_kernel: Optional[bool] = None,
+                interpret: Optional[bool] = None):
+    """Attention of one new token a slot over layer ``layer`` of the stacked
+    cache.
+
+    q: [slots, NH, D]; k_all, v_all: [layers, slots, max_len, NKV * D], the
+    token's own row already written; layer: int32 scalar (traced or not);
+    live: [slots] int32, the positions of each slot that count, the new
+    token's among them (0: the slot is inactive, its output is zeros).
+    Returns [slots, NH, D] in q's dtype.  Only the live blocks of ``layer``
+    are read: no slab leaves the stack.
+
+    ``use_kernel=None`` takes the Pallas kernel on a TPU and the twin
+    elsewhere and under a mesh of more than one device (``LLMEngine`` with
+    ``tp > 1`` enters its mesh: the compiler cannot partition the kernel);
+    ``interpret=True`` runs the kernel interpreted (tests)."""
+    if use_kernel is None:
+        use_kernel = bool(interpret) or (
+            jax.default_backend() == "tpu"
+            and jax.sharding.get_abstract_mesh().size <= 1)
+    if not use_kernel:
+        return decode_attn_jnp(q, k_all, v_all, layer, live, num_kv_heads,
+                               softcap)
+    interpret = resolve_interpret(interpret, "decode_attn")
+    return _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads,
+                               softcap, interpret)

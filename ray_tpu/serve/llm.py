@@ -49,8 +49,8 @@ _FLUSH = object()
 class GenRequest:
     __slots__ = ("tokens", "max_tokens", "temperature", "top_k", "eos_id",
                  "out", "slot", "generated", "submitted_at", "admitted_at",
-                 "emit_times", "pages", "prompt_len", "deployment",
-                 "trace_ctx", "span_parent")
+                 "emit_times", "pages", "prompt_len", "cache_len",
+                 "deployment", "trace_ctx", "span_parent")
 
     def __init__(self, tokens: List[int], max_tokens: int,
                  temperature: float, top_k: int, eos_id: Optional[int]):
@@ -64,6 +64,9 @@ class GenRequest:
         self.pages: List[int] = []
         self.generated = 0
         self.prompt_len = len(tokens)
+        #: positions of this request the device's cache holds once every
+        #: dispatched program has run: the prompt, then one a decode step
+        self.cache_len = len(tokens)
         # one monotonic stamp per stage: submit (the caller's thread), the
         # dispatch of the admit that carries the request, and every token's
         # _emit (engine thread; emit_times[0] is the first token's).  The
@@ -185,6 +188,11 @@ class LLMEngine:
         # bytes by kind of per-slot state and layers by kind: shapes, so
         # read once (the cache's arrays are donated at every dispatch)
         self._cache_gauges = dec.cache_gauges(cfg, self.cache)
+        if not paged:
+            from ray_tpu.ops.decode_attention import block_len
+            rows = self.cache["k"]
+            self._kv_block = block_len(
+                self.max_len, rows.shape[-1] * rows.dtype.itemsize)
         # In-replica tensor parallelism: place params + cache with tp
         # shardings; jit propagates them, XLA inserts the collectives.
         self.tp = tp
@@ -288,6 +296,11 @@ class LLMEngine:
         # position of the [prefill_batch, bucket] arrays that carried them
         self.admit_tokens_real = 0
         self.admit_tokens_padded = 0
+        # what decode attention reads of the cache it holds (dense cache,
+        # plain decode): per step the live positions of the active slots,
+        # rounded up to the kernel's blocks, against every slot's max_len
+        self.kv_positions_read = 0
+        self.kv_positions_held = 0
         # request stages (engine thread): submit -> dispatch of the admit,
         # that dispatch -> first token on the request's queue
         self.admitted_requests = 0
@@ -303,7 +316,7 @@ class LLMEngine:
         # monotonic -> wall, taken once: the task-event spans want wall time
         self._wall = time.time() - time.monotonic()
         self._obs_dep = "-"  # deployment tag, learned from first request
-        self._thread = threading.Thread(target=self._loop, daemon=True,
+        self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="llm-engine")
         self._thread.start()
         if warmup_buckets:
@@ -422,6 +435,8 @@ class LLMEngine:
             "first_token_wait_s": self.first_token_wait_s,
             "admit_tokens_real": self.admit_tokens_real,
             "admit_tokens_padded": self.admit_tokens_padded,
+            "kv_positions_read": self.kv_positions_read,
+            "kv_positions_held": self.kv_positions_held,
         }
         for ph in ENGINE_PHASES:
             out[f"loop_{ph}_s"] = loop_s[ph]
@@ -475,7 +490,9 @@ class LLMEngine:
                     or "b_in" in path:
                 return at(dims - 1)
             if path.endswith("/k") or path.endswith("/v"):
-                return at(3)                 # [L, P|S, len, NKV, D]
+                # dense [L, S, len, NKV * D], paged [L, P, page, NKV, D]:
+                # whole KV heads a chip either way
+                return at(3)
             return P()                       # replicate
 
         def place(tree):
@@ -676,6 +693,16 @@ class LLMEngine:
             self._spec_fns[k] = ent
         return ent
 
+    def _run(self):
+        """The engine thread.  With ``tp > 1`` its programs are traced under
+        the engine's mesh: a dispatch that would hand the compiler a Pallas
+        kernel to partition sees it there and keeps to its twin
+        (``ops/decode_attention.py``)."""
+        if self.mesh is None:
+            return self._loop()
+        with self._jax.set_mesh(self.mesh):
+            return self._loop()
+
     def _loop(self):
         # Every stretch of this thread's time belongs to one of five phases
         # (_Phase: admit, dispatch, fetch, emit, idle), one span and one
@@ -873,6 +900,24 @@ class LLMEngine:
             self.params, self.cache, self._state)
         self._unfetched.append((emitted, dict(self._active), None))
         self.steps += self.steps_per_dispatch
+        if not self.paged:
+            self._count_kv_positions()
+
+    def _count_kv_positions(self):
+        """One decode dispatch's ``kv_positions_read`` / ``_held``.  The
+        device runs a slot for as many steps as its budget has left
+        (``_admit_arrays``; an EOS it samples is not known here yet) and
+        reads, at a step that finds ``n`` positions cached, the blocks that
+        hold ``n + 1``."""
+        steps, block = self.steps_per_dispatch, self._kv_block
+        for r in self._active.values():
+            budget = min(r.max_tokens, self.max_len - r.prompt_len)
+            run = min(steps, budget - 1 - (r.cache_len - r.prompt_len))
+            self.kv_positions_read += sum(
+                -(-(r.cache_len + j + 1) // block) * block
+                for j in range(run))
+            r.cache_len += max(run, 0)
+        self.kv_positions_held += steps * (self.num_slots + 1) * self.max_len
 
     def _drain_spec(self, payload, snapshot):
         """Fetch one speculative dispatch, then emit it."""

@@ -150,6 +150,29 @@ def test_flash_kernels_are_named():
         re.findall(r"flash_\w+", str(jaxpr)))
 
 
+def test_the_decode_attention_kernel_is_named():
+    """``decode_attn [pallas]`` in a device trace; the benchmark's
+    ``decode_attn_roofline`` spells the name out for itself."""
+    import importlib.util
+    import os
+
+    from ray_tpu.ops import decode_attention as da
+
+    assert da.KERNEL_DECODE_ATTN == "decode_attn"
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "layer_metrics",
+        "decode_attn_roofline.py")
+    spec = importlib.util.spec_from_file_location("_decode_attn_reader", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert reader.DECODE_ATTN == da.KERNEL_DECODE_ATTN
+    stack = jnp.ones((2, 3, 32, 4 * 8), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: da.decode_attn(
+        q, k, v, jnp.int32(1), jnp.asarray([1, 0, 32]), 4, interpret=True))(
+            jnp.ones((3, 8, 8), jnp.float32), stack, stack)
+    assert "decode_attn" in str(jaxpr)
+
+
 def test_named_jit_names_a_lambda():
     fn = profiler.named_jit("some_program", lambda x, y: x + y,
                             donate_argnums=(0,))
@@ -167,12 +190,16 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
     eng = _engine(tiny_cfg)
     try:
         eng.warmup(16)
+        time.sleep(0.1)      # the warm-up's last dispatches drain outside it
         with jax.profiler.trace(str(tmp_path)):
             c0 = eng.counters()
             outs = [eng.generate([1, 2, 3 + i], max_tokens=9)
                     for i in range(3)]
+            # idle passes inside the capture; the dispatches the engine ran
+            # ahead of the last answer drain before the second snapshot (two
+            # of nine: their fetches would be the test's whole tolerance)
+            time.sleep(0.1)
             c1 = eng.counters()
-            time.sleep(0.1)              # idle passes inside the capture
         assert [len(o) for o in outs] == [9, 9, 9]
     finally:
         eng.shutdown()
