@@ -261,19 +261,15 @@ def _decode_numerics(cfg, dev):
     paged_cache = paged_decode.init_paged_cache(cfg, 2 * mp + 1, 64, 2, mp)
     paged_cache["block_table"] = jnp.arange(1, 2 * mp + 1,
                                             dtype=jnp.int32).reshape(2, mp)
-    paged_prefill = jax.jit(lambda p, c, t, ln, sl: paged_decode.paged_prefill(
-        p, c, t, ln, sl, jnp.zeros((2,), jnp.int32), cfg))
-    for name, pre, cache, step in (
-            ("dense", prefill, dense_cache, decode.decode_step),
-            ("paged", paged_prefill, paged_cache,
-             paged_decode.paged_decode_step)):
-        cache, logits = pre(params, cache, tokens, lens, slots)
+    # the same two calls on either tree: the cache says what it is
+    step = jax.jit(lambda p, c, t, a: decode.decode_step(p, c, t, a, cfg))
+    for name, cache in (("dense", dense_cache), ("paged", paged_cache)):
+        cache, logits = prefill(params, cache, tokens, lens, slots)
         d_pre = float(np.abs(np.asarray(logits) - ref_prefill).max())
         nxt = np.asarray(logits).argmax(-1).astype(np.int32)
         toks2 = tokens.copy()
         toks2[np.arange(2), lens] = nxt
-        cache, logits2 = jax.jit(lambda p, c, t, a: step(p, c, t, a, cfg))(
-            params, cache, nxt, jnp.ones((2,), bool))
+        cache, logits2 = step(params, cache, nxt, jnp.ones((2,), bool))
         d_dec = float(np.abs(np.asarray(logits2) - ref_at(toks2, lens)).max())
         say("decode", dev, f"{name}: max|logit diff| prefill={d_pre:.4f} "
             f"decode_step={d_dec:.4f} (tolerance {TOL_LOGITS}) "

@@ -1,25 +1,40 @@
-"""Autoregressive decoding with a slot-based KV cache — the inference side of
+"""Autoregressive decoding on a device-resident cache: the inference side of
 the transformer (training side: ``transformer.apply_trunk``).
 
 The reference has no LLM inference engine (SURVEY §2.7 note: no vLLM in the
 snapshot; ``@serve.batch`` is the primitive) — this is greenfield TPU-first
 code backing ``ray_tpu.serve.llm``.
 
+**One walk over the layers.**  ``layer_stack`` is the serving path's only
+forward pass: embedding, one ``lax.scan`` over the layers, the block's
+wiring, the MLP, the final norm and the head.  What differs between a
+prefill, a decode step and a speculative window, and between kinds of cache,
+is the *mixer* it is handed for each kind of layer: a plain function
+``mixer(y, layer_weights, layer_index, carry) -> (out, carry, ys)`` closed
+over what it needs, and the only code that knows a cache.  The cache tree
+says which mixers apply (``prefill``, ``window_step``):
+
+* rows, ``k`` and ``v`` [layers, slots, max_len, KV * D] (a position's KV
+  heads side by side in one row: one layout for 8 heads and for 30,
+  ``ops/decode_attention.py``; a "slot" is one sequence's reserved rows):
+  ``prefill_attention`` over whole right-padded prompts, ``decode_attention``
+  for the W tokens a slot appends;
+* pages, a tree with a ``block_table`` (``paged_decode.py``):
+  ``paged_decode.page_attention`` for both;
+* a recurrent ``state`` and a ``conv`` tail beside the rows, where the
+  configuration has a ``layer_pattern`` (``hybrid.py``):
+  ``hybrid.linear_prefill`` / ``linear_step`` for its "linear" layers.
+
 TPU-first design:
-* **Static shapes.**  The cache is a fixed [L, slots, max_len, KV * D] HBM
-  tensor (a position's KV heads side by side in one row: one layout for 8
-  heads and for 30, ``ops/decode_attention.py``); a "slot" is one
-  sequence's reserved cache row.  Continuous batching
-  admits/retires sequences by slot index — tensor shapes never change, so jit
-  compiles exactly two programs (one prefill per length bucket, one decode
-  step) and reuses them forever.
-* **Scan over layers**: compile time is depth-independent, matching
-  ``apply_trunk``.  The stacked cache never goes through the scan as xs/ys
-  (that slices every layer out and restacks all of it, three passes over the
-  whole cache a step).  Decode carries it and scatters one row per slot at
-  ``[layer, slot, length]``; prefill returns each layer's new K/V as ys and
-  writes them after the scan.  Either way the donated buffer is updated in
-  place and the only bytes written are the new rows.
+* **Static shapes.**  Continuous batching admits/retires sequences by slot
+  index — tensor shapes never change, so jit compiles one prefill per length
+  bucket and one decode program and reuses them forever.
+* **The stacked cache is never a scan's xs/ys**, whatever its kind (that
+  slices every layer out and restacks all of it, three passes over the whole
+  cache a step).  A window carries the stack and scatters its rows at
+  ``[layer, slot, position]``; a prefill over rows returns each layer's new
+  K/V as ys and writes them after the scan.  Either way the donated buffer
+  is updated in place and the only bytes written are the new rows.
 * **Prefill** runs the normal causal forward over a right-padded [B, bucket]
   block and writes K/V for every position; padding beyond a sequence's length
   is never *read* because decode masks by per-slot length (causality makes
@@ -35,32 +50,33 @@ No torch, no dynamic shapes, no per-request Python in the hot loop.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .config import TransformerConfig
-from .transformer import Params, _norm, _rope, lm_head_logits
+from .transformer import Params, _norm, lm_head_logits
 
 KVCache = Dict[str, jnp.ndarray]
 
 
 def init_kv_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
                   dtype=jnp.bfloat16) -> KVCache:
-    """Allocate the HBM cache: K/V per layer per slot, plus per-slot lengths.
-    A model with a ``layer_pattern`` keeps K/V for its full-attention layers
-    only and a recurrent state for the others (``hybrid.init_cache``)."""
-    if cfg.layer_pattern:
-        from . import hybrid
-        return hybrid.init_cache(cfg, num_slots, max_len, dtype)
-    shape = (cfg.num_layers, num_slots, max_len,
+    """Allocate the HBM cache: K/V per full-attention layer per slot, plus
+    per-slot lengths.  A model with recurrent layers keeps a state and a
+    convolution tail for those beside it (``hybrid.init_state``)."""
+    shape = (cfg.full_layers, num_slots, max_len,
              cfg.num_kv_heads * cfg.head_dim)
-    return {
+    cache = {
         "k": jnp.zeros(shape, dtype),
         "v": jnp.zeros(shape, dtype),
         "length": jnp.zeros((num_slots,), jnp.int32),
     }
+    if cfg.linear_layers:
+        from . import hybrid
+        cache.update(hybrid.init_state(cfg, num_slots, dtype))
+    return cache
 
 
 def cache_bytes(cfg: TransformerConfig, num_slots: int, max_len: int,
@@ -86,7 +102,7 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
 
 
 # ---------------------------------------------------------------------------
-# Shared per-layer attention pieces
+# Shared per-layer pieces
 # ---------------------------------------------------------------------------
 
 @jax.named_scope("attn")
@@ -152,6 +168,125 @@ def _proj_out(attn, p, cast):
     return out
 
 
+def masked_attention(q, k, v, positions, cfg: TransformerConfig):
+    """Plain float32 attention of W queries a row over the row's whole span:
+    no kernel reads a window of several tokens, or pages, yet.  q: [R, W,
+    NH, D] at absolute ``positions`` [R, W]; k, v: [R, span, NKV, D], row
+    ``m`` holding position ``m``; query j reads positions <= its own.
+    Returns [R, W, NH * D] float32."""
+    r, w = positions.shape
+    reps = cfg.num_heads // cfg.num_kv_heads
+    qh = q.reshape(r, w, cfg.num_kv_heads, reps, cfg.head_dim)
+    scores = jnp.einsum("rwgpd,rmgd->rwgpm", qh.astype(jnp.float32),
+                        k.astype(jnp.float32)) * cfg.head_dim ** -0.5
+    if cfg.attn_logit_softcap:
+        c = cfg.attn_logit_softcap
+        scores = c * jnp.tanh(scores / c)
+    causal = jnp.arange(k.shape[1])[None, None] <= positions[:, :, None]
+    scores = jnp.where(causal[:, :, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    attn = jnp.einsum("rwgpm,rmgd->rwgpd", probs, v.astype(jnp.float32))
+    return attn.reshape(r, w, cfg.num_heads * cfg.head_dim)
+
+
+# ---------------------------------------------------------------------------
+# The layer stack
+# ---------------------------------------------------------------------------
+
+Mixer = Callable[..., Tuple[jnp.ndarray, Any, Any]]
+# the norm of a kind's mixer branch, among its layer's weights
+_BRANCH_NORM = {"full": "attn_norm", "linear": "mixer_norm"}
+
+
+def _layer_weights(stack: Params, index) -> Params:
+    """Layer ``index`` (traced) of a kind's stacked weights [periods, layers
+    of the kind a period, ...]: one dynamic index of the stack flattened, a
+    slice its matmul reads where it lies.  A period's slice taken first (the
+    stack as a scan's xs) is copied out whole: every weight of the linear
+    layers once a decode step, 35% of the hybrid cell's chip (PERF.md, PR
+    30)."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(
+            a.reshape((-1,) + a.shape[2:]), index, 0, keepdims=False),
+        stack)
+
+
+def _kv_mixer(attention, cfg: TransformerConfig, *closed) -> Mixer:
+    """``attention(y, attn_weights, cfg, k_all, v_all, layer, *closed) ->
+    (out, k_all, v_all)`` as the mixer of the "full" layers, on the carried
+    pair ``(k_all, v_all)``."""
+    def mixer(y, lp, i, kv):
+        out, *kv = attention(y, lp["attn"], cfg, *kv, i, *closed)
+        return out, tuple(kv), None
+    return mixer
+
+
+def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
+                mixers: Dict[str, Mixer], carry: Dict[str, Any],
+                cfg: TransformerConfig, compute_dtype,
+                pick: Optional[jnp.ndarray] = None):
+    """The serving forward pass: ``tokens`` [rows, W] at absolute
+    ``positions`` [rows, W] through every layer, each layer's mixing done by
+    its kind's entry of ``mixers`` on its kind's entry of ``carry`` (the
+    cache arrays a mixer updates in place; never scan xs/ys).
+
+    One ``lax.scan`` over periods of ``cfg.layer_pattern`` with the kinds
+    inside a period unrolled, so the trace is one period whatever the depth;
+    a model without a pattern is the pattern ``("full",)``, one layer a
+    period.  A block is wired ``x + f(norm(x))``, or ``x + norm(f(x))``
+    under ``cfg.norm_on_output``, for the mixer and the MLP alike.
+
+    Returns (logits float32, carry, ys): logits [rows, W, V], or [rows, V]
+    of position ``pick`` [rows] of each row; ys maps a kind to what its
+    mixer returned a layer, stacked [layers of the kind, ...] (None where
+    it returns none)."""
+    cast = compute_dtype
+    pattern, blocks = cfg.layer_pattern or ("full",), params["blocks"]
+    per_period = {kind: pattern.count(kind) for kind in mixers}
+    x = params["embed"]["tokens"][tokens].astype(cast)
+    if cfg.learned_positions:
+        x = x + params["embed"]["pos"][
+            jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
+
+    def period(walk, step):
+        (x, carry), (p, dense) = walk, step
+        carry, at = dict(carry), dict.fromkeys(per_period, 0)
+        ys = {kind: [] for kind in per_period}
+        for kind in pattern:
+            index = p * per_period[kind] + at[kind]   # in its kind's stack
+            at[kind] += 1
+            lp = (_layer_weights(blocks[kind], index) if dense is None
+                  else dense)
+            norm = lambda y, name: _norm(y, lp[name], cfg)       # noqa: E731
+            # the block's wiring, decided here and nowhere else
+            before, after = ((lambda y, name: y, norm) if cfg.norm_on_output
+                             else (norm, lambda y, name: y))
+            branch = _BRANCH_NORM[kind]
+            out, carry[kind], rows = mixers[kind](
+                before(x, branch), lp, index, carry[kind])
+            x = x + after(out, branch)
+            x = x + after(_mlp(before(x, "mlp_norm"), lp, cfg), "mlp_norm")
+            ys[kind].append(rows)
+        return (x, carry), {kind: jax.tree.map(lambda *a: jnp.stack(a), *outs)
+                            for kind, outs in ys.items() if outs}
+
+    # A layer's weights: a pattern's stacks [periods, n, ...] are indexed in
+    # place (``_layer_weights``); a dense model's one-dimensional stack [L,
+    # ...] is the scan's xs, whose one-layer slices always fused.  Indexed
+    # too, Mistral's programs compiled to other code (prefill temporaries +97
+    # KB) and the open-loop cells read 0.2-0.6% later first tokens on the
+    # chip, four pairs of four (PERF.md, PR 31).
+    (x, carry), ys = jax.lax.scan(
+        period, (x, carry), (jnp.arange(cfg.num_layers // len(pattern)),
+                             None if cfg.layer_pattern else blocks))
+    # [periods, layers of the kind a period, ...] -> [layers of the kind, ...]
+    ys = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+    x = _norm(x, params["final_norm"], cfg)
+    if pick is not None:
+        x = jnp.take_along_axis(x, pick[:, None, None], axis=1)[:, 0]
+    return lm_head_logits(params, x, cfg), carry, ys
+
+
 # ---------------------------------------------------------------------------
 # Prefill
 # ---------------------------------------------------------------------------
@@ -170,82 +305,152 @@ def prefill_attention(y, ap, cfg: TransformerConfig, positions):
 
 def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
             lengths: jnp.ndarray, slot_ids: jnp.ndarray,
-            cfg: TransformerConfig,
-            compute_dtype=jnp.bfloat16) -> Tuple[KVCache, jnp.ndarray]:
+            cfg: TransformerConfig, compute_dtype=jnp.bfloat16,
+            start_pos: Optional[jnp.ndarray] = None
+            ) -> Tuple[KVCache, jnp.ndarray]:
     """Run the causal forward over right-padded prompts, populate the cache.
 
     tokens: [B, S] int32 (right-padded to the bucket length S)
     lengths: [B] true prompt lengths; slot_ids: [B] cache rows to fill.
+    start_pos: [B], a paged tree only: the absolute position of
+      ``tokens[:, 0]``, where the slot's block-table row already points at
+      pages that hold a reused prefix (they are read, never written here).
     Returns (cache, last-token logits [B, V] f32).
     """
-    if cfg.layer_pattern:
-        from . import hybrid
-        return hybrid.prefill(params, cache, tokens, lengths, slot_ids, cfg,
-                              compute_dtype)
     b, s = tokens.shape
-    cast = compute_dtype
-    x = params["embed"]["tokens"][tokens].astype(cast)
-    if cfg.learned_positions:
-        x = x + params["embed"]["pos"][:s][None].astype(cast)
+    last = jnp.maximum(lengths - 1, 0)     # each prompt's last real token
+    if "block_table" in cache:
+        from .paged_decode import page_attention
+        start = jnp.zeros_like(lengths) if start_pos is None else start_pos
+        positions = start[:, None] + jnp.arange(s)[None]
+        pages = _kv_mixer(page_attention, cfg, cache["block_table"][slot_ids],
+                          positions, jnp.arange(s)[None] < lengths[:, None])
+        logits, carry, _ = layer_stack(
+            params, tokens, positions, {"full": pages},
+            {"full": (cache["k"], cache["v"])}, cfg, compute_dtype, last)
+        k_new, v_new = carry["full"]
+        return dict(cache, k=k_new, v=v_new, length=cache["length"].at[
+            slot_ids].set(start + lengths)), logits
+    if start_pos is not None:
+        raise ValueError("start_pos: only pages hold a prefix to start after")
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
-    def body(x, lp):
-        out, k, v = prefill_attention(_norm(x, lp["attn_norm"], cfg),
-                                      lp["attn"], cfg, positions)
-        x = x + out
-        x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
-        return x, (k.reshape(b, s, -1).astype(cache["k"].dtype),
-                   v.reshape(b, s, -1).astype(cache["v"].dtype))
+    def rows(y, lp, i, carry):
+        out, k, v = prefill_attention(y, lp["attn"], cfg, positions)
+        return out, carry, (k.reshape(b, s, -1).astype(cache["k"].dtype),
+                            v.reshape(b, s, -1).astype(cache["v"].dtype))
 
-    x, (k_rows, v_rows) = jax.lax.scan(body, x, params["blocks"])
-    # write every layer's K/V into the slots, in place on the donated cache
-    # (padded tail included; decode's length mask keeps it unread)
-    with jax.named_scope("kv_write"):
-        k_new = cache["k"].at[:, slot_ids, :s].set(k_rows)
-        v_new = cache["v"].at[:, slot_ids, :s].set(v_rows)
-    x = _norm(x, params["final_norm"], cfg)
-    # logits of each prompt's *last real token* (next-token distribution)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]  # [B, H]
-    logits = lm_head_logits(params, last, cfg)
-    cache = {
-        "k": k_new, "v": v_new,
-        "length": cache["length"].at[slot_ids].set(lengths),
-    }
-    return cache, logits
+    mixers = {"full": rows}
+    if "state" in cache:
+        from .hybrid import linear_prefill
+
+        def recurrent(y, lp, i, carry):
+            out, state, tail = linear_prefill(y, lp["mixer"], cfg, lengths)
+            return out, carry, (state, tail.astype(cache["conv"].dtype))
+
+        mixers["linear"] = recurrent
+    logits, _, ys = layer_stack(params, tokens, positions, mixers,
+                                dict.fromkeys(mixers), cfg, compute_dtype,
+                                last)
+    new = dict(cache, length=cache["length"].at[slot_ids].set(lengths))
+    # every layer's rows into the slots, in place on the donated cache (the
+    # K/V of the padded tail included; decode's length mask keeps it unread)
+    if "full" in ys:
+        with jax.named_scope("kv_write"):
+            new["k"] = cache["k"].at[:, slot_ids, :s].set(ys["full"][0])
+            new["v"] = cache["v"].at[:, slot_ids, :s].set(ys["full"][1])
+    if "linear" in ys:
+        with jax.named_scope("state_write"):
+            new["state"] = cache["state"].at[:, slot_ids].set(ys["linear"][0])
+            new["conv"] = cache["conv"].at[:, slot_ids].set(ys["linear"][1])
+    return new, logits
 
 
 # ---------------------------------------------------------------------------
-# Decode step
+# A window of W new tokens a slot (decode: W = 1; speculative verify: W = k)
 # ---------------------------------------------------------------------------
 
 def decode_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
                      active):
-    """One layer's attention for one new token a slot.  y: [slots, 1, H];
+    """One layer's attention for W new tokens a slot.  y: [slots, W, H];
     k_all, v_all: the stacked cache [layers, slots, max_len, NKV * D], of
-    which this is layer ``i``.  Appends the token's K/V at ``[i, slot,
-    length]`` in place and attends over the layer's rows up to it, of the
-    ``active`` slots only (an inactive slot keeps a stale length; it is
-    read as length 0 and its output is zeros).  Returns (attention after
-    its output projection [slots, 1, H], k_all, v_all)."""
+    which this is layer ``i``.  Appends the tokens' K/V at ``[i, slot,
+    length + j]`` in place and attends over the layer's rows up to each.
+    One token (the shape says) reads through the ``decode_attn`` kernel, of
+    the ``active`` slots only (an inactive slot keeps a stale length; it is
+    read as length 0 and its output is zeros); several read the layer's slab
+    indexed out of the stack (``masked_attention``).  Returns (attention
+    after its output projection [slots, W, H], k_all, v_all)."""
     from ..ops.decode_attention import decode_attn
-    n_slots, cast = y.shape[0], y.dtype
-    max_len = k_all.shape[2]
-    slot_idx = jnp.arange(n_slots)
-    q, k, v = _qkv(y, ap, cfg, lengths[:, None])  # q:[S,1,NH,D] k/v:[S,1,NKV,D]
-    # append at position `length` (one row per slot of this layer)
+    n_slots, w, _ = y.shape
+    cast, max_len = y.dtype, k_all.shape[2]
+    positions = lengths[:, None] + jnp.arange(w)[None]           # [slots, W]
+    q, k, v = _qkv(y, ap, cfg, positions)   # q:[S,W,NH,D] k/v:[S,W,NKV,D]
+    # one row a token, slot-major: [i, slot, length + j]
+    slot, at = jnp.repeat(jnp.arange(n_slots), w), positions.reshape(-1)
     with jax.named_scope("kv_write"):
-        k_all = k_all.at[i, slot_idx, lengths].set(
-            k.reshape(n_slots, -1).astype(k_all.dtype))
-        v_all = v_all.at[i, slot_idx, lengths].set(
-            v.reshape(n_slots, -1).astype(v_all.dtype))
-    # positions that count: up to and with the new token's
-    live = jnp.where(active, jnp.minimum(lengths + 1, max_len), 0)
+        k_all = k_all.at[i, slot, at].set(
+            k.reshape(n_slots * w, -1).astype(k_all.dtype))
+        v_all = v_all.at[i, slot, at].set(
+            v.reshape(n_slots * w, -1).astype(v_all.dtype))
     with jax.named_scope("kv_read"):
-        attn = decode_attn(q[:, 0], k_all, v_all, i, live, cfg.num_kv_heads,
-                           cfg.attn_logit_softcap)
-    attn = attn.reshape(n_slots, 1, cfg.num_heads * cfg.head_dim)
+        if w == 1:
+            # positions that count: up to and with the new token's
+            live = jnp.where(active, jnp.minimum(lengths + 1, max_len), 0)
+            attn = decode_attn(q[:, 0], k_all, v_all, i, live,
+                               cfg.num_kv_heads, cfg.attn_logit_softcap)
+        else:
+            heads = (n_slots, max_len, cfg.num_kv_heads, cfg.head_dim)
+            attn = masked_attention(
+                q, *(jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+                     .reshape(heads) for a in (k_all, v_all)), positions, cfg)
+    attn = attn.reshape(n_slots, w, cfg.num_heads * cfg.head_dim)
     return _proj_out(attn.astype(cast), ap, cast), k_all, v_all
+
+
+def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
+                active: jnp.ndarray, cfg: TransformerConfig,
+                compute_dtype=jnp.bfloat16) -> Tuple[KVCache, jnp.ndarray]:
+    """W new tokens for every slot in one forward, on any cache tree.
+
+    tokens: [slots, W] int32 — token j sits at position ``length + j``
+    active: [slots] bool — inactive slots compute garbage that is masked
+    out; their lengths (and recurrent states) stay as they were
+    Returns (cache, logits [slots, W, V] f32): K/V of all W positions are
+    appended and ``length`` advances by W for active slots.
+    """
+    w = tokens.shape[1]
+    lengths = cache["length"]
+    positions = lengths[:, None] + jnp.arange(w)[None]
+    if "block_table" in cache:
+        from .paged_decode import page_attention
+        span = cache["block_table"].shape[1] * cache["k"].shape[2]
+        attend = _kv_mixer(page_attention, cfg, cache["block_table"],
+                           positions, active[:, None])
+    else:
+        span = cache["k"].shape[2]
+        attend = _kv_mixer(decode_attention, cfg, lengths, active)
+    mixers, carry = {"full": attend}, {"full": (cache["k"], cache["v"])}
+    if "state" in cache:
+        from .hybrid import linear_step
+        if w != 1:
+            raise ValueError("a recurrent state steps one token at a time: "
+                             f"window of {w}")
+
+        def recurrent(y, lp, i, sc):
+            out, *sc = linear_step(y, lp["mixer"], cfg, i, *sc, active)
+            return out, tuple(sc), None
+
+        mixers["linear"] = recurrent
+        carry["linear"] = (cache["state"], cache["conv"])
+    logits, carry, _ = layer_stack(params, tokens, positions, mixers, carry,
+                                   cfg, compute_dtype)
+    new = dict(cache, length=jnp.where(
+        active, jnp.minimum(lengths + w, span), lengths))
+    new["k"], new["v"] = carry["full"]
+    if "linear" in carry:
+        new["state"], new["conv"] = carry["linear"]
+    return new, logits
 
 
 def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
@@ -258,39 +463,9 @@ def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
     Returns (cache, logits [slots, V] f32).  Appends K/V at position `length`
     and increments `length` for active slots.
     """
-    if cfg.layer_pattern:
-        from . import hybrid
-        return hybrid.decode_step(params, cache, tokens, active, cfg,
-                                  compute_dtype)
-    max_len = cache["k"].shape[2]
-    cast = compute_dtype
-    lengths = cache["length"]                                  # [slots]
-    x = params["embed"]["tokens"][tokens][:, None].astype(cast)  # [S,1,H]
-    if cfg.learned_positions:
-        x = x + params["embed"]["pos"][jnp.minimum(
-            lengths, cfg.max_seq_len - 1)][:, None].astype(cast)
-
-    def body(carry, layer):
-        x, k_all, v_all = carry         # k/v_all: [L, slots, max_len, NKV*D]
-        lp, i = layer
-        out, k_all, v_all = decode_attention(
-            _norm(x, lp["attn_norm"], cfg), lp["attn"], cfg, k_all, v_all,
-            i, lengths, active)
-        x = x + out
-        x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
-        return (x, k_all, v_all), None
-
-    (x, k_new, v_new), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["blocks"], jnp.arange(cache["k"].shape[0])))
-    x = _norm(x, params["final_norm"], cfg)
-    logits = lm_head_logits(params, x[:, 0], cfg)
-    cache = {
-        "k": k_new, "v": v_new,
-        "length": jnp.where(active, jnp.minimum(lengths + 1, max_len),
-                            lengths),
-    }
-    return cache, logits
+    cache, logits = window_step(params, cache, tokens[:, None], active, cfg,
+                                compute_dtype)
+    return cache, logits[:, 0]
 
 
 def sample(logits: jnp.ndarray, key: jax.Array, temperature: float = 0.0,
@@ -323,59 +498,6 @@ def sample_per_slot(logits: jnp.ndarray, key: jax.Array,
     return jnp.where(temperature > 0.0, drawn, greedy)
 
 
-def decode_and_sample(params: Params, cache: KVCache, tokens: jnp.ndarray,
-                      active: jnp.ndarray, temperature: jnp.ndarray,
-                      key: jax.Array, cfg: TransformerConfig,
-                      top_k: int = 0,
-                      compute_dtype=jnp.bfloat16
-                      ) -> Tuple[KVCache, jnp.ndarray]:
-    """One decode step with on-device sampling: the whole autoregressive
-    recurrence (embed -> attend-over-cache -> sample -> feed back) stays on
-    the device, so the host only reads tokens back lazily (the engine fetches
-    with a pipelined lag, so a readback never stalls the next dispatch).
-    Inactive slots keep their token."""
-    cache, logits = decode_step(params, cache, tokens, active, cfg,
-                                compute_dtype)
-    nxt = sample_per_slot(logits, key, temperature, top_k)
-    return cache, jnp.where(active, nxt, tokens)
-
-
-def decode_loop(params: Params, cache: KVCache, tokens: jnp.ndarray,
-                active: jnp.ndarray, temperature: jnp.ndarray,
-                key: jax.Array, n_steps: int, cfg: TransformerConfig,
-                top_k: int = 0, compute_dtype=jnp.bfloat16
-                ) -> Tuple[KVCache, jnp.ndarray, jnp.ndarray]:
-    """``n_steps`` decode steps in one compiled program (``lax.scan``).
-
-    One host dispatch + one readback per *n_steps* tokens-per-slot instead of
-    per token: a decode step of a small batch is short, so per-token host
-    dispatch would leave the chip waiting on the host.  Returns
-    (cache, final tokens [slots], emitted [n_steps, slots])."""
-
-    def body(carry, i):
-        cache, toks = carry
-        cache, nxt = decode_and_sample(
-            params, cache, toks, active, temperature,
-            jax.random.fold_in(key, i), cfg, top_k, compute_dtype)
-        return (cache, nxt), nxt
-
-    (cache, tokens), emitted = jax.lax.scan(
-        body, (cache, tokens), jnp.arange(n_steps))
-    return cache, tokens, emitted
-
-
-def prefill_and_sample(params: Params, cache: KVCache, tokens: jnp.ndarray,
-                       lengths: jnp.ndarray, slot_ids: jnp.ndarray,
-                       temperature: jnp.ndarray, key: jax.Array,
-                       cfg: TransformerConfig, top_k: int = 0,
-                       compute_dtype=jnp.bfloat16
-                       ) -> Tuple[KVCache, jnp.ndarray]:
-    """Prefill + sample each prompt's first output token on device."""
-    cache, logits = prefill(params, cache, tokens, lengths, slot_ids, cfg,
-                            compute_dtype)
-    return cache, sample_per_slot(logits, key, temperature, top_k)
-
-
 # ---------------------------------------------------------------------------
 # Device-resident autoregressive state (zero host ops in the serving loop)
 # ---------------------------------------------------------------------------
@@ -383,7 +505,8 @@ def prefill_and_sample(params: Params, cache: KVCache, tokens: jnp.ndarray,
 # Every EAGER op or small host->device transfer is its own dispatch and, where
 # its result is read, a sync point; a jitted dispatch is async.  The serving
 # engine therefore keeps the complete per-slot
-# autoregressive state ON DEVICE and only ever calls two jitted programs:
+# autoregressive state ON DEVICE and only ever calls two jitted programs,
+# whatever the kind of cache:
 #
 #   decode_state_loop(params, cache, state, n)   — n steps, state evolves
 #   prefill_admit(params, cache, state, <numpy admit batch>)
@@ -429,11 +552,19 @@ def prefill_admit(params: Params, cache: KVCache, state: Dict[str, Any],
                   slot_ids: jnp.ndarray, temps: jnp.ndarray,
                   budgets: jnp.ndarray, eos: jnp.ndarray,
                   real_mask: jnp.ndarray, cfg: TransformerConfig,
-                  top_k: int = 0, compute_dtype=jnp.bfloat16):
+                  top_k: int = 0, compute_dtype=jnp.bfloat16,
+                  start_pos: Optional[jnp.ndarray] = None,
+                  table_rows: Optional[jnp.ndarray] = None):
     """Prefill + sample + merge into the decode state, one fixed-shape
-    program.  Returns (cache, state, first_tokens [B])."""
+    program.  A paged admit brings two more arrays: the admitted slots'
+    block-table rows ``table_rows`` [B, max_pages], written first, and
+    ``start_pos`` (``prefill``), so that only the uncached suffixes are
+    prefilled.  Returns (cache, state, first_tokens [B])."""
+    if table_rows is not None:
+        cache = dict(cache, block_table=cache["block_table"].at[
+            slot_ids].set(table_rows))
     cache, logits = prefill(params, cache, tokens, lengths, slot_ids, cfg,
-                            compute_dtype)
+                            compute_dtype, start_pos)
     first = sample_per_slot(logits, state["key"], temps, top_k)
     state = _merge_admit(state, first, slot_ids, temps, budgets, eos,
                          real_mask)
@@ -445,6 +576,9 @@ def decode_state_loop(params: Params, cache: KVCache, state: Dict[str, Any],
                       compute_dtype=jnp.bfloat16):
     """``n_steps`` decode+sample steps with on-device active decay.
 
+    One host dispatch + one readback per *n_steps* tokens-per-slot instead of
+    per token: a decode step of a small batch is short, so per-token host
+    dispatch would leave the chip waiting on the host.
     Returns (cache, state, emitted [n_steps, slots]).  A slot goes inactive
     the step its budget hits zero or it samples its EOS token; inactive
     slots repeat their last token (the host emits only to live requests)."""

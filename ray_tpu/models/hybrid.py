@@ -1,8 +1,12 @@
 """Layers of two kinds in one model: gated-delta-rule linear-attention
 layers beside full-attention layers (``TransformerConfig.layer_pattern``),
-on the serving path.  ``decode.init_kv_cache`` / ``prefill`` / ``decode_step``
-come here when the configuration has a pattern, so ``serve/llm.py`` runs the
-same three calls on a cache tree of two kinds of state:
+on the serving path.  This file is what is particular to the "linear" kind:
+its weights, its per-slot state and its mixer, for whole rows
+(``linear_prefill``) and for one token a slot (``linear_step``).  The walk
+over the layers, the block's wiring and the full-attention layers are
+``decode.py``'s, which hands these two to ``decode.layer_stack`` where the
+cache tree has a ``state``; ``serve/llm.py`` runs the same calls on it as on
+any cache:
 
 * ``k``, ``v``: [full_layers, slots, max_len, NKV * D], the dense cache of
   ``decode.py`` with rows for the full-attention layers only, written and
@@ -36,15 +40,14 @@ length.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops import gated_delta
 from .config import TransformerConfig
-from .decode import (KVCache, _mlp, decode_attention, prefill_attention)
-from .transformer import Params, _norm, lm_head_logits
+from .transformer import Params, _norm
 
 L2_EPS = 1e-6
 
@@ -62,7 +65,7 @@ def _channels(cfg: TransformerConfig) -> Tuple[int, int]:
 
 
 # ---------------------------------------------------------------------------
-# Parameters and cache
+# Parameters and per-slot state
 # ---------------------------------------------------------------------------
 
 def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
@@ -132,19 +135,17 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
     return blocks
 
 
-def init_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
-               dtype=jnp.bfloat16) -> KVCache:
+def init_state(cfg: TransformerConfig, num_slots: int,
+               dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
+    """What a slot keeps for the linear layers, beside the rows of K/V that
+    ``decode.init_kv_cache`` allocates for the full-attention layers."""
     kd, vd = _channels(cfg)
-    kv = (cfg.full_layers, num_slots, max_len, cfg.num_kv_heads * cfg.head_dim)
     return {
-        "k": jnp.zeros(kv, dtype),
-        "v": jnp.zeros(kv, dtype),
         "state": jnp.zeros((cfg.linear_layers, num_slots, cfg.linear_num_heads,
                             cfg.linear_key_dim, cfg.linear_value_dim),
                            jnp.float32),
         "conv": jnp.zeros((cfg.linear_layers, num_slots,
                            cfg.linear_conv_width - 1, 2 * kd + vd), dtype),
-        "length": jnp.zeros((num_slots,), jnp.int32),
     }
 
 
@@ -192,175 +193,57 @@ def _mixer_out(o, x, mp, cfg: TransformerConfig):
     return y @ mp["w_o"].astype(cast)
 
 
-def _mlp_branch(x, lp, cfg: TransformerConfig):
-    return x + _norm(_mlp(x, lp, cfg), lp["mlp_norm"], cfg)
-
-
-def _layer_params(blocks: Params, kind: str, index):
-    """Layer ``index`` (traced) of a kind's stack [periods, layers a period,
-    ...], by one dynamic index of the stack flattened: a slice its matmul
-    reads where it lies.  A period's slice taken first (the stack as a
-    scan's xs) is copied out whole: every weight of the linear layers once
-    a decode step, 35% of the hybrid cell's chip (PERF.md, PR 30)."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(
-            a.reshape((-1,) + a.shape[2:]), index, 0, keepdims=False),
-        blocks[kind])
-
-
 # ---------------------------------------------------------------------------
-# Prefill
+# The mixer, over whole rows and one token a slot
 # ---------------------------------------------------------------------------
 
-def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
-            lengths: jnp.ndarray, slot_ids: jnp.ndarray,
-            cfg: TransformerConfig,
-            compute_dtype=jnp.bfloat16) -> Tuple[KVCache, jnp.ndarray]:
-    """``decode.prefill`` for a model with a layer pattern (same arguments
-    and results).  ``tokens`` [B, S] may have any S."""
-    b, s = tokens.shape
-    cast = compute_dtype
-    width = cfg.linear_conv_width
-    x = params["embed"]["tokens"][tokens].astype(cast)
-    positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+def linear_prefill(x, mp, cfg: TransformerConfig, lengths):
+    """One linear layer's mixer over whole right-padded rows.  x: [B, S, H]
+    (any S) -> (mixer output [B, S, H], the state [B, heads, dk, dv] and
+    the convolution tail [B, width - 1, C] each row leaves at its length)."""
+    s, cast, width = x.shape[1], x.dtype, cfg.linear_conv_width
     # where each row's convolution tail sits: its last width-1 inputs
     tail_pos = lengths[:, None] - (width - 1) + jnp.arange(width - 1)[None]
-
-    def linear_layer(x, lp):
-        mp = lp["mixer"]
-        with jax.named_scope("gdn"):
-            proj = x @ mp["w_qkv"].astype(cast)                 # [B, S, C]
-        with jax.named_scope("gdn_conv"):
-            padded = jnp.pad(proj, ((0, 0), (width - 1, 0), (0, 0)))
-            conv = sum(padded[:, j:j + s] * mp["conv_w"][j].astype(cast)
-                       for j in range(width))
-            conv = jax.nn.silu(conv)
-            tail = jnp.take_along_axis(
-                proj, jnp.maximum(tail_pos, 0)[..., None], axis=1)
-            tail = jnp.where((tail_pos >= 0)[..., None], tail, 0)
-        q, k, v = _split_heads(conv, cfg)
-        g, beta = _gates(x, mp, cfg)
-        with jax.named_scope("gdn"):
-            # positions at or beyond a row's length leave its state alone
-            o, state = gated_delta.gdn_chunk_fwd(q, k, v, g, beta, lengths)
-        x = x + _norm(_mixer_out(o, x, mp, cfg), lp["mixer_norm"], cfg)
-        return _mlp_branch(x, lp, cfg), (state, tail)
-
-    def full_layer(x, lp):
-        out, k, v = prefill_attention(x, lp["attn"], cfg, positions)
-        x = x + _norm(out, lp["attn_norm"], cfg)
-        return _mlp_branch(x, lp, cfg), (k, v)
-
-    per_period = dict(zip(("linear", "full"), _counts(cfg)[1:]))
-
-    def period(x, p):
-        rows = {"linear": [], "full": []}
-        at = {"linear": 0, "full": 0}
-        for kind in cfg.layer_pattern:
-            layer = linear_layer if kind == "linear" else full_layer
-            x, out = layer(x, _layer_params(
-                params["blocks"], kind, p * per_period[kind] + at[kind]))
-            rows[kind].append(out)
-            at[kind] += 1
-        # per kind, the layers' (a, b) pairs stacked: ([n, ...], [n, ...])
-        stacked = {kind: jax.tree.map(lambda *a: jnp.stack(a), *outs)
-                   if outs else (None, None) for kind, outs in rows.items()}
-        return x, stacked["linear"] + stacked["full"]
-
-    x, (states, tails, k_rows, v_rows) = jax.lax.scan(
-        period, x, jnp.arange(cfg.num_periods))
-    merge = lambda a: a.reshape((-1,) + a.shape[2:])             # noqa: E731
-    new = dict(cache)
-    # every layer's rows into the slots, in place on the donated cache (the
-    # K/V of the padded tail included; decode's length mask keeps it unread)
-    if k_rows is not None:
-        with jax.named_scope("kv_write"):
-            rows = lambda a: merge(a).reshape(            # noqa: E731
-                (-1, b, s, cache["k"].shape[-1])).astype(cache["k"].dtype)
-            new["k"] = cache["k"].at[:, slot_ids, :s].set(rows(k_rows))
-            new["v"] = cache["v"].at[:, slot_ids, :s].set(rows(v_rows))
-    if states is not None:
-        with jax.named_scope("state_write"):
-            new["state"] = cache["state"].at[:, slot_ids].set(merge(states))
-            new["conv"] = cache["conv"].at[:, slot_ids].set(
-                merge(tails).astype(cache["conv"].dtype))
-    new["length"] = cache["length"].at[slot_ids].set(lengths)
-    x = _norm(x, params["final_norm"], cfg)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
-    return new, lm_head_logits(params, last, cfg)
+    with jax.named_scope("gdn"):
+        proj = x @ mp["w_qkv"].astype(cast)                     # [B, S, C]
+    with jax.named_scope("gdn_conv"):
+        padded = jnp.pad(proj, ((0, 0), (width - 1, 0), (0, 0)))
+        conv = sum(padded[:, j:j + s] * mp["conv_w"][j].astype(cast)
+                   for j in range(width))
+        conv = jax.nn.silu(conv)
+        tail = jnp.take_along_axis(
+            proj, jnp.maximum(tail_pos, 0)[..., None], axis=1)
+        tail = jnp.where((tail_pos >= 0)[..., None], tail, 0)
+    q, k, v = _split_heads(conv, cfg)
+    g, beta = _gates(x, mp, cfg)
+    with jax.named_scope("gdn"):
+        # positions at or beyond a row's length leave its state alone
+        o, state = gated_delta.gdn_chunk_fwd(q, k, v, g, beta, lengths)
+    return _mixer_out(o, x, mp, cfg), state, tail
 
 
-# ---------------------------------------------------------------------------
-# Decode step
-# ---------------------------------------------------------------------------
-
-def decode_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
-                active: jnp.ndarray, cfg: TransformerConfig,
-                compute_dtype=jnp.bfloat16) -> Tuple[KVCache, jnp.ndarray]:
-    """``decode.decode_step`` for a model with a layer pattern.  An inactive
-    slot's recurrent state and convolution tail stay as they were."""
-    cast = compute_dtype
-    width = cfg.linear_conv_width
-    max_len = cache["k"].shape[2]
-    lengths = cache["length"]
-    _, n_lin, n_full = _counts(cfg)
-    x = params["embed"]["tokens"][tokens][:, None].astype(cast)  # [slots,1,H]
-    live = active[:, None]                                       # [slots, 1]
-
-    def linear_layer(x, lp, li, state, conv):
-        mp = lp["mixer"]
-        y = x[:, 0]                                              # [slots, H]
-        with jax.named_scope("gdn"):
-            proj = y @ mp["w_qkv"].astype(cast)                  # [slots, C]
-        with jax.named_scope("state_read"):
-            tail = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
-        with jax.named_scope("gdn_conv"):
-            window = jnp.concatenate([tail.astype(cast), proj[:, None]], 1)
-            mixed = jax.nn.silu(sum(window[:, j] * mp["conv_w"][j].astype(cast)
-                                    for j in range(width)))
-        with jax.named_scope("state_write"):
-            conv = jax.lax.dynamic_update_index_in_dim(
-                conv, jnp.where(live[..., None], window[:, 1:].astype(
-                    conv.dtype), tail), li, 0)
-        q, k, v = _split_heads(mixed, cfg)
-        g, beta = _gates(y, mp, cfg, live)
-        with jax.named_scope("gdn"):
-            state, o = gated_delta.gdn_recurrent_step(state, li, q, k, v, g,
-                                                      beta)
-        out = _mixer_out(o, y, mp, cfg)[:, None]
-        x = x + _norm(out, lp["mixer_norm"], cfg)
-        return _mlp_branch(x, lp, cfg), state, conv
-
-    def full_layer(x, lp, fi, k_all, v_all):
-        out, k_all, v_all = decode_attention(x, lp["attn"], cfg, k_all, v_all,
-                                             fi, lengths, active)
-        x = x + _norm(out, lp["attn_norm"], cfg)
-        return _mlp_branch(x, lp, cfg), k_all, v_all
-
-    per_period = {"linear": n_lin, "full": n_full}
-
-    def period(carry, p):
-        x, k_all, v_all, state, conv = carry
-        at = {"linear": 0, "full": 0}
-        for kind in cfg.layer_pattern:
-            index = p * per_period[kind] + at[kind]   # in its kind's stack
-            lp = _layer_params(params["blocks"], kind, index)
-            if kind == "linear":
-                x, state, conv = linear_layer(x, lp, index, state, conv)
-            else:
-                x, k_all, v_all = full_layer(x, lp, index, k_all, v_all)
-            at[kind] += 1
-        return (x, k_all, v_all, state, conv), None
-
-    (x, k_new, v_new, state, conv), _ = jax.lax.scan(
-        period, (x, cache["k"], cache["v"], cache["state"], cache["conv"]),
-        jnp.arange(cfg.num_periods))
-    x = _norm(x, params["final_norm"], cfg)
-    logits = lm_head_logits(params, x[:, 0], cfg)
-    cache = {
-        "k": k_new, "v": v_new, "state": state, "conv": conv,
-        "length": jnp.where(active, jnp.minimum(lengths + 1, max_len),
-                            lengths),
-    }
-    return cache, logits
+def linear_step(x, mp, cfg: TransformerConfig, li, state, conv, active):
+    """One linear layer's mixer for one new token a slot.  x: [slots, 1, H];
+    state, conv: the stacks of every linear layer, of which this is layer
+    ``li``, updated in place; an inactive slot's recurrent state and
+    convolution tail stay as they were.  Returns (mixer output [slots, 1,
+    H], state, conv)."""
+    cast, width = x.dtype, cfg.linear_conv_width
+    y, live = x[:, 0], active[:, None]                 # [slots, H], [slots, 1]
+    with jax.named_scope("gdn"):
+        proj = y @ mp["w_qkv"].astype(cast)                     # [slots, C]
+    with jax.named_scope("state_read"):
+        tail = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
+    with jax.named_scope("gdn_conv"):
+        window = jnp.concatenate([tail.astype(cast), proj[:, None]], 1)
+        mixed = jax.nn.silu(sum(window[:, j] * mp["conv_w"][j].astype(cast)
+                                for j in range(width)))
+    with jax.named_scope("state_write"):
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, jnp.where(live[..., None], window[:, 1:].astype(
+                conv.dtype), tail), li, 0)
+    q, k, v = _split_heads(mixed, cfg)
+    g, beta = _gates(y, mp, cfg, live)
+    with jax.named_scope("gdn"):
+        state, o = gated_delta.gdn_recurrent_step(state, li, q, k, v, g, beta)
+    return _mixer_out(o, y, mp, cfg)[:, None], state, conv

@@ -17,219 +17,50 @@ target's argmax at the same position, then emit the target's own token
 at the first mismatch — ≥1 token per verify call, so worst case equals
 vanilla decode plus the (cheap) draft work.
 
-Everything is fixed-shape and jittable: the multi-round driver is a
-``lax.scan`` whose carry holds both caches, per-slot emit buffers and
-lengths — no host round-trip between rounds (cf. decode.py's
-``decode_loop``).
+Everything is fixed-shape and jittable: the multi-round driver
+(``spec_decode_state_loop``) is a ``lax.scan`` whose carry holds both
+caches, the engine's device-resident decode state and per-slot emit buffers
+— no host round-trip between rounds (cf. ``decode.decode_state_loop``).
+The target's cache may be rows or pages: ``verify_window`` is the same
+walk over the layers as every other serving forward (``decode.layer_stack``)
+and the cache tree says which attention it gets.
 """
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .config import TransformerConfig
-from .decode import (KVCache, Params, _mlp, _norm, _proj_out, _qkv,
-                     decode_step, lm_head_logits, sample_per_slot)
+from .decode import (KVCache, Params, decode_step, sample_per_slot,
+                     window_step)
 
-__all__ = ["verify_window", "speculative_round", "speculative_decode_loop",
-           "spec_state_round", "spec_decode_state_loop", "make_draft_params",
-           "damp_block_outputs"]
+__all__ = ["verify_window", "spec_state_round", "spec_decode_state_loop",
+           "make_draft_params", "damp_block_outputs"]
 
 
 def verify_window(params: Params, cache: KVCache, tokens: jnp.ndarray,
                   active: jnp.ndarray, cfg: TransformerConfig,
                   compute_dtype=jnp.bfloat16
                   ) -> Tuple[KVCache, jnp.ndarray]:
-    """Process a k-token window per slot in one forward.
+    """Process a k-token window per slot in one forward, over rows or pages:
+    ``decode_step`` at window k (``decode.window_step``; with k=1 it
+    computes identical math).
 
     tokens: [slots, k] int32 — token j sits at cache position length+j
     active: [slots] bool
     Returns (cache, logits [slots, k, V] f32); K/V for all k positions
-    are appended and ``length`` advances by k for active slots (callers
-    roll length back to the accepted prefix afterwards — the garbage
-    tail beyond ``length`` is never read, same contract as prefill's
-    padded tail).
-
-    This is ``decode_step`` generalized from window 1 to window k; with
-    k=1 it computes identical math.
+    are appended and ``length`` advances by k for active slots.  Callers
+    roll ``length`` back to the accepted prefix afterwards — rollback is a
+    length reset ONLY: the garbage tail beyond ``length`` is never read
+    (same contract as prefill's padded tail) and the next round overwrites
+    it.  On pages that is exact by construction too: every window position
+    lands in a page the slot's block table already owns (private pages at
+    index >= the shared-prefix boundary).
     """
-    n_slots, k = tokens.shape
-    max_len = cache["k"].shape[2]
-    cast = compute_dtype
-    lengths = cache["length"]                                   # [slots]
-    x = params["embed"]["tokens"][tokens].astype(cast)          # [S,k,H]
-    positions = lengths[:, None] + jnp.arange(k)[None]          # [S,k]
-    if cfg.learned_positions:
-        x = x + params["embed"]["pos"][
-            jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
-    scale = cfg.head_dim ** -0.5
-    reps = cfg.num_heads // cfg.num_kv_heads
-    # query j may see cache positions <= length+j (its own position)
-    pos_mask = (jnp.arange(max_len)[None, None]
-                <= positions[:, :, None])          # [slots, k, max_len]
-    row = jnp.arange(n_slots)[:, None]                          # [S,1]
-
-    def body(x, layer):
-        lp, k_lay, v_lay = layer
-        y = _norm(x, lp["attn_norm"], cfg)
-        q, kk, vv = _qkv(y, lp["attn"], cfg, positions)  # [S,k,N*,D]
-        # append the whole window's K/V rows (scatter at length..length+k-1);
-        # the cache's rows hold the KV heads side by side
-        k_lay = k_lay.at[row, positions].set(
-            kk.reshape(n_slots, k, -1).astype(k_lay.dtype))
-        v_lay = v_lay.at[row, positions].set(
-            vv.reshape(n_slots, k, -1).astype(v_lay.dtype))
-        heads = (n_slots, max_len, cfg.num_kv_heads, cfg.head_dim)
-        qh = q.reshape(n_slots, k, cfg.num_kv_heads, reps, cfg.head_dim)
-        scores = jnp.einsum("skgrd,smgd->skgrm", qh.astype(jnp.float32),
-                            k_lay.reshape(heads).astype(jnp.float32)) * scale
-        if cfg.attn_logit_softcap:
-            c = cfg.attn_logit_softcap
-            scores = c * jnp.tanh(scores / c)
-        scores = jnp.where(pos_mask[:, :, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("skgrm,smgd->skgrd", probs,
-                          v_lay.reshape(heads).astype(jnp.float32))
-        attn = attn.reshape(n_slots, k, cfg.num_heads * cfg.head_dim)
-        x = x + _proj_out(attn.astype(cast), lp["attn"], cast)
-        x = x + _mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg)
-        return x, (k_lay, v_lay)
-
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _norm(x, params["final_norm"], cfg)
-    logits = lm_head_logits(params, x, cfg)
-    cache = {
-        "k": k_new, "v": v_new,
-        "length": jnp.where(active, jnp.minimum(lengths + k, max_len),
-                            lengths),
-    }
-    return cache, logits
-
-
-def speculative_round(target_params: Params, target_cache: KVCache,
-                      draft_params: Params, draft_cache: KVCache,
-                      last_tokens: jnp.ndarray, active: jnp.ndarray,
-                      k: int, target_cfg: TransformerConfig,
-                      draft_cfg: TransformerConfig,
-                      ) -> Tuple[KVCache, KVCache, jnp.ndarray,
-                                 jnp.ndarray, jnp.ndarray]:
-    """One draft→verify→accept round for every slot.
-
-    Returns (target_cache, draft_cache, emitted [slots, k] int32,
-    emit_count [slots] int32 in 1..k, new_last [slots]).  Emitted slots
-    beyond emit_count hold garbage; inactive slots emit 0 tokens.
-
-    Greedy acceptance: with drafts d_1..d_{k-1} and target logits
-    l_0..l_{k-1} over window [last, d_1..d_{k-1}], accept d_{j+1} while
-    d_{j+1} == argmax(l_j); then emit argmax(l_a) at the first mismatch
-    (the "free" correction) — output identical to vanilla greedy.
-    """
-    n_slots = last_tokens.shape[0]
-
-    # -- draft rollout: k-1 small-model steps ------------------------------
-    def draft_body(carry, _):
-        dc, tok = carry
-        dc, logits = decode_step(draft_params, dc, tok, active, draft_cfg)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (dc, nxt), nxt
-
-    (draft_cache, last_d), drafts = jax.lax.scan(
-        draft_body, (draft_cache, last_tokens), None, length=k - 1)
-    drafts = drafts.T                                   # [slots, k-1]
-    # one extra KV-only draft step: when every draft is accepted the next
-    # round needs d_{k-1}'s row in the draft cache too (its logits are
-    # discarded — this is the fixed price of fixed shapes)
-    draft_cache, _ = decode_step(draft_params, draft_cache, last_d,
-                                 active, draft_cfg)
-
-    # -- target verify: ONE k-token window ---------------------------------
-    window = jnp.concatenate([last_tokens[:, None], drafts], axis=1)
-    t_len0 = target_cache["length"]
-    target_cache, logits = verify_window(target_params, target_cache,
-                                         window, active, target_cfg)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [slots, k]
-
-    # -- acceptance --------------------------------------------------------
-    match = (drafts == greedy[:, :-1])                       # [slots, k-1]
-    accepted = jnp.argmin(
-        jnp.concatenate([match, jnp.zeros((n_slots, 1), bool)], 1), axis=1)
-    # ^ index of first False; all-True gives k-1 (argmin of all-False tail
-    #   trick: appended False guarantees a minimum exists)
-    emit_count = jnp.where(active, accepted + 1, 0)          # drafts + fix
-    # emitted tokens: d_1..d_a then greedy[a] at position a
-    emitted = jnp.where(
-        jnp.arange(k)[None] < accepted[:, None],
-        jnp.concatenate([drafts, jnp.zeros((n_slots, 1), jnp.int32)], 1),
-        jnp.take_along_axis(greedy, accepted[:, None], 1))   # [slots, k]
-    new_last = jnp.take_along_axis(greedy, accepted[:, None], 1)[:, 0]
-    new_last = jnp.where(active, new_last, last_tokens)
-
-    # -- roll both caches back to the verified prefix ----------------------
-    # context now ends with ...last, d_1..d_a; the correction token is
-    # fed next round, so length = len0 + 1 + accepted
-    new_len = t_len0 + 1 + accepted
-    target_cache = dict(target_cache,
-                        length=jnp.where(active, new_len, t_len0))
-    # the draft ingested the same prefix (its rows cover last..d_{k-1})
-    draft_cache = dict(draft_cache,
-                       length=jnp.where(active, new_len,
-                                        draft_cache["length"]))
-    return target_cache, draft_cache, emitted, emit_count, new_last
-
-
-@partial(jax.jit, static_argnames=("k", "num_rounds", "target_cfg",
-                                   "draft_cfg", "eos_id"))
-def speculative_decode_loop(target_params: Params, target_cache: KVCache,
-                            draft_params: Params, draft_cache: KVCache,
-                            last_tokens: jnp.ndarray, active: jnp.ndarray,
-                            k: int, num_rounds: int,
-                            target_cfg: TransformerConfig,
-                            draft_cfg: TransformerConfig,
-                            eos_id: int = -1,
-                            ) -> Dict[str, Any]:
-    """Fixed-shape multi-round driver: ``num_rounds`` spec rounds under
-    one ``lax.scan`` — no host sync between rounds.
-
-    Returns {tokens: [slots, num_rounds*k], counts: [slots],
-    target_cache, draft_cache, last_tokens, rounds_accepted: [slots,
-    num_rounds]} — tokens beyond counts are garbage; a slot that emits
-    ``eos_id`` (if >= 0) deactivates for the remaining rounds.
-    """
-    n_slots = last_tokens.shape[0]
-    out = jnp.zeros((n_slots, num_rounds * k), jnp.int32)
-    counts = jnp.zeros((n_slots,), jnp.int32)
-
-    def round_body(carry, _):
-        tc, dc, last, act, out, counts = carry
-        tc, dc, emitted, n_emit, last = speculative_round(
-            target_params, tc, draft_params, dc, last, act,
-            k, target_cfg, draft_cfg)
-        # scatter emitted[0:n_emit] at out[counts:counts+n_emit]
-        idx = counts[:, None] + jnp.arange(k)[None]          # [slots, k]
-        keep = jnp.arange(k)[None] < n_emit[:, None]
-        out = out.at[jnp.arange(n_slots)[:, None],
-                     jnp.minimum(idx, out.shape[1] - 1)].set(
-            jnp.where(keep, emitted, out[jnp.arange(n_slots)[:, None],
-                                         jnp.minimum(idx, out.shape[1] - 1)]))
-        counts = counts + n_emit
-        if eos_id >= 0:
-            hit_eos = (jnp.where(keep, emitted, -1) == eos_id).any(axis=1)
-            act = act & ~hit_eos
-        return (tc, dc, last, act, out, counts), n_emit
-
-    (target_cache, draft_cache, last_tokens, active, out, counts), accs = \
-        jax.lax.scan(round_body,
-                     (target_cache, draft_cache, last_tokens, active,
-                      out, counts), None, length=num_rounds)
-    return {"tokens": out, "counts": counts,
-            "target_cache": target_cache, "draft_cache": draft_cache,
-            "last_tokens": last_tokens, "active": active,
-            "rounds_accepted": accs.T}
+    return window_step(params, cache, tokens, active, cfg, compute_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -239,13 +70,17 @@ def speculative_decode_loop(target_params: Params, target_cache: KVCache,
 def spec_state_round(target_params: Params, target_cache, draft_params:
                      Params, draft_cache: KVCache, state: Dict[str, Any],
                      k: int, target_cfg: TransformerConfig,
-                     draft_cfg: TransformerConfig, paged: bool = False,
-                     top_k: int = 0, compute_dtype=jnp.bfloat16):
-    """One speculative round against the engine's device-resident decode
-    state (``decode.init_decode_state`` layout) — the serving twin of
-    ``speculative_round``, run inside LLMEngine's scheduler thread.
+                     draft_cfg: TransformerConfig, top_k: int = 0,
+                     compute_dtype=jnp.bfloat16):
+    """One draft→verify→accept round for every slot, against the engine's
+    device-resident decode state (``decode.init_decode_state`` layout), run
+    inside LLMEngine's scheduler thread.
 
-    Differences from the standalone round (tier-1 tests pin all three):
+    Greedy acceptance: with drafts d_1..d_{k-1} and target logits
+    l_0..l_{k-1} over window [last, d_1..d_{k-1}], accept d_{j+1} while
+    d_{j+1} == argmax(l_j); then emit argmax(l_a) at the first mismatch
+    (the "free" correction) — output identical to vanilla greedy.  Beyond
+    that (tier-1 tests pin all three):
 
     * **Sampling-aware.**  Greedy slots (temperature 0) take the classic
       accept-while-matching path; sampled slots accept NO drafts and emit
@@ -258,10 +93,9 @@ def spec_state_round(target_params: Params, target_cache, draft_params:
       budget and active decay on device by the same predicate
       ``decode_state_loop`` applies per step — the host scheduling mirror
       stays byte-consistent with the plain decode path.
-    * **Paged or dense target.**  ``paged=True`` verifies through
-      ``paged_decode.paged_verify_window``; either way rollback is a
-      length reset to ``len0 + emit_count`` (the cache then covers
-      ``last, e_1..e_{cnt-1}`` and ``e_cnt`` is fed back next round).
+    * **Paged or dense target.**  Either way rollback is a length reset
+      to ``len0 + emit_count`` (the cache then covers ``last,
+      e_1..e_{cnt-1}`` and ``e_cnt`` is fed back next round).
 
     The draft cache is always DENSE (the paged HBM win matters for the
     big target; the draft is layers-sliced and small).  Returns
@@ -285,23 +119,18 @@ def spec_state_round(target_params: Params, target_cache, draft_params:
     (draft_cache, last_d), drafts = jax.lax.scan(
         draft_body, (draft_cache, last), None, length=k - 1)
     drafts = drafts.T if k > 1 else jnp.zeros((n_slots, 0), jnp.int32)
-    # KV-only extra step so a fully-accepted round leaves d_{k-1}'s row in
-    # the draft cache (fixed price of fixed shapes, as speculative_round)
+    # one extra KV-only draft step: when every draft is accepted the next
+    # round needs d_{k-1}'s row in the draft cache too (its logits are
+    # discarded — this is the fixed price of fixed shapes)
     draft_cache, _ = decode_step(draft_params, draft_cache, last_d, active,
                                  draft_cfg, compute_dtype)
 
     # -- target verify: ONE k-token window ---------------------------------
     window = jnp.concatenate([last[:, None], drafts], axis=1)
     t_len0 = target_cache["length"]
-    if paged:
-        from .paged_decode import paged_verify_window
-        target_cache, logits = paged_verify_window(
-            target_params, target_cache, window, active, target_cfg,
-            compute_dtype)
-    else:
-        target_cache, logits = verify_window(target_params, target_cache,
-                                             window, active, target_cfg,
-                                             compute_dtype)
+    target_cache, logits = verify_window(target_params, target_cache,
+                                         window, active, target_cfg,
+                                         compute_dtype)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [slots, k]
 
     # -- acceptance --------------------------------------------------------
@@ -356,9 +185,8 @@ def spec_decode_state_loop(target_params: Params, target_cache,
                            draft_params: Params, draft_cache: KVCache,
                            state: Dict[str, Any], k: int, num_rounds: int,
                            target_cfg: TransformerConfig,
-                           draft_cfg: TransformerConfig, paged: bool = False,
-                           top_k: int = 0, compute_dtype=jnp.bfloat16
-                           ) -> Dict[str, Any]:
+                           draft_cfg: TransformerConfig, top_k: int = 0,
+                           compute_dtype=jnp.bfloat16) -> Dict[str, Any]:
     """``num_rounds`` decode-state spec rounds under one ``lax.scan`` —
     the engine's speculative twin of ``decode_state_loop`` (one dispatch,
     no host sync between rounds).
@@ -378,7 +206,7 @@ def spec_decode_state_loop(target_params: Params, target_cache,
         tc, dc, st, out, counts = carry
         tc, dc, st, emitted, n_emit = spec_state_round(
             target_params, tc, draft_params, dc, st, k, target_cfg,
-            draft_cfg, paged, top_k, compute_dtype)
+            draft_cfg, top_k, compute_dtype)
         idx = jnp.minimum(counts[:, None] + jnp.arange(k)[None],
                           out.shape[1] - 1)
         keep = jnp.arange(k)[None] < n_emit[:, None]

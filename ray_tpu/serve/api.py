@@ -105,15 +105,22 @@ def run(target: Union[Deployment, Dict[str, Deployment]], *,
 
 
 def _wait_healthy(ctrl, names, timeout_s: float):
+    """Poll the controller until every deployment reads HEALTHY.  The verdict
+    falls on a status asked for at or after the deadline: one that turned
+    HEALTHY while a slow controller was answering is not a timeout (the
+    driver's tier-1 run of PR 30 raised here with a HEALTHY status in the
+    message)."""
     deadline = time.monotonic() + timeout_s
-    while time.monotonic() < deadline:
+    while True:
+        last = time.monotonic() >= deadline
         status = ray_tpu.get(ctrl.get_status.remote(), timeout=30)
         if all(status.get(n, {}).get("status") == HEALTHY for n in names):
             return
+        if last:
+            raise TimeoutError(
+                f"deployments {names} not healthy after {timeout_s}s: "
+                f"{status}")
         time.sleep(0.1)
-    raise TimeoutError(
-        f"deployments {names} not healthy after {timeout_s}s: "
-        f"{ray_tpu.get(ctrl.get_status.remote(), timeout=30)}")
 
 
 def get_deployment_handle(name: str) -> DeploymentHandle:

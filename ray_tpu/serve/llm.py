@@ -170,7 +170,6 @@ class LLMEngine:
         self.paged = paged
         if paged:
             from ray_tpu.models import paged_decode as pdec
-            self._pdec = pdec
             self.page_size = page_size
             self.max_pages_per_slot = -(-self.max_len // page_size)
             # default HBM budget = half the dense cache (the paged win)
@@ -225,12 +224,11 @@ class LLMEngine:
         # Compiled programs: one decode dispatch (cache + state donated —
         # the multi-GB cache must be updated in place, not copied), one
         # prefill per bucket (lazy unless warmup_buckets).
-        loop_fn = (self._pdec.paged_decode_state_loop if paged
-                   else dec.decode_state_loop)
         self._decode_fn = named_jit(
             PROGRAM_DECODE,
-            lambda p, c, st: loop_fn(p, c, st, self.steps_per_dispatch, cfg,
-                                     top_k, self.compute_dtype),
+            lambda p, c, st: dec.decode_state_loop(
+                p, c, st, self.steps_per_dispatch, cfg, top_k,
+                self.compute_dtype),
             donate_argnums=(1, 2))
         self._prefill_fns: Dict[int, Any] = {}
 
@@ -598,20 +596,12 @@ class LLMEngine:
             # fresh program per batch size).  Admit batches arrive as plain
             # numpy arrays — transferred as part of the async dispatch, not
             # as per-array eager round trips.  Padding rows target the
-            # scratch slot.
-            if self.paged:
-                pdec = self._pdec
-
-                def admit_fn(p, c, st, t, ln, sl, start, bt, tmp, bud, eos,
-                             real_mask):
-                    return pdec.paged_prefill_admit(
-                        p, c, st, t, ln, sl, start, bt, tmp, bud, eos,
-                        real_mask, cfg, tk, dt)
-            else:
-                def admit_fn(p, c, st, t, ln, sl, tmp, bud, eos, real_mask):
-                    return dec.prefill_admit(
-                        p, c, st, t, ln, sl, tmp, bud, eos, real_mask, cfg,
-                        tk, dt)
+            # scratch slot.  A paged admit brings two arrays more (start
+            # positions, block-table rows): data, not another program.
+            def admit_fn(p, c, st, t, ln, sl, tmp, bud, eos, real_mask,
+                         *paged):
+                return dec.prefill_admit(p, c, st, t, ln, sl, tmp, bud, eos,
+                                         real_mask, cfg, tk, dt, *paged)
 
             fn = named_jit(PROGRAM_PREFILL, admit_fn, donate_argnums=(1, 2))
             self._prefill_fns[bucket] = fn
@@ -682,11 +672,11 @@ class LLMEngine:
         if ent is None:
             rounds = max(1, self.steps_per_dispatch // k)
             spec, cfg, dcfg = self._spec, self.cfg, self._spec_draft_cfg
-            tk, dt, paged = self.top_k, self.compute_dtype, self.paged
+            tk, dt = self.top_k, self.compute_dtype
 
             def run(tp, tc, dp, dc, st):
                 return spec.spec_decode_state_loop(
-                    tp, tc, dp, dc, st, k, rounds, cfg, dcfg, paged, tk, dt)
+                    tp, tc, dp, dc, st, k, rounds, cfg, dcfg, tk, dt)
 
             ent = (named_jit(PROGRAM_SPEC_DECODE, run,
                              donate_argnums=(1, 3, 4)), rounds)
@@ -856,8 +846,8 @@ class LLMEngine:
         try:
             self.cache, self._state, first = self._prefill_fn(sbucket)(
                 self.params, self.cache, self._state, toks, lengths,
-                slots_arr, starts_arr, bt_rows, temps, budgets, eos,
-                real_mask)
+                slots_arr, temps, budgets, eos, real_mask, starts_arr,
+                bt_rows)
         except BaseException as e:  # noqa: BLE001
             for (r, (_reused, pages)), s in zip(planned, slots):
                 self._free_slots.append(s)

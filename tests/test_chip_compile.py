@@ -138,11 +138,10 @@ def _admit_rows(one_chip, bucket, b=8):
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 def test_decode_state_loop_compiles(one_chip, paged):
     """The engine's one decode dispatch: 8 steps, cache and state donated."""
-    loop = (paged_decode.paged_decode_state_loop if paged
-            else decode.decode_state_loop)
     params, cache, state = _serve_shapes(one_chip, LLAMA_400M, paged)
     compiled, _ = _compile(
-        lambda p, c, st: loop(p, c, st, STEPS, LLAMA_400M, 0, jnp.bfloat16),
+        lambda p, c, st: decode.decode_state_loop(
+            p, c, st, STEPS, LLAMA_400M, 0, jnp.bfloat16),
         params, cache, state, donate_argnums=(1, 2))
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
@@ -163,11 +162,10 @@ def test_dense_prefill_1024_takes_the_flash_kernel(one_chip, as_tpu):
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 def test_speculative_verify_window_compiles(one_chip, paged):
     """The target's k+1-token verify step of speculative decode (k=4)."""
-    verify = (paged_decode.paged_verify_window if paged
-              else speculative.verify_window)
     params, cache, _ = _serve_shapes(one_chip, LLAMA_400M, paged)
     _compile(
-        lambda p, c, t, a: verify(p, c, t, a, LLAMA_400M, jnp.bfloat16),
+        lambda p, c, t, a: speculative.verify_window(
+            p, c, t, a, LLAMA_400M, jnp.bfloat16),
         params, cache,
         jax.ShapeDtypeStruct((SLOTS, 5), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one_chip),

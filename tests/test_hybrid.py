@@ -405,3 +405,90 @@ def test_only_a_kinds_file_names_the_programs_models():
                 hits += [f"{path}:{n}: {line}" for n, line in
                          enumerate(open(path), 1) if names.search(line)]
     assert not hits, "".join(hits)
+
+
+# ------------- one walk over the layers, and no cache through a scan (PR 31)
+
+def _scans(jaxpr):
+    """Every ``scan`` of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+def test_one_walk_over_the_layers_and_no_cache_through_a_scan(tiny):
+    """In ``ray_tpu/models/`` two functions loop over the layers' weights:
+    ``decode.layer_stack`` (serving, every kind of cache) and
+    ``transformer.apply_trunk`` (training).  And whichever tree the serving
+    programs are traced on, no scan takes or returns a cache array as
+    xs / ys: that slices every layer out and restacks all of it (PR 26)."""
+    import ast
+
+    from ray_tpu.models import paged_decode, speculative
+    from ray_tpu.models.config import TransformerConfig
+
+    loops = {"scan", "fori_loop", "while_loop", "map"}
+    models = os.path.join(REPO, "ray_tpu", "models")
+    over_layers = set()
+    for name in sorted(os.listdir(models)):
+        if not name.endswith(".py"):
+            continue
+        src = open(os.path.join(models, name)).read()
+        for fn in ast.parse(src).body:
+            if isinstance(fn, ast.FunctionDef) and any(
+                    isinstance(n, ast.Call)
+                    and isinstance(n.func, ast.Attribute)
+                    and n.func.attr in loops
+                    and ast.unparse(n.func.value).endswith("lax")
+                    for n in ast.walk(fn)) and '"blocks"' in ast.get_source_segment(src, fn):
+                over_layers.add((name, fn.name))
+    assert over_layers == {("decode.py", "layer_stack"),
+                           ("transformer.py", "apply_trunk")}
+
+    hybrid_cfg, hybrid_params = tiny
+    dense_cfg = TransformerConfig(
+        vocab_size=128, num_layers=3, hidden_size=64, num_heads=4,
+        num_kv_heads=2, mlp_size=128, max_seq_len=96)
+    dense_params = transformer.init_params(jax.random.PRNGKey(0), dense_cfg,
+                                           dtype=jnp.float32)
+    draft_cfg = TransformerConfig(**{**dense_cfg.__dict__, "num_layers": 1})
+    slots, max_len, rows, bucket = 5, 96, 2, 16
+    trees = {
+        "rows": (dense_cfg, dense_params,
+                 decode.init_kv_cache(dense_cfg, slots, max_len)),
+        "pages": (dense_cfg, dense_params, paged_decode.init_paged_cache(
+            dense_cfg, 20, 8, slots, max_len // 8)),
+        "recurrent": (hybrid_cfg, hybrid_params,
+                      decode.init_kv_cache(hybrid_cfg, slots, max_len)),
+    }
+    state = decode.init_decode_state(slots, jax.random.PRNGKey(1))
+    admit = (jnp.zeros((rows, bucket), jnp.int32),) + tuple(
+        jnp.ones((rows,), dt) for dt in (jnp.int32, jnp.int32, jnp.float32,
+                                         jnp.int32, jnp.int32, jnp.bool_))
+    for name, (cfg, params, cache) in trees.items():
+        held = {a.shape for k, a in cache.items()
+                if k not in ("length", "block_table")}
+        programs = [
+            (lambda p, c, st: decode.decode_state_loop(p, c, st, 2, cfg),
+             (params, cache, state)),
+            (lambda p, c, st, *a: decode.prefill_admit(p, c, st, *a, cfg),
+             (params, cache, state) + admit),
+        ]
+        if name != "recurrent":
+            draft = speculative.make_draft_params(params, 1)
+            programs.append((
+                lambda p, c, dp, dc, st: speculative.spec_decode_state_loop(
+                    p, c, dp, dc, st, 3, 2, cfg, draft_cfg),
+                (params, cache, draft,
+                 decode.init_kv_cache(draft_cfg, slots, max_len), state)))
+        for fn, args in programs:
+            found = list(_scans(jax.make_jaxpr(fn)(*args).jaxpr))
+            assert found
+            for eqn in found:
+                skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+                through = ([v.aval.shape for v in eqn.invars[skip:]]
+                           + [v.aval.shape for v in
+                              eqn.outvars[eqn.params["num_carry"]:]])
+                assert not held & set(through), (name, through)

@@ -40,15 +40,18 @@ def test_paged_matches_dense_greedy():
     dcache, dlogits = decode.prefill(params, dcache, jnp.asarray(toks),
                                      lengths, slot_ids, CFG,
                                      compute_dtype=jnp.float32)
-    dtok = jnp.argmax(dlogits, -1).astype(jnp.int32)
-    dense_first = int(dtok[0])
-    slot_tok = jnp.zeros((2,), jnp.int32).at[0].set(dtok[0])
-    active = jnp.array([True, False])
-    temp = jnp.zeros((2,), jnp.float32)
-    dcache, _, demitted = decode.decode_loop(
-        params, dcache, slot_tok, active, temp, jax.random.PRNGKey(1),
-        n_steps, CFG, compute_dtype=jnp.float32)
-    dense_seq = [dense_first] + [int(t) for t in np.asarray(demitted)[:, 0]]
+    def greedy(cache, logits):
+        """The engine's decode loop on either tree, slot 0 live."""
+        first = jnp.argmax(logits, -1).astype(jnp.int32)[0]
+        state = decode.init_decode_state(2, jax.random.PRNGKey(1))
+        state = dict(state, tokens=state["tokens"].at[0].set(first),
+                     active=state["active"].at[0].set(True),
+                     budget=state["budget"].at[0].set(n_steps))
+        _, _, emitted = decode.decode_state_loop(
+            params, cache, state, n_steps, CFG, compute_dtype=jnp.float32)
+        return [int(first)] + [int(t) for t in np.asarray(emitted)[:, 0]]
+
+    dense_seq = greedy(dcache, dlogits)
 
     # paged
     pcache = paged_decode.init_paged_cache(
@@ -59,18 +62,12 @@ def test_paged_matches_dense_greedy():
     bt = np.zeros((2, 8), np.int32)
     bt[0, :4] = pages
     pcache["block_table"] = jnp.asarray(bt)
-    pcache, plogits = paged_decode.paged_prefill(
-        params, pcache, jnp.asarray(toks), lengths, slot_ids,
-        jnp.array([0], jnp.int32), CFG, compute_dtype=jnp.float32)
+    pcache, plogits = decode.prefill(
+        params, pcache, jnp.asarray(toks), lengths, slot_ids, CFG,
+        compute_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(dlogits), np.asarray(plogits),
                                rtol=2e-4, atol=2e-4)
-    ptok = jnp.argmax(plogits, -1).astype(jnp.int32)
-    slot_tok = jnp.zeros((2,), jnp.int32).at[0].set(ptok[0])
-    pcache, _, pemitted = paged_decode.paged_decode_loop(
-        params, pcache, slot_tok, active, temp, jax.random.PRNGKey(1),
-        n_steps, CFG, compute_dtype=jnp.float32)
-    paged_seq = [int(ptok[0])] + [int(t) for t in np.asarray(pemitted)[:, 0]]
-    assert paged_seq == dense_seq
+    assert greedy(pcache, plogits) == dense_seq
 
 
 def test_prefix_reuse_matches_cold_prefill():
@@ -88,10 +85,10 @@ def test_prefix_reuse_matches_cold_prefill():
         cache["block_table"] = jnp.asarray(bt)
         toks = np.zeros((1, 24), np.int32)
         toks[0, :20] = full
-        cache, logits = paged_decode.paged_prefill(
+        cache, logits = decode.prefill(
             params, cache, jnp.asarray(toks), jnp.array([20], jnp.int32),
-            jnp.array([0], jnp.int32), jnp.array([0], jnp.int32), CFG,
-            compute_dtype=jnp.float32)
+            jnp.array([0], jnp.int32), CFG, compute_dtype=jnp.float32,
+            start_pos=jnp.array([0], jnp.int32))
         return cache, logits, pages
 
     cache, logits_cold, pages = cold()
@@ -122,10 +119,10 @@ def test_prefix_reuse_matches_cold_prefill():
     }
     toks = np.zeros((1, 8), np.int32)
     toks[0, :4] = full[16:]
-    cache2, logits_warm = paged_decode.paged_prefill(
+    cache2, logits_warm = decode.prefill(
         params, cache2, jnp.asarray(toks), jnp.array([4], jnp.int32),
-        jnp.array([1], jnp.int32), jnp.array([16], jnp.int32), CFG,
-        compute_dtype=jnp.float32)
+        jnp.array([1], jnp.int32), CFG, compute_dtype=jnp.float32,
+        start_pos=jnp.array([16], jnp.int32))
     np.testing.assert_allclose(np.asarray(logits_cold),
                                np.asarray(logits_warm), rtol=2e-4, atol=2e-4)
 

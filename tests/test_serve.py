@@ -125,6 +125,38 @@ def test_replica_death_recovery(serve_clean):
         pytest.fail(f"replacement replica never became RUNNING: {st}")
 
 
+def test_wait_healthy_decides_on_a_status_read_at_the_deadline(monkeypatch):
+    """A controller that answers slowly (a loaded machine) and turns HEALTHY
+    just past the deadline: ``serve.run`` must not raise a timeout whose own
+    message says HEALTHY.  One that never does still times out."""
+    from ray_tpu.serve import api
+
+    def controller(healthy_at):
+        t0, asked = time.monotonic(), []
+
+        class SlowController:
+            class get_status:                              # noqa: N801
+                @staticmethod
+                def remote():
+                    asked.append(time.monotonic() - t0)
+                    return "HEALTHY" if asked[-1] >= healthy_at \
+                        else "DEPLOYING"
+        return SlowController, asked
+
+    def slow_get(status, timeout=None):
+        time.sleep(0.3)
+        return {"app": {"status": status}}
+
+    monkeypatch.setattr(api.ray_tpu, "get", slow_get)
+    ctrl, asked = controller(healthy_at=0.55)
+    api._wait_healthy(ctrl, ["app"], timeout_s=0.5)
+    assert len(asked) == 3 and asked[-1] >= 0.5
+    ctrl, asked = controller(healthy_at=float("inf"))
+    with pytest.raises(TimeoutError, match="DEPLOYING"):
+        api._wait_healthy(ctrl, ["app"], timeout_s=0.5)
+    assert len(asked) == 3
+
+
 def test_rolling_update(serve_clean):
     @serve.deployment(num_replicas=2)
     def versioned(_x=None):

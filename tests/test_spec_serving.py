@@ -90,18 +90,17 @@ def test_spec_engine_matches_vanilla_greedy(paged):
 def _paged_admit(params, cache, slot, prompt, next_free, max_pages, cfg):
     """Host-side stand-in for the engine's admit: point the slot's block
     table at fresh pages and prefill the whole prompt from position 0."""
-    from ray_tpu.models import paged_decode as pd
+    from ray_tpu.models import decode
     bt = np.zeros((max_pages,), np.int32)
     bt[:] = range(next_free, next_free + max_pages)
     cache = dict(cache, block_table=cache["block_table"].at[slot].set(
         jnp.asarray(bt)))
     toks = np.zeros((1, 64), np.int32)
     toks[0, :len(prompt)] = prompt
-    cache, logits = pd.paged_prefill(
+    cache, logits = decode.prefill(
         params, cache, jnp.asarray(toks),
         jnp.asarray([len(prompt)], jnp.int32),
-        jnp.asarray([slot], jnp.int32), jnp.asarray([0], jnp.int32),
-        cfg, jnp.float32)
+        jnp.asarray([slot], jnp.int32), cfg, jnp.float32)
     return cache, int(jnp.argmax(logits[0])), next_free + max_pages
 
 
@@ -152,8 +151,8 @@ def test_spec_paged_rollback_matches_fresh_prefill():
                  budget=state["budget"].at[0].set(budget))
     k, rounds = 4, 5  # rounds*k > budget => the budget clamp path runs
     res = spec.spec_decode_state_loop(params, cache, dparams, dcache, state,
-                                      k, rounds, TINY, dcfg, paged=True,
-                                      top_k=0, compute_dtype=jnp.float32)
+                                      k, rounds, TINY, dcfg, top_k=0,
+                                      compute_dtype=jnp.float32)
     cnt = int(res["counts"][0])
     emitted = [int(t) for t in np.asarray(res["tokens"][0])[:cnt]]
     assert cnt == budget  # clamp stopped emission exactly at the budget
