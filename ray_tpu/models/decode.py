@@ -33,12 +33,16 @@ TPU-first design:
   slices every layer out and restacks all of it, three passes over the whole
   cache a step).  A window carries the stack and scatters its rows at
   ``[layer, slot, position]``; a prefill over rows returns each layer's new
-  K/V as ys and writes them after the scan.  Either way the donated buffer
-  is updated in place and the only bytes written are the new rows.
-* **Prefill** runs the normal causal forward over a right-padded [B, bucket]
-  block and writes K/V for every position; padding beyond a sequence's length
-  is never *read* because decode masks by per-slot length (causality makes
-  the writes at pad positions harmless: real positions never attend to them).
+  K/V as ys and writes them after the scan, into the cache its loop over
+  the admit's rows carries.  Either way the donated buffer is updated in
+  place and the only bytes written are the new rows.
+* **Prefill** is a fixed shape and counted rows: a [B, bucket] block of
+  right-padded prompts is one program whatever an admit holds, and the
+  program walks the rows that hold a prompt, one causal forward a row, in a
+  loop whose trip count is data.  It writes K/V for every position of a row;
+  padding beyond a sequence's length is never *read* because decode masks by
+  per-slot length (causality makes the writes at pad positions harmless:
+  real positions never attend to them).
 * **Decode** is one token per active slot: q at position `len`, attention
   over the slot's rows up to it, read where they lie in the stack by one
   kernel that takes the layer index and the live lengths
@@ -303,67 +307,109 @@ def prefill_attention(y, ap, cfg: TransformerConfig, positions):
     return _proj_out(attn.reshape(b, s, -1), ap, y.dtype), k, v
 
 
-def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
-            lengths: jnp.ndarray, slot_ids: jnp.ndarray,
-            cfg: TransformerConfig, compute_dtype=jnp.bfloat16,
-            start_pos: Optional[jnp.ndarray] = None
-            ) -> Tuple[KVCache, jnp.ndarray]:
-    """Run the causal forward over right-padded prompts, populate the cache.
-
-    tokens: [B, S] int32 (right-padded to the bucket length S)
-    lengths: [B] true prompt lengths; slot_ids: [B] cache rows to fill.
-    start_pos: [B], a paged tree only: the absolute position of
-      ``tokens[:, 0]``, where the slot's block-table row already points at
-      pages that hold a reused prefix (they are read, never written here).
-    Returns (cache, last-token logits [B, V] f32).
-    """
-    b, s = tokens.shape
-    last = jnp.maximum(lengths - 1, 0)     # each prompt's last real token
+def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
+                 length: jnp.ndarray, slot: jnp.ndarray, start: jnp.ndarray,
+                 cfg: TransformerConfig, compute_dtype
+                 ) -> Tuple[KVCache, jnp.ndarray]:
+    """One prompt through the layers and into its slot of ``cache``, which
+    comes and goes in place.  tokens: [1, S]; length, start: [1]; slot: a
+    scalar.  Returns (cache, last-token logits [1, V] f32)."""
+    s = tokens.shape[1]
+    last = jnp.maximum(length - 1, 0)      # the prompt's last real token
+    positions = start[:, None] + jnp.arange(s)[None]
+    new = dict(cache, length=cache["length"].at[slot].set(
+        (start + length)[0]))
     if "block_table" in cache:
         from .paged_decode import page_attention
-        start = jnp.zeros_like(lengths) if start_pos is None else start_pos
-        positions = start[:, None] + jnp.arange(s)[None]
-        pages = _kv_mixer(page_attention, cfg, cache["block_table"][slot_ids],
-                          positions, jnp.arange(s)[None] < lengths[:, None])
+        table = jax.lax.dynamic_slice_in_dim(cache["block_table"], slot, 1)
+        pages = _kv_mixer(page_attention, cfg, table, positions,
+                          jnp.arange(s)[None] < length[:, None])
         logits, carry, _ = layer_stack(
             params, tokens, positions, {"full": pages},
             {"full": (cache["k"], cache["v"])}, cfg, compute_dtype, last)
-        k_new, v_new = carry["full"]
-        return dict(cache, k=k_new, v=v_new, length=cache["length"].at[
-            slot_ids].set(start + lengths)), logits
-    if start_pos is not None:
-        raise ValueError("start_pos: only pages hold a prefix to start after")
-    positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        new["k"], new["v"] = carry["full"]
+        return new, logits
 
     def rows(y, lp, i, carry):
         out, k, v = prefill_attention(y, lp["attn"], cfg, positions)
-        return out, carry, (k.reshape(b, s, -1).astype(cache["k"].dtype),
-                            v.reshape(b, s, -1).astype(cache["v"].dtype))
+        return out, carry, (k.reshape(1, s, -1).astype(cache["k"].dtype),
+                            v.reshape(1, s, -1).astype(cache["v"].dtype))
 
     mixers = {"full": rows}
     if "state" in cache:
         from .hybrid import linear_prefill
 
         def recurrent(y, lp, i, carry):
-            out, state, tail = linear_prefill(y, lp["mixer"], cfg, lengths)
+            out, state, tail = linear_prefill(y, lp["mixer"], cfg, length)
             return out, carry, (state, tail.astype(cache["conv"].dtype))
 
         mixers["linear"] = recurrent
     logits, _, ys = layer_stack(params, tokens, positions, mixers,
                                 dict.fromkeys(mixers), cfg, compute_dtype,
                                 last)
-    new = dict(cache, length=cache["length"].at[slot_ids].set(lengths))
-    # every layer's rows into the slots, in place on the donated cache (the
-    # K/V of the padded tail included; decode's length mask keeps it unread)
+
+    # every layer's rows [layers of the kind, 1, ...] into the slot, in place
+    # on the donated cache (the K/V of the padded tail included; decode's
+    # length mask keeps it unread)
+    def put(name, rows):
+        return jax.lax.dynamic_update_slice(
+            cache[name], rows, (0, slot) + (0,) * (rows.ndim - 2))
+
     if "full" in ys:
         with jax.named_scope("kv_write"):
-            new["k"] = cache["k"].at[:, slot_ids, :s].set(ys["full"][0])
-            new["v"] = cache["v"].at[:, slot_ids, :s].set(ys["full"][1])
+            new["k"], new["v"] = put("k", ys["full"][0]), put("v", ys["full"][1])
     if "linear" in ys:
         with jax.named_scope("state_write"):
-            new["state"] = cache["state"].at[:, slot_ids].set(ys["linear"][0])
-            new["conv"] = cache["conv"].at[:, slot_ids].set(ys["linear"][1])
+            new["state"] = put("state", ys["linear"][0])
+            new["conv"] = put("conv", ys["linear"][1])
     return new, logits
+
+
+def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
+            lengths: jnp.ndarray, slot_ids: jnp.ndarray,
+            cfg: TransformerConfig, compute_dtype=jnp.bfloat16,
+            start_pos: Optional[jnp.ndarray] = None,
+            rows: Optional[jnp.ndarray] = None
+            ) -> Tuple[KVCache, jnp.ndarray]:
+    """Run the causal forward over right-padded prompts, populate the cache.
+
+    A fixed shape, counted rows: the arrays are [B, ...] whatever an admit
+    holds, so a bucket is one program, and the program walks the first
+    ``rows`` of them one after another in a loop whose trip count is that
+    number, data.  Each pass takes one row [1, S] through ``layer_stack`` and
+    writes its slot of the cache, carried through the loop in place.  Rows
+    past the count are not computed: their slots, lengths and states stay as
+    they were and their logits read 0.  (A [8, 2048] admit of Mistral's 14
+    layers was 664 ms on the chip with one prompt in it or eight: PERF.md,
+    PR 32.)
+
+    tokens: [B, S] int32 (right-padded to the bucket length S)
+    lengths: [B] true prompt lengths; slot_ids: [B] cache rows to fill.
+    start_pos: [B], a paged tree only: the absolute position of
+      ``tokens[:, 0]``, where the slot's block-table row already points at
+      pages that hold a reused prefix (they are read, never written here).
+    rows: scalar int32, how many rows hold a prompt, real rows first; every
+      row where it is not given.
+    Returns (cache, last-token logits [B, V] f32).
+    """
+    tokens, lengths, slot_ids = map(jnp.asarray, (tokens, lengths, slot_ids))
+    b = tokens.shape[0]
+    if start_pos is None:
+        start_pos = jnp.zeros_like(lengths)
+    elif "block_table" not in cache:
+        raise ValueError("start_pos: only pages hold a prefix to start after")
+
+    def one(r, walk):
+        cache, logits = walk
+        row = lambda a: jax.lax.dynamic_slice_in_dim(a, r, 1)   # noqa: E731
+        cache, lg = _prefill_row(params, cache, row(tokens), row(lengths),
+                                 slot_ids[r], row(start_pos), cfg,
+                                 compute_dtype)
+        return cache, jax.lax.dynamic_update_slice_in_dim(logits, lg, r, 0)
+
+    return jax.lax.fori_loop(
+        0, b if rows is None else rows, one,
+        (cache, jnp.zeros((b, cfg.vocab_size), jnp.float32)))
 
 
 # ---------------------------------------------------------------------------
@@ -556,15 +602,19 @@ def prefill_admit(params: Params, cache: KVCache, state: Dict[str, Any],
                   start_pos: Optional[jnp.ndarray] = None,
                   table_rows: Optional[jnp.ndarray] = None):
     """Prefill + sample + merge into the decode state, one fixed-shape
-    program.  A paged admit brings two more arrays: the admitted slots'
-    block-table rows ``table_rows`` [B, max_pages], written first, and
-    ``start_pos`` (``prefill``), so that only the uncached suffixes are
-    prefilled.  Returns (cache, state, first_tokens [B])."""
+    program that walks the rows ``real_mask`` counts (real rows first;
+    ``prefill``) and samples and merges all B, so a row's first token does
+    not depend on how many rows came with it.  A paged admit brings two
+    more arrays: the admitted slots' block-table rows ``table_rows`` [B,
+    max_pages], written first, and ``start_pos`` (``prefill``), so that only
+    the uncached suffixes are prefilled.  Returns (cache, state,
+    first_tokens [B])."""
     if table_rows is not None:
         cache = dict(cache, block_table=cache["block_table"].at[
             slot_ids].set(table_rows))
     cache, logits = prefill(params, cache, tokens, lengths, slot_ids, cfg,
-                            compute_dtype, start_pos)
+                            compute_dtype, start_pos,
+                            rows=jnp.sum(real_mask, dtype=jnp.int32))
     first = sample_per_slot(logits, state["key"], temps, top_k)
     state = _merge_admit(state, first, slot_ids, temps, budgets, eos,
                          real_mask)

@@ -161,10 +161,14 @@ class LLMEngine:
             params = transformer.init_params(
                 jax.random.PRNGKey(seed), cfg, dtype=jnp.bfloat16)
         self.params = params
-        # Admission batches are padded to a FIXED size so each length bucket
-        # compiles exactly one prefill program (a varying batch dim would
-        # recompile mid-traffic).  Padding rows write into a scratch cache
-        # slot (index num_slots) that decode never activates.
+        # A fixed shape, counted rows: admission batches are padded to a
+        # FIXED size so each length bucket compiles exactly one prefill
+        # program (a varying batch dim would recompile mid-traffic), and
+        # that program walks only the rows that hold a request, their
+        # count data (decode.prefill).  So prefill_batch is the most rows
+        # one admit may take, not a shape the chip pays for.  Padding rows
+        # aim at a scratch cache slot (index num_slots) that decode never
+        # activates; only their sampling state is ever written there.
         self.prefill_batch = prefill_batch or min(num_slots, 8)
         self._scratch_slot = num_slots
         self.paged = paged
@@ -285,13 +289,14 @@ class LLMEngine:
         # steady-state metrics
         self.steps = 0
         self.tokens_out = 0
-        # admission accounting (padding waste = padded rows the fixed-size
-        # prefill batch shipped for nothing; bench_llm reads these)
+        # admission accounting, of what the chip walked (bench_llm reads
+        # these): rows that held a request and rows that held none, which
+        # the prefill program skips (always 0)
         self.admit_batches = 0
         self.admit_rows_real = 0
         self.admit_rows_padded = 0
         # the same per token: prompt tokens prefilled, and every other
-        # position of the [prefill_batch, bucket] arrays that carried them
+        # position of the walked rows (a row is rounded up to its bucket)
         self.admit_tokens_real = 0
         self.admit_tokens_padded = 0
         # what decode attention reads of the cache it holds (dense cache,
@@ -516,9 +521,8 @@ class LLMEngine:
         now = time.monotonic()
         self.admit_batches += 1
         self.admit_rows_real += len(reqs)
-        self.admit_rows_padded += self.prefill_batch - len(reqs)
         self.admit_tokens_real += tokens_real
-        self.admit_tokens_padded += self.prefill_batch * bucket - tokens_real
+        self.admit_tokens_padded += len(reqs) * bucket - tokens_real
         self.admitted_requests += len(reqs)
         for r in reqs:
             r.admitted_at = now
@@ -593,11 +597,13 @@ class LLMEngine:
 
             # Prefill + sample + merge into the decode state in ONE
             # fixed-shape program (a varying admit count would compile a
-            # fresh program per batch size).  Admit batches arrive as plain
-            # numpy arrays — transferred as part of the async dispatch, not
-            # as per-array eager round trips.  Padding rows target the
-            # scratch slot.  A paged admit brings two arrays more (start
-            # positions, block-table rows): data, not another program.
+            # fresh program per batch size) that walks counted rows: how
+            # many hold a request it reads off real_mask, so an admit of
+            # one costs one row.  Admit batches arrive as plain numpy
+            # arrays — transferred as part of the async dispatch, not as
+            # per-array eager round trips.  Padding rows target the scratch
+            # slot.  A paged admit brings two arrays more (start positions,
+            # block-table rows): data, not another program.
             def admit_fn(p, c, st, t, ln, sl, tmp, bud, eos, real_mask,
                          *paged):
                 return dec.prefill_admit(p, c, st, t, ln, sl, tmp, bud, eos,
@@ -618,8 +624,8 @@ class LLMEngine:
             dcfg, dt = self._spec_draft_cfg, self.compute_dtype
             dec = self._dec
 
-            def f(p, c, t, ln, sl):
-                return dec.prefill(p, c, t, ln, sl, dcfg, dt)[0]
+            def f(p, c, t, ln, sl, n):
+                return dec.prefill(p, c, t, ln, sl, dcfg, dt, rows=n)[0]
 
             fn = named_jit(PROGRAM_DRAFT_PREFILL, f, donate_argnums=(1,))
             self._draft_prefill_fns[bucket] = fn
@@ -643,7 +649,7 @@ class LLMEngine:
         try:
             self._draft_cache = self._draft_prefill_fn(bucket)(
                 self._draft_params, self._draft_cache, toks, lengths,
-                slots_arr)
+                slots_arr, np.int32(len(reqs)))
         except BaseException:  # noqa: BLE001
             self.spec_draft_errors += 1
 

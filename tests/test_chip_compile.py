@@ -183,8 +183,13 @@ def test_speculative_verify_window_compiles(one_chip, paged):
 CELL_SLOTS, CELL_MAX_LEN = 33, 2048
 HBM_GIB = 15.75          # what the compiler allows a program on a v5e
 # decode: 0.59 GB, the wq and wk stacks transposed once a dispatch (0.47 +
-# 0.12 GB, as before PR 30) and no slab of the cache (0.98 GB with two)
-TEMP_GB = {"decode": 0.7, "prefill-256": 0.6, "prefill-2048": 3.0}
+# 0.12 GB, as before PR 30) and no slab of the cache (0.98 GB with two).
+# prefill (PR 32: a loop over the admit's real rows, one row a pass): what one
+# row needs, 0.002 GB at 256 and 0.24 GB at 2048 (eight rows at once held
+# 0.24 / 2.28 GB), beside the same two stacks transposed once a program, which
+# the compiler hoists out of the row loop as it does out of decode's step
+# loop: readings 0.589 and 0.825 GB
+TEMP_GB = {"decode": 0.7, "prefill-256": 0.6, "prefill-2048": 0.9}
 _cell_compiled = {}
 
 
@@ -196,22 +201,28 @@ def _cell_cfg(layers):
         use_swiglu=True, use_qkv_bias=False)
 
 
-def _cell_program(one_chip, program, layers):
-    """One of the engine's programs at the cell's size, cache and state
-    donated as the engine donates them; compiled once per module."""
-    if (program, layers) not in _cell_compiled:
-        cfg = _cell_cfg(layers)
-        args = _serve_shapes(one_chip, cfg, False, CELL_SLOTS, CELL_MAX_LEN)
+def _cell_program(one_chip, program, model):
+    """One of the engine's programs at a cell's size, cache and state
+    donated as the engine donates them; compiled once per module.  ``model``:
+    how many of Mistral's layers, or "hybrid" for the hybrid cell's model."""
+    if (program, model) not in _cell_compiled:
+        if model == "hybrid":
+            cfg, slots, max_len, rows = (_hybrid_cfg(), HYBRID_SLOTS,
+                                         HYBRID_MAX_LEN, 2)
+        else:
+            cfg, slots, max_len, rows = (_cell_cfg(model), CELL_SLOTS,
+                                         CELL_MAX_LEN, 8)
+        args = _serve_shapes(one_chip, cfg, False, slots, max_len)
         if program == "decode":
             fn = lambda p, c, st: decode.decode_state_loop(  # noqa: E731
                 p, c, st, STEPS, cfg, 0, jnp.bfloat16)
         else:
-            args += _admit_rows(one_chip, int(program.split("-")[1]))
+            args += _admit_rows(one_chip, int(program.split("-")[1]), rows)
             fn = lambda p, c, st, *a: decode.prefill_admit(  # noqa: E731
                 p, c, st, *a, cfg, 0, jnp.bfloat16)
-        _cell_compiled[program, layers] = _compile(
+        _cell_compiled[program, model] = _compile(
             fn, *args, donate_argnums=(1, 2))
-    return _cell_compiled[program, layers]
+    return _cell_compiled[program, model]
 
 
 cell_programs = pytest.mark.parametrize("program", list(TEMP_GB))
@@ -225,24 +236,43 @@ def test_cell_program_temporaries(one_chip, as_tpu, program):
     assert temp < TEMP_GB[program] * 1e9, f"{temp / 1e9:.2f} GB"
 
 
-@cell_programs
-def test_cell_program_updates_the_cache_in_place(one_chip, as_tpu, program):
+MISTRAL_KV = (f"bf16[14,{CELL_SLOTS},{CELL_MAX_LEN},1024]",)
+
+
+@pytest.mark.parametrize("program,model,stacks", [
+    *((program, 14, MISTRAL_KV) for program in TEMP_GB),
+    # the hybrid's largest bucket (PR 32): K/V of the three full layers, 2.36
+    # GB each, and the float32 state, carried through the loop over the
+    # admit's rows (4.7 + 0.7 GB: a copy would not fit)
+    ("prefill-4096", "hybrid", ("bf16[3,25,4096,3840]",
+                                "f32[9,25,30,96,192]")),
+])
+def test_cell_program_updates_the_cache_in_place(one_chip, as_tpu, program,
+                                                 model, stacks):
     """Nothing copies the stacked cache and nothing restacks a layer's slab
     into it, in a loop body or outside one: the only writes to the stack are
-    scatters and row-sized ``dynamic-update-slice``s, which alias it."""
-    _, text = _cell_program(one_chip, program, 14)
-    stack = f"bf16[14,{CELL_SLOTS},{CELL_MAX_LEN},1024]"
-    slab = CELL_SLOTS * CELL_MAX_LEN * 1024
+    scatters and row-sized ``dynamic-update-slice``s, which alias it.  A
+    prefill program carries the stack through its loop over the admit's rows
+    the same way."""
+    _, text = _cell_program(one_chip, program, model)
     dims_of = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
-    assert stack in text
-    writes = re.findall(
-        r"%([\w.\-]+) = " + re.escape(stack)
-        + r"\S* (copy|dynamic-update-slice)\(%[\w.\-]+(?:, %([\w.\-]+))?",
-        text)
-    for name, op, update in writes:
-        assert op != "copy", f"%{name} copies the stacked cache"
-        assert np.prod(np.int64(dims_of[update].split(","))) < slab, (
-            f"%{name} writes [{dims_of[update]}] into the stacked cache")
+    for stack in stacks:
+        assert stack in text
+        dims = np.int64(stack[stack.index("[") + 1:-1].split(","))
+        slab = np.prod(dims[1:])
+        writes = re.findall(
+            r"%([\w.\-]+) = " + re.escape(stack)
+            + r"\S* (copy|dynamic-update-slice)\(%[\w.\-]+(?:, %([\w.\-]+))?",
+            text)
+        for name, op, update in writes:
+            assert op != "copy", f"%{name} copies the stacked cache"
+            assert np.prod(np.int64(dims_of[update].split(","))) < slab, (
+                f"%{name} writes [{dims_of[update]}] into the stacked cache")
+    if program != "decode":
+        # rows, then layers: the outer loop's trip count is the admit's data
+        assert len(re.findall(r" while\(", text)) == 2
+        if model == "hybrid":    # one row's pass still takes the kernels:
+            assert text.count(KERNEL) == 4    # gdn_chunk_fwd x 3, flash_fwd
 
 
 def _slab_ops(text, slots, max_len, chan):

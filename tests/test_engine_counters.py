@@ -61,11 +61,16 @@ def test_admitted_tokens_are_counted_real_and_padded(tiny_cfg, paged):
         c = eng.counters()
         assert c["admit_tokens_real"] == sum(PROMPT_LENS)
         assert c["admit_tokens_real"] == sum(real for _, _, real in seen)
-        # every position of every [prefill_batch, bucket] array is counted
-        # once, as a prompt token or as padding
+        # every position the chip walked is counted once, as a prompt token
+        # or as padding: the rows that held a request, each rounded up to
+        # its bucket, and no row of the [prefill_batch, bucket] arrays
+        # besides
         assert c["admit_tokens_real"] + c["admit_tokens_padded"] == sum(
-            eng.prefill_batch * bucket for _, bucket, _ in seen)
+            rows * bucket for rows, bucket, _ in seen)
         assert c["admit_tokens_padded"] > 0
+        assert any(rows < eng.prefill_batch for rows, _, _ in seen)
+        assert eng.admit_rows_padded == 0
+        assert eng.breakdown()["padding_fraction"] == 0.0
         assert c["admitted_requests"] == c["first_tokens"] == len(prompts)
         assert sum(rows for rows, _, _ in seen) == len(prompts)
         # the row accounting the committed readers use is untouched
@@ -91,8 +96,9 @@ def test_prefix_hit_counts_only_the_prefilled_suffix(tiny_cfg):
         rows, bucket, real = seen[-1]
         assert (rows, real) == (1, len(second) - reused)
         assert bucket == eng._bucket_for(len(second) - reused)
+        # one row an admit, walked to the end of its bucket
         assert (after["admit_tokens_real"] + after["admit_tokens_padded"]
-                == sum(eng.prefill_batch * b for _, b, _ in seen))
+                == sum(rows * b for rows, b, _ in seen))
     finally:
         eng.shutdown()
 
