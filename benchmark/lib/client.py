@@ -29,10 +29,21 @@ process's first request fires 25 ms late and its first call to the replica
 takes tens of ms more now and then, which a user of a long-lived ingress
 never sees, and the first request of a pre-roll sets the phase of every
 dispatch after it (PERF.md, PR 28).
+
+The caller runs with Python's cyclic collector off (``gc.disable()``).  The
+program's ``ReferenceCounter`` guards its counts with a plain lock that
+``ObjectRef.__del__`` takes too; a collection that starts on the IO thread
+while ``add_local_ref`` holds the lock (every token makes a ref) finalizes
+a ref there, and the thread waits for itself: the stream never ends and
+the process is lost (PERF.md section 7, PR 34: one request in 300 at 6
+requests/s).  With the collector off no finalizer runs inside the lock; a
+caller lives for one run and makes no cycles to speak of.  To go when the
+program's lock is re-entrant.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import sys
 import time
@@ -72,6 +83,7 @@ INGRESS = {"native_generator": native_generator,
 
 def main(argv) -> int:
     address, deployment, traffic_json, vocab, seed, timeout_s = argv
+    gc.disable()      # the module's text says why
     import ray_tpu
 
     from benchmark.lib import loadgen

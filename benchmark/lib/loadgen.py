@@ -44,7 +44,7 @@ import subprocess
 import sys
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -327,6 +327,8 @@ class LoadRun:
         self._pool = ThreadPoolExecutor(max_workers=max_outstanding,
                                         thread_name_prefix="loadgen")
         self._inflight: Dict[int, Tuple[Planned, float]] = {}
+        self.nothing_more = threading.Event()
+        self.running = True           # until the drain has ended
 
     def _now(self) -> float:
         return time.monotonic() - self.epoch
@@ -341,14 +343,23 @@ class LoadRun:
             self.samples.append(s)
 
     def _finish(self, futures):
+        """Wait for the requests in flight: to the drain's deadline, or until
+        ``nothing_more`` is set (the runner sets it once the system holds no
+        request any more: what is still open then will not end, and waiting
+        out the grace only makes the run longer; it counts as failed either
+        way)."""
         deadline = self.epoch + self.stop_at + self.drain_grace_s
         for f in futures:
-            try:
-                f.result(timeout=max(0.0, deadline - time.monotonic()))
-            except Exception:  # noqa: BLE001 — timeout: counted below
+            while not f.done():
+                left = deadline - time.monotonic()
+                if left <= 0 or self.nothing_more.is_set():
+                    break
+                wait([f], timeout=min(left, 0.25))
+            if not f.done():
                 break
         with self._lock:
             self.unfinished = list(self._inflight.values())
+        self.running = False
         self._pool.shutdown(wait=False, cancel_futures=True)
 
     def run_open(self, plan: List[Planned]):

@@ -36,21 +36,6 @@ def prefill_seconds(ctx: dict) -> Optional[float]:
     return seconds
 
 
-def streamed(samples) -> bool:
-    """Did tokens reach the callers while their requests ran?  True when,
-    over the completed requests of 8 tokens or more, the median request got
-    its middle token with at least a twentieth of its life (send to end)
-    still to come.  On the polling ingress (``lib/client.py``) every token
-    arrives at the end, and a first-token time then says nothing about
-    when prefill ran."""
-    left = sorted(
-        (s.t_end - s.token_times[len(s.token_times) // 2])
-        / (s.t_end - s.t_fired)
-        for s in samples
-        if len(s.token_times) >= 8 and s.t_end > s.t_fired)
-    return bool(left) and left[len(left) // 2] >= 0.05
-
-
 def in_flight(ctx: dict) -> list:
     """Requests whose life, send to end, overlaps the traced span."""
     t0, t1 = ctx["span"]["t0"], ctx["span"]["t1"]

@@ -14,10 +14,14 @@ import time
 
 from ..lib import loadgen, manifest, rollup
 from ..lib.peaks import peaks_for
-from .common import CellFailed, compact, dump, result_line, say
+from .common import (CellFailed, StallClock, compact, dump, result_line,
+                     say)
 
 #: the traced span inside the window, unless the traffic file says otherwise
 TRACE_START_SHARE, TRACE_SECONDS_MAX, TRACE_SHARE_MAX = 0.4, 6.0, 0.25
+#: the drain ends early after this many looks, this far apart, at an engine
+#: that holds no request and has made no token since the last look
+IDLE_POLLS, IDLE_POLL_S = 5, 1.0
 
 
 def _wait_exited(pids, timeout_s: float = 60.0):
@@ -59,6 +63,7 @@ class Replica:
         self.cell, self.seed = cell, seed
         self.want = cell.config.get("platform", "tpu")
         self.pids = []
+        self.gave_up = False      # a drain ended on an idle engine
 
     def __enter__(self):
         cell, want = self.cell, self.want
@@ -192,25 +197,44 @@ class Replica:
             time.sleep(max(0.0, epoch + seconds - time.monotonic()))
             marks["stats1"] = self.call("stats", timeout_s=60)
             marks["compile1"] = self.call("compile_state", timeout_s=60)
+            # the drain: once the engine has held no request for IDLE_POLLS
+            # looks in a row, a stream still open will not end (PERF.md, the
+            # stream that never ends): stop waiting out the grace for it
+            idle, made = 0, None
+            while load.running and idle < IDLE_POLLS:
+                time.sleep(IDLE_POLL_S)
+                now = self.call("stats", timeout_s=60)
+                quiet = now.get("active") == 0 and now.get("tokens_out") == made
+                made = now.get("tokens_out")
+                idle = idle + 1 if quiet else 0
+            if load.running:
+                say(f"the engine has held no request for "
+                    f"{IDLE_POLLS * IDLE_POLL_S:.0f}s: the drain ends")
+                load.nothing_more.set()
+                self.gave_up = True
 
         edge = threading.Thread(target=at_window_edges, name="bench-edges")
         edge.start()
         if on_epoch:
             on_epoch(epoch)
-        if traffic["loop"] == "open":
-            load.run_open(plan)
-        else:
-            load.run_closed(plan, start_at=-pre_s)
-        edge.join()
+        with StallClock() as clock:
+            if traffic["loop"] == "open":
+                load.run_open(plan)
+            else:
+                load.run_closed(plan, start_at=-pre_s)
+            edge.join()
         roll = rollup.serve_window(load.samples, load.unfinished, seconds,
                                    seconds + grace)
+        # what the machine did to the run, beside what the run read
+        roll["host_stalls_preroll"] = clock.between(epoch - pre_s, epoch)
+        roll["host_stalls"] = clock.between(epoch, epoch + seconds)
         limits = traffic.get("limits")
         if limits:
             roll["share_meeting_limits"] = rollup.share_meeting(
                 load.samples, seconds, limits["ttft_ms"] / 1e3,
                 limits["tpot_ms"] / 1e3)
         return {"roll": roll, "samples": load.samples, "epoch": epoch,
-                **marks}
+                "unfinished": load.unfinished, **marks}
 
 
 def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
@@ -221,8 +245,32 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
             result = _measure(rep, pool, cell, seed, seconds, trace,
                               t_process_start, dump_path)
         finally:
-            pool.close()      # a caller still mid-request is killed here
+            # a caller still mid-request is killed here; at once where the
+            # drain has shown that its stream will not end
+            pool.close(2.0 if rep.gave_up else 20.0)
     result_line(*result)      # the last line, after the teardown's
+
+
+def say_failed(rep: Replica, win: dict, seconds: float):
+    """What each failed request looked like to its caller, and whether the
+    engine still holds anything once the drain has ended: a request the
+    engine has finished and its caller never saw end is the program's
+    delivery path's (PERF.md, the stream that never ends)."""
+    for s in win["samples"]:
+        if 0.0 <= s.t_start < seconds and not s.complete:
+            say(f"failed request: scheduled {s.t_start:.3f} prompt "
+                f"{s.prompt_len} got {len(s.token_times)} of "
+                f"{s.expected_tokens} tokens, last at "
+                f"{(s.token_times or [float('nan')])[-1]:.3f}, ended "
+                f"{s.t_end:.3f}: {s.error or 'short'}")
+    for req, t in win["unfinished"]:
+        say(f"failed request: scheduled {t:.3f} prompt {req.prompt_len} "
+            f"asked {req.output_len} tokens: no end by the drain's deadline")
+    now = rep.call("stats", timeout_s=60)
+    say("engine after the drain: " + compact(
+        {k: now.get(k) for k in ("active", "free_slots", "tokens_out",
+                                 "delivered_tokens", "admitted_requests",
+                                 "first_tokens")}))
 
 
 def _measure(rep: Replica, pool, cell: manifest.Cell, seed: int,
@@ -243,7 +291,11 @@ def _measure(rep: Replica, pool, cell: manifest.Cell, seed: int,
     say("window: " + compact(roll))
     dump(dump_path, cell=cell.name, seed=seed, seconds=seconds, roll=roll,
          samples=[dataclasses.asdict(s) for s in win["samples"]],
+         unfinished=[[dataclasses.asdict(r), t]
+                     for r, t in win["unfinished"]],
          stats0=win["stats0"], stats1=win["stats1"])
+    if roll["failed"]:
+        say_failed(rep, win, seconds)
     say("engine over the window: " + compact(
         {k: win["stats1"][k] - win["stats0"][k]
          for k in ("steps", "tokens_out", "admit_batches")}))
@@ -257,6 +309,12 @@ def _measure(rep: Replica, pool, cell: manifest.Cell, seed: int,
         say(f"COMPILED INSIDE THE WINDOW: {win['compile0']} -> "
             f"{win['compile1']}")
     correct = bool(ref["ok"] and no_compile)
+    chk = cell.config["serve"]["check"]
+    compared = {
+        "max_abs_diff": {"value": ref["max_abs_diff"],
+                         "limit": chk["tol_max_abs"]},
+        "rms_diff": {"value": ref["rms_diff"], "limit": chk["tol_rms"]},
+        "compiled_in_window": {"value": int(not no_compile), "limit": 0}}
     dev = rep.dev
     device = {"platform": dev["platform"], "kind": dev["kind"],
               "count": dev["count"],
@@ -292,4 +350,4 @@ def _measure(rep: Replica, pool, cell: manifest.Cell, seed: int,
     else:
         metrics = manifest.metric_line(roll, cell.metrics("end_to_end"))
     return (correct, roll["attempted"], roll["failed"], metrics, device,
-            breakdown)
+            breakdown, compared, roll["host_stalls"])

@@ -21,6 +21,7 @@ another object), and imports are absolute.
 from __future__ import annotations
 
 import asyncio
+import functools
 import json
 import os
 import time
@@ -109,9 +110,17 @@ class BenchLLMServer(LLMServer):
     # ----------------------------------------------------------- the tracer
 
     async def trace_start(self, trace_dir: str) -> dict:
+        """The device's operations and the ``TraceAnnotation`` spans, and
+        not the Python tracer (on by default): nothing reads its events,
+        and its hook on every call in every thread of a replica with some
+        tens of open streams delayed their tokens by 0.7 s and made the
+        arrivals of a traced window a second late (PERF.md, PR 34)."""
         jax = self.engine._jax
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
         await asyncio.get_event_loop().run_in_executor(
-            self._pool, jax.profiler.start_trace, trace_dir)
+            self._pool, functools.partial(
+                jax.profiler.start_trace, trace_dir, profiler_options=options))
         self._trace = {"dir": trace_dir, "t0": time.monotonic(),
                        "stats0": self.stats()}
         return {"t0": self._trace["t0"]}

@@ -14,6 +14,20 @@ PATH = os.path.join(REPO, "BENCHMARK.json")
 M = json.load(open(PATH))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+MISTRAL_7B = dict(hidden_size=4096, intermediate_size=14336,
+                  num_attention_heads=32, num_key_value_heads=8,
+                  vocab_size=32768, rope_theta=1000000.0)
+#: configuration -> the widths its source publishes (a new configuration
+#: adds its line here)
+WIDTHS = {
+    "mistral-7b-v0.3-serve-l14": MISTRAL_7B,
+    "mistral-7b-v0.3-train-l8": MISTRAL_7B,
+    "olmo-hybrid-7b-serve-l12": dict(
+        hidden_size=3840, intermediate_size=11008, vocab_size=100352,
+        num_attention_heads=30, num_key_value_heads=30,
+        linear_num_key_heads=30, linear_key_head_dim=96,
+        linear_value_head_dim=192, linear_conv_kernel_dim=4),
+}
 
 
 def test_keys_names_and_sizes():
@@ -70,11 +84,9 @@ def test_every_name_resolves_to_its_file():
         assert sorted(doc["reduced"]) == sorted(entry["reduced"])
         for key in ("assumed", "departures", "stands_for", "kind"):
             assert key in doc
-        # Mistral-7B-v0.3's published widths, unchanged
-        assert (doc["hidden_size"], doc["intermediate_size"],
-                doc["num_attention_heads"], doc["num_key_value_heads"],
-                doc["vocab_size"], doc["rope_theta"]) == \
-            (4096, 14336, 32, 8, 32768, 1000000.0)
+        # the configuration's own published widths, unchanged
+        for key, value in WIDTHS[entry["name"]].items():
+            assert doc[key] == value, (entry["name"], key)
         for m in cell.metrics("per_layer"):
             assert callable(cell.reader(m["name"]))
 

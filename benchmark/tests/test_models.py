@@ -1,8 +1,9 @@
 """A block kind is files: ``lib/manifest.Cell`` finds a configuration's
 mapping, entry points, plain reference and counts by its ``model_type``.
-The counts of ``models/mistral.py`` against literals taken from
-``lib/costs.py`` at the parent of PR 28 (the file it was moved from); a
-second kind added as files in another ``paths`` directory; the failures of
+The counts of each kind's file, cases of one test: ``models/mistral.py``
+against literals taken from ``lib/costs.py`` at the parent of PR 28 (the
+file it was moved from), ``models/olmo_hybrid.py`` against literals worked
+out by hand from the published keys; a second kind added as files in another ``paths`` directory; the failures of
 the lookup; and the rule that nothing else in ``benchmark/`` names a model.
 """
 
@@ -20,6 +21,7 @@ from benchmark.tests.test_runners import REPO, TINY, last_json, run_cell
 
 BENCH = os.path.join(REPO, "benchmark")
 MISTRAL = os.path.join(BENCH, "models", "mistral.py")
+OLMO = os.path.join(BENCH, "models", "olmo_hybrid.py")
 
 # decode at 8.25 active slots of 700.5 live tokens each, train and the flash
 # kernels at the train cell's 4096 tokens and one sequence a chip (a float
@@ -53,10 +55,8 @@ PINNED["tests/tiny/configs/tiny-train.json"] = \
     PINNED["tests/tiny/configs/tiny-serve.json"]
 
 
-@pytest.mark.parametrize("config", sorted(PINNED))
-def test_counts_equal_the_parents(config):
-    doc = json.load(open(os.path.join(BENCH, config)))
-    m, live = load_model(MISTRAL), 8.25 * 700.5
+def mistral_counts(m, doc):
+    live = 8.25 * 700.5
     got = dict(
         layer_params=m.layer_params(doc), num_params=m.num_params(doc),
         train_flops_per_token=m.train_flops_per_token(doc, 4096),
@@ -67,9 +67,66 @@ def test_counts_equal_the_parents(config):
         flash_attention_bytes=m.flash_attention_bytes(doc, 1.0, 4096, True),
         flash_attention_flops_fwd=m.flash_attention_flops(doc, 2, 512, False),
         flash_attention_bytes_fwd=m.flash_attention_bytes(doc, 2, 512, False))
-    assert got == PINNED[config]
     # a dense block holds nothing per slot beyond its keys and values
     assert m.decode_step_bytes(doc, 0, live) == got["decode_step_bytes"]
+    return got
+
+
+# Olmo-Hybrid-7B at 12 layers (9 linear + 3 full), worked out by hand from
+# the published keys: hidden 3840, MLP 11008, vocab 100352 untied, 30 heads
+# of 128, linear layers with 30 key heads of 96 and 30 value heads of 192
+_MIXER = 3840 * (2880 + 2880 + 5760 + 5760) + 5760 * 3840 + 2 * 3840 * 30
+_MLP = 3 * 3840 * 11008
+_SMALL = 9 * (4 * 11520 + 2 * 30 + 192 + 2 * 3840) \
+    + 3 * (2 * 3840 + 2 * 3840) + 3840
+_WEIGHTS = 5_764_761_600          # bf16 bytes a decode step reads
+OLMO_PINNED = dict(
+    mixer=88_704_000, mlp=126_812_160,
+    layer_matrix_params={"linear": _MIXER + _MLP,
+                         "full": 4 * 3840 * 3840 + _MLP},
+    num_params=3_268_268_508,
+    weights=2 * (3_268_268_508 - _SMALL - 100352 * 3840),
+    decode_step_bytes_empty=_WEIGHTS,
+    state_bytes_per_slot=19_906_560, kv_bytes_per_token=46_080,
+    decode_step_bytes=_WEIGHTS + 2 * 24 * 19_906_560 + 24 * 1900 * 46_080,
+    decode_step_flops=24 * _WEIGHTS + 24 * 9 * 30 * 6 * 96 * 192
+    + 4 * 3 * 30 * 128 * 24 * 1900,
+    gdn_recurrent_step_flops=24 * 9 * 30 * 6 * 96 * 192,
+    gdn_recurrent_step_bytes=2 * 19_906_560 + 311_040,
+    gdn_chunk_fwd_bytes=9 * 30 * 1160 * 4096,
+    train_flops_above_six_a_weight=True)
+
+
+def olmo_counts(m, doc):
+    return dict(
+        mixer=_MIXER, mlp=_MLP,
+        layer_matrix_params=m.layer_matrix_params(doc),
+        num_params=m.num_params(doc),
+        weights=_WEIGHTS,
+        decode_step_bytes_empty=m.decode_step_bytes(doc, 0, 0),
+        state_bytes_per_slot=m.state_bytes_per_slot(doc),
+        kv_bytes_per_token=m.kv_bytes_per_token(doc),
+        decode_step_bytes=m.decode_step_bytes(doc, 24, 24 * 1900),
+        decode_step_flops=m.decode_step_flops(doc, 24, 24 * 1900),
+        gdn_recurrent_step_flops=m.gdn_recurrent_step_flops(doc, 24),
+        gdn_recurrent_step_bytes=m.gdn_recurrent_step_bytes(doc, 1),
+        gdn_chunk_fwd_bytes=m.gdn_chunk_fwd_bytes(doc, 4096),
+        train_flops_above_six_a_weight=(
+            m.train_flops_per_token(doc, 4096) > 6 * _WEIGHTS / 2))
+
+
+#: configuration file -> (its kind's file, the counts taken, their values)
+COUNTS = {config: (MISTRAL, mistral_counts, want)
+          for config, want in PINNED.items()}
+COUNTS["configs/olmo-hybrid-7b-serve-l12.json"] = (OLMO, olmo_counts,
+                                                   OLMO_PINNED)
+
+
+@pytest.mark.parametrize("config", sorted(COUNTS))
+def test_counts_from_the_published_keys(config):
+    kind, counts, want = COUNTS[config]
+    doc = json.load(open(os.path.join(BENCH, config)))
+    assert counts(load_model(kind), doc) == want
 
 
 def test_the_mapping_refuses_what_the_dense_block_cannot_express():
@@ -163,6 +220,10 @@ def test_the_replica_compares_with_the_kinds_own_reference(tmp_path):
 
 # ------------------------------------------------ the lookup's own failures
 
+#: a published ``model_type`` no directory of ``paths`` has a file for
+NO_KIND = "a_kind_with_no_file"
+
+
 def broken(tmp_path, change):
     root = tmp_path / "bench"
     shutil.copytree(TINY, root)
@@ -177,14 +238,15 @@ def broken(tmp_path, change):
 
 
 def test_a_model_type_with_no_file_names_the_paths_tried(tmp_path):
-    root = broken(tmp_path, lambda d: d.update(model_type="olmo_hybrid"))
+    assert not os.path.exists(os.path.join(BENCH, "models", NO_KIND + ".py"))
+    root = broken(tmp_path, lambda d: d.update(model_type=NO_KIND))
     with pytest.raises(ManifestError) as e:
         Cell(str(root / "BENCHMARK.json"), "tiny-open")
-    assert str(root / "models" / "olmo_hybrid.py") in str(e.value)
-    assert os.path.join(BENCH, "models", "olmo_hybrid.py") in str(e.value)
+    assert str(root / "models" / (NO_KIND + ".py")) in str(e.value)
+    assert os.path.join(BENCH, "models", NO_KIND + ".py") in str(e.value)
     # and through the command: no result line
     p = run_cell(str(root / "BENCHMARK.json"), "tiny-open")
-    assert p.returncode != 0 and "olmo_hybrid.py" in p.stderr
+    assert p.returncode != 0 and NO_KIND + ".py" in p.stderr
     assert not any(line.startswith("{") for line in p.stdout.splitlines())
 
 

@@ -1,23 +1,9 @@
-"""The per-layer readers that join the trace with the callers' samples: the
-two sets have to be the same requests."""
-
-import importlib.util
-import os
-
-import pytest
+"""What the per-layer readers share that joins the trace with the callers'
+samples: the requests in flight during the traced span, whichever way their
+tokens travel."""
 
 from benchmark.lib import readers
 from benchmark.lib.loadgen import Sample
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def reader(name):
-    spec = importlib.util.spec_from_file_location(
-        "m", os.path.join(HERE, "..", "layer_metrics", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
 
 
 def request(t_fired, ttft, n, gap, prompt):
@@ -48,21 +34,6 @@ SAMPLES = [request(5.0, 1.0, 200, 0.1, 900),     # prefilled long before
            request(19.5, 1.0, 100, 0.1, 300),    # first token at 20.5: in
            request(24.0, 1.5, 50, 0.1, 700),     # first token at 25.5: in
            request(25.5, 1.0, 50, 0.1, 500)]     # first token at 26.5: after
-
-
-def test_prefill_tokens_are_those_prefilled_in_the_span():
-    got = reader("prefill_ms_per_ktoken")(ctx(SAMPLES))
-    assert got == pytest.approx(0.5 * 1000.0 / ((300 + 700) / 1000.0))
-
-
-def test_prefill_reader_returns_nothing_when_tokens_arrive_at_the_end():
-    late = [at_its_end(s) for s in SAMPLES]
-    assert readers.streamed(SAMPLES) and not readers.streamed(late)
-    assert reader("prefill_ms_per_ktoken")(ctx(late)) is None
-    # and nothing when batches were admitted and no prefill program named
-    c = ctx(SAMPLES)
-    c["trace"]["programs"] = [["jit__lambda", 2.0, 7]]
-    assert reader("prefill_ms_per_ktoken")(c) is None
 
 
 def test_in_flight_is_send_to_end_whichever_way_tokens_travel():

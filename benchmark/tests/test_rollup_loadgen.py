@@ -131,3 +131,47 @@ def test_load_run_abandons_what_outlives_the_grace():
     release.set()
     assert [s.t_start for s in run.samples] == [0.0]
     assert [r.index for r, _t in run.unfinished] == [1]
+
+
+def test_load_run_stops_waiting_once_nothing_more_can_come():
+    """The runner sets ``nothing_more`` when the system holds no request
+    any more: the drain ends there and not at the grace's end, and what is
+    still open counts as unfinished either way."""
+    import threading
+    import time
+    release = threading.Event()
+
+    def fire(req, t_start):
+        if req.index == 1:
+            release.wait(10)
+        return Sample(t_start, t_start, [t_start + 0.01], 1, 1,
+                      t_start + 0.01)
+
+    epoch = time.monotonic()
+    run = loadgen.LoadRun(fire, epoch, stop_at=0.1, drain_grace_s=8.0)
+    threading.Timer(0.5, run.nothing_more.set).start()
+    assert run.running
+    run.run_open([Planned(0.0, 1, 1, -1, 0), Planned(0.05, 1, 1, -1, 1),
+                  Planned(0.06, 1, 1, -1, 2)])
+    took = time.monotonic() - epoch
+    release.set()
+    assert 0.4 < took < 3.0 and not run.running
+    assert sorted(s.t_start for s in run.samples) == [0.0, 0.06]
+    assert [r.index for r, _t in run.unfinished] == [1]
+
+
+def test_stall_clock_counts_the_stops_that_ended_in_a_span():
+    """``StallClock.between`` reads the window's stops and no others; the
+    thread starts, ticks and ends with its block."""
+    import time
+
+    from benchmark.runners.common import StallClock
+    with StallClock(tick_s=0.002, late_s=5.0) as clock:
+        time.sleep(0.02)
+    assert not clock._thread.is_alive() and clock.stalls == []
+    clock.stalls = [(9.9, 0.2), (10.5, 0.1), (12.0, 6.9), (60.0, 1.0)]
+    got = clock.between(10.0, 60.0)
+    assert got["n"] == 2 and abs(got["longest_ms"] - 6900.0) < 1e-6
+    assert abs(got["total_ms"] - 7000.0) < 1e-6
+    assert clock.between(0.0, 9.0) == {"n": 0, "longest_ms": 0.0,
+                                       "total_ms": 0.0}

@@ -37,6 +37,13 @@ def last_json(p):
                                   "memory_peak_bytes"}
     for m in out["metrics"].values():
         assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
+    # each number that decided ``correct`` beside its limit: the line's last
+    # key, and the run's last lines on standard error
+    assert list(out)[-1] == "compared" and out["compared"]
+    tail = p.stderr.strip().splitlines()[-len(out["compared"]):]
+    for line, (name, c) in zip(tail, out["compared"].items()):
+        assert set(c) == {"value", "limit"}
+        assert line.startswith(f"compared {name}: "), line
     return out
 
 
