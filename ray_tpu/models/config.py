@@ -84,9 +84,48 @@ class TransformerConfig:
     qk_norm: bool = False           # RMSNorm over the whole q and k rows
     norm_on_output: bool = False    # x + norm(f(x)), the hybrid blocks' wiring
     no_positions: bool = False      # neither rotary nor learned positions
+    # latent attention (models/latent.py; DeepSeek-V2's MLA, whose keys these
+    # are): ``kv_lora_rank`` > 0 turns it on.  Queries and keys have heads
+    # of ``qk_nope_head_dim + qk_rope_head_dim``, values of ``v_head_dim``;
+    # a token caches one row of ``kv_lora_rank + qk_rope_head_dim`` a layer,
+    # shared by all heads.  ``head_dim`` is then not hidden / heads.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN on the rotary dimensions (``rope_yarn_factor`` > 0)
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
+    # dropless expert layers (ops/moe.py ``moe_dropless``): no capacity, a
+    # token's output does not depend on its batch.  ``num_experts`` routed
+    # experts of width ``expert_mlp_size`` with sigmoid scores and a
+    # selection bias, ``shared_experts`` more on every token; the first
+    # ``dense_prefix_layers`` layers keep a dense MLP of ``mlp_size``.
+    moe_dropless: bool = False
+    expert_mlp_size: int = 0
+    shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    dense_prefix_layers: int = 0
+    # ``hc_mult`` > 1 residual streams mixed by manifold-constrained
+    # hyper-connections (models/latent.py ``hc_*``; arXiv 2512.24880)
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+
+    #: the fields that only the serving path's one walk over the layers
+    #: (models/decode.py ``layer_stack``) knows
+    SERVED_ONLY = ("kv_lora_rank", "moe_dropless", "dense_prefix_layers",
+                   "hc_mult")
 
     def __post_init__(self):
         pat = self.layer_pattern
+        self._check_served_only()
         if not pat:
             if self.qk_norm or self.norm_on_output:
                 raise ValueError("qk_norm and norm_on_output are wired for "
@@ -107,9 +146,82 @@ class TransformerConfig:
             raise ValueError("a 'linear' layer needs linear_num_heads, "
                              "linear_key_dim and linear_value_dim")
 
+    def _check_served_only(self):
+        on = [f for f in self.SERVED_ONLY if getattr(self, f)]
+        if on and self.layer_pattern:
+            raise ValueError(f"{on} do not combine with a layer_pattern "
+                             "(models/hybrid.py wires its own blocks)")
+        if self.kv_lora_rank:
+            if not (self.q_lora_rank and self.qk_nope_head_dim
+                    and self.qk_rope_head_dim and self.v_head_dim):
+                raise ValueError(
+                    "latent attention (kv_lora_rank) needs q_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+            if self.num_kv_heads != self.num_heads:
+                raise ValueError("latent attention has one key and one "
+                                 "value head a query head: num_kv_heads "
+                                 f"{self.num_kv_heads} != num_heads")
+            if not (self.use_rope and self.use_rmsnorm) \
+                    or self.use_qkv_bias or self.attn_logit_softcap:
+                raise ValueError("latent attention is rotary, RMS-normed, "
+                                 "without biases or softcap")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError("qk_rope_head_dim must be even")
+        elif self.rope_yarn_factor:
+            raise ValueError("rope_yarn_factor is read by latent attention "
+                             "only (models/latent.py)")
+        if self.rope_yarn_factor and not self.rope_yarn_original_max:
+            raise ValueError("rope_yarn_factor needs rope_yarn_original_max")
+        if self.moe_dropless:
+            if not (self.num_experts > 1 and self.expert_mlp_size
+                    and self.use_swiglu):
+                raise ValueError("moe_dropless needs num_experts > 1, "
+                                 "expert_mlp_size and a SwiGLU MLP")
+            if not 0 < self.experts_per_token <= self.num_experts:
+                raise ValueError(
+                    f"experts_per_token {self.experts_per_token} of "
+                    f"{self.num_experts} experts")
+            if not 0 <= self.dense_prefix_layers < self.num_layers:
+                raise ValueError(
+                    f"dense_prefix_layers {self.dense_prefix_layers} of "
+                    f"{self.num_layers} layers leaves no expert layer")
+        elif self.dense_prefix_layers or self.shared_experts \
+                or self.expert_mlp_size:
+            raise ValueError("dense_prefix_layers, shared_experts and "
+                             "expert_mlp_size belong to moe_dropless")
+        if self.hc_mult == 1 or self.hc_mult < 0:
+            raise ValueError(f"hc_mult {self.hc_mult}: 0 (one residual "
+                             "stream) or at least 2")
+
     @property
     def head_dim(self) -> int:
+        if self.kv_lora_rank:
+            raise AttributeError(
+                "latent attention has no one head_dim: qk_head_dim for "
+                "queries and keys, v_head_dim for values")
         return self.hidden_size // self.num_heads
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Width of the one row a token caches a layer under latent
+        attention: the compressed keys and values, then the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def served_only(self) -> Tuple[str, ...]:
+        """The mechanisms of this configuration that only the serving path
+        runs (no backward, no pages, no window of several tokens, no
+        mesh)."""
+        return tuple(f for f in self.SERVED_ONLY if getattr(self, f))
+
+    @property
+    def expert_layers(self) -> int:
+        return (self.num_layers - self.dense_prefix_layers
+                if self.moe_dropless else 0)
 
     @property
     def learned_positions(self) -> bool:
@@ -131,6 +243,8 @@ class TransformerConfig:
     def num_params(self) -> int:
         """Approximate parameter count (for MFU math)."""
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
+        if self.served_only:
+            return self._served_only_params()
         attn = h * h + 2 * h * (self.num_kv_heads * self.head_dim) + h * h
         if self.layer_pattern:
             kd = self.linear_num_heads * self.linear_key_dim
@@ -146,9 +260,38 @@ class TransformerConfig:
         emb = v * h * (1 if self.tied_embeddings else 2)
         return L * (attn + mlp) + emb
 
+    def _served_only_params(self) -> int:
+        """Matrix parameters where latent attention, dropless experts, a
+        dense prefix or hyper-connections are on."""
+        h, nh, L = self.hidden_size, self.num_heads, self.num_layers
+        if self.kv_lora_rank:
+            attn = (h * self.q_lora_rank
+                    + self.q_lora_rank * nh * self.qk_head_dim
+                    + h * self.latent_row
+                    + self.kv_lora_rank * nh * (self.qk_nope_head_dim
+                                                + self.v_head_dim)
+                    + nh * self.v_head_dim * h)
+        else:
+            attn = 2 * h * h + 2 * h * self.num_kv_heads * (h // nh)
+        dense = 3 * h * self.mlp_size
+        sparse = dense
+        if self.moe_dropless:
+            sparse = (3 * h * self.expert_mlp_size
+                      * (self.num_experts + self.shared_experts)
+                      + h * self.num_experts)
+        n = self.hc_mult
+        hc = 2 * n * h * (2 * n + n * n) if n else 0
+        return (L * (attn + hc) + self.dense_prefix_layers * dense
+                + (L - self.dense_prefix_layers) * sparse
+                + self.vocab_size * h * (1 if self.tied_embeddings else 2))
+
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Training FLOPs/token ≈ 6*N_active + attention quadratic term."""
         h, L = self.hidden_size, self.num_layers
+        if self.served_only:
+            raise NotImplementedError(
+                f"{self.served_only} are served, not trained: no training "
+                "FLOPs a token")
         if self.layer_pattern:
             # the quadratic term for the full layers only; the mixer's state
             # update and read are 4 * key_dim * value_dim a head a token

@@ -23,7 +23,10 @@ says which mixers apply (``prefill``, ``window_step``):
   ``paged_decode.page_attention`` for both;
 * a recurrent ``state`` and a ``conv`` tail beside the rows, where the
   configuration has a ``layer_pattern`` (``hybrid.py``):
-  ``hybrid.linear_prefill`` / ``linear_step`` for its "linear" layers.
+  ``hybrid.linear_prefill`` / ``linear_step`` for its "linear" layers;
+* ``latent`` rows and ``rope_key`` columns, one compressed row a token a
+  layer shared by all heads, where the configuration has latent attention
+  (``latent.py``): ``latent.prefill_attention`` / ``decode_attention``.
 
 TPU-first design:
 * **Static shapes.**  Continuous batching admits/retires sequences by slot
@@ -63,19 +66,50 @@ from .config import TransformerConfig
 from .transformer import Params, _norm, lm_head_logits
 
 KVCache = Dict[str, jnp.ndarray]
+#: the two arrays of a latent cache (``latent.py``)
+LATENT = ("latent", "rope_key")
+#: the optional record of the routers' choices (``init_kv_cache``)
+CHOICES = "expert_choices"
 
 
 def init_kv_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
-                  dtype=jnp.bfloat16) -> KVCache:
+                  dtype=jnp.bfloat16, expert_choices: bool = False) -> KVCache:
     """Allocate the HBM cache: K/V per full-attention layer per slot, plus
     per-slot lengths.  A model with recurrent layers keeps a state and a
-    convolution tail for those beside it (``hybrid.init_state``)."""
+    convolution tail for those beside it (``hybrid.init_state``); a model
+    with latent attention keeps compressed rows instead of K/V
+    (``latent.init_cache``) and, with dropless experts, two running counts
+    of what they did (``moe_counts``: assignments, experts touched).
+    ``expert_choices`` adds a record of every cached token's routing
+    ([expert layers, slots, max_len, k] int32, -1 where nothing was routed):
+    ``prefill`` and ``window_step`` write it where the tree has it, as they
+    write the token's row.  No engine asks for it; a comparison with a
+    reference does, because a choice between two experts that score alike is
+    the one thing here a rounding can turn over."""
+    length = jnp.zeros((num_slots,), jnp.int32)
+    if cfg.kv_lora_rank:
+        from . import latent
+        cache = dict(latent.init_cache(cfg, num_slots, max_len, dtype),
+                     length=length)
+    else:
+        cache = _init_rows(cfg, num_slots, max_len, dtype, length)
+    if cfg.moe_dropless:
+        cache["moe_counts"] = jnp.zeros((2,), jnp.int32)
+    if expert_choices:
+        cache[CHOICES] = jnp.full(
+            (cfg.expert_layers, num_slots, max_len, cfg.experts_per_token),
+            -1, jnp.int32)
+    return cache
+
+
+def _init_rows(cfg: TransformerConfig, num_slots: int, max_len: int, dtype,
+               length) -> KVCache:
     shape = (cfg.full_layers, num_slots, max_len,
              cfg.num_kv_heads * cfg.head_dim)
     cache = {
         "k": jnp.zeros(shape, dtype),
         "v": jnp.zeros(shape, dtype),
-        "length": jnp.zeros((num_slots,), jnp.int32),
+        "length": length,
     }
     if cfg.linear_layers:
         from . import hybrid
@@ -91,18 +125,22 @@ def cache_bytes(cfg: TransformerConfig, num_slots: int, max_len: int,
 
 def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
     """What a cache tree holds, by kind of state: bytes of keys and values
-    (per token) and of everything else a slot keeps (per sequence: a
-    recurrent state, a convolution tail), and the layers of each kind."""
-    def nbytes(a):
-        return int(a.size) * jnp.dtype(a.dtype).itemsize
+    and of latent rows (per token) and of everything else a slot keeps (per
+    sequence: a recurrent state, a convolution tail), the layers of each
+    kind, and the experts a layer holds."""
+    def nbytes(*names):
+        return sum(int(a.size) * jnp.dtype(a.dtype).itemsize
+                   for n, a in cache.items() if n in names)
 
-    control = ("length", "block_table")
-    kv = sum(nbytes(a) for n, a in cache.items() if n in ("k", "v"))
-    state = sum(nbytes(a) for n, a in cache.items()
-                if n not in ("k", "v") + control)
-    return {"cache_kv_bytes": kv, "cache_state_bytes": state,
+    per_token = ("k", "v") + LATENT
+    control = ("length", "block_table", "moe_counts", CHOICES)
+    state = nbytes(*(n for n in cache if n not in per_token + control))
+    return {"cache_kv_bytes": nbytes("k", "v"), "cache_state_bytes": state,
+            "cache_latent_bytes": nbytes(*LATENT),
             "linear_layers": cfg.linear_layers,
-            "full_layers": cfg.full_layers}
+            "full_layers": cfg.full_layers,
+            "expert_layers": cfg.expert_layers,
+            "experts_held": cfg.num_experts if cfg.moe_dropless else 0}
 
 
 # ---------------------------------------------------------------------------
@@ -146,10 +184,25 @@ def _rope_per_row(x: jnp.ndarray, positions: jnp.ndarray,
     return out.astype(x.dtype)
 
 
+def _experts(y, lp, cfg: TransformerConfig, live, cast, layer, stacks):
+    """The dropless expert layer (``ops.moe.moe_dropless``) on y: [rows, W,
+    H], of the ``live`` tokens [rows, W], multiplied in ``cast`` (the router
+    scores y as it comes); the experts' weights stay in ``stacks`` [layers,
+    experts, ...], of which this is ``layer``.  Returns (out, (counts [2],
+    the chosen experts [rows, W, k]))."""
+    from ..ops import moe as moe_ops
+    out, counts, idx = moe_ops.moe_dropless(
+        y.reshape(-1, y.shape[-1]), lp["moe"], stacks, layer,
+        experts_per_token=cfg.experts_per_token,
+        scaling=cfg.routed_scaling_factor, compute_dtype=cast,
+        live=None if live is None else live.reshape(-1))
+    return out.reshape(y.shape), (counts, idx.reshape(y.shape[:2] + (-1,)))
+
+
 @jax.named_scope("mlp")
 def _mlp(y, p, cfg: TransformerConfig):
     cast = y.dtype
-    if cfg.num_experts > 1:
+    if "moe" in p:       # with a capacity; a dense prefix layer has none
         from ..ops import moe as moe_ops
         out, _ = moe_ops.moe_mlp(
             y, p["moe"]["router"], p["moe"]["w_gate"], p["moe"]["w_in"],
@@ -225,10 +278,19 @@ def _kv_mixer(attention, cfg: TransformerConfig, *closed) -> Mixer:
     return mixer
 
 
+def _layer_of(stack: Params, index) -> Params:
+    """Layer ``index`` (traced or not) of weights stacked [layers, ...],
+    each a slice its matmul reads where it lies."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, index, 0, keepdims=False),
+        stack)
+
+
 def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
                 mixers: Dict[str, Mixer], carry: Dict[str, Any],
                 cfg: TransformerConfig, compute_dtype,
-                pick: Optional[jnp.ndarray] = None):
+                pick: Optional[jnp.ndarray] = None,
+                live: Optional[jnp.ndarray] = None):
     """The serving forward pass: ``tokens`` [rows, W] at absolute
     ``positions`` [rows, W] through every layer, each layer's mixing done by
     its kind's entry of ``mixers`` on its kind's entry of ``carry`` (the
@@ -237,20 +299,72 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     One ``lax.scan`` over periods of ``cfg.layer_pattern`` with the kinds
     inside a period unrolled, so the trace is one period whatever the depth;
     a model without a pattern is the pattern ``("full",)``, one layer a
-    period.  A block is wired ``x + f(norm(x))``, or ``x + norm(f(x))``
-    under ``cfg.norm_on_output``, for the mixer and the MLP alike.
+    period.  A dense prefix (``cfg.dense_prefix_layers``) is walked before
+    the scan, which is then over the expert layers; a mixer is handed the
+    layer's index among all of them.  A block is wired ``x + f(norm(x))``,
+    ``x + norm(f(x))`` under ``cfg.norm_on_output``, or with ``cfg.hc_mult``
+    residual streams, read, written and mixed around the sublayer by
+    per-token coefficients (``latent.hc_coeff``), for the mixer and the MLP
+    alike.  ``live`` [rows, W] are the tokens that count (None: all); only
+    a dropless expert layer asks, so that a padded position or an idle slot
+    is routed nowhere.
 
     Returns (logits float32, carry, ys): logits [rows, W, V], or [rows, V]
     of position ``pick`` [rows] of each row; ys maps a kind to what its
     mixer returned a layer, stacked [layers of the kind, ...] (None where
-    it returns none)."""
+    it returns none), ``"moe"`` to the expert layers' counts [expert
+    layers, 2] and ``"experts"`` to their routers' choices [expert layers,
+    rows, W, k]."""
     cast = compute_dtype
     pattern, blocks = cfg.layer_pattern or ("full",), params["blocks"]
     per_period = {kind: pattern.count(kind) for kind in mixers}
+    prefix = cfg.dense_prefix_layers
     x = params["embed"]["tokens"][tokens].astype(cast)
     if cfg.learned_positions:
         x = x + params["embed"]["pos"][
             jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
+    if cfg.hc_mult:
+        # the embedding fills every stream; the streams are float32.  Their
+        # mixing is float32 either way (``latent.hc_write``), so it costs
+        # nothing (tokens/s and temporaries equal on the chip), and carried
+        # in ``cast`` the compiler rounds them at other points in one
+        # program than in another: two compilations of one prefill then
+        # chose other experts for 7-23% of (token, layer) pairs, and none
+        # of 24,558 so (PERF.md, PR 35): a token's experts should not depend
+        # on which program ran it.
+        x = jnp.broadcast_to(x[:, :, None].astype(jnp.float32),
+                             x.shape[:2] + (cfg.hc_mult,) + x.shape[2:])
+
+    def layer(x, carry, kind, lp, index, experts=None):
+        """One block on the streams ``x``; returns (x, carry, what the
+        mixer returned, the expert layer's (counts, choices) or None)."""
+        norm = lambda y, name: _norm(y, lp[name], cfg)           # noqa: E731
+
+        # the block's wiring, decided here and nowhere else: what the
+        # sublayer behind ``name``'s norm sees of x, and how its output
+        # joins x
+        def wire(x, name):
+            if cfg.hc_mult:
+                from . import latent
+                pre, post, res = latent.hc_coeff(
+                    x, lp["hc_attn" if name == branch else "hc_mlp"], cfg)
+                return (norm(latent.hc_read(x, pre), name),
+                        lambda out: latent.hc_write(x, out, post, res))
+            if cfg.norm_on_output:
+                return x, lambda out: x + norm(out, name)
+            return norm(x, name), lambda out: x + out
+
+        branch = _BRANCH_NORM[kind]
+        # (what a sublayer multiplies is ``cast``; a router scores what it
+        # is given)
+        seen, join = wire(x, branch)
+        out, carry, rows = mixers[kind](seen.astype(cast), lp, index, carry)
+        x = join(out)
+        seen, join = wire(x, "mlp_norm")
+        if experts is None:
+            return join(_mlp(seen.astype(cast), lp, cfg)), carry, rows, None
+        out, routed = _experts(seen, lp, cfg, live, cast, *experts)
+        return join(out), carry, rows, routed
 
     def period(walk, step):
         (x, carry), (p, dense) = walk, step
@@ -261,31 +375,56 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
             at[kind] += 1
             lp = (_layer_weights(blocks[kind], index) if dense is None
                   else dense)
-            norm = lambda y, name: _norm(y, lp[name], cfg)       # noqa: E731
-            # the block's wiring, decided here and nowhere else
-            before, after = ((lambda y, name: y, norm) if cfg.norm_on_output
-                             else (norm, lambda y, name: y))
-            branch = _BRANCH_NORM[kind]
-            out, carry[kind], rows = mixers[kind](
-                before(x, branch), lp, index, carry[kind])
-            x = x + after(out, branch)
-            x = x + after(_mlp(before(x, "mlp_norm"), lp, cfg), "mlp_norm")
+            x, carry[kind], rows, _ = layer(x, carry[kind], kind, lp, index)
             ys[kind].append(rows)
         return (x, carry), {kind: jax.tree.map(lambda *a: jnp.stack(a), *outs)
                             for kind, outs in ys.items() if outs}
 
+    def expert_layer(walk, p):
+        """A layer after the dense prefix: its small weights indexed out of
+        the stack, its experts' left there for the kernel."""
+        x, carry = walk
+        small = {k: v for k, v in blocks.items() if k != "moe"}
+        routed = ("w_gate", "w_in", "w_out")
+        small["moe"] = {k: v for k, v in blocks["moe"].items()
+                        if k not in routed}
+        x, kv, rows, (counts, chosen) = layer(
+            x, carry["full"], "full", _layer_of(small, p), prefix + p,
+            (p, {k: blocks["moe"][k] for k in routed}))
+        ys = {"moe": counts, "experts": chosen}
+        if rows is not None:
+            ys["full"] = rows
+        return (x, dict(carry, full=kv)), ys
+
     # A layer's weights: a pattern's stacks [periods, n, ...] are indexed in
-    # place (``_layer_weights``); a dense model's one-dimensional stack [L,
-    # ...] is the scan's xs, whose one-layer slices always fused.  Indexed
-    # too, Mistral's programs compiled to other code (prefill temporaries +97
+    # place (``_layer_weights``), as are the layers before and with experts
+    # (``_layer_of``); a dense model's one-dimensional stack [L, ...] is the
+    # scan's xs, whose one-layer slices always fused.  Indexed too,
+    # Mistral's programs compiled to other code (prefill temporaries +97
     # KB) and the open-loop cells read 0.2-0.6% later first tokens on the
     # chip, four pairs of four (PERF.md, PR 31).
-    (x, carry), ys = jax.lax.scan(
-        period, (x, carry), (jnp.arange(cfg.num_layers // len(pattern)),
-                             None if cfg.layer_pattern else blocks))
-    # [periods, layers of the kind a period, ...] -> [layers of the kind, ...]
-    ys = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
-    x = _norm(x, params["final_norm"], cfg)
+    if cfg.moe_dropless:
+        carry, first = dict(carry), []
+        for j in range(prefix):
+            x, carry["full"], rows, _ = layer(
+                x, carry["full"], "full", _layer_of(params["prefix"], j), j)
+            first.append(rows)
+        (x, carry), ys = jax.lax.scan(expert_layer, (x, carry),
+                                      jnp.arange(cfg.num_layers - prefix))
+        if "full" in ys and first:
+            ys["full"] = jax.tree.map(
+                lambda *a: jnp.concatenate([jnp.stack(a[:-1]), a[-1]]),
+                *first, ys["full"])
+    else:
+        (x, carry), ys = jax.lax.scan(
+            period, (x, carry), (jnp.arange(cfg.num_layers // len(pattern)),
+                                 None if cfg.layer_pattern else blocks))
+        # [periods, layers of the kind a period, ...] -> [layers of the
+        # kind, ...]
+        ys = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+    if cfg.hc_mult:                  # the streams are summed before the norm
+        x = x.sum(axis=2)
+    x = _norm(x, params["final_norm"], cfg).astype(cast)
     if pick is not None:
         x = jnp.take_along_axis(x, pick[:, None, None], axis=1)[:, 0]
     return lm_head_logits(params, x, cfg), carry, ys
@@ -329,6 +468,23 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
             {"full": (cache["k"], cache["v"])}, cfg, compute_dtype, last)
         new["k"], new["v"] = carry["full"]
         return new, logits
+    # a padded position is routed to no expert
+    live = (jnp.arange(s)[None] < length[:, None] if cfg.moe_dropless
+            else None)
+    if "latent" in cache:
+        from .latent import prefill_attention as latent_rows
+        logits, carry, ys = layer_stack(
+            params, tokens, positions,
+            {"full": _kv_mixer(latent_rows, cfg, slot, positions)},
+            {"full": tuple(cache[n] for n in LATENT)}, cfg, compute_dtype,
+            last, live)
+        new.update(zip(LATENT, carry["full"]))
+        if CHOICES in cache:
+            new[CHOICES] = jax.lax.dynamic_update_slice(
+                cache[CHOICES], jnp.where(live[None, ..., None],
+                                          ys["experts"], -1),
+                (0, slot, start[0], 0))
+        return new, logits
 
     def rows(y, lp, i, carry):
         out, k, v = prefill_attention(y, lp["attn"], cfg, positions)
@@ -346,7 +502,7 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
         mixers["linear"] = recurrent
     logits, _, ys = layer_stack(params, tokens, positions, mixers,
                                 dict.fromkeys(mixers), cfg, compute_dtype,
-                                last)
+                                last, live)
 
     # every layer's rows [layers of the kind, 1, ...] into the slot, in place
     # on the donated cache (the K/V of the padded tail included; decode's
@@ -473,10 +629,15 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
         span = cache["block_table"].shape[1] * cache["k"].shape[2]
         attend = _kv_mixer(page_attention, cfg, cache["block_table"],
                            positions, active[:, None])
+    elif "latent" in cache:
+        from .latent import decode_attention as latent_step
+        span = cache["latent"].shape[2]
+        attend = _kv_mixer(latent_step, cfg, lengths, active)
     else:
         span = cache["k"].shape[2]
         attend = _kv_mixer(decode_attention, cfg, lengths, active)
-    mixers, carry = {"full": attend}, {"full": (cache["k"], cache["v"])}
+    rows = LATENT if "latent" in cache else ("k", "v")
+    mixers, carry = {"full": attend}, {"full": tuple(cache[n] for n in rows)}
     if "state" in cache:
         from .hybrid import linear_step
         if w != 1:
@@ -489,11 +650,21 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
 
         mixers["linear"] = recurrent
         carry["linear"] = (cache["state"], cache["conv"])
-    logits, carry, _ = layer_stack(params, tokens, positions, mixers, carry,
-                                   cfg, compute_dtype)
+    logits, carry, ys = layer_stack(
+        params, tokens, positions, mixers, carry, cfg, compute_dtype,
+        live=jnp.broadcast_to(active[:, None], tokens.shape)
+        if cfg.moe_dropless else None)
     new = dict(cache, length=jnp.where(
         active, jnp.minimum(lengths + w, span), lengths))
-    new["k"], new["v"] = carry["full"]
+    new.update(zip(rows, carry["full"]))
+    if "moe_counts" in cache:   # what the experts did, over layers and steps
+        new["moe_counts"] = cache["moe_counts"] + ys["moe"].sum(axis=0)
+    if CHOICES in cache:    # each token's at its position; an idle slot's
+        # falls past the end and is dropped
+        new[CHOICES] = cache[CHOICES].at[
+            :, jnp.arange(tokens.shape[0])[:, None],
+            jnp.where(active[:, None], positions, span)].set(
+                ys["experts"], mode="drop")
     if "linear" in carry:
         new["state"], new["conv"] = carry["linear"]
     return new, logits
@@ -631,8 +802,14 @@ def decode_state_loop(params: Params, cache: KVCache, state: Dict[str, Any],
     dispatch would leave the chip waiting on the host.
     Returns (cache, state, emitted [n_steps, slots]).  A slot goes inactive
     the step its budget hits zero or it samples its EOS token; inactive
-    slots repeat their last token (the host emits only to live requests)."""
+    slots repeat their last token (the host emits only to live requests).
+    A cache with ``moe_counts`` gives ``emitted`` one row more: what the
+    dropless expert layers did over these steps, [assignments, experts
+    touched, 0...], so that the counts ride the tokens' one fetch
+    (``split_moe_counts``)."""
     temps, eos, key = state["temps"], state["eos"], state["key"]
+    if "moe_counts" in cache:
+        cache = dict(cache, moe_counts=jnp.zeros_like(cache["moe_counts"]))
 
     def body(carry, i):
         cache, toks, active, budget = carry
@@ -651,4 +828,13 @@ def decode_state_loop(params: Params, cache: KVCache, state: Dict[str, Any],
     state = {"tokens": toks, "active": active, "budget": budget,
              "temps": temps, "eos": eos,
              "key": jax.random.fold_in(key, n_steps)}
+    if "moe_counts" in cache:
+        emitted = jnp.concatenate([emitted, jnp.zeros_like(
+            emitted[:1]).at[0, :2].set(cache["moe_counts"])])
     return cache, state, emitted
+
+
+def split_moe_counts(emitted):
+    """(tokens [n_steps, slots], (assignments, experts touched)) of what
+    ``decode_state_loop`` returned for a cache with ``moe_counts``."""
+    return emitted[:-1], (int(emitted[-1, 0]), int(emitted[-1, 1]))

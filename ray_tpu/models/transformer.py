@@ -86,6 +86,11 @@ class ParallelContext:
 
 def init_params(key: jax.Array, cfg: TransformerConfig,
                 dtype=jnp.float32) -> Params:
+    if cfg.served_only:
+        # latent attention, dropless experts, a dense prefix, residual
+        # streams (models/latent.py)
+        from . import latent
+        return latent.init_params(key, cfg, dtype)
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, m, L = cfg.num_heads, cfg.num_kv_heads, cfg.mlp_size, cfg.num_layers
     keys = iter(jax.random.split(key, 32))
@@ -306,13 +311,22 @@ def embed_tokens(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
 
 
 def refuse_layer_pattern(cfg: TransformerConfig, what: str):
-    """The training path has no layers of two kinds: the gated-delta-rule
-    kernels have no backward and ``apply_trunk`` scans one block kind."""
+    """The training path has no layers of two kinds (the gated-delta-rule
+    kernels have no backward and ``apply_trunk`` scans one block kind) and
+    none of what ``cfg.served_only`` names (latent attention, dropless
+    experts, a dense prefix, residual streams)."""
     if cfg.layer_pattern:
         raise NotImplementedError(
             f"{what}: layer_pattern {cfg.layer_pattern} is served "
             "(models/hybrid.py: prefill, decode_step), not trained: the "
             "linear-attention kernels have no backward")
+    if cfg.served_only:
+        raise NotImplementedError(
+            f"{what}: {', '.join(cfg.served_only)} are served "
+            "(models/latent.py, ops/moe.py moe_dropless: prefill, "
+            "decode_step), not trained: the grouped matmul and the latent "
+            "decode kernel have no backward, and apply_trunk wires one "
+            "residual stream")
 
 
 def apply_trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,

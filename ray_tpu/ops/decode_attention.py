@@ -28,6 +28,21 @@ The twin reshapes a layer's rows to heads and is plain einsums in the same
 precisions.  It is the CPU path, and the path under a mesh of more than one
 device: the compiler cannot partition a Mosaic kernel, and the twin's
 einsums split by KV heads as the cache does.
+
+``mla_decode_attn`` is the latent sibling, on the same work list.  A token
+caches ``C + R`` numbers a layer, shared by every head: the compressed keys
+and values (``C``, after their norm) and the rotary key (``R``), 576 for
+512 + 64.  They lie in two arrays, ``latent`` ``[layers, slots, max_len,
+C]`` and ``rope_key`` ``[layers, slots, R, max_len]`` (a position's rotary
+key is a column): HBM rows come in 128 lanes, so one row of 576 would take
+640, and the layout the compiler picks to avoid that padding for a
+parameter of that shape (positions minor-most) is one the kernel cannot
+read without a copy of the whole cache a call (sandbox compile, PERF.md, PR
+35).  Each head's query arrives carried into the latent space
+(``models/latent.py``, the absorbed form), so a block is a ``[NH, C] x [C,
+block]`` and a ``[NH, R] x [R, block]`` score matmul and one ``[NH, block]
+x [block, C]`` value matmul off the one fetched block of rows: the values
+are the latent rows themselves.
 """
 
 from __future__ import annotations
@@ -46,6 +61,9 @@ from .flash_attention import resolve_interpret
 #: pinned by tests/test_trace_names.py, read by the benchmark's
 #: ``decode_attn_roofline``
 KERNEL_DECODE_ATTN = "decode_attn"
+#: the latent sibling's (``mla_decode_attn [pallas]``), read by the
+#: benchmark's ``mla_decode_attn_roofline``
+KERNEL_MLA_DECODE_ATTN = "mla_decode_attn"
 
 NEG = -1e30
 F32 = jnp.float32
@@ -256,3 +274,134 @@ def decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
     interpret = resolve_interpret(interpret, "decode_attn")
     return _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads,
                                softcap, interpret)
+
+
+# ---------------------------------------------------------------------------
+# The latent sibling: C + R numbers a token, shared by every head
+# ---------------------------------------------------------------------------
+
+def mla_decode_attn_jnp(q_lat, q_rope, latent_all, rope_all, layer, live,
+                        scale: float):
+    """The twin of ``mla_decode_attn``; shapes as there."""
+    rows, keys = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                  for a in (latent_all, rope_all))
+    s = (jnp.einsum("shc,smc->shm", q_lat.astype(rows.dtype), rows,
+                    preferred_element_type=F32)
+         + jnp.einsum("shr,srm->shm", q_rope.astype(keys.dtype), keys,
+                      preferred_element_type=F32)) * scale
+    seen = (jnp.arange(rows.shape[1])[None, :] < live[:, None])[:, None, :]
+    s = jnp.where(seen, s, NEG)
+    p = jnp.where(seen, jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
+    o = jnp.einsum("shm,smc->shc", p.astype(rows.dtype), rows,
+                   preferred_element_type=F32)
+    o = o / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    return o.astype(q_lat.dtype)
+
+
+def _mla_kernel(layer_ref, live_ref, slot_ref, block_ref, total_ref, ql_ref,
+                qr_ref, c_ref, k_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                block: int, scale: float):
+    del layer_ref                               # the index maps' only
+    ti = pl.program_id(0)
+    si, bi = slot_ref[ti], block_ref[ti]
+
+    @pl.when(ti < total_ref[0])
+    def _item():
+        @pl.when(bi == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        rows = c_ref[0, 0]                                        # [T, C]
+        s = (jax.lax.dot_general(ql_ref[0], rows, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=F32)
+             + jnp.dot(qr_ref[0], k_ref[0, 0],
+                       preferred_element_type=F32)) * scale       # [NH', T]
+        pos = bi * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < live_ref[si], s, NEG)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc = alpha * acc_ref[...] + jnp.dot(p.astype(rows.dtype), rows,
+                                             preferred_element_type=F32)
+        m_ref[...], l_ref[...], acc_ref[...] = m_new, l, acc
+
+        @pl.when(bi == (live_ref[si] - 1) // block)   # the slot's last
+        def _flush():
+            o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def _mla_decode_attn_pallas(q_lat, q_rope, latent_all, rope_all, layer, live,
+                            scale: float, interpret: bool):
+    slots, nh, c = q_lat.shape
+    r, max_len = rope_all.shape[2:]
+    nhp = -(-nh // 16) * 16
+    block = block_len(max_len, (c + r) * latent_all.dtype.itemsize)
+    live = live.astype(jnp.int32)
+    slot_of, block_of, total = _plan(live, block, max_len // block)
+
+    def rows(ti, layer, live, slot_of, block_of, total):
+        return (layer[0], slot_of[ti], block_of[ti], 0)
+
+    def keys(ti, layer, live, slot_of, block_of, total):
+        return (layer[0], slot_of[ti], 0, block_of[ti])
+
+    def per_slot(ti, layer, live, slot_of, block_of, total):
+        return (slot_of[ti], 0, 0)
+
+    def padded(q):
+        return jnp.pad(q, ((0, 0), (0, nhp - nh), (0, 0))).astype(
+            latent_all.dtype)
+
+    out = pl.pallas_call(
+        functools.partial(_mla_kernel, block=block, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(jnp.maximum(total, 1),),
+            in_specs=[pl.BlockSpec((1, nhp, c), per_slot),
+                      pl.BlockSpec((1, nhp, r), per_slot),
+                      pl.BlockSpec((1, 1, block, c), rows),
+                      pl.BlockSpec((1, 1, r, block), keys)],
+            out_specs=pl.BlockSpec((1, nhp, c), per_slot),
+            scratch_shapes=[pltpu.VMEM((nhp, 1), F32),
+                            pltpu.VMEM((nhp, 1), F32),
+                            pltpu.VMEM((nhp, c), F32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((slots, nhp, c), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=KERNEL_MLA_DECODE_ATTN,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), live, slot_of, block_of,
+      jnp.reshape(total, (1,)).astype(jnp.int32), padded(q_lat),
+      padded(q_rope), latent_all, rope_all)
+    return jnp.where(live[:, None, None] > 0, out[:, :nh], 0)
+
+
+def mla_decode_attn(q_lat, q_rope, latent_all, rope_all, layer, live,
+                    scale: float, use_kernel: Optional[bool] = None,
+                    interpret: Optional[bool] = None):
+    """Latent attention of one new token a slot over layer ``layer`` of the
+    stacked latent cache.
+
+    q_lat: [slots, NH, C], each head's query carried into the latent space;
+    q_rope: [slots, NH, R], its rotary part; latent_all: [layers, slots,
+    max_len, C] and rope_all: [layers, slots, R, max_len], the token's own
+    entries already written; layer, live: as ``decode_attn``.  Scores are
+    ``scale * (q_lat . latent + q_rope . rope_key)``.  Returns [slots, NH,
+    C] in q_lat's dtype: softmax-weighted latent rows, which the caller
+    carries out of the latent space."""
+    if use_kernel is None:
+        use_kernel = bool(interpret) or (
+            jax.default_backend() == "tpu"
+            and jax.sharding.get_abstract_mesh().size <= 1)
+    if not use_kernel:
+        return mla_decode_attn_jnp(q_lat, q_rope, latent_all, rope_all, layer,
+                                   live, scale)
+    interpret = resolve_interpret(interpret, "mla_decode_attn")
+    return _mla_decode_attn_pallas(q_lat, q_rope, latent_all, rope_all, layer,
+                                   live, scale, interpret)

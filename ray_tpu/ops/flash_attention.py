@@ -38,6 +38,9 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
+#: resident K and V bytes up to which the forward kernel fits the compiler's
+#: default scoped VMEM (16 MiB) with its blocks and products
+FWD_VMEM_DEFAULT = 12 << 20
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
@@ -105,9 +108,18 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
     grid = (b, h, s // block_q)
     kernel = functools.partial(_fwd_kernel, block_kv=block_kv, seq_kv=s,
                                causal=causal, scale=scale)
+    # a head's whole K and V sit in VMEM, double-buffered: past the
+    # compiler's default of 16 MiB (8,192 positions of 256 lanes are 4 MiB
+    # each) the kernel asks for what it needs; below, nothing is passed and
+    # the call compiles as it always did
+    resident = 4 * s * (-(-d // 128) * 128) * k.dtype.itemsize
+    params = ({} if resident <= FWD_VMEM_DEFAULT else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=resident + FWD_VMEM_DEFAULT)})
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
+        **params,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // reps, 0, 0)),

@@ -1,18 +1,50 @@
-"""Mixture-of-Experts layer: top-k routing with capacity-based dense dispatch.
+"""Mixture-of-experts layers, two of them.
 
-TPU-first: dispatch/combine are einsums against one-hot routing tensors so everything
-stays on the MXU with static shapes (the standard TPU MoE formulation; dynamic gather/
-scatter routing is hostile to XLA).  With the expert dimension sharded over the ``ep``
-mesh axis, XLA lowers the dispatch einsum into the expert all-to-all over ICI
-(SURVEY §2.3 EP row: the reference has no MoE support in core — this is first-class).
+``moe_mlp`` / ``top_k_routing``: top-k softmax routing with capacity-based
+dense dispatch, for the training presets (Mixtral).  Dispatch and combine are
+einsums against one-hot ``[T, E, C]`` routing tensors in float32, so
+everything stays on the MXU with static shapes and, with the expert dimension
+sharded over the ``ep`` mesh axis, XLA lowers the dispatch einsum into the
+expert all-to-all over ICI.  It is **not for serving**: each expert accepts
+at most ``C`` tokens and drops the rest, so a token's output depends on the
+batch it came in, prefill-then-decode cannot match a full forward, and the
+one-hot tensors are ``T x E x C`` floats (8 GB at 8,192 tokens, 64 experts).
+
+``moe_dropless``: the serving layer (no backward).  Sigmoid scores in
+float32, the top k of score + selection bias, gates the chosen scores
+normalised and scaled; the ``T x k`` assignments sorted by expert, one
+grouped matmul over the experts that have a token (``moe_gmm``: a Pallas
+kernel, group sizes by scalar prefetch, an expert with no token neither
+fetched nor computed; its twin ``jax.lax.ragged_dot`` on the CPU), combined
+by the gates, beside a shared expert on every token.  No capacity and no
+dropped token: a token's output does not depend on its batch.  The layer is
+told which experts it holds (``expert_start`` and the leading dimension of
+the weights it is given) and routes over all of them: assignments to experts
+it does not hold are left out of its part of the result, as they would be
+computed on the chips that hold those (on one chip the range is every expert
+and there is no exchange; nothing here stands in for absent chips).  The
+expert weights stay where they lie in the layer stack ``[layers, experts,
+...]``: the kernel takes the layer index by scalar prefetch as
+``decode_attn`` does.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import resolve_interpret
+
+#: the grouped matmul's name as a device trace shows it (``moe_gmm
+#: [pallas]``); pinned by tests/test_trace_names.py, read by the benchmark's
+#: ``moe_gmm_roofline``
+KERNEL_MOE_GMM = "moe_gmm"
+F32 = jnp.float32
 
 
 def top_k_routing(router_logits: jnp.ndarray, k: int,
@@ -55,8 +87,10 @@ def top_k_routing(router_logits: jnp.ndarray, k: int,
 def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
             w_in: jnp.ndarray, w_out: jnp.ndarray, experts_per_token: int,
             capacity_factor: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sparse SwiGLU MLP. x: [B, S, H]; router_w: [H, E];
-    w_gate/w_in: [E, H, M]; w_out: [E, M, H]. Returns (out [B,S,H], aux_loss)."""
+    """Sparse SwiGLU MLP with a capacity (training presets; not for serving:
+    tokens over an expert's capacity are dropped, see the module docstring).
+    x: [B, S, H]; router_w: [H, E]; w_gate/w_in: [E, H, M]; w_out: [E, M, H].
+    Returns (out [B,S,H], aux_loss)."""
     b, s, h = x.shape
     e = router_w.shape[-1]
     tokens = x.reshape(b * s, h)
@@ -71,3 +105,221 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
     out_e = jnp.einsum("ecm,emh->ech", act, w_out.astype(act.dtype))
     out = jnp.einsum("tec,ech->th", combine.astype(out_e.dtype), out_e)
     return out.reshape(b, s, h), aux
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer
+# ---------------------------------------------------------------------------
+
+#: rows of the sorted assignments one grid step multiplies, at most: a
+#: group is padded to whole tiles, so a decode step's two or three rows an
+#: expert take the smallest bf16 tile and a prefill row's hundreds the MXU's
+TILE_ROWS = (16, 32, 64, 128, 256)
+#: output channels a grid step computes (a weight block is ``[K, BLOCK_N]``)
+BLOCK_N = 512
+#: the kernel's VMEM: two weight blocks (three where gated), double
+#: buffered, the row tile and its product; the chip has 128 MiB
+VMEM_LIMIT = 64 << 20
+
+
+def route_sigmoid(x, router_w, bias, k: int, scaling: float):
+    """The ``noaux_tc`` router without groups.  x: [T, H]; router_w: [H, E];
+    bias: [E], the selection bias.  Scores ``sigmoid(x W_r)`` in float32;
+    the experts are the top ``k`` of score + bias; the gates are the chosen
+    scores (without the bias) over their sum, times ``scaling``.  Returns
+    (experts [T, k] int32, gates [T, k] float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(F32), router_w.astype(F32),
+                                    precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + bias.astype(F32), k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = gates / (gates.sum(-1, keepdims=True) + 1e-20) * scaling
+    return idx.astype(jnp.int32), gates
+
+
+def tile_rows(assignments: int, experts: int) -> int:
+    """Rows a tile: the mean group, rounded up to one of ``TILE_ROWS``."""
+    mean = -(-assignments // experts)
+    return next((t for t in TILE_ROWS if t >= mean), TILE_ROWS[-1])
+
+
+def sort_by_expert(idx, held, experts: int, tile: int):
+    """Where each assignment goes in the expert-sorted, tile-padded layout.
+
+    idx: [T, k] int32, each assignment's expert among the ``experts`` this
+    layer holds (already shifted by its ``expert_start``); held: [T, k]
+    bool, False for an assignment that is not this layer's (another chip's
+    expert, a dead token).  Every expert's assignments are contiguous and
+    start at a multiple of ``tile``.  Returns (dest [T, k] int32: the
+    assignment's row, ``rows`` for one not held; source [rows] int32: the
+    token of each row, T for padding; tile_expert [rows / tile] int32;
+    tiles: how many tiles hold anything; sizes [experts]: assignments an
+    expert)."""
+    t, k = idx.shape
+    rows = -(-(t * k + experts * (tile - 1)) // tile) * tile
+    flat = jnp.where(held, idx, experts).reshape(-1)
+    sizes = jnp.zeros((experts + 1,), jnp.int32).at[flat].add(1)[:experts]
+    padded = -(-sizes // tile) * tile
+    ends = jnp.cumsum(padded)
+    order = jnp.argsort(flat, stable=True)
+    # rank of an assignment among its expert's, in token order
+    first = jnp.cumsum(sizes) - sizes
+    rank = jnp.zeros_like(flat).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32)) - jnp.append(first, 0)[flat]
+    dest = jnp.where(flat < experts,
+                     jnp.append(ends - padded, 0)[flat] + rank, rows)
+    source = jnp.full((rows,), t, jnp.int32).at[dest].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        ends, jnp.arange(rows // tile, dtype=jnp.int32) * tile,
+        side="right"), experts - 1).astype(jnp.int32)
+    return (dest.reshape(t, k).astype(jnp.int32), source, tile_expert,
+            (ends[-1] // tile).astype(jnp.int32), sizes)
+
+
+def _gmm_kernel(layer_ref, expert_ref, tiles_ref, x_ref, *refs, gated: bool):
+    del layer_ref, expert_ref                   # the index maps' only
+    o_ref = refs[-1]
+
+    @pl.when(pl.program_id(1) < tiles_ref[0])
+    def _tile():
+        x = x_ref[...]
+        out = jnp.dot(x, refs[0][0, 0], preferred_element_type=F32)
+        if gated:
+            out = jax.nn.silu(out) * jnp.dot(x, refs[1][0, 0],
+                                             preferred_element_type=F32)
+        o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _gmm_pallas(x, weights, layer, tile_expert, tiles, tile: int,
+                interpret: bool):
+    rows, kdim = x.shape
+    n = weights[0].shape[-1]
+    bn = BLOCK_N if n % BLOCK_N == 0 else n
+    num_tiles = rows // tile
+
+    # tiles past the last that holds anything repeat its blocks: nothing
+    # is fetched for them and nothing computed
+    def at(ti, tiles):
+        return jnp.minimum(ti, jnp.maximum(tiles[0] - 1, 0))
+
+    def x_map(ni, ti, layer, tile_expert, tiles):
+        return (at(ti, tiles), 0)
+
+    def w_map(ni, ti, layer, tile_expert, tiles):
+        return (layer[0], tile_expert[at(ti, tiles)], 0, ni)
+
+    def o_map(ni, ti, layer, tile_expert, tiles):
+        return (at(ti, tiles), ni)
+
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, gated=len(weights) == 2),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            # output channels outermost: a tile's neighbours are of its
+            # expert, whose weight block is then fetched once for them all
+            grid=(n // bn, num_tiles),
+            in_specs=[pl.BlockSpec((tile, kdim), x_map)]
+            + [pl.BlockSpec((1, 1, kdim, bn), w_map)] * len(weights),
+            out_specs=pl.BlockSpec((tile, bn), o_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=KERNEL_MOE_GMM,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tile_expert,
+      jnp.reshape(tiles, (1,)).astype(jnp.int32), x, *weights)
+
+
+def _gmm_jnp(x, weights, layer, tile_expert, tile: int):
+    """The twin of ``moe_gmm``: ``jax.lax.ragged_dot`` over the same padded
+    layout (a tile is a group of its own), in the same precisions."""
+    experts = weights[0].shape[1]
+    sizes = jnp.zeros((experts,), jnp.int32).at[tile_expert].add(tile)
+    ws = [jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+          for w in weights]
+    # every tile is counted to its expert, those that hold nothing to the
+    # last tile's: rows nobody reads
+    out = jax.lax.ragged_dot(x, ws[0], sizes, preferred_element_type=F32)
+    if len(ws) == 2:
+        out = jax.nn.silu(out) * jax.lax.ragged_dot(
+            x, ws[1], sizes, preferred_element_type=F32)
+    return out.astype(x.dtype)
+
+
+def moe_gmm(x, weights, layer, tile_expert, tiles, tile: int,
+            use_kernel: Optional[bool] = None,
+            interpret: Optional[bool] = None):
+    """Grouped matmul of expert-sorted rows with their experts' weights.
+
+    x: [rows, K], rows in tiles of ``tile``, tile ``i`` of expert
+    ``tile_expert[i]``, the first ``tiles`` of them holding anything
+    (``sort_by_expert``); weights: one ``[layers, experts, K, N]`` stack, or
+    two (gate and up) for ``silu(x W_g) * (x W_u)`` in one pass; layer: the
+    int32 scalar index into the stacks, traced or not.  Returns [rows, N] in
+    x's dtype; rows of tiles past ``tiles`` are undefined.  Only the weight
+    blocks of experts that have a tile are read, where they lie.
+
+    ``use_kernel=None`` takes the Pallas kernel on a TPU and the twin
+    elsewhere; ``interpret=True`` runs the kernel interpreted (tests)."""
+    if use_kernel is None:
+        use_kernel = bool(interpret) or jax.default_backend() == "tpu"
+    if not use_kernel:
+        return _gmm_jnp(x, weights, layer, tile_expert, tile)
+    return _gmm_pallas(x, weights, layer, tile_expert, tiles, tile,
+                       resolve_interpret(interpret, "moe_gmm"))
+
+
+def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
+                 scaling: float, compute_dtype=None, live=None,
+                 expert_start: int = 0,
+                 shared: bool = True, use_kernel: Optional[bool] = None,
+                 interpret: Optional[bool] = None):
+    """The dropless expert layer of one layer of a stack.  x: [T, H];
+    ``small``: this layer's ``router`` [H, E], ``bias`` [E] and, where the
+    model has one, the shared expert's ``shared_gate`` / ``shared_in`` [H,
+    S] and ``shared_out`` [S, H]; ``stacks``: ``w_gate``, ``w_in`` [layers,
+    held, H, M] and ``w_out`` [layers, held, M, H], the experts
+    ``expert_start`` to ``expert_start + held`` of all E; live: [T] bool,
+    the tokens that count (a padded position, an idle slot: routed nowhere,
+    their output is the shared expert's alone).  ``shared=False`` leaves
+    the shared expert to another holder of this layer.  The router scores x
+    as it comes (float32 where the caller has it); the experts multiply it
+    in ``compute_dtype`` (x's own where none is given).
+
+    Returns (out [T, H] in x's dtype, counts [2] int32: assignments this
+    layer computed, experts it touched; experts [T, k] int32: the router's
+    choice for every token, live or not, among all E)."""
+    t, _ = x.shape
+    held = stacks["w_gate"].shape[1]
+    with jax.named_scope("moe_route"):
+        idx, gates = route_sigmoid(x, small["router"], small["bias"],
+                                   experts_per_token, scaling)
+        mine = (idx >= expert_start) & (idx < expert_start + held)
+        if live is not None:
+            mine = mine & live[:, None]
+    x = x.astype(compute_dtype or x.dtype)
+    with jax.named_scope("moe_sort"):
+        tile = tile_rows(t * experts_per_token, held)
+        dest, source, tile_expert, tiles, sizes = sort_by_expert(
+            idx - expert_start, mine, held, tile)
+        xs = jnp.take(x, source, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope("moe_experts"):
+        gmm = functools.partial(moe_gmm, layer=layer, tile_expert=tile_expert,
+                                tiles=tiles, tile=tile, use_kernel=use_kernel,
+                                interpret=interpret)
+        act = gmm(xs, (stacks["w_gate"], stacks["w_in"]))
+        ys = gmm(act, (stacks["w_out"],))
+    with jax.named_scope("moe_combine"):
+        # an assignment that is not this layer's reads a row past the end
+        picked = jnp.take(ys, dest, axis=0, mode="fill", fill_value=0)
+        out = jnp.einsum("tkh,tk->th", picked.astype(F32),
+                         jnp.where(mine, gates, 0.0))
+    if shared and "shared_gate" in small:
+        with jax.named_scope("moe_shared"):
+            out = out + ((jax.nn.silu(x @ small["shared_gate"].astype(x.dtype))
+                          * (x @ small["shared_in"].astype(x.dtype)))
+                         @ small["shared_out"].astype(x.dtype)).astype(F32)
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return out.astype(x.dtype), counts, idx

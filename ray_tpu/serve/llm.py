@@ -143,6 +143,23 @@ class LLMEngine:
                     raise ValueError(
                         f"layer_pattern {cfg.layer_pattern} does not run "
                         f"with {what}")
+        if cfg.served_only:
+            # latent rows, dropless experts and residual streams
+            # (models/latent.py) run on a dense cache of their own kind,
+            # unsharded, one token a step
+            for on, what in ((paged, "paged=True: the page arena holds keys "
+                              "and values only, and the latent decode "
+                              "kernel reads rows by slot"),
+                             (spec_decode_enabled, "spec_decode_enabled: a "
+                              "window of several tokens has no latent "
+                              "kernel, and the draft would be the dense "
+                              "prefix alone"),
+                             (tp > 1, f"tp={tp}: no sharding rule covers the "
+                              "latent rows, the experts or their kernels")):
+                if on:
+                    raise ValueError(
+                        f"{', '.join(cfg.served_only)} do not run with "
+                        f"{what}")
         self.max_len = max_len or cfg.max_seq_len
         self.num_slots = num_slots
         self.buckets = tuple(b for b in buckets if b <= self.max_len)
@@ -193,9 +210,13 @@ class LLMEngine:
         self._cache_gauges = dec.cache_gauges(cfg, self.cache)
         if not paged:
             from ray_tpu.ops.decode_attention import block_len
-            rows = self.cache["k"]
+            # what a position holds of one layer: a K (or V) row, or its
+            # latent row and rotary key
+            width = (cfg.latent_row if "latent" in self.cache
+                     else self.cache["k"].shape[-1])
             self._kv_block = block_len(
-                self.max_len, rows.shape[-1] * rows.dtype.itemsize)
+                self.max_len,
+                width * jnp.dtype(self.compute_dtype).itemsize)
         # In-replica tensor parallelism: place params + cache with tp
         # shardings; jit propagates them, XLA inserts the collectives.
         self.tp = tp
@@ -301,9 +322,21 @@ class LLMEngine:
         self.admit_tokens_padded = 0
         # what decode attention reads of the cache it holds (dense cache,
         # plain decode): per step the live positions of the active slots,
-        # rounded up to the kernel's blocks, against every slot's max_len
+        # rounded up to the kernel's blocks, against every slot's max_len;
+        # and the same positions as they are, not rounded
         self.kv_positions_read = 0
         self.kv_positions_held = 0
+        self.kv_positions_live = 0
+        # what the dropless expert layers did (cfg.moe_dropless): decode's
+        # assignments (live tokens x experts a token x expert layers) and
+        # experts touched (those with a live token, summed over expert
+        # layers and steps) ride each dispatch's tokens; the steps that had
+        # a live slot, times the expert layers, and the admits' assignments
+        # are counted here
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
+        self.moe_expert_layer_steps = 0
+        self.moe_assignments_prefill = 0
         # request stages (engine thread): submit -> dispatch of the admit,
         # that dispatch -> first token on the request's queue
         self.admitted_requests = 0
@@ -440,7 +473,14 @@ class LLMEngine:
             "admit_tokens_padded": self.admit_tokens_padded,
             "kv_positions_read": self.kv_positions_read,
             "kv_positions_held": self.kv_positions_held,
+            "kv_positions_live": self.kv_positions_live,
         }
+        if self.cfg.moe_dropless:
+            out.update(
+                moe_assignments=self.moe_assignments,
+                moe_experts_touched=self.moe_experts_touched,
+                moe_expert_layer_steps=self.moe_expert_layer_steps,
+                moe_assignments_prefill=self.moe_assignments_prefill)
         for ph in ENGINE_PHASES:
             out[f"loop_{ph}_s"] = loop_s[ph]
             out[f"loop_{ph}_n"] = self.loop_n[ph]
@@ -523,6 +563,8 @@ class LLMEngine:
         self.admit_rows_real += len(reqs)
         self.admit_tokens_real += tokens_real
         self.admit_tokens_padded += len(reqs) * bucket - tokens_real
+        self.moe_assignments_prefill += (
+            tokens_real * self.cfg.experts_per_token * self.cfg.expert_layers)
         self.admitted_requests += len(reqs)
         for r in reqs:
             r.admitted_at = now
@@ -900,20 +942,27 @@ class LLMEngine:
             self._count_kv_positions()
 
     def _count_kv_positions(self):
-        """One decode dispatch's ``kv_positions_read`` / ``_held``.  The
+        """One decode dispatch's ``kv_positions_read`` / ``_held`` / ``_live``.  The
         device runs a slot for as many steps as its budget has left
         (``_admit_arrays``; an EOS it samples is not known here yet) and
         reads, at a step that finds ``n`` positions cached, the blocks that
         hold ``n + 1``."""
         steps, block = self.steps_per_dispatch, self._kv_block
+        longest = 0
         for r in self._active.values():
             budget = min(r.max_tokens, self.max_len - r.prompt_len)
             run = min(steps, budget - 1 - (r.cache_len - r.prompt_len))
             self.kv_positions_read += sum(
                 -(-(r.cache_len + j + 1) // block) * block
                 for j in range(run))
+            if run > 0:
+                self.kv_positions_live += (run * r.cache_len
+                                           + run * (run + 1) // 2)
             r.cache_len += max(run, 0)
+            longest = max(longest, run)
         self.kv_positions_held += steps * (self.num_slots + 1) * self.max_len
+        # the steps of this dispatch that find a live slot
+        self.moe_expert_layer_steps += longest * self.cfg.expert_layers
 
     def _drain_spec(self, payload, snapshot):
         """Fetch one speculative dispatch, then emit it."""
@@ -973,7 +1022,13 @@ class LLMEngine:
                 for i, s in enumerate(prefill_slots):
                     self._emit(snapshot[s], int(tokens[i]))
             else:
-                # decode entry: [steps_per_dispatch, slots]
+                # decode entry: [steps_per_dispatch, slots], and a row of
+                # the expert layers' counts where the model has them
+                if self.cfg.moe_dropless:
+                    tokens, (ran, touched) = self._dec.split_moe_counts(
+                        tokens)
+                    self.moe_assignments += ran
+                    self.moe_experts_touched += touched
                 for k in range(tokens.shape[0]):
                     for s, r in snapshot.items():
                         if r.slot == s and self._active.get(s) is r:

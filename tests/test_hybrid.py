@@ -387,7 +387,9 @@ def test_counts_of_the_l12_configuration(kind):
         "cache_kv_bytes": 25 * 4096 * 46_080,
         "cache_state_bytes": 25 * (kind.state_bytes_per_slot(doc)
                                    + 9 * 3 * 11520 * 2),
-        "linear_layers": 9, "full_layers": 3}
+        "linear_layers": 9, "full_layers": 3,
+        # no latent rows and no experts here (PR 35's gauges)
+        "cache_latent_bytes": 0, "expert_layers": 0, "experts_held": 0}
 
 
 # ------------- nothing in benchmark/ outside models/ and tests/ names a model

@@ -1,0 +1,541 @@
+"""Latent attention, dropless expert layers behind a dense prefix and
+hyper-connected residual streams on the serving path (models/latent.py,
+ops/decode_attention.py ``mla_decode_attn``, ops/moe.py): a tiny model of 1
+dense + 2 expert layers, hidden 128, 4 heads of 16 + 8 / 16, 8 experts top
+2 and a shared one, 4 streams, seeded random weights, on the CPU.  The
+independent side of every comparison is the block kind's plain float32
+reference (benchmark/models/xing4_0.py: expanded attention over the whole
+sequence, every expert on every token, no cache, nothing imported from
+ray_tpu.models or ray_tpu.ops).  Numbers here are about results, never
+speed."""
+
+import dataclasses
+import json
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import decode, latent, transformer
+from ray_tpu.models.config import TransformerConfig
+from ray_tpu.ops import decode_attention as da
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+KIND = os.path.join(REPO, "benchmark", "models", "xing4_0.py")
+L7 = os.path.join(REPO, "benchmark", "configs",
+                  "xing4.0-29b-a4b-serve-l7.json")
+TINY = os.path.join(REPO, "benchmark", "tests", "tiny", "configs",
+                    "tiny-latent.json")
+
+
+@pytest.fixture(scope="module")
+def kind():
+    from benchmark.lib.manifest import load_model
+    return load_model(KIND)
+
+
+@pytest.fixture(scope="module")
+def doc():
+    with open(TINY) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def tiny(kind, doc):
+    cfg = kind.program_config(doc)
+    params = kind.init_params(jax.random.PRNGKey(3), cfg, jnp.float32)
+    return cfg, params
+
+
+def _prefill(cfg, params, cache, toks, slot, pad=0):
+    row = np.pad(toks, (0, pad))[None]
+    return jax.jit(lambda p, c, t, n, s: decode.prefill(
+        p, c, t, n, s, cfg, jnp.float32))(
+            params, cache, row, np.array([len(toks)], np.int32),
+            np.array([slot], np.int32))
+
+
+# ------------------------------------------------ (a) the decode kernel
+
+@pytest.mark.parametrize("max_len,block", [(128, 32), (64, 64)])
+def test_latent_kernel_interpreted_equals_its_twin(monkeypatch, max_len,
+                                                   block):
+    """Several blocks a slot and one; an idle slot, one position, a full
+    row; a layer in the middle of the stack."""
+    monkeypatch.setattr(da, "BLOCK_LEN", block)
+    ks = jax.random.split(jax.random.PRNGKey(max_len), 4)
+    layers, slots, c, r, nh = 3, 5, 32, 8, 4
+    rows = jax.random.normal(ks[0], (layers, slots, max_len, c))
+    keys = jax.random.normal(ks[1], (layers, slots, r, max_len))
+    q_lat = jax.random.normal(ks[2], (slots, nh, c))
+    q_rope = jax.random.normal(ks[3], (slots, nh, r))
+    live = jnp.array([0, 1, 17, max_len, 33])
+    twin = da.mla_decode_attn(q_lat, q_rope, rows, keys, jnp.int32(1), live,
+                              0.2, use_kernel=False)
+    kernel = da.mla_decode_attn(q_lat, q_rope, rows, keys, jnp.int32(1),
+                                live, 0.2, interpret=True)
+    np.testing.assert_allclose(kernel, twin, atol=2e-6)
+    assert not np.asarray(twin[0]).any()        # nothing live: zeros
+    # one live position: the output is that position's latent row
+    np.testing.assert_allclose(twin[1], jnp.broadcast_to(
+        rows[1, 1, 0], (nh, c)), atol=1e-6)
+
+
+# --------------------------- (b) prefill then decode against the reference
+
+def test_prefill_then_decode_equals_the_reference(kind, doc, tiny):
+    """The expanded form over a prompt, then the absorbed form a token at a
+    time through the cache, against the reference's one full forward pass:
+    logits of std 1.0 at every position from the prompt's last.  Float32
+    both sides; 2e-4 is rounding through 3 layers of 20 Sinkhorn rounds."""
+    cfg, params = tiny
+    n, steps = 29, 6
+    toks = np.random.default_rng(1).integers(1, cfg.vocab_size,
+                                             n + steps).astype(np.int32)
+    ref = np.asarray(kind.logits(params, toks, doc,
+                                 jnp.arange(n - 1, n + steps), follow=None))
+    cache = decode.init_kv_cache(cfg, 3, 64, jnp.float32,
+                                 expert_choices=True)
+    cache, lg = _prefill(cfg, params, cache, toks[:n], slot=1, pad=3)
+    got = [np.asarray(lg)[0]]
+    step = jax.jit(lambda p, c, t, a: decode.decode_step(
+        p, c, t, a, cfg, jnp.float32))
+    for i in range(steps):
+        fed = np.zeros(3, np.int32)
+        fed[1] = toks[n + i]
+        cache, lg = step(params, cache, fed, np.array([False, True, False]))
+        got.append(np.asarray(lg)[1])
+    assert ref.std() > 0.5
+    np.testing.assert_allclose(np.stack(got), ref, atol=2e-4)
+    # an idle slot writes at its own stale length and nowhere else (as K
+    # and V do: its length does not move, so the row is never read); the
+    # counts are the live slot's
+    assert not np.asarray(cache["latent"][:, 0, 1:]).any()
+    assert not np.asarray(cache["rope_key"][:, 2, :, 1:]).any()
+    assert cache["moe_counts"].tolist() == [
+        steps * cfg.experts_per_token * cfg.expert_layers] * 2
+    assert cache["length"].tolist() == [0, n + steps, 0]
+    # the record of the routers' choices: the live slot's tokens, each at
+    # its position, by the expanded form and by the absorbed form alike
+    chosen = np.asarray(cache[decode.CHOICES])
+    assert chosen.shape == (cfg.expert_layers, 3, 64, cfg.experts_per_token)
+    assert (chosen[:, [0, 2]] == -1).all() and (chosen[:, 1, n + steps:]
+                                                == -1).all()
+    whole, _ = _prefill(cfg, params, decode.init_kv_cache(
+        cfg, 3, 64, jnp.float32, expert_choices=True), toks, slot=1)
+    np.testing.assert_array_equal(chosen[:, 1, :n + steps],
+                                  whole[decode.CHOICES][:, 1, :n + steps])
+    assert chosen[:, 1, :n + steps].min() >= 0
+
+
+@pytest.mark.parametrize("case", ["tie", "far", "nothing"])
+def test_the_reference_follows_a_tie_break_and_nothing_more(kind, doc, case):
+    """The reference's router told another's choice: its k-th expert swapped
+    for its next one, which scores within ``FOLLOW_MARGIN`` of it, is
+    followed (either set is the equations' answer up to rounding); swapped
+    for its worst one, it is not; -1 (nothing was routed) is not.  The gates
+    are the router's own scores of whatever set it takes."""
+    k, e = doc["num_experts_per_tok"], doc["n_routed_experts"]
+    x = jax.random.normal(jax.random.PRNGKey(5), (200, doc["hidden_size"]))
+    router = jax.random.normal(jax.random.PRNGKey(6),
+                               (doc["hidden_size"], e)) * 0.02
+    bias = jnp.zeros((e,))
+    own, gates, short = kind.route(x, router, bias, doc)
+    assert not np.asarray(short).any()
+    scores = jax.nn.sigmoid(x @ router)
+    order = jnp.argsort(-scores, axis=-1)
+    swap = {"tie": order[:, k], "far": order[:, -1],
+            "nothing": jnp.full((200,), -1)}[case]
+    told = own.at[:, -1].set(swap)
+    idx, gates_told, short = kind.route(x, router, bias, doc, follow=told)
+    gap = np.asarray(jnp.take_along_axis(scores, order[:, k - 1:k + 1], -1))
+    if case == "tie":
+        taken = gap[:, 0] - gap[:, 1] <= kind.FOLLOW_MARGIN
+        assert taken.any()
+        np.testing.assert_allclose(short, gap[:, 0] - gap[:, 1], atol=1e-6)
+    else:
+        taken = np.zeros(200, bool)
+        assert (np.asarray(short) > kind.FOLLOW_MARGIN).all()
+    np.testing.assert_array_equal(idx, np.where(taken[:, None], told, own))
+    picked = jnp.take_along_axis(scores, idx, -1)
+    np.testing.assert_allclose(
+        gates_told, picked / picked.sum(-1, keepdims=True)
+        * doc["routed_scaling_factor"], rtol=1e-6)
+
+
+def _route_bf16(x, router_w, bias, k, scaling):
+    """The lower-precision control of the configuration's ``gate_in_float32``:
+    ``ops.moe.route_sigmoid`` with scores, top k and gates in bf16."""
+    bf = jnp.bfloat16
+    scores = jax.nn.sigmoid(x.astype(bf) @ router_w.astype(bf))
+    _, idx = jax.lax.top_k(scores + bias.astype(bf), k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = gates / gates.sum(-1, keepdims=True) * jnp.asarray(scaling, bf)
+    return idx.astype(jnp.int32), gates.astype(jnp.float32)
+
+
+@pytest.mark.parametrize("router", ["bf16", "wrong_at_a_few"])
+def test_a_router_that_is_not_float32_is_not_followed(kind, doc, monkeypatch,
+                                                      router):
+    """The reference asks the program's router about its own input and
+    follows the program's choice only where the answer is its own float32
+    set.  A router wrong at a few tokens is refused at those, whatever it
+    was told, and followed at the rest as before; one that scores in bf16
+    ties or swaps at more than ``1 - ROUTER_TRUSTED`` of the tokens, and is
+    followed nowhere.  The program's own router passes at every token."""
+    from ray_tpu.ops import moe
+    k, e = doc["num_experts_per_tok"], doc["n_routed_experts"]
+    x = jax.random.normal(jax.random.PRNGKey(7), (2000, doc["hidden_size"]))
+    router_w = jax.random.normal(jax.random.PRNGKey(8),
+                                 (doc["hidden_size"], e)) * 0.02
+    bias = jnp.zeros((e,))
+    own, _, _ = kind.route(x, router_w, bias, doc)
+    scores = jax.nn.sigmoid(x @ router_w)
+    order = jnp.argsort(-scores, axis=-1)
+    told = own.at[:, -1].set(order[:, k])           # the tie-break, everywhere
+    _, _, sound = kind.route(x, router_w, bias, doc, follow=told)
+    assert np.isfinite(np.asarray(sound)).all()
+
+    def wrong_at_a_few(x, router_w, bias, k, scaling):
+        idx, gates = moe_route(x, router_w, bias, k, scaling)
+        return idx.at[:5, -1].set(order[:5, -1].astype(idx.dtype)), gates
+
+    moe_route = moe.route_sigmoid
+    patched = {"bf16": _route_bf16, "wrong_at_a_few": wrong_at_a_few}[router]
+    asked, _ = patched(x, router_w, bias, k, doc["routed_scaling_factor"])
+    low = jnp.take_along_axis(scores, asked, -1).min(-1)
+    inexact = np.asarray(jnp.take_along_axis(
+        scores, order[:, k - 1:k], -1)[:, 0] - low > kind.ROUTER_EXACT)
+    monkeypatch.setattr(moe, "route_sigmoid", patched)
+    idx, _, short = kind.route(x, router_w, bias, doc, follow=told)
+    if router == "bf16":
+        assert 1 - kind.ROUTER_TRUSTED < inexact.mean() < 0.5
+        inexact = np.ones_like(inexact)
+    else:
+        assert inexact.sum() == 5
+    assert np.isinf(np.asarray(short)[inexact]).all()
+    np.testing.assert_array_equal(np.asarray(idx)[inexact],
+                                  np.asarray(own)[inexact])
+    np.testing.assert_array_equal(np.asarray(short)[~inexact],
+                                  np.asarray(sound)[~inexact])
+
+
+def test_the_followed_reference_is_the_plain_one_where_the_sets_agree(
+        kind, doc, tiny):
+    """``logits`` by default runs the program as the harness's comparison
+    does (a prefill, then decode steps) and follows its routers' choices:
+    at float32 parameters and tiny sizes nearly every set is the
+    reference's own, and where all of a sequence's are, the two references
+    are one; told nothing (-1 everywhere), they always are."""
+    cfg, params = tiny
+    n, steps = 17, 4
+    toks = jnp.asarray(np.random.default_rng(8).integers(
+        1, cfg.vocab_size, n + steps), jnp.int32)
+    at = jnp.arange(n - 1, n + steps)
+    plain = kind.logits(params, toks, doc, at, follow=None)
+    chosen = kind.program_choices(params, toks, doc, n)
+    assert chosen.shape == (cfg.expert_layers, n + steps,
+                            cfg.experts_per_token)
+    assert int(chosen.min()) >= 0
+    _, short = kind.hidden_states(params, toks, doc, chosen)
+    followed = kind.logits(params, toks, doc, at)
+    if not np.asarray(short).any():
+        np.testing.assert_allclose(followed, plain, atol=1e-5)
+    assert np.abs(np.asarray(followed - plain)).max() < 0.5
+    np.testing.assert_array_equal(
+        kind.logits(params, toks, doc, at, follow=jnp.full_like(chosen, -1)),
+        plain)
+
+
+def test_absorbed_form_equals_expanded_form(tiny):
+    """A decode step through the cache (the query carried into the latent
+    space) against a prefill of the same tokens (keys and values rebuilt
+    per head): the same logits up to float32 rounding."""
+    cfg, params = tiny
+    toks = np.random.default_rng(2).integers(1, cfg.vocab_size,
+                                             21).astype(np.int32)
+    cache = decode.init_kv_cache(cfg, 2, 32, jnp.float32)
+    _, expanded = _prefill(cfg, params, cache, toks, slot=0)
+    cache, _ = _prefill(cfg, params, cache, toks[:-1], slot=0)
+    _, absorbed = jax.jit(lambda p, c, t, a: decode.decode_step(
+        p, c, t, a, cfg, jnp.float32))(
+            params, cache, np.array([toks[-1], 0], np.int32),
+            np.array([True, False]))
+    np.testing.assert_allclose(absorbed[0], expanded[0], atol=5e-5)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(moe_dropless=True, num_experts=8, experts_per_token=2,
+         expert_mlp_size=64, shared_experts=1, routed_scaling_factor=2.0,
+         dense_prefix_layers=1, hc_mult=2),
+    dict(hc_mult=3),
+    dict(moe_dropless=True, num_experts=4, experts_per_token=2,
+         expert_mlp_size=32),
+], ids=["gqa-prefix-experts-streams", "gqa-streams", "gqa-experts"])
+def test_the_mechanisms_combine_with_kv_rows(kw):
+    """Each mechanism is its own switch: over plain K/V rows (grouped-query
+    attention) a decode step continues a prefill as under latent rows, with
+    a dense prefix's rows stacked before the expert layers'."""
+    cfg = TransformerConfig(
+        vocab_size=256, num_layers=3, hidden_size=64, num_heads=4,
+        num_kv_heads=2, mlp_size=128, max_seq_len=64, norm_eps=1e-6, **kw)
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    cache = decode.init_kv_cache(cfg, 2, 32, jnp.float32)
+    toks = np.random.default_rng(0).integers(1, 256, 20).astype(np.int32)
+    _, whole = _prefill(cfg, params, cache, toks, slot=0)
+    cache, _ = _prefill(cfg, params, cache, toks[:-1], slot=0)
+    _, step = jax.jit(lambda p, c, t, a: decode.decode_step(
+        p, c, t, a, cfg, jnp.float32))(
+            params, cache, np.array([toks[-1], 0], np.int32),
+            np.array([True, False]))
+    np.testing.assert_allclose(step[0], whole[0], atol=2e-5)
+
+
+def test_yarn_frequencies_and_scale_are_the_references(kind):
+    """At the published sizes, against the kind's own arithmetic."""
+    with open(L7) as f:
+        doc = json.load(f)
+    cfg = kind.program_config(doc)
+    np.testing.assert_allclose(latent.rope_inv_freq(cfg),
+                               kind.yarn_inv_freq(doc), rtol=1e-6)
+    assert latent.rope_magnitude(cfg) == 1.0
+    assert latent.softmax_scale(cfg) == pytest.approx(
+        192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2)
+    assert latent.softmax_scale(cfg) == pytest.approx(
+        kind.attention_scale(doc))
+    # the slowest dimensions are divided by the factor, the fastest kept
+    plain = 10000.0 ** -(np.arange(0, 64, 2) / 64)
+    freq = latent.rope_inv_freq(cfg)
+    assert freq[0] == pytest.approx(plain[0])
+    assert freq[-1] == pytest.approx(plain[-1] / 64)
+
+
+# ------------------------------------------------ (c) hyper-connections
+
+def test_h_res_is_doubly_stochastic(tiny):
+    cfg, params = tiny
+    x = jax.random.normal(jax.random.PRNGKey(0),
+                          (2, 9, cfg.hc_mult, cfg.hidden_size)) * 3.0
+    hp = jax.tree.map(lambda a: a[0], params["blocks"]["hc_attn"])
+    pre, post, res = latent.hc_coeff(x, hp, cfg)
+    np.testing.assert_allclose(res.sum(-1), 1.0, atol=1e-5)
+    np.testing.assert_allclose(res.sum(-2), 1.0, atol=1e-5)
+    assert float(res.min()) > 0 and res.dtype == jnp.float32
+    assert float(pre.min()) > 0 and float(pre.max()) < 1
+    assert float(post.min()) > 0 and float(post.max()) < 2
+    # the coefficients are the token's own: they differ from row to row
+    assert float(jnp.std(res[..., 0, 0])) > 1e-3
+
+
+def test_sinkhorn_holds_at_the_clamp():
+    logits = jnp.array([[30.0, -30.0, 0.0], [-30.0, 30.0, 5.0],
+                        [1.0, 2.0, -30.0]])
+    m = latent.sinkhorn(logits, 20, 1e-6)
+    assert bool(jnp.isfinite(m).all())
+    np.testing.assert_allclose(m.sum(-1), 1.0, atol=1e-4)
+
+
+def test_one_stream_would_be_the_plain_residual(tiny):
+    """``hc_write`` with the identity mix and unit gains is ``x + f``."""
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 3, 4, 8))
+    out = jax.random.normal(jax.random.PRNGKey(2), (1, 3, 8))
+    ones = jnp.ones((1, 3, 4))
+    eye = jnp.broadcast_to(jnp.eye(4), (1, 3, 4, 4))
+    np.testing.assert_allclose(latent.hc_write(x, out, ones, eye),
+                               x + out[:, :, None], atol=1e-6)
+    np.testing.assert_allclose(latent.hc_read(x, ones), x.sum(2), atol=1e-6)
+
+
+# ------------------------------------------------------ (d) the engine
+
+@pytest.fixture(scope="module")
+def engine(tiny):
+    from ray_tpu.serve.llm import LLMEngine
+    cfg, params = tiny
+    eng = LLMEngine(cfg, params=params, num_slots=3, max_len=64,
+                    buckets=(16, 32), compute_dtype=jnp.float32,
+                    steps_per_dispatch=4)
+    yield eng
+    eng.shutdown()
+
+
+def test_engine_generates_the_references_greedy_tokens(kind, doc, tiny,
+                                                       engine):
+    cfg, params = tiny
+    prompt = np.random.default_rng(4).integers(1, cfg.vocab_size,
+                                               11).tolist()
+    out = engine.generate(prompt, max_tokens=6)
+    # the reference's greedy choice after each prefix of what the engine
+    # wrote is the engine's next token: one forward over the whole answer
+    toks = np.asarray(prompt + out, np.int32)
+    lg = jax.jit(lambda p, t: kind.logits(
+        p, t, doc, jnp.arange(len(prompt) - 1, len(toks) - 1),
+        follow=None))(params, toks)
+    assert out == np.asarray(jnp.argmax(lg, axis=-1)).tolist()
+
+
+def test_the_engine_counts_what_the_experts_did(tiny, engine):
+    """Cumulative counters from the two sums that ride each dispatch's
+    tokens: assignments are live tokens x experts a token x expert layers,
+    and no step touches more experts than it has."""
+    from ray_tpu.serve.llm import _FLUSH
+    cfg, _ = tiny
+    c0 = engine.counters()
+    outs = [engine.submit(list(range(1, 9 + i)), max_tokens=7)
+            for i in range(3)]
+    for r in outs:
+        while r.out.get() is not _FLUSH:
+            pass
+    while engine._unfetched:        # the dispatches still in flight
+        time.sleep(0.01)
+    c1 = engine.counters()
+    d = {k: c1[k] - c0[k] for k in c1 if k.startswith("moe_")}
+    per = cfg.experts_per_token * cfg.expert_layers
+    assert d["moe_assignments"] == 3 * 6 * per      # 6 decoded tokens each
+    assert d["moe_assignments_prefill"] == (8 + 9 + 10) * per
+    assert 0 < d["moe_experts_touched"] <= d["moe_assignments"]
+    assert d["moe_experts_touched"] <= (d["moe_expert_layer_steps"]
+                                        * cfg.num_experts)
+    assert d["moe_expert_layer_steps"] >= 6 * cfg.expert_layers
+    g = engine.breakdown()
+    assert g["cache_latent_bytes"] == (
+        cfg.num_layers * 4 * 64 * cfg.latent_row * 4)
+    assert (g["cache_kv_bytes"], g["cache_state_bytes"]) == (0, 0)
+    assert (g["experts_held"], g["expert_layers"]) == (8, 2)
+    counted = engine.counters()
+    assert 0 < counted["kv_positions_live"] <= counted["kv_positions_read"]
+
+
+def test_a_dense_engine_has_no_expert_counters():
+    from ray_tpu.models import config as mcfg
+    from ray_tpu.serve.llm import LLMEngine
+    eng = LLMEngine(mcfg.tiny(), num_slots=2, max_len=32, buckets=(16,))
+    try:
+        assert not [k for k in eng.counters() if k.startswith("moe_")]
+        g = eng.breakdown()
+    finally:
+        eng.shutdown()
+    assert (g["cache_latent_bytes"], g["experts_held"],
+            g["expert_layers"]) == (0, 0, 0)
+
+
+# --------------------------------------------------- (e) the refusals
+
+@pytest.mark.parametrize("kw,match", [
+    (dict(paged=True), "paged=True"),
+    (dict(spec_decode_enabled=True), "spec_decode_enabled"),
+    (dict(tp=2), "tp=2"),
+])
+def test_the_engine_refuses_what_a_latent_cache_cannot_do(tiny, kw, match):
+    from ray_tpu.serve.llm import LLMEngine
+    cfg, params = tiny
+    with pytest.raises(ValueError, match=match) as e:
+        LLMEngine(cfg, params=params, num_slots=2, max_len=32, **kw)
+    assert "kv_lora_rank" in str(e.value) and ":" in str(e.value)
+
+
+@pytest.mark.parametrize("what", ["make_train_step", "apply_trunk"])
+def test_training_refuses_what_is_only_served(tiny, what):
+    cfg, params = tiny
+    with pytest.raises(NotImplementedError, match="no backward"):
+        if what == "apply_trunk":
+            transformer.apply_trunk(params, jnp.zeros((1, 8), jnp.int32), cfg)
+        else:
+            from ray_tpu.parallel.train_step import make_train_step
+            make_train_step(cfg, None, None, None)
+
+
+def test_a_window_of_several_tokens_is_refused(tiny):
+    cfg, params = tiny
+    cache = decode.init_kv_cache(cfg, 2, 32, jnp.float32)
+    with pytest.raises(ValueError, match="one token a step"):
+        decode.window_step(params, cache, jnp.zeros((2, 3), jnp.int32),
+                           jnp.ones((2,), bool), cfg, jnp.float32)
+
+
+@pytest.mark.parametrize("kw,match", [
+    (dict(layer_pattern=("linear", "full"), norm_on_output=True,
+          linear_num_heads=2, linear_key_dim=8, linear_value_dim=8),
+     "layer_pattern"),
+    (dict(q_lora_rank=0), "needs q_lora_rank"),
+    (dict(num_kv_heads=2), "one key and one value head"),
+    (dict(use_qkv_bias=True), "without biases"),
+    (dict(kv_lora_rank=0), "rope_yarn_factor"),
+    (dict(moe_dropless=False), "belong to moe_dropless"),
+    (dict(dense_prefix_layers=3), "leaves no expert layer"),
+    (dict(experts_per_token=9), "experts_per_token"),
+    (dict(hc_mult=1), "hc_mult"),
+])
+def test_config_refuses_what_the_mechanisms_are_not(tiny, kw, match):
+    cfg, _ = tiny
+    with pytest.raises(ValueError, match=match):
+        dataclasses.replace(cfg, **kw)
+
+
+def test_config_names_what_is_only_served(tiny):
+    cfg, _ = tiny
+    assert cfg.served_only == ("kv_lora_rank", "moe_dropless",
+                               "dense_prefix_layers", "hc_mult")
+    assert TransformerConfig.__dataclass_fields__["hc_mult"].default == 0
+    with pytest.raises(AttributeError, match="qk_head_dim"):
+        cfg.head_dim
+    with pytest.raises(NotImplementedError, match="served, not trained"):
+        cfg.flops_per_token()
+
+
+@pytest.mark.parametrize("change,match", [
+    (dict(scoring_func="softmax"), "sigmoid"),
+    (dict(n_group=8), "group limit"),
+    (dict(norm_topk_prob=False), "norm_topk_prob"),
+    (dict(tie_word_embeddings=True), "own head"),
+    (dict(rope_scaling={"type": "linear"}), "YaRN"),
+    (dict(mhc_h_res_clamp_min=-10), "symmetrically"),
+    (dict(ep_size=4), "ep_size"),
+    (dict(moe_layer_freq=2), "moe_layer_freq"),
+    (dict(hidden_act="gelu"), "SiLU"),
+])
+def test_the_kind_refuses_what_the_block_cannot_express(kind, doc, change,
+                                                        match):
+    with pytest.raises(ValueError, match=match):
+        kind.program_config({**doc, **change})
+
+
+# ------------------------------------------------------- (f) the counts
+
+def test_counts_of_the_l7_configuration(kind):
+    """The program's tree, the kind's counts and ISSUE 35's arithmetic
+    agree: 5.54B parameters, an expert layer of 745M of which 40M lie
+    beside the experts (the catalog's figure), 8,064 B a token."""
+    with open(L7) as f:
+        doc = json.load(f)
+    cfg = kind.program_config(doc)
+    shapes = jax.eval_shape(lambda: kind.init_params(
+        jax.random.PRNGKey(0), cfg, jnp.bfloat16))
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    assert n == kind.num_params(doc)
+    assert round(n / 1e9, 2) == 5.54
+    assert abs(cfg.num_params() - n) < 1e-4 * n      # matrices alone
+    per = kind.layer_matrix_params(doc)
+    beside = per["attention"] + per["shared"] + per["router"] + per["hc"]
+    assert round(beside / 1e6) == 40
+    assert round((beside + 64 * per["expert"]) / 1e6) == 745
+    assert round(per["attention"] / 1e6, 1) == 28.4
+    assert kind.kv_bytes_per_token(doc) == 7 * 1152 == 8064
+    cache = jax.eval_shape(lambda: kind.init_cache(cfg, 33, 8192,
+                                                   jnp.bfloat16))
+    assert sum(int(np.prod(a.shape)) * 2 for k, a in cache.items()
+               if k in decode.LATENT) == 33 * 8192 * 8064
+    # a step reads the experts its tokens reach, never all 64 where fewer
+    # can be touched
+    assert round(kind.experts_touched(doc, 32), 1) == 55.9
+    assert round(kind.experts_touched(doc, 26), 1) == 52.0
+    step = kind.decode_step_bytes(doc, 26, 26 * 4600)
+    whole = kind.decode_step_bytes(doc, 1e9, 26 * 4600)
+    assert 9.3e9 < step < 9.7e9 and whole - step > 6 * 11 * per["expert"]
+    assert kind.moe_gmm_flops(doc, 128) == 128 * 6 * 3584 * 1024
+    assert kind.moe_gmm_bytes(doc, 0, 56) == 56 * per["expert"] * 2
+    assert kind.mla_decode_attn_bytes(doc, 1000) == 1000 * 8064
+    assert kind.mla_decode_attn_flops(doc, 1) == 7 * 32 * 2 * (576 + 512)

@@ -298,3 +298,80 @@ def test_hybrid_serve_programs_carry_their_scopes(hybrid_cfg):
                 "full_layers"} <= set(stats)
     finally:
         eng.shutdown()
+
+
+# ------- latent attention, dropless experts, residual streams (PR 35)
+
+@pytest.fixture(scope="module")
+def latent_cfg():
+    from ray_tpu.models.config import TransformerConfig
+    return TransformerConfig(
+        vocab_size=256, num_layers=3, hidden_size=64, num_heads=4,
+        num_kv_heads=4, mlp_size=128, max_seq_len=64, rope_theta=10000.0,
+        norm_eps=1e-6, q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_yarn_factor=64.0,
+        rope_yarn_original_max=16, rope_yarn_mscale_all_dim=1.0,
+        moe_dropless=True, num_experts=8, experts_per_token=2,
+        expert_mlp_size=32, shared_experts=1, routed_scaling_factor=2.0,
+        dense_prefix_layers=1, hc_mult=4)
+
+
+def _reader(name):
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "layer_metrics", name)
+    spec = importlib.util.spec_from_file_location(
+        "_reader_" + name.split(".")[0], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_moe_and_latent_kernels_are_named():
+    """The names a device trace shows (``moe_gmm [pallas]``,
+    ``mla_decode_attn [pallas]``), which the benchmark's readers spell out
+    for themselves."""
+    from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops import moe
+
+    assert moe.KERNEL_MOE_GMM == "moe_gmm"
+    assert da.KERNEL_MLA_DECODE_ATTN == "mla_decode_attn"
+    assert _reader("moe_gmm_roofline.py").MOE_GMM == moe.KERNEL_MOE_GMM
+    assert (_reader("mla_decode_attn_roofline.py").MLA_DECODE_ATTN
+            == da.KERNEL_MLA_DECODE_ATTN)
+    assert _reader("moe_mla_kernels_device_share.py").KERNELS == (
+        moe.KERNEL_MOE_GMM, da.KERNEL_MLA_DECODE_ATTN)
+    x = jnp.ones((32, 64), jnp.float32)
+    w = jnp.ones((2, 4, 64, 32), jnp.float32)
+    gmm = jax.make_jaxpr(lambda x, w: moe.moe_gmm(
+        x, (w, w), jnp.int32(1), jnp.zeros((2,), jnp.int32), jnp.int32(2),
+        16, interpret=True))(x, w)
+    assert "moe_gmm" in str(gmm)
+    attn = jax.make_jaxpr(lambda q, r, c, k: da.mla_decode_attn(
+        q, r, c, k, jnp.int32(0), jnp.array([3, 0]), 0.1, interpret=True))(
+            jnp.ones((2, 4, 32)), jnp.ones((2, 4, 8)),
+            jnp.ones((1, 2, 16, 32)), jnp.ones((1, 2, 8, 16)))
+    assert "mla_decode_attn" in str(attn)
+
+
+def test_latent_serve_programs_carry_their_scopes(latent_cfg):
+    eng = _engine(latent_cfg)
+    try:
+        decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
+        assert _module_name(decode) == "jit_engine_decode"
+        shared = {"attn", "mlp", "norm", "lm_head", "mla_down", "mla_up",
+                  "latent_write", "moe_route", "moe_sort", "moe_experts",
+                  "moe_shared", "moe_combine", "hc_coeff", "hc_mix"}
+        assert shared | {"latent_read"} <= _scopes(decode)
+        admit = eng._prefill_fn(16).lower(
+            eng.params, eng.cache, eng._state, *eng._admit_arrays([], 16, []))
+        assert _module_name(admit) == "jit_admit_fn"
+        assert shared <= _scopes(admit)
+        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
+        assert {"cache_latent_bytes", "experts_held", "expert_layers",
+                "moe_assignments", "moe_experts_touched",
+                "moe_expert_layer_steps", "moe_assignments_prefill"} <= set(
+                    {**eng.breakdown(), **eng.counters()})
+    finally:
+        eng.shutdown()
