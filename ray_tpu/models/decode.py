@@ -290,7 +290,7 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
                 mixers: Dict[str, Mixer], carry: Dict[str, Any],
                 cfg: TransformerConfig, compute_dtype,
                 pick: Optional[jnp.ndarray] = None,
-                live: Optional[jnp.ndarray] = None):
+                live: Optional[jnp.ndarray] = None, head: bool = True):
     """The serving forward pass: ``tokens`` [rows, W] at absolute
     ``positions`` [rows, W] through every layer, each layer's mixing done by
     its kind's entry of ``mixers`` on its kind's entry of ``carry`` (the
@@ -310,11 +310,13 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     is routed nowhere.
 
     Returns (logits float32, carry, ys): logits [rows, W, V], or [rows, V]
-    of position ``pick`` [rows] of each row; ys maps a kind to what its
-    mixer returned a layer, stacked [layers of the kind, ...] (None where
-    it returns none), ``"moe"`` to the expert layers' counts [expert
-    layers, 2] and ``"experts"`` to their routers' choices [expert layers,
-    rows, W, k]."""
+    of position ``pick`` [rows] of each row, or with ``head=False`` what the
+    head would be given ([rows, W, H] or [rows, H], after the final norm: a
+    caller that walks a row in pieces runs the head once); ys maps a kind
+    to what its mixer returned a layer, stacked [layers of the kind, ...]
+    (None where it returns none), ``"moe"`` to the expert layers' counts
+    [expert layers, 2] and ``"experts"`` to their routers' choices [expert
+    layers, rows, W, k]."""
     cast = compute_dtype
     pattern, blocks = cfg.layer_pattern or ("full",), params["blocks"]
     per_period = {kind: pattern.count(kind) for kind in mixers}
@@ -427,7 +429,7 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     x = _norm(x, params["final_norm"], cfg).astype(cast)
     if pick is not None:
         x = jnp.take_along_axis(x, pick[:, None, None], axis=1)[:, 0]
-    return lm_head_logits(params, x, cfg), carry, ys
+    return (lm_head_logits(params, x, cfg) if head else x), carry, ys
 
 
 # ---------------------------------------------------------------------------
@@ -446,14 +448,116 @@ def prefill_attention(y, ap, cfg: TransformerConfig, positions):
     return _proj_out(attn.reshape(b, s, -1), ap, y.dtype), k, v
 
 
+#: Positions a pass of the admit program walks of a row it walks in chunks.
+#: 512 is the shortest row that costs its share of a 2,048 row on the chip:
+#: 19 ms of 76, where a row of 256 is 10.6 ms, 11% over its share, and one of
+#: 128 is 9.4, the 8.1 ms read of the weights being the floor under both
+#: (PERF.md section 5, "The admit program alone").
+PREFILL_CHUNK = 512
+
+
+def _rows_alone(cache: KVCache) -> bool:
+    """A dense K/V tree: rows a prefill may start after.  A paged tree
+    starts after a prefix its own way (``page_attention``); a recurrent
+    state and latent rows take no start yet."""
+    return not any(n in cache for n in ("block_table", "state", "latent"))
+
+
+def prefill_width(cache: KVCache, bucket: int,
+                  chunk: int = PREFILL_CHUNK) -> int:
+    """How many positions of a row of ``bucket`` one pass of the admit
+    program walks: ``chunk`` where the row is walked in counted chunks, else
+    the whole bucket.  Read off shapes alone: a dense K/V tree and a bucket
+    of at least four chunks (where buckets double, every length of a
+    shorter one needs all of its chunks, and nothing could be saved)."""
+    chunked = (_rows_alone(cache) and bucket >= 4 * chunk
+               and bucket % chunk == 0)
+    return chunk if chunked else bucket
+
+
+def continued_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, slot,
+                        start, span: int):
+    """One layer's attention for W tokens of one row that continue what its
+    slot holds.  y: [1, W, H] at positions ``start ..``; k_all, v_all: the
+    stacked cache [layers, slots, max_len, NKV * D], of which this is layer
+    ``i``.  Writes the tokens' K/V at ``[i, slot, start : start + W]`` in
+    place and attends, causally, over the slot's rows ``0 .. span`` (static;
+    ``start + W <= span``) where they lie
+    (``ops.flash_attention.flash_attention_rows``).  Returns (attention
+    after its output projection [1, W, H], k_all, v_all)."""
+    from ..ops.flash_attention import flash_attention_rows
+    w = y.shape[1]
+    q, k, v = _qkv(y, ap, cfg, start + jnp.arange(w)[None])
+    with jax.named_scope("kv_write"):
+        k_all, v_all = (
+            jax.lax.dynamic_update_slice(
+                a, rows.reshape(1, 1, w, -1).astype(a.dtype),
+                (i, slot, start, 0))
+            for a, rows in ((k_all, k), (v_all, v)))
+    with jax.named_scope("attn"):
+        attn = flash_attention_rows(q, k_all, v_all, i, slot, start, span,
+                                    cfg.num_kv_heads, cfg.attn_logit_softcap)
+    return _proj_out(attn, ap, y.dtype), k_all, v_all
+
+
+def _prefill_chunks(params: Params, cache: KVCache, tokens: jnp.ndarray,
+                    length: jnp.ndarray, slot: jnp.ndarray,
+                    start: jnp.ndarray, width: int, span: int,
+                    cfg: TransformerConfig, compute_dtype
+                    ) -> Tuple[KVCache, jnp.ndarray]:
+    """``_prefill_row`` on a dense K/V tree in ``ceil(length / width)``
+    passes of ``width`` positions, a loop whose trip count is data: each
+    pass is the same walk over ``[1, width]`` tokens with a mixer that
+    writes the chunk's rows into the slot in place and reads the slot's rows
+    up to them (``continued_attention``), so a row costs the chunks its
+    prompt fills and the chunks past them are left as they were.  The last
+    pass holds the prompt's last token; the head runs on it once, after the
+    loop."""
+    last = jnp.maximum(length - 1, 0)      # the prompt's last real token
+
+    def one(c, walk):
+        k_all, v_all, _ = walk
+        at = c * width
+        x, carry, _ = layer_stack(
+            params, jax.lax.dynamic_slice_in_dim(tokens, at, width, 1),
+            (start + at)[:, None] + jnp.arange(width)[None],
+            {"full": _kv_mixer(continued_attention, cfg, slot,
+                               (start + at)[0], span)},
+            {"full": (k_all, v_all)}, cfg, compute_dtype,
+            jnp.clip(last - at, 0, width - 1),
+            ((at + jnp.arange(width))[None] < length[:, None]
+             if cfg.moe_dropless else None), head=False)
+        return (*carry["full"], x)
+
+    k_all, v_all, x = jax.lax.fori_loop(
+        0, jnp.maximum(-(-length[0] // width), 1), one,
+        (cache["k"], cache["v"],
+         jnp.zeros((1, cfg.hidden_size), compute_dtype)))
+    return (dict(cache, k=k_all, v=v_all, length=cache["length"].at[slot].set(
+        (start + length)[0])), lm_head_logits(params, x, cfg))
+
+
 def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
-                 length: jnp.ndarray, slot: jnp.ndarray, start: jnp.ndarray,
-                 cfg: TransformerConfig, compute_dtype
-                 ) -> Tuple[KVCache, jnp.ndarray]:
+                 length: jnp.ndarray, slot: jnp.ndarray,
+                 start: Optional[jnp.ndarray], cfg: TransformerConfig,
+                 compute_dtype, chunk: int) -> Tuple[KVCache, jnp.ndarray]:
     """One prompt through the layers and into its slot of ``cache``, which
-    comes and goes in place.  tokens: [1, S]; length, start: [1]; slot: a
-    scalar.  Returns (cache, last-token logits [1, V] f32)."""
+    comes and goes in place.  tokens: [1, S]; length, start: [1] (start
+    None: the row begins its slot); slot: a scalar.  A dense K/V tree walks
+    a long row in chunks (``prefill_width``) and any row that has a start
+    after what its slot holds, in one chunk if it is short.  Returns (cache,
+    last-token logits [1, V] f32)."""
     s = tokens.shape[1]
+    width = prefill_width(cache, s, chunk)
+    if width < s or (start is not None and _rows_alone(cache)):
+        # a start that is data may lie anywhere in the slot
+        span = s if start is None else cache["k"].shape[2]
+        return _prefill_chunks(
+            params, cache, tokens, length, slot,
+            jnp.zeros_like(length) if start is None else start, width, span,
+            cfg, compute_dtype)
+    if start is None:
+        start = jnp.zeros_like(length)
     last = jnp.maximum(length - 1, 0)      # the prompt's last real token
     positions = start[:, None] + jnp.arange(s)[None]
     new = dict(cache, length=cache["length"].at[slot].set(
@@ -525,42 +629,51 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
             lengths: jnp.ndarray, slot_ids: jnp.ndarray,
             cfg: TransformerConfig, compute_dtype=jnp.bfloat16,
             start_pos: Optional[jnp.ndarray] = None,
-            rows: Optional[jnp.ndarray] = None
+            rows: Optional[jnp.ndarray] = None, chunk: int = PREFILL_CHUNK
             ) -> Tuple[KVCache, jnp.ndarray]:
     """Run the causal forward over right-padded prompts, populate the cache.
 
-    A fixed shape, counted rows: the arrays are [B, ...] whatever an admit
-    holds, so a bucket is one program, and the program walks the first
-    ``rows`` of them one after another in a loop whose trip count is that
-    number, data.  Each pass takes one row [1, S] through ``layer_stack`` and
-    writes its slot of the cache, carried through the loop in place.  Rows
-    past the count are not computed: their slots, lengths and states stay as
-    they were and their logits read 0.  (A [8, 2048] admit of Mistral's 14
-    layers was 664 ms on the chip with one prompt in it or eight: PERF.md,
-    PR 32.)
+    A fixed shape, counted rows, counted chunks: the arrays are [B, ...]
+    whatever an admit holds, so a bucket is one program, and the program
+    walks the first ``rows`` of them one after another in a loop whose trip
+    count is that number, data.  Each pass takes one row [1, S] through
+    ``layer_stack`` and writes its slot of the cache, carried through the
+    loop in place; on a dense K/V tree a row of four chunks or more
+    (``prefill_width``) is itself a loop over the ``chunk``-position pieces
+    its prompt fills, each written into the slot and attending over what the
+    slot holds by then.  Rows past the count are not computed: their slots,
+    lengths and states stay as they were and their logits read 0; nor are
+    the chunks past a prompt's last token, and their rows of the slot stay
+    as they were.  (A [8, 2048] admit of Mistral's 14 layers was 664 ms on
+    the chip with one prompt in it or eight, PERF.md, PR 32; a row of 2,048
+    was 76 ms whether its prompt had 1,100 tokens or 2,000, PR 37.)
 
     tokens: [B, S] int32 (right-padded to the bucket length S)
     lengths: [B] true prompt lengths; slot_ids: [B] cache rows to fill.
-    start_pos: [B], a paged tree only: the absolute position of
-      ``tokens[:, 0]``, where the slot's block-table row already points at
-      pages that hold a reused prefix (they are read, never written here).
+    start_pos: [B], the absolute position of ``tokens[:, 0]``, where the
+      slot already holds what comes before: on a paged tree its block-table
+      row points at pages with a reused prefix (read, never written here);
+      on a dense tree rows ``0 .. start_pos`` of the slot, which an earlier
+      call wrote (``start_pos + S`` may not pass the slot's ``max_len``).
     rows: scalar int32, how many rows hold a prompt, real rows first; every
       row where it is not given.
+    chunk: the chunk's length (static); the engine leaves it alone.
     Returns (cache, last-token logits [B, V] f32).
     """
     tokens, lengths, slot_ids = map(jnp.asarray, (tokens, lengths, slot_ids))
     b = tokens.shape[0]
-    if start_pos is None:
-        start_pos = jnp.zeros_like(lengths)
-    elif "block_table" not in cache:
-        raise ValueError("start_pos: only pages hold a prefix to start after")
+    if start_pos is not None and not ("block_table" in cache
+                                      or _rows_alone(cache)):
+        raise ValueError("start_pos: a recurrent state and latent rows take "
+                         "no prefix to start after")
 
     def one(r, walk):
         cache, logits = walk
         row = lambda a: jax.lax.dynamic_slice_in_dim(a, r, 1)   # noqa: E731
         cache, lg = _prefill_row(params, cache, row(tokens), row(lengths),
-                                 slot_ids[r], row(start_pos), cfg,
-                                 compute_dtype)
+                                 slot_ids[r],
+                                 None if start_pos is None else row(start_pos),
+                                 cfg, compute_dtype, chunk)
         return cache, jax.lax.dynamic_update_slice_in_dim(logits, lg, r, 0)
 
     return jax.lax.fori_loop(

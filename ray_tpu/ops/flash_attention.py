@@ -41,11 +41,16 @@ NEG_INF = -1e30
 #: resident K and V bytes up to which the forward kernel fits the compiler's
 #: default scoped VMEM (16 MiB) with its blocks and products
 FWD_VMEM_DEFAULT = 12 << 20
+#: the forward kernel where its queries start after what a slot of the
+#: stacked cache already holds (``flash_attention_rows``), as a trace shows it
+KERNEL_FLASH_ROWS = "flash_fwd_rows"
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
-                seq_kv: int, causal: bool, scale: float):
-    """One (batch, head, q-block) program: stream KV blocks, online softmax."""
+                seq_kv: int, causal: bool, scale: float, q_start=None):
+    """One (batch, head, q-block) program: stream KV blocks, online softmax.
+    ``q_start``: the position of the first query among the keys, a scalar
+    that is data (``_fwd_rows_kernel``); None where query i sits at key i."""
     qi = pl.program_id(2)
     block_q = q_ref.shape[2]
     d = q_ref.shape[3]
@@ -55,14 +60,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, d), jnp.float32)
 
+    def first():      # this q-block's first position among the keys
+        return qi * block_q if q_start is None else q_start + qi * block_q
+
     if causal:
         # KV blocks strictly after this q-block's diagonal are fully masked:
         # don't even loop over them.
-        num_kv = (qi * block_q + block_q + block_kv - 1) // block_kv
+        num_kv = (first() + block_q + block_kv - 1) // block_kv
+        if q_start is not None:     # a start that is data may say anything
+            num_kv = jnp.minimum(num_kv, seq_kv // block_kv)
     else:
         num_kv = seq_kv // block_kv
 
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+    q_pos = first() + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_kv), 0)
 
     def body(j, carry):
@@ -95,6 +105,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
                                      (8, block_q))
 
 
+def _fwd_vmem(kv_len: int, d: int, dtype) -> dict:
+    """What a forward call passes the compiler for a head's whole K and V,
+    ``kv_len`` positions each, in VMEM double-buffered: past the compiler's
+    default of 16 MiB (8,192 positions of 256 lanes are 4 MiB each) the
+    kernel asks for what it needs; below, nothing is passed and the call
+    compiles as it always did."""
+    resident = 4 * kv_len * (-(-d // 128) * 128) * jnp.dtype(dtype).itemsize
+    return ({} if resident <= FWD_VMEM_DEFAULT else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=resident + FWD_VMEM_DEFAULT)})
+
+
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
                interpret: bool):
     """q: [B, H, S, D], k/v: [B, KV, S, D] -> (out [B, H, S, D], lse [B, H, S])."""
@@ -108,18 +130,10 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
     grid = (b, h, s // block_q)
     kernel = functools.partial(_fwd_kernel, block_kv=block_kv, seq_kv=s,
                                causal=causal, scale=scale)
-    # a head's whole K and V sit in VMEM, double-buffered: past the
-    # compiler's default of 16 MiB (8,192 positions of 256 lanes are 4 MiB
-    # each) the kernel asks for what it needs; below, nothing is passed and
-    # the call compiles as it always did
-    resident = 4 * s * (-(-d // 128) * 128) * k.dtype.itemsize
-    params = ({} if resident <= FWD_VMEM_DEFAULT else {
-        "compiler_params": pltpu.CompilerParams(
-            vmem_limit_bytes=resident + FWD_VMEM_DEFAULT)})
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        **params,
+        **_fwd_vmem(s, d, k.dtype),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // reps, 0, 0)),
@@ -137,6 +151,46 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
         name="flash_fwd",
     )(q, k, v)
     return out, lse[:, :, 0, :]
+
+
+def _fwd_rows_kernel(at_ref, *refs, **static):
+    """``_fwd_kernel`` with its first query at position ``at_ref[2]`` of the
+    keys (``at_ref``: layer, slot, start; the first two are the index maps')."""
+    _fwd_kernel(*refs, q_start=at_ref[2], **static)
+
+
+def _flash_fwd_rows(q, k_all, v_all, at, num_heads: int, kv_len: int,
+                    block_q: int, block_kv: int, interpret: bool):
+    """The forward kernel on rows where they lie.  q: [W, NH * D], a
+    position's heads side by side; k_all, v_all: [layers, slots, max_len,
+    NKV * D]; at: int32 [3], (layer, slot, the first query's position).  A
+    head is a block of D lanes of a row, so nothing is sliced out of the
+    stack and nothing is transposed on the way in or out.  Returns [W,
+    NH * D]."""
+    w, d = q.shape[0], q.shape[1] // num_heads
+    reps = num_heads * d // k_all.shape[-1]
+    rows = pl.BlockSpec((1, 1, kv_len, d),
+                        lambda bi, hi, qi, at: (at[0], at[1], 0, hi // reps))
+    heads = pl.BlockSpec((1, 1, block_q, d),
+                         lambda bi, hi, qi, at: (0, 0, qi, hi))
+    out, _ = pl.pallas_call(
+        functools.partial(_fwd_rows_kernel, block_kv=block_kv, seq_kv=kv_len,
+                          causal=True, scale=d ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1, num_heads, w // block_q),
+            in_specs=[heads, rows, rows],
+            out_specs=[heads,
+                       pl.BlockSpec((1, 1, 8, block_q),
+                                    lambda bi, hi, qi, at: (0, hi, 0, qi))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((1, 1) + q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((1, num_heads, 8, w), jnp.float32)],
+        **_fwd_vmem(kv_len, d, k_all.dtype),
+        interpret=interpret,
+        name=KERNEL_FLASH_ROWS,
+    )(at, q[None, None], k_all, v_all)
+    return out[0, 0]
 
 
 def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
@@ -505,3 +559,62 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
         return local(q, k, v)
     return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
+
+
+def flash_rows_supported(width: int, kv_len: int, head_dim: int,
+                         block_q: int = 512, block_kv: int = 512
+                         ) -> Optional[str]:
+    """None when ``flash_attention_rows`` can take its kernel at this shape,
+    else the reason."""
+    if head_dim % 128:
+        return f"a head of {head_dim} lanes is no whole block of a row"
+    return flash_supported(width, kv_len, 1, 1, block_q, block_kv)
+
+
+def flash_attention_rows(q, k_all, v_all, layer, slot, start, kv_len: int,
+                         num_kv_heads: int, logit_softcap: float = 0.0,
+                         block_q: int = 512, block_kv: int = 512,
+                         use_kernel: Optional[bool] = None,
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Causal attention of W queries of one sequence that sit at positions
+    ``start .. start + W`` of a slot of the stacked cache, over the slot's
+    rows ``0 .. kv_len`` where they lie, the queries' own rows among them
+    (already written): the forward kernel with a query offset that is data.
+
+    q: [1, W, NH, D]; k_all, v_all: [layers, slots, max_len, NKV * D];
+    layer, slot, start: int32 scalars, traced or not; ``kv_len`` (static)
+    bounds what the row may hold, ``start + W <= kv_len``; rows past a
+    query's own position are masked, whatever they hold.  Returns [1, W,
+    NH * D] in q's dtype.
+
+    ``use_kernel=None`` takes the kernel (bf16 operands as the cache has
+    them, float32 scores and accumulator) on a TPU outside a mesh where the
+    shape tiles and there is no softcap; else the plain ``attend`` with
+    ``q_offset`` over the slot's slab: the CPU path and the path under a
+    mesh."""
+    _, w, num_heads, d = q.shape
+    reason = flash_rows_supported(w, kv_len, d, block_q, block_kv)
+    if reason is None and logit_softcap:
+        reason = "the flash kernel has no logit softcap"
+    if use_kernel is None:
+        use_kernel = reason is None and (bool(interpret) or (
+            jax.default_backend() == "tpu"
+            and jax.sharding.get_abstract_mesh().size <= 1))
+    if use_kernel:
+        if reason is not None:
+            raise ValueError(f"flash attention cannot run this shape: {reason}")
+        out = _flash_fwd_rows(
+            q.reshape(w, -1).astype(k_all.dtype), k_all, v_all,
+            jnp.stack([layer, slot, start]).astype(jnp.int32), num_heads,
+            kv_len, min(block_q, w), min(block_kv, kv_len),
+            resolve_interpret(interpret, "flash"))
+        return out[None].astype(q.dtype)
+    from .attention import attend
+
+    def slab(a):
+        return jax.lax.dynamic_slice(
+            a, (layer, slot, 0, 0), (1, 1, kv_len, a.shape[-1])).reshape(
+                1, kv_len, num_kv_heads, d)
+
+    return attend(q, slab(k_all), slab(v_all), causal=True, q_offset=start,
+                  logit_softcap=logit_softcap).reshape(1, w, -1)

@@ -317,9 +317,13 @@ class LLMEngine:
         self.admit_rows_real = 0
         self.admit_rows_padded = 0
         # the same per token: prompt tokens prefilled, and every other
-        # position of the walked rows (a row is rounded up to its bucket)
+        # position the program walked (a row is rounded up to its bucket,
+        # or to whole chunks where the program walks it in chunks:
+        # decode.prefill_width); the chunks walked and the rows they made up
         self.admit_tokens_real = 0
         self.admit_tokens_padded = 0
+        self.admit_chunks = 0
+        self.admit_rows_chunked = 0
         # what decode attention reads of the cache it holds (dense cache,
         # plain decode): per step the live positions of the active slots,
         # rounded up to the kernel's blocks, against every slot's max_len;
@@ -471,6 +475,8 @@ class LLMEngine:
             "first_token_wait_s": self.first_token_wait_s,
             "admit_tokens_real": self.admit_tokens_real,
             "admit_tokens_padded": self.admit_tokens_padded,
+            "admit_chunks": self.admit_chunks,
+            "admit_rows_chunked": self.admit_rows_chunked,
             "kv_positions_read": self.kv_positions_read,
             "kv_positions_held": self.kv_positions_held,
             "kv_positions_live": self.kv_positions_live,
@@ -551,6 +557,17 @@ class LLMEngine:
 
     # ----------------------------------------------------- observability
 
+    def _admit_walk(self, reqs: List[GenRequest], bucket: int):
+        """(chunks, positions) the admit program walks for ``reqs`` at
+        ``bucket``: whole rows and no chunks, or the chunks each prompt
+        fills (``decode.prefill_width``; only a dense tree is walked in
+        chunks, and its rows are whole prompts)."""
+        width = self._dec.prefill_width(self.cache, bucket)
+        if width == bucket:
+            return 0, len(reqs) * bucket
+        chunks = sum(-(-len(r.tokens) // width) for r in reqs)
+        return chunks, chunks * width
+
     def _obs_admit(self, reqs: List[GenRequest], bucket: int,
                    tokens_real: int):
         """One admit batch, just dispatched: padding accounting (rows and
@@ -562,7 +579,10 @@ class LLMEngine:
         self.admit_batches += 1
         self.admit_rows_real += len(reqs)
         self.admit_tokens_real += tokens_real
-        self.admit_tokens_padded += len(reqs) * bucket - tokens_real
+        chunks, walked = self._admit_walk(reqs, bucket)
+        self.admit_chunks += chunks
+        self.admit_rows_chunked += len(reqs) if chunks else 0
+        self.admit_tokens_padded += walked - tokens_real
         self.moe_assignments_prefill += (
             tokens_real * self.cfg.experts_per_token * self.cfg.expert_layers)
         self.admitted_requests += len(reqs)
@@ -763,7 +783,9 @@ class LLMEngine:
                         if b != bucket:
                             break
                         admits.append(self._pending.get())
-                    span.set_metadata(bucket=bucket, rows=len(admits))
+                    span.set_metadata(
+                        bucket=bucket, rows=len(admits),
+                        chunks=self._admit_walk(admits, bucket)[0])
                     self._admit(admits, bucket)
                 did_work = True
             if self._active:

@@ -188,8 +188,10 @@ HBM_GIB = 15.75          # what the compiler allows a program on a v5e
 # row needs, 0.002 GB at 256 and 0.24 GB at 2048 (eight rows at once held
 # 0.24 / 2.28 GB), beside the same two stacks transposed once a program, which
 # the compiler hoists out of the row loop as it does out of decode's step
-# loop: readings 0.589 and 0.825 GB
-TEMP_GB = {"decode": 0.7, "prefill-256": 0.6, "prefill-2048": 0.9}
+# loop: readings 0.589 and 0.825 GB.  Since PR 37 the 2048 program walks a row
+# in chunks of 512, so what one pass needs is a chunk's and the stacks are
+# hoisted out of that loop too: reading 0.591 GB
+TEMP_GB = {"decode": 0.7, "prefill-256": 0.6, "prefill-2048": 0.7}
 _cell_compiled = {}
 
 
@@ -269,10 +271,37 @@ def test_cell_program_updates_the_cache_in_place(one_chip, as_tpu, program,
             assert np.prod(np.int64(dims_of[update].split(","))) < slab, (
                 f"%{name} writes [{dims_of[update]}] into the stacked cache")
     if program != "decode":
-        # rows, then layers: the outer loop's trip count is the admit's data
-        assert len(re.findall(r" while\(", text)) == 2
+        # rows, then layers: the outer loop's trip count is the admit's
+        # data; a dense tree's bucket of four chunks has the loop over a
+        # row's chunks between them, its trip count data too, and one
+        # kernel, the forward kernel with a query offset
+        chunked = model != "hybrid" and (
+            int(program.split("-")[1]) >= 4 * decode.PREFILL_CHUNK)
+        assert len(re.findall(r" while\(", text)) == (3 if chunked else 2)
+        if chunked:
+            from ray_tpu.ops.flash_attention import KERNEL_FLASH_ROWS
+            assert text.count(KERNEL) == 1 and KERNEL_FLASH_ROWS in text
         if model == "hybrid":    # one row's pass still takes the kernels:
             assert text.count(KERNEL) == 4    # gdn_chunk_fwd x 3, flash_fwd
+
+
+def test_offset_flash_kernel_compiles_over_the_stack(one_chip):
+    """A chunk's queries [1, 512, 32, 128] over a slot's 2,048 rows of the
+    cell's stacked cache, where they lie: a head is a block of 128 lanes of a
+    row, so no slab is sliced out and nothing is transposed beside it."""
+    from ray_tpu.ops import flash_attention as fa
+    S = lambda dims, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dt, sharding=one_chip)
+    stack = S((14, CELL_SLOTS, CELL_MAX_LEN, 1024))
+    i32 = S((), jnp.int32)
+    compiled, text = _compile(
+        lambda q, k, v, layer, slot, start: fa.flash_attention_rows(
+            q, k, v, layer, slot, start, CELL_MAX_LEN, 8, use_kernel=True,
+            interpret=False),
+        S((1, 512, 32, 128)), stack, stack, i32, i32, i32)
+    assert text.count(KERNEL) == 1 and fa.KERNEL_FLASH_ROWS in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+    assert not _slab_ops(text, 1, CELL_MAX_LEN, 1024)
 
 
 def _slab_ops(text, slots, max_len, chan):
@@ -511,6 +540,41 @@ def test_latent_cell_program_fits_and_updates_its_cache_in_place(
     assert not re.search(
         r"= bf16\[(1,)?64,(3584,1024|1024,3584)\]\S* "
         r"(dynamic-slice|copy|fusion)\(", text)
+
+
+# ---------- the programs that walk whole rows are the parent's (PR 37)
+# Only a dense tree's bucket of four chunks or more compiles to another
+# program; every other one keeps the temporaries and the generated code size
+# it had at PR 35 (sandbox compiles of both trees, PR 37), to the byte.  The
+# hybrid's 512 / 1024 and the latent kind's 4096 read equal too ((102804992,
+# 13414400), (130491392, 13181440), (960504832, 27387392)); they are left out
+# for the minute their compiles take: the choice is read off the tree's
+# leaves, not the bucket.
+
+WHOLE_ROW_PROGRAMS = {
+    (14, "decode"): (588719616, 2803200),
+    (14, "prefill-128"): (589478400, 3622400),
+    (14, "prefill-256"): (589478400, 5218816),
+    (14, "prefill-512"): (589478400, 7218688),
+    (14, "prefill-1024"): (651342848, 7483392),
+    ("hybrid", "prefill-256"): (88294912, 10439168),
+    ("hybrid", "prefill-2048"): (275977216, 13357056),
+    ("hybrid", "prefill-4096"): (731474432, 14008832),
+    ("latent", "decode"): (163313664, 10171904),
+    ("latent", "prefill-2048"): (499856896, 23137792),
+    ("latent", "prefill-8192"): (1710878208, 31636992),
+}
+
+
+@pytest.mark.parametrize("model,program", list(WHOLE_ROW_PROGRAMS),
+                         ids=lambda v: str(v))
+def test_whole_row_programs_are_the_parents(one_chip, as_tpu, model,
+                                            program):
+    compiled, _ = (_latent_program(one_chip, program) if model == "latent"
+                   else _cell_program(one_chip, program, model))
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes, mem.generated_code_size_in_bytes
+            ) == WHOLE_ROW_PROGRAMS[model, program]
 
 
 # ------------------------------------------------- the sharded train step

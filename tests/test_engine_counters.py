@@ -59,6 +59,8 @@ def test_admitted_tokens_are_counted_real_and_padded(tiny_cfg, paged):
                    for i, n in enumerate(PROMPT_LENS)]
         _run(eng, prompts)
         c = eng.counters()
+        # buckets this short are walked whole: no chunks
+        assert c["admit_chunks"] == c["admit_rows_chunked"] == 0
         assert c["admit_tokens_real"] == sum(PROMPT_LENS)
         assert c["admit_tokens_real"] == sum(real for _, _, real in seen)
         # every position the chip walked is counted once, as a prompt token
@@ -75,6 +77,37 @@ def test_admitted_tokens_are_counted_real_and_padded(tiny_cfg, paged):
         assert sum(rows for rows, _, _ in seen) == len(prompts)
         # the row accounting the committed readers use is untouched
         assert eng.breakdown()["admit_batches"] == len(seen)
+    finally:
+        eng.shutdown()
+
+
+def test_chunked_rows_count_the_chunks_they_walked(tiny_cfg):
+    """A dense tree's bucket of four chunks is walked in the chunks a prompt
+    fills (``decode.prefill_width``): the padding counted is what the chip
+    walked less the prompt, a row rounded up to whole chunks and not to its
+    bucket, and ``admit_chunks`` / ``admit_rows_chunked`` say how often."""
+    from ray_tpu.models import decode
+    chunk, bucket = decode.PREFILL_CHUNK, 4 * decode.PREFILL_CHUNK
+    eng = _engine(tiny_cfg, num_slots=2, max_len=bucket + 64,
+                  buckets=(64, bucket))
+    try:
+        assert decode.prefill_width(eng.cache, bucket) == chunk
+        assert decode.prefill_width(eng.cache, 64) == 64
+        seen = _spy_admits(eng)
+        lens = (chunk * 2 + 76, 40, chunk * 3 + 164, bucket, chunk * 3)
+        _run(eng, [[1 + (i + j) % 50 for j in range(n)]
+                   for i, n in enumerate(lens)], max_tokens=2)
+        c = eng.counters()
+        assert c["admit_tokens_real"] == sum(lens)
+        assert c["admit_chunks"] == 3 + 4 + 4 + 3
+        assert c["admit_rows_chunked"] == 4
+        assert c["admit_tokens_padded"] == (
+            (chunk - 76) + (64 - 40) + (chunk - 164) + 0 + 0)
+        # every admit of the long bucket walked fewer positions than its
+        # rows times the bucket, unless every prompt needed all four chunks
+        assert c["admit_tokens_real"] + c["admit_tokens_padded"] < sum(
+            rows * b for rows, b, _ in seen)
+        assert c["first_tokens"] == len(lens)
     finally:
         eng.shutdown()
 
@@ -202,7 +235,8 @@ def test_server_counts_delivered_tokens_and_their_lag():
         # and the new keys ride along
         for key in ["t_mono", "loop_iterations", "admitted_requests",
                     "queue_wait_s", "first_tokens", "first_token_wait_s",
-                    "admit_tokens_real", "admit_tokens_padded"] + [
+                    "admit_tokens_real", "admit_tokens_padded",
+                    "admit_chunks", "admit_rows_chunked"] + [
                         f"loop_{ph}_{k}" for ph in ENGINE_PHASES
                         for k in ("s", "n")]:
             assert key in st, key

@@ -150,6 +150,21 @@ def test_flash_kernels_are_named():
         re.findall(r"flash_\w+", str(jaxpr)))
 
 
+def test_the_offset_flash_kernel_is_named():
+    """``flash_fwd_rows [pallas]`` in a device trace: the forward kernel
+    with a query offset, a name of its own beside the whole row's
+    ``flash_fwd``, so a reader of one does not read the other unawares."""
+    from ray_tpu.ops import flash_attention as fa
+
+    assert fa.KERNEL_FLASH_ROWS == "flash_fwd_rows"
+    stack = jnp.ones((2, 3, 256, 2 * 128), jnp.float32)
+    jaxpr = str(jax.make_jaxpr(lambda q, k, v, at: fa.flash_attention_rows(
+        q, k, v, 1, 2, at, 256, 2, interpret=True))(
+            jnp.ones((1, 128, 4, 128), jnp.float32), stack, stack,
+            jnp.int32(128)))
+    assert set(re.findall(r"flash_\w+", jaxpr)) == {"flash_fwd_rows"}
+
+
 def test_the_decode_attention_kernel_is_named():
     """``decode_attn [pallas]`` in a device trace; the benchmark's
     ``decode_attn_roofline`` spells the name out for itself."""
@@ -220,7 +235,7 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
     admits = by_name["raytpu:engine.admit"]
     assert len(admits) == 3
     assert all(st.get("bucket") == 16 and st.get("rows") == 1
-               for _d, st in admits)
+               and st.get("chunks") == 0 for _d, st in admits)
     assert sum(st["tokens"] for _d, st in by_name["raytpu:engine.emit"]) \
         == 27
     # the capture brackets the two snapshots, so it holds at least the
