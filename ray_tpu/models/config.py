@@ -89,6 +89,8 @@ class TransformerConfig:
     # of ``qk_nope_head_dim + qk_rope_head_dim``, values of ``v_head_dim``;
     # a token caches one row of ``kv_lora_rank + qk_rope_head_dim`` a layer,
     # shared by all heads.  ``head_dim`` is then not hidden / heads.
+    # ``q_lora_rank`` 0: the queries are projected directly (one matrix, no
+    # compression and no norm), as the published file says with ``null``.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -111,6 +113,23 @@ class TransformerConfig:
     shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     dense_prefix_layers: int = 0
+    # a chip's share of the dropless experts: the router keeps its
+    # ``num_experts`` outputs and this holder has the weights of experts
+    # ``expert_start .. expert_start + experts_held`` (given as 0: all of
+    # them, and the field then reads ``num_experts``).  Assignments to the
+    # others are left out of its part of the result (``moe_dropless``);
+    # nothing here exchanges tokens or stands in for the other holders.
+    # ``share_by_position``: the held weights stand for another group of
+    # ``experts_held`` router outputs at each position of a sequence, group
+    # ``expert_start / experts_held + position`` modulo the number of
+    # groups, so that the share of a step's assignments kept here is
+    # ``experts_held / num_experts`` whatever the router learns.  With one
+    # fixed group and the others' part left out, a router under training
+    # learns to send its tokens past the held experts (PERF.md, PR 39).
+    # The train step's; the serving path refuses it.
+    expert_start: int = 0
+    experts_held: int = 0
+    share_by_position: bool = False
     # ``hc_mult`` > 1 residual streams mixed by manifold-constrained
     # hyper-connections (models/latent.py ``hc_*``; arXiv 2512.24880)
     hc_mult: int = 0
@@ -118,14 +137,18 @@ class TransformerConfig:
     hc_eps: float = 1e-6
     hc_res_clamp: float = 30.0
 
-    #: the fields that only the serving path's one walk over the layers
-    #: (models/decode.py ``layer_stack``) knows
-    SERVED_ONLY = ("kv_lora_rank", "moe_dropless", "dense_prefix_layers",
+    #: the fields whose parameters are ``models/latent.py``'s tree
+    #: (``prefix`` / ``blocks``) and whose serving state is its cache
+    LATENT_TREE = ("kv_lora_rank", "moe_dropless", "dense_prefix_layers",
                    "hc_mult")
+    #: of them, what only the serving path's walk over the layers
+    #: (models/decode.py ``layer_stack``) knows: the train step wires one
+    #: residual stream
+    SERVED_ONLY = ("hc_mult",)
 
     def __post_init__(self):
         pat = self.layer_pattern
-        self._check_served_only()
+        self._check_latent_tree()
         if not pat:
             if self.qk_norm or self.norm_on_output:
                 raise ValueError("qk_norm and norm_on_output are wired for "
@@ -146,17 +169,18 @@ class TransformerConfig:
             raise ValueError("a 'linear' layer needs linear_num_heads, "
                              "linear_key_dim and linear_value_dim")
 
-    def _check_served_only(self):
-        on = [f for f in self.SERVED_ONLY if getattr(self, f)]
+    def _check_latent_tree(self):
+        on = self.latent_tree
         if on and self.layer_pattern:
             raise ValueError(f"{on} do not combine with a layer_pattern "
                              "(models/hybrid.py wires its own blocks)")
         if self.kv_lora_rank:
-            if not (self.q_lora_rank and self.qk_nope_head_dim
-                    and self.qk_rope_head_dim and self.v_head_dim):
+            if not (self.qk_nope_head_dim and self.qk_rope_head_dim
+                    and self.v_head_dim) or self.q_lora_rank < 0:
                 raise ValueError(
-                    "latent attention (kv_lora_rank) needs q_lora_rank, "
-                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+                    "latent attention (kv_lora_rank) needs qk_nope_head_dim, "
+                    "qk_rope_head_dim and v_head_dim, and a q_lora_rank of "
+                    "0 (queries projected directly) or more")
             if self.num_kv_heads != self.num_heads:
                 raise ValueError("latent attention has one key and one "
                                  "value head a query head: num_kv_heads "
@@ -167,9 +191,9 @@ class TransformerConfig:
                                  "without biases or softcap")
             if self.qk_rope_head_dim % 2:
                 raise ValueError("qk_rope_head_dim must be even")
-        elif self.rope_yarn_factor:
-            raise ValueError("rope_yarn_factor is read by latent attention "
-                             "only (models/latent.py)")
+        elif self.rope_yarn_factor or self.q_lora_rank:
+            raise ValueError("rope_yarn_factor and q_lora_rank are read by "
+                             "latent attention only (models/latent.py)")
         if self.rope_yarn_factor and not self.rope_yarn_original_max:
             raise ValueError("rope_yarn_factor needs rope_yarn_original_max")
         if self.moe_dropless:
@@ -185,10 +209,27 @@ class TransformerConfig:
                 raise ValueError(
                     f"dense_prefix_layers {self.dense_prefix_layers} of "
                     f"{self.num_layers} layers leaves no expert layer")
+            if not self.experts_held:
+                object.__setattr__(self, "experts_held", self.num_experts)
+            if self.expert_start < 0 or self.experts_held < 0 or \
+                    self.expert_start + self.experts_held > self.num_experts:
+                raise ValueError(
+                    f"experts {self.expert_start} to {self.expert_start} + "
+                    f"{self.experts_held} are not among the router's "
+                    f"{self.num_experts}")
+            if self.share_by_position and (
+                    self.num_experts % self.experts_held
+                    or self.expert_start % self.experts_held):
+                raise ValueError(
+                    f"share_by_position: the router's {self.num_experts} "
+                    f"outputs in groups of experts_held {self.experts_held}"
+                    f", expert_start {self.expert_start} the first of one")
         elif self.dense_prefix_layers or self.shared_experts \
-                or self.expert_mlp_size:
-            raise ValueError("dense_prefix_layers, shared_experts and "
-                             "expert_mlp_size belong to moe_dropless")
+                or self.expert_mlp_size or self.expert_start \
+                or self.experts_held or self.share_by_position:
+            raise ValueError("dense_prefix_layers, shared_experts, "
+                             "expert_mlp_size, expert_start, experts_held "
+                             "and share_by_position belong to moe_dropless")
         if self.hc_mult == 1 or self.hc_mult < 0:
             raise ValueError(f"hc_mult {self.hc_mult}: 0 (one residual "
                              "stream) or at least 2")
@@ -212,10 +253,16 @@ class TransformerConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
+    def latent_tree(self) -> Tuple[str, ...]:
+        """The mechanisms of this configuration that make its parameters
+        ``models/latent.py``'s tree and its serving state that file's cache
+        (no pages, no window of several tokens, no mesh)."""
+        return tuple(f for f in self.LATENT_TREE if getattr(self, f))
+
+    @property
     def served_only(self) -> Tuple[str, ...]:
         """The mechanisms of this configuration that only the serving path
-        runs (no backward, no pages, no window of several tokens, no
-        mesh)."""
+        runs (the train step has no block for them)."""
         return tuple(f for f in self.SERVED_ONLY if getattr(self, f))
 
     @property
@@ -241,10 +288,11 @@ class TransformerConfig:
         return self.num_layers - self.linear_layers
 
     def num_params(self) -> int:
-        """Approximate parameter count (for MFU math)."""
+        """Approximate parameter count (for MFU math): what this holder
+        has, where it holds a share of the experts."""
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
-        if self.served_only:
-            return self._served_only_params()
+        if self.latent_tree:
+            return self._latent_tree_params(self.experts_held)
         attn = h * h + 2 * h * (self.num_kv_heads * self.head_dim) + h * h
         if self.layer_pattern:
             kd = self.linear_num_heads * self.linear_key_dim
@@ -260,13 +308,16 @@ class TransformerConfig:
         emb = v * h * (1 if self.tied_embeddings else 2)
         return L * (attn + mlp) + emb
 
-    def _served_only_params(self) -> int:
-        """Matrix parameters where latent attention, dropless experts, a
-        dense prefix or hyper-connections are on."""
+    def _latent_tree_params(self, routed: float) -> float:
+        """Matrix parameters of ``models/latent.py``'s tree (latent
+        attention, dropless experts, a dense prefix or hyper-connections)
+        with ``routed`` routed experts a layer: those held, for what a
+        holder has; those a token meets here, for its FLOPs."""
         h, nh, L = self.hidden_size, self.num_heads, self.num_layers
         if self.kv_lora_rank:
-            attn = (h * self.q_lora_rank
-                    + self.q_lora_rank * nh * self.qk_head_dim
+            qr = self.q_lora_rank
+            attn = ((h * qr + qr * nh * self.qk_head_dim if qr
+                     else h * nh * self.qk_head_dim)
                     + h * self.latent_row
                     + self.kv_lora_rank * nh * (self.qk_nope_head_dim
                                                 + self.v_head_dim)
@@ -277,7 +328,7 @@ class TransformerConfig:
         sparse = dense
         if self.moe_dropless:
             sparse = (3 * h * self.expert_mlp_size
-                      * (self.num_experts + self.shared_experts)
+                      * (routed + self.shared_experts)
                       + h * self.num_experts)
         n = self.hc_mult
         hc = 2 * n * h * (2 * n + n * n) if n else 0
@@ -292,6 +343,21 @@ class TransformerConfig:
             raise NotImplementedError(
                 f"{self.served_only} are served, not trained: no training "
                 "FLOPs a token")
+        if self.latent_tree:
+            # what this holder does for a token: 6 a parameter the token
+            # meets here (its routed experts at the expected share that
+            # lands on the experts held), the head without the embedding's
+            # lookup, and the score and value matmuls at the heads' own
+            # widths (not the kernel's padded ones), halved by causality
+            s = seq_len or self.max_seq_len
+            met = self.experts_per_token * self.experts_held \
+                / self.num_experts if self.moe_dropless else 0.0
+            emb = self.vocab_size * h * (1 if self.tied_embeddings else 2)
+            heads = (self.qk_head_dim + self.v_head_dim if self.kv_lora_rank
+                     else 2 * (h // self.num_heads))
+            return (6.0 * (self._latent_tree_params(met) - emb
+                           + self.vocab_size * h)
+                    + 3.0 * L * self.num_heads * heads * s)
         if self.layer_pattern:
             # the quadratic term for the full layers only; the mixer's state
             # update and read are 4 * key_dim * value_dim a head a token

@@ -140,7 +140,7 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
             "linear_layers": cfg.linear_layers,
             "full_layers": cfg.full_layers,
             "expert_layers": cfg.expert_layers,
-            "experts_held": cfg.num_experts if cfg.moe_dropless else 0}
+            "experts_held": cfg.experts_held if cfg.moe_dropless else 0}
 
 
 # ---------------------------------------------------------------------------
@@ -191,11 +191,12 @@ def _experts(y, lp, cfg: TransformerConfig, live, cast, layer, stacks):
     experts, ...], of which this is ``layer``.  Returns (out, (counts [2],
     the chosen experts [rows, W, k]))."""
     from ..ops import moe as moe_ops
-    out, counts, idx = moe_ops.moe_dropless(
+    out, counts, idx, _ = moe_ops.moe_dropless(
         y.reshape(-1, y.shape[-1]), lp["moe"], stacks, layer,
         experts_per_token=cfg.experts_per_token,
         scaling=cfg.routed_scaling_factor, compute_dtype=cast,
-        live=None if live is None else live.reshape(-1))
+        live=None if live is None else live.reshape(-1),
+        expert_start=cfg.expert_start)
     return out.reshape(y.shape), (counts, idx.reshape(y.shape[:2] + (-1,)))
 
 

@@ -1,16 +1,20 @@
 """Latent attention, a dense prefix before dropless expert layers, and
-residual streams mixed by hyper-connections, on the serving path.  This file
-is what is particular to them: the parameter tree, the per-slot state and the
-attention mixers for whole rows (``prefill_attention``) and for one token a
-slot (``decode_attention``), and the hyper-connections' coefficients.  The
-walk over the layers and the block's wiring are ``decode.py``'s
-(``layer_stack``), the expert layer is ``ops/moe.py``'s (``moe_dropless``);
-``serve/llm.py`` runs the same calls on this cache as on any.
+residual streams mixed by hyper-connections.  This file is what is
+particular to them: the parameter tree, the per-slot state and the attention
+mixers for whole rows (``prefill_attention``, with its cache write for
+serving and without for the train step: ``attention``) and for
+one token a slot (``decode_attention``), and the hyper-connections'
+coefficients.  Serving's walk over the layers and its block's wiring are
+``decode.py``'s (``layer_stack``), training's ``transformer.py``'s
+(``block_forward``; one residual stream), the expert layer is
+``ops/moe.py``'s (``moe_dropless``); ``serve/llm.py`` runs the same calls on
+this cache as on any.
 
 **Latent attention** (DeepSeek-V2's MLA; ``N`` an RMSNorm with a learned
 scale), for a layer's input ``x``::
 
     c_q = N(x W_dq);  q = c_q W_uq -> heads of [q_nope | q_rope]
+    (``q_lora_rank`` 0:  q = x W_q, projected directly)
     [c_kv | k_r] = x W_dkv;  c_kv = N(c_kv);  k_r = rope(k_r)   one for all heads
     [k_nope | v] = c_kv W_ukv                                   per head
     score = (q_nope . k_nope + rope(q_rope) . k_r) * scale;  o = softmax(score) v W_o
@@ -53,6 +57,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from jax.ad_checkpoint import checkpoint_name
 
 from .config import TransformerConfig
 from .transformer import Params, _norm
@@ -153,9 +159,11 @@ def _init_group(key, cfg: TransformerConfig, layers: int, sparse: bool,
     group: Params = {"attn_norm": ones(h), "mlp_norm": ones(h)}
     if cfg.kv_lora_rank:
         qr, cr = cfg.q_lora_rank, cfg.kv_lora_rank
+        queries = ({"w_dq": dense((h, qr), h), "q_norm": ones(qr),
+                    "w_uq": dense((qr, nh * cfg.qk_head_dim), qr)} if qr
+                   else {"wq": dense((h, nh * cfg.qk_head_dim), h)})
         group["attn"] = {
-            "w_dq": dense((h, qr), h), "q_norm": ones(qr),
-            "w_uq": dense((qr, nh * cfg.qk_head_dim), qr),
+            **queries,
             "w_dkv": dense((h, cfg.latent_row), h), "kv_norm": ones(cr),
             "w_ukv": dense((cr, nh * (cfg.qk_nope_head_dim
                                       + cfg.v_head_dim)), cr),
@@ -172,10 +180,12 @@ def _init_group(key, cfg: TransformerConfig, layers: int, sparse: bool,
 
         def experts(shape, fan_in):
             # a layer at a time: one [layers, experts, ...] draw would hold
-            # its float32 bits beside the result
+            # its float32 bits beside the result.  Of the router's ``e``
+            # experts the ones this holder has (``cfg.experts_held``).
             return jax.lax.map(
-                lambda k: (jax.random.normal(k, (e,) + shape, dtype)
-                           * fan_in ** -0.5).astype(dtype),
+                lambda k: (jax.random.normal(
+                    k, (cfg.experts_held,) + shape, dtype)
+                    * fan_in ** -0.5).astype(dtype),
                 jax.random.split(next(keys), layers))
 
         group["moe"] = {"router": dense((h, e), h),
@@ -249,9 +259,12 @@ def _down(y, ap, cfg: TransformerConfig, positions):
     heads, and what a token caches."""
     b, s, _ = y.shape
     cast = y.dtype
-    c_q = _norm(y @ ap["w_dq"].astype(cast), ap["q_norm"], cfg)
-    q = (c_q @ ap["w_uq"].astype(cast)).reshape(b, s, cfg.num_heads,
-                                                cfg.qk_head_dim)
+    if "wq" in ap:                  # q_lora_rank 0: projected directly
+        q = y @ ap["wq"].astype(cast)
+    else:
+        c_q = _norm(y @ ap["w_dq"].astype(cast), ap["q_norm"], cfg)
+        q = c_q @ ap["w_uq"].astype(cast)
+    q = q.reshape(b, s, cfg.num_heads, cfg.qk_head_dim)
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
     down = y @ ap["w_dkv"].astype(cast)
     c_kv = _norm(down[..., :cfg.kv_lora_rank], ap["kv_norm"], cfg)
@@ -278,22 +291,15 @@ def _w_ukv(ap, cfg: TransformerConfig, cast):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def prefill_attention(y, ap, cfg: TransformerConfig, latent_all, rope_all,
-                      i, slot, positions):
-    """One layer's causal latent attention over one right-padded row, the
-    expanded form.  y: [1, S, H]; writes the row's ``c_kv`` and ``k_r`` at
-    ``[i, slot]`` of the stacked cache, in place.  Returns (attention after
-    its output projection [1, S, H], latent_all, rope_all)."""
+def _expanded(q_nope, q_rope, c_kv, k_r, ap, cfg: TransformerConfig):
+    """Causal latent attention over whole rows in the expanded form, from
+    what ``_down`` returns: keys and values rebuilt per head from the latent
+    rows, through the flash kernel the other kinds share
+    (``ops.attention.mha``; it has a backward), then the output projection.
+    Returns [B, S, H]."""
     from ..ops.attention import mha
-    b, s, _ = y.shape
-    cast, nh = y.dtype, cfg.num_heads
-    q_nope, q_rope, c_kv, k_r = _down(y, ap, cfg, positions)
-    with jax.named_scope("latent_write"):
-        latent_all = _row_major(jax.lax.dynamic_update_slice(
-            latent_all, c_kv.astype(latent_all.dtype)[None], (i, slot, 0, 0)))
-        rope_all = _row_major(jax.lax.dynamic_update_slice(
-            rope_all, k_r.astype(rope_all.dtype).swapaxes(1, 2)[None],
-            (i, slot, 0, 0)))
+    b, s, nh, _ = q_nope.shape
+    cast = c_kv.dtype
     with jax.named_scope("mla_up"):
         w_uk, w_uv = _w_ukv(ap, cfg, cast)
         k_nope = jnp.einsum("bsc,chd->bshd", c_kv, w_uk)
@@ -316,7 +322,35 @@ def prefill_attention(y, ap, cfg: TransformerConfig, latent_all, rope_all,
         attn = mha(fit(q.astype(cast)), fit(k), fit(v), causal=True)
     attn = attn[:, :s, :, :cfg.v_head_dim].reshape(b, s, -1)
     with jax.named_scope("attn"):
-        return attn @ ap["wo"].astype(cast), latent_all, rope_all
+        return attn @ ap["wo"].astype(cast)
+
+
+def attention(y, ap, cfg: TransformerConfig, positions):
+    """One layer's causal latent attention over whole sequences, no cache:
+    the train step's mixer.  What a layer's replay needs carries the names
+    ``transformer.REMAT_SAVE_NAMES`` has: the queries and what a token would
+    cache (keys and values are rebuilt from that, a small matmul).  y: [B,
+    S, H]; positions: [1, S] or [B, S].  Returns [B, S, H]."""
+    parts = (checkpoint_name(a, "mla_" + n) for a, n in zip(
+        _down(y, ap, cfg, positions), ("q_nope", "q_rope", "c_kv", "k_r")))
+    return _expanded(*parts, ap, cfg)
+
+
+def prefill_attention(y, ap, cfg: TransformerConfig, latent_all, rope_all,
+                      i, slot, positions):
+    """One layer's causal latent attention over one right-padded row, the
+    expanded form.  y: [1, S, H]; writes the row's ``c_kv`` and ``k_r`` at
+    ``[i, slot]`` of the stacked cache, in place.  Returns (attention after
+    its output projection [1, S, H], latent_all, rope_all)."""
+    q_nope, q_rope, c_kv, k_r = _down(y, ap, cfg, positions)
+    with jax.named_scope("latent_write"):
+        latent_all = _row_major(jax.lax.dynamic_update_slice(
+            latent_all, c_kv.astype(latent_all.dtype)[None], (i, slot, 0, 0)))
+        rope_all = _row_major(jax.lax.dynamic_update_slice(
+            rope_all, k_r.astype(rope_all.dtype).swapaxes(1, 2)[None],
+            (i, slot, 0, 0)))
+    return (_expanded(q_nope, q_rope, c_kv, k_r, ap, cfg), latent_all,
+            rope_all)
 
 
 def decode_attention(y, ap, cfg: TransformerConfig, latent_all, rope_all, i,

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+import jax
 from jax.sharding import PartitionSpec as P
 
 from .config import TransformerConfig
@@ -29,8 +30,31 @@ def batch_spec() -> P:
     return P(BATCH_AXES, "sp")
 
 
+def refuse_mesh(cfg: TransformerConfig, mesh, what: str):
+    """``models/latent.py``'s tree (latent attention, dropless experts, a
+    dense prefix) lives on one device: none of its kernels is shard_mapped,
+    no rule here splits its leaves, and nothing exchanges tokens over
+    ``ep``: a holder of a share of the experts (``cfg.experts_held``)
+    computes its part of a layer and no more.  As ``LLMEngine`` refuses
+    ``tp > 1`` for the same fields."""
+    if cfg.latent_tree and mesh.size > 1:
+        raise NotImplementedError(
+            f"{what}: {', '.join(cfg.latent_tree)} on a mesh of "
+            f"{dict(mesh.shape)}: these parameters have no sharding rule, "
+            "the grouped-matmul and flash kernels under them are not "
+            "shard_mapped, and no exchange of tokens over ep exists; one "
+            "device holds the tree (a share of the experts: experts_held)")
+
+
 def logical_param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpec tree matching init_params' structure."""
+    if cfg.latent_tree:
+        # whole on its one device (``refuse_mesh``): the tree's own
+        # structure, every leaf unsplit
+        from . import transformer
+        return jax.tree.map(
+            lambda _: P(), jax.eval_shape(
+                lambda: transformer.init_params(jax.random.PRNGKey(0), cfg)))
     def norm_spec(stacked: bool):
         p = {"scale": P(None, None) if stacked else P(None)}
         if not cfg.use_rmsnorm:

@@ -36,7 +36,14 @@ Params = Dict[str, Any]
 # bytes/token/layer of HBM.  "save_mlp" keeps just the MLP half (the FLOP bulk)
 # when the full set doesn't fit.
 REMAT_SAVE_NAMES = ("attn_q", "attn_k", "attn_v", "attn_out", "attn_lse",
-                    "mlp_gate", "mlp_up", "mlp_pre")
+                    "mlp_gate", "mlp_up", "mlp_pre",
+                    # latent attention (models/latent.py ``attention``): the
+                    # queries and what a token would cache; keys and values
+                    # are rebuilt from that.  Nothing of a dropless expert
+                    # layer's sorted rows is kept: they are sized for every
+                    # assignment (ops/moe.py), and its replay is three
+                    # grouped products
+                    "mla_q_nope", "mla_q_rope", "mla_c_kv", "mla_k_r")
 
 
 def remat_policy(remat: Union[bool, str, None]):
@@ -86,7 +93,7 @@ class ParallelContext:
 
 def init_params(key: jax.Array, cfg: TransformerConfig,
                 dtype=jnp.float32) -> Params:
-    if cfg.served_only:
+    if cfg.latent_tree:
         # latent attention, dropless experts, a dense prefix, residual
         # streams (models/latent.py)
         from . import latent
@@ -266,8 +273,13 @@ def _attention_block(x, p, cfg: TransformerConfig, positions, pctx: ParallelCont
 def _mlp_block(x, p, cfg: TransformerConfig):
     cast = x.dtype
     if cfg.use_swiglu:
-        gate = checkpoint_name(x @ p["w_gate"].astype(cast), "mlp_gate")
-        up = checkpoint_name(x @ p["w_in"].astype(cast), "mlp_up")
+        gate, up = x @ p["w_gate"].astype(cast), x @ p["w_in"].astype(cast)
+        # a dense prefix's MLP is wide and alone: its two products kept for
+        # the backward are 0.7 GB of a 16 GB chip at the share cell's
+        # sizes, and their replay a hundredth of the step (PERF.md, PR 39)
+        if not cfg.dense_prefix_layers:
+            gate = checkpoint_name(gate, "mlp_gate")
+            up = checkpoint_name(up, "mlp_up")
         return (jax.nn.silu(gate) * up) @ p["w_out"].astype(cast)
     hmid = x @ p["w_in"].astype(cast) + p["b_in"].astype(cast)
     hmid = checkpoint_name(hmid, "mlp_pre")
@@ -279,25 +291,73 @@ def _mlp_block(x, p, cfg: TransformerConfig):
 # Forward
 # ---------------------------------------------------------------------------
 
+def _block(x: jnp.ndarray, layer_params: Params, cfg: TransformerConfig,
+           positions: jnp.ndarray, pctx: ParallelContext):
+    """One block on one residual stream: x [B, S, H] -> (x, aux), the
+    sublayers chosen by what the configuration and the layer's parameters
+    say: attention dense or latent (``cfg.kv_lora_rank``), the MLP dense
+    (a layer with ``mlp``: every layer of a dense model, the dense prefix of
+    an expert model), experts with a capacity (``moe_mlp``) or dropless
+    (``cfg.moe_dropless``).  aux: ``moe_aux_loss`` (the capacity layer's
+    balance term, else 0) and, of a dropless layer, ``moe_load`` [experts
+    held] int32, the assignments each held expert computed."""
+    y = _norm(x, layer_params["attn_norm"], cfg)
+    if cfg.kv_lora_rank:
+        from . import latent
+        attn_out = latent.attention(y, layer_params["attn"], cfg,
+                                    positions[None])
+    else:
+        attn_out = _attention_block(y, layer_params["attn"], cfg, positions,
+                                    pctx)
+    x = x + attn_out
+    y = _norm(x, layer_params["mlp_norm"], cfg)
+    aux = {"moe_aux_loss": jnp.zeros((), jnp.float32)}
+    if "mlp" in layer_params:
+        out = _mlp_block(y, layer_params["mlp"], cfg)
+    elif cfg.moe_dropless:
+        out, aux["moe_load"] = _dropless_block(y, layer_params["moe"], cfg)
+    else:
+        out, aux["moe_aux_loss"] = moe_ops.moe_mlp(
+            y, layer_params["moe"]["router"], layer_params["moe"]["w_gate"],
+            layer_params["moe"]["w_in"], layer_params["moe"]["w_out"],
+            cfg.experts_per_token, cfg.expert_capacity_factor)
+    return x + out, aux
+
+
+def _dropless_block(y, mp: Params, cfg: TransformerConfig):
+    """The dropless expert layer (``ops.moe.moe_dropless``) on y [B, S, H]
+    with one layer's parameters ``mp``: the experts this holder has, cast to
+    y's dtype and handed over as a stack of one layer; the router scores y
+    in float32 with its float32 master weights.  Returns (out, load
+    [experts held])."""
+    routed = ("w_gate", "w_in", "w_out")
+    start = cfg.expert_start
+    if cfg.share_by_position:
+        # the held weights stand for another group of the router's outputs
+        # at each position (config.py): the first expert of a token's group
+        group = (start // cfg.experts_held + jnp.arange(y.shape[1])) \
+            % (cfg.num_experts // cfg.experts_held)
+        start = jnp.tile(group * cfg.experts_held, y.shape[0])
+    out, _, _, load = moe_ops.moe_dropless(
+        y.reshape(-1, y.shape[-1]),
+        {k: v for k, v in mp.items() if k not in routed},
+        # (the barrier keeps the cast in the layer: hoisted out of the scan
+        # it is a second copy of every layer's experts)
+        {k: jax.lax.optimization_barrier(mp[k])[None].astype(y.dtype)
+         for k in routed}, 0,
+        experts_per_token=cfg.experts_per_token,
+        scaling=cfg.routed_scaling_factor, expert_start=start)
+    return out.reshape(y.shape), load
+
+
 def block_forward(x: jnp.ndarray, layer_params: Params, cfg: TransformerConfig,
                   positions: jnp.ndarray,
                   pctx: ParallelContext = ParallelContext()):
     """One transformer block: x [B, S, H] -> (x, moe aux loss).  Shared by the
     layer scan below and the pipeline-parallel stage loop
     (parallel/pipeline.py)."""
-    attn_out = _attention_block(
-        _norm(x, layer_params["attn_norm"], cfg), layer_params["attn"],
-        cfg, positions, pctx)
-    x = x + attn_out
-    y = _norm(x, layer_params["mlp_norm"], cfg)
-    if cfg.num_experts > 1:
-        out, aux = moe_ops.moe_mlp(
-            y, layer_params["moe"]["router"], layer_params["moe"]["w_gate"],
-            layer_params["moe"]["w_in"], layer_params["moe"]["w_out"],
-            cfg.experts_per_token, cfg.expert_capacity_factor)
-    else:
-        out, aux = _mlp_block(y, layer_params["mlp"], cfg), jnp.zeros((), jnp.float32)
-    return x + out, aux
+    x, aux = _block(x, layer_params, cfg, positions, pctx)
+    return x, aux["moe_aux_loss"]
 
 
 def embed_tokens(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
@@ -313,8 +373,7 @@ def embed_tokens(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
 def refuse_layer_pattern(cfg: TransformerConfig, what: str):
     """The training path has no layers of two kinds (the gated-delta-rule
     kernels have no backward and ``apply_trunk`` scans one block kind) and
-    none of what ``cfg.served_only`` names (latent attention, dropless
-    experts, a dense prefix, residual streams)."""
+    none of what ``cfg.served_only`` names (several residual streams)."""
     if cfg.layer_pattern:
         raise NotImplementedError(
             f"{what}: layer_pattern {cfg.layer_pattern} is served "
@@ -322,10 +381,9 @@ def refuse_layer_pattern(cfg: TransformerConfig, what: str):
             "linear-attention kernels have no backward")
     if cfg.served_only:
         raise NotImplementedError(
-            f"{what}: {', '.join(cfg.served_only)} are served "
-            "(models/latent.py, ops/moe.py moe_dropless: prefill, "
-            "decode_step), not trained: the grouped matmul and the latent "
-            "decode kernel have no backward, and apply_trunk wires one "
+            f"{what}: {', '.join(cfg.served_only)} is served "
+            "(models/latent.py hc_*, models/decode.py layer_stack: prefill, "
+            "decode_step), not trained: the train step's block wires one "
             "residual stream")
 
 
@@ -347,7 +405,7 @@ def apply_trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
     positions = jnp.arange(s)
 
     def scan_body(x, layer_params):
-        return block_forward(x, layer_params, cfg, positions, pctx)
+        return _block(x, layer_params, cfg, positions, pctx)
 
     enabled, policy = remat_policy(remat)
     if enabled:
@@ -358,9 +416,13 @@ def apply_trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         # keeping ~1/3 of the no-remat activation footprint.
         scan_body = jax.checkpoint(scan_body, policy=policy)
 
-    x, aux_losses = jax.lax.scan(scan_body, x, params["blocks"])
+    # a dense prefix (models/latent.py's tree) is walked before the scan,
+    # which is then over the expert layers
+    for j in range(cfg.dense_prefix_layers):
+        x, _ = scan_body(x, jax.tree.map(lambda a: a[j], params["prefix"]))
+    x, aux = jax.lax.scan(scan_body, x, params["blocks"])
     x = _norm(x, params["final_norm"], cfg)
-    return x, {"moe_aux_loss": aux_losses.mean()}
+    return x, dict(aux, moe_aux_loss=aux["moe_aux_loss"].mean())
 
 
 def lm_head_weight(params: Params, cfg: TransformerConfig, dtype) -> jnp.ndarray:
@@ -522,5 +584,14 @@ def causal_lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
         loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1)
         denom = mask.sum()
     total = loss + moe_aux_weight * aux["moe_aux_loss"]
-    return total, {"loss": loss, "moe_aux_loss": aux["moe_aux_loss"],
-                   "tokens": denom}
+    metrics = {"loss": loss, "moe_aux_loss": aux["moe_aux_loss"],
+               "tokens": denom}
+    if "moe_load" in aux:
+        # what a trainer logs of dropless expert layers: the assignments
+        # the experts held here computed, summed over the expert layers,
+        # and the most and the fewest any one of them saw in a layer
+        load = aux["moe_load"]
+        metrics.update(moe_assignments_held=load.sum(),
+                       moe_expert_load_max=load.max(),
+                       moe_expert_load_min=load.min())
+    return total, metrics

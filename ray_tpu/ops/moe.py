@@ -10,14 +10,24 @@ at most ``C`` tokens and drops the rest, so a token's output depends on the
 batch it came in, prefill-then-decode cannot match a full forward, and the
 one-hot tensors are ``T x E x C`` floats (8 GB at 8,192 tokens, 64 experts).
 
-``moe_dropless``: the serving layer (no backward).  Sigmoid scores in
-float32, the top k of score + selection bias, gates the chosen scores
-normalised and scaled; the ``T x k`` assignments sorted by expert, one
-grouped matmul over the experts that have a token (``moe_gmm``: a Pallas
-kernel, group sizes by scalar prefetch, an expert with no token neither
-fetched nor computed; its twin ``jax.lax.ragged_dot`` on the CPU), combined
-by the gates, beside a shared expert on every token.  No capacity and no
-dropped token: a token's output does not depend on its batch.  The layer is
+``moe_dropless``: the layer that serves, and that trains without a capacity.
+Sigmoid scores in float32, the top k of score + selection bias, gates the
+chosen scores normalised and scaled; the ``T x k`` assignments sorted by
+expert, one grouped matmul over the experts that have a token (``moe_gmm``:
+a Pallas kernel, group sizes by scalar prefetch, an expert with no token
+neither fetched nor computed; its twin ``jax.lax.ragged_dot`` on the CPU),
+combined by the gates, beside a shared expert on every token.  No capacity
+and no dropped token: a token's output does not depend on its batch.  **The
+backward**: the kernel is a ``custom_vjp``: the rows' gradient is the same
+grouped product over the experts' matrices read transposed where they lie
+(``moe_gmm_dx``), the weights' gradient a grouped ``x^T dy`` per expert,
+summed over the expert's tiles in float32 (``moe_gmm_dw``), which writes
+only the experts that had a token and leaves the rest zero; the twin's
+derivative is ``ragged_dot``'s own.  The gates' gradient reaches the router
+through its float32 scores; the top-k choice and the selection bias carry
+none (no rule here moves the bias).  The sort's gather and the combine's
+gather have gathers for transposes (``_rows_in``, ``_combine``), not the
+scatter-adds autodiff would write.  The layer is
 told which experts it holds (``expert_start`` and the leading dimension of
 the weights it is given) and routes over all of them: assignments to experts
 it does not hold are left out of its part of the result, as they would be
@@ -35,6 +45,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -44,6 +55,11 @@ from .flash_attention import resolve_interpret
 #: [pallas]``); pinned by tests/test_trace_names.py, read by the benchmark's
 #: ``moe_gmm_roofline``
 KERNEL_MOE_GMM = "moe_gmm"
+#: the backward's two kernels: the rows' gradient (the grouped product over
+#: the matrices transposed) and the weights' (``x^T dy`` an expert); read
+#: by the benchmark's ``moe_gmm_train_roofline``
+KERNEL_MOE_GMM_DX = "moe_gmm_dx"
+KERNEL_MOE_GMM_DW = "moe_gmm_dw"
 F32 = jnp.float32
 
 
@@ -130,7 +146,10 @@ def route_sigmoid(x, router_w, bias, k: int, scaling: float):
     (experts [T, k] int32, gates [T, k] float32)."""
     scores = jax.nn.sigmoid(jnp.dot(x.astype(F32), router_w.astype(F32),
                                     precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(scores + bias.astype(F32), k)
+    # the choice is no function to differentiate: the gates' gradient goes
+    # through the chosen scores, none through the bias
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(scores + bias.astype(F32)),
+                           k)
     gates = jnp.take_along_axis(scores, idx, axis=-1)
     gates = gates / (gates.sum(-1, keepdims=True) + 1e-20) * scaling
     return idx.astype(jnp.int32), gates
@@ -197,19 +216,14 @@ def _gmm_pallas(x, weights, layer, tile_expert, tiles, tile: int,
     bn = BLOCK_N if n % BLOCK_N == 0 else n
     num_tiles = rows // tile
 
-    # tiles past the last that holds anything repeat its blocks: nothing
-    # is fetched for them and nothing computed
-    def at(ti, tiles):
-        return jnp.minimum(ti, jnp.maximum(tiles[0] - 1, 0))
-
     def x_map(ni, ti, layer, tile_expert, tiles):
-        return (at(ti, tiles), 0)
+        return (_at(ti, tiles), 0)
 
     def w_map(ni, ti, layer, tile_expert, tiles):
-        return (layer[0], tile_expert[at(ti, tiles)], 0, ni)
+        return (layer[0], tile_expert[_at(ti, tiles)], 0, ni)
 
     def o_map(ni, ti, layer, tile_expert, tiles):
-        return (at(ti, tiles), ni)
+        return (_at(ti, tiles), ni)
 
     return pl.pallas_call(
         functools.partial(_gmm_kernel, gated=len(weights) == 2),
@@ -248,6 +262,184 @@ def _gmm_jnp(x, weights, layer, tile_expert, tile: int):
     return out.astype(x.dtype)
 
 
+def _no_grad(a):
+    """The cotangent of an integer or boolean argument of a ``custom_vjp``."""
+    return np.zeros(jnp.shape(a), jax.dtypes.float0)
+
+
+def _at(ti, tiles):
+    """Tiles past the last that holds anything repeat its blocks: nothing
+    is fetched for them and nothing computed."""
+    return jnp.minimum(ti, jnp.maximum(tiles[0] - 1, 0))
+
+
+def _gmm_dx_kernel(layer_ref, expert_ref, tiles_ref, *refs):
+    del layer_ref, expert_ref
+    o_ref, half = refs[-1], (len(refs) - 1) // 2
+
+    @pl.when(pl.program_id(1) < tiles_ref[0])
+    def _tile():
+        out = None
+        for dy_ref, w_ref in zip(refs[:half], refs[half:-1]):
+            part = jax.lax.dot_general(
+                dy_ref[...], w_ref[0, 0], (((1,), (1,)), ((), ())),
+                preferred_element_type=F32)
+            out = part if out is None else out + part
+        o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _gmm_dx_pallas(dys, weights, layer, tile_expert, tiles, tile: int,
+                   interpret: bool):
+    """The rows' gradient: ``sum_i dys[i] W_i^T`` a tile, the experts'
+    matrices ``[layers, experts, K, N]`` read where they lie, contracted
+    over N.  dys: one or two [rows, N]; returns [rows, K]."""
+    rows, n = dys[0].shape
+    kdim = weights[0].shape[-2]
+    bk = BLOCK_N if kdim % BLOCK_N == 0 else kdim
+
+    def dy_map(ki, ti, layer, tile_expert, tiles):
+        return (_at(ti, tiles), 0)
+
+    def w_map(ki, ti, layer, tile_expert, tiles):
+        return (layer[0], tile_expert[_at(ti, tiles)], ki, 0)
+
+    def o_map(ki, ti, layer, tile_expert, tiles):
+        return (_at(ti, tiles), ki)
+
+    return pl.pallas_call(
+        _gmm_dx_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(kdim // bk, rows // tile),
+            in_specs=[pl.BlockSpec((tile, n), dy_map)] * len(dys)
+            + [pl.BlockSpec((1, 1, bk, n), w_map)] * len(weights),
+            out_specs=pl.BlockSpec((tile, bk), o_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, kdim), dys[0].dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=KERNEL_MOE_GMM_DX,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tile_expert,
+      jnp.reshape(tiles, (1,)).astype(jnp.int32), *dys, *weights)
+
+
+def _gmm_dw_kernel(expert_ref, tiles_ref, x_ref, dy_ref, zero_ref, o_ref):
+    del zero_ref                    # aliased to the output: the zeros
+    ti = pl.program_id(1)
+
+    @pl.when(ti < tiles_ref[0])
+    def _tile():
+        part = jax.lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=F32)
+        first = (ti == 0) | (expert_ref[ti]
+                             != expert_ref[jnp.maximum(ti - 1, 0)])
+
+        @pl.when(first)
+        def _():
+            o_ref[0] = part
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            o_ref[0] += part
+
+    # no tile at all (every assignment went to experts held elsewhere): the
+    # grid still stands on the first tile's block and writes it back
+    @pl.when((ti == 0) & (tiles_ref[0] == 0))
+    def _none():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _gmm_dw_pallas(x, dy, experts: int, tile_expert, tiles, tile: int,
+                   interpret: bool):
+    """The weights' gradient: per expert ``x^T dy`` over its tiles, which
+    are neighbours, so an expert's block stays in VMEM while they are
+    summed, in float32.  x: [rows, K]; dy: [rows, N]; returns [experts, K,
+    N] float32, zero for an expert with no tile: its block is never
+    visited, and the output is a buffer of zeros given in."""
+    rows, kdim = x.shape
+    n = dy.shape[-1]
+    bn = BLOCK_N if n % BLOCK_N == 0 else n
+
+    def x_map(ni, ti, tile_expert, tiles):
+        return (_at(ti, tiles), 0)
+
+    def dy_map(ni, ti, tile_expert, tiles):
+        return (_at(ti, tiles), ni)
+
+    def o_map(ni, ti, tile_expert, tiles):
+        return (tile_expert[_at(ti, tiles)], 0, ni)
+
+    return pl.pallas_call(
+        _gmm_dw_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // bn, rows // tile),
+            in_specs=[pl.BlockSpec((tile, kdim), x_map),
+                      pl.BlockSpec((tile, bn), dy_map),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, kdim, bn), o_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct((experts, kdim, n), F32),
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=KERNEL_MOE_GMM_DW,
+    )(tile_expert, jnp.reshape(tiles, (1,)).astype(jnp.int32), x, dy,
+      jnp.zeros((experts, kdim, n), F32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _gmm_kernel_vjp(x, weights, layer, tile_expert, tiles, tile, interpret):
+    return _gmm_pallas(x, weights, layer, tile_expert, tiles, tile,
+                       interpret)
+
+
+def _gmm_vjp_fwd(x, weights, layer, tile_expert, tiles, tile, interpret):
+    plan = (layer, tile_expert, tiles)
+    if len(weights) == 1:
+        out = _gmm_pallas(x, weights, *plan, tile, interpret)
+        return out, (x, weights, plan, None)
+    # gate and up apart: the backward needs both, and a pass that is
+    # differentiated is a layer's replay, which writes them once
+    gate, up = (_gmm_pallas(x, (w,), *plan, tile, interpret)
+                for w in weights)
+    out = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)).astype(x.dtype)
+    return out, (x, weights, plan, (gate, up))
+
+
+def _gmm_vjp_bwd(tile, interpret, res, dy):
+    x, weights, plan, gated = res
+    layer, tile_expert, tiles = plan
+    if gated is None:
+        dys = (dy,)
+    else:
+        gate, up = (a.astype(F32) for a in gated)
+        sig = jax.nn.sigmoid(gate)
+        d32 = dy.astype(F32)
+        dys = ((d32 * up * sig * (1.0 + gate * (1.0 - sig))).astype(x.dtype),
+               (d32 * gate * sig).astype(x.dtype))
+    dx = _gmm_dx_pallas(dys, weights, layer, tile_expert, tiles, tile,
+                        interpret)
+    # (rows of tiles past ``tiles`` are as undefined as the forward's:
+    # no assignment sits there, and ``_rows_in``'s transpose reads none)
+    experts = weights[0].shape[1]
+    dws = tuple(
+        jax.lax.dynamic_update_index_in_dim(
+            jnp.zeros(w.shape, w.dtype),
+            _gmm_dw_pallas(x, d, experts, tile_expert, tiles, tile,
+                           interpret).astype(w.dtype), layer, 0)
+        for w, d in zip(weights, dys))
+    return dx, dws, _no_grad(layer), _no_grad(tile_expert), _no_grad(tiles)
+
+
+_gmm_kernel_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
+
+
 def moe_gmm(x, weights, layer, tile_expert, tiles, tile: int,
             use_kernel: Optional[bool] = None,
             interpret: Optional[bool] = None):
@@ -260,6 +452,7 @@ def moe_gmm(x, weights, layer, tile_expert, tiles, tile: int,
     int32 scalar index into the stacks, traced or not.  Returns [rows, N] in
     x's dtype; rows of tiles past ``tiles`` are undefined.  Only the weight
     blocks of experts that have a tile are read, where they lie.
+    Differentiable in x and the weights (the module docstring's backward).
 
     ``use_kernel=None`` takes the Pallas kernel on a TPU and the twin
     elsewhere; ``interpret=True`` runs the kernel interpreted (tests)."""
@@ -267,13 +460,83 @@ def moe_gmm(x, weights, layer, tile_expert, tiles, tile: int,
         use_kernel = bool(interpret) or jax.default_backend() == "tpu"
     if not use_kernel:
         return _gmm_jnp(x, weights, layer, tile_expert, tile)
-    return _gmm_pallas(x, weights, layer, tile_expert, tiles, tile,
-                       resolve_interpret(interpret, "moe_gmm"))
+    return _gmm_kernel_vjp(x, tuple(weights), jnp.asarray(layer, jnp.int32),
+                           tile_expert, jnp.asarray(tiles, jnp.int32), tile,
+                           resolve_interpret(interpret, "moe_gmm"))
+
+
+@jax.custom_vjp
+def _rows_in(x, source, dest):
+    """The rows of x [T, H] in the sorted layout: row r is token
+    ``source[r]`` (T: padding, zeros).  Its transpose is a gather too: a
+    token's gradient is the sum of its assignments' rows, at ``dest``."""
+    return jnp.take(x, source, axis=0, mode="fill", fill_value=0)
+
+
+def _rows_in_fwd(x, source, dest):
+    return _rows_in(x, source, dest), (source, dest)
+
+
+def _rows_in_bwd(res, g):
+    source, dest = res
+    # an assignment at a time: [T, k, H] at once is k copies of the tokens
+    dx = sum(jnp.take(g, dest[:, j], axis=0, mode="fill",
+                      fill_value=0).astype(F32)
+             for j in range(dest.shape[1]))
+    return dx.astype(g.dtype), _no_grad(source), _no_grad(dest)
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+def _picked(ys, dest, j):
+    """Assignment j's row of ys for every token [T, H], float32; zeros
+    where it is not held (``dest`` past the end)."""
+    return jnp.take(ys, dest[:, j], axis=0, mode="fill",
+                    fill_value=0).astype(F32)
+
+
+@jax.custom_vjp
+def _combine(ys, gates, mine, source, dest):
+    """``out[t] = sum_j gates[t, j] ys[dest[t, j]]`` in float32 over the
+    assignments that are ``mine``: the experts' rows back at their tokens,
+    weighted.  ys: [rows, H]; gates: [T, k] float32; mine: [T, k] bool;
+    source [rows], dest [T, k]: ``sort_by_expert``'s.  Its transposes are
+    gathers too: a row's gradient is its token's, times the gate of the
+    assignment that sits there; a gate's is its row times its token's."""
+    # an assignment at a time: [T, k, H] at once is k copies of the tokens
+    gates = jnp.where(mine, gates, 0.0)
+    return sum(gates[:, j, None] * _picked(ys, dest, j)
+               for j in range(dest.shape[1]))
+
+
+def _combine_fwd(ys, gates, mine, source, dest):
+    return (_combine(ys, gates, mine, source, dest),
+            (ys, jnp.where(mine, gates, 0.0), mine, source, dest))
+
+
+def _combine_bwd(res, g):
+    ys, gates, mine, source, dest = res
+    flat = dest.reshape(-1)
+    # the gate of the assignment that sits in each row (0: padding)
+    gate_row = jnp.zeros((ys.shape[0],), F32).at[flat].set(
+        gates.reshape(-1), mode="drop")
+    # (the token's gradient rounded to the rows' type before it is spread
+    # over them, as a dense layer's backward rounds it)
+    d_ys = (jnp.take(g.astype(ys.dtype), source, axis=0, mode="fill",
+                     fill_value=0) * gate_row[:, None]).astype(ys.dtype)
+    d_gates = jnp.stack([(_picked(ys, dest, j) * g).sum(-1)
+                         for j in range(dest.shape[1])], axis=1)
+    return (d_ys, jnp.where(mine, d_gates, 0.0), _no_grad(mine),
+            _no_grad(source), _no_grad(dest))
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
                  scaling: float, compute_dtype=None, live=None,
-                 expert_start: int = 0,
+                 expert_start=0,
                  shared: bool = True, use_kernel: Optional[bool] = None,
                  interpret: Optional[bool] = None):
     """The dropless expert layer of one layer of a stack.  x: [T, H];
@@ -281,21 +544,26 @@ def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
     model has one, the shared expert's ``shared_gate`` / ``shared_in`` [H,
     S] and ``shared_out`` [S, H]; ``stacks``: ``w_gate``, ``w_in`` [layers,
     held, H, M] and ``w_out`` [layers, held, M, H], the experts
-    ``expert_start`` to ``expert_start + held`` of all E; live: [T] bool,
-    the tokens that count (a padded position, an idle slot: routed nowhere,
-    their output is the shared expert's alone).  ``shared=False`` leaves
+    ``expert_start`` to ``expert_start + held`` of all E (an int; or [T]
+    int32, a first expert for each token, and the held weights stand for
+    the experts from there on: ``TransformerConfig.share_by_position``);
+    live: [T] bool, the tokens that count (a padded position, an idle slot:
+    routed nowhere, their output is the shared expert's alone).  ``shared=False`` leaves
     the shared expert to another holder of this layer.  The router scores x
     as it comes (float32 where the caller has it); the experts multiply it
     in ``compute_dtype`` (x's own where none is given).
 
     Returns (out [T, H] in x's dtype, counts [2] int32: assignments this
     layer computed, experts it touched; experts [T, k] int32: the router's
-    choice for every token, live or not, among all E)."""
+    choice for every token, live or not, among all E; load [held] int32:
+    the assignments each held expert computed)."""
     t, _ = x.shape
     held = stacks["w_gate"].shape[1]
     with jax.named_scope("moe_route"):
         idx, gates = route_sigmoid(x, small["router"], small["bias"],
                                    experts_per_token, scaling)
+        if jnp.ndim(expert_start):
+            expert_start = expert_start[:, None]
         mine = (idx >= expert_start) & (idx < expert_start + held)
         if live is not None:
             mine = mine & live[:, None]
@@ -304,7 +572,7 @@ def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
         tile = tile_rows(t * experts_per_token, held)
         dest, source, tile_expert, tiles, sizes = sort_by_expert(
             idx - expert_start, mine, held, tile)
-        xs = jnp.take(x, source, axis=0, mode="fill", fill_value=0)
+        xs = _rows_in(x, source, dest)
     with jax.named_scope("moe_experts"):
         gmm = functools.partial(moe_gmm, layer=layer, tile_expert=tile_expert,
                                 tiles=tiles, tile=tile, use_kernel=use_kernel,
@@ -313,13 +581,11 @@ def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
         ys = gmm(act, (stacks["w_out"],))
     with jax.named_scope("moe_combine"):
         # an assignment that is not this layer's reads a row past the end
-        picked = jnp.take(ys, dest, axis=0, mode="fill", fill_value=0)
-        out = jnp.einsum("tkh,tk->th", picked.astype(F32),
-                         jnp.where(mine, gates, 0.0))
+        out = _combine(ys, gates, mine, source, dest)
     if shared and "shared_gate" in small:
         with jax.named_scope("moe_shared"):
             out = out + ((jax.nn.silu(x @ small["shared_gate"].astype(x.dtype))
                           * (x @ small["shared_in"].astype(x.dtype)))
                          @ small["shared_out"].astype(x.dtype)).astype(F32)
     counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
-    return out.astype(x.dtype), counts, idx
+    return out.astype(x.dtype), counts, idx, sizes

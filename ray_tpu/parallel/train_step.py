@@ -122,6 +122,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh,
     the default — is byte-for-byte today's path.
     """
     transformer.refuse_layer_pattern(cfg, "make_train_step")
+    shard_rules.refuse_mesh(cfg, mesh, "make_train_step")
     if grad_quant_enabled or zero_sharded_update:
         from . import zero
         return zero.make_dp_train_step(
@@ -181,7 +182,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh,
     dp = 1
     for ax in ("dp", "fsdp"):
         dp *= mesh.shape.get(ax, 1)
-    n_params = cfg.num_params()
+    n_params = cfg.num_params()      # what this holder has of the model
     step.collective_bytes = (
         {("all_reduce", "float32"): 2 * n_params * 4} if dp > 1 else {})
     step.opt_state_bytes = 2 * n_params * 4 + 8

@@ -143,7 +143,7 @@ class LLMEngine:
                     raise ValueError(
                         f"layer_pattern {cfg.layer_pattern} does not run "
                         f"with {what}")
-        if cfg.served_only:
+        if cfg.latent_tree:
             # latent rows, dropless experts and residual streams
             # (models/latent.py) run on a dense cache of their own kind,
             # unsharded, one token a step
@@ -158,8 +158,12 @@ class LLMEngine:
                               "latent rows, the experts or their kernels")):
                 if on:
                     raise ValueError(
-                        f"{', '.join(cfg.served_only)} do not run with "
+                        f"{', '.join(cfg.latent_tree)} do not run with "
                         f"{what}")
+            if cfg.share_by_position:
+                raise ValueError(
+                    "share_by_position is the train step's: a served "
+                    "share holds the experts from expert_start on")
         self.max_len = max_len or cfg.max_seq_len
         self.num_slots = num_slots
         self.buckets = tuple(b for b in buckets if b <= self.max_len)

@@ -440,7 +440,9 @@ def test_the_engine_refuses_what_a_latent_cache_cannot_do(tiny, kw, match):
 @pytest.mark.parametrize("what", ["make_train_step", "apply_trunk"])
 def test_training_refuses_what_is_only_served(tiny, what):
     cfg, params = tiny
-    with pytest.raises(NotImplementedError, match="no backward"):
+    # (latent attention and dropless experts train since PR 39; the tiny
+    # configuration's four residual streams do not)
+    with pytest.raises(NotImplementedError, match="one residual stream"):
         if what == "apply_trunk":
             transformer.apply_trunk(params, jnp.zeros((1, 8), jnp.int32), cfg)
         else:
@@ -460,7 +462,7 @@ def test_a_window_of_several_tokens_is_refused(tiny):
     (dict(layer_pattern=("linear", "full"), norm_on_output=True,
           linear_num_heads=2, linear_key_dim=8, linear_value_dim=8),
      "layer_pattern"),
-    (dict(q_lora_rank=0), "needs q_lora_rank"),
+    (dict(q_lora_rank=-1), "a q_lora_rank of 0"),
     (dict(num_kv_heads=2), "one key and one value head"),
     (dict(use_qkv_bias=True), "without biases"),
     (dict(kv_lora_rank=0), "rope_yarn_factor"),
@@ -477,8 +479,11 @@ def test_config_refuses_what_the_mechanisms_are_not(tiny, kw, match):
 
 def test_config_names_what_is_only_served(tiny):
     cfg, _ = tiny
-    assert cfg.served_only == ("kv_lora_rank", "moe_dropless",
+    # the tree and the cache of models/latent.py; of these only the
+    # residual streams have no train block (PR 39)
+    assert cfg.latent_tree == ("kv_lora_rank", "moe_dropless",
                                "dense_prefix_layers", "hc_mult")
+    assert cfg.served_only == ("hc_mult",)
     assert TransformerConfig.__dataclass_fields__["hc_mult"].default == 0
     with pytest.raises(AttributeError, match="qk_head_dim"):
         cfg.head_dim

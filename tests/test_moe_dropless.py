@@ -69,7 +69,7 @@ def test_grouped_matmul_equals_the_expert_loop(layer, t, dead, path):
     live = jnp.arange(t) < t - dead
     kw = (dict(interpret=True) if path == "kernel"
           else dict(use_kernel=False))
-    out, counts, chosen = jax.jit(lambda x, l: dropless(
+    out, counts, chosen, _ = jax.jit(lambda x, l: dropless(
         x, small, stacks, l, live=live, **kw))(x, jnp.int32(1))
     np.testing.assert_allclose(out, expert_loop(x, small, stacks, 1, live),
                                atol=5e-6)
@@ -89,7 +89,7 @@ def test_the_shares_add_up_to_the_whole_layer(layer):
     parts, ran = [], 0
     for start, shared in ((0, True), (4, False)):
         held = jax.tree.map(lambda w: w[:, start:start + 4], stacks)
-        out, counts, _ = dropless(x, small, held, 2, expert_start=start,
+        out, counts, *_ = dropless(x, small, held, 2, expert_start=start,
                                   shared=shared, use_kernel=False)
         parts.append(out)
         ran += int(counts[0])

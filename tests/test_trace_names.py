@@ -390,3 +390,55 @@ def test_latent_serve_programs_carry_their_scopes(latent_cfg):
                     {**eng.breakdown(), **eng.counters()})
     finally:
         eng.shutdown()
+
+
+# ---------- latent attention and dropless experts in the train step (PR 39)
+
+def test_the_train_steps_moe_kernels_are_named():
+    """The backward's two kernels beside the forward's, as a device trace
+    shows them (``moe_gmm_dx [pallas]``, ``moe_gmm_dw [pallas]``), which the
+    benchmark's train readers spell out for themselves."""
+    from ray_tpu.ops import moe
+
+    assert (moe.KERNEL_MOE_GMM, moe.KERNEL_MOE_GMM_DX,
+            moe.KERNEL_MOE_GMM_DW) == ("moe_gmm", "moe_gmm_dx", "moe_gmm_dw")
+    shared = _reader("_moe_train.py")
+    assert shared.MOE_GMM_TRAIN == (
+        moe.KERNEL_MOE_GMM, moe.KERNEL_MOE_GMM_DX, moe.KERNEL_MOE_GMM_DW)
+    assert shared.FLASH_TRAIN == ("flash_fwd", "flash_dq", "flash_dkv")
+    x = jnp.ones((32, 64), jnp.float32)
+    w = jnp.ones((1, 4, 64, 32), jnp.float32)
+    grad = jax.make_jaxpr(jax.grad(lambda x, w: moe.moe_gmm(
+        x, (w, w), jnp.int32(0), jnp.zeros((2,), jnp.int32), jnp.int32(2),
+        16, interpret=True).sum(), argnums=(0, 1)))(x, w)
+    for name in shared.MOE_GMM_TRAIN:
+        assert re.search(r"\b" + name + r"\b", str(grad)), name
+
+
+def test_the_train_step_carries_the_latent_and_expert_scopes(latent_cfg):
+    """``jit_train_step`` of a configuration with latent attention, a dense
+    prefix and dropless experts: the same scopes as its serve programs have
+    (no cache write or read), forward and backward."""
+    from ray_tpu.parallel import MeshSpec, make_optimizer, make_train_step
+    from ray_tpu.parallel.train_step import TrainState, state_shardings
+    from ray_tpu.models import transformer
+
+    cfg = dataclasses.replace(latent_cfg, hc_mult=0, q_lora_rank=0,
+                              experts_held=4, expert_start=4)
+    mesh = MeshSpec(fsdp=-1).build(jax.devices()[:1])
+    opt = make_optimizer()
+
+    def init():
+        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+        return TrainState(params=params, opt_state=opt.init(params),
+                          step=jnp.zeros((), jnp.int32))
+
+    shapes = jax.eval_shape(init)
+    sh = state_shardings(cfg, mesh, opt, shapes)
+    step = make_train_step(cfg, mesh, opt, sh, remat="save_acts")
+    tok = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    lowered = step._jitted.lower(shapes, {"tokens": tok, "targets": tok})
+    assert _module_name(lowered) == "jit_train_step"
+    assert {"attn", "mlp", "norm", "loss", "optimizer", "mla_down", "mla_up",
+            "moe_route", "moe_sort", "moe_experts", "moe_shared",
+            "moe_combine", "transpose", "jvp"} <= _scopes(lowered)
