@@ -17,7 +17,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple, Union
+import math
+from typing import Any, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -452,7 +453,8 @@ def apply(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
 
 @jax.named_scope("loss")
 def chunked_cross_entropy(x: jnp.ndarray, w: jnp.ndarray,
-                          targets: jnp.ndarray, chunk: int) -> jnp.ndarray:
+                          targets: jnp.ndarray, chunk: int,
+                          layout: Optional[HeadLayout] = None) -> jnp.ndarray:
     """Blockwise LM-head + softmax cross entropy: peak memory O(B*chunk*V)
     instead of O(B*S*V).
 
@@ -470,6 +472,12 @@ def chunked_cross_entropy(x: jnp.ndarray, w: jnp.ndarray,
     pass ever materializes more than one [B, chunk, V] tile, and the max/sum
     replay the generic remat path did is gone.
 
+    ``layout`` (``head_whole_over_batch``): how the two chunk loops hold
+    the head under a mesh.  ``w`` is an invariant of a loop, and the
+    partitioner gathers a sharded invariant where it is used, in the body,
+    once a chunk; constrained ahead of the loop it is gathered once a pass.
+    What the backward keeps is ``w`` as it came, sharded.
+
     x: [B, S, H] (compute dtype), w: [H, V], targets: [B, S] int. -> nll [B, S] f32.
     """
     s = x.shape[1]
@@ -477,7 +485,38 @@ def chunked_cross_entropy(x: jnp.ndarray, w: jnp.ndarray,
         # Static shapes only — shrink to the largest divisor of s instead of
         # silently materializing the full [B,S,V] logits (the round-1 OOM).
         chunk = next((c for c in range(min(chunk, s), 0, -1) if s % c == 0), s)
-    return _chunked_ce(x, w, targets, chunk)
+    return _chunked_ce(x, w, targets, chunk, layout)
+
+
+class HeadLayout(NamedTuple):
+    """Where the chunked loss's two loops keep the head under a mesh whose
+    batch axes split the tokens (``head_whole_over_batch``)."""
+    w: Any        # sharding of the [H, V] head a loop multiplies by
+    dw: Any       # sharding of [shards, H, V]: each batch shard's own sum
+    shards: int   # of the chunks' gradients of the head
+
+
+def head_whole_over_batch(cfg: TransformerConfig,
+                          pctx: ParallelContext) -> Optional[HeadLayout]:
+    """The [H, V] head as the chunked loss's loops want it under ``pctx``:
+    whole over the batch axes, which split the tokens it multiplies, and
+    split as ``models/sharding.py`` has it over ``tp`` (the vocabulary of a
+    separate head, the hidden size of a tied one, whose [V, H] table is read
+    transposed).  Its gradient is summed over the chunks where the tokens
+    are, a sum a batch shard, and meets the other shards' once, after the
+    loop.  None where nothing is to gather: no mesh, no batch axis above
+    one, or a caller inside its own ``shard_map``."""
+    mesh = pctx.mesh
+    if mesh is None or pctx.manual_collectives:
+        return None
+    batch = tuple(a for a in pctx.batch_axes if mesh.shape.get(a, 1) > 1)
+    if not batch:
+        return None
+    tp = "tp" if "tp" in mesh.axis_names else None
+    spec = (tp, None) if cfg.tied_embeddings else (None, tp)
+    P, named = jax.sharding.PartitionSpec, jax.sharding.NamedSharding
+    return HeadLayout(w=named(mesh, P(*spec)), dw=named(mesh, P(batch, *spec)),
+                      shards=math.prod(mesh.shape[a] for a in batch))
 
 
 def _ce_chunks(x, targets, chunk):
@@ -488,14 +527,21 @@ def _ce_chunks(x, targets, chunk):
     return xs, ts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _chunked_ce(x, w, targets, chunk):
-    return _chunked_ce_fwd(x, w, targets, chunk)[0]
+def _loop_head(w, layout):
+    return w if layout is None else jax.lax.with_sharding_constraint(
+        w, layout.w)
 
 
-def _chunked_ce_fwd(x, w, targets, chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _chunked_ce(x, w, targets, chunk, layout):
+    return _chunked_ce_fwd(x, w, targets, chunk, layout)[0]
+
+
+def _chunked_ce_fwd(x, w, targets, chunk, layout):
     b, s, _ = x.shape
     xs, ts = _ce_chunks(x, targets, chunk)
+    res = (x, w, targets)
+    w = _loop_head(w, layout)
 
     def body(carry, xt):
         xc, tc = xt
@@ -508,16 +554,25 @@ def _chunked_ce_fwd(x, w, targets, chunk):
     _, (lses, nll) = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ts))
     nll = nll.swapaxes(0, 1).reshape(b, s)
     lse = lses.swapaxes(0, 1).reshape(b, s)
-    return nll, (x, w, targets, lse)
+    return nll, res + (lse,)
 
 
-def _chunked_ce_bwd(chunk, res, g):
+def _chunked_ce_bwd(chunk, layout, res, g):
     x, w, targets, lse = res
+    w_dtype, w = w.dtype, _loop_head(w, layout)
     b, s, h = x.shape
     v = w.shape[1]
     xs, ts = _ce_chunks(x, targets, chunk)
     gs = g.reshape(b, s // chunk, chunk).swapaxes(0, 1)     # [n, B, C] f32
     ls = lse.reshape(b, s // chunk, chunk).swapaxes(0, 1)
+
+    # Under a layout the batch is read as [shards, B / shards]: a chunk's dw
+    # summed within a shard needs no other chip, so the shards' float32 sums
+    # meet once, after the loop, and not once a chunk.
+    split = layout is not None and b % layout.shards == 0
+
+    def by_shard(a):
+        return a.reshape(layout.shards, -1, *a.shape[1:]) if split else a
 
     def body(dw, xt):
         xc, tc, gc, lc = xt
@@ -528,18 +583,23 @@ def _chunked_ce_bwd(chunk, res, g):
                   == tc[..., None])
         dlog = ((p - onehot) * gc[..., None]).astype(x.dtype)
         dxc = jnp.einsum("bcv,hv->bch", dlog, w)
-        dw_c = jnp.einsum("bch,bcv->hv", xc, dlog,
+        dw_c = jnp.einsum("...bch,...bcv->...hv", by_shard(xc), by_shard(dlog),
                           preferred_element_type=jnp.float32)
         return dw + dw_c, dxc
 
-    dw, dxs = jax.lax.scan(body, jnp.zeros((h, v), jnp.float32), (xs, ts, gs, ls))
+    dw0 = jnp.zeros((h, v), jnp.float32)
+    if split:
+        dw0 = jax.lax.with_sharding_constraint(
+            jnp.zeros((layout.shards, h, v), jnp.float32), layout.dw)
+    dw, dxs = jax.lax.scan(body, dw0, (xs, ts, gs, ls))
+    if split:
+        dw = dw.sum(0)
     dx = dxs.swapaxes(0, 1).reshape(b, s, h)
     dt = np.zeros(targets.shape, jax.dtypes.float0)
-    return dx, dw.astype(w.dtype), dt
+    return dx, dw.astype(w_dtype), dt
 
 
-_chunked_ce.defvjp(lambda x, w, t, chunk: _chunked_ce_fwd(x, w, t, chunk),
-                   _chunked_ce_bwd)
+_chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
 
 
 def causal_lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
@@ -569,7 +629,8 @@ def causal_lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
     x, aux = apply_trunk(params, tokens, cfg, pctx, compute_dtype, remat=remat)
     if loss_chunk:
         w = lm_head_weight(params, cfg, x.dtype)
-        nll = chunked_cross_entropy(x, w, targets, min(loss_chunk, s))
+        nll = chunked_cross_entropy(x, w, targets, min(loss_chunk, s),
+                                    head_whole_over_batch(cfg, pctx))
     else:
         logits = lm_head_logits(params, x, cfg)
         with jax.named_scope("loss"):

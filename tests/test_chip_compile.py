@@ -588,6 +588,104 @@ def test_whole_row_programs_are_the_parents(one_chip, as_tpu, model,
 
 # ------------------------------------------------- the sharded train step
 
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+_GATHER = re.compile(
+    r"= (\S+) all-gather(?:-start)?\(.*?channel_id=(\d+)")
+
+
+def _computations(text):
+    """The compiled module's computations: name -> instruction lines."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line.strip())
+    return comps
+
+
+def _gathers(lines):
+    """(dimensions of the result, channel) of each all-gather in ``lines``;
+    an asynchronous one's fusions repeat the instruction under one channel."""
+    return [(tuple(int(d) for d in re.findall(
+        r"\[([\d,]*)\]", m.group(1))[-1].split(",") if d), m.group(2))
+        for m in map(_GATHER.search, lines) if m]
+
+
+def _loop_gathers(text):
+    """The all-gathers that run once a trip: those of every computation
+    that is a ``while`` body, and of what it calls."""
+    comps = _computations(text)
+    seen, todo = set(), re.findall(r"\bwhile\([^\n]*?body=%?([\w.\-]+)", text)
+    assert todo, "no loop in the step"
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += [c for ln in comps[name] for c in _CALLED.findall(ln)]
+    return _gathers([ln for name in seen for ln in comps[name]])
+
+
+def _assert_head_gathered_once_a_pass(text, cfg, batch, chunk=512):
+    """PR 41: the chunked loss's two loops read the head whole over the
+    batch axes.  Closed over sharded it was gathered in both bodies, once a
+    chunk, and the backward's body gathered the chunk's ``dlog`` over the
+    batch besides: sixteen synchronous passes of the head a step."""
+    h, v = cfg.hidden_size, cfg.vocab_size
+    head = ((h, v), (v, h))
+    once_a_trip = [dims for dims, _ in _loop_gathers(text)
+                   if dims in head + ((batch, chunk, v),)]
+    assert not once_a_trip, once_a_trip
+    whole = {ch for dims, ch in _gathers(text.splitlines()) if dims in head}
+    assert len(whole) <= 2, whole
+
+
+def _train_cell(config, model_file):
+    """A train cell's configuration file, its block kind and the program's
+    configuration."""
+    import json
+    import os
+
+    from benchmark.lib.manifest import load_model
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    doc = json.load(open(os.path.join(bench, "configs", config + ".json")))
+    kind = load_model(os.path.join(bench, "models", model_file))
+    return doc, kind, kind.program_config(doc)
+
+
+def _compiled_train_step(devices, cfg, init_params, train):
+    """``make_train_step`` over ``devices`` of the described chips, compiled
+    at ``train``'s sizes (the keys of a configuration file's ``train``)."""
+    from ray_tpu.parallel import MeshSpec, make_optimizer, make_train_step
+    from ray_tpu.parallel.train_step import TrainState, state_shardings
+
+    mesh = MeshSpec(**train["mesh"]).build(devices)
+    assert isinstance(mesh, Mesh) and mesh.size == len(devices)
+    opt = make_optimizer(**train["optimizer"])
+
+    def init(key):
+        params = init_params(key, cfg, jnp.float32)
+        return TrainState(params=params, opt_state=opt.init(params),
+                          step=jnp.zeros((), jnp.int32))
+
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
+    sh = state_shardings(cfg, mesh, opt, shapes)
+    step = make_train_step(cfg, mesh, opt, sh, remat=train["remat"])
+    tok = jax.ShapeDtypeStruct(
+        (train["global_batch"], train["sequence_length"]), jnp.int32,
+        sharding=step.batch_sharding)
+    compiled = step._jitted.lower(
+        _on(sh, shapes), {"tokens": tok, "targets": tok}).compile()
+    return compiled, shapes, sh
+
+
 @pytest.mark.parametrize("impl", ["auto", "splash"])
 def test_sharded_train_step_compiles(topo, as_tpu, impl):
     """llama-1b widths, depth cut to 2 layers, ``MeshSpec(fsdp=-1)`` over the
@@ -597,28 +695,13 @@ def test_sharded_train_step_compiles(topo, as_tpu, impl):
     ``"splash"`` takes the same wrap."""
     import dataclasses
 
-    from ray_tpu.parallel import MeshSpec, make_optimizer, make_train_step
-    from ray_tpu.parallel.train_step import TrainState, state_shardings
-
     assert mcfg.llama_1b().attention_impl == "auto"
     cfg = dataclasses.replace(mcfg.llama_1b(), num_layers=2,
                               attention_impl=impl)
-    mesh = MeshSpec(fsdp=-1).build(topo.devices)
-    assert isinstance(mesh, Mesh) and mesh.size == 4
-    opt = make_optimizer()
-
-    def init():
-        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-        return TrainState(params=params, opt_state=opt.init(params),
-                          step=jnp.zeros((), jnp.int32))
-
-    shapes = jax.eval_shape(init)
-    sh = state_shardings(cfg, mesh, opt, shapes)
-    step = make_train_step(cfg, mesh, opt, sh, remat="save_acts")
-    tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32,
-                               sharding=step.batch_sharding)
-    compiled = step._jitted.lower(
-        _on(sh, shapes), {"tokens": tok, "targets": tok}).compile()
+    compiled, shapes, sh = _compiled_train_step(
+        topo.devices, cfg, transformer.init_params,
+        dict(mesh={"fsdp": -1}, optimizer={}, remat="save_acts",
+             global_batch=8, sequence_length=2048))
     text = compiled.as_text()
     assert text.count(KERNEL) >= 3, "no attention kernel in the sharded step"
     for collective in ("all-gather", "reduce-scatter"):
@@ -630,24 +713,30 @@ def test_sharded_train_step_compiles(topo, as_tpu, impl):
     total = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                 for x in jax.tree.leaves(shapes))
     assert per_device < 0.3 * total + 1e6
+    _assert_head_gathered_once_a_pass(text, cfg, batch=8)
+
+
+def test_fsdp_cell_step_gathers_the_head_once_a_pass(topo, as_tpu):
+    """``train-fsdp4-s4096``'s own step: its configuration file, 8 layers,
+    4 x 4,096 tokens over the four chips."""
+    doc, kind, cfg = _train_cell("mistral-7b-v0.3-train-l8", "mistral.py")
+    compiled, _, _ = _compiled_train_step(topo.devices, cfg, kind.init_params,
+                                          doc["train"])
+    _assert_head_gathered_once_a_pass(
+        compiled.as_text(), cfg, batch=doc["train"]["global_batch"])
+    # 13.21e9 at the program's peak, arguments included (12.81e9 at the
+    # parent; sandbox compile, PR 41): each chip's own float32 sum of the
+    # head's gradient, 0.40e9 more than a quarter of it.  Arguments +
+    # temporaries, the sum ``benchmark/runners/train.py`` reports, read
+    # 16.29e9 (14.78e9): 0.80e9 for that 0.40e9, and no measure of what a
+    # chip of 16.91e9 holds (the share cell's step below reads 19.9e9 so,
+    # and runs).
+    mem = compiled.memory_analysis()
+    assert mem.peak_memory_in_bytes < 13.5e9, mem.peak_memory_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
 
 
 # ------------- latent attention + dropless experts trained on one chip (PR 39)
-
-def _kimi_cell():
-    """The cell's configuration file, its block kind and the program's
-    configuration."""
-    import json
-    import os
-
-    from benchmark.lib.manifest import load_model
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    doc = json.load(open(os.path.join(
-        bench, "configs", "kimi-vl-a3b-train-l6-e8.json")))
-    kind = load_model(os.path.join(bench, "models", "kimi_vl.py"))
-    return doc, kind, kind.program_config(doc)
-
 
 def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
         topo, as_tpu):
@@ -658,36 +747,22 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
     ISSUE 39 set.  The kernel calls in the step are the ones the block kind
     counts FLOPs for (``moe_gmm_train_calls``, ``mla_flash_train_calls``): a
     roofline share must not credit a pass the program does not run."""
-    from ray_tpu.parallel import MeshSpec, make_optimizer, make_train_step
-    from ray_tpu.parallel.train_step import TrainState, state_shardings
-
-    doc, kind, cfg = _kimi_cell()
-    tr = doc["train"]
-    mesh = MeshSpec(**tr["mesh"]).build(topo.devices[:1])
-    opt = make_optimizer(**tr["optimizer"])
-
-    def init(key):
-        params = kind.init_params(key, cfg, jnp.float32)
-        return TrainState(params=params, opt_state=opt.init(params),
-                          step=jnp.zeros((), jnp.int32))
-
-    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
-    sh = state_shardings(cfg, mesh, opt, shapes)
-    step = make_train_step(cfg, mesh, opt, sh, remat=tr["remat"])
-    tok = jax.ShapeDtypeStruct(
-        (tr["global_batch"], tr["sequence_length"]), jnp.int32,
-        sharding=step.batch_sharding)
-    compiled = step._jitted.lower(
-        _on(sh, shapes), {"tokens": tok, "targets": tok}).compile()
+    doc, kind, cfg = _train_cell("kimi-vl-a3b-train-l6-e8", "kimi_vl.py")
+    compiled, _, _ = _compiled_train_step(topo.devices[:1], cfg,
+                                          kind.init_params, doc["train"])
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(
         12 * kind.num_params(doc), rel=1e-3)
     assert mem.alias_size_in_bytes > 0.999 * mem.output_size_in_bytes
     assert mem.peak_memory_in_bytes < 15.0 * 2**30, mem.peak_memory_in_bytes
+    # a mesh of one device: nothing to gather, the program PR 39 left
+    assert mem.temp_size_in_bytes == 11_878_587_904
     # the dense layer's pass is unrolled, the expert layers' a scan's body:
     # a kernel's calls in the text are its calls a layer, forward plus
     # backward, once for each
     text = compiled.as_text()
+    assert not re.findall(r" (?:%s)(?:-start)?\(" % "|".join(COLLECTIVES),
+                          text)
     calls = {name: len(re.findall(
         "%" + name + r"(?:\.\d+)? = [^\n]*" + KERNEL, text)) for name in (
             "moe_gmm", "moe_gmm_dx", "moe_gmm_dw", "flash_fwd", "flash_dq",

@@ -166,3 +166,110 @@ def test_param_count_estimates():
     from ray_tpu.models.config import gpt2_small, llama3_8b
     assert abs(gpt2_small().num_params() - 124e6) / 124e6 < 0.1
     assert abs(llama3_8b().num_params() - 8.0e9) / 8.0e9 < 0.1
+
+
+# ------------------------- the loss under a mesh against one device (PR 41)
+
+LOSS_MESHES = {"fsdp4": dict(fsdp=4), "dp2-fsdp2": dict(dp=2, fsdp=2),
+               "fsdp2-tp2": dict(fsdp=2, tp=2)}
+
+
+def _loss_case(tied):
+    cfg = TransformerConfig(**{**tiny().__dict__, "tied_embeddings": tied})
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0,
+                              cfg.vocab_size)
+    return cfg, params, {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
+
+
+def _loss_and_grads(cfg, pctx, loss_chunk):
+    def fn(params, batch):
+        (_, metrics), grads = jax.value_and_grad(causal_lm_loss, has_aux=True)(
+            params, batch, cfg, pctx, compute_dtype=jnp.float32,
+            loss_chunk=loss_chunk)
+        return metrics["loss"], grads
+    return fn
+
+
+def _assert_same_loss_and_grads(got, want):
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    flat, _ = jax.tree_util.tree_flatten_with_path(want[1])
+    for (path, w), g in zip(flat, jax.tree.leaves(got[1])):
+        np.testing.assert_allclose(
+            g, w, rtol=2e-4, atol=1e-6, err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("loss_chunk", [8, None], ids=["chunked", "whole"])
+@pytest.mark.parametrize("tied", [False, True], ids=["head", "tied"])
+@pytest.mark.parametrize("axes", list(LOSS_MESHES))
+def test_loss_and_gradients_under_a_mesh_match_one_device(axes, tied,
+                                                          loss_chunk):
+    """``causal_lm_loss`` with its parameters and batch laid out as
+    ``make_train_step`` lays them out: the loss and every gradient leaf are
+    the one-device ones, whether the head reaches the chunk loops gathered
+    over the batch axes (``head_whole_over_batch``) or the logits are whole."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import sharding as shard_rules
+    from ray_tpu.models.transformer import head_whole_over_batch
+    from ray_tpu.parallel.mesh import named_sharding
+
+    cfg, params, batch = _loss_case(tied)
+    want = jax.jit(_loss_and_grads(cfg, ParallelContext(), loss_chunk))(
+        params, batch)
+
+    mesh = make_mesh(4, **LOSS_MESHES[axes])
+    pctx = ParallelContext(mesh=mesh, batch_axes=shard_rules.BATCH_AXES)
+    whole = head_whole_over_batch(cfg, pctx)
+    tp = "tp" if mesh.shape["tp"] > 1 else None
+    spec = (tp, None) if tied else (None, tp)
+    assert whole.w.is_equivalent_to(NamedSharding(mesh, P(*spec)), 2)
+    assert whole.dw.is_equivalent_to(NamedSharding(
+        mesh, P(shard_rules.BATCH_AXES, *spec)), 3)
+    assert whole.shards == 4 // mesh.shape["tp"]
+    param_sh = named_sharding(mesh, shard_rules.logical_param_specs(cfg))
+    batch_sh = NamedSharding(mesh, shard_rules.batch_spec())
+    got = jax.jit(_loss_and_grads(cfg, pctx, loss_chunk),
+                  in_shardings=(param_sh, batch_sh),
+                  out_shardings=(None, param_sh))(
+        jax.device_put(params, param_sh), jax.device_put(batch, batch_sh))
+    _assert_same_loss_and_grads(got, want)
+
+
+def test_head_stays_as_it_is_where_nothing_is_to_gather():
+    cfg = tiny()
+    from ray_tpu.models import sharding as shard_rules
+    from ray_tpu.models.transformer import head_whole_over_batch
+    four = make_mesh(4, fsdp=4)
+    for pctx in (ParallelContext(),
+                 ParallelContext(mesh=make_mesh(1, fsdp=1),
+                                 batch_axes=shard_rules.BATCH_AXES),
+                 ParallelContext(mesh=make_mesh(4, fsdp=1, tp=4),
+                                 batch_axes=shard_rules.BATCH_AXES),
+                 ParallelContext(mesh=four, manual_collectives=True,
+                                 batch_axes=shard_rules.BATCH_AXES)):
+        assert head_whole_over_batch(cfg, pctx) is None
+    assert head_whole_over_batch(cfg, ParallelContext(
+        mesh=four, batch_axes=shard_rules.BATCH_AXES)) is not None
+
+
+def test_chunked_loss_traces_inside_a_shard_map_under_manual_collectives():
+    """The pipeline calls the loss inside its own ``shard_map`` with
+    ``manual_collectives``: no constraint on the head there (a
+    ``NamedSharding`` of the whole mesh names axes that are manual), and
+    the shards' mean is the one-device loss."""
+    from jax.sharding import PartitionSpec as P
+
+    cfg, params, batch = _loss_case(False)
+    want = jax.jit(_loss_and_grads(cfg, ParallelContext(), 8))(params, batch)
+    mesh = make_mesh(4, dp=4, fsdp=1)
+    local = _loss_and_grads(
+        cfg, ParallelContext(mesh=mesh, manual_collectives=True), 8)
+
+    def body(params, batch):
+        return jax.lax.pmean(local(params, batch), "dp")
+
+    got = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P(), P("dp")), out_specs=P(),
+        check_vma=False))(params, batch)        # as parallel/pipeline.py
+    _assert_same_loss_and_grads(got, want)
