@@ -48,8 +48,9 @@ _FLUSH = object()
 
 class GenRequest:
     __slots__ = ("tokens", "max_tokens", "temperature", "top_k", "eos_id",
-                 "out", "slot", "generated", "submitted_at", "admitted_at",
-                 "emit_times", "pages", "prompt_len", "cache_len",
+                 "out", "slot", "generated", "submitted_at", "seen_at",
+                 "admitted_at", "emit_times", "held_by", "prefill_attrs",
+                 "pages", "prompt_len", "cache_len",
                  "deployment", "trace_ctx", "span_parent")
 
     def __init__(self, tokens: List[int], max_tokens: int,
@@ -68,13 +69,22 @@ class GenRequest:
         #: dispatched program has run: the prompt, then one a decode step
         self.cache_len = len(tokens)
         # one monotonic stamp per stage: submit (the caller's thread), the
-        # dispatch of the admit that carries the request, and every token's
+        # first look of the engine's loop that found the request pending,
+        # the dispatch of the admit that carries it, and every token's
         # _emit (engine thread; emit_times[0] is the first token's).  The
         # stage counters difference them; the task-event spans get their
         # wall time from the engine's one offset (LLMEngine._wall).
         self.submitted_at = time.monotonic()
+        self.seen_at: Optional[float] = None
         self.admitted_at: Optional[float] = None
         self.emit_times: List[float] = []
+        #: why the last look that saw the request left it pending: "bucket"
+        #: (the admit took another bucket), "batch" (prefill_batch was
+        #: full), "slot" (no free slot), "pages" (the page arena was full)
+        self.held_by: Optional[str] = None
+        #: what stood between the admit's dispatch and the first token, for
+        #: the prefill span (LLMEngine._program_done)
+        self.prefill_attrs: Dict[str, Any] = {}
         # observability: who/what this request belongs to (the replica's
         # deployment tag + the caller's trace context, captured at submit
         # on the caller's thread)
@@ -87,7 +97,7 @@ class GenRequest:
 class _Phase:
     """One interval of one phase of the engine thread: a host span in a
     profiler capture and the phase's cumulative seconds and count."""
-    __slots__ = ("eng", "name", "span", "t0")
+    __slots__ = ("eng", "name", "span", "t0", "t1")
 
     def __init__(self, eng: "LLMEngine", name: str, **args):
         self.eng, self.name = eng, name
@@ -100,12 +110,35 @@ class _Phase:
         return self.span
 
     def __exit__(self, *exc):
-        now = time.monotonic()
+        self.t1 = now = time.monotonic()
         self.span.__exit__(*exc)
         eng = self.eng
         eng._phase_open = None
         eng.loop_s[self.name] += now - self.t0
         eng.loop_n[self.name] += 1
+
+
+class _Program:
+    """One program the engine thread has dispatched and not yet fetched."""
+    __slots__ = ("kind", "seq", "out", "snapshot", "dispatched", "streams",
+                 "rows", "chunks", "k")
+
+    def __init__(self, kind: str, out: tuple, dispatched: float,
+                 snapshot=None, rows=(), chunks: int = 0, k: int = 0):
+        #: "admit", "decode" or "spec"
+        self.kind = kind
+        #: device arrays the fetch reads back
+        self.out = out
+        #: host stamp taken once the dispatching call had returned
+        self.dispatched = dispatched
+        #: a decode or speculative dispatch's {slot: request} as it saw them
+        self.snapshot = snapshot
+        #: an admit's ``(request, positions walked)`` in row order and the
+        #: chunks they made up; a speculative dispatch's window
+        self.rows, self.chunks, self.k = rows, chunks, k
+        #: ordinal since the engine started and the streams live at the
+        #: dispatch (LLMEngine._in_flight)
+        self.seq = self.streams = 0
 
 
 class LLMEngine:
@@ -307,19 +340,18 @@ class LLMEngine:
         self._pending: "queue.Queue[GenRequest]" = queue.Queue()
         self._active: Dict[int, GenRequest] = {}
         self._free_slots = list(range(num_slots))
-        # dispatched-but-unfetched steps: (tokens_dev, {slot: req} snapshot)
-        self._unfetched: List[tuple] = []
+        # dispatched-but-unfetched programs, oldest first
+        self._unfetched: List[_Program] = []
         self._stop = False
         self._wake = threading.Event()
         # steady-state metrics
         self.steps = 0
         self.tokens_out = 0
         # admission accounting, of what the chip walked (bench_llm reads
-        # these): rows that held a request and rows that held none, which
-        # the prefill program skips (always 0)
+        # these): admits and the rows that held a request (the prefill
+        # program walks no other)
         self.admit_batches = 0
         self.admit_rows_real = 0
-        self.admit_rows_padded = 0
         # the same per token: prompt tokens prefilled, and every other
         # position the program walked (a row is rounded up to its bucket,
         # or to whole chunks where the program walks it in chunks:
@@ -351,6 +383,33 @@ class LLMEngine:
         self.queue_wait_s = 0.0
         self.first_tokens = 0
         self.first_token_wait_s = 0.0
+        # the queue wait by cause: submit -> the first look of _loop that
+        # found the request pending, that look -> the look that admitted it
+        # (looks that saw it and left it: GenRequest.held_by says why);
+        # what is left of queue_wait_s is the host building and dispatching
+        # the admit
+        self.queue_look_s = 0.0
+        self.queue_held_s = 0.0
+        # the first-token wait by cause (_program_done): the admit's
+        # dispatch -> its start on the chip (the programs in flight ahead
+        # of it), then the admit's run split by the positions walked for
+        # the request's own row over the whole admit's; what is left of
+        # first_token_wait_s is the fetch's return to _emit
+        self.first_token_ahead_s = 0.0
+        self.first_token_own_row_s = 0.0
+        self.first_token_other_rows_s = 0.0
+        # what holds a stream between its tokens: every program's run
+        # times the streams live at its dispatch (requests with a first
+        # token, not retired), and the same for the admits alone
+        self.stream_s = 0.0
+        self.stream_admit_s = 0.0
+        # the look of the pass under way, programs dispatched since the
+        # engine started, and the return of the last blocking fetch: the
+        # engine is the chip's only submitter, so a program starts at the
+        # later of its dispatch and the end of the one before it
+        self._look_at = 0.0
+        self._seq = 0
+        self._done_at = 0.0
         # where the engine thread's time goes (_Phase): cumulative seconds
         # and intervals per phase, the interval open now, passes of _loop
         self.loop_s = dict.fromkeys(ENGINE_PHASES, 0.0)
@@ -420,12 +479,10 @@ class LLMEngine:
         """Serving-picture rollup (bench_llm records this next to the
         per-request percentiles): admission batch occupancy + padding
         waste, KV page utilization, prefix-cache hit rate."""
-        rows = self.admit_rows_real + self.admit_rows_padded
         out = {
             "admit_batches": self.admit_batches,
-            "batch_occupancy": (self.admit_rows_real / rows) if rows else 0.0,
-            "padding_fraction": (self.admit_rows_padded / rows) if rows
-            else 0.0,
+            # of the rows the chip walked, those that held a request: all
+            "batch_occupancy": 1.0 if self.admit_rows_real else 0.0,
             "active_slots": len(self._active),
             "num_slots": self.num_slots,
             **self._cache_gauges,
@@ -477,6 +534,13 @@ class LLMEngine:
             "queue_wait_s": self.queue_wait_s,
             "first_tokens": self.first_tokens,
             "first_token_wait_s": self.first_token_wait_s,
+            "queue_look_s": self.queue_look_s,
+            "queue_held_s": self.queue_held_s,
+            "first_token_ahead_s": self.first_token_ahead_s,
+            "first_token_own_row_s": self.first_token_own_row_s,
+            "first_token_other_rows_s": self.first_token_other_rows_s,
+            "stream_s": self.stream_s,
+            "stream_admit_s": self.stream_admit_s,
             "admit_tokens_real": self.admit_tokens_real,
             "admit_tokens_padded": self.admit_tokens_padded,
             "admit_chunks": self.admit_chunks,
@@ -562,50 +626,61 @@ class LLMEngine:
     # ----------------------------------------------------- observability
 
     def _admit_walk(self, reqs: List[GenRequest], bucket: int):
-        """(chunks, positions) the admit program walks for ``reqs`` at
+        """(chunks, positions a row) the admit program walks for ``reqs`` at
         ``bucket``: whole rows and no chunks, or the chunks each prompt
         fills (``decode.prefill_width``; only a dense tree is walked in
         chunks, and its rows are whole prompts)."""
         width = self._dec.prefill_width(self.cache, bucket)
         if width == bucket:
-            return 0, len(reqs) * bucket
-        chunks = sum(-(-len(r.tokens) // width) for r in reqs)
-        return chunks, chunks * width
+            return 0, [bucket] * len(reqs)
+        chunks = [-(-len(r.tokens) // width) for r in reqs]
+        return sum(chunks), [c * width for c in chunks]
 
-    def _obs_admit(self, reqs: List[GenRequest], bucket: int,
-                   tokens_real: int):
+    def _obs_admit(self, prog: _Program, tokens_real: int):
         """One admit batch, just dispatched: padding accounting (rows and
-        tokens), queue wait per request; then, behind one enabled() check,
-        occupancy + queue-wait metrics, batch_wait span per request
-        (chained under the request's trace), KV/slot gauges.  Engine-thread
-        side; every metric call is a precomputed-key observe."""
-        now = time.monotonic()
+        tokens), queue wait per request and its two causes; then, behind
+        one enabled() check, occupancy + queue-wait metrics, batch_wait
+        span per request (chained under the request's trace), KV/slot
+        gauges.  Engine-thread side; every metric call is a
+        precomputed-key observe."""
+        now, look = prog.dispatched, self._look_at
+        reqs = [r for r, _n in prog.rows]
         self.admit_batches += 1
         self.admit_rows_real += len(reqs)
         self.admit_tokens_real += tokens_real
-        chunks, walked = self._admit_walk(reqs, bucket)
-        self.admit_chunks += chunks
-        self.admit_rows_chunked += len(reqs) if chunks else 0
-        self.admit_tokens_padded += walked - tokens_real
+        self.admit_chunks += prog.chunks
+        self.admit_rows_chunked += len(reqs) if prog.chunks else 0
+        self.admit_tokens_padded += sum(n for _r, n in prog.rows) - tokens_real
         self.moe_assignments_prefill += (
             tokens_real * self.cfg.experts_per_token * self.cfg.expert_layers)
         self.admitted_requests += len(reqs)
+        causes = []
         for r in reqs:
             r.admitted_at = now
             self.queue_wait_s += now - r.submitted_at
+            if r.seen_at is None:
+                # submitted after this pass's look and taken all the same:
+                # no look made it wait
+                r.seen_at = r.submitted_at
+            look_s = r.seen_at - r.submitted_at
+            held_s = max(0.0, look - r.seen_at)
+            self.queue_look_s += look_s
+            self.queue_held_s += held_s
+            causes.append((look_s, held_s))
         if not obs.enabled():
             return
         dep = self._obs_dep
         obs.record_batch(dep, len(reqs), self.prefill_batch,
                          waits_s=[now - r.submitted_at for r in reqs])
         self._obs_gauges()
-        for r in reqs:
+        for r, (look_s, held_s) in zip(reqs, causes):
             r.span_parent = obs.stamp_span(
                 "batch_wait", self._wall + r.submitted_at,
                 now - r.submitted_at,
                 trace_id=r.trace_ctx[0] if r.trace_ctx else None,
                 parent_id=r.trace_ctx[1] if r.trace_ctx else None,
-                deployment=r.deployment)
+                deployment=r.deployment, look_s=look_s, held_s=held_s,
+                held_by=r.held_by)
 
     def _obs_first_token(self, r: GenRequest, now: float):
         """One request's first token is being emitted (``now``): the stage
@@ -622,7 +697,8 @@ class LLMEngine:
             "prefill", self._wall + r.admitted_at, now - r.admitted_at,
             trace_id=r.trace_ctx[0] if r.trace_ctx else None,
             parent_id=r.span_parent,
-            deployment=r.deployment, prompt_len=r.prompt_len)
+            deployment=r.deployment, prompt_len=r.prompt_len,
+            **r.prefill_attrs)
 
     def _obs_retire(self, r: GenRequest):
         """Generation done: decode span (first token -> last), TPOT, token
@@ -716,6 +792,7 @@ class LLMEngine:
             self._draft_cache = self._draft_prefill_fn(bucket)(
                 self._draft_params, self._draft_cache, toks, lengths,
                 slots_arr, np.int32(len(reqs)))
+            self._seq += 1   # a program of the chip's, though never fetched
         except BaseException:  # noqa: BLE001
             self.spec_draft_errors += 1
 
@@ -772,8 +849,11 @@ class LLMEngine:
         while not self._stop:
             self.loop_iterations += 1
             did_work = False
+            pending = not self._pending.empty()
+            if pending:
+                self._look()
             # admit: batch pending prompts of the same bucket into one prefill
-            if self._free_slots and not self._pending.empty():
+            if self._free_slots and pending:
                 with _Phase(self, "admit") as span:
                     admits: List[GenRequest] = []
                     bucket = None
@@ -787,11 +867,18 @@ class LLMEngine:
                         if b != bucket:
                             break
                         admits.append(self._pending.get())
+                    self._hold(
+                        "slot" if len(admits) >= len(self._free_slots)
+                        else "batch" if len(admits) >= self.prefill_batch
+                        else "bucket")
                     span.set_metadata(
                         bucket=bucket, rows=len(admits),
-                        chunks=self._admit_walk(admits, bucket)[0])
+                        chunks=self._admit_walk(admits, bucket)[0],
+                        ahead=len(self._unfetched))
                     self._admit(admits, bucket)
                 did_work = True
+            elif pending:
+                self._hold("slot")
             if self._active:
                 with _Phase(self, "dispatch"):
                     self._dispatch_step()
@@ -806,6 +893,24 @@ class LLMEngine:
                 with _Phase(self, "idle"):
                     self._wake.wait(timeout=0.02)
                     self._wake.clear()
+
+    def _look(self):
+        """One look at the queue, at the top of a pass that finds anything
+        pending: the stamp, and ``seen_at`` for what it finds for the first
+        time."""
+        self._look_at = now = time.monotonic()
+        with self._pending.mutex:
+            for r in self._pending.queue:
+                if r.seen_at is None:
+                    r.seen_at = now
+
+    def _hold(self, why: str):
+        """The pass's look leaves what it saw and did not take: the reason
+        goes on each request (the last one rides its batch_wait span)."""
+        with self._pending.mutex:
+            for r in self._pending.queue:
+                if r.seen_at is not None:
+                    r.held_by = why
 
     def _admit_arrays(self, reqs: List[GenRequest], bucket: int,
                       slots: List[int], starts: Optional[List[int]] = None):
@@ -851,16 +956,60 @@ class LLMEngine:
                 r.out.put(e)
                 r.out.put(_FLUSH)
             return
-        snapshot = {}
+        self._admitted(reqs, slots, first, bucket,
+                       int(lengths[:len(reqs)].sum()))
+
+    def _admitted(self, reqs: List[GenRequest], slots: List[int], first,
+                  bucket: int, tokens_real: int):
+        """What every admit does once its program is dispatched, dense or
+        paged: the rows become active, the draft ingests them, the program
+        joins those in flight with what its fetch will account by."""
         for r, s in zip(reqs, slots):
             r.slot = s
             self._active[s] = r
-            snapshot[s] = r
         if self._spec is not None:
             self._draft_prefill(reqs, slots)
-        self._unfetched.append((first, snapshot, slots))
         self.steps += 1
-        self._obs_admit(reqs, bucket, int(lengths[:len(reqs)].sum()))
+        chunks, walk = self._admit_walk(reqs, bucket)
+        prog = _Program("admit", (first,), time.monotonic(),
+                        rows=list(zip(reqs, walk)), chunks=chunks)
+        self._obs_admit(prog, tokens_real)
+        self._in_flight(prog)
+
+    def _in_flight(self, prog: _Program):
+        """``prog`` was just dispatched: its ordinal, and the streams that
+        stand still while it runs (requests with a first token, not
+        retired, as the host knows them)."""
+        self._seq += 1
+        prog.seq = self._seq
+        prog.streams = sum(1 for r in self._active.values() if r.emit_times)
+        self._unfetched.append(prog)
+
+    def _program_done(self, prog: _Program, done: float):
+        """``prog``'s blocking fetch returned at ``done``.  At a busy chip
+        the engine thread is always waiting there, so that is the program's
+        end to within the host's few milliseconds a pass, and the later of
+        its dispatch and the end of the one before it is its start (the
+        engine is the chip's only submitter; a speculative engine's draft
+        prefill, never fetched, counts into the program after it).  From
+        the two: how long the live streams stood behind the program, and
+        for an admit what stood between its dispatch and each first token."""
+        start = max(prog.dispatched, self._done_at)
+        self._done_at = done
+        ran = done - start
+        self.stream_s += prog.streams * ran
+        if prog.kind != "admit":
+            return
+        self.stream_admit_s += prog.streams * ran
+        walked = sum(n for _r, n in prog.rows)
+        for r, n in prog.rows:
+            ahead, own = start - r.admitted_at, ran * n / walked
+            self.first_token_ahead_s += ahead
+            self.first_token_own_row_s += own
+            self.first_token_other_rows_s += ran - own
+            r.prefill_attrs = {"ahead_s": ahead, "own_row_s": own,
+                               "rows": len(prog.rows),
+                               "chunks": prog.chunks}
 
     def _plan_pages(self, r: GenRequest):
         """Reserve pages for one request: reuse cached prefix pages, allocate
@@ -897,6 +1046,7 @@ class LLMEngine:
             plan = self._plan_pages(r)
             if plan is None:
                 # arena full: requeue and stop admitting (backpressure)
+                r.held_by = "pages"
                 self._pending.put(r)
                 continue
             planned.append((r, plan))
@@ -929,21 +1079,14 @@ class LLMEngine:
                 r.out.put(e)
                 r.out.put(_FLUSH)
             return
-        snapshot = {}
-        for (r, (_reused, _pages)), s in zip(planned, slots):
-            r.slot = s
-            self._active[s] = r
-            snapshot[s] = r
-            if self.prefix is not None:
+        if self.prefix is not None:
+            for r in preqs:
                 # register this prompt's full pages for future reuse
                 self.prefix.insert(r.tokens,
                                    r.pages[:len(r.tokens) // self.page_size])
-        if self._spec is not None:
-            self._draft_prefill(preqs, slots)
-        self._unfetched.append((first, snapshot, slots))
-        self.steps += 1
         # tokens prefilled: each prompt's uncached suffix (after `start`)
-        self._obs_admit(preqs, sbucket, int(lengths[:len(preqs)].sum()))
+        self._admitted(preqs, slots, first, sbucket,
+                       int(lengths[:len(preqs)].sum()))
 
     def _dispatch_step(self):
         if self._spec is not None:
@@ -954,15 +1097,16 @@ class LLMEngine:
             self.cache = res["target_cache"]
             self._draft_cache = res["draft_cache"]
             self._state = res["state"]
-            self._unfetched.append(
-                ((res["tokens"], res["counts"], res["emit_counts"], k),
-                 dict(self._active), "spec"))
+            self._in_flight(_Program(
+                "spec", (res["tokens"], res["counts"], res["emit_counts"]),
+                time.monotonic(), dict(self._active), k=k))
             self.steps += rounds
             self.spec_dispatch_k[k] = self.spec_dispatch_k.get(k, 0) + 1
             return
         self.cache, self._state, emitted = self._decode_fn(
             self.params, self.cache, self._state)
-        self._unfetched.append((emitted, dict(self._active), None))
+        self._in_flight(_Program("decode", (emitted,), time.monotonic(),
+                                 dict(self._active)))
         self.steps += self.steps_per_dispatch
         if not self.paged:
             self._count_kv_positions()
@@ -989,19 +1133,6 @@ class LLMEngine:
         self.kv_positions_held += steps * (self.num_slots + 1) * self.max_len
         # the steps of this dispatch that find a live slot
         self.moe_expert_layer_steps += longest * self.cfg.expert_layers
-
-    def _drain_spec(self, payload, snapshot):
-        """Fetch one speculative dispatch, then emit it."""
-        import numpy as np
-        tokens_dev, counts_dev, round_counts_dev, k = payload
-        with _Phase(self, "fetch"):
-            tokens = np.asarray(tokens_dev)   # blocks until the dispatch ran
-            counts = np.asarray(counts_dev)
-            rounds = np.asarray(round_counts_dev)  # [num_rounds, slots]
-        with _Phase(self, "emit") as span:
-            before = self.tokens_out
-            self._emit_spec(tokens, counts, rounds, k, snapshot)
-            span.set_metadata(tokens=self.tokens_out - before)
 
     def _emit_spec(self, tokens, counts, rounds, k: int, snapshot):
         """Emit each slot's accepted window and fold the per-round emit
@@ -1034,29 +1165,35 @@ class LLMEngine:
                 self._emit(r, int(tokens[s, j]))
 
     def _drain_one(self):
+        """Fetch the oldest program in flight, then emit what it made."""
         import numpy as np
-        tokens_dev, snapshot, prefill_slots = self._unfetched.pop(0)
-        if prefill_slots == "spec":
-            self._drain_spec(tokens_dev, snapshot)
-            return
-        with _Phase(self, "fetch"):
-            tokens = np.asarray(tokens_dev)   # blocks until the step finished
+        prog = self._unfetched.pop(0)
+        fetch = _Phase(self, "fetch", program=prog.kind, seq=prog.seq)
+        with fetch:
+            # blocks until the program has run
+            fetched = [np.asarray(a) for a in prog.out]
+        self._program_done(prog, fetch.t1)
         with _Phase(self, "emit") as span:
             before = self.tokens_out
-            if prefill_slots is not None:
-                # prefill entry: tokens is [len(slots)] in admit order
-                for i, s in enumerate(prefill_slots):
-                    self._emit(snapshot[s], int(tokens[i]))
+            if prog.kind == "spec":
+                tokens, counts, rounds = fetched  # rounds [num_rounds, slots]
+                self._emit_spec(tokens, counts, rounds, prog.k,
+                                prog.snapshot)
+            elif prog.kind == "admit":
+                # tokens is [prefill_batch], the real rows first
+                for (r, _n), token in zip(prog.rows, fetched[0]):
+                    self._emit(r, int(token))
             else:
-                # decode entry: [steps_per_dispatch, slots], and a row of
-                # the expert layers' counts where the model has them
+                # [steps_per_dispatch, slots], and a row of the expert
+                # layers' counts where the model has them
+                tokens = fetched[0]
                 if self.cfg.moe_dropless:
                     tokens, (ran, touched) = self._dec.split_moe_counts(
                         tokens)
                     self.moe_assignments += ran
                     self.moe_experts_touched += touched
                 for k in range(tokens.shape[0]):
-                    for s, r in snapshot.items():
+                    for s, r in prog.snapshot.items():
                         if r.slot == s and self._active.get(s) is r:
                             self._emit(r, int(tokens[k, s]))
             span.set_metadata(tokens=self.tokens_out - before)

@@ -27,13 +27,13 @@ def _engine(cfg, **kw):
 
 def _spy_admits(eng):
     """Every admit batch as ``(rows, bucket, real tokens)``."""
-    seen, orig = [], eng._obs_admit
+    seen, orig = [], eng._admitted
 
-    def spy(reqs, bucket, tokens_real):
+    def spy(reqs, slots, first, bucket, tokens_real):
         seen.append((len(reqs), bucket, tokens_real))
-        orig(reqs, bucket, tokens_real)
+        orig(reqs, slots, first, bucket, tokens_real)
 
-    eng._obs_admit = spy
+    eng._admitted = spy
     return seen
 
 
@@ -71,8 +71,6 @@ def test_admitted_tokens_are_counted_real_and_padded(tiny_cfg, paged):
             rows * bucket for rows, bucket, _ in seen)
         assert c["admit_tokens_padded"] > 0
         assert any(rows < eng.prefill_batch for rows, _, _ in seen)
-        assert eng.admit_rows_padded == 0
-        assert eng.breakdown()["padding_fraction"] == 0.0
         assert c["admitted_requests"] == c["first_tokens"] == len(prompts)
         assert sum(rows for rows, _, _ in seen) == len(prompts)
         # the row accounting the committed readers use is untouched
@@ -164,7 +162,8 @@ def test_gen_request_keeps_one_clock_per_stage():
     from ray_tpu.serve.llm import GenRequest
     clocks = [s for s in GenRequest.__slots__
               if s.endswith(("_at", "_wall", "_times"))]
-    assert sorted(clocks) == ["admitted_at", "emit_times", "submitted_at"]
+    assert sorted(clocks) == ["admitted_at", "emit_times", "seen_at",
+                              "submitted_at"]
 
 
 def test_phases_partition_the_engine_threads_time(tiny_cfg):
@@ -209,6 +208,122 @@ def test_open_phase_counts_up_to_the_snapshot(tiny_cfg):
         eng.shutdown()
 
 
+# --------------------------------- a request's wait, by cause (PR 42)
+
+WAIT_KEYS = ("queue_look_s", "queue_held_s", "first_token_ahead_s",
+             "first_token_own_row_s", "first_token_other_rows_s",
+             "stream_s", "stream_admit_s")
+ENGINES = {
+    "dense": {},
+    "paged": dict(paged=True, page_size=8, num_pages=96),
+    "spec": dict(spec_decode_enabled=True, spec_k=2, spec_draft_layers=1),
+}
+EPS = 1e-9          # float additions in another order, never a clock
+
+
+def _submit_together(eng, prompts, max_tokens=3):
+    """Requests that one look of the loop finds together: they join the
+    queue under its own lock, which the loop's every reading takes."""
+    from ray_tpu.serve.llm import GenRequest
+    reqs = [GenRequest(list(p), max_tokens, 0.0, 0, None) for p in prompts]
+    with eng._pending.mutex:
+        eng._pending.queue.extend(reqs)
+    eng._wake.set()
+    return reqs
+
+
+def _finish(reqs):
+    from ray_tpu.serve.llm import _FLUSH
+    for r in reqs:
+        while r.out.get(timeout=120) is not _FLUSH:
+            pass
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_the_waits_are_partitioned_by_cause(tiny_cfg, kind):
+    """Queue wait = the wait for a look + the looks that left the request
+    + the host's admit; first-token wait = the programs ahead + the own
+    row + the other rows + the fetch's return to the emit.  Sums and signs
+    on every engine kind, from the counters and from each request."""
+    import dataclasses
+    eng = _engine(dataclasses.replace(tiny_cfg, num_layers=2),
+                  **ENGINES[kind])
+    try:
+        prompts = [[1 + (i + j) % 50 for j in range(n)]
+                   for i, n in enumerate(PROMPT_LENS)]
+        reqs = _run(eng, prompts, max_tokens=12)
+        c = eng.counters()
+        assert all(c[k] >= 0 for k in WAIT_KEYS), c
+        assert c["queue_look_s"] + c["queue_held_s"] <= c["queue_wait_s"] + EPS
+        parts = [c["first_token_ahead_s"], c["first_token_own_row_s"],
+                 c["first_token_other_rows_s"]]
+        assert sum(parts) <= c["first_token_wait_s"] + EPS
+        assert c["first_token_own_row_s"] > 0
+        assert c["stream_admit_s"] <= c["stream_s"]
+        for r in reqs:
+            assert r.submitted_at <= r.seen_at <= r.admitted_at
+            a = r.prefill_attrs
+            assert a["ahead_s"] >= 0 and a["own_row_s"] > 0
+            assert a["rows"] >= 1 and a["chunks"] == 0
+            assert (a["ahead_s"] + a["own_row_s"]
+                    <= r.emit_times[0] - r.admitted_at + EPS)
+        assert c["queue_look_s"] == pytest.approx(
+            sum(r.seen_at - r.submitted_at for r in reqs))
+        assert c["first_token_ahead_s"] == pytest.approx(
+            sum(r.prefill_attrs["ahead_s"] for r in reqs))
+        assert c["first_token_own_row_s"] == pytest.approx(
+            sum(r.prefill_attrs["own_row_s"] for r in reqs))
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("why,kw,lens", [
+    ("bucket", dict(num_slots=4), (5, 40)),
+    ("slot", dict(num_slots=1), (5, 6)),
+    ("batch", dict(num_slots=4, prefill_batch=1), (5, 6)),
+])
+def test_a_look_that_leaves_a_request_says_why(tiny_cfg, why, kw, lens):
+    """Two requests that one look finds together, of which the admit takes
+    the first: the other bucket, the one free slot, the full admit."""
+    eng = _engine(tiny_cfg, **kw)
+    try:
+        first, second = reqs = _submit_together(
+            eng, [[1 + j % 50 for j in range(n)] for n in lens])
+        _finish(reqs)
+        c = eng.counters()
+        assert first.held_by is None and second.held_by == why
+        assert first.seen_at == second.seen_at
+        assert first.admitted_at < second.admitted_at
+        # the look that took the first left the second, and a later look
+        # took it: all of the held time is the second's
+        assert 0 < c["queue_held_s"] <= second.admitted_at - second.seen_at
+        assert c["queue_look_s"] == pytest.approx(sum(
+            r.seen_at - r.submitted_at for r in reqs))
+    finally:
+        eng.shutdown()
+
+
+def test_an_admit_stalls_the_streams_live_at_its_dispatch(tiny_cfg):
+    """``stream_s`` grows by every program's run times the streams live at
+    its dispatch, ``stream_admit_s`` by the admits' alone: nothing while no
+    admit runs beside a stream, something once one does."""
+    from ray_tpu.serve.llm import _FLUSH
+    eng = _engine(tiny_cfg)
+    try:
+        _run(eng, [[1, 2, 3, 4]], max_tokens=40)
+        alone = eng.counters()
+        assert alone["stream_admit_s"] == 0
+        assert alone["stream_s"] > 0
+        long = eng.submit([5, 6, 7], max_tokens=100)
+        assert long.out.get(timeout=120) is not _FLUSH   # a live stream
+        _run(eng, [[8, 9, 10, 11]], max_tokens=3)        # an admit beside it
+        _finish([long])
+        both = eng.counters()
+        assert 0 < both["stream_admit_s"] <= both["stream_s"]
+    finally:
+        eng.shutdown()
+
+
 async def _consume(server, body):
     return [tok async for tok in server(body)]
 
@@ -229,14 +344,14 @@ def test_server_counts_delivered_tokens_and_their_lag():
         assert 0 <= st["deliver_lag_s"] < 18 * 5.0
         # what the committed readers difference keeps its names
         for key in ("steps", "tokens_out", "admit_batches",
-                    "padding_fraction", "batch_occupancy", "num_slots",
+                    "batch_occupancy", "num_slots",
                     "active", "free_slots", "prefill_buckets"):
             assert key in st
         # and the new keys ride along
         for key in ["t_mono", "loop_iterations", "admitted_requests",
                     "queue_wait_s", "first_tokens", "first_token_wait_s",
                     "admit_tokens_real", "admit_tokens_padded",
-                    "admit_chunks", "admit_rows_chunked"] + [
+                    "admit_chunks", "admit_rows_chunked", *WAIT_KEYS] + [
                         f"loop_{ph}_{k}" for ph in ENGINE_PHASES
                         for k in ("s", "n")]:
             assert key in st, key

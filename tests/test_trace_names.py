@@ -236,6 +236,15 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
     assert len(admits) == 3
     assert all(st.get("bucket") == 16 and st.get("rows") == 1
                and st.get("chunks") == 0 for _d, st in admits)
+    # the programs in flight ahead of an admit at its dispatch, and which
+    # program a fetch waited for: its kind and its ordinal since the engine
+    # started, one apart from fetch to fetch (they are drained in order)
+    assert all(0 <= st["ahead"] <= eng.fetch_lag + 1 for _d, st in admits)
+    fetches = [st for _d, st in by_name["raytpu:engine.fetch"]]
+    assert {st["program"] for st in fetches} == {"admit", "decode"}
+    assert sum(st["program"] == "admit" for st in fetches) == 3
+    seqs = [st["seq"] for st in fetches]
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
     assert sum(st["tokens"] for _d, st in by_name["raytpu:engine.emit"]) \
         == 27
     # the capture brackets the two snapshots, so it holds at least the
@@ -248,6 +257,58 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
     spanned = sum(d for ph in ("admit", "dispatch", "fetch", "emit")
                   for d, _st in by_name[f"raytpu:engine.{ph}"]) / 1e9
     assert spanned == pytest.approx(counted, rel=0.2, abs=0.02)
+
+
+def test_stage_spans_carry_the_wait_account(tiny_cfg, monkeypatch):
+    """The task-event spans of a request say what its counters sum: a
+    ``batch_wait`` its wait for a look, the looks that left it and why, a
+    ``prefill`` the programs ahead of its admit, its own row's part of the
+    admit's run, and the admit's rows and chunks."""
+    from ray_tpu.core.config import Config, reset_config, set_config
+    from ray_tpu.serve.llm import _FLUSH, GenRequest
+    from ray_tpu.util import tracing
+
+    spans = {}
+    monkeypatch.setattr(
+        tracing, "record_span",
+        lambda name, t0, dur, **kw: spans.setdefault(name, []).append(
+            dict(kw, dur=dur)) or "sid")
+    try:
+        set_config(Config(serve_metrics_enabled=True))
+        eng = _engine(tiny_cfg)
+        try:
+            # two buckets that one look finds together: the second is left
+            reqs = [GenRequest([1 + j for j in range(n)], 3, 0.0, 0, None)
+                    for n in (5, 20)]
+            with eng._pending.mutex:
+                eng._pending.queue.extend(reqs)
+            eng._wake.set()
+            for r in reqs:
+                while r.out.get(timeout=120) is not _FLUSH:
+                    pass
+            c = eng.counters()
+        finally:
+            eng.shutdown()
+    finally:
+        reset_config()
+    waits, prefills = spans["batch_wait"], spans["prefill"]
+    assert len(waits) == len(prefills) == 2
+    for sp in waits:
+        assert {"look_s", "held_s", "held_by"} <= set(sp)
+        assert sp["look_s"] >= 0 and sp["held_s"] >= 0
+        assert sp["look_s"] + sp["held_s"] <= sp["dur"] + 1e-9
+    assert [sp["held_by"] for sp in waits] == [None, "bucket"]
+    assert waits[0]["held_s"] == 0 < waits[1]["held_s"]
+    for sp in prefills:
+        assert {"ahead_s", "own_row_s", "rows", "chunks",
+                "prompt_len"} <= set(sp)
+        assert (sp["rows"], sp["chunks"]) == (1, 0)
+        assert sp["ahead_s"] >= 0 and sp["own_row_s"] > 0
+        assert sp["ahead_s"] + sp["own_row_s"] <= sp["dur"] + 1e-9
+    assert sum(sp["held_s"] for sp in waits) == pytest.approx(
+        c["queue_held_s"])
+    assert sum(sp["own_row_s"] for sp in prefills) == pytest.approx(
+        c["first_token_own_row_s"])
 
 
 # ------------------------------------------------ layers of two kinds (PR 29)
