@@ -147,7 +147,7 @@ class LLMEngine:
     def __init__(self, cfg, params=None, *, num_slots: int = 8,
                  max_len: Optional[int] = None, buckets=DEFAULT_BUCKETS,
                  compute_dtype=None, seed: int = 0, top_k: int = 0,
-                 fetch_lag: int = 2, steps_per_dispatch: int = 8,
+                 steps_per_dispatch: int = 8,
                  prefill_batch: Optional[int] = None,
                  warmup_buckets: bool = False,
                  paged: bool = False, page_size: int = 64,
@@ -202,11 +202,13 @@ class LLMEngine:
         self.buckets = tuple(b for b in buckets if b <= self.max_len)
         self.compute_dtype = compute_dtype or jnp.bfloat16
         self.top_k = top_k
-        self.fetch_lag = max(0, fetch_lag)
         # decode steps fused into one dispatch: amortizes the host's
         # per-dispatch cost at the price of <= steps_per_dispatch wasted
-        # steps after a sequence finishes and <= one dispatch of added
-        # admission latency
+        # steps after a sequence finishes.  It is also what an admission
+        # waits for: the loop looks at its queue once a pass, as a dispatch
+        # starts on the chip, so a request waits up to a pass (a dispatch
+        # and the admit behind it) for that look and then that dispatch,
+        # which its admit is queued behind (_loop)
         self.steps_per_dispatch = max(1, steps_per_dispatch)
         self._dec = dec
         self._jax = jax
@@ -849,6 +851,7 @@ class LLMEngine:
         while not self._stop:
             self.loop_iterations += 1
             did_work = False
+            keep = 1            # programs left in flight at the next look
             pending = not self._pending.empty()
             if pending:
                 self._look()
@@ -876,6 +879,8 @@ class LLMEngine:
                         chunks=self._admit_walk(admits, bucket)[0],
                         ahead=len(self._unfetched))
                     self._admit(admits, bucket)
+                    if 2 * len(admits) > self.prefill_batch:
+                        keep = 2    # a burst's admit: see the fetch below
                 did_work = True
             elif pending:
                 self._hold("slot")
@@ -883,10 +888,21 @@ class LLMEngine:
                 with _Phase(self, "dispatch"):
                     self._dispatch_step()
                 did_work = True
-            # fetch completed steps once the pipeline is `fetch_lag` deep
-            # (device computes step N+1 while the host reads back step N)
-            while len(self._unfetched) > (self.fetch_lag if self._active
-                                          else 0):
+            # Fetch all but the program dispatched last, the decode dispatch
+            # behind this pass's admit: when the fetch before it returns the
+            # chip has just started on it, so the next pass looks at the
+            # queue and binds its admit ONE program ahead of the chip.  One
+            # keeps the chip fed (the host's share of a pass is a few
+            # milliseconds of a whole dispatch, and an admit, the one program
+            # that can be shorter, always has a dispatch behind it); a second
+            # would stand between every admit and its first tokens.
+            # Behind a burst's admit, one more than half full, the loop
+            # stops a fetch sooner and looks as that admit STARTS: what
+            # arrives while it runs would be a second long admit, and bound
+            # at its end the two hold every live stream with one dispatch
+            # between them (PERF.md section 6, PR 43).  With no stream live
+            # nothing is held back.
+            while len(self._unfetched) > (keep if self._active else 0):
                 self._drain_one()
                 did_work = True
             if not did_work:
@@ -986,14 +1002,17 @@ class LLMEngine:
         self._unfetched.append(prog)
 
     def _program_done(self, prog: _Program, done: float):
-        """``prog``'s blocking fetch returned at ``done``.  At a busy chip
-        the engine thread is always waiting there, so that is the program's
-        end to within the host's few milliseconds a pass, and the later of
-        its dispatch and the end of the one before it is its start (the
-        engine is the chip's only submitter; a speculative engine's draft
-        prefill, never fetched, counts into the program after it).  From
-        the two: how long the live streams stood behind the program, and
-        for an admit what stood between its dispatch and each first token."""
+        """``prog``'s blocking fetch returned at ``done``.  With a stream
+        live the loop has bound what follows ``prog`` before it comes here
+        (``_loop`` leaves one program in flight at its look, two behind a
+        burst's admit), and comes here within the host's few milliseconds a
+        pass of the fetch before: at a busy chip the engine thread is always
+        waiting there.  So that is the program's end, and the later of its
+        dispatch and the end of the one before it is its start (the engine
+        is the chip's only submitter; a speculative engine's draft prefill,
+        never fetched, counts into the program after it).  From the two:
+        how long the live streams stood behind the program, and for an
+        admit what stood between its dispatch and each first token."""
         start = max(prog.dispatched, self._done_at)
         self._done_at = done
         ran = done - start

@@ -221,6 +221,19 @@ ENGINES = {
 EPS = 1e-9          # float additions in another order, never a clock
 
 
+def _two_layers(cfg):
+    import dataclasses
+    return dataclasses.replace(cfg, num_layers=2)   # a draft needs one less
+
+
+def _live_stream(eng, max_tokens=100):
+    """A request with its first token out and most of its answer to come."""
+    from ray_tpu.serve.llm import _FLUSH
+    long = eng.submit([5, 6, 7], max_tokens=max_tokens)
+    assert long.out.get(timeout=120) is not _FLUSH
+    return long
+
+
 def _submit_together(eng, prompts, max_tokens=3):
     """Requests that one look of the loop finds together: they join the
     queue under its own lock, which the loop's every reading takes."""
@@ -245,9 +258,7 @@ def test_the_waits_are_partitioned_by_cause(tiny_cfg, kind):
     + the host's admit; first-token wait = the programs ahead + the own
     row + the other rows + the fetch's return to the emit.  Sums and signs
     on every engine kind, from the counters and from each request."""
-    import dataclasses
-    eng = _engine(dataclasses.replace(tiny_cfg, num_layers=2),
-                  **ENGINES[kind])
+    eng = _engine(_two_layers(tiny_cfg), **ENGINES[kind])
     try:
         prompts = [[1 + (i + j) % 50 for j in range(n)]
                    for i, n in enumerate(PROMPT_LENS)]
@@ -322,6 +333,184 @@ def test_an_admit_stalls_the_streams_live_at_its_dispatch(tiny_cfg):
         assert 0 < both["stream_admit_s"] <= both["stream_s"]
     finally:
         eng.shutdown()
+
+
+# ------------------- an admit is bound one program ahead of the chip (PR 43)
+
+def _spy_programs(eng):
+    """Every program the engine thread binds, in order, beside the programs
+    that were in flight (dispatched, not fetched) at its bind."""
+    log, orig = [], eng._in_flight
+
+    def spy(prog):
+        log.append((prog, list(eng._unfetched)))
+        orig(prog)
+
+    eng._in_flight = spy
+    return log
+
+
+def test_the_depth_of_the_loop_is_no_argument():
+    import inspect
+    from ray_tpu.serve.llm import LLMEngine
+    assert "fetch_lag" not in inspect.signature(LLMEngine.__init__).parameters
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_an_admit_is_bound_behind_the_one_dispatch_in_flight(tiny_cfg, kind):
+    """A request that arrives while a dispatch is the newest program bound
+    is admitted by the very next program, and when the loop binds that admit
+    the dispatch is all that is in flight: the pass before it has fetched
+    everything older, so the first tokens wait out one dispatch and no
+    admit."""
+    eng = _engine(_two_layers(tiny_cfg), **ENGINES[kind])
+    try:
+        eng.warmup(16)
+        log = _spy_programs(eng)
+        long = _live_stream(eng)
+        late, dispatch = {}, eng._dispatch_step
+
+        def dispatch_then_submit():
+            dispatch()
+            if not late:    # on the engine thread, right behind a dispatch
+                late["behind"] = eng._unfetched[-1]
+                late["req"] = eng.submit([8, 9, 10, 11], max_tokens=3)
+
+        eng._dispatch_step = dispatch_then_submit
+        while not late:
+            time.sleep(0.001)
+        _finish([late["req"], long])
+        progs = [p for p, _ahead in log]
+        at = progs.index(late["behind"])
+        admit, ahead = log[at + 1]
+        assert late["behind"].kind in ("decode", "spec")
+        assert admit.kind == "admit"
+        assert [r for r, _n in admit.rows] == [late["req"]]
+        assert ahead == [late["behind"]]
+        # the chip's next program but for a draft's prefill, which is one
+        # of the chip's and never fetched
+        assert admit.seq == late["behind"].seq + 1 + (kind == "spec")
+        assert progs[at + 2].kind == late["behind"].kind
+        assert late["req"].prefill_attrs["rows"] == 1
+    finally:
+        eng.shutdown()
+
+
+def _burst(eng, admit):
+    """More than half of what an admit may take: the loop looks again as
+    such an admit starts, not as it ends."""
+    return admit.kind == "admit" and 2 * len(admit.rows) > eng.prefill_batch
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_admits_and_dispatches_alternate_one_ahead(tiny_cfg, kind):
+    """Requests arriving at their own times through few slots: the chip's
+    programs alternate (an admit always has a dispatch bound behind it, so
+    no two admits stand back to back), and an admit bound beside a live
+    stream finds one program in flight, a dispatch; two only behind a
+    burst's admit, which is then the one running."""
+    eng = _engine(_two_layers(tiny_cfg), num_slots=3, **ENGINES[kind])
+    try:
+        log = _spy_programs(eng)
+        reqs = []
+        for i, n in enumerate(PROMPT_LENS * 2):
+            reqs.append(eng.submit([1 + (i + j) % 50 for j in range(n)],
+                                   max_tokens=4 + 5 * (i % 4)))
+            time.sleep(0.003 * (i % 3))
+        _finish(reqs)
+        assert [r.generated for r in reqs] == [
+            4 + 5 * (i % 4) for i in range(len(reqs))]
+        kinds = [p.kind for p, _ahead in log]
+        admits = [(p, ahead) for p, ahead in log if p.kind == "admit"]
+        assert sum(len(p.rows) for p, _a in admits) == len(reqs)
+        assert all(a != "admit" or b != "admit"
+                   for a, b in zip(kinds, kinds[1:])), kinds
+        for p, ahead in admits:
+            assert len(ahead) <= 2
+            assert not ahead or ahead[-1].kind != "admit"
+            if len(ahead) == 2:
+                assert _burst(eng, ahead[0])
+            # a live stream means a dispatch in flight
+            assert ahead or not p.streams
+        assert any(p.streams for p, _ahead in admits)
+        # each fetched once: the dispatch bound behind the last answer's is
+        # drained too, once no stream is left
+        deadline = time.monotonic() + 30
+        while eng._unfetched and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.05)
+        assert eng.counters()["loop_fetch_n"] == len(log)
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_behind_a_bursts_admit_the_loop_looks_as_it_starts(tiny_cfg, kind):
+    """An admit more than half full is a burst.  The loop does not wait for
+    its end to look again: a request that arrives as it is bound is bound
+    behind it and its dispatch, and what arrives while it runs waits a pass
+    more, so two long admits never hold the live streams with one dispatch
+    between.  The pass after is one ahead again."""
+    eng = _engine(_two_layers(tiny_cfg), num_slots=8, prefill_batch=4,
+                  **ENGINES[kind])
+    try:
+        eng.warmup(16)
+        log = _spy_programs(eng)
+        long = _live_stream(eng)
+        late, admitted = {}, eng._admitted
+
+        def admitted_then_submit(reqs, *rest):
+            admitted(reqs, *rest)
+            if len(reqs) == 3 and not late:   # on the engine thread
+                late["req"] = eng.submit([8, 9, 10, 11], max_tokens=3)
+
+        eng._admitted = admitted_then_submit
+        wave = _submit_together(
+            eng, [[1 + (i + j) % 50 for j in range(5)] for i in range(3)])
+        _finish(wave + [long])
+        _finish([late["req"]])
+        admits = [(p, ahead) for p, ahead in log if p.kind == "admit"]
+        burst = next(p for p, _a in admits
+                     if [r for r, _n in p.rows] == wave)
+        after, ahead = next((p, a) for p, a in admits
+                            if [r for r, _n in p.rows] == [late["req"]])
+        assert _burst(eng, burst) and not _burst(eng, after)
+        assert len(ahead) == 2 and ahead[0] is burst
+        assert ahead[1].kind in ("decode", "spec")
+        # and the look after that one found a single dispatch in flight
+        later = [a for p, a in log if p.seq > after.seq]
+        assert later and all(len(a) <= 1 for a in later[1:])
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_a_mixed_batch_is_greedy_token_for_token(tiny_cfg, kind):
+    """When the loop binds a program decides which requests share an admit
+    and a dispatch, never what any of them is answered: a mixed batch
+    through few slots reads what ``generate`` gives one request at a time."""
+    cfg = _two_layers(tiny_cfg)
+    prompts = [[1 + (3 * i + j) % 50 for j in range(n)]
+               for i, n in enumerate(PROMPT_LENS)]
+    budgets = [12, 5, 9, 1, 16, 7]
+    eng = _engine(cfg, num_slots=3, seed=7, **ENGINES[kind])
+    try:
+        reqs = []
+        for p, m in zip(prompts, budgets):
+            reqs.append(eng.submit(list(p), max_tokens=m))
+            time.sleep(0.002)
+        _finish(reqs)
+        mixed = [r.tokens[r.prompt_len:] for r in reqs]
+    finally:
+        eng.shutdown()
+    alone = _engine(cfg, num_slots=3, seed=7, **ENGINES[kind])
+    try:
+        one_by_one = [alone.generate(list(p), max_tokens=m)
+                      for p, m in zip(prompts, budgets)]
+    finally:
+        alone.shutdown()
+    assert [len(o) for o in mixed] == budgets
+    assert mixed == one_by_one
 
 
 async def _consume(server, body):

@@ -210,9 +210,9 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
             c0 = eng.counters()
             outs = [eng.generate([1, 2, 3 + i], max_tokens=9)
                     for i in range(3)]
-            # idle passes inside the capture; the dispatches the engine ran
-            # ahead of the last answer drain before the second snapshot (two
-            # of nine: their fetches would be the test's whole tolerance)
+            # idle passes inside the capture; the dispatch the engine bound
+            # ahead of the last answer drains before the second snapshot
+            # (its fetch would be the test's whole tolerance)
             time.sleep(0.1)
             c1 = eng.counters()
         assert [len(o) for o in outs] == [9, 9, 9]
@@ -239,7 +239,7 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
     # the programs in flight ahead of an admit at its dispatch, and which
     # program a fetch waited for: its kind and its ordinal since the engine
     # started, one apart from fetch to fetch (they are drained in order)
-    assert all(0 <= st["ahead"] <= eng.fetch_lag + 1 for _d, st in admits)
+    assert all(0 <= st["ahead"] <= 1 for _d, st in admits)
     fetches = [st for _d, st in by_name["raytpu:engine.fetch"]]
     assert {st["program"] for st in fetches} == {"admit", "decode"}
     assert sum(st["program"] == "admit" for st in fetches) == 3
