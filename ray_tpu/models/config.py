@@ -81,8 +81,19 @@ class TransformerConfig:
     linear_value_dim: int = 0       # per head
     linear_conv_width: int = 4      # causal depthwise convolution over time
     linear_neg_eigval: bool = False  # beta in (0, 2) instead of (0, 1)
+    # the linear kind's variant (ops/kda.py, Kimi Delta Attention): a decay
+    # a key channel instead of one a head and a sigmoid output gate instead
+    # of SiLU, the decay's and the gate's projections through a bottleneck
+    # of the published rank
+    linear_decay_per_channel: bool = False
+    linear_gate_rank: int = 0
     qk_norm: bool = False           # RMSNorm over the whole q and k rows
-    norm_on_output: bool = False    # x + norm(f(x)), the hybrid blocks' wiring
+    norm_on_output: bool = False    # x + norm(f(x)); else x + f(norm(x))
+    # the full kind under a pattern: the published width of a head where it
+    # is not hidden / heads (0: it is), and an elementwise sigmoid gate on
+    # the attention's output from a projection of the layer's input
+    attn_head_dim: int = 0
+    attn_output_gate: bool = False
     no_positions: bool = False      # neither rotary nor learned positions
     # latent attention (models/latent.py; DeepSeek-V2's MLA, whose keys these
     # are): ``kv_lora_rank`` > 0 turns it on.  Queries and keys have heads
@@ -150,13 +161,14 @@ class TransformerConfig:
         pat = self.layer_pattern
         self._check_latent_tree()
         if not pat:
-            if self.qk_norm or self.norm_on_output:
-                raise ValueError("qk_norm and norm_on_output are wired for "
-                                 "a layer_pattern only (models/hybrid.py)")
+            only = [f for f in ("qk_norm", "norm_on_output", "attn_head_dim",
+                                "attn_output_gate", "linear_decay_per_channel",
+                                "linear_gate_rank")
+                    if getattr(self, f)]
+            if only:
+                raise ValueError(f"{only} are wired for a layer_pattern only "
+                                 "(models/hybrid.py builds those blocks)")
             return
-        if not self.norm_on_output:
-            raise ValueError("a layer_pattern's blocks are wired x + norm(f(x)) "
-                             "(models/hybrid.py): set norm_on_output")
         if set(pat) - {"linear", "full"}:
             raise ValueError(f"layer_pattern {pat}: kinds are 'linear' and "
                              "'full'")
@@ -168,12 +180,29 @@ class TransformerConfig:
                                     and self.linear_value_dim):
             raise ValueError("a 'linear' layer needs linear_num_heads, "
                              "linear_key_dim and linear_value_dim")
+        if self.linear_gate_rank < 0 or self.attn_head_dim < 0:
+            raise ValueError("linear_gate_rank and attn_head_dim: 0 or more")
+        if self.linear_decay_per_channel != bool(self.linear_gate_rank):
+            raise ValueError(
+                "linear_decay_per_channel and linear_gate_rank go together: "
+                "the decay a channel and its sigmoid gate are projected "
+                "through linear_gate_rank (models/hybrid.py has no full "
+                "[hidden, heads x key_dim] projection of either, and no "
+                "bottleneck for the mixer with a decay a head)")
 
     def _check_latent_tree(self):
-        on = self.latent_tree
-        if on and self.layer_pattern:
-            raise ValueError(f"{on} do not combine with a layer_pattern "
-                             "(models/hybrid.py wires its own blocks)")
+        if self.layer_pattern:
+            # a pattern's blocks are models/hybrid.py's: full attention
+            # over K/V rows and the linear mixer, one residual stream, with
+            # a dense MLP or dropless experts under every layer
+            on = [f for f in ("kv_lora_rank", "hc_mult",
+                              "dense_prefix_layers") if getattr(self, f)]
+            if on:
+                raise ValueError(
+                    f"{on} do not combine with a layer_pattern: "
+                    "models/hybrid.py's full layers cache K/V rows, not "
+                    "latent ones, its blocks carry one residual stream, "
+                    "and every layer of a period has the same MLP")
         if self.kv_lora_rank:
             if not (self.qk_nope_head_dim and self.qk_rope_head_dim
                     and self.v_head_dim) or self.q_lora_rank < 0:
@@ -240,7 +269,7 @@ class TransformerConfig:
             raise AttributeError(
                 "latent attention has no one head_dim: qk_head_dim for "
                 "queries and keys, v_head_dim for values")
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
 
     @property
     def qk_head_dim(self) -> int:
@@ -256,7 +285,11 @@ class TransformerConfig:
     def latent_tree(self) -> Tuple[str, ...]:
         """The mechanisms of this configuration that make its parameters
         ``models/latent.py``'s tree and its serving state that file's cache
-        (no pages, no window of several tokens, no mesh)."""
+        (no pages, no window of several tokens, no mesh).  None under a
+        ``layer_pattern``, whose tree is ``models/hybrid.py``'s whatever its
+        layers' MLP."""
+        if self.layer_pattern:
+            return ()
         return tuple(f for f in self.LATENT_TREE if getattr(self, f))
 
     @property
@@ -295,12 +328,21 @@ class TransformerConfig:
             return self._latent_tree_params(self.experts_held)
         attn = h * h + 2 * h * (self.num_kv_heads * self.head_dim) + h * h
         if self.layer_pattern:
-            kd = self.linear_num_heads * self.linear_key_dim
-            vd = self.linear_num_heads * self.linear_value_dim
-            mixer = (h * (2 * kd + 2 * vd) + vd * h
-                     + 2 * h * self.linear_num_heads)
+            lh, r = self.linear_num_heads, self.linear_gate_rank
+            kd, vd = lh * self.linear_key_dim, lh * self.linear_value_dim
+            wide = self.num_heads * self.head_dim
+            attn = (h * wide * (3 if self.attn_output_gate else 2)
+                    + 2 * h * self.num_kv_heads * self.head_dim)
+            decay = h * r + r * kd if r else h * lh
+            gate = h * r + r * vd if r else h * vd
+            mixer = h * (2 * kd + vd) + vd * h + decay + gate + h * lh
+            mlp = 3 * h * self.mlp_size
+            if self.moe_dropless:
+                mlp = (3 * h * self.expert_mlp_size
+                       * (self.experts_held + self.shared_experts)
+                       + h * self.num_experts)
             return (self.linear_layers * mixer + self.full_layers * attn
-                    + L * 3 * h * self.mlp_size + 2 * v * h)
+                    + L * mlp + 2 * v * h)
         if self.num_experts > 1:
             mlp = self.num_experts * 3 * h * self.mlp_size + h * self.num_experts
         else:

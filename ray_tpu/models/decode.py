@@ -219,7 +219,12 @@ def _mlp(y, p, cfg: TransformerConfig):
 
 
 @jax.named_scope("attn")
-def _proj_out(attn, p, cast):
+def _proj_out(attn, p, cast, x=None):
+    """The output projection; where the layer has an output gate
+    (``cfg.attn_output_gate``) the attention is first multiplied, element by
+    element, by the sigmoid of a projection of the layer's input ``x``."""
+    if "w_gate" in p:
+        attn = attn * jax.nn.sigmoid(x @ p["w_gate"].astype(cast))
     out = attn @ p["wo"].astype(cast)
     if "bo" in p:
         out = out + p["bo"].astype(cast)
@@ -256,16 +261,16 @@ Mixer = Callable[..., Tuple[jnp.ndarray, Any, Any]]
 _BRANCH_NORM = {"full": "attn_norm", "linear": "mixer_norm"}
 
 
-def _layer_weights(stack: Params, index) -> Params:
-    """Layer ``index`` (traced) of a kind's stacked weights [periods, layers
-    of the kind a period, ...]: one dynamic index of the stack flattened, a
-    slice its matmul reads where it lies.  A period's slice taken first (the
-    stack as a scan's xs) is copied out whole: every weight of the linear
-    layers once a decode step, 35% of the hybrid cell's chip (PERF.md, PR
-    30)."""
+def _layer_weights(stack: Params, index, lead: int) -> Params:
+    """Layer ``index`` (traced or not) of weights stacked over ``lead``
+    leading dims ([layers, ...], or a kind's [periods, layers of the kind a
+    period, ...]): one dynamic index of the stack flattened, a slice its
+    matmul reads where it lies.  A period's slice taken first (the stack as
+    a scan's xs) is copied out whole: every weight of the linear layers
+    once a decode step, 35% of the hybrid cell's chip (PERF.md, PR 30)."""
     return jax.tree.map(
         lambda a: jax.lax.dynamic_index_in_dim(
-            a.reshape((-1,) + a.shape[2:]), index, 0, keepdims=False),
+            a.reshape((-1,) + a.shape[lead:]), index, 0, keepdims=False),
         stack)
 
 
@@ -277,14 +282,6 @@ def _kv_mixer(attention, cfg: TransformerConfig, *closed) -> Mixer:
         out, *kv = attention(y, lp["attn"], cfg, *kv, i, *closed)
         return out, tuple(kv), None
     return mixer
-
-
-def _layer_of(stack: Params, index) -> Params:
-    """Layer ``index`` (traced or not) of weights stacked [layers, ...],
-    each a slice its matmul reads where it lies."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, index, 0, keepdims=False),
-        stack)
 
 
 def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
@@ -300,15 +297,17 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     One ``lax.scan`` over periods of ``cfg.layer_pattern`` with the kinds
     inside a period unrolled, so the trace is one period whatever the depth;
     a model without a pattern is the pattern ``("full",)``, one layer a
-    period.  A dense prefix (``cfg.dense_prefix_layers``) is walked before
+    period.  Under any kind of layer lies a dense MLP or, with
+    ``cfg.moe_dropless``, the dropless experts, whose weights stay in their
+    stacks.  A dense prefix (``cfg.dense_prefix_layers``) is walked before
     the scan, which is then over the expert layers; a mixer is handed the
-    layer's index among all of them.  A block is wired ``x + f(norm(x))``,
-    ``x + norm(f(x))`` under ``cfg.norm_on_output``, or with ``cfg.hc_mult``
-    residual streams, read, written and mixed around the sublayer by
-    per-token coefficients (``latent.hc_coeff``), for the mixer and the MLP
-    alike.  ``live`` [rows, W] are the tokens that count (None: all); only
-    a dropless expert layer asks, so that a padded position or an idle slot
-    is routed nowhere.
+    layer's index among its kind's cache rows.  A block is wired
+    ``x + f(norm(x))``, ``x + norm(f(x))`` under ``cfg.norm_on_output``, or
+    with ``cfg.hc_mult`` residual streams, read, written and mixed around
+    the sublayer by per-token coefficients (``latent.hc_coeff``), for the
+    mixer and the MLP alike.  ``live`` [rows, W] are the tokens that count
+    (None: all); only a dropless expert layer asks, so that a padded position
+    or an idle slot is routed nowhere.
 
     Returns (logits float32, carry, ys): logits [rows, W, V], or [rows, V]
     of position ``pick`` [rows] of each row, or with ``head=False`` what the
@@ -369,62 +368,71 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
         out, routed = _experts(seen, lp, cfg, live, cast, *experts)
         return join(out), carry, rows, routed
 
-    def period(walk, step):
-        (x, carry), (p, dense) = walk, step
-        carry, at = dict(carry), dict.fromkeys(per_period, 0)
-        ys = {kind: [] for kind in per_period}
-        for kind in pattern:
-            index = p * per_period[kind] + at[kind]   # in its kind's stack
-            at[kind] += 1
-            lp = (_layer_weights(blocks[kind], index) if dense is None
-                  else dense)
-            x, carry[kind], rows, _ = layer(x, carry[kind], kind, lp, index)
-            ys[kind].append(rows)
-        return (x, carry), {kind: jax.tree.map(lambda *a: jnp.stack(a), *outs)
-                            for kind, outs in ys.items() if outs}
-
-    def expert_layer(walk, p):
-        """A layer after the dense prefix: its small weights indexed out of
-        the stack, its experts' left there for the kernel."""
-        x, carry = walk
-        small = {k: v for k, v in blocks.items() if k != "moe"}
-        routed = ("w_gate", "w_in", "w_out")
-        small["moe"] = {k: v for k, v in blocks["moe"].items()
-                        if k not in routed}
-        x, kv, rows, (counts, chosen) = layer(
-            x, carry["full"], "full", _layer_of(small, p), prefix + p,
-            (p, {k: blocks["moe"][k] for k in routed}))
-        ys = {"moe": counts, "experts": chosen}
-        if rows is not None:
-            ys["full"] = rows
-        return (x, dict(carry, full=kv)), ys
-
-    # A layer's weights: a pattern's stacks [periods, n, ...] are indexed in
-    # place (``_layer_weights``), as are the layers before and with experts
-    # (``_layer_of``); a dense model's one-dimensional stack [L, ...] is the
-    # scan's xs, whose one-layer slices always fused.  Indexed too,
+    # Where a layer's weights lie.  A pattern's blocks are stacked by kind
+    # [periods, layers of the kind a period, ...] with the experts of every
+    # layer in one stack of their own; without a pattern the blocks are one
+    # stack [layers, ...], the routed experts among a dropless layer's
+    # ``moe``.  Both are indexed where they lie (``_layer_weights``) and the
+    # experts never leave their stacks: the kernel takes the layer's index
+    # among the expert layers.  Only a dense model's one-dimensional stack
+    # is the scan's xs, whose one-layer slices always fused: indexed too,
     # Mistral's programs compiled to other code (prefill temporaries +97
     # KB) and the open-loop cells read 0.2-0.6% later first tokens on the
     # chip, four pairs of four (PERF.md, PR 31).
-    if cfg.moe_dropless:
-        carry, first = dict(carry), []
-        for j in range(prefix):
-            x, carry["full"], rows, _ = layer(
-                x, carry["full"], "full", _layer_of(params["prefix"], j), j)
-            first.append(rows)
-        (x, carry), ys = jax.lax.scan(expert_layer, (x, carry),
-                                      jnp.arange(cfg.num_layers - prefix))
-        if "full" in ys and first:
-            ys["full"] = jax.tree.map(
-                lambda *a: jnp.concatenate([jnp.stack(a[:-1]), a[-1]]),
-                *first, ys["full"])
-    else:
-        (x, carry), ys = jax.lax.scan(
-            period, (x, carry), (jnp.arange(cfg.num_layers // len(pattern)),
-                                 None if cfg.layer_pattern else blocks))
-        # [periods, layers of the kind a period, ...] -> [layers of the
-        # kind, ...]
-        ys = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+    routed = ("w_gate", "w_in", "w_out")
+    stacks, small = None, blocks
+    if cfg.moe_dropless and cfg.layer_pattern:
+        stacks = blocks["experts"]
+    elif cfg.moe_dropless:
+        stacks = {k: blocks["moe"][k] for k in routed}
+        small = dict(blocks, moe={k: v for k, v in blocks["moe"].items()
+                                  if k not in routed})
+    as_xs = not (cfg.layer_pattern or cfg.moe_dropless)
+
+    def weights(kind, index):
+        by_kind = bool(cfg.layer_pattern)
+        return _layer_weights(small[kind] if by_kind else small, index,
+                              1 + by_kind)
+
+    def period(walk, step):
+        """One period of the pattern, its kinds unrolled; a layer's index
+        is the one in its kind's stack of weights (a mixer's in its stack
+        of cache rows: the layers before the scan come first there)."""
+        (x, carry), (p, dense) = walk, step
+        carry, at = dict(carry), dict.fromkeys(per_period, 0)
+        ys = {kind: [] for kind in per_period}
+        chosen = []
+        for j, kind in enumerate(pattern):
+            index = p * per_period[kind] + at[kind]
+            at[kind] += 1
+            x, carry[kind], rows, said = layer(
+                x, carry[kind], kind,
+                weights(kind, index) if dense is None else dense,
+                prefix + index,
+                (p * len(pattern) + j, stacks) if stacks else None)
+            ys[kind].append(rows)
+            chosen.append(said)
+        if stacks:
+            ys["moe"], ys["experts"] = zip(*chosen)
+        return (x, carry), {kind: jax.tree.map(lambda *a: jnp.stack(a), *outs)
+                            for kind, outs in ys.items() if outs}
+
+    carry, first = dict(carry), []
+    for j in range(prefix):         # the dense layers before the experts
+        x, carry["full"], rows, _ = layer(
+            x, carry["full"], "full",
+            _layer_weights(params["prefix"], j, 1), j)
+        first.append(rows)
+    (x, carry), ys = jax.lax.scan(
+        period, (x, carry),
+        (jnp.arange((cfg.num_layers - prefix) // len(pattern)),
+         blocks if as_xs else None))
+    # [periods, layers a period, ...] -> [layers, ...]
+    ys = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+    if "full" in ys and first:
+        ys["full"] = jax.tree.map(
+            lambda *a: jnp.concatenate([jnp.stack(a[:-1]), a[-1]]),
+            *first, ys["full"])
     if cfg.hc_mult:                  # the streams are summed before the norm
         x = x.sum(axis=2)
     x = _norm(x, params["final_norm"], cfg).astype(cast)
@@ -446,7 +454,7 @@ def prefill_attention(y, ap, cfg: TransformerConfig, positions):
     q, k, v = _qkv(y, ap, cfg, positions)
     with jax.named_scope("attn"):
         attn = mha(q, k, v, causal=True, logit_softcap=cfg.attn_logit_softcap)
-    return _proj_out(attn.reshape(b, s, -1), ap, y.dtype), k, v
+    return _proj_out(attn.reshape(b, s, -1), ap, y.dtype, y), k, v
 
 
 #: Positions a pass of the admit program walks of a row it walks in chunks.
@@ -498,7 +506,7 @@ def continued_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, slot,
     with jax.named_scope("attn"):
         attn = flash_attention_rows(q, k_all, v_all, i, slot, start, span,
                                     cfg.num_kv_heads, cfg.attn_logit_softcap)
-    return _proj_out(attn, ap, y.dtype), k_all, v_all
+    return _proj_out(attn, ap, y.dtype, y), k_all, v_all
 
 
 def _prefill_chunks(params: Params, cache: KVCache, tokens: jnp.ndarray,
@@ -576,6 +584,16 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
     # a padded position is routed to no expert
     live = (jnp.arange(s)[None] < length[:, None] if cfg.moe_dropless
             else None)
+
+    def choices(ys):
+        """``new`` with the row's routing recorded, where the tree asks."""
+        if CHOICES in cache:
+            new[CHOICES] = jax.lax.dynamic_update_slice(
+                cache[CHOICES], jnp.where(live[None, ..., None],
+                                          ys["experts"], -1),
+                (0, slot, start[0], 0))
+        return new
+
     if "latent" in cache:
         from .latent import prefill_attention as latent_rows
         logits, carry, ys = layer_stack(
@@ -584,12 +602,7 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
             {"full": tuple(cache[n] for n in LATENT)}, cfg, compute_dtype,
             last, live)
         new.update(zip(LATENT, carry["full"]))
-        if CHOICES in cache:
-            new[CHOICES] = jax.lax.dynamic_update_slice(
-                cache[CHOICES], jnp.where(live[None, ..., None],
-                                          ys["experts"], -1),
-                (0, slot, start[0], 0))
-        return new, logits
+        return choices(ys), logits
 
     def rows(y, lp, i, carry):
         out, k, v = prefill_attention(y, lp["attn"], cfg, positions)
@@ -623,7 +636,7 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
         with jax.named_scope("state_write"):
             new["state"] = put("state", ys["linear"][0])
             new["conv"] = put("conv", ys["linear"][1])
-    return new, logits
+    return choices(ys), logits
 
 
 def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
@@ -721,7 +734,7 @@ def decode_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
                 q, *(jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
                      .reshape(heads) for a in (k_all, v_all)), positions, cfg)
     attn = attn.reshape(n_slots, w, cfg.num_heads * cfg.head_dim)
-    return _proj_out(attn.astype(cast), ap, cast), k_all, v_all
+    return _proj_out(attn.astype(cast), ap, cast, y), k_all, v_all
 
 
 def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,

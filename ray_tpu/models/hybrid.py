@@ -1,18 +1,20 @@
-"""Layers of two kinds in one model: gated-delta-rule linear-attention
-layers beside full-attention layers (``TransformerConfig.layer_pattern``),
-on the serving path.  This file is what is particular to the "linear" kind:
-its weights, its per-slot state and its mixer, for whole rows
-(``linear_prefill``) and for one token a slot (``linear_step``).  The walk
-over the layers, the block's wiring and the full-attention layers are
-``decode.py``'s, which hands these two to ``decode.layer_stack`` where the
-cache tree has a ``state``; ``serve/llm.py`` runs the same calls on it as on
-any cache:
+"""Layers of two kinds in one model: linear-attention layers with a
+recurrent state beside full-attention layers
+(``TransformerConfig.layer_pattern``), on the serving path.  This file is
+what is particular to the "linear" kind: its weights, its per-slot state and
+its mixer, for whole rows (``linear_prefill``) and for one token a slot
+(``linear_step``), and the pattern's parameter tree (``init_blocks``).  The
+walk over the layers, the block's wiring, the full-attention layers and the
+MLP or dropless experts under every layer are ``decode.py``'s, which hands
+these two to ``decode.layer_stack`` where the cache tree has a ``state``;
+``serve/llm.py`` runs the same calls on it as on any cache:
 
 * ``k``, ``v``: [full_layers, slots, max_len, NKV * D], the dense cache of
   ``decode.py`` with rows for the full-attention layers only, written and
   read by ``decode.prefill_attention`` / ``decode_attention``;
 * ``state``: [linear_layers, slots, heads, key_dim, value_dim] float32, the
-  delta rule's state (``ops/gated_delta.py``), constant in the context;
+  delta rule's state (``ops/gated_delta.py``, ``ops/kda.py``), constant in
+  the context;
 * ``conv``: [linear_layers, slots, conv_width - 1, channels], the last
   inputs of the mixer's causal convolution;
 * ``length``: [slots].
@@ -25,9 +27,17 @@ A linear layer's mixer, for input ``x`` (``linear_*`` sizes of the config)::
     o = gated_delta_rule(q, k, v, g, beta)
     y = W_o [rmsnorm_head(o) * silu(W_g x)]
 
+Its variant (Kimi Delta Attention, ``linear_decay_per_channel``) makes ``g``
+one decay a key channel, ``-exp(A_log_head) * softplus(W_f_up W_f_down x +
+dt_bias)`` in ``R^{heads x dk}``, the rule ``ops/kda.py``'s and the gate a
+sigmoid, the decay's and the gate's projections through a bottleneck of
+``linear_gate_rank``.  A full layer may gate its
+attention's output (``attn_output_gate``, ``decode._proj_out``) and have
+heads of a published width (``attn_head_dim``).
+
 Blocks are wired ``h = x + norm(mixer(x)); out = h + norm(mlp(h))``
-(``norm_on_output``) and nothing adds positions (``no_positions``): the
-recurrences and convolutions carry them.
+(``norm_on_output``) or pre-norm, and nothing adds positions
+(``no_positions``): the recurrences and convolutions carry them.
 
 Parameters are stacked per kind with leading dims [periods, layers of the
 kind in a period], so one ``lax.scan`` over periods traces one period's
@@ -70,21 +80,28 @@ def _channels(cfg: TransformerConfig) -> Tuple[int, int]:
 
 def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
     """``params["blocks"]`` of a model with a layer pattern: ``{"linear":
-    ..., "full": ...}``, leaves [periods, layers of the kind a period, ...].
+    ..., "full": ...}``, leaves [periods, layers of the kind a period, ...],
+    and with dropless experts ``"experts"``, the routed experts of every
+    layer in layer order [layers, experts held, ...] (a layer then has the
+    router and the shared expert under ``moe`` where it had ``mlp``).
 
     The decay's parameters are drawn so that ``alpha`` spreads over about
-    (0.9, 1) across heads (``-log alpha`` log-uniform in 0.002..0.08): a
-    state that decays to nothing within a few tokens would make every check
-    of it vacuous.  The gate projections ``w_a`` / ``w_b`` are small for the
-    same reason: they see the residual stream, whose scale grows with depth
-    under norm-on-output wiring."""
+    (0.9, 1) across heads, or across channels where the decay is one a
+    channel (``-log alpha`` log-uniform in 0.002..0.08): a state that decays
+    to nothing within a few tokens would make every check of it vacuous.
+    The gate projections ``w_a`` (``w_f_up``) / ``w_b`` are small for the
+    same reason: they see the residual stream, whose scale grows with
+    depth."""
     h, m, hd = cfg.hidden_size, cfg.mlp_size, cfg.head_dim
     nh, nkv, lh = cfg.num_heads, cfg.num_kv_heads, cfg.linear_num_heads
     kd, vd = _channels(cfg)
     periods, n_lin, n_full = _counts(cfg)
     keys = iter(jax.random.split(key, 24))
+    # what the variants add draws from keys of its own: the scalar-decay
+    # mixer's and the plain full layer's weights are what they were
+    more = iter(jax.random.split(jax.random.fold_in(key, 1), 24))
 
-    def dense(lead, shape, fan_in, gain=1.0):
+    def dense(lead, shape, fan_in, gain=1.0, keys=keys):
         return (jax.random.normal(next(keys), lead + shape, dtype)
                 * (gain * fan_in ** -0.5)).astype(dtype)
 
@@ -92,33 +109,48 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
         return {"scale": jnp.ones(lead + (n,), dtype)}
 
     def mlp(lead):
-        return {"w_gate": dense(lead, (h, m), h), "w_in": dense(lead, (h, m), h),
-                "w_out": dense(lead, (m, h), m)}
+        if not cfg.moe_dropless:
+            return {"mlp": {"w_gate": dense(lead, (h, m), h),
+                            "w_in": dense(lead, (h, m), h),
+                            "w_out": dense(lead, (m, h), m)}}
+        e, sm = cfg.num_experts, cfg.shared_experts * cfg.expert_mlp_size
+        moe = {"router": dense(lead, (h, e), h, keys=more),
+               "bias": jnp.zeros(lead + (e,), dtype)}
+        if sm:
+            moe.update(shared_gate=dense(lead, (h, sm), h, keys=more),
+                       shared_in=dense(lead, (h, sm), h, keys=more),
+                       shared_out=dense(lead, (sm, h), sm, keys=more))
+        return {"moe": moe}
 
     blocks: Params = {}
     if n_lin:
         lead = (periods, n_lin)
+        rank, decays = cfg.linear_gate_rank, (
+            kd if cfg.linear_decay_per_channel else lh)
         rate = jnp.exp(jax.random.uniform(
-            next(keys), lead + (lh,), jnp.float32,
+            next(keys), lead + (decays,), jnp.float32,
             jnp.log(0.002), jnp.log(0.08)))
-        blocks["linear"] = {
-            "mixer": {
-                "w_qkv": dense(lead, (h, 2 * kd + vd), h),
-                "conv_w": dense(lead, (cfg.linear_conv_width, 2 * kd + vd),
-                                cfg.linear_conv_width),
-                "w_a": dense(lead, (h, lh), h, 0.1),
-                "w_b": dense(lead, (h, lh), h, 0.5),
-                "A_log": jnp.zeros(lead + (lh,), dtype),
-                # softplus^-1(rate), so that alpha = exp(-rate) at w_a x = 0
-                "dt_bias": jnp.log(jnp.expm1(rate)).astype(dtype),
-                "w_g": dense(lead, (h, vd), h),
-                "o_norm": ones(lead, cfg.linear_value_dim),
-                "w_o": dense(lead, (vd, h), vd),
-            },
-            "mixer_norm": ones(lead, h),
-            "mlp": mlp(lead),
-            "mlp_norm": ones(lead, h),
-        }
+        mixer = {"w_qkv": dense(lead, (h, 2 * kd + vd), h),
+                 "conv_w": dense(lead, (cfg.linear_conv_width, 2 * kd + vd),
+                                 cfg.linear_conv_width)}
+        if cfg.linear_decay_per_channel:
+            mixer.update(w_f_down=dense(lead, (h, rank), h, keys=more),
+                         w_f_up=dense(lead, (rank, kd), rank, 0.1, more),
+                         w_g_down=dense(lead, (h, rank), h, keys=more),
+                         w_g_up=dense(lead, (rank, vd), rank, keys=more))
+        else:
+            mixer["w_a"] = dense(lead, (h, lh), h, 0.1)
+        mixer.update(
+            w_b=dense(lead, (h, lh), h, 0.5),
+            A_log=jnp.zeros(lead + (lh,), dtype),
+            # softplus^-1(rate), so that alpha = exp(-rate) at w_a x = 0
+            dt_bias=jnp.log(jnp.expm1(rate)).astype(dtype))
+        if not cfg.linear_decay_per_channel:
+            mixer["w_g"] = dense(lead, (h, vd), h)
+        mixer.update(o_norm=ones(lead, cfg.linear_value_dim),
+                     w_o=dense(lead, (vd, h), vd))
+        blocks["linear"] = {"mixer": mixer, "mixer_norm": ones(lead, h),
+                            **mlp(lead), "mlp_norm": ones(lead, h)}
     if n_full:
         lead = (periods, n_full)
         attn = {
@@ -127,11 +159,22 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
             "wv": dense(lead, (h, nkv * hd), h),
             "wo": dense(lead, (nh * hd, h), nh * hd),
         }
+        if cfg.attn_output_gate:
+            attn["w_gate"] = dense(lead, (h, nh * hd), h, keys=more)
         if cfg.qk_norm:
             attn["q_norm"] = ones(lead, nh * hd)
             attn["k_norm"] = ones(lead, nkv * hd)
         blocks["full"] = {"attn": attn, "attn_norm": ones(lead, h),
-                          "mlp": mlp(lead), "mlp_norm": ones(lead, h)}
+                          **mlp(lead), "mlp_norm": ones(lead, h)}
+    if cfg.moe_dropless:
+        from .latent import expert_stack
+        em = cfg.expert_mlp_size
+        blocks["experts"] = {
+            name: expert_stack(next(more), cfg, cfg.num_layers, shape, fan,
+                               dtype)
+            for name, shape, fan in (("w_gate", (h, em), h),
+                                     ("w_in", (h, em), h),
+                                     ("w_out", (em, h), em))}
     return blocks
 
 
@@ -153,44 +196,77 @@ def init_state(cfg: TransformerConfig, num_slots: int,
 # The linear mixer's pieces
 # ---------------------------------------------------------------------------
 
-@jax.named_scope("gdn")
+def _scope(cfg: TransformerConfig, part: str = "") -> str:
+    """The named scope of the mixer's pieces: ``gdn`` / ``gdn_conv`` with a
+    decay a head, ``kda`` / ``kda_conv`` / ``kda_gate`` with one a channel."""
+    return ("kda" if cfg.linear_decay_per_channel else "gdn") + part
+
+
 def _gates(x, mp, cfg: TransformerConfig, live=None):
-    """x [..., H] -> (g, beta) [..., heads] float32; where ``live`` is given
-    and false the step is the identity on the state (g 0, beta 0)."""
+    """x [..., H] -> (g [..., heads] or, with a decay a channel, [..., heads,
+    dk]; beta [..., heads]), float32; where ``live`` is given and false the
+    step is the identity on the state (g 0, beta 0)."""
     cast = x.dtype
-    a = (x @ mp["w_a"].astype(cast)).astype(jnp.float32)
-    g = -jnp.exp(mp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
-        a + mp["dt_bias"].astype(jnp.float32))
-    beta = jax.nn.sigmoid((x @ mp["w_b"].astype(cast)).astype(jnp.float32))
-    if cfg.linear_neg_eigval:
-        beta = 2.0 * beta
-    if live is None:
-        return g, beta
-    return jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
+    with jax.named_scope(_scope(cfg, "_gate" if cfg.linear_decay_per_channel
+                                else "")):
+        if cfg.linear_decay_per_channel:
+            a = (x @ mp["w_f_down"].astype(cast)) @ mp["w_f_up"].astype(cast)
+        else:
+            a = x @ mp["w_a"].astype(cast)
+        g = jax.nn.softplus(a.astype(jnp.float32)
+                            + mp["dt_bias"].astype(jnp.float32))
+        rate = -jnp.exp(mp["A_log"].astype(jnp.float32))
+        if cfg.linear_decay_per_channel:        # A_log a head, g a channel
+            g = g.reshape(g.shape[:-1] + (cfg.linear_num_heads,
+                                          cfg.linear_key_dim))
+            rate = rate[..., None]
+        g = rate * g
+        beta = jax.nn.sigmoid(
+            (x @ mp["w_b"].astype(cast)).astype(jnp.float32))
+        if cfg.linear_neg_eigval:
+            beta = 2.0 * beta
+        if live is None:
+            return g, beta
+        return (jnp.where(live[..., None] if g.ndim > beta.ndim else live,
+                          g, 0.0), jnp.where(live, beta, 0.0))
 
 
-@jax.named_scope("gdn")
 def _split_heads(y, cfg: TransformerConfig):
     """Convolved channels [..., 2 kd + vd] -> q, k [..., heads, dk], v [...,
     heads, dv]: q and k l2-normalised per head, q scaled by dk^-0.5."""
     kd, _ = _channels(cfg)
     nh, dk = cfg.linear_num_heads, cfg.linear_key_dim
     lead = y.shape[:-1]
-    q = y[..., :kd].reshape(lead + (nh, dk)).astype(jnp.float32)
-    k = y[..., kd:2 * kd].reshape(lead + (nh, dk)).astype(jnp.float32)
-    v = y[..., 2 * kd:].reshape(lead + (nh, cfg.linear_value_dim))
-    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + L2_EPS)
-    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + L2_EPS)
-    return (q * dk ** -0.5).astype(y.dtype), k.astype(y.dtype), v
+    with jax.named_scope(_scope(cfg)):
+        q = y[..., :kd].reshape(lead + (nh, dk)).astype(jnp.float32)
+        k = y[..., kd:2 * kd].reshape(lead + (nh, dk)).astype(jnp.float32)
+        v = y[..., 2 * kd:].reshape(lead + (nh, cfg.linear_value_dim))
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + L2_EPS)
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + L2_EPS)
+        return (q * dk ** -0.5).astype(y.dtype), k.astype(y.dtype), v
 
 
-@jax.named_scope("gdn")
 def _mixer_out(o, x, mp, cfg: TransformerConfig):
-    """o [..., heads, dv], x [..., H] -> W_o [rmsnorm_head(o) * silu(W_g x)]."""
+    """o [..., heads, dv], x [..., H] -> W_o [rmsnorm_head(o) * gate(x)]:
+    the gate SiLU of one projection or, in the variant with a decay a
+    channel, a sigmoid of two through a bottleneck."""
     cast = x.dtype
-    gate = jax.nn.silu(x @ mp["w_g"].astype(cast))
-    y = _norm(o, mp["o_norm"], cfg).astype(cast).reshape(gate.shape) * gate
-    return y @ mp["w_o"].astype(cast)
+    with jax.named_scope(_scope(cfg)):
+        if cfg.linear_decay_per_channel:
+            gate = jax.nn.sigmoid((x @ mp["w_g_down"].astype(cast))
+                                  @ mp["w_g_up"].astype(cast))
+        else:
+            gate = jax.nn.silu(x @ mp["w_g"].astype(cast))
+        y = _norm(o, mp["o_norm"], cfg).astype(cast).reshape(gate.shape) * gate
+        return y @ mp["w_o"].astype(cast)
+
+
+def _rule(cfg: TransformerConfig):
+    """The delta rule's two kernels for this configuration's decay."""
+    if cfg.linear_decay_per_channel:
+        from ..ops import kda
+        return kda.kda_chunk_fwd, kda.kda_recurrent_step
+    return gated_delta.gdn_chunk_fwd, gated_delta.gdn_recurrent_step
 
 
 # ---------------------------------------------------------------------------
@@ -204,9 +280,9 @@ def linear_prefill(x, mp, cfg: TransformerConfig, lengths):
     s, cast, width = x.shape[1], x.dtype, cfg.linear_conv_width
     # where each row's convolution tail sits: its last width-1 inputs
     tail_pos = lengths[:, None] - (width - 1) + jnp.arange(width - 1)[None]
-    with jax.named_scope("gdn"):
+    with jax.named_scope(_scope(cfg)):
         proj = x @ mp["w_qkv"].astype(cast)                     # [B, S, C]
-    with jax.named_scope("gdn_conv"):
+    with jax.named_scope(_scope(cfg, "_conv")):
         padded = jnp.pad(proj, ((0, 0), (width - 1, 0), (0, 0)))
         conv = sum(padded[:, j:j + s] * mp["conv_w"][j].astype(cast)
                    for j in range(width))
@@ -216,9 +292,9 @@ def linear_prefill(x, mp, cfg: TransformerConfig, lengths):
         tail = jnp.where((tail_pos >= 0)[..., None], tail, 0)
     q, k, v = _split_heads(conv, cfg)
     g, beta = _gates(x, mp, cfg)
-    with jax.named_scope("gdn"):
+    with jax.named_scope(_scope(cfg)):
         # positions at or beyond a row's length leave its state alone
-        o, state = gated_delta.gdn_chunk_fwd(q, k, v, g, beta, lengths)
+        o, state = _rule(cfg)[0](q, k, v, g, beta, lengths)
     return _mixer_out(o, x, mp, cfg), state, tail
 
 
@@ -230,11 +306,11 @@ def linear_step(x, mp, cfg: TransformerConfig, li, state, conv, active):
     H], state, conv)."""
     cast, width = x.dtype, cfg.linear_conv_width
     y, live = x[:, 0], active[:, None]                 # [slots, H], [slots, 1]
-    with jax.named_scope("gdn"):
+    with jax.named_scope(_scope(cfg)):
         proj = y @ mp["w_qkv"].astype(cast)                     # [slots, C]
     with jax.named_scope("state_read"):
         tail = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
-    with jax.named_scope("gdn_conv"):
+    with jax.named_scope(_scope(cfg, "_conv")):
         window = jnp.concatenate([tail.astype(cast), proj[:, None]], 1)
         mixed = jax.nn.silu(sum(window[:, j] * mp["conv_w"][j].astype(cast)
                                 for j in range(width)))
@@ -244,6 +320,6 @@ def linear_step(x, mp, cfg: TransformerConfig, li, state, conv, active):
                 conv.dtype), tail), li, 0)
     q, k, v = _split_heads(mixed, cfg)
     g, beta = _gates(y, mp, cfg, live)
-    with jax.named_scope("gdn"):
-        state, o = gated_delta.gdn_recurrent_step(state, li, q, k, v, g, beta)
+    with jax.named_scope(_scope(cfg)):
+        state, o = _rule(cfg)[1](state, li, q, k, v, g, beta)
     return _mixer_out(o, y, mp, cfg)[:, None], state, conv

@@ -142,6 +142,18 @@ def hc_widths(cfg: TransformerConfig) -> Tuple[int, int]:
     return n * cfg.hidden_size, 2 * n + n * n
 
 
+def expert_stack(key, cfg: TransformerConfig, layers: int, shape, fan_in,
+                 dtype):
+    """One matrix of every held expert of ``layers`` layers, [layers,
+    experts held, *shape], drawn a layer at a time: one [layers, experts,
+    ...] draw would hold its float32 bits beside the result.  Of the
+    router's experts the ones this holder has (``cfg.experts_held``)."""
+    return jax.lax.map(
+        lambda k: (jax.random.normal(k, (cfg.experts_held,) + shape, dtype)
+                   * fan_in ** -0.5).astype(dtype),
+        jax.random.split(key, layers))
+
+
 def _init_group(key, cfg: TransformerConfig, layers: int, sparse: bool,
                 dtype) -> Params:
     """``layers`` layers of one group, leaves stacked [layers, ...]."""
@@ -179,14 +191,7 @@ def _init_group(key, cfg: TransformerConfig, layers: int, sparse: bool,
         e, em = cfg.num_experts, cfg.expert_mlp_size
 
         def experts(shape, fan_in):
-            # a layer at a time: one [layers, experts, ...] draw would hold
-            # its float32 bits beside the result.  Of the router's ``e``
-            # experts the ones this holder has (``cfg.experts_held``).
-            return jax.lax.map(
-                lambda k: (jax.random.normal(
-                    k, (cfg.experts_held,) + shape, dtype)
-                    * fan_in ** -0.5).astype(dtype),
-                jax.random.split(next(keys), layers))
+            return expert_stack(next(keys), cfg, layers, shape, fan_in, dtype)
 
         group["moe"] = {"router": dense((h, e), h),
                         "bias": jnp.zeros(lead + (e,), dtype),

@@ -164,7 +164,8 @@ class LLMEngine:
         self.cfg = cfg
         if cfg.layer_pattern:
             # a cache of two kinds of state (models/hybrid.py) is dense,
-            # unsharded and decoded one token a step
+            # unsharded and decoded one token a step, whatever MLP lies
+            # under its layers
             for on, what in ((paged, "paged=True: the page arena holds keys "
                               "and values only"),
                              (spec_decode_enabled, "spec_decode_enabled: a "
@@ -193,10 +194,10 @@ class LLMEngine:
                     raise ValueError(
                         f"{', '.join(cfg.latent_tree)} do not run with "
                         f"{what}")
-            if cfg.share_by_position:
-                raise ValueError(
-                    "share_by_position is the train step's: a served "
-                    "share holds the experts from expert_start on")
+        if cfg.share_by_position:
+            raise ValueError(
+                "share_by_position is the train step's: a served "
+                "share holds the experts from expert_start on")
         self.max_len = max_len or cfg.max_seq_len
         self.num_slots = num_slots
         self.buckets = tuple(b for b in buckets if b <= self.max_len)
@@ -653,8 +654,11 @@ class LLMEngine:
         self.admit_chunks += prog.chunks
         self.admit_rows_chunked += len(reqs) if prog.chunks else 0
         self.admit_tokens_padded += sum(n for _r, n in prog.rows) - tokens_real
+        # (of a share of the experts, the part that lands on those held
+        # under uniform routing: the admit program returns no count)
         self.moe_assignments_prefill += (
-            tokens_real * self.cfg.experts_per_token * self.cfg.expert_layers)
+            tokens_real * self.cfg.experts_per_token * self.cfg.expert_layers
+            * self.cfg.experts_held // self.cfg.num_experts)
         self.admitted_requests += len(reqs)
         causes = []
         for r in reqs:

@@ -333,10 +333,11 @@ def test_the_kind_refuses_what_the_block_cannot_express(kind, change, match):
 @pytest.mark.parametrize("kw,match", [
     (dict(num_layers=5), "whole periods"),
     (dict(layer_pattern=("linear", "window")), "kinds"),
-    (dict(norm_on_output=False), "norm_on_output"),
+    (dict(linear_decay_per_channel=True), "linear_gate_rank"),
     (dict(linear_key_dim=0), "linear_key_dim"),
     (dict(layer_pattern=()), "layer_pattern only"),
-], ids=["half-a-period", "unknown-kind", "pre-norm", "no-mixer-sizes",
+], ids=["half-a-period", "unknown-kind", "decay-a-channel-without-its-rank",
+        "no-mixer-sizes",
         "norms-without-a-pattern"])
 def test_config_refuses_what_the_hybrid_blocks_are_not(kw, match):
     from ray_tpu.models.config import TransformerConfig

@@ -503,3 +503,78 @@ def test_the_train_step_carries_the_latent_and_expert_scopes(latent_cfg):
     assert {"attn", "mlp", "norm", "loss", "optimizer", "mla_down", "mla_up",
             "moe_route", "moe_sort", "moe_experts", "moe_shared",
             "moe_combine", "transpose", "jvp"} <= _scopes(lowered)
+
+
+# ---- a decay a channel, a gated full layer, experts under a pattern (PR 44)
+
+@pytest.fixture(scope="module")
+def kda_cfg():
+    from ray_tpu.models.config import TransformerConfig
+    return TransformerConfig(
+        vocab_size=256, num_layers=4, hidden_size=64, num_heads=4,
+        num_kv_heads=2, mlp_size=128, max_seq_len=64, use_rope=False,
+        no_positions=True, attn_head_dim=32, attn_output_gate=True,
+        layer_pattern=("full", "linear", "linear", "linear"),
+        linear_num_heads=4, linear_key_dim=16, linear_value_dim=16,
+        linear_neg_eigval=True, linear_decay_per_channel=True,
+        linear_gate_rank=16, moe_dropless=True,
+        num_experts=16, experts_per_token=4, expert_mlp_size=32,
+        shared_experts=1, expert_start=4, experts_held=4)
+
+
+def test_the_kda_kernels_are_named():
+    """The names a device trace shows (``kda_chunk_fwd [pallas]``,
+    ``kda_recurrent_step [pallas]``), which the benchmark's kda_* readers
+    spell out for themselves."""
+    from ray_tpu.ops import kda
+
+    assert kda.KERNEL_KDA_CHUNK_FWD == "kda_chunk_fwd"
+    assert kda.KERNEL_KDA_RECURRENT_STEP == "kda_recurrent_step"
+    readers = _reader("_kda.py")
+    assert (readers.CHUNK_FWD, readers.RECURRENT_STEP, readers.MOE_GMM) == (
+        kda.KERNEL_KDA_CHUNK_FWD, kda.KERNEL_KDA_RECURRENT_STEP, "moe_gmm")
+    assert _reader("kda_moe_kernels_device_share.py").KERNELS == (
+        "kda_chunk_fwd", "kda_recurrent_step", "moe_gmm")
+    q = jnp.ones((1, 64, 2, 8), jnp.float32)
+    v = jnp.ones((1, 64, 2, 16), jnp.float32)
+    g = jnp.zeros((1, 64, 2, 8), jnp.float32)
+    chunk = jax.make_jaxpr(lambda *a: kda.kda_chunk_fwd(
+        *a, interpret=True))(q, q, v, g, g[..., 0])
+    assert "kda_chunk_fwd" in str(chunk)
+    step = jax.make_jaxpr(lambda *a: kda.kda_recurrent_step(
+        *a, interpret=True))(jnp.zeros((2, 1, 2, 8, 16)), jnp.int32(1),
+                             q[:, 0], q[:, 0], v[:, 0], g[:, 0], g[:, 0, :, 0])
+    assert "kda_recurrent_step" in str(step)
+
+
+def test_kda_serve_programs_carry_their_scopes(kda_cfg):
+    """The KDA mixer's pieces under ``kda`` / ``kda_conv`` / ``kda_gate``
+    (state reads and writes keep ``state_read`` / ``state_write``), the
+    gated full layer's gate under ``attn``, the experts' under ``moe_*``;
+    none of the scalar-decay mixer's ``gdn`` scopes."""
+    eng = _engine(kda_cfg)
+    try:
+        decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
+        assert _module_name(decode) == "jit_engine_decode"
+        shared = {"attn", "norm", "lm_head", "kv_write", "kda", "kda_conv",
+                  "kda_gate", "state_write", "moe_route", "moe_sort",
+                  "moe_experts", "moe_shared", "moe_combine"}
+        assert shared | {"kv_read", "state_read"} <= _scopes(decode)
+        assert not {"gdn", "gdn_conv"} & _scopes(decode)
+        admit = eng._prefill_fn(16).lower(
+            eng.params, eng.cache, eng._state, *eng._admit_arrays([], 16, []))
+        assert _module_name(admit) == "jit_admit_fn"
+        assert shared <= _scopes(admit)
+        # the gate's sigmoid sits under the full layer's ``attn``
+        assert re.search(r'attn/logistic', decode.as_text(debug_info=True))
+        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
+        stats = {**eng.counters(), **eng.breakdown()}
+        assert {"cache_kv_bytes", "cache_state_bytes", "linear_layers",
+                "full_layers", "experts_held", "expert_layers",
+                "moe_assignments", "moe_experts_touched",
+                "moe_expert_layer_steps", "moe_assignments_prefill"} <= set(
+                    stats)
+        assert (stats["experts_held"], stats["expert_layers"],
+                stats["linear_layers"], stats["full_layers"]) == (4, 4, 3, 1)
+    finally:
+        eng.shutdown()
