@@ -64,9 +64,6 @@ from .config import TransformerConfig
 from .transformer import Params, _norm
 
 F32 = jnp.float32
-#: the flash kernel's head widths (``ops.attention.mha``): the expanded
-#: form's heads are padded with zeros to the next of them
-FLASH_WIDTHS = (64, 128, 256)
 #: a prefill row is padded to whole flash blocks (causality keeps the
 #: padding unread)
 FLASH_BLOCK = 512
@@ -309,23 +306,21 @@ def _expanded(q_nope, q_rope, c_kv, k_r, ap, cfg: TransformerConfig):
         w_uk, w_uv = _w_ukv(ap, cfg, cast)
         k_nope = jnp.einsum("bsc,chd->bshd", c_kv, w_uk)
         v = jnp.einsum("bsc,chd->bshd", c_kv, w_uv)
-    # heads of one width for the kernel, the sequence in whole blocks; the
-    # kernel scales by its own width, so the query carries the difference
-    width = next(w for w in FLASH_WIDTHS if w >= cfg.qk_head_dim)
+    # the heads at their own two widths, the sequence in whole blocks; the
+    # kernel scales by the query's width, so the query carries the difference
     seq = -(-s // FLASH_BLOCK) * FLASH_BLOCK if s >= 2 * FLASH_BLOCK else s
 
     def fit(a):
-        return jnp.pad(a, ((0, 0), (0, seq - s), (0, 0),
-                           (0, width - a.shape[-1])))
+        return jnp.pad(a, ((0, 0), (0, seq - s), (0, 0), (0, 0)))
 
     q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(F32) \
-        * (softmax_scale(cfg) * width ** 0.5)
+        * (softmax_scale(cfg) * cfg.qk_head_dim ** 0.5)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_r[:, :, None], (b, s, nh, k_r.shape[-1]))],
         axis=-1)
     with jax.named_scope("attn"):
         attn = mha(fit(q.astype(cast)), fit(k), fit(v), causal=True)
-    attn = attn[:, :s, :, :cfg.v_head_dim].reshape(b, s, -1)
+    attn = attn[:, :s].reshape(b, s, -1)
     with jax.named_scope("attn"):
         return attn @ ap["wo"].astype(cast)
 

@@ -95,15 +95,19 @@ def mha(q, k, v, causal: bool = True, logit_softcap: float = 0.0,
         use_flash: Optional[bool] = None, mesh=None,
         batch_axes: Tuple[str, ...] = ("dp", "fsdp")):
     """Dispatch between the Pallas flash kernel (TPU, long seq) and plain XLA.
+    q, k: [B, S, heads, Dqk]; v: [B, S, KV, Dv], as wide as q or not.
 
     ``use_flash=None`` chooses from what it can observe: the backend and the
     shape.  ``mesh``/``batch_axes`` go to the kernel, which must be
     shard_mapped by hand on a multi-device mesh (see ``flash_attention``)."""
     if use_flash is None:
         from .flash_attention import flash_supported
-        # The flash kernel does not implement logit softcap.
+        # The flash kernel does not implement logit softcap; its heads are
+        # whole multiples of 64 lanes, the value's and the query's each.
         use_flash = (jax.default_backend() == "tpu" and q.shape[1] >= 1024
-                     and q.shape[-1] in (64, 128, 256) and logit_softcap == 0.0
+                     and all(d in (64, 128, 192, 256)
+                             for d in (q.shape[-1], v.shape[-1]))
+                     and logit_softcap == 0.0
                      and flash_supported(q.shape[1], k.shape[1], q.shape[2],
                                          k.shape[2]) is None)
     if use_flash:

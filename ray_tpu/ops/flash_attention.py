@@ -1,4 +1,4 @@
-"""Pallas TPU flash attention (forward kernel + memory-bounded backward).
+"""Pallas TPU flash attention: a forward kernel and two backward kernels.
 
 The reference has no TPU kernels at all (its attention lives in external torch
 models); this is greenfield TPU-first code (SURVEY §5.7, §7 stance).
@@ -15,11 +15,17 @@ Design:
   ``h // (num_q_heads / num_kv_heads)`` so grouped-query K/V blocks are read
   in place; the `repeat_kv` copy the plain path makes is skipped.
 * **Backward** recomputes attention blockwise from the saved (out, lse)
-  residuals — standard flash-attention recurrence — as a `lax.scan` over KV
-  blocks in plain JAX.  Peak memory O(S·block) like the forward; XLA fuses the
-  per-block matmuls onto the MXU.  (A Pallas backward kernel is a further
-  speedup, not a correctness need: training-step wall time is dominated by
-  the big MLP matmuls.)
+  residuals — standard flash-attention recurrence — in two Pallas kernels,
+  `flash_dq` (a q block's gradient over its KV blocks) and `flash_dkv` (a KV
+  block's gradients over its q blocks and the query heads that share it).
+  Per-program VMEM is O(block) like the forward.  Blocks that do not tile for
+  them (under 128 positions) fall back to `_bwd_blockwise`, the same
+  recurrence as a `lax.scan` over KV blocks in plain JAX: the CPU tests'
+  small shapes.
+* **Two widths**: queries and keys are `d_qk` lanes wide, values, the output
+  and its gradient `d_v`; each kernel reads both off its operands.  A latent
+  head's 192 / 128 runs as it is; where the two are equal (every other
+  caller) the kernels lower to what one width gave.
 
 Numerics: logits and softmax statistics in f32 (MXU accumulates f32 via
 ``preferred_element_type``); probabilities cast back to the input dtype for
@@ -53,12 +59,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
     that is data (``_fwd_rows_kernel``); None where query i sits at key i."""
     qi = pl.program_id(2)
     block_q = q_ref.shape[2]
-    d = q_ref.shape[3]
-    q = q_ref[0, 0]                                   # [block_q, d]
+    q = q_ref[0, 0]                                   # [block_q, d_qk]
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[3]), jnp.float32)
 
     def first():      # this q-block's first position among the keys
         return qi * block_q if q_start is None else q_start + qi * block_q
@@ -77,8 +82,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
 
     def body(j, carry):
         m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(j * block_kv, block_kv), :]     # [block_kv, d]
-        v = v_ref[0, 0, pl.ds(j * block_kv, block_kv), :]
+        k = k_ref[0, 0, pl.ds(j * block_kv, block_kv), :]     # [bkv, d_qk]
+        v = v_ref[0, 0, pl.ds(j * block_kv, block_kv), :]     # [bkv, d_v]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # [bq, bkv]
@@ -105,13 +110,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
                                      (8, block_q))
 
 
-def _fwd_vmem(kv_len: int, d: int, dtype) -> dict:
+def _fwd_vmem(kv_len: int, d_qk: int, d_v: int, dtype) -> dict:
     """What a forward call passes the compiler for a head's whole K and V,
-    ``kv_len`` positions each, in VMEM double-buffered: past the compiler's
-    default of 16 MiB (8,192 positions of 256 lanes are 4 MiB each) the
-    kernel asks for what it needs; below, nothing is passed and the call
-    compiles as it always did."""
-    resident = 4 * kv_len * (-(-d // 128) * 128) * jnp.dtype(dtype).itemsize
+    ``kv_len`` positions each at its own width, in VMEM double-buffered: past
+    the compiler's default of 16 MiB (8,192 positions of 256 lanes are 4 MiB,
+    of 128 lanes 2 MiB) the kernel asks for what it needs; below, nothing is
+    passed and the call compiles as it always did."""
+    lanes = sum(-(-d // 128) * 128 for d in (d_qk, d_v))
+    resident = 2 * kv_len * lanes * jnp.dtype(dtype).itemsize
     return ({} if resident <= FWD_VMEM_DEFAULT else {
         "compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=resident + FWD_VMEM_DEFAULT)})
@@ -119,8 +125,10 @@ def _fwd_vmem(kv_len: int, d: int, dtype) -> dict:
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
                interpret: bool):
-    """q: [B, H, S, D], k/v: [B, KV, S, D] -> (out [B, H, S, D], lse [B, H, S])."""
+    """q: [B, H, S, Dqk], k: [B, KV, S, Dqk], v: [B, KV, S, Dv] -> (out [B,
+    H, S, Dv], lse [B, H, S])."""
     b, h, s, d = q.shape
+    d_v = v.shape[3]
     kv_heads = k.shape[1]
     reps = h // kv_heads
     scale = d ** -0.5
@@ -133,18 +141,20 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        **_fwd_vmem(s, d, k.dtype),
+        **_fwd_vmem(s, d, d_v, k.dtype),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // reps, 0, 0)),
-            pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // reps, 0, 0)),
+            pl.BlockSpec((1, 1, s, d_v),
+                         lambda bi, hi, qi: (bi, hi // reps, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, d_v),
+                         lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, 8, block_q), lambda bi, hi, qi: (bi, hi, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, 8, s), jnp.float32),
         ],
         interpret=interpret,
@@ -186,7 +196,7 @@ def _flash_fwd_rows(q, k_all, v_all, at, num_heads: int, kv_len: int,
         ),
         out_shape=[jax.ShapeDtypeStruct((1, 1) + q.shape, q.dtype),
                    jax.ShapeDtypeStruct((1, num_heads, 8, w), jnp.float32)],
-        **_fwd_vmem(kv_len, d, k_all.dtype),
+        **_fwd_vmem(kv_len, d, d, k_all.dtype),
         interpret=interpret,
         name=KERNEL_FLASH_ROWS,
     )(at, q[None, None], k_all, v_all)
@@ -196,10 +206,12 @@ def _flash_fwd_rows(q, k_all, v_all, at, num_heads: int, kv_len: int,
 def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
     """Flash backward, recompute-based, as a scan over KV blocks.
 
-    q/out/g: [B, H, S, D]; k/v: [B, KV, S, D]; lse: [B, H, S].
-    Returns (dq, dk, dv) with dk/dv in kv-head layout.
+    q: [B, H, S, Dqk]; out/g: [B, H, S, Dv]; k: [B, KV, S, Dqk]; v: [B, KV,
+    S, Dv]; lse: [B, H, S].  Returns (dq, dk, dv) with dk/dv in kv-head
+    layout.
     """
     b, h, s, d = q.shape
+    d_v = v.shape[3]
     kv_heads = k.shape[1]
     reps = h // kv_heads
     scale = d ** -0.5
@@ -213,10 +225,10 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
     q_pos = jnp.arange(s)
 
     kb = jnp.moveaxis(k.reshape(b, kv_heads, n_blocks, block_kv, d), 2, 0)
-    vb = jnp.moveaxis(v.reshape(b, kv_heads, n_blocks, block_kv, d), 2, 0)
+    vb = jnp.moveaxis(v.reshape(b, kv_heads, n_blocks, block_kv, d_v), 2, 0)
 
     def per_block(j, kj, vj):
-        # kj/vj: [B, KV, block_kv, D] -> repeat to q heads.
+        # kj: [B, KV, block_kv, Dqk], vj: [.., Dv] -> repeat to q heads.
         kjh = jnp.repeat(kj, reps, axis=1) if reps > 1 else kj
         vjh = jnp.repeat(vj, reps, axis=1) if reps > 1 else vj
         sj = jnp.einsum("bhqd,bhkd->bhqk", qf, kjh.astype(jnp.float32)) * scale
@@ -232,7 +244,7 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
         # fold q-head grads back to kv heads (GQA)
         dk_h = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
         if reps > 1:
-            dv_h = dv_h.reshape(b, kv_heads, reps, block_kv, d).sum(2)
+            dv_h = dv_h.reshape(b, kv_heads, reps, block_kv, d_v).sum(2)
             dk_h = dk_h.reshape(b, kv_heads, reps, block_kv, d).sum(2)
         return dq_j, dk_h, dv_h
 
@@ -246,7 +258,7 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
     dq, (dkb, dvb) = jax.lax.scan(
         scan_fn, jnp.zeros_like(qf), (jnp.arange(n_blocks), kb, vb))
     dk = jnp.moveaxis(dkb, 0, 2).reshape(b, kv_heads, s, d)
-    dv = jnp.moveaxis(dvb, 0, 2).reshape(b, kv_heads, s, d)
+    dv = jnp.moveaxis(dvb, 0, 2).reshape(b, kv_heads, s, d_v)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -258,7 +270,8 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
 # block_q(lanes)] — so the per-q-row statistics (lse, delta) enter as natural
 # [1, block_q] rows and broadcast over sublanes, which Mosaic supports
 # directly; no lane-replicated stat arrays and no [1,N]->[N,1] relayout.
-# Every matmul contracts either d or a block dim, all MXU-shaped.
+# Every matmul contracts either a head width (d_qk for the scores and dq/dk,
+# d_v for dp and dv) or a block dim, all MXU-shaped.
 #
 # Grids iterate over BOTH block axes (q and kv) with an f32 VMEM scratch
 # accumulator initialised on the first visit of an output tile and flushed on
@@ -271,8 +284,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
     """Grid (b, h, n_q, n_kv): accumulate one q-block's dq over KV blocks."""
     qi, kj = pl.program_id(2), pl.program_id(3)
     n_kv = pl.num_programs(3)
-    block_q, d = q_ref.shape[2], q_ref.shape[3]
-    block_kv = k_ref.shape[2]
+    block_q, block_kv = q_ref.shape[2], k_ref.shape[2]
 
     @pl.when(kj == 0)
     def _init():
@@ -284,10 +296,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                               # [bq, d]
-        g = g_ref[0, 0]                               # [bq, d]
-        k = k_ref[0, 0]                               # [bkv, d]
-        v = v_ref[0, 0]
+        q = q_ref[0, 0]                               # [bq, d_qk]
+        g = g_ref[0, 0]                               # [bq, d_v]
+        k = k_ref[0, 0]                               # [bkv, d_qk]
+        v = v_ref[0, 0]                               # [bkv, d_v]
         lse = lse_ref[0, 0]                           # [1, bq] f32
         dlt = dlt_ref[0, 0]
         s_t = jax.lax.dot_general(
@@ -306,7 +318,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
         ds_t = p_t * (dp_t - dlt) * scale
         acc_ref[...] += jax.lax.dot_general(
             ds_t.astype(k.dtype), k, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, d]
+            preferred_element_type=jnp.float32)               # [bq, d_qk]
 
     @pl.when(kj == n_kv - 1)
     def _flush():
@@ -322,8 +334,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
     which is what makes the scratch init/flush pattern valid."""
     ki, r, qj = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     n_rep, n_q = pl.num_programs(3), pl.num_programs(4)
-    block_kv, d = k_ref.shape[2], k_ref.shape[3]
-    block_q = q_ref.shape[2]
+    block_kv, block_q = k_ref.shape[2], q_ref.shape[2]
 
     @pl.when((r == 0) & (qj == 0))
     def _init():
@@ -335,10 +346,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                               # [bq, d]
-        g = g_ref[0, 0]
-        k = k_ref[0, 0]                               # [bkv, d]
-        v = v_ref[0, 0]
+        q = q_ref[0, 0]                               # [bq, d_qk]
+        g = g_ref[0, 0]                               # [bq, d_v]
+        k = k_ref[0, 0]                               # [bkv, d_qk]
+        v = v_ref[0, 0]                               # [bkv, d_v]
         lse = lse_ref[0, 0]                           # [1, bq] f32
         dlt = dlt_ref[0, 0]
         s_t = jax.lax.dot_general(
@@ -353,14 +364,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
         p_t = jnp.exp(s_t - lse)
         dv_acc[...] += jax.lax.dot_general(
             p_t.astype(g.dtype), g, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bkv, d]
+            preferred_element_type=jnp.float32)               # [bkv, d_v]
         dp_t = jax.lax.dot_general(
             v, g, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds_t = p_t * (dp_t - dlt) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bkv, d]
+            preferred_element_type=jnp.float32)               # [bkv, d_qk]
 
     @pl.when((r == n_rep - 1) & (qj == n_q - 1))
     def _flush():
@@ -370,8 +381,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
 
 def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
                       block_kv: int, interpret: bool):
-    """Pallas flash backward: (dq, dk, dv), dk/dv in kv-head layout."""
+    """Pallas flash backward: (dq, dk, dv), dk/dv in kv-head layout.  q, k
+    (and dq, dk) are ``d`` lanes wide; v, out, g (and dv) ``d_v``."""
     b, h, s, d = q.shape
+    d_v = v.shape[3]
     kv_heads = k.shape[1]
     reps = h // kv_heads
     scale = d ** -0.5
@@ -379,65 +392,58 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
     bkv = min(block_kv, s)
 
     gf = g.astype(q.dtype)
-    # D_i = rowsum(dO * O), the softmax-jacobian diagonal term.
+    # D_i = rowsum(dO * O), the softmax-jacobian diagonal term (over d_v).
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
     # Stats ride as [B, H, 1, S] so the (1, 1, 1, bq) block satisfies the
     # Mosaic tiling rule (second-to-last block dim == full array dim).
     lse4 = lse[:, :, None, :]
     dlt4 = delta[:, :, None, :]
 
+    def q_rows(width, index):       # a q block of a query head: q, g, dq
+        return pl.BlockSpec((1, 1, bq, width), index)
+
+    def kv_rows(width, index):      # a kv block of a kv head: k, v, dk, dv
+        return pl.BlockSpec((1, 1, bkv, width), index)
+
+    def dq_q(bi, hi, qi, kj):
+        return bi, hi, qi, 0
+
+    def dq_kv(bi, hi, qi, kj):
+        return bi, hi // reps, kj, 0
+
+    stat = pl.BlockSpec((1, 1, 1, bq), lambda bi, hi, qi, kj: (bi, hi, 0, qi))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale),
         grid=(b, h, s // bq, s // bkv),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda bi, hi, qi, kj: (bi, hi // reps, kj, 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda bi, hi, qi, kj: (bi, hi // reps, kj, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, 1, bq),
-                         lambda bi, hi, qi, kj: (bi, hi, 0, qi)),
-            pl.BlockSpec((1, 1, 1, bq),
-                         lambda bi, hi, qi, kj: (bi, hi, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
+        in_specs=[q_rows(d, dq_q), kv_rows(d, dq_kv), kv_rows(d_v, dq_kv),
+                  q_rows(d_v, dq_q), stat, stat],
+        out_specs=q_rows(d, dq_q),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
         name="flash_dq",
     )(q, k, v, gf, lse4, dlt4)
 
+    def dkv_q(bi, gi, ki, r, qj):
+        return bi, gi * reps + r, qj, 0
+
+    def dkv_kv(bi, gi, ki, r, qj):
+        return bi, gi, ki, 0
+
+    stat = pl.BlockSpec((1, 1, 1, bq),
+                        lambda bi, gi, ki, r, qj: (bi, gi * reps + r, 0, qj))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale),
         grid=(b, kv_heads, s // bkv, reps, s // bq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda bi, gi, ki, r, qj: (bi, gi * reps + r, qj, 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda bi, gi, ki, r, qj: (bi, gi, ki, 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda bi, gi, ki, r, qj: (bi, gi, ki, 0)),
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda bi, gi, ki, r, qj: (bi, gi * reps + r, qj, 0)),
-            pl.BlockSpec((1, 1, 1, bq),
-                         lambda bi, gi, ki, r, qj: (bi, gi * reps + r, 0, qj)),
-            pl.BlockSpec((1, 1, 1, bq),
-                         lambda bi, gi, ki, r, qj: (bi, gi * reps + r, 0, qj)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda bi, gi, ki, r, qj: (bi, gi, ki, 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda bi, gi, ki, r, qj: (bi, gi, ki, 0)),
-        ],
+        in_specs=[q_rows(d, dkv_q), kv_rows(d, dkv_kv), kv_rows(d_v, dkv_kv),
+                  q_rows(d_v, dkv_q), stat, stat],
+        out_specs=[kv_rows(d, dkv_kv), kv_rows(d_v, dkv_kv)],
         out_shape=[
             jax.ShapeDtypeStruct((b, kv_heads, s, d), k.dtype),
-            jax.ShapeDtypeStruct((b, kv_heads, s, d), v.dtype),
+            jax.ShapeDtypeStruct((b, kv_heads, s, d_v), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bkv, d), jnp.float32),
-                        pltpu.VMEM((bkv, d), jnp.float32)],
+                        pltpu.VMEM((bkv, d_v), jnp.float32)],
         interpret=interpret,
         name="flash_dkv",
     )(q, k, v, gf, lse4, dlt4)
@@ -529,13 +535,16 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
                     interpret: Optional[bool] = None, mesh=None,
                     batch_axes: Tuple[str, ...] = ("dp", "fsdp")
                     ) -> jnp.ndarray:
-    """Flash attention. q: [B, Sq, H, D], k/v: [B, Skv, KV, D] -> [B, Sq, H, D].
+    """Flash attention. q: [B, Sq, H, Dqk], k: [B, Skv, KV, Dqk], v: [B, Skv,
+    KV, Dv] -> [B, Sq, H, Dv].
 
     Layout matches ``attention.attend``; internally transposed to [B, H, S, D]
-    (the kernel wants the sequence on the sublane dim and D=64/128 on lanes).
-    Sequence lengths must be multiples of the block sizes: a shape that does
-    not tile raises (``flash_supported`` says why; the ``mha`` dispatcher asks
-    it before choosing this kernel).
+    (the kernel wants the sequence on the sublane dim and a head's width on
+    lanes: 64, 128, 192, 256; the value's may differ from the query's and
+    key's, as a latent head's 128 beside 192).  Sequence lengths must be
+    multiples of the block sizes: a shape that does not tile raises
+    (``flash_supported`` says why; the ``mha`` dispatcher asks it before
+    choosing this kernel).
 
     With a ``mesh`` of more than one device the call is wrapped in a
     shard_map over ``batch_axes``, each device on its local batch shard.
@@ -543,6 +552,9 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     """
     b, sq, h, d = q.shape
     reason = flash_supported(sq, k.shape[1], h, k.shape[2], block_q, block_kv)
+    if reason is None and (k.shape[-1] != d or v.shape[:-1] != k.shape[:-1]):
+        reason = (f"keys {k.shape} are not as wide as queries {q.shape} or "
+                  f"not as many as values {v.shape}")
     if reason is not None:
         raise ValueError(f"flash attention cannot run this shape: {reason}")
     interpret = resolve_interpret(interpret, "flash")

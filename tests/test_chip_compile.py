@@ -69,10 +69,11 @@ def _compile(fn, *args, **jit_kw):
     return compiled, compiled.as_text()
 
 
-def _qkv(sharding, b, s, h, kv, d):
-    return (jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=sharding),
-            jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=sharding),
-            jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=sharding))
+def _qkv(sharding, b, s, h, kv, d, d_v=None):
+    """q, k of ``d`` lanes and v of ``d_v`` (``d`` where None)."""
+    return tuple(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+        for shape in ((b, s, h, d), (b, s, kv, d), (b, s, kv, d_v or d)))
 
 
 # --------------------------------------------------------------- kernels
@@ -81,9 +82,9 @@ def _qkv(sharding, b, s, h, kv, d):
 @pytest.mark.parametrize("shape", [
     pytest.param((8, 2048, 12, 6, 128), id="llama-400m"),
     pytest.param((8, 1024, 12, 12, 64), id="gpt2-124m"),
-    # heads of 192 / 128 padded to 256, as the latent kind's prefill and its
-    # train step (PR 39) hand them over, at the train cell's 8,192
-    pytest.param((2, 8192, 16, 16, 256), id="latent-8192"),
+    # keys of 192 and values of 128 as they are, as the latent kind's prefill
+    # and its train step hand them over (PR 45), at the train cell's 8,192
+    pytest.param((2, 8192, 16, 16, 192, 128), id="latent-8192"),
 ])
 def test_flash_attention_compiles(one_chip, shape, direction):
     from ray_tpu.ops.flash_attention import flash_attention
@@ -672,8 +673,15 @@ WHOLE_ROW_PROGRAMS = {
     # are reshaped where they came back [layers, 2].  The same temporaries
     # to the byte, 2,560 bytes (0.025%) less code than (163378176, 10205696)
     ("latent", "decode"): (163378176, 10203136),
-    ("latent", "prefill-2048"): (478939136, 22371328),
-    ("latent", "prefill-8192"): (1711136256, 30828544),
+    # the latent kind's two prefills were pinned anew at PR 45: the expanded
+    # attention hands the flash kernel keys of 192 and values of 128 as they
+    # are, not both padded to 256 (models/latent.py ``_expanded``).  The
+    # 2,048 program's temporaries fell 4.0%, the 8,192 program's stayed to
+    # the byte (another buffer sets its peak), of (478939136, 22371328),
+    # (1711136256, 30828544).  Every other program above is one whose heads
+    # have one width: the same kernel, to the byte
+    ("latent", "prefill-2048"): (459842048, 22800896),
+    ("latent", "prefill-8192"): (1711136256, 30621184),
 }
 
 
@@ -844,9 +852,10 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
         topo, as_tpu):
     """``kimi-vl-a3b-train-l6-e8`` as its cell runs it: 2 x 8,192 tokens,
     float32 AdamW state of 668.9M parameters (8.03 GB in place), one chip.
-    Reading 15.86e9 bytes at the program's peak, arguments included
-    (``peak_memory_in_bytes``; sandbox compile, PR 39): under the 15.0 GiB
-    ISSUE 39 set.  The kernel calls in the step are the ones the block kind
+    Reading 15.26e9 bytes at the program's peak, arguments included
+    (``peak_memory_in_bytes``; sandbox compile, PR 45; 15.86e9 at PR 39, with
+    the attention heads padded to 256 lanes): under the 15.0 GiB ISSUE 39
+    set.  The kernel calls in the step are the ones the block kind
     counts FLOPs for (``moe_gmm_train_calls``, ``mla_flash_train_calls``): a
     roofline share must not credit a pass the program does not run."""
     doc, kind, cfg = _train_cell("kimi-vl-a3b-train-l6-e8", "kimi_vl.py")
@@ -857,8 +866,10 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
         12 * kind.num_params(doc), rel=1e-3)
     assert mem.alias_size_in_bytes > 0.999 * mem.output_size_in_bytes
     assert mem.peak_memory_in_bytes < 15.0 * 2**30, mem.peak_memory_in_bytes
-    # a mesh of one device: nothing to gather, the program PR 39 left
-    assert mem.temp_size_in_bytes == 11_878_587_904
+    # a mesh of one device: nothing to gather.  0.94e9 under the
+    # 11_878_587_904 PR 39 left: the flash kernels' operands, results and
+    # saved ``attn_out`` at 192 and 128 lanes where all were 256 (PR 45)
+    assert mem.temp_size_in_bytes == 10_939_999_232
     # the dense layer's pass is unrolled, the expert layers' a scan's body:
     # a kernel's calls in the text are its calls a layer, forward plus
     # backward, once for each
@@ -874,3 +885,10 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
                  kind.mla_flash_train_calls(doc).items()})
     assert calls == want and text.count(KERNEL) == sum(want.values())
     assert kind.moe_gmm_train_passes(doc) == 4
+    # a latent head reaches the flash kernels at its own two widths: no
+    # operand or result of theirs is padded to 256 lanes
+    flash = re.findall(r"%flash_(?:fwd|dq|dkv)(?:\.\d+)? = [^\n]*" + KERNEL
+                       + r"[^\n]*", text)
+    widths = {int(d) for line in flash for d in re.findall(
+        r"bf16\[\d+,\d+,\d+,(\d+)\]", line)}
+    assert widths == {cfg.qk_head_dim, cfg.v_head_dim} == {192, 128}
