@@ -54,6 +54,10 @@ class TransformerConfig:
     use_rmsnorm: bool = True    # False => LayerNorm with bias (GPT-2)
     use_qkv_bias: bool = False  # True => biases on Q/K/V only (Qwen-2)
     use_swiglu: bool = True     # False => GELU MLP (GPT-2)
+    # "relu2": the MLPs, the routed and the shared experts alike, are two
+    # matrices and no gate, ``W_out relu(W_in x)^2`` (a layer_pattern's
+    # only: models/hybrid.py builds them); "": what ``use_swiglu`` says
+    mlp_act: str = ""
     tied_embeddings: bool = False
     # MoE (Mixtral): num_experts > 1 enables the sparse MLP
     num_experts: int = 1
@@ -70,16 +74,27 @@ class TransformerConfig:
     #: sizes).  An explicit "flash"/"splash" that cannot run for the shape
     #: raises; only "auto" chooses.
     attention_impl: str = "auto"
-    # layers of two kinds (models/hybrid.py).  ``layer_pattern`` is one
-    # period of kinds, "linear" (a gated-delta-rule mixer with a recurrent
-    # state per sequence) or "full" (the dense attention above); the model
-    # is ``num_layers / len(layer_pattern)`` such periods.  Empty: every
-    # layer is a dense block, the path of every other preset.
+    # layers of several kinds (models/hybrid.py).  ``layer_pattern`` is one
+    # period of kinds: "linear" (a gated-delta-rule mixer with a recurrent
+    # state per sequence), "ssm" (a state-space mixer, Mamba-2's, with one
+    # too), "full" (the dense attention above) and "mlp" (the feed-forward
+    # alone: dense, or the dropless experts where ``moe_dropless``); the
+    # model is ``num_layers / len(layer_pattern)`` such periods.  Empty:
+    # every layer is a dense block, the path of every other preset.
+    # A pattern with an "mlp" kind is one whose layers are each ONE sublayer
+    # alone, ``x + f(norm(x))`` (``sublayers_alone``): a mixer kind carries
+    # no MLP beneath and the MLPs are the pattern's "mlp" layers; without
+    # one an MLP lies under every mixer.
     layer_pattern: Tuple[str, ...] = ()
+    # (the "ssm" kind reads the same four: its heads, the state's width N
+    # as the key's, the head's width P as the value's, its convolution)
     linear_num_heads: int = 0       # key heads = value heads of the mixer
     linear_key_dim: int = 0         # per head
     linear_value_dim: int = 0       # per head
     linear_conv_width: int = 4      # causal depthwise convolution over time
+    # groups of the "ssm" kind: B and C (its keys and queries) are shared
+    # by the ``linear_num_heads / ssm_groups`` heads of a group
+    ssm_groups: int = 0
     linear_neg_eigval: bool = False  # beta in (0, 2) instead of (0, 1)
     # the linear kind's variant (ops/kda.py, Kimi Delta Attention): a decay
     # a key channel instead of one a head and a sigmoid output gate instead
@@ -157,29 +172,46 @@ class TransformerConfig:
     #: residual stream
     SERVED_ONLY = ("hc_mult",)
 
+    #: a pattern's kinds of layer
+    KINDS = ("linear", "ssm", "full", "mlp")
+
     def __post_init__(self):
         pat = self.layer_pattern
         self._check_latent_tree()
         if not pat:
             only = [f for f in ("qk_norm", "norm_on_output", "attn_head_dim",
                                 "attn_output_gate", "linear_decay_per_channel",
-                                "linear_gate_rank")
+                                "linear_gate_rank", "ssm_groups", "mlp_act")
                     if getattr(self, f)]
             if only:
                 raise ValueError(f"{only} are wired for a layer_pattern only "
                                  "(models/hybrid.py builds those blocks)")
             return
-        if set(pat) - {"linear", "full"}:
-            raise ValueError(f"layer_pattern {pat}: kinds are 'linear' and "
-                             "'full'")
+        if set(pat) - set(self.KINDS):
+            raise ValueError(f"layer_pattern {pat}: kinds are "
+                             f"{', '.join(map(repr, self.KINDS))}")
         if self.num_layers % len(pat):
             raise ValueError(f"num_layers {self.num_layers} is not whole "
                              f"periods of {pat}")
-        if "linear" in pat and not (self.linear_num_heads
-                                    and self.linear_key_dim
-                                    and self.linear_value_dim):
-            raise ValueError("a 'linear' layer needs linear_num_heads, "
-                             "linear_key_dim and linear_value_dim")
+        if "linear" in pat and "ssm" in pat:
+            raise ValueError(
+                f"layer_pattern {pat}: one recurrent kind a model, 'linear' "
+                "or 'ssm' (they read the same linear_* sizes and keep the "
+                "cache tree's one state)")
+        if ("linear" in pat or "ssm" in pat) and not (
+                self.linear_num_heads and self.linear_key_dim
+                and self.linear_value_dim):
+            raise ValueError("a 'linear' or 'ssm' layer needs "
+                             "linear_num_heads, linear_key_dim and "
+                             "linear_value_dim")
+        if ("ssm" in pat) != bool(self.ssm_groups) or (
+                self.ssm_groups and self.linear_num_heads % self.ssm_groups):
+            raise ValueError(
+                f"ssm_groups {self.ssm_groups}: the groups of an 'ssm' "
+                f"layer's {self.linear_num_heads} heads, whole heads a "
+                "group, and no other kind's")
+        if self.mlp_act not in ("", "relu2"):
+            raise ValueError(f"mlp_act {self.mlp_act!r}: '' or 'relu2'")
         if self.linear_gate_rank < 0 or self.attn_head_dim < 0:
             raise ValueError("linear_gate_rank and attn_head_dim: 0 or more")
         if self.linear_decay_per_channel != bool(self.linear_gate_rank):
@@ -193,8 +225,9 @@ class TransformerConfig:
     def _check_latent_tree(self):
         if self.layer_pattern:
             # a pattern's blocks are models/hybrid.py's: full attention
-            # over K/V rows and the linear mixer, one residual stream, with
-            # a dense MLP or dropless experts under every layer
+            # over K/V rows and the recurrent mixers, one residual stream,
+            # with a dense MLP or dropless experts under every mixer or as
+            # layers of their own
             on = [f for f in ("kv_lora_rank", "hc_mult",
                               "dense_prefix_layers") if getattr(self, f)]
             if on:
@@ -202,7 +235,7 @@ class TransformerConfig:
                     f"{on} do not combine with a layer_pattern: "
                     "models/hybrid.py's full layers cache K/V rows, not "
                     "latent ones, its blocks carry one residual stream, "
-                    "and every layer of a period has the same MLP")
+                    "and the layers that have one all have the same MLP")
         if self.kv_lora_rank:
             if not (self.qk_nope_head_dim and self.qk_rope_head_dim
                     and self.v_head_dim) or self.q_lora_rank < 0:
@@ -227,9 +260,10 @@ class TransformerConfig:
             raise ValueError("rope_yarn_factor needs rope_yarn_original_max")
         if self.moe_dropless:
             if not (self.num_experts > 1 and self.expert_mlp_size
-                    and self.use_swiglu):
+                    and (self.use_swiglu or self.mlp_act)):
                 raise ValueError("moe_dropless needs num_experts > 1, "
-                                 "expert_mlp_size and a SwiGLU MLP")
+                                 "expert_mlp_size and a SwiGLU MLP (or "
+                                 "mlp_act 'relu2' under a layer_pattern)")
             if not 0 < self.experts_per_token <= self.num_experts:
                 raise ValueError(
                     f"experts_per_token {self.experts_per_token} of "
@@ -299,8 +333,22 @@ class TransformerConfig:
         return tuple(f for f in self.SERVED_ONLY if getattr(self, f))
 
     @property
+    def sublayers_alone(self) -> bool:
+        """Every layer is one sublayer alone: the pattern has "mlp" layers,
+        so no mixer carries an MLP beneath."""
+        return "mlp" in self.layer_pattern
+
+    @property
+    def mlp_layers(self) -> int:
+        """Layers with a feed-forward: a pattern's "mlp" layers where its
+        layers are sublayers alone, else every layer."""
+        if self.sublayers_alone:
+            return self.num_periods * self.layer_pattern.count("mlp")
+        return self.num_layers
+
+    @property
     def expert_layers(self) -> int:
-        return (self.num_layers - self.dense_prefix_layers
+        return (self.mlp_layers - self.dense_prefix_layers
                 if self.moe_dropless else 0)
 
     @property
@@ -311,14 +359,29 @@ class TransformerConfig:
     def num_periods(self) -> int:
         return self.num_layers // len(self.layer_pattern)
 
-    @property
-    def linear_layers(self) -> int:
-        return (self.num_periods * self.layer_pattern.count("linear")
+    def _layers_of(self, kind: str) -> int:
+        return (self.num_periods * self.layer_pattern.count(kind)
                 if self.layer_pattern else 0)
 
     @property
+    def linear_layers(self) -> int:
+        return self._layers_of("linear")
+
+    @property
+    def ssm_layers(self) -> int:
+        return self._layers_of("ssm")
+
+    @property
     def full_layers(self) -> int:
-        return self.num_layers - self.linear_layers
+        return (self._layers_of("full") if self.layer_pattern
+                else self.num_layers)
+
+    @property
+    def ssm_channels(self) -> Tuple[int, int]:
+        """(inner channels heads x head width, channels the convolution
+        mixes: those and B and C of every group) of the "ssm" kind."""
+        inner = self.linear_num_heads * self.linear_value_dim
+        return inner, inner + 2 * self.ssm_groups * self.linear_key_dim
 
     def num_params(self) -> int:
         """Approximate parameter count (for MFU math): what this holder
@@ -326,29 +389,39 @@ class TransformerConfig:
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         if self.latent_tree:
             return self._latent_tree_params(self.experts_held)
-        attn = h * h + 2 * h * (self.num_kv_heads * self.head_dim) + h * h
         if self.layer_pattern:
-            lh, r = self.linear_num_heads, self.linear_gate_rank
-            kd, vd = lh * self.linear_key_dim, lh * self.linear_value_dim
-            wide = self.num_heads * self.head_dim
-            attn = (h * wide * (3 if self.attn_output_gate else 2)
-                    + 2 * h * self.num_kv_heads * self.head_dim)
-            decay = h * r + r * kd if r else h * lh
-            gate = h * r + r * vd if r else h * vd
-            mixer = h * (2 * kd + vd) + vd * h + decay + gate + h * lh
-            mlp = 3 * h * self.mlp_size
-            if self.moe_dropless:
-                mlp = (3 * h * self.expert_mlp_size
-                       * (self.experts_held + self.shared_experts)
-                       + h * self.num_experts)
-            return (self.linear_layers * mixer + self.full_layers * attn
-                    + L * mlp + 2 * v * h)
+            return self._pattern_params(self.experts_held)
+        attn = h * h + 2 * h * (self.num_kv_heads * self.head_dim) + h * h
         if self.num_experts > 1:
             mlp = self.num_experts * 3 * h * self.mlp_size + h * self.num_experts
         else:
             mlp = (3 if self.use_swiglu else 2) * h * self.mlp_size
         emb = v * h * (1 if self.tied_embeddings else 2)
         return L * (attn + mlp) + emb
+
+    def _pattern_params(self, routed: float) -> float:
+        """Matrix parameters of ``models/hybrid.py``'s tree with ``routed``
+        routed experts an expert layer (``_latent_tree_params``'s two
+        uses)."""
+        h = self.hidden_size
+        lh, r = self.linear_num_heads, self.linear_gate_rank
+        kd, vd = lh * self.linear_key_dim, lh * self.linear_value_dim
+        wide = self.num_heads * self.head_dim
+        attn = (h * wide * (3 if self.attn_output_gate else 2)
+                + 2 * h * self.num_kv_heads * self.head_dim)
+        decay = h * r + r * kd if r else h * lh
+        gate = h * r + r * vd if r else h * vd
+        mixer = h * (2 * kd + vd) + vd * h + decay + gate + h * lh
+        inner, mixed = self.ssm_channels
+        ssm = h * (inner + mixed + lh) + inner * h
+        mats = 2 if self.mlp_act else 3
+        mlp = mats * h * self.mlp_size
+        if self.moe_dropless:
+            mlp = (mats * h * self.expert_mlp_size
+                   * (routed + self.shared_experts) + h * self.num_experts)
+        return (self.linear_layers * mixer + self.ssm_layers * ssm
+                + self.full_layers * attn + self.mlp_layers * mlp
+                + 2 * self.vocab_size * h)
 
     def _latent_tree_params(self, routed: float) -> float:
         """Matrix parameters of ``models/latent.py``'s tree (latent
@@ -401,13 +474,19 @@ class TransformerConfig:
                            + self.vocab_size * h)
                     + 3.0 * L * self.num_heads * heads * s)
         if self.layer_pattern:
-            # the quadratic term for the full layers only; the mixer's state
-            # update and read are 4 * key_dim * value_dim a head a token
+            # the quadratic term for the full layers only; a recurrent
+            # mixer's state update and read are 4 * key_dim * value_dim a
+            # head a token; of a token's routed experts the expected share
+            # that lands on the experts held
             s = seq_len or self.max_seq_len
-            state = (self.linear_layers * self.linear_num_heads * 4
+            state = ((self.linear_layers + self.ssm_layers)
+                     * self.linear_num_heads * 4
                      * self.linear_key_dim * self.linear_value_dim)
-            return (6.0 * (self.num_params() - self.vocab_size * h)
-                    + 6.0 * self.full_layers * 2 * s * h + 3.0 * state)
+            met = (self.experts_per_token * self.experts_held
+                   / self.num_experts if self.moe_dropless else 0.0)
+            return (6.0 * (self._pattern_params(met) - self.vocab_size * h)
+                    + 6.0 * self.full_layers * 2 * s
+                    * self.num_heads * self.head_dim + 3.0 * state)
         attn = L * (h * h + 2 * h * self.num_kv_heads * self.head_dim + h * h)
         if self.num_experts > 1:
             mlp = L * self.experts_per_token * 3 * h * self.mlp_size

@@ -23,7 +23,8 @@ says which mixers apply (``prefill``, ``window_step``):
   ``paged_decode.page_attention`` for both;
 * a recurrent ``state`` and a ``conv`` tail beside the rows, where the
   configuration has a ``layer_pattern`` (``hybrid.py``):
-  ``hybrid.linear_prefill`` / ``linear_step`` for its "linear" layers;
+  ``hybrid.linear_prefill`` / ``linear_step`` for its "linear" layers or
+  ``hybrid.ssm_prefill`` / ``ssm_step`` for its "ssm" layers;
 * ``latent`` rows and ``rope_key`` columns, one compressed row a token a
   layer shared by all heads, where the configuration has latent attention
   (``latent.py``): ``latent.prefill_attention`` / ``decode_attention``.
@@ -111,7 +112,7 @@ def _init_rows(cfg: TransformerConfig, num_slots: int, max_len: int, dtype,
         "v": jnp.zeros(shape, dtype),
         "length": length,
     }
-    if cfg.linear_layers:
+    if cfg.linear_layers or cfg.ssm_layers:
         from . import hybrid
         cache.update(hybrid.init_state(cfg, num_slots, dtype))
     return cache
@@ -127,7 +128,8 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
     """What a cache tree holds, by kind of state: bytes of keys and values
     and of latent rows (per token) and of everything else a slot keeps (per
     sequence: a recurrent state, a convolution tail), the layers of each
-    kind, and the experts a layer holds."""
+    kind (``ssm_layers`` where the model has them), and the experts a layer
+    holds."""
     def nbytes(*names):
         return sum(int(a.size) * jnp.dtype(a.dtype).itemsize
                    for n, a in cache.items() if n in names)
@@ -138,6 +140,7 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
     return {"cache_kv_bytes": nbytes("k", "v"), "cache_state_bytes": state,
             "cache_latent_bytes": nbytes(*LATENT),
             "linear_layers": cfg.linear_layers,
+            **({"ssm_layers": cfg.ssm_layers} if cfg.ssm_layers else {}),
             "full_layers": cfg.full_layers,
             "expert_layers": cfg.expert_layers,
             "experts_held": cfg.experts_held if cfg.moe_dropless else 0}
@@ -211,6 +214,11 @@ def _mlp(y, p, cfg: TransformerConfig):
             cfg.expert_capacity_factor)
         return out
     mp = p["mlp"]
+    if cfg.mlp_act:                 # "relu2": two matrices, no gate
+        from ..ops.moe import relu2
+        up = relu2(jnp.dot(y, mp["w_in"].astype(cast),
+                            preferred_element_type=jnp.float32))
+        return up.astype(cast) @ mp["w_out"].astype(cast)
     if cfg.use_swiglu:
         return (jax.nn.silu(y @ mp["w_gate"].astype(cast))
                 * (y @ mp["w_in"].astype(cast))) @ mp["w_out"].astype(cast)
@@ -257,8 +265,10 @@ def masked_attention(q, k, v, positions, cfg: TransformerConfig):
 # ---------------------------------------------------------------------------
 
 Mixer = Callable[..., Tuple[jnp.ndarray, Any, Any]]
-# the norm of a kind's mixer branch, among its layer's weights
-_BRANCH_NORM = {"full": "attn_norm", "linear": "mixer_norm"}
+# the norm of a kind's mixer branch, among its layer's weights (an "mlp"
+# layer has no mixer)
+_BRANCH_NORM = {"full": "attn_norm", "linear": "mixer_norm",
+                "ssm": "mixer_norm"}
 
 
 def _layer_weights(stack: Params, index, lead: int) -> Params:
@@ -297,11 +307,15 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     One ``lax.scan`` over periods of ``cfg.layer_pattern`` with the kinds
     inside a period unrolled, so the trace is one period whatever the depth;
     a model without a pattern is the pattern ``("full",)``, one layer a
-    period.  Under any kind of layer lies a dense MLP or, with
-    ``cfg.moe_dropless``, the dropless experts, whose weights stay in their
-    stacks.  A dense prefix (``cfg.dense_prefix_layers``) is walked before
-    the scan, which is then over the expert layers; a mixer is handed the
-    layer's index among its kind's cache rows.  A block is wired
+    period.  A kind says which sublayers a layer has: a mixer and, under
+    it, a dense MLP or, with ``cfg.moe_dropless``, the dropless experts,
+    whose weights stay in their stacks; or, with ``cfg.sublayers_alone``,
+    one of the two alone (an "mlp" layer is the feed-forward, every other
+    kind its mixer), each behind its own norm.  A dense prefix
+    (``cfg.dense_prefix_layers``) is walked before the scan, which is then
+    over the expert layers; a mixer is handed the layer's index among its
+    kind's cache rows, an expert layer its rank among the expert layers.  A
+    block is wired
     ``x + f(norm(x))``, ``x + norm(f(x))`` under ``cfg.norm_on_output``, or
     with ``cfg.hc_mult`` residual streams, read, written and mixed around
     the sublayer by per-token coefficients (``latent.hc_coeff``), for the
@@ -356,17 +370,23 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
                 return x, lambda out: x + norm(out, name)
             return norm(x, name), lambda out: x + out
 
-        branch = _BRANCH_NORM[kind]
+        branch = _BRANCH_NORM.get(kind)
+        rows = routed = None
         # (what a sublayer multiplies is ``cast``; a router scores what it
         # is given)
-        seen, join = wire(x, branch)
-        out, carry, rows = mixers[kind](seen.astype(cast), lp, index, carry)
-        x = join(out)
-        seen, join = wire(x, "mlp_norm")
-        if experts is None:
-            return join(_mlp(seen.astype(cast), lp, cfg)), carry, rows, None
-        out, routed = _experts(seen, lp, cfg, live, cast, *experts)
-        return join(out), carry, rows, routed
+        if branch:
+            seen, join = wire(x, branch)
+            out, carry, rows = mixers[kind](seen.astype(cast), lp, index,
+                                            carry)
+            x = join(out)
+        if kind == "mlp" or not cfg.sublayers_alone:
+            seen, join = wire(x, "mlp_norm")
+            if experts is None:
+                out = _mlp(seen.astype(cast), lp, cfg)
+            else:
+                out, routed = _experts(seen, lp, cfg, live, cast, *experts)
+            x = join(out)
+        return x, carry, rows, routed
 
     # Where a layer's weights lie.  A pattern's blocks are stacked by kind
     # [periods, layers of the kind a period, ...] with the experts of every
@@ -381,6 +401,9 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     # chip, four pairs of four (PERF.md, PR 31).
     routed = ("w_gate", "w_in", "w_out")
     stacks, small = None, blocks
+    # the layers of a period that have an MLP, the experts where the model
+    # has them: the "mlp" layers, or every layer
+    has_mlp = [kind == "mlp" or not cfg.sublayers_alone for kind in pattern]
     if cfg.moe_dropless and cfg.layer_pattern:
         stacks = blocks["experts"]
     elif cfg.moe_dropless:
@@ -399,19 +422,23 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
         is the one in its kind's stack of weights (a mixer's in its stack
         of cache rows: the layers before the scan come first there)."""
         (x, carry), (p, dense) = walk, step
-        carry, at = dict(carry), dict.fromkeys(per_period, 0)
+        carry, at = dict(carry), dict.fromkeys(pattern, 0)
         ys = {kind: [] for kind in per_period}
         chosen = []
         for j, kind in enumerate(pattern):
-            index = p * per_period[kind] + at[kind]
+            index = p * pattern.count(kind) + at[kind]
             at[kind] += 1
-            x, carry[kind], rows, said = layer(
-                x, carry[kind], kind,
+            x, state, rows, said = layer(
+                x, carry.get(kind), kind,
                 weights(kind, index) if dense is None else dense,
                 prefix + index,
-                (p * len(pattern) + j, stacks) if stacks else None)
-            ys[kind].append(rows)
-            chosen.append(said)
+                (p * sum(has_mlp) + sum(has_mlp[:j]), stacks)
+                if stacks and has_mlp[j] else None)
+            if kind in per_period:
+                carry[kind] = state
+                ys[kind].append(rows)
+            if said is not None:
+                chosen.append(said)
         if stacks:
             ys["moe"], ys["experts"] = zip(*chosen)
         return (x, carry), {kind: jax.tree.map(lambda *a: jnp.stack(a), *outs)
@@ -609,15 +636,16 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
         return out, carry, (k.reshape(1, s, -1).astype(cache["k"].dtype),
                             v.reshape(1, s, -1).astype(cache["v"].dtype))
 
-    mixers = {"full": rows}
+    mixers, kind = {"full": rows}, None
     if "state" in cache:
-        from .hybrid import linear_prefill
+        from . import hybrid
+        kind, whole_rows, _ = hybrid.recurrent(cfg)
 
         def recurrent(y, lp, i, carry):
-            out, state, tail = linear_prefill(y, lp["mixer"], cfg, length)
+            out, state, tail = whole_rows(y, lp["mixer"], cfg, length)
             return out, carry, (state, tail.astype(cache["conv"].dtype))
 
-        mixers["linear"] = recurrent
+        mixers[kind] = recurrent
     logits, _, ys = layer_stack(params, tokens, positions, mixers,
                                 dict.fromkeys(mixers), cfg, compute_dtype,
                                 last, live)
@@ -632,10 +660,10 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
     if "full" in ys:
         with jax.named_scope("kv_write"):
             new["k"], new["v"] = put("k", ys["full"][0]), put("v", ys["full"][1])
-    if "linear" in ys:
+    if kind in ys:
         with jax.named_scope("state_write"):
-            new["state"] = put("state", ys["linear"][0])
-            new["conv"] = put("conv", ys["linear"][1])
+            new["state"] = put("state", ys[kind][0])
+            new["conv"] = put("conv", ys[kind][1])
     return choices(ys), logits
 
 
@@ -765,18 +793,20 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
         attend = _kv_mixer(decode_attention, cfg, lengths, active)
     rows = LATENT if "latent" in cache else ("k", "v")
     mixers, carry = {"full": attend}, {"full": tuple(cache[n] for n in rows)}
+    kind = None
     if "state" in cache:
-        from .hybrid import linear_step
+        from . import hybrid
+        kind, _, one_step = hybrid.recurrent(cfg)
         if w != 1:
             raise ValueError("a recurrent state steps one token at a time: "
                              f"window of {w}")
 
         def recurrent(y, lp, i, sc):
-            out, *sc = linear_step(y, lp["mixer"], cfg, i, *sc, active)
+            out, *sc = one_step(y, lp["mixer"], cfg, i, *sc, active)
             return out, tuple(sc), None
 
-        mixers["linear"] = recurrent
-        carry["linear"] = (cache["state"], cache["conv"])
+        mixers[kind] = recurrent
+        carry[kind] = (cache["state"], cache["conv"])
     logits, carry, ys = layer_stack(
         params, tokens, positions, mixers, carry, cfg, compute_dtype,
         live=jnp.broadcast_to(active[:, None], tokens.shape)
@@ -792,8 +822,8 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
             :, jnp.arange(tokens.shape[0])[:, None],
             jnp.where(active[:, None], positions, span)].set(
                 ys["experts"], mode="drop")
-    if "linear" in carry:
-        new["state"], new["conv"] = carry["linear"]
+    if kind:
+        new["state"], new["conv"] = carry[kind]
     return new, logits
 
 

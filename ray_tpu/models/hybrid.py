@@ -1,21 +1,25 @@
-"""Layers of two kinds in one model: linear-attention layers with a
-recurrent state beside full-attention layers
-(``TransformerConfig.layer_pattern``), on the serving path.  This file is
-what is particular to the "linear" kind: its weights, its per-slot state and
-its mixer, for whole rows (``linear_prefill``) and for one token a slot
-(``linear_step``), and the pattern's parameter tree (``init_blocks``).  The
-walk over the layers, the block's wiring, the full-attention layers and the
-MLP or dropless experts under every layer are ``decode.py``'s, which hands
-these two to ``decode.layer_stack`` where the cache tree has a ``state``;
+"""Layers of several kinds in one model: layers with a recurrent state
+beside full-attention layers (``TransformerConfig.layer_pattern``), on the
+serving path.  This file is what is particular to the two recurrent kinds,
+"linear" (the gated delta rule) and "ssm" (Mamba-2's state-space mixer; a
+model has one of the two): their weights, their per-slot state and their
+mixers, for whole rows (``linear_prefill``, ``ssm_prefill``) and for one
+token a slot (``linear_step``, ``ssm_step``), and the pattern's parameter
+tree (``init_blocks``).  The walk over the layers, the block's wiring, the
+full-attention layers and the MLP or dropless experts (under every mixer,
+or as "mlp" layers of their own where ``cfg.sublayers_alone``) are
+``decode.py``'s, which hands a kind's two (``recurrent``) to
+``decode.layer_stack`` where the cache tree has a ``state``;
 ``serve/llm.py`` runs the same calls on it as on any cache:
 
 * ``k``, ``v``: [full_layers, slots, max_len, NKV * D], the dense cache of
   ``decode.py`` with rows for the full-attention layers only, written and
   read by ``decode.prefill_attention`` / ``decode_attention``;
 * ``state``: [linear_layers, slots, heads, key_dim, value_dim] float32, the
-  delta rule's state (``ops/gated_delta.py``, ``ops/kda.py``), constant in
-  the context;
-* ``conv``: [linear_layers, slots, conv_width - 1, channels], the last
+  delta rule's state (``ops/gated_delta.py``, ``ops/kda.py``), or
+  [ssm_layers, slots, heads, head width P, state width N] float32, the
+  state-space one (``ops/ssd.py``): constant in the context;
+* ``conv``: [recurrent layers, slots, conv_width - 1, channels], the last
   inputs of the mixer's causal convolution;
 * ``length``: [slots].
 
@@ -35,8 +39,18 @@ sigmoid, the decay's and the gate's projections through a bottleneck of
 attention's output (``attn_output_gate``, ``decode._proj_out``) and have
 heads of a published width (``attn_head_dim``).
 
+An ssm layer's mixer (``linear_num_heads`` H heads of ``linear_value_dim``
+P, ``ssm_groups`` G groups of ``linear_key_dim`` N, head ``h`` reads group
+``h // (H / G)``)::
+
+    [z | xBC | dt~] = W_in x;  xBC = silu(causal_conv(xBC) + b_conv)
+    dt = softplus(dt~ + dt_bias);  a = exp(-exp(A_log) dt)     float32
+    S_t = a_t S_{t-1} + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
+    out = W_out [ rmsnorm_group(y * silu(z)) ]    groups of H P / G channels
+
 Blocks are wired ``h = x + norm(mixer(x)); out = h + norm(mlp(h))``
-(``norm_on_output``) or pre-norm, and nothing adds positions
+(``norm_on_output``) or pre-norm, or each layer is one of the two
+(``sublayers_alone``), and nothing adds positions
 (``no_positions``): the recurrences and convolutions carry them.
 
 Parameters are stacked per kind with leading dims [periods, layers of the
@@ -55,17 +69,18 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import gated_delta
+from ..ops import gated_delta, ssd
 from .config import TransformerConfig
 from .transformer import Params, _norm
 
 L2_EPS = 1e-6
 
 
-def _counts(cfg: TransformerConfig) -> Tuple[int, int, int]:
-    """(periods, linear layers a period, full layers a period)."""
-    n_lin = cfg.layer_pattern.count("linear")
-    return cfg.num_periods, n_lin, len(cfg.layer_pattern) - n_lin
+#: the published draw of the ssm kind's step and decay (Mamba-2's
+#: ``time_step_min`` / ``_max`` and ``A_init_range``): dt log-uniform, A
+#: uniform, so that ``a = exp(-A dt)`` spreads over about (0.2, 0.999)
+SSM_DT_RANGE = (0.001, 0.1)
+SSM_A_RANGE = (1.0, 16.0)
 
 
 def _channels(cfg: TransformerConfig) -> Tuple[int, int]:
@@ -79,11 +94,15 @@ def _channels(cfg: TransformerConfig) -> Tuple[int, int]:
 # ---------------------------------------------------------------------------
 
 def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
-    """``params["blocks"]`` of a model with a layer pattern: ``{"linear":
-    ..., "full": ...}``, leaves [periods, layers of the kind a period, ...],
-    and with dropless experts ``"experts"``, the routed experts of every
-    layer in layer order [layers, experts held, ...] (a layer then has the
-    router and the shared expert under ``moe`` where it had ``mlp``).
+    """``params["blocks"]`` of a model with a layer pattern: an entry a kind
+    of its pattern (``"linear"``, ``"ssm"``, ``"full"``, ``"mlp"``), leaves
+    [periods, layers of the kind a period, ...], and with dropless experts
+    ``"experts"``, the routed experts of every expert layer in layer order
+    [expert layers, experts held, ...] (a layer then has the router and the
+    shared expert under ``moe`` where it had ``mlp``).  An MLP lies under
+    every mixer, or, with ``cfg.sublayers_alone``, in the "mlp" layers
+    alone; with ``cfg.mlp_act`` it is two matrices (no ``w_gate``; a routed
+    expert's up projection is ``w_up`` [M, H], ``ops.moe.moe_dropless``).
 
     The decay's parameters are drawn so that ``alpha`` spreads over about
     (0.9, 1) across heads, or across channels where the decay is one a
@@ -91,11 +110,13 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
     to nothing within a few tokens would make every check of it vacuous.
     The gate projections ``w_a`` (``w_f_up``) / ``w_b`` are small for the
     same reason: they see the residual stream, whose scale grows with
-    depth."""
+    depth.  The ssm kind's are the published draw (``SSM_DT_RANGE``,
+    ``SSM_A_RANGE``), the step's columns of ``w_in`` small for that
+    reason."""
     h, m, hd = cfg.hidden_size, cfg.mlp_size, cfg.head_dim
     nh, nkv, lh = cfg.num_heads, cfg.num_kv_heads, cfg.linear_num_heads
     kd, vd = _channels(cfg)
-    periods, n_lin, n_full = _counts(cfg)
+    pattern, periods = cfg.layer_pattern, cfg.num_periods
     keys = iter(jax.random.split(key, 24))
     # what the variants add draws from keys of its own: the scalar-decay
     # mixer's and the plain full layer's weights are what they were
@@ -108,23 +129,36 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
     def ones(lead, n):
         return {"scale": jnp.ones(lead + (n,), dtype)}
 
-    def mlp(lead):
+    gated = not cfg.mlp_act
+
+    def mlp(lead, under_mixer=True):
+        """The feed-forward of the layers ``lead`` and its norm; nothing
+        under a mixer whose layer is that sublayer alone."""
+        if cfg.sublayers_alone and under_mixer:
+            return {}
+        norm = {"mlp_norm": ones(lead, h)}
         if not cfg.moe_dropless:
-            return {"mlp": {"w_gate": dense(lead, (h, m), h),
-                            "w_in": dense(lead, (h, m), h),
-                            "w_out": dense(lead, (m, h), m)}}
+            if gated:
+                ws = {"w_gate": dense(lead, (h, m), h),
+                      "w_in": dense(lead, (h, m), h),
+                      "w_out": dense(lead, (m, h), m)}
+            else:
+                ws = {"w_in": dense(lead, (h, m), h, keys=more),
+                      "w_out": dense(lead, (m, h), m, keys=more)}
+            return {"mlp": ws, **norm}
         e, sm = cfg.num_experts, cfg.shared_experts * cfg.expert_mlp_size
         moe = {"router": dense(lead, (h, e), h, keys=more),
                "bias": jnp.zeros(lead + (e,), dtype)}
+        if sm and gated:
+            moe["shared_gate"] = dense(lead, (h, sm), h, keys=more)
         if sm:
-            moe.update(shared_gate=dense(lead, (h, sm), h, keys=more),
-                       shared_in=dense(lead, (h, sm), h, keys=more),
+            moe.update(shared_in=dense(lead, (h, sm), h, keys=more),
                        shared_out=dense(lead, (sm, h), sm, keys=more))
-        return {"moe": moe}
+        return {"moe": moe, **norm}
 
     blocks: Params = {}
-    if n_lin:
-        lead = (periods, n_lin)
+    if "linear" in pattern:
+        lead = (periods, pattern.count("linear"))
         rank, decays = cfg.linear_gate_rank, (
             kd if cfg.linear_decay_per_channel else lh)
         rate = jnp.exp(jax.random.uniform(
@@ -150,9 +184,32 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
         mixer.update(o_norm=ones(lead, cfg.linear_value_dim),
                      w_o=dense(lead, (vd, h), vd))
         blocks["linear"] = {"mixer": mixer, "mixer_norm": ones(lead, h),
-                            **mlp(lead), "mlp_norm": ones(lead, h)}
-    if n_full:
-        lead = (periods, n_full)
+                            **mlp(lead)}
+    if "ssm" in pattern:
+        lead = (periods, pattern.count("ssm"))
+        inner, mixed = cfg.ssm_channels
+        width = cfg.linear_conv_width
+        dt = jnp.exp(jax.random.uniform(
+            next(more), lead + (lh,), jnp.float32,
+            *(jnp.log(v) for v in SSM_DT_RANGE)))
+        a = jax.random.uniform(next(more), lead + (lh,), jnp.float32,
+                               *SSM_A_RANGE)
+        w_in = dense(lead, (h, inner + mixed + lh), h, keys=more)
+        blocks["ssm"] = {
+            "mixer": {
+                # [z | x B C | dt]: the step's columns small
+                "w_in": w_in.at[..., inner + mixed:].multiply(0.1),
+                "conv_w": dense(lead, (width, mixed), width, keys=more),
+                "conv_b": jnp.zeros(lead + (mixed,), dtype),
+                "A_log": jnp.log(a).astype(dtype),
+                "D": jnp.ones(lead + (lh,), dtype),
+                # softplus^-1(dt), so that the step is dt at w_in x = 0
+                "dt_bias": jnp.log(jnp.expm1(dt)).astype(dtype),
+                "o_norm": ones(lead, inner),
+                "w_out": dense(lead, (inner, h), inner, keys=more)},
+            "mixer_norm": ones(lead, h), **mlp(lead)}
+    if "full" in pattern:
+        lead = (periods, pattern.count("full"))
         attn = {
             "wq": dense(lead, (h, nh * hd), h),
             "wk": dense(lead, (h, nkv * hd), h),
@@ -165,30 +222,39 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
             attn["q_norm"] = ones(lead, nh * hd)
             attn["k_norm"] = ones(lead, nkv * hd)
         blocks["full"] = {"attn": attn, "attn_norm": ones(lead, h),
-                          **mlp(lead), "mlp_norm": ones(lead, h)}
+                          **mlp(lead)}
+    if "mlp" in pattern:
+        blocks["mlp"] = mlp((periods, pattern.count("mlp")), False)
     if cfg.moe_dropless:
         from .latent import expert_stack
         em = cfg.expert_mlp_size
         blocks["experts"] = {
-            name: expert_stack(next(more), cfg, cfg.num_layers, shape, fan,
-                               dtype)
-            for name, shape, fan in (("w_gate", (h, em), h),
-                                     ("w_in", (h, em), h),
-                                     ("w_out", (em, h), em))}
+            name: expert_stack(next(more), cfg, cfg.expert_layers, shape,
+                               fan, dtype)
+            for name, shape, fan in ((("w_gate", (h, em), h),
+                                      ("w_in", (h, em), h),
+                                      ("w_out", (em, h), em)) if gated else
+                                     # as a linear map stores it: ops/moe.py
+                                     (("w_up", (em, h), h),
+                                      ("w_out", (em, h), em)))}
     return blocks
 
 
 def init_state(cfg: TransformerConfig, num_slots: int,
                dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
-    """What a slot keeps for the linear layers, beside the rows of K/V that
-    ``decode.init_kv_cache`` allocates for the full-attention layers."""
-    kd, vd = _channels(cfg)
+    """What a slot keeps for the recurrent layers, beside the rows of K/V
+    that ``decode.init_kv_cache`` allocates for the full-attention layers."""
+    lh, width = cfg.linear_num_heads, cfg.linear_conv_width
+    if cfg.ssm_layers:
+        layers, channels = cfg.ssm_layers, cfg.ssm_channels[1]
+        state = (lh, cfg.linear_value_dim, cfg.linear_key_dim)
+    else:
+        kd, vd = _channels(cfg)
+        layers, channels = cfg.linear_layers, 2 * kd + vd
+        state = (lh, cfg.linear_key_dim, cfg.linear_value_dim)
     return {
-        "state": jnp.zeros((cfg.linear_layers, num_slots, cfg.linear_num_heads,
-                            cfg.linear_key_dim, cfg.linear_value_dim),
-                           jnp.float32),
-        "conv": jnp.zeros((cfg.linear_layers, num_slots,
-                           cfg.linear_conv_width - 1, 2 * kd + vd), dtype),
+        "state": jnp.zeros((layers, num_slots) + state, jnp.float32),
+        "conv": jnp.zeros((layers, num_slots, width - 1, channels), dtype),
     }
 
 
@@ -270,26 +336,59 @@ def _rule(cfg: TransformerConfig):
 
 
 # ---------------------------------------------------------------------------
-# The mixer, over whole rows and one token a slot
+# The mixers, over whole rows and one token a slot
 # ---------------------------------------------------------------------------
+
+def _conv_rows(proj, mp, width: int, lengths):
+    """The causal depthwise convolution over time and its SiLU on whole
+    right-padded rows.  proj: [B, S, C] -> (mixed [B, S, C], the tail [B,
+    width - 1, C] each row leaves at its length: its last width-1 inputs)."""
+    s, cast = proj.shape[1], proj.dtype
+    tail_pos = lengths[:, None] - (width - 1) + jnp.arange(width - 1)[None]
+    padded = jnp.pad(proj, ((0, 0), (width - 1, 0), (0, 0)))
+    conv = sum(padded[:, j:j + s] * mp["conv_w"][j].astype(cast)
+               for j in range(width))
+    if "conv_b" in mp:
+        conv = conv + mp["conv_b"].astype(cast)
+    tail = jnp.take_along_axis(
+        proj, jnp.maximum(tail_pos, 0)[..., None], axis=1)
+    return jax.nn.silu(conv), jnp.where((tail_pos >= 0)[..., None], tail, 0)
+
+
+def _conv_step(proj, mp, width: int, tail):
+    """The same for one new input a slot.  proj: [slots, C]; tail: [slots,
+    width - 1, C] -> (mixed [slots, C], the window's last width-1 inputs)."""
+    cast = proj.dtype
+    window = jnp.concatenate([tail.astype(cast), proj[:, None]], 1)
+    conv = sum(window[:, j] * mp["conv_w"][j].astype(cast)
+               for j in range(width))
+    if "conv_b" in mp:
+        conv = conv + mp["conv_b"].astype(cast)
+    return jax.nn.silu(conv), window[:, 1:]
+
+
+def _state_io(li, conv, live, mix):
+    """Layer ``li``'s tail read out of the stack ``conv``, ``mix(tail) ->
+    (mixed, new tail)`` run on it, and the new tail written back in place
+    for the ``live`` slots [slots, 1] alone.  Returns (mixed, conv)."""
+    with jax.named_scope("state_read"):
+        tail = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
+    mixed, new = mix(tail)
+    with jax.named_scope("state_write"):
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, jnp.where(live[..., None], new.astype(conv.dtype), tail),
+            li, 0)
+    return mixed, conv
+
 
 def linear_prefill(x, mp, cfg: TransformerConfig, lengths):
     """One linear layer's mixer over whole right-padded rows.  x: [B, S, H]
     (any S) -> (mixer output [B, S, H], the state [B, heads, dk, dv] and
     the convolution tail [B, width - 1, C] each row leaves at its length)."""
-    s, cast, width = x.shape[1], x.dtype, cfg.linear_conv_width
-    # where each row's convolution tail sits: its last width-1 inputs
-    tail_pos = lengths[:, None] - (width - 1) + jnp.arange(width - 1)[None]
     with jax.named_scope(_scope(cfg)):
-        proj = x @ mp["w_qkv"].astype(cast)                     # [B, S, C]
+        proj = x @ mp["w_qkv"].astype(x.dtype)                  # [B, S, C]
     with jax.named_scope(_scope(cfg, "_conv")):
-        padded = jnp.pad(proj, ((0, 0), (width - 1, 0), (0, 0)))
-        conv = sum(padded[:, j:j + s] * mp["conv_w"][j].astype(cast)
-                   for j in range(width))
-        conv = jax.nn.silu(conv)
-        tail = jnp.take_along_axis(
-            proj, jnp.maximum(tail_pos, 0)[..., None], axis=1)
-        tail = jnp.where((tail_pos >= 0)[..., None], tail, 0)
+        conv, tail = _conv_rows(proj, mp, cfg.linear_conv_width, lengths)
     q, k, v = _split_heads(conv, cfg)
     g, beta = _gates(x, mp, cfg)
     with jax.named_scope(_scope(cfg)):
@@ -304,22 +403,103 @@ def linear_step(x, mp, cfg: TransformerConfig, li, state, conv, active):
     ``li``, updated in place; an inactive slot's recurrent state and
     convolution tail stay as they were.  Returns (mixer output [slots, 1,
     H], state, conv)."""
-    cast, width = x.dtype, cfg.linear_conv_width
     y, live = x[:, 0], active[:, None]                 # [slots, H], [slots, 1]
     with jax.named_scope(_scope(cfg)):
-        proj = y @ mp["w_qkv"].astype(cast)                     # [slots, C]
-    with jax.named_scope("state_read"):
-        tail = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
-    with jax.named_scope(_scope(cfg, "_conv")):
-        window = jnp.concatenate([tail.astype(cast), proj[:, None]], 1)
-        mixed = jax.nn.silu(sum(window[:, j] * mp["conv_w"][j].astype(cast)
-                                for j in range(width)))
-    with jax.named_scope("state_write"):
-        conv = jax.lax.dynamic_update_index_in_dim(
-            conv, jnp.where(live[..., None], window[:, 1:].astype(
-                conv.dtype), tail), li, 0)
+        proj = y @ mp["w_qkv"].astype(x.dtype)                  # [slots, C]
+
+    def mix(tail):
+        with jax.named_scope(_scope(cfg, "_conv")):
+            return _conv_step(proj, mp, cfg.linear_conv_width, tail)
+
+    mixed, conv = _state_io(li, conv, live, mix)
     q, k, v = _split_heads(mixed, cfg)
     g, beta = _gates(y, mp, cfg, live)
     with jax.named_scope(_scope(cfg)):
         state, o = _rule(cfg)[1](state, li, q, k, v, g, beta)
     return _mixer_out(o, y, mp, cfg)[:, None], state, conv
+
+
+def _ssm_split(mixed, cfg: TransformerConfig):
+    """Convolved channels [..., inner + 2 G N] -> x [..., H, P], B and C
+    [..., G, N]."""
+    inner, _ = cfg.ssm_channels
+    lead, gn = mixed.shape[:-1], cfg.ssm_groups * cfg.linear_key_dim
+    groups = lead + (cfg.ssm_groups, cfg.linear_key_dim)
+    return (mixed[..., :inner].reshape(
+                lead + (cfg.linear_num_heads, cfg.linear_value_dim)),
+            mixed[..., inner:inner + gn].reshape(groups),
+            mixed[..., inner + gn:].reshape(groups))
+
+
+def _ssm_step_size(dt, mp, live=None):
+    """``softplus(dt~ + dt_bias)`` in float32; 0 where ``live`` is given
+    and false: the identity on the state."""
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + mp["dt_bias"].astype(jnp.float32))
+    return dt if live is None else jnp.where(live, dt, 0.0)
+
+
+def _ssm_out(y, z, mp, cfg: TransformerConfig):
+    """y [..., H, P], z [..., H P] -> W_out [rmsnorm_group(y * silu(z))],
+    the norm over the ``H P / G`` channels of a group."""
+    cast = z.dtype
+    with jax.named_scope("ssm"):
+        gated = (y.reshape(z.shape).astype(jnp.float32)
+                 * jax.nn.silu(z.astype(jnp.float32)))
+        by_group = gated.reshape(z.shape[:-1] + (cfg.ssm_groups, -1))
+        by_group = by_group * jax.lax.rsqrt(
+            jnp.mean(by_group * by_group, -1, keepdims=True) + cfg.norm_eps)
+        normed = (by_group.reshape(z.shape)
+                  * mp["o_norm"]["scale"].astype(jnp.float32)).astype(cast)
+        return normed @ mp["w_out"].astype(cast)
+
+
+def _ssm_in(x, mp, cfg: TransformerConfig):
+    """x [..., H] -> (z, xBC, dt~) of ``W_in x``."""
+    inner, mixed = cfg.ssm_channels
+    with jax.named_scope("ssm"):
+        proj = x @ mp["w_in"].astype(x.dtype)
+    return (proj[..., :inner], proj[..., inner:inner + mixed],
+            proj[..., inner + mixed:])
+
+
+def ssm_prefill(x, mp, cfg: TransformerConfig, lengths):
+    """One ssm layer's mixer over whole right-padded rows.  x: [B, S, H]
+    (any S) -> (mixer output [B, S, H], the state [B, heads, P, N] and the
+    convolution tail [B, width - 1, C] each row leaves at its length)."""
+    z, xbc, dt = _ssm_in(x, mp, cfg)
+    with jax.named_scope("ssm_conv"):
+        mixed, tail = _conv_rows(xbc, mp, cfg.linear_conv_width, lengths)
+    xs, b, c = _ssm_split(mixed, cfg)
+    with jax.named_scope("ssm"):
+        # positions at or beyond a row's length leave its state alone
+        y, state = ssd.ssd_chunk_fwd(xs, _ssm_step_size(dt, mp), mp["A_log"],
+                                     b, c, mp["D"], lengths)
+    return _ssm_out(y, z, mp, cfg), state, tail
+
+
+def ssm_step(x, mp, cfg: TransformerConfig, li, state, conv, active):
+    """One ssm layer's mixer for one new token a slot; arguments and
+    results as ``linear_step``'s."""
+    y, live = x[:, 0], active[:, None]                 # [slots, H], [slots, 1]
+    z, xbc, dt = _ssm_in(y, mp, cfg)
+
+    def mix(tail):
+        with jax.named_scope("ssm_conv"):
+            return _conv_step(xbc, mp, cfg.linear_conv_width, tail)
+
+    mixed, conv = _state_io(li, conv, live, mix)
+    xs, b, c = _ssm_split(mixed, cfg)
+    with jax.named_scope("ssm"):
+        state, o = ssd.ssd_recurrent_step(
+            state, li, xs, _ssm_step_size(dt, mp, live), mp["A_log"], b, c,
+            mp["D"])
+    return _ssm_out(o, z, mp, cfg)[:, None], state, conv
+
+
+def recurrent(cfg: TransformerConfig):
+    """(kind, its mixer over whole rows, its mixer for one token a slot) of
+    the pattern's recurrent kind."""
+    if cfg.ssm_layers:
+        return "ssm", ssm_prefill, ssm_step
+    return "linear", linear_prefill, linear_step

@@ -195,24 +195,38 @@ def sort_by_expert(idx, held, experts: int, tile: int):
             (ends[-1] // tile).astype(jnp.int32), sizes)
 
 
-def _gmm_kernel(layer_ref, expert_ref, tiles_ref, x_ref, *refs, gated: bool):
+def relu2(out):
+    """Squared ReLU, the epilogue of an expert of two matrices."""
+    return jnp.square(jnp.maximum(out, 0.0))
+
+
+def _gmm_kernel(layer_ref, expert_ref, tiles_ref, x_ref, *refs, gated: bool,
+                activation: Optional[str], transposed: bool):
     del layer_ref, expert_ref                   # the index maps' only
     o_ref = refs[-1]
 
     @pl.when(pl.program_id(1) < tiles_ref[0])
     def _tile():
         x = x_ref[...]
-        out = jnp.dot(x, refs[0][0, 0], preferred_element_type=F32)
+        if transposed:              # the matrix as stored [N, K]
+            out = jax.lax.dot_general(x, refs[0][0, 0],
+                                      (((1,), (1,)), ((), ())),
+                                      preferred_element_type=F32)
+        else:
+            out = jnp.dot(x, refs[0][0, 0], preferred_element_type=F32)
         if gated:
             out = jax.nn.silu(out) * jnp.dot(x, refs[1][0, 0],
                                              preferred_element_type=F32)
+        elif activation:
+            out = relu2(out)
         o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _gmm_pallas(x, weights, layer, tile_expert, tiles, tile: int,
-                interpret: bool):
+                interpret: bool, activation: Optional[str] = None,
+                transposed: bool = False):
     rows, kdim = x.shape
-    n = weights[0].shape[-1]
+    n = weights[0].shape[-2 if transposed else -1]
     bn = BLOCK_N if n % BLOCK_N == 0 else n
     num_tiles = rows // tile
 
@@ -220,20 +234,23 @@ def _gmm_pallas(x, weights, layer, tile_expert, tiles, tile: int,
         return (_at(ti, tiles), 0)
 
     def w_map(ni, ti, layer, tile_expert, tiles):
-        return (layer[0], tile_expert[_at(ti, tiles)], 0, ni)
+        at = (ni, 0) if transposed else (0, ni)
+        return (layer[0], tile_expert[_at(ti, tiles)], *at)
 
     def o_map(ni, ti, layer, tile_expert, tiles):
         return (_at(ti, tiles), ni)
 
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, gated=len(weights) == 2),
+        functools.partial(_gmm_kernel, gated=len(weights) == 2,
+                          activation=activation, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             # output channels outermost: a tile's neighbours are of its
             # expert, whose weight block is then fetched once for them all
             grid=(n // bn, num_tiles),
             in_specs=[pl.BlockSpec((tile, kdim), x_map)]
-            + [pl.BlockSpec((1, 1, kdim, bn), w_map)] * len(weights),
+            + [pl.BlockSpec((1, 1, bn, kdim) if transposed
+                            else (1, 1, kdim, bn), w_map)] * len(weights),
             out_specs=pl.BlockSpec((tile, bn), o_map),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
@@ -246,19 +263,24 @@ def _gmm_pallas(x, weights, layer, tile_expert, tiles, tile: int,
       jnp.reshape(tiles, (1,)).astype(jnp.int32), x, *weights)
 
 
-def _gmm_jnp(x, weights, layer, tile_expert, tile: int):
+def _gmm_jnp(x, weights, layer, tile_expert, tile: int,
+             activation: Optional[str] = None, transposed: bool = False):
     """The twin of ``moe_gmm``: ``jax.lax.ragged_dot`` over the same padded
     layout (a tile is a group of its own), in the same precisions."""
     experts = weights[0].shape[1]
     sizes = jnp.zeros((experts,), jnp.int32).at[tile_expert].add(tile)
     ws = [jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
           for w in weights]
+    if transposed:
+        ws = [w.swapaxes(-1, -2) for w in ws]
     # every tile is counted to its expert, those that hold nothing to the
     # last tile's: rows nobody reads
     out = jax.lax.ragged_dot(x, ws[0], sizes, preferred_element_type=F32)
     if len(ws) == 2:
         out = jax.nn.silu(out) * jax.lax.ragged_dot(
             x, ws[1], sizes, preferred_element_type=F32)
+    elif activation:
+        out = relu2(out)
     return out.astype(x.dtype)
 
 
@@ -393,13 +415,20 @@ def _gmm_dw_pallas(x, dy, experts: int, tile_expert, tiles, tile: int,
       jnp.zeros((experts, kdim, n), F32))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _gmm_kernel_vjp(x, weights, layer, tile_expert, tiles, tile, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _gmm_kernel_vjp(x, weights, layer, tile_expert, tiles, tile, interpret,
+                    activation, transposed):
     return _gmm_pallas(x, weights, layer, tile_expert, tiles, tile,
-                       interpret)
+                       interpret, activation, transposed)
 
 
-def _gmm_vjp_fwd(x, weights, layer, tile_expert, tiles, tile, interpret):
+def _gmm_vjp_fwd(x, weights, layer, tile_expert, tiles, tile, interpret,
+                 activation, transposed):
+    if activation or transposed:
+        raise NotImplementedError(
+            "moe_gmm with an activation in its epilogue or a matrix stored "
+            "transposed has no backward: experts of two matrices are "
+            "served, not trained (a layer_pattern has no backward pass)")
     plan = (layer, tile_expert, tiles)
     if len(weights) == 1:
         out = _gmm_pallas(x, weights, *plan, tile, interpret)
@@ -412,7 +441,7 @@ def _gmm_vjp_fwd(x, weights, layer, tile_expert, tiles, tile, interpret):
     return out, (x, weights, plan, (gate, up))
 
 
-def _gmm_vjp_bwd(tile, interpret, res, dy):
+def _gmm_vjp_bwd(tile, interpret, activation, transposed, res, dy):
     x, weights, plan, gated = res
     layer, tile_expert, tiles = plan
     if gated is None:
@@ -442,27 +471,42 @@ _gmm_kernel_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
 
 def moe_gmm(x, weights, layer, tile_expert, tiles, tile: int,
             use_kernel: Optional[bool] = None,
-            interpret: Optional[bool] = None):
+            interpret: Optional[bool] = None,
+            activation: Optional[str] = None, transposed: bool = False):
     """Grouped matmul of expert-sorted rows with their experts' weights.
 
     x: [rows, K], rows in tiles of ``tile``, tile ``i`` of expert
     ``tile_expert[i]``, the first ``tiles`` of them holding anything
     (``sort_by_expert``); weights: one ``[layers, experts, K, N]`` stack, or
     two (gate and up) for ``silu(x W_g) * (x W_u)`` in one pass; layer: the
-    int32 scalar index into the stacks, traced or not.  Returns [rows, N] in
-    x's dtype; rows of tiles past ``tiles`` are undefined.  Only the weight
-    blocks of experts that have a tile are read, where they lie.
-    Differentiable in x and the weights (the module docstring's backward).
+    int32 scalar index into the stacks, traced or not; ``activation``
+    (static): None, or "relu2" for ``relu(x W)^2`` of the one-matrix form,
+    applied to the float32 product before it is rounded and written (the up
+    projection of an expert of two matrices: no round trip of the rows
+    through HBM); ``transposed`` (static): the one stack is ``[layers,
+    experts, N, K]``, each matrix as a linear map stores it, contracted over
+    its last dimension.  Returns [rows, N] in x's dtype; rows of tiles past
+    ``tiles`` are undefined.  Only the weight blocks of experts that have a
+    tile are read, where they lie.  Differentiable in x and the weights (the
+    module docstring's backward) in the plain forms; with an activation or a
+    transposed stack the kernel refuses to be differentiated (the twin has
+    ``ragged_dot``'s own derivative).
 
     ``use_kernel=None`` takes the Pallas kernel on a TPU and the twin
     elsewhere; ``interpret=True`` runs the kernel interpreted (tests)."""
     if use_kernel is None:
         use_kernel = bool(interpret) or jax.default_backend() == "tpu"
+    if activation not in (None, "relu2") or (
+            (activation or transposed) and len(weights) != 1):
+        raise ValueError(f"moe_gmm: activation {activation!r} (None or "
+                         "'relu2') and transposed are of the one-matrix form")
     if not use_kernel:
-        return _gmm_jnp(x, weights, layer, tile_expert, tile)
+        return _gmm_jnp(x, weights, layer, tile_expert, tile, activation,
+                        transposed)
     return _gmm_kernel_vjp(x, tuple(weights), jnp.asarray(layer, jnp.int32),
                            tile_expert, jnp.asarray(tiles, jnp.int32), tile,
-                           resolve_interpret(interpret, "moe_gmm"))
+                           resolve_interpret(interpret, "moe_gmm"),
+                           activation, transposed)
 
 
 @jax.custom_vjp
@@ -543,22 +587,31 @@ def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
     ``small``: this layer's ``router`` [H, E], ``bias`` [E] and, where the
     model has one, the shared expert's ``shared_gate`` / ``shared_in`` [H,
     S] and ``shared_out`` [S, H]; ``stacks``: ``w_gate``, ``w_in`` [layers,
-    held, H, M] and ``w_out`` [layers, held, M, H], the experts
+    held, H, M] and ``w_out`` [layers, held, M, H], or, an expert of two
+    matrices ``W_out relu(W_up x)^2``, ``w_up`` and ``w_out``, both [layers,
+    held, M, H]: the up projection as a linear map stores it, so that the
+    stack's minor dimension is H whatever M is (an M that is no multiple of
+    the 128 lanes would make the chip lay [.., H, M] out with H minor, and
+    every program that hands it to the kernel copy the whole stack first:
+    2.55 GB a dispatch at 64 experts of 2688 x 1856 in 4 layers, sandbox
+    compile, PR 46); the shared expert is of the same form without
+    ``shared_gate``; the experts
     ``expert_start`` to ``expert_start + held`` of all E (an int; or [T]
     int32, a first expert for each token, and the held weights stand for
     the experts from there on: ``TransformerConfig.share_by_position``);
     live: [T] bool, the tokens that count (a padded position, an idle slot:
-    routed nowhere, their output is the shared expert's alone).  ``shared=False`` leaves
-    the shared expert to another holder of this layer.  The router scores x
-    as it comes (float32 where the caller has it); the experts multiply it
-    in ``compute_dtype`` (x's own where none is given).
+    routed nowhere, their output is the shared expert's alone).
+    ``shared=False`` leaves the shared expert to another holder of this
+    layer.  The router scores x as it comes (float32 where the caller has
+    it); the experts multiply it in ``compute_dtype`` (x's own where none is
+    given).
 
     Returns (out [T, H] in x's dtype, counts [2] int32: assignments this
     layer computed, experts it touched; experts [T, k] int32: the router's
     choice for every token, live or not, among all E; load [held] int32:
     the assignments each held expert computed)."""
     t, _ = x.shape
-    held = stacks["w_gate"].shape[1]
+    held = stacks["w_out"].shape[1]
     with jax.named_scope("moe_route"):
         idx, gates = route_sigmoid(x, small["router"], small["bias"],
                                    experts_per_token, scaling)
@@ -577,15 +630,25 @@ def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
         gmm = functools.partial(moe_gmm, layer=layer, tile_expert=tile_expert,
                                 tiles=tiles, tile=tile, use_kernel=use_kernel,
                                 interpret=interpret)
-        act = gmm(xs, (stacks["w_gate"], stacks["w_in"]))
+        if "w_gate" in stacks:
+            act = gmm(xs, (stacks["w_gate"], stacks["w_in"]))
+        else:
+            act = gmm(xs, (stacks["w_up"],), activation="relu2",
+                      transposed=True)
         ys = gmm(act, (stacks["w_out"],))
     with jax.named_scope("moe_combine"):
         # an assignment that is not this layer's reads a row past the end
         out = _combine(ys, gates, mine, source, dest)
-    if shared and "shared_gate" in small:
+    if shared and "shared_in" in small:
         with jax.named_scope("moe_shared"):
-            out = out + ((jax.nn.silu(x @ small["shared_gate"].astype(x.dtype))
-                          * (x @ small["shared_in"].astype(x.dtype)))
-                         @ small["shared_out"].astype(x.dtype)).astype(F32)
+            if "shared_gate" in small:
+                up = (jax.nn.silu(x @ small["shared_gate"].astype(x.dtype))
+                      * (x @ small["shared_in"].astype(x.dtype)))
+            else:
+                # squared in float32 before it is rounded, as the kernel's
+                up = relu2(jnp.dot(x, small["shared_in"].astype(x.dtype),
+                                    preferred_element_type=F32)
+                            ).astype(x.dtype)
+            out = out + (up @ small["shared_out"].astype(x.dtype)).astype(F32)
     counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
     return out.astype(x.dtype), counts, idx, sizes

@@ -643,6 +643,166 @@ def test_solar_cell_program_fits_and_updates_its_cache_in_place(
         text)
 
 
+# ------ the state-space model's programs (PR 46): Nemotron-3-Nano's first
+# nine layers, each a sublayer alone (4 state-space, 4 expert layers of 64
+# held experts of two matrices, 1 attention layer of 2 KV heads), 64 slots
+# of 8,192: the state [4, 65, 64, 64, 128] float32 and the K/V rows updated
+# in place, the experts never out of their stack.
+
+NANO_SLOTS, NANO_MAX_LEN = 65, 8192
+NANO_STACKS = (f"bf16[1,{NANO_SLOTS},{NANO_MAX_LEN},256]",
+               f"f32[4,{NANO_SLOTS},64,64,128]")
+
+
+def _nano_cfg():
+    return mcfg.TransformerConfig(
+        vocab_size=65536, num_layers=9, hidden_size=2688, num_heads=32,
+        num_kv_heads=2, mlp_size=1856, max_seq_len=262144, norm_eps=1e-5,
+        use_rope=False, no_positions=True, attn_head_dim=128,
+        layer_pattern=("ssm", "mlp", "ssm", "mlp", "ssm", "full", "mlp",
+                       "ssm", "mlp"),
+        mlp_act="relu2",
+        linear_num_heads=64, linear_key_dim=128, linear_value_dim=64,
+        linear_conv_width=4, ssm_groups=8,
+        moe_dropless=True, num_experts=128, experts_per_token=6,
+        expert_mlp_size=1856, shared_experts=2, routed_scaling_factor=2.5,
+        expert_start=0, experts_held=64)
+
+
+@pytest.mark.parametrize("kernel", ["chunk_fwd", "recurrent_step",
+                                    "moe_gmm-relu2"])
+def test_ssd_and_two_matrix_expert_kernels_compile_at_published_sizes(
+        one_chip, kernel):
+    """64 heads of 64 over 8 groups of 128; an expert of 2688 x 1856, whose
+    width is no multiple of the 128 lanes: one block is the whole matrix."""
+    from ray_tpu.ops import moe, ssd
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    nh, p, g, n, bf, f32 = 64, 64, 8, 128, jnp.bfloat16, jnp.float32
+    if kernel == "chunk_fwd":
+        b, t = 1, 2048
+        _, text = _compile(
+            lambda *a: ssd.ssd_chunk_fwd(*a, use_kernel=True,
+                                         interpret=False),
+            S((b, t, nh, p), bf), S((b, t, nh), f32), S((nh,), bf),
+            S((b, t, g, n), bf), S((b, t, g, n), bf), S((nh,), bf),
+            S((b,), jnp.int32))
+    elif kernel == "recurrent_step":
+        slots = NANO_SLOTS
+        compiled, text = _compile(
+            lambda *a: ssd.ssd_recurrent_step(*a, use_kernel=True,
+                                              interpret=False),
+            S((4, slots, nh, p, n), f32), S((), jnp.int32),
+            S((slots, nh, p), bf), S((slots, nh), f32), S((nh,), bf),
+            S((slots, g, n), bf), S((slots, g, n), bf), S((nh,), bf),
+            donate_argnums=(0,))
+        # in place: the donated stack is the output, nothing beside it
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+    else:
+        held, h, em, tile = 64, 2688, 1856, 16
+        rows = (64 * 6 + held * (tile - 1) + tile - 1) // tile * tile
+
+        def up_down(x, w_up, w_out, layer, tile_expert, tiles):
+            plan = dict(layer=layer, tile_expert=tile_expert, tiles=tiles,
+                        tile=tile, use_kernel=True, interpret=False)
+            act = moe.moe_gmm(x, (w_up,), activation="relu2",
+                              transposed=True, **plan)
+            return moe.moe_gmm(act, (w_out,), **plan)
+
+        _, text = _compile(
+            up_down, S((rows, h), bf), S((4, held, em, h), bf),
+            S((4, held, em, h), bf), S((), jnp.int32),
+            S((rows // tile,), jnp.int32), S((), jnp.int32))
+        assert text.count(KERNEL) == 2
+    assert KERNEL in text
+
+
+@pytest.mark.parametrize("program,temp_gb,kernels", [
+    # a recurrent step a state-space layer, decode_attn, two grouped matmuls
+    # an expert layer
+    # (readings 0.004 and 0.92 GB)
+    ("decode", 0.1, 4 + 1 + 2 * 4),
+    # a chunked forward a state-space layer, flash_fwd, two grouped matmuls
+    # an expert layer
+    ("prefill-8192", 1.2, 4 + 1 + 2 * 4)])
+def test_nano_cell_program_fits_and_updates_its_cache_in_place(
+        one_chip, as_tpu, program, temp_gb, kernels):
+    """Under 15.0 GiB, as ISSUE 46 asks of the largest program (readings in
+    PERF.md section 4)."""
+    cfg = _nano_cfg()
+    args = _serve_shapes(one_chip, cfg, False, NANO_SLOTS, NANO_MAX_LEN)
+    if program == "decode":
+        fn = lambda p, c, st: decode.decode_state_loop(  # noqa: E731
+            p, c, st, STEPS, cfg, 0, jnp.bfloat16)
+    else:
+        args += _admit_rows(one_chip, int(program.split("-")[1]), 8)
+        fn = lambda p, c, st, *a: decode.prefill_admit(  # noqa: E731
+            p, c, st, *a, cfg, 0, jnp.bfloat16)
+    compiled, text = _compile(fn, *args, donate_argnums=(1, 2))
+    mem = compiled.memory_analysis()
+    print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+          mem.generated_code_size_in_bytes)
+    assert mem.temp_size_in_bytes < temp_gb * 1e9, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.0 * 2**30, f"{total / 2**30:.2f} GiB"
+    assert text.count(KERNEL) == kernels
+    for stack in NANO_STACKS:
+        assert stack in text
+        assert not re.search(r"= " + re.escape(stack) + r"\S* copy\(", text)
+    # no layer's experts leave their stack ([64, 1856, 2688] is 0.64 GB),
+    # and no stack is copied into another layout
+    assert not re.search(
+        r"= bf16\[(4,|1,)?64,1856,2688\]\S* "
+        r"(dynamic-slice|copy|fusion)\(", text)
+    # no layer's [slots, 64, 64, 128] slab is sliced out of the state
+    assert not re.search(
+        rf"= f32\[(1,)?{NANO_SLOTS},64,64,128\]\S* (dynamic-slice|copy)\(",
+        text)
+
+
+def test_the_nano_checks_prefill_runs_a_buckets_kernels(one_chip, as_tpu):
+    """The configuration's ``check`` compares a prefill and decode steps
+    through the kind's entry points with the reference on its own.  Its
+    prompt's length is no multiple of a chunk, and the kind's ``prefill``
+    pads the row to whole blocks as the engine's admits are padded to its
+    buckets: the compared prefill is the 2,048 bucket's row, with the flash
+    kernel (from 1,024 positions up, whole blocks of 512), a chunked scan a
+    state-space layer and two grouped products an expert layer in it; a row
+    of the prompt's own length would run plain attention."""
+    import json
+    import os
+    from benchmark.lib.manifest import load_model
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    kind = load_model(os.path.join(repo, "benchmark", "models",
+                                   "nemotron_h.py"))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "nemotron-3-nano-30b-a3b-serve-l9-e64.json")) as f:
+        doc = json.load(f)
+    cfg, chk = kind.program_config(doc), doc["serve"]["check"]
+    n_prompt, n_dec = chk["prompt_len"], chk["decode_steps"]
+    bucket = -(-n_prompt // kind.ROW_BLOCK) * kind.ROW_BLOCK
+    cache_len = -(-(n_prompt + n_dec + 1) // 128) * 128
+    assert n_prompt % 128 and n_dec >= 256
+    assert 1024 <= bucket <= cache_len and bucket in doc["serve"]["buckets"]
+    S = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: kind.init_params(k, cfg, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    cache = _on(one_chip, jax.eval_shape(lambda: kind.init_cache(
+        cfg, 1, cache_len, jnp.bfloat16)))
+    # as ``serve_app._check_reference`` jits it
+    _, text = _compile(
+        lambda p, c, t, ln, sl: kind.prefill(p, c, t, ln, sl, cfg),
+        params, cache, S((1, n_prompt), jnp.int32), S((1,), jnp.int32),
+        S((1,), jnp.int32))
+    assert text.count(KERNEL) == 4 + 1 + 2 * 4
+    for name in ("flash_fwd", "ssd_chunk_fwd", "moe_gmm"):
+        assert name in text, name
+    assert f"s32[1,{bucket}]" in text
+
+
 # ---------- the programs that walk whole rows are the parent's (PR 37)
 # Only a dense tree's bucket of four chunks or more compiles to another
 # program; every other one keeps the temporaries and the generated code size

@@ -578,3 +578,78 @@ def test_kda_serve_programs_carry_their_scopes(kda_cfg):
                 stats["linear_layers"], stats["full_layers"]) == (4, 4, 3, 1)
     finally:
         eng.shutdown()
+
+
+# ---- a state-space mixer, layers that are one sublayer alone, experts of
+# ---- two matrices (PR 46)
+
+@pytest.fixture(scope="module")
+def ssm_cfg():
+    from ray_tpu.models.config import TransformerConfig
+    return TransformerConfig(
+        vocab_size=256, num_layers=9, hidden_size=64, num_heads=4,
+        num_kv_heads=2, mlp_size=24, max_seq_len=64, use_rope=False,
+        no_positions=True, attn_head_dim=32,
+        layer_pattern=("ssm", "mlp", "ssm", "mlp", "ssm", "full", "mlp",
+                       "ssm", "mlp"),
+        mlp_act="relu2", linear_num_heads=4,
+        linear_key_dim=32, linear_value_dim=16, ssm_groups=2,
+        moe_dropless=True, num_experts=16, experts_per_token=3,
+        expert_mlp_size=24, shared_experts=2, routed_scaling_factor=2.5,
+        expert_start=8, experts_held=8)
+
+
+def test_the_ssd_kernels_are_named():
+    """The names a device trace shows (``ssd_chunk_fwd [pallas]``,
+    ``ssd_recurrent_step [pallas]``), which the benchmark's ssd_* readers
+    spell out for themselves."""
+    from ray_tpu.ops import ssd
+
+    assert ssd.KERNEL_SSD_CHUNK_FWD == "ssd_chunk_fwd"
+    assert ssd.KERNEL_SSD_RECURRENT_STEP == "ssd_recurrent_step"
+    readers = _reader("_ssd.py")
+    assert (readers.CHUNK_FWD, readers.RECURRENT_STEP, readers.MOE_GMM) == (
+        ssd.KERNEL_SSD_CHUNK_FWD, ssd.KERNEL_SSD_RECURRENT_STEP, "moe_gmm")
+    assert _reader("ssm_moe_kernels_device_share.py").KERNELS == (
+        "ssd_chunk_fwd", "ssd_recurrent_step", "moe_gmm")
+    x = jnp.ones((1, 128, 2, 8), jnp.float32)
+    b = jnp.ones((1, 128, 1, 16), jnp.float32)
+    a = jnp.zeros((2,), jnp.float32)
+    chunk = jax.make_jaxpr(lambda *args: ssd.ssd_chunk_fwd(
+        *args, interpret=True))(x, x[..., 0], a, b, b, a)
+    assert "ssd_chunk_fwd" in str(chunk)
+    step = jax.make_jaxpr(lambda *args: ssd.ssd_recurrent_step(
+        *args, interpret=True))(jnp.zeros((2, 1, 2, 8, 16)), jnp.int32(1),
+                                x[:, 0], x[:, 0, :, 0], a, b[:, 0], b[:, 0],
+                                a)
+    assert "ssd_recurrent_step" in str(step)
+
+
+def test_ssm_serve_programs_carry_their_scopes(ssm_cfg):
+    """The state-space mixer's pieces under ``ssm`` / ``ssm_conv`` (state
+    reads and writes keep ``state_read`` / ``state_write``), the expert
+    layer's under ``moe_*`` as under any layer."""
+    eng = _engine(ssm_cfg)
+    try:
+        decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
+        assert _module_name(decode) == "jit_engine_decode"
+        shared = {"attn", "norm", "lm_head", "kv_write", "ssm", "ssm_conv",
+                  "state_write", "moe_route", "moe_sort", "moe_experts",
+                  "moe_shared", "moe_combine"}
+        assert shared | {"kv_read", "state_read"} <= _scopes(decode)
+        admit = eng._prefill_fn(16).lower(
+            eng.params, eng.cache, eng._state, *eng._admit_arrays([], 16, []))
+        assert _module_name(admit) == "jit_admit_fn"
+        assert shared <= _scopes(admit)
+        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
+        stats = {**eng.counters(), **eng.breakdown()}
+        assert {"cache_kv_bytes", "cache_state_bytes", "linear_layers",
+                "ssm_layers", "full_layers", "experts_held", "expert_layers",
+                "moe_assignments", "moe_experts_touched",
+                "moe_expert_layer_steps", "moe_assignments_prefill"} <= set(
+                    stats)
+        assert (stats["experts_held"], stats["expert_layers"],
+                stats["linear_layers"], stats["ssm_layers"],
+                stats["full_layers"]) == (8, 4, 0, 4, 1)
+    finally:
+        eng.shutdown()
