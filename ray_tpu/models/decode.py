@@ -29,6 +29,11 @@ says which mixers apply (``prefill``, ``window_step``):
   layer shared by all heads, where the configuration has latent attention
   (``latent.py``): ``latent.prefill_attention`` / ``decode_attention``.
 
+Rows alone (K/V or latent) may be continued: ``continued_attention``, this
+file's and ``latent.py``'s, writes a chunk of one row after what its slot
+holds and attends over the slot so far; a long row is walked that way in
+counted chunks (``prefill_width``).
+
 TPU-first design:
 * **Static shapes.**  Continuous batching admits/retires sequences by slot
   index — tensor shapes never change, so jit compiles one prefill per length
@@ -490,22 +495,36 @@ def prefill_attention(y, ap, cfg: TransformerConfig, positions):
 #: 128 is 9.4, the 8.1 ms read of the weights being the floor under both
 #: (PERF.md section 5, "The admit program alone").
 PREFILL_CHUNK = 512
+#: Rows of a chunk an expert is handed, under uniform routing, where the
+#: layers have dropless experts: the MXU's tile.  The grouped matmul fetches
+#: every touched expert's weights once a call whatever the rows, and a chunk
+#: of a prompt touches them all: 10.3 ms a chunk of Xing4.0's six expert
+#: layers, against 1.4 ms of multiplying at 32 rows an expert (a chunk of
+#: 512); at 128 rows the two meet (PERF.md section 6, PR 47).
+EXPERT_TILE = 128
 
 
 def _rows_alone(cache: KVCache) -> bool:
-    """A dense K/V tree: rows a prefill may start after.  A paged tree
-    starts after a prefix its own way (``page_attention``); a recurrent
-    state and latent rows take no start yet."""
-    return not any(n in cache for n in ("block_table", "state", "latent"))
+    """Rows a prefill may start after, K/V or latent: what a position left
+    in its slot is all a later one needs of it.  A paged tree starts after
+    a prefix its own way (``page_attention``); a recurrent state takes no
+    start yet."""
+    return not any(n in cache for n in ("block_table", "state"))
 
 
-def prefill_width(cache: KVCache, bucket: int,
+def prefill_width(cache: KVCache, bucket: int, cfg: TransformerConfig,
                   chunk: int = PREFILL_CHUNK) -> int:
     """How many positions of a row of ``bucket`` one pass of the admit
-    program walks: ``chunk`` where the row is walked in counted chunks, else
-    the whole bucket.  Read off shapes alone: a dense K/V tree and a bucket
-    of at least four chunks (where buckets double, every length of a
-    shorter one needs all of its chunks, and nothing could be saved)."""
+    program walks: a chunk where the row is walked in counted chunks, else
+    the whole bucket.  Read off shapes alone.  The chunk is ``chunk``, or
+    under dropless experts the shortest multiple of it that hands an expert
+    ``EXPERT_TILE`` rows (Xing4.0's 64 experts, 4 a token: 2,048); a row is
+    walked in chunks on a tree of rows alone at a bucket of at least four
+    chunks (where buckets double, every length of a shorter one needs all of
+    its chunks, and nothing could be saved)."""
+    if cfg.moe_dropless:
+        rows = -(-EXPERT_TILE * cfg.num_experts // cfg.experts_per_token)
+        chunk *= -(-rows // chunk)
     chunked = (_rows_alone(cache) and bucket >= 4 * chunk
                and bucket % chunk == 0)
     return chunk if chunked else bucket
@@ -536,40 +555,57 @@ def continued_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, slot,
     return _proj_out(attn, ap, y.dtype, y), k_all, v_all
 
 
+def _said(choices, ys, live, slot, at):
+    """The record of the routers' choices with a walk's ``ys`` written at
+    ``[:, slot, at ..]``: -1 at a position that is not ``live``."""
+    return jax.lax.dynamic_update_slice(
+        choices, jnp.where(live[None, ..., None], ys["experts"], -1),
+        (0, slot, at, 0))
+
+
 def _prefill_chunks(params: Params, cache: KVCache, tokens: jnp.ndarray,
                     length: jnp.ndarray, slot: jnp.ndarray,
                     start: jnp.ndarray, width: int, span: int,
                     cfg: TransformerConfig, compute_dtype
                     ) -> Tuple[KVCache, jnp.ndarray]:
-    """``_prefill_row`` on a dense K/V tree in ``ceil(length / width)``
+    """``_prefill_row`` on a tree of rows alone in ``ceil(length / width)``
     passes of ``width`` positions, a loop whose trip count is data: each
     pass is the same walk over ``[1, width]`` tokens with a mixer that
     writes the chunk's rows into the slot in place and reads the slot's rows
-    up to them (``continued_attention``), so a row costs the chunks its
-    prompt fills and the chunks past them are left as they were.  The last
-    pass holds the prompt's last token; the head runs on it once, after the
-    loop."""
+    up to them (``continued_attention``, the tree's kind's), so a row costs
+    the chunks its prompt fills and the chunks past them are left as they
+    were.  The last pass holds the prompt's last token; the head runs on it
+    once, after the loop."""
     last = jnp.maximum(length - 1, 0)      # the prompt's last real token
+    attention, rows = continued_attention, ("k", "v")
+    if "latent" in cache:
+        from .latent import continued_attention as attention
+        rows = LATENT
+    kept = rows + ((CHOICES,) if CHOICES in cache else ())
 
     def one(c, walk):
-        k_all, v_all, _ = walk
+        held, _ = walk
         at = c * width
-        x, carry, _ = layer_stack(
+        # a padded position is routed to no expert
+        live = ((at + jnp.arange(width))[None] < length[:, None]
+                if cfg.moe_dropless else None)
+        x, carry, ys = layer_stack(
             params, jax.lax.dynamic_slice_in_dim(tokens, at, width, 1),
             (start + at)[:, None] + jnp.arange(width)[None],
-            {"full": _kv_mixer(continued_attention, cfg, slot,
-                               (start + at)[0], span)},
-            {"full": (k_all, v_all)}, cfg, compute_dtype,
-            jnp.clip(last - at, 0, width - 1),
-            ((at + jnp.arange(width))[None] < length[:, None]
-             if cfg.moe_dropless else None), head=False)
-        return (*carry["full"], x)
+            {"full": _kv_mixer(attention, cfg, slot, (start + at)[0], span)},
+            {"full": tuple(held[n] for n in rows)}, cfg, compute_dtype,
+            jnp.clip(last - at, 0, width - 1), live, head=False)
+        held = dict(held, **dict(zip(rows, carry["full"])))
+        if CHOICES in held:
+            held[CHOICES] = _said(held[CHOICES], ys, live, slot,
+                                  (start + at)[0])
+        return held, x
 
-    k_all, v_all, x = jax.lax.fori_loop(
+    held, x = jax.lax.fori_loop(
         0, jnp.maximum(-(-length[0] // width), 1), one,
-        (cache["k"], cache["v"],
+        ({n: cache[n] for n in kept},
          jnp.zeros((1, cfg.hidden_size), compute_dtype)))
-    return (dict(cache, k=k_all, v=v_all, length=cache["length"].at[slot].set(
+    return (dict(cache, **held, length=cache["length"].at[slot].set(
         (start + length)[0])), lm_head_logits(params, x, cfg))
 
 
@@ -579,15 +615,16 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
                  compute_dtype, chunk: int) -> Tuple[KVCache, jnp.ndarray]:
     """One prompt through the layers and into its slot of ``cache``, which
     comes and goes in place.  tokens: [1, S]; length, start: [1] (start
-    None: the row begins its slot); slot: a scalar.  A dense K/V tree walks
-    a long row in chunks (``prefill_width``) and any row that has a start
-    after what its slot holds, in one chunk if it is short.  Returns (cache,
-    last-token logits [1, V] f32)."""
+    None: the row begins its slot); slot: a scalar.  A tree of rows alone,
+    K/V or latent, walks a long row in chunks (``prefill_width``) and any
+    row that has a start after what its slot holds, in one chunk if it is
+    short.  Returns (cache, last-token logits [1, V] f32)."""
     s = tokens.shape[1]
-    width = prefill_width(cache, s, chunk)
+    width = prefill_width(cache, s, cfg, chunk)
     if width < s or (start is not None and _rows_alone(cache)):
         # a start that is data may lie anywhere in the slot
-        span = s if start is None else cache["k"].shape[2]
+        held = cache["latent" if "latent" in cache else "k"]
+        span = s if start is None else held.shape[2]
         return _prefill_chunks(
             params, cache, tokens, length, slot,
             jnp.zeros_like(length) if start is None else start, width, span,
@@ -615,10 +652,7 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
     def choices(ys):
         """``new`` with the row's routing recorded, where the tree asks."""
         if CHOICES in cache:
-            new[CHOICES] = jax.lax.dynamic_update_slice(
-                cache[CHOICES], jnp.where(live[None, ..., None],
-                                          ys["experts"], -1),
-                (0, slot, start[0], 0))
+            new[CHOICES] = _said(cache[CHOICES], ys, live, slot, start[0])
         return new
 
     if "latent" in cache:
@@ -680,34 +714,38 @@ def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
     walks the first ``rows`` of them one after another in a loop whose trip
     count is that number, data.  Each pass takes one row [1, S] through
     ``layer_stack`` and writes its slot of the cache, carried through the
-    loop in place; on a dense K/V tree a row of four chunks or more
-    (``prefill_width``) is itself a loop over the ``chunk``-position pieces
-    its prompt fills, each written into the slot and attending over what the
-    slot holds by then.  Rows past the count are not computed: their slots,
-    lengths and states stay as they were and their logits read 0; nor are
-    the chunks past a prompt's last token, and their rows of the slot stay
-    as they were.  (A [8, 2048] admit of Mistral's 14 layers was 664 ms on
-    the chip with one prompt in it or eight, PERF.md, PR 32; a row of 2,048
-    was 76 ms whether its prompt had 1,100 tokens or 2,000, PR 37.)
+    loop in place; on a tree of rows alone, K/V or latent, a row of four
+    chunks or more (``prefill_width``: a chunk is ``chunk`` positions, 2,048
+    under Xing4.0's experts) is itself a loop over the chunks its prompt
+    fills, each written into the slot and attending over what the slot holds
+    by then.  Rows past the count are not computed: their slots, lengths and
+    states stay as they were and their logits read 0; nor are the chunks
+    past a prompt's last token, and their rows of the slot stay as they
+    were.  (A [8, 2048] admit of Mistral's 14 layers was 664 ms on the chip
+    with one prompt in it or eight, PERF.md, PR 32; a row of 2,048 was 76 ms
+    whether its prompt had 1,100 tokens or 2,000, PR 37; a latent row of
+    8,192 was 272 ms whether its prompt had 4,100 tokens or 8,000, PR 47.)
 
     tokens: [B, S] int32 (right-padded to the bucket length S)
     lengths: [B] true prompt lengths; slot_ids: [B] cache rows to fill.
     start_pos: [B], the absolute position of ``tokens[:, 0]``, where the
       slot already holds what comes before: on a paged tree its block-table
       row points at pages with a reused prefix (read, never written here);
-      on a dense tree rows ``0 .. start_pos`` of the slot, which an earlier
-      call wrote (``start_pos + S`` may not pass the slot's ``max_len``).
+      on a tree of rows alone, K/V or latent, rows ``0 .. start_pos`` of
+      the slot, which an earlier call wrote (``start_pos + S`` may not pass
+      the slot's ``max_len``).
     rows: scalar int32, how many rows hold a prompt, real rows first; every
       row where it is not given.
-    chunk: the chunk's length (static); the engine leaves it alone.
+    chunk: the shortest chunk (static; ``prefill_width`` lengthens it for
+      dropless experts); the engine leaves it alone.
     Returns (cache, last-token logits [B, V] f32).
     """
     tokens, lengths, slot_ids = map(jnp.asarray, (tokens, lengths, slot_ids))
     b = tokens.shape[0]
     if start_pos is not None and not ("block_table" in cache
                                       or _rows_alone(cache)):
-        raise ValueError("start_pos: a recurrent state and latent rows take "
-                         "no prefix to start after")
+        raise ValueError("start_pos: a recurrent state takes no prefix to "
+                         "start after")
 
     def one(r, walk):
         cache, logits = walk

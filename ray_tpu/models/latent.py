@@ -293,6 +293,26 @@ def _w_ukv(ap, cfg: TransformerConfig, cast):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
+def _query(q_nope, q_rope, cfg: TransformerConfig, cast):
+    """The expanded form's queries [.., NH, dn + R].  The flash kernels
+    scale by the query's width, so the query carries the difference to the
+    kind's own scale."""
+    q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(F32)
+    return (q * (softmax_scale(cfg) * cfg.qk_head_dim ** 0.5)).astype(cast)
+
+
+def _write(latent_all, rope_all, c_kv, k_r, i, slot, at):
+    """One row's ``c_kv`` [1, W, C] and ``k_r`` [1, W, R] at ``[i, slot, at
+    : at + W]`` of the stacked cache, in place."""
+    with jax.named_scope("latent_write"):
+        return (_row_major(jax.lax.dynamic_update_slice(
+            latent_all, c_kv.astype(latent_all.dtype)[None],
+            (i, slot, at, 0))),
+                _row_major(jax.lax.dynamic_update_slice(
+                    rope_all, k_r.astype(rope_all.dtype).swapaxes(1, 2)[None],
+                    (i, slot, 0, at))))
+
+
 def _expanded(q_nope, q_rope, c_kv, k_r, ap, cfg: TransformerConfig):
     """Causal latent attention over whole rows in the expanded form, from
     what ``_down`` returns: keys and values rebuilt per head from the latent
@@ -306,20 +326,18 @@ def _expanded(q_nope, q_rope, c_kv, k_r, ap, cfg: TransformerConfig):
         w_uk, w_uv = _w_ukv(ap, cfg, cast)
         k_nope = jnp.einsum("bsc,chd->bshd", c_kv, w_uk)
         v = jnp.einsum("bsc,chd->bshd", c_kv, w_uv)
-    # the heads at their own two widths, the sequence in whole blocks; the
-    # kernel scales by the query's width, so the query carries the difference
+    # the heads at their own two widths, the sequence in whole blocks
     seq = -(-s // FLASH_BLOCK) * FLASH_BLOCK if s >= 2 * FLASH_BLOCK else s
 
     def fit(a):
         return jnp.pad(a, ((0, 0), (0, seq - s), (0, 0), (0, 0)))
 
-    q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(F32) \
-        * (softmax_scale(cfg) * cfg.qk_head_dim ** 0.5)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_r[:, :, None], (b, s, nh, k_r.shape[-1]))],
         axis=-1)
     with jax.named_scope("attn"):
-        attn = mha(fit(q.astype(cast)), fit(k), fit(v), causal=True)
+        attn = mha(fit(_query(q_nope, q_rope, cfg, cast)), fit(k), fit(v),
+                   causal=True)
     attn = attn[:, :s].reshape(b, s, -1)
     with jax.named_scope("attn"):
         return attn @ ap["wo"].astype(cast)
@@ -343,14 +361,74 @@ def prefill_attention(y, ap, cfg: TransformerConfig, latent_all, rope_all,
     ``[i, slot]`` of the stacked cache, in place.  Returns (attention after
     its output projection [1, S, H], latent_all, rope_all)."""
     q_nope, q_rope, c_kv, k_r = _down(y, ap, cfg, positions)
-    with jax.named_scope("latent_write"):
-        latent_all = _row_major(jax.lax.dynamic_update_slice(
-            latent_all, c_kv.astype(latent_all.dtype)[None], (i, slot, 0, 0)))
-        rope_all = _row_major(jax.lax.dynamic_update_slice(
-            rope_all, k_r.astype(rope_all.dtype).swapaxes(1, 2)[None],
-            (i, slot, 0, 0)))
-    return (_expanded(q_nope, q_rope, c_kv, k_r, ap, cfg), latent_all,
-            rope_all)
+    return (_expanded(q_nope, q_rope, c_kv, k_r, ap, cfg),
+            *_write(latent_all, rope_all, c_kv, k_r, i, slot, 0))
+
+
+def _up(c_kv, k_r, ap, cfg: TransformerConfig):
+    """The expanded form's keys and values of one sequence's cached rows,
+    a head's rows apart.  c_kv: [S, C]; k_r: [S, R] -> (keys [NH, S, dn + R],
+    every head's rotary part the one ``k_r``, values [NH, S, dv])."""
+    with jax.named_scope("mla_up"):
+        w_uk, w_uv = _w_ukv(ap, cfg, c_kv.dtype)
+        k_nope = jnp.einsum("sc,chd->hsd", c_kv, w_uk)
+        v = jnp.einsum("sc,chd->hsd", c_kv, w_uv)
+    return jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_r[None], (cfg.num_heads,) + k_r.shape)],
+        axis=-1), v
+
+
+def continued_attention(y, ap, cfg: TransformerConfig, latent_all, rope_all,
+                        i, slot, start, span: int):
+    """One layer's latent attention for W tokens of one row that continue
+    what its slot holds, the expanded form (``decode.continued_attention``
+    is the dense kind's).  y: [1, W, H] at positions ``start ..``; writes
+    the tokens' ``c_kv`` and ``k_r`` at ``[i, slot, start : start + W]`` of
+    the stacked cache in place and attends, causally, over the row so far:
+    keys and values [NH, span, ..] (``span`` static, ``start + W <= span``)
+    rebuilt from the slot's latent rows ``0 .. start`` in blocks of W, a
+    loop whose trip count is data, so the work goes by what the slot holds
+    and not by the bucket, and the tokens' own from what ``_down`` gave;
+    what lies past them stays zero and is masked.  They are one layer's
+    temporaries, not a cache.  The absorbed form would read the latent rows
+    as they lie, at 576-wide keys and 512-wide values for 192 / 128: 3.4
+    times the kernel's work.  Returns (attention after its output
+    projection [1, W, H], latent_all, rope_all)."""
+    from ..ops.flash_attention import flash_attention_rows
+    w, cast = y.shape[1], y.dtype
+    q_nope, q_rope, c_kv, k_r = _down(y, ap, cfg,
+                                      start + jnp.arange(w)[None])
+    latent_all, rope_all = _write(latent_all, rope_all, c_kv, k_r, i, slot,
+                                  start)
+
+    def put(kv, rows, at):
+        # (as the kernel reads them: left alone the compiler carries the
+        # keys positions-minor-most and transposes them once a call)
+        return tuple(_row_major(jax.lax.dynamic_update_slice_in_dim(
+            a, r, at, 1)) for a, r in zip(kv, rows))
+
+    def block(b, kv):
+        """Rows ``b * W ..`` of the prefix, read back from the slot."""
+        c = jax.lax.dynamic_slice(
+            latent_all, (i, slot, b * w, 0), (1, 1, w, cfg.kv_lora_rank))
+        r = jax.lax.dynamic_slice(
+            rope_all, (i, slot, 0, b * w), (1, 1, cfg.qk_rope_head_dim, w))
+        with jax.named_scope("latent_read"):
+            c, r = c[0, 0].astype(cast), r[0, 0].T.astype(cast)
+        return put(kv, _up(c, r, ap, cfg), b * w)
+
+    kv = jax.lax.fori_loop(
+        0, -(-start // w), block,
+        (jnp.zeros((cfg.num_heads, span, cfg.qk_head_dim), cast),
+         jnp.zeros((cfg.num_heads, span, cfg.v_head_dim), cast)))
+    # the tokens' own rows last: a start that is no whole number of blocks
+    # leaves the last block reaching into them
+    k, v = put(kv, _up(c_kv[0], k_r[0], ap, cfg), start)
+    with jax.named_scope("attn"):
+        attn = flash_attention_rows(_query(q_nope, q_rope, cfg, cast),
+                                    k[None, None], v[None, None], 0, 0,
+                                    start, span, cfg.num_heads)
+        return attn @ ap["wo"].astype(cast), latent_all, rope_all
 
 
 def decode_attention(y, ap, cfg: TransformerConfig, latent_all, rope_all, i,

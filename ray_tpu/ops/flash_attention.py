@@ -26,6 +26,10 @@ Design:
   and its gradient `d_v`; each kernel reads both off its operands.  A latent
   head's 192 / 128 runs as it is; where the two are equal (every other
   caller) the kernels lower to what one width gave.
+* **A query offset that is data** (`flash_attention_rows`): the forward
+  kernel for a chunk of a row that continues what a slot holds, over rows
+  where they lie: the stacked K/V cache with a position's heads side by side,
+  or a latent head's rebuilt keys and values, a head's rows apart.
 
 Numerics: logits and softmax statistics in f32 (MXU accumulates f32 via
 ``preferred_element_type``); probabilities cast back to the input dtype for
@@ -171,36 +175,62 @@ def _fwd_rows_kernel(at_ref, *refs, **static):
 
 def _flash_fwd_rows(q, k_all, v_all, at, num_heads: int, kv_len: int,
                     block_q: int, block_kv: int, interpret: bool):
-    """The forward kernel on rows where they lie.  q: [W, NH * D], a
-    position's heads side by side; k_all, v_all: [layers, slots, max_len,
-    NKV * D]; at: int32 [3], (layer, slot, the first query's position).  A
-    head is a block of D lanes of a row, so nothing is sliced out of the
-    stack and nothing is transposed on the way in or out.  Returns [W,
-    NH * D]."""
-    w, d = q.shape[0], q.shape[1] // num_heads
-    reps = num_heads * d // k_all.shape[-1]
-    rows = pl.BlockSpec((1, 1, kv_len, d),
-                        lambda bi, hi, qi, at: (at[0], at[1], 0, hi // reps))
-    heads = pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, qi, at: (0, 0, qi, hi))
+    """The forward kernel on rows where they lie; at: int32 [3], (layer,
+    slot, the first query's position).  Keys and queries are as wide as
+    each other, values and the output as each other; both widths are read
+    off the operands.  Two layouts, one kernel:
+
+    * heads side by side: q [W, NH * Dqk], a position's heads in one row;
+      k_all [layers, slots, max_len, NKV * Dqk], v_all [.., NKV * Dv].  A
+      head is a block of lanes of a row (whole 128s), so nothing is sliced
+      out of the stack and nothing is transposed on the way in or out.
+      Returns [W, NH * Dv].
+    * a head's rows apart: q [NH, W, Dqk]; k_all [layers, slots, NKV,
+      max_len, Dqk], v_all [.., Dv], a block a head's whole width whatever it
+      is (a latent head's 192 is no whole block of a row).  Returns [NH, W,
+      Dv]."""
+    apart = k_all.ndim == 5
+    if apart:
+        w, d, d_v = q.shape[1], q.shape[2], v_all.shape[4]
+        reps, slots = num_heads // k_all.shape[2], k_all.shape[1]
+        k_all, v_all = (a.reshape((-1,) + a.shape[2:]) for a in (k_all, v_all))
+        q, shape = q[None], (1, num_heads, w, d_v)
+        row_at = lambda at, g: (at[0] * slots + at[1], g, 0, 0)  # noqa: E731
+        head_at = lambda hi, qi: (0, hi, qi, 0)                  # noqa: E731
+    else:
+        w, d = q.shape[0], q.shape[1] // num_heads
+        reps = num_heads * d // k_all.shape[-1]
+        d_v = v_all.shape[-1] * reps // num_heads
+        q, shape = q[None, None], (1, 1, w, num_heads * d_v)
+        row_at = lambda at, g: (at[0], at[1], 0, g)              # noqa: E731
+        head_at = lambda hi, qi: (0, 0, qi, hi)                  # noqa: E731
+
+    def rows(width):        # a KV head's rows of the slot, all kv_len
+        return pl.BlockSpec((1, 1, kv_len, width),
+                            lambda bi, hi, qi, at: row_at(at, hi // reps))
+
+    def heads(width):       # a block of a head's queries, or of its output
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda bi, hi, qi, at: head_at(hi, qi))
+
     out, _ = pl.pallas_call(
         functools.partial(_fwd_rows_kernel, block_kv=block_kv, seq_kv=kv_len,
                           causal=True, scale=d ** -0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(1, num_heads, w // block_q),
-            in_specs=[heads, rows, rows],
-            out_specs=[heads,
+            in_specs=[heads(d), rows(d), rows(d_v)],
+            out_specs=[heads(d_v),
                        pl.BlockSpec((1, 1, 8, block_q),
                                     lambda bi, hi, qi, at: (0, hi, 0, qi))],
         ),
-        out_shape=[jax.ShapeDtypeStruct((1, 1) + q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct(shape, q.dtype),
                    jax.ShapeDtypeStruct((1, num_heads, 8, w), jnp.float32)],
-        **_fwd_vmem(kv_len, d, d, k_all.dtype),
+        **_fwd_vmem(kv_len, d, d_v, k_all.dtype),
         interpret=interpret,
         name=KERNEL_FLASH_ROWS,
-    )(at, q[None, None], k_all, v_all)
-    return out[0, 0]
+    )(at, q, k_all, v_all)
+    return out[0] if apart else out[0, 0]
 
 
 def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
@@ -574,12 +604,15 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
 
 
 def flash_rows_supported(width: int, kv_len: int, head_dim: int,
-                         block_q: int = 512, block_kv: int = 512
-                         ) -> Optional[str]:
+                         block_q: int = 512, block_kv: int = 512,
+                         apart: bool = False) -> Optional[str]:
     """None when ``flash_attention_rows`` can take its kernel at this shape,
-    else the reason."""
-    if head_dim % 128:
-        return f"a head of {head_dim} lanes is no whole block of a row"
+    else the reason.  ``head_dim``: a head's width, the widest of the two
+    where they differ; ``apart``: a head's rows lie apart, not side by side
+    with the position's other heads."""
+    if head_dim % (64 if apart else 128):
+        return (f"a head of {head_dim} lanes is no whole block of "
+                f"{'64 lanes' if apart else 'a row'}")
     return flash_supported(width, kv_len, 1, 1, block_q, block_kv)
 
 
@@ -589,15 +622,19 @@ def flash_attention_rows(q, k_all, v_all, layer, slot, start, kv_len: int,
                          use_kernel: Optional[bool] = None,
                          interpret: Optional[bool] = None) -> jnp.ndarray:
     """Causal attention of W queries of one sequence that sit at positions
-    ``start .. start + W`` of a slot of the stacked cache, over the slot's
-    rows ``0 .. kv_len`` where they lie, the queries' own rows among them
+    ``start .. start + W`` of a slot of stacked rows, over the slot's rows
+    ``0 .. kv_len`` where they lie, the queries' own rows among them
     (already written): the forward kernel with a query offset that is data.
 
-    q: [1, W, NH, D]; k_all, v_all: [layers, slots, max_len, NKV * D];
-    layer, slot, start: int32 scalars, traced or not; ``kv_len`` (static)
-    bounds what the row may hold, ``start + W <= kv_len``; rows past a
-    query's own position are masked, whatever they hold.  Returns [1, W,
-    NH * D] in q's dtype.
+    q: [1, W, NH, Dqk]; k_all, v_all: the stacked cache [layers, slots,
+    max_len, NKV * D], a position's heads side by side, or [layers, slots,
+    NKV, max_len, D], a head's rows apart (rows rebuilt for the call: a
+    latent head's keys of 192 are no whole block of a row); keys are as wide
+    as the queries, values may be narrower (192 / 128), and the result is as
+    wide as they.  layer, slot, start: int32 scalars, traced or not;
+    ``kv_len`` (static) bounds what the row may hold, ``start + W <=
+    kv_len``; rows past a query's own position are masked, whatever they
+    hold.  Returns [1, W, NH * Dv] in q's dtype.
 
     ``use_kernel=None`` takes the kernel (bf16 operands as the cache has
     them, float32 scores and accumulator) on a TPU outside a mesh where the
@@ -605,7 +642,8 @@ def flash_attention_rows(q, k_all, v_all, layer, slot, start, kv_len: int,
     ``q_offset`` over the slot's slab: the CPU path and the path under a
     mesh."""
     _, w, num_heads, d = q.shape
-    reason = flash_rows_supported(w, kv_len, d, block_q, block_kv)
+    apart = k_all.ndim == 5
+    reason = flash_rows_supported(w, kv_len, d, block_q, block_kv, apart)
     if reason is None and logit_softcap:
         reason = "the flash kernel has no logit softcap"
     if use_kernel is None:
@@ -615,18 +653,26 @@ def flash_attention_rows(q, k_all, v_all, layer, slot, start, kv_len: int,
     if use_kernel:
         if reason is not None:
             raise ValueError(f"flash attention cannot run this shape: {reason}")
+        rows = q[0].astype(k_all.dtype)
         out = _flash_fwd_rows(
-            q.reshape(w, -1).astype(k_all.dtype), k_all, v_all,
-            jnp.stack([layer, slot, start]).astype(jnp.int32), num_heads,
-            kv_len, min(block_q, w), min(block_kv, kv_len),
+            rows.swapaxes(0, 1) if apart else rows.reshape(w, -1), k_all,
+            v_all, jnp.stack([layer, slot, start]).astype(jnp.int32),
+            num_heads, kv_len, min(block_q, w), min(block_kv, kv_len),
             resolve_interpret(interpret, "flash"))
+        if apart:
+            out = out.swapaxes(0, 1).reshape(w, -1)
         return out[None].astype(q.dtype)
     from .attention import attend
 
     def slab(a):
+        """The slot's rows [1, kv_len, NKV, D]."""
+        if apart:
+            return jax.lax.dynamic_slice(
+                a, (layer, slot, 0, 0, 0),
+                (1, 1, num_kv_heads, kv_len, a.shape[-1]))[0].swapaxes(1, 2)
         return jax.lax.dynamic_slice(
             a, (layer, slot, 0, 0), (1, 1, kv_len, a.shape[-1])).reshape(
-                1, kv_len, num_kv_heads, d)
+                1, kv_len, num_kv_heads, -1)
 
     return attend(q, slab(k_all), slab(v_all), causal=True, q_offset=start,
                   logit_softcap=logit_softcap).reshape(1, w, -1)

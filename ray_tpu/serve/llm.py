@@ -631,9 +631,10 @@ class LLMEngine:
     def _admit_walk(self, reqs: List[GenRequest], bucket: int):
         """(chunks, positions a row) the admit program walks for ``reqs`` at
         ``bucket``: whole rows and no chunks, or the chunks each prompt
-        fills (``decode.prefill_width``; only a dense tree is walked in
-        chunks, and its rows are whole prompts)."""
-        width = self._dec.prefill_width(self.cache, bucket)
+        fills (``decode.prefill_width``: a chunk's length is the tree's and
+        the experts'; only a tree of rows alone is walked in chunks, and
+        its rows are whole prompts)."""
+        width = self._dec.prefill_width(self.cache, bucket, self.cfg)
         if width == bucket:
             return 0, [bucket] * len(reqs)
         chunks = [-(-len(r.tokens) // width) for r in reqs]

@@ -521,21 +521,23 @@ def test_moe_and_latent_kernels_compile_at_published_sizes(one_chip, kernel):
 
 @pytest.mark.parametrize("program,temp_gb", [("decode", 0.3),
                                              ("prefill-2048", 0.7),
-                                             ("prefill-8192", 1.9)])
+                                             ("prefill-8192", 1.0)])
 def test_latent_cell_program_fits_and_updates_its_cache_in_place(
         one_chip, as_tpu, program, temp_gb):
     """Readings 0.163, 0.500 and 1.712 GB of temporaries beside 13.26 GB of
     arguments (sandbox compile, PR 35; 1.712 too with the four streams
     carried in bf16: their mixing is float32 either way):
-    under 15.0 GiB, as ISSUE 35 asks of the largest program."""
+    under 15.0 GiB, as ISSUE 35 asks of the largest program.  Since PR 47
+    the 8,192 program walks a row in chunks of 2,048 and holds a chunk's
+    temporaries: reading 0.534 GB."""
     compiled, text = _latent_program(one_chip, program)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < temp_gb * 1e9, mem.temp_size_in_bytes
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 15.0 * 2**30, f"{total / 2**30:.2f} GiB"
-    # mla_decode_attn or flash_fwd, twice each (the dense layer, the scan's
-    # body), and the two grouped matmuls
+    # mla_decode_attn, flash_fwd or flash_fwd_rows, twice each (the dense
+    # layer, the scan's body), and the two grouped matmuls
     assert text.count(KERNEL) == 4
     for stack in LATENT_STACKS:
         assert stack in text
@@ -544,6 +546,52 @@ def test_latent_cell_program_fits_and_updates_its_cache_in_place(
     assert not re.search(
         r"= bf16\[(1,)?64,(3584,1024|1024,3584)\]\S* "
         r"(dynamic-slice|copy|fusion)\(", text)
+
+
+def test_latent_8192_program_walks_a_row_in_chunks_of_2048(one_chip, as_tpu):
+    """PR 47: of the latent cell's five buckets the 8,192 alone has four
+    chunks of the length its experts ask for (128 rows an expert of 64, 4 a
+    token), so its program is the loop over a row's chunks: the latent kind's
+    ``continued_attention`` through the forward kernel with a query offset,
+    keys of 192 and values of 128 a head's rows apart, rebuilt a layer a
+    chunk from the slot's latent rows and never laid out anew on the way to
+    the kernel.  It left ``WHOLE_ROW_PROGRAMS`` for this test."""
+    from ray_tpu.ops.flash_attention import KERNEL_FLASH_ROWS
+    from ray_tpu.ops.moe import KERNEL_MOE_GMM as KERNEL_GMM
+    cfg = _latent_cfg()
+    cache = jax.eval_shape(lambda: decode.init_kv_cache(
+        cfg, LATENT_SLOTS, LATENT_MAX_LEN))
+    assert [decode.prefill_width(cache, b, cfg)
+            for b in (512, 1024, 2048, 4096, 8192)] == [
+                512, 1024, 2048, 4096, 2048]
+    compiled, text = _latent_program(one_chip, "prefill-8192")
+    mem = compiled.memory_analysis()
+    # reading 533,731,840 (the whole row: 1,711,136,256)
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.0 * 2**30, f"{total / 2**30:.2f} GiB"
+    # the kernels it now has: the offset kernel for the dense layer and for
+    # the scan's body, no whole-row flash_fwd, and the two grouped matmuls
+    names = re.findall(r'custom-call\(.*?"tpu_custom_call".*?op_name="[^"]*?'
+                       r'/(\w+)/pallas_call"', text)
+    assert sorted(names) == sorted([KERNEL_FLASH_ROWS] * 2 + [KERNEL_GMM] * 2)
+    # a chunk of 2,048 tokens a pass; rows, chunks and layers are loops, and
+    # so is the prefix's rebuilding, in the dense layer and in the body
+    assert "s32[1,2048]" in text and "f32[1,2048,4,3584]" in text
+    assert "f32[1,8192,4,3584]" not in text
+    assert len(re.findall(r" while\(", text)) >= 5
+    for stack in LATENT_STACKS:
+        assert stack in text
+        assert not re.search(r"= " + re.escape(stack) + r"\S* copy\(", text)
+    assert not re.search(
+        r"= bf16\[(1,)?64,(3584,1024|1024,3584)\]\S* "
+        r"(dynamic-slice|copy|fusion)\(", text)
+    # the rebuilt rows [32 heads, 8192, 192 | 128] are carried as the kernel
+    # reads them: no transposing copy before a call
+    assert "bf16[32,8192,192]{2,1,0" in text
+    assert not re.search(r"= bf16\[(1,)?32,8192,(192|128)\]\S* "
+                         r"(copy|transpose)\(", text)
 
 
 # --- a decay a channel, gated NoPE attention, a share of the experts (PR 44)
@@ -804,8 +852,9 @@ def test_the_nano_checks_prefill_runs_a_buckets_kernels(one_chip, as_tpu):
 
 
 # ---------- the programs that walk whole rows are the parent's (PR 37)
-# Only a dense tree's bucket of four chunks or more compiles to another
-# program; every other one keeps the temporaries and the generated code size
+# Only a bucket of four chunks or more of a tree of rows alone (K/V; latent
+# since PR 47) compiles to another program; every other one keeps the
+# temporaries and the generated code size
 # it had at PR 35 (sandbox compiles of both trees, PR 37), to the byte.  The
 # hybrid's 512 / 1024 and the latent kind's 4096 read equal too at PR 37
 # ((102804992, 13414400), (130491392, 13181440), (960504832, 27387392)); they
@@ -841,7 +890,8 @@ WHOLE_ROW_PROGRAMS = {
     # (1711136256, 30828544).  Every other program above is one whose heads
     # have one width: the same kernel, to the byte
     ("latent", "prefill-2048"): (459842048, 22800896),
-    ("latent", "prefill-8192"): (1711136256, 30621184),
+    # the latent kind's 8,192 left this list at PR 47: its rows are walked
+    # in chunks of 2,048 (was (1711136256, 30621184); its own test above)
 }
 
 

@@ -79,20 +79,64 @@ def test_admitted_tokens_are_counted_real_and_padded(tiny_cfg, paged):
         eng.shutdown()
 
 
-def test_chunked_rows_count_the_chunks_they_walked(tiny_cfg):
-    """A dense tree's bucket of four chunks is walked in the chunks a prompt
-    fills (``decode.prefill_width``): the padding counted is what the chip
-    walked less the prompt, a row rounded up to whole chunks and not to its
-    bucket, and ``admit_chunks`` / ``admit_rows_chunked`` say how often."""
+def _latent_engine():
+    """The tiny latent + dropless tree of ``tests/test_latent.py`` behind an
+    engine, with a shortest chunk of 4 and an expert's tile of 2 rows
+    slipped in while its programs are traced (8 experts, 2 a token: a chunk
+    of 8, and the bucket of 32 is four)."""
+    import functools
+    import json
+    import os
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    from benchmark.lib.manifest import load_model
     from ray_tpu.models import decode
-    chunk, bucket = decode.PREFILL_CHUNK, 4 * decode.PREFILL_CHUNK
-    eng = _engine(tiny_cfg, num_slots=2, max_len=bucket + 64,
-                  buckets=(64, bucket))
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    kind = load_model(os.path.join(bench, "models", "xing4_0.py"))
+    with open(os.path.join(bench, "tests", "tiny", "configs",
+                           "tiny-latent.json")) as f:
+        cfg = kind.program_config(json.load(f))
+    width = decode.prefill_width
+    small = mock.patch.multiple(
+        decode, EXPERT_TILE=2,
+        prefill=functools.partial(decode.prefill, chunk=4),
+        prefill_width=lambda cache, bucket, cfg, chunk=4: width(
+            cache, bucket, cfg, 4))
+    small.start()
+    eng = _engine(cfg, params=kind.init_params(jax.random.PRNGKey(3), cfg,
+                                               jnp.float32),
+                  num_slots=2, max_len=64, buckets=(16, 32),
+                  compute_dtype=jnp.float32)
+    return eng, small.stop
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_chunked_rows_count_the_chunks_they_walked(tiny_cfg, kind):
+    """A bucket of four chunks of a tree of rows alone, K/V or latent, is
+    walked in the chunks a prompt fills (``decode.prefill_width``; the
+    latent tree's chunk is the one its experts ask for, not the shortest):
+    the padding counted is what the chip walked less the prompt, a row
+    rounded up to whole chunks and not to its bucket, and ``admit_chunks`` /
+    ``admit_rows_chunked`` say how often."""
+    from ray_tpu.models import decode
+    if kind == "latent":
+        chunk, bucket, short = 8, 32, 16
+        eng, undo = _latent_engine()
+    else:
+        chunk, bucket, short = (decode.PREFILL_CHUNK,
+                                4 * decode.PREFILL_CHUNK, 64)
+        eng, undo = _engine(tiny_cfg, num_slots=2, max_len=bucket + 64,
+                            buckets=(short, bucket)), lambda: None
     try:
-        assert decode.prefill_width(eng.cache, bucket) == chunk
-        assert decode.prefill_width(eng.cache, 64) == 64
+        assert decode.prefill_width(eng.cache, bucket, eng.cfg) == chunk
+        assert decode.prefill_width(eng.cache, short, eng.cfg) == short
         seen = _spy_admits(eng)
-        lens = (chunk * 2 + 76, 40, chunk * 3 + 164, bucket, chunk * 3)
+        # (3, whole, 4, 4 and 3 chunks; the second sits in the short bucket)
+        lens = (chunk * 2 + chunk // 8, short * 5 // 8,
+                chunk * 3 + chunk // 4, bucket, chunk * 3)
         _run(eng, [[1 + (i + j) % 50 for j in range(n)]
                    for i, n in enumerate(lens)], max_tokens=2)
         c = eng.counters()
@@ -100,7 +144,8 @@ def test_chunked_rows_count_the_chunks_they_walked(tiny_cfg):
         assert c["admit_chunks"] == 3 + 4 + 4 + 3
         assert c["admit_rows_chunked"] == 4
         assert c["admit_tokens_padded"] == (
-            (chunk - 76) + (64 - 40) + (chunk - 164) + 0 + 0)
+            (chunk - chunk // 8) + (short - short * 5 // 8)
+            + (chunk - chunk // 4) + 0 + 0)
         # every admit of the long bucket walked fewer positions than its
         # rows times the bucket, unless every prompt needed all four chunks
         assert c["admit_tokens_real"] + c["admit_tokens_padded"] < sum(
@@ -108,6 +153,7 @@ def test_chunked_rows_count_the_chunks_they_walked(tiny_cfg):
         assert c["first_tokens"] == len(lens)
     finally:
         eng.shutdown()
+        undo()
 
 
 def test_prefix_hit_counts_only_the_prefilled_suffix(tiny_cfg):

@@ -267,6 +267,108 @@ def test_absorbed_form_equals_expanded_form(tiny):
     np.testing.assert_allclose(absorbed[0], expanded[0], atol=5e-5)
 
 
+# ------------- (c') a row walked in counted chunks (PR 47): the expanded
+# form over what the slot holds so far, a chunk as long as the experts ask
+
+BUCKET, SHORTEST, TILE = 32, 4, 2      # 8 experts, 2 a token: a chunk of 8
+
+
+def _chunked_prefill(cfg, params, cache, toks, slot):
+    """``_prefill`` at the bucket of 32 with a row walked in chunks of 8."""
+    from unittest import mock
+    row = np.pad(toks, (0, BUCKET - len(toks)))[None]
+    with mock.patch.object(decode, "EXPERT_TILE", TILE):
+        assert decode.prefill_width(cache, BUCKET, cfg, SHORTEST) == 8
+        return jax.jit(lambda p, c, t, n, s: decode.prefill(
+            p, c, t, n, s, cfg, jnp.float32, chunk=SHORTEST))(
+                params, cache, row, np.array([len(toks)], np.int32),
+                np.array([slot], np.int32))
+
+
+# prompts that end inside the first chunk, a middle one and the last
+@pytest.mark.parametrize("n", [5, 19, 29])
+def test_chunks_then_decode_equal_the_reference(kind, doc, tiny, n):
+    """What the chunks leave in the slot is what the whole row leaves
+    (latent rows, rotary keys, the length, every token's experts), and the
+    absorbed form continues it a token at a time to the reference's
+    logits."""
+    cfg, params = tiny
+    steps = 3
+    toks = np.random.default_rng(10 + n).integers(
+        1, cfg.vocab_size, n + steps).astype(np.int32)
+    ref = np.asarray(kind.logits(params, toks, doc,
+                                 jnp.arange(n - 1, n + steps), follow=None))
+    empty = decode.init_kv_cache(cfg, 3, 64, jnp.float32,
+                                 expert_choices=True)
+    whole, lg_whole = _prefill(cfg, params, empty, toks[:n], slot=1,
+                               pad=BUCKET - n)
+    cache, lg = _chunked_prefill(cfg, params, empty, toks[:n], slot=1)
+    np.testing.assert_allclose(lg, lg_whole, atol=2e-5)
+    np.testing.assert_allclose(cache["latent"][:, 1, :n],
+                               whole["latent"][:, 1, :n], atol=1e-5)
+    np.testing.assert_allclose(cache["rope_key"][:, 1, :, :n],
+                               whole["rope_key"][:, 1, :, :n], atol=1e-5)
+    assert cache["length"].tolist() == whole["length"].tolist() == [0, n, 0]
+    np.testing.assert_array_equal(cache[decode.CHOICES][:, 1, :n],
+                                  whole[decode.CHOICES][:, 1, :n])
+    # no expert for a padded position, no row past the last chunk walked
+    assert (np.asarray(cache[decode.CHOICES])[:, 1, n:] == -1).all()
+    assert not np.asarray(cache["latent"][:, 1, -(-n // 8) * 8:]).any()
+    assert not np.asarray(cache["latent"][:, [0, 2]]).any()
+    got = [np.asarray(lg)[0]]
+    step = jax.jit(lambda p, c, t, a: decode.decode_step(
+        p, c, t, a, cfg, jnp.float32))
+    for i in range(steps):
+        fed = np.zeros(3, np.int32)
+        fed[1] = toks[n + i]
+        cache, lg = step(params, cache, fed, np.array([False, True, False]))
+        got.append(np.asarray(lg)[1])
+    np.testing.assert_allclose(np.stack(got), ref, atol=2e-4)
+
+
+@pytest.mark.parametrize("row", [100, 384], ids=["one-chunk", "three-chunks"])
+def test_the_continued_mixer_is_the_whole_rows_at_published_head_sizes(
+        tiny, monkeypatch, row):
+    """One layer's ``continued_attention`` a chunk of 128 at a time, its
+    kernel interpreted at a latent head's 128 + 64 / 128 (keys of 192 and
+    values of 128 as they are, a head's rows apart), against
+    ``prefill_attention`` over the whole row: the same attention and the
+    same cached rows, and nothing written past the chunks walked."""
+    import functools
+    from ray_tpu.ops import flash_attention as fa
+    cfg = dataclasses.replace(tiny[0], num_heads=2, num_kv_heads=2,
+                              qk_nope_head_dim=128, qk_rope_head_dim=64,
+                              v_head_dim=128)
+    ap = jax.tree.map(lambda a: a[0], latent._init_group(
+        jax.random.PRNGKey(5), cfg, 1, False, jnp.float32)["attn"])
+    w, span, layers, slots = 128, 512, 2, 3
+    y = jax.random.normal(jax.random.PRNGKey(row), (1, span, cfg.hidden_size))
+    stacks = (jnp.zeros((layers, slots, span, cfg.kv_lora_rank)),
+              jnp.zeros((layers, slots, cfg.qk_rope_head_dim, span)))
+    want, *held = latent.prefill_attention(
+        y, ap, cfg, *stacks, 1, 2, jnp.arange(span)[None])
+    monkeypatch.setattr(fa, "flash_attention_rows", functools.partial(
+        fa.flash_attention_rows, interpret=True))
+    before = fa.INTERPRET_TRACES.get("flash", 0)
+    chunk = jax.jit(lambda y, lat, rope, at: latent.continued_attention(
+        y, ap, cfg, lat, rope, 1, 2, at, span))
+    got, chunks = [], -(-row // w)
+    for c in range(chunks):
+        out, *stacks = chunk(y[:, c * w:(c + 1) * w], *stacks,
+                             jnp.int32(c * w))
+        got.append(out)
+    assert fa.INTERPRET_TRACES["flash"] == before + 1     # one program
+    np.testing.assert_allclose(jnp.concatenate(got, axis=1)[:, :row],
+                               want[:, :row], atol=2e-5)
+    walked = chunks * w
+    np.testing.assert_allclose(stacks[0][:, :, :walked],
+                               held[0][:, :, :walked], atol=1e-6)
+    np.testing.assert_allclose(stacks[1][..., :walked],
+                               held[1][..., :walked], atol=1e-6)
+    assert not np.asarray(stacks[0][:, :, walked:]).any()
+    assert not np.asarray(stacks[1][..., walked:]).any()
+
+
 @pytest.mark.parametrize("kw", [
     dict(moe_dropless=True, num_experts=8, experts_per_token=2,
          expert_mlp_size=64, shared_experts=1, routed_scaling_factor=2.0,
