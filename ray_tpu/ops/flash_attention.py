@@ -1,4 +1,4 @@
-"""Pallas TPU flash attention: a forward kernel and two backward kernels.
+"""Pallas TPU flash attention: a forward kernel and one backward kernel.
 
 The reference has no TPU kernels at all (its attention lives in external torch
 models); this is greenfield TPU-first code (SURVEY §5.7, §7 stance).
@@ -15,13 +15,17 @@ Design:
   ``h // (num_q_heads / num_kv_heads)`` so grouped-query K/V blocks are read
   in place; the `repeat_kv` copy the plain path makes is skipped.
 * **Backward** recomputes attention blockwise from the saved (out, lse)
-  residuals — standard flash-attention recurrence — in two Pallas kernels,
-  `flash_dq` (a q block's gradient over its KV blocks) and `flash_dkv` (a KV
-  block's gradients over its q blocks and the query heads that share it).
-  Per-program VMEM is O(block) like the forward.  Blocks that do not tile for
-  them (under 128 positions) fall back to `_bwd_blockwise`, the same
-  recurrence as a `lax.scan` over KV blocks in plain JAX: the CPU tests'
-  small shapes.
+  residuals — standard flash-attention recurrence — in ONE Pallas kernel,
+  `flash_dkv`: a live (q block, kv block) pair's score tile is computed once
+  and dq, dk and dv are all taken from it, five products a tile.  dk / dv of
+  a KV block are summed over its q blocks and the query heads that share it
+  in O(block) VMEM; dq, which a q block gathers from every KV block, stays
+  resident in f32 for the whole sequence of the query heads under a kv head
+  and is written once.  That is the kernel's reach, a rule of the shapes
+  (`flash_bwd_supported`): past it, and where blocks do not tile for the
+  kernel (under 128 positions: the CPU tests' small shapes), the backward
+  is `_bwd_blockwise`, the same recurrence as a `lax.scan` over KV blocks in
+  plain JAX: correct and slow.
 * **Two widths**: queries and keys are `d_qk` lanes wide, values, the output
   and its gradient `d_v`; each kernel reads both off its operands.  A latent
   head's 192 / 128 runs as it is; where the two are equal (every other
@@ -51,6 +55,14 @@ NEG_INF = -1e30
 #: resident K and V bytes up to which the forward kernel fits the compiler's
 #: default scoped VMEM (16 MiB) with its blocks and products
 FWD_VMEM_DEFAULT = 12 << 20
+#: resident dq bytes (``_bwd_resident``) up to which the backward kernel fits
+#: the compiler's default scoped VMEM with its blocks and products
+BWD_VMEM_DEFAULT = 8 << 20
+#: what the backward kernel's blocks and products take beside its resident dq
+BWD_VMEM_BLOCKS = 12 << 20
+#: resident dq bytes past which the backward is not the kernel's: with its
+#: blocks and products, what a call may ask of a v5e's 128 MiB of VMEM
+BWD_VMEM_REACH = 64 << 20
 #: the forward kernel where its queries start after what a slot of the
 #: stacked cache already holds (``flash_attention_rows``), as a trace shows it
 KERNEL_FLASH_ROWS = "flash_fwd_rows"
@@ -293,83 +305,47 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
 
 
 # ---------------------------------------------------------------------------
-# Pallas backward kernels
+# Pallas backward kernel
 # ---------------------------------------------------------------------------
 #
-# Both kernels compute the score tile TRANSPOSED — s_t = [block_kv(sublanes),
+# The kernel computes the score tile TRANSPOSED — s_t = [block_kv(sublanes),
 # block_q(lanes)] — so the per-q-row statistics (lse, delta) enter as natural
 # [1, block_q] rows and broadcast over sublanes, which Mosaic supports
 # directly; no lane-replicated stat arrays and no [1,N]->[N,1] relayout.
-# Every matmul contracts either a head width (d_qk for the scores and dq/dk,
-# d_v for dp and dv) or a block dim, all MXU-shaped.
+# Every matmul contracts either a head width (d_qk for the scores and dk,
+# d_v for dp and dv) or a block dim (dq), all MXU-shaped.
 #
-# Grids iterate over BOTH block axes (q and kv) with an f32 VMEM scratch
-# accumulator initialised on the first visit of an output tile and flushed on
-# the last, so per-program VMEM is O(block) at any sequence length (the
-# first version loaded full-sequence K/V per program and died at S>=4096).
+# The grid iterates over BOTH block axes (q and kv), kv-major: an f32 VMEM
+# scratch accumulator is initialised on the first visit of an output tile
+# and flushed on the last.  dk / dv are revisited consecutively, O(block) of
+# VMEM; dq is revisited once a kv block, so it stays resident for the whole
+# sequence (``_bwd_resident``), which bounds the kernel's reach.
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
-                   acc_ref, *, causal: bool, scale: float):
-    """Grid (b, h, n_q, n_kv): accumulate one q-block's dq over KV blocks."""
-    qi, kj = pl.program_id(2), pl.program_id(3)
-    n_kv = pl.num_programs(3)
-    block_q, block_kv = q_ref.shape[2], k_ref.shape[2]
-
-    @pl.when(kj == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # Under causal masking, KV blocks strictly past this q-block's diagonal
-    # contribute nothing: skip their compute (loads are pipelined anyway).
-    live = (kj * block_kv < (qi + 1) * block_q) if causal else (kj >= 0)
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0]                               # [bq, d_qk]
-        g = g_ref[0, 0]                               # [bq, d_v]
-        k = k_ref[0, 0]                               # [bkv, d_qk]
-        v = v_ref[0, 0]                               # [bkv, d_v]
-        lse = lse_ref[0, 0]                           # [1, bq] f32
-        dlt = dlt_ref[0, 0]
-        s_t = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [bkv, bq]
-        if causal:
-            k_pos = kj * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (block_kv, block_q), 0)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_kv, block_q), 1)
-            s_t = jnp.where(q_pos >= k_pos, s_t, NEG_INF)
-        p_t = jnp.exp(s_t - lse)                              # [bkv, bq]
-        dp_t = jax.lax.dot_general(
-            v, g, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bkv, bq]
-        ds_t = p_t * (dp_t - dlt) * scale
-        acc_ref[...] += jax.lax.dot_general(
-            ds_t.astype(k.dtype), k, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, d_qk]
-
-    @pl.when(kj == n_kv - 1)
-    def _flush():
-        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
-                    scale: float):
-    """Grid (b, kv_heads, n_kv, reps, n_q): accumulate one kv-block's dk/dv
-    over q blocks and over the `reps` query heads sharing it (GQA fold-back).
-    The two innermost grid dims revisit the same output tile consecutively,
-    which is what makes the scratch init/flush pattern valid."""
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                causal: bool, scale: float):
+    """Grid (b, kv_heads, n_kv, reps, n_q): one (kv block, query head, q
+    block) pair a step.  dk / dv of the kv block accumulate over the two
+    innermost dims (GQA fold-back), which revisit their output tile
+    consecutively; dq of the `reps` query heads sharing the kv head
+    accumulates in ``dq_acc`` [reps, S, d_qk] over kv blocks ascending and
+    leaves through ``dq_ref``, a block of the (batch, kv head)'s whole rows
+    that the three inner dims revisit consecutively."""
     ki, r, qj = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-    n_rep, n_q = pl.num_programs(3), pl.num_programs(4)
+    n_kv, n_rep, n_q = (pl.num_programs(i) for i in (2, 3, 4))
     block_kv, block_q = k_ref.shape[2], q_ref.shape[2]
+    rows = pl.ds(pl.multiple_of(qj * block_q, block_q), block_q)
 
     @pl.when((r == 0) & (qj == 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ki == 0)           # a new (batch, kv head): what the scratch
+    def _init_dq():             # holds is the last one's
+        dq_acc[r, rows, :] = jnp.zeros((block_q, dq_acc.shape[2]),
+                                       jnp.float32)
 
     # q blocks strictly before this kv-block's diagonal see none of it.
     live = ((qj + 1) * block_q > ki * block_kv) if causal else (qj >= 0)
@@ -398,15 +374,42 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
         dp_t = jax.lax.dot_general(
             v, g, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - dlt) * scale
+        ds_t = (p_t * (dp_t - dlt) * scale).astype(q.dtype)
         dk_acc[...] += jax.lax.dot_general(
-            ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            ds_t, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [bkv, d_qk]
+        dq_acc[r, rows, :] += jax.lax.dot_general(
+            ds_t, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bq, d_qk]
 
     @pl.when((r == n_rep - 1) & (qj == n_q - 1))
     def _flush():
         dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(ki == n_kv - 1)    # the q block has seen its last kv block
+    def _flush_dq():
+        dq_ref[0, r, rows, :] = dq_acc[r, rows, :].astype(dq_ref.dtype)
+
+
+def _bwd_resident(seq: int, reps: int, d_qk: int, dtype) -> int:
+    """Bytes of VMEM the backward kernel keeps for a (batch, kv head)'s dq:
+    the f32 accumulator of the ``reps`` query heads' ``seq`` rows and their
+    output block, double-buffered, each row whole tiles of 128 lanes."""
+    lanes = -(-d_qk // 128) * 128
+    return reps * seq * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def _bwd_vmem(seq: int, reps: int, d_qk: int, dtype) -> dict:
+    """What the backward call passes the compiler, as ``_fwd_vmem`` does for
+    the forward's resident K and V: past what the compiler's default of 16
+    MiB leaves beside the kernel's blocks and products (8,192 rows of a head
+    of 192 lanes, or 4,096 of four heads of 128, are 16 MiB) the kernel asks
+    for what it needs; below, nothing is passed."""
+    resident = _bwd_resident(seq, reps, d_qk, dtype)
+    return ({} if resident <= BWD_VMEM_DEFAULT else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=resident + BWD_VMEM_BLOCKS)})
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
@@ -429,55 +432,38 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
     lse4 = lse[:, :, None, :]
     dlt4 = delta[:, :, None, :]
 
-    def q_rows(width, index):       # a q block of a query head: q, g, dq
-        return pl.BlockSpec((1, 1, bq, width), index)
+    def q_rows(width):              # a q block of a query head: q, g
+        return pl.BlockSpec((1, 1, bq, width),
+                            lambda bi, gi, ki, r, qj: (bi, gi * reps + r, qj, 0))
 
-    def kv_rows(width, index):      # a kv block of a kv head: k, v, dk, dv
-        return pl.BlockSpec((1, 1, bkv, width), index)
-
-    def dq_q(bi, hi, qi, kj):
-        return bi, hi, qi, 0
-
-    def dq_kv(bi, hi, qi, kj):
-        return bi, hi // reps, kj, 0
-
-    stat = pl.BlockSpec((1, 1, 1, bq), lambda bi, hi, qi, kj: (bi, hi, 0, qi))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, scale=scale),
-        grid=(b, h, s // bq, s // bkv),
-        in_specs=[q_rows(d, dq_q), kv_rows(d, dq_kv), kv_rows(d_v, dq_kv),
-                  q_rows(d_v, dq_q), stat, stat],
-        out_specs=q_rows(d, dq_q),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_dq",
-    )(q, k, v, gf, lse4, dlt4)
-
-    def dkv_q(bi, gi, ki, r, qj):
-        return bi, gi * reps + r, qj, 0
-
-    def dkv_kv(bi, gi, ki, r, qj):
-        return bi, gi, ki, 0
+    def kv_rows(width):             # a kv block of a kv head: k, v, dk, dv
+        return pl.BlockSpec((1, 1, bkv, width),
+                            lambda bi, gi, ki, r, qj: (bi, gi, ki, 0))
 
     stat = pl.BlockSpec((1, 1, 1, bq),
                         lambda bi, gi, ki, r, qj: (bi, gi * reps + r, 0, qj))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale),
+    # the whole rows of the query heads under a kv head (they are
+    # contiguous: h = gi * reps + r)
+    dq_rows = pl.BlockSpec((1, reps, s, d),
+                           lambda bi, gi, ki, r, qj: (bi, gi, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, causal=causal, scale=scale),
         grid=(b, kv_heads, s // bkv, reps, s // bq),
-        in_specs=[q_rows(d, dkv_q), kv_rows(d, dkv_kv), kv_rows(d_v, dkv_kv),
-                  q_rows(d_v, dkv_q), stat, stat],
-        out_specs=[kv_rows(d, dkv_kv), kv_rows(d_v, dkv_kv)],
+        **_bwd_vmem(s, reps, d, q.dtype),
+        in_specs=[q_rows(d), kv_rows(d), kv_rows(d_v), q_rows(d_v), stat,
+                  stat],
+        out_specs=[dq_rows, kv_rows(d), kv_rows(d_v)],
         out_shape=[
+            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, kv_heads, s, d), k.dtype),
             jax.ShapeDtypeStruct((b, kv_heads, s, d_v), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((bkv, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((reps, s, d), jnp.float32),
+                        pltpu.VMEM((bkv, d), jnp.float32),
                         pltpu.VMEM((bkv, d_v), jnp.float32)],
         interpret=interpret,
         name="flash_dkv",
     )(q, k, v, gf, lse4, dlt4)
-    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -499,11 +485,8 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_kv, interpret):
 
 def _flash_vjp_bwd(causal, block_q, block_kv, interpret, res, g):
     q, k, v, out, lse = res
-    s = q.shape[2]
-    bq, bkv = min(block_q, s), min(block_kv, s)
-    # bq rides the lane dim of the stat rows (must be 128-aligned); bkv the
-    # sublane dim of the transposed score tile.
-    if bq % 128 == 0 and bkv % 128 == 0 and s % bq == 0 and s % bkv == 0:
+    if flash_bwd_supported(q.shape[2], q.shape[1], k.shape[1], q.shape[3],
+                           q.dtype, block_q, block_kv) is None:
         return _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q,
                                  block_kv, interpret)
     return _bwd_blockwise(q, k, v, out, lse, g, causal, block_kv)
@@ -546,6 +529,31 @@ def flash_supported(seq_q: int, seq_kv: int, num_heads: int,
                 f"({bq}, {bkv})")
     if num_kv_heads < 1 or num_heads % num_kv_heads:
         return f"heads {num_heads} not a multiple of kv heads {num_kv_heads}"
+    return None
+
+
+def flash_bwd_supported(seq: int, num_heads: int, num_kv_heads: int,
+                        d_qk: int, dtype, block_q: int = 512,
+                        block_kv: int = 512) -> Optional[str]:
+    """None when a differentiated ``flash_attention`` call of this shape
+    takes the Pallas backward kernel, else the reason it takes the scan in
+    plain JAX (``_bwd_blockwise``: the same recurrence, correct and slow).
+    A rule of the shapes alone: the blocks must tile for the kernel, and the
+    dq it keeps resident (``_bwd_resident``: the f32 rows of the query heads
+    under a kv head and their output block) must fit the chip's VMEM.  8,192
+    positions of a head of 192 lanes and 4,096 of four heads of 128 (the two
+    train cells) are 16 MiB; four heads of 128 at 32,768 positions are
+    128 MiB and take the scan."""
+    bq, bkv = min(block_q, seq), min(block_kv, seq)
+    # bq rides the lane dim of the stat rows (must be 128-aligned); bkv the
+    # sublane dim of the transposed score tile.
+    if bq % 128 or bkv % 128 or seq % bq or seq % bkv:
+        return (f"blocks ({bq}, {bkv}) are no whole 128s or do not tile "
+                f"{seq} positions")
+    resident = _bwd_resident(seq, num_heads // num_kv_heads, d_qk, dtype)
+    if resident > BWD_VMEM_REACH:
+        return (f"{resident} bytes of resident dq are past the kernel's "
+                f"reach of {BWD_VMEM_REACH}")
     return None
 
 

@@ -1,9 +1,10 @@
-"""The three flash kernels alone on the chip at a latent head's shapes (PR 45).
+"""The flash kernels alone on the chip at a latent head's shapes (PR 45; the
+backward one kernel since PR 48).
 
     chiprun -- python tests/chip_flash_widths.py [out_dir]
 
-Times ``flash_fwd``, ``flash_dq`` and ``flash_dkv`` at ``(2, 8192, 16, 16)``
-heads, causal, bf16, for pairs of (query/key width, value width): 192 / 128
+Times ``flash_fwd`` and ``flash_dkv`` (the whole backward: dq, dk and dv
+from one score tile) at ``(2, 8192, 16, 16)`` heads, causal, bf16, for pairs of (query/key width, value width): 192 / 128
 as the latent kind hands them over, 256 / 128 (q and k padded, the fallback
 ISSUE 45 names), 256 / 256 (what the kind padded to before) and 128 / 128
 (Mistral's).  Each pair is traced for ``STEPS`` forward + backward calls and
@@ -29,7 +30,7 @@ from ray_tpu.ops.flash_attention import flash_attention
 
 SHAPE = (2, 8192, 16, 16)            # batch, positions, heads, kv heads
 PAIRS = ((192, 128), (256, 128), (256, 256), (128, 128))
-KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+KERNELS = ("flash_fwd", "flash_dkv")
 STEPS = 5
 
 
@@ -69,7 +70,7 @@ def timed(pair, out_dir):
     rows = trace.summarize(trace.load_xplane(trace.find_xplane(where)),
                            wall)["ops"]
     # outside a scan the instruction carries the transformation's name too
-    # (``transpose_jvp_flash_dq__``)
+    # (``transpose_jvp_flash_dkv__``)
     ms = {k: 1e3 * trace.seconds_matching(
         rows, k + r"_* \[pallas\]$")[0] / STEPS for k in KERNELS}
     ms["all_ops"] = 1e3 * sum(r[1] for r in rows) / STEPS

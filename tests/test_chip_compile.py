@@ -94,8 +94,8 @@ def test_flash_attention_compiles(one_chip, shape, direction):
         fn = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
                       argnums=(0, 1, 2))
     _, text = _compile(fn, *_qkv(one_chip, *shape))
-    # forward: one kernel; backward: forward + dq + dkv kernels
-    assert text.count(KERNEL) >= (1 if direction == "fwd" else 3)
+    # forward: one kernel; backward: the forward and the one backward kernel
+    assert text.count(KERNEL) == (1 if direction == "fwd" else 2)
 
 
 def test_splash_attention_compiles_fwd_bwd(one_chip):
@@ -1023,7 +1023,7 @@ def test_sharded_train_step_compiles(topo, as_tpu, impl):
         dict(mesh={"fsdp": -1}, optimizer={}, remat="save_acts",
              global_batch=8, sequence_length=2048))
     text = compiled.as_text()
-    assert text.count(KERNEL) >= 3, "no attention kernel in the sharded step"
+    assert text.count(KERNEL) >= 2, "no attention kernel in the sharded step"
     for collective in ("all-gather", "reduce-scatter"):
         assert collective in text, f"fsdp step without {collective}"
     # fsdp shards the big weights: each device holds about a quarter
@@ -1050,10 +1050,13 @@ def test_fsdp_cell_step_gathers_the_head_once_a_pass(topo, as_tpu):
     # temporaries, the sum ``benchmark/runners/train.py`` reports, read
     # 16.29e9 (14.78e9): 0.80e9 for that 0.40e9, and no measure of what a
     # chip of 16.91e9 holds (the share cell's step below reads 19.9e9 so,
-    # and runs).
+    # and runs).  With the backward one kernel (PR 48) the compiler orders
+    # the layer's backward otherwise and keeps other buffers in its second
+    # memory space: 13.35e9 at the peak and 16.57e9 by the sum, whatever
+    # VMEM the kernel asks for (sandbox compiles, PR 48).
     mem = compiled.memory_analysis()
     assert mem.peak_memory_in_bytes < 13.5e9, mem.peak_memory_in_bytes
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.7e9
 
 
 # ------------- latent attention + dropless experts trained on one chip (PR 39)
@@ -1063,9 +1066,9 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
     """``kimi-vl-a3b-train-l6-e8`` as its cell runs it: 2 x 8,192 tokens,
     float32 AdamW state of 668.9M parameters (8.03 GB in place), one chip.
     Reading 15.26e9 bytes at the program's peak, arguments included
-    (``peak_memory_in_bytes``; sandbox compile, PR 45; 15.86e9 at PR 39, with
-    the attention heads padded to 256 lanes): under the 15.0 GiB ISSUE 39
-    set.  The kernel calls in the step are the ones the block kind
+    (``peak_memory_in_bytes``; sandbox compile, PR 45 and again PR 48;
+    15.86e9 at PR 39, with the attention heads padded to 256 lanes): under
+    the 15.0 GiB ISSUE 39 set.  The kernel calls in the step are the ones the block kind
     counts FLOPs for (``moe_gmm_train_calls``, ``mla_flash_train_calls``): a
     roofline share must not credit a pass the program does not run."""
     doc, kind, cfg = _train_cell("kimi-vl-a3b-train-l6-e8", "kimi_vl.py")
@@ -1078,8 +1081,10 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
     assert mem.peak_memory_in_bytes < 15.0 * 2**30, mem.peak_memory_in_bytes
     # a mesh of one device: nothing to gather.  0.94e9 under the
     # 11_878_587_904 PR 39 left: the flash kernels' operands, results and
-    # saved ``attn_out`` at 192 and 128 lanes where all were 256 (PR 45)
-    assert mem.temp_size_in_bytes == 10_939_999_232
+    # saved ``attn_out`` at 192 and 128 lanes where all were 256 (PR 45);
+    # 0.45e6 under PR 45's 10_939_999_232 with the backward one kernel
+    # (PR 48: dq leaves the call that writes dk and dv)
+    assert mem.temp_size_in_bytes == 10_939_547_648
     # the dense layer's pass is unrolled, the expert layers' a scan's body:
     # a kernel's calls in the text are its calls a layer, forward plus
     # backward, once for each
@@ -1088,17 +1093,31 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
                           text)
     calls = {name: len(re.findall(
         "%" + name + r"(?:\.\d+)? = [^\n]*" + KERNEL, text)) for name in (
-            "moe_gmm", "moe_gmm_dx", "moe_gmm_dw", "flash_fwd", "flash_dq",
-            "flash_dkv")}
+            "moe_gmm", "moe_gmm_dx", "moe_gmm_dw", "flash_fwd", "flash_dkv")}
     want = dict(kind.moe_gmm_train_calls(doc))
-    want.update({k: 2 * v for k, v in
-                 kind.mla_flash_train_calls(doc).items()})
+    # the backward is one kernel, ``flash_dkv``, since PR 48.  The block
+    # kind's dict still says ``flash_dq: 1``; only its ``flash_fwd`` count
+    # feeds the FLOPs, so the roofline credits no pass the program does not
+    # run, and the entry is a ``benchmark`` PR's to drop.
+    flash_calls = kind.mla_flash_train_calls(doc)
+    want.update({k: 2 * flash_calls[k] for k in ("flash_fwd", "flash_dkv")})
     assert calls == want and text.count(KERNEL) == sum(want.values())
+    assert "flash_dq" not in text
     assert kind.moe_gmm_train_passes(doc) == 4
     # a latent head reaches the flash kernels at its own two widths: no
     # operand or result of theirs is padded to 256 lanes
-    flash = re.findall(r"%flash_(?:fwd|dq|dkv)(?:\.\d+)? = [^\n]*" + KERNEL
+    flash = re.findall(r"%flash_(?:fwd|dkv)(?:\.\d+)? = [^\n]*" + KERNEL
                        + r"[^\n]*", text)
     widths = {int(d) for line in flash for d in re.findall(
         r"bf16\[\d+,\d+,\d+,(\d+)\]", line)}
     assert widths == {cfg.qk_head_dim, cfg.v_head_dim} == {192, 128}
+    # the backward kernel asks for the VMEM its shape rule gives: a head's
+    # 8,192 rows of dq resident in float32 with their output block (16 MiB)
+    # and the room of its blocks and products
+    from ray_tpu.ops import flash_attention as fa
+    asked = fa._bwd_vmem(doc["train"]["sequence_length"], 1, cfg.qk_head_dim,
+                         jnp.bfloat16)["compiler_params"].vmem_limit_bytes
+    assert asked == (16 << 20) + fa.BWD_VMEM_BLOCKS
+    backward = [line for line in flash if "%flash_dkv" in line]
+    assert len(backward) == 2 and all(
+        '"size":"%d"' % asked in line for line in backward)

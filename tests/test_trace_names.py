@@ -146,8 +146,10 @@ def test_flash_kernels_are_named():
         lambda q, k, v: flash_attention(q, k, v, causal=True,
                                         interpret=True).sum(),
         argnums=(0, 1, 2)))(q, q, q)
-    assert {"flash_fwd", "flash_dq", "flash_dkv"} <= set(
-        re.findall(r"flash_\w+", str(jaxpr)))
+    # the backward is one kernel since PR 48, under the name the benchmark's
+    # ``mla_flash_train_roofline`` sums beside ``flash_fwd``
+    assert set(re.findall(r"flash_\w+", str(jaxpr))) == {"flash_fwd",
+                                                          "flash_dkv"}
 
 
 def test_the_offset_flash_kernel_is_named():
@@ -466,6 +468,9 @@ def test_the_train_steps_moe_kernels_are_named():
     shared = _reader("_moe_train.py")
     assert shared.MOE_GMM_TRAIN == (
         moe.KERNEL_MOE_GMM, moe.KERNEL_MOE_GMM_DX, moe.KERNEL_MOE_GMM_DW)
+    # the reader's list still holds ``flash_dq``, a kernel that is gone
+    # since PR 48 (the backward is ``flash_dkv`` alone); it sums what it
+    # finds, and the entry is a ``benchmark`` PR's to drop
     assert shared.FLASH_TRAIN == ("flash_fwd", "flash_dq", "flash_dkv")
     x = jnp.ones((32, 64), jnp.float32)
     w = jnp.ones((1, 4, 64, 32), jnp.float32)

@@ -4,7 +4,7 @@ lanes wide, values, the output and its gradient ``d_v``, as a latent head's
 
 Every case holds one path to the plain ``attend``: the forward kernel, the
 scan fallback of the backward (``_bwd_blockwise``, blocks under 128) and the
-two Pallas backward kernels (``_flash_bwd_pallas``, blocks of 128), causal
+Pallas backward kernel (``_flash_bwd_pallas``, blocks of 128), causal
 and not, one kv head under all query heads and a kv head a query head, at
 one width (the parent's cases, ``tests/test_ops.py``) beside two.  Then the
 dispatcher and ``models/latent.py``'s expanded attention, result and
@@ -85,7 +85,7 @@ def test_flash_scan_backward_matches_plain(causal, kv_heads, widths):
 @CAUSAL
 @KV_HEADS
 def test_flash_pallas_backward_matches_plain(causal, kv_heads, widths):
-    """Blocks of 128 take the Pallas dq / dkv kernels."""
+    """Blocks of 128 take the Pallas backward kernel."""
     _assert_same_gradients(causal, _qkv(widths, B=1, S=256, KV=kv_heads),
                            block_q=128, block_kv=128)
 
@@ -137,7 +137,7 @@ def test_expanded_attention_and_its_gradient_are_the_references(
     widths, against the kind's float32 reference: the result and the
     gradient by the input and by every matrix of the layer, through the
     plain path the dispatcher takes on the CPU, and through the flash
-    kernels interpreted, forward and both backward kernels (256 positions
+    kernels interpreted, the forward and the backward kernel (256 positions
     are two blocks of 128)."""
     from ray_tpu.ops import attention as ops_attention
 
