@@ -77,19 +77,23 @@ class ObjectRef:
 _global_worker_or_none = None
 
 
-def _ref_created(ref: ObjectRef):
+def _worker():
     global _global_worker_or_none
     if _global_worker_or_none is None:
         from .core_worker import \
             global_worker_or_none as _global_worker_or_none
-    w = _global_worker_or_none()
+    return _global_worker_or_none()
+
+
+def _ref_created(ref: ObjectRef):
+    w = _worker()
     if w is not None:
         w.reference_counter.add_local_ref(ref.id, ref.owner)
 
 
 def _ref_deleted(ref: ObjectRef):
-    if _global_worker_or_none is None:
-        return
-    w = _global_worker_or_none()
+    # binds the lookup too: the submit path registers a task's return refs
+    # without passing through ``_ref_created``
+    w = _worker()
     if w is not None:
         w.reference_counter.remove_local_ref(ref.id, ref.owner)

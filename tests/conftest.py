@@ -19,6 +19,11 @@ import signal  # noqa: E402
 
 import pytest  # noqa: E402
 
+# the tests' shared modules assert too: show their operands as a test's own
+pytest.register_assert_rewrite("contract", "chip_compile")
+
+from kinds import kind, tiny_doc, tiny_model  # noqa: E402,F401  (fixtures)
+
 # Per-test timeout (reference: pytest.ini's 180 s pytest-timeout default).
 # pytest-timeout isn't in this image, so a SIGALRM in the main thread stands
 # in: a wedged test raises instead of hanging the whole suite forever.
@@ -117,6 +122,16 @@ def pytest_sessionfinish(session, exitstatus):
         tr.write_line(msg)
     if session.exitstatus == 0:
         session.exitstatus = 1
+
+
+def pytest_generate_tests(metafunc):
+    """A contract test's cases are its class's (``contract.cases``)."""
+    marked = getattr(metafunc.function, "class_cases", None)
+    if marked and metafunc.cls is not None:
+        arg, of_class = marked
+        rows = of_class(metafunc.cls)
+        metafunc.parametrize(arg, [r[1:] for r in rows],
+                             ids=[r[0] for r in rows])
 
 
 @pytest.fixture

@@ -135,6 +135,29 @@ def test_task_in_task(ray_start_regular):
     assert ray_tpu.get(parent.remote(10)) == 21
 
 
+def test_a_tasks_return_ref_deleted_first_lowers_the_count(ray_start_regular,
+                                                           monkeypatch):
+    """The submit path counts a task's one return ref itself, past
+    ``_ref_created``: in a process whose first ref is such a one (the
+    module's worker lookup still unbound) its deletion has to reach the
+    counter all the same."""
+    from ray_tpu.core import object_ref
+    from ray_tpu.core.core_worker import global_worker
+
+    @ray_tpu.remote
+    def one():
+        return 1
+
+    monkeypatch.setattr(object_ref, "_global_worker_or_none", None)
+    ref = one.remote()
+    assert object_ref._global_worker_or_none is None
+    oid, local = ref.id, global_worker().reference_counter.local
+    assert local.get(oid) == 1
+    del ref
+    assert local.get(oid, 0) == 0
+    assert object_ref._global_worker_or_none is not None
+
+
 def test_nested_get_no_deadlock():
     """Parents blocking on children must not deadlock the worker pool: blocked
     workers release their lease resources (reference: raylet blocked-worker

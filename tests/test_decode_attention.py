@@ -3,7 +3,8 @@ kernel, interpreted, against its twin and against a per-slot softmax written
 out in numpy, at both head arrangements the cells run (32 query heads over 8
 KV heads, 30 over 30), lengths at the blocks' edges, inactive slots and the
 logit softcap.  The chip's compiler sees the kernel in
-``tests/test_chip_compile.py``; here the arithmetic and the index maps."""
+``tests/test_chip_compile_kernels.py``; here the arithmetic and the index
+maps."""
 
 import jax
 import jax.numpy as jnp
@@ -145,23 +146,21 @@ def test_block_length_follows_the_row_width(monkeypatch):
 
 def test_an_inactive_slots_stale_length_changes_no_active_slots_output():
     """Through ``decode.decode_step``: a retired slot keeps its length."""
+    import kinds
     from ray_tpu.models import config as mcfg
     from ray_tpu.models import decode, transformer
 
     cfg = mcfg.tiny()
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg,
-                                     dtype=jnp.float32)
+    params, run = kinds.init(transformer.init_params, cfg), kinds.programs(cfg)
     cache = decode.init_kv_cache(cfg, 3, 32, dtype=jnp.float32)
     toks = jnp.asarray([[5, 6, 7, 8, 9, 10], [11, 12, 13, 0, 0, 0],
                         [1, 2, 3, 4, 5, 6]], jnp.int32)
-    cache, _ = decode.prefill(params, cache, toks, jnp.asarray([6, 3, 6]),
-                              jnp.arange(3), cfg, jnp.float32)
+    cache, _ = run.prefill(params, cache, toks, jnp.asarray([6, 3, 6]),
+                           jnp.arange(3))
     active = jnp.asarray([True, True, False])
     step = jnp.asarray([3, 4, 5], jnp.int32)
-    _, logits = decode.decode_step(params, cache, step, active, cfg,
-                                   jnp.float32)
+    _, logits = run.step(params, cache, step, active)
     stale = dict(cache, length=cache["length"].at[2].set(31))
-    _, logits_stale = decode.decode_step(params, stale, step, active, cfg,
-                                         jnp.float32)
+    _, logits_stale = run.step(params, stale, step, active)
     np.testing.assert_array_equal(np.asarray(logits[:2]),
                                   np.asarray(logits_stale[:2]))

@@ -85,20 +85,13 @@ def _latent_engine():
     slipped in while its programs are traced (8 experts, 2 a token: a chunk
     of 8, and the bucket of 32 is four)."""
     import functools
-    import json
-    import os
     from unittest import mock
 
-    import jax
     import jax.numpy as jnp
-    from benchmark.lib.manifest import load_model
+
+    import kinds
     from ray_tpu.models import decode
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    kind = load_model(os.path.join(bench, "models", "xing4_0.py"))
-    with open(os.path.join(bench, "tests", "tiny", "configs",
-                           "tiny-latent.json")) as f:
-        cfg = kind.program_config(json.load(f))
+    cfg, params = kinds.tiny("xing4_0")
     width = decode.prefill_width
     small = mock.patch.multiple(
         decode, EXPERT_TILE=2,
@@ -106,10 +99,8 @@ def _latent_engine():
         prefill_width=lambda cache, bucket, cfg, chunk=4: width(
             cache, bucket, cfg, 4))
     small.start()
-    eng = _engine(cfg, params=kind.init_params(jax.random.PRNGKey(3), cfg,
-                                               jnp.float32),
-                  num_slots=2, max_len=64, buckets=(16, 32),
-                  compute_dtype=jnp.float32)
+    eng = _engine(cfg, params=params, num_slots=2, max_len=64,
+                  buckets=(16, 32), compute_dtype=jnp.float32)
     return eng, small.stop
 
 
@@ -423,7 +414,7 @@ def test_an_admit_is_bound_behind_the_one_dispatch_in_flight(tiny_cfg, kind):
                 late["req"] = eng.submit([8, 9, 10, 11], max_tokens=3)
 
         eng._dispatch_step = dispatch_then_submit
-        while not late:
+        while "req" not in late:    # (the engine's thread sets "behind" first)
             time.sleep(0.001)
         _finish([late["req"], long])
         progs = [p for p, _ahead in log]

@@ -12,35 +12,44 @@ import pytest
 
 @pytest.fixture(scope="module")
 def tiny_model():
-    import jax
-    import jax.numpy as jnp
-
+    import kinds
     from ray_tpu.models import config as mcfg
     from ray_tpu.models import transformer
 
     cfg = mcfg.tiny()
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg,
-                                     dtype=jnp.float32)
-    return cfg, params
+    return cfg, kinds.init(transformer.init_params, cfg)
 
 
-def _reference_greedy(cfg, params, prompt, n_steps):
-    """Greedy decode via the full training forward (no cache)."""
+_forward = {}
+
+
+def _apply(cfg, params, toks):
+    """``transformer.apply`` in float32 under ``jit``, one program a
+    configuration: logits [B, S, V]."""
+    import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import transformer
+    if cfg not in _forward:
+        _forward[cfg] = jax.jit(lambda p, t: transformer.apply(
+            p, t, cfg, compute_dtype=jnp.float32)[0])
+    return _forward[cfg](params, jnp.asarray(toks, jnp.int32))
 
-    toks = list(prompt)
-    for _ in range(n_steps):
-        logits, _ = transformer.apply(params, jnp.asarray([toks], jnp.int32),
-                                      cfg, compute_dtype=jnp.float32)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
+
+def _reference_greedy(cfg, params, prompt, n_steps):
+    """Greedy decode via the full training forward (no cache): one shape,
+    the tokens not yet chosen zeros behind the causal mask."""
+    toks = np.zeros((1, len(prompt) + n_steps), np.int32)
+    toks[0, :len(prompt)] = prompt
+    for at in range(len(prompt), len(prompt) + n_steps):
+        toks[0, at] = int(np.argmax(_apply(cfg, params, toks)[0, at - 1]))
+    return toks[0, len(prompt):].tolist()
 
 
 def test_prefill_decode_matches_full_forward(tiny_model):
     import jax.numpy as jnp
 
+    import kinds
     from ray_tpu.models import decode as dec
 
     cfg, params = tiny_model
@@ -48,18 +57,17 @@ def test_prefill_decode_matches_full_forward(tiny_model):
     n_steps = 6
     want = _reference_greedy(cfg, params, prompt, n_steps)
 
+    run = kinds.programs(cfg)
     cache = dec.init_kv_cache(cfg, num_slots=2, max_len=32, dtype=jnp.float32)
     toks = jnp.asarray([prompt + [0] * (8 - len(prompt))], jnp.int32)
-    cache, logits = dec.prefill(params, cache, toks,
+    cache, logits = run.prefill(params, cache, toks,
                                 jnp.asarray([len(prompt)], jnp.int32),
-                                jnp.asarray([1], jnp.int32), cfg,
-                                compute_dtype=jnp.float32)
+                                jnp.asarray([1], jnp.int32))
     got = [int(jnp.argmax(logits[0]))]
     for _ in range(n_steps - 1):
         step_toks = jnp.zeros((2,), jnp.int32).at[1].set(got[-1])
-        cache, logits = dec.decode_step(params, cache, step_toks,
-                                        jnp.asarray([False, True]), cfg,
-                                        compute_dtype=jnp.float32)
+        cache, logits = run.step(params, cache, step_toks,
+                                 jnp.asarray([False, True]))
         got.append(int(jnp.argmax(logits[1])))
     assert got == want, f"cache decode {got} != full forward {want}"
 
@@ -80,39 +88,27 @@ def _seqs(cfg, n, length, seed=0):
 
 def _full_logits(cfg, params, seq):
     """logits[j]: the next-token distribution after seq[:j + 1]."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models import transformer
-    logits, _ = transformer.apply(params, jnp.asarray(seq[None]), cfg,
-                                  compute_dtype=jnp.float32)
-    return np.asarray(logits[0])
+    return np.asarray(_apply(cfg, params, seq[None])[0])
 
 
 def _prefill(cfg, params, cache, seqs, lens, slots, bucket):
-    import jax.numpy as jnp
-
-    from ray_tpu.models import decode as dec
+    import kinds
     toks = np.zeros((len(slots), bucket), np.int32)
     for row, (seq, n) in enumerate(zip(seqs, lens)):
         toks[row, :n] = seq[:n]
-    return dec.prefill(params, cache, jnp.asarray(toks),
-                       jnp.asarray(lens, jnp.int32),
-                       jnp.asarray(slots, jnp.int32), cfg,
-                       compute_dtype=jnp.float32)
+    return kinds.programs(cfg).prefill(params, cache, toks,
+                                       np.asarray(lens, np.int32),
+                                       np.asarray(slots, np.int32))
 
 
 def _step(cfg, params, cache, seqs_by_slot, active):
     """One teacher-forced decode step: slot s is fed seqs_by_slot[s][length]."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models import decode as dec
+    import kinds
     lengths = np.asarray(cache["length"])
     toks = np.zeros_like(lengths)
     for s, seq in seqs_by_slot.items():
         toks[s] = seq[min(lengths[s], len(seq) - 1)]
-    return dec.decode_step(params, cache, jnp.asarray(toks),
-                           jnp.asarray(active), cfg,
-                           compute_dtype=jnp.float32)
+    return kinds.programs(cfg).step(params, cache, toks, np.asarray(active))
 
 
 def _check_steps(cfg, params, cache, seqs_by_slot, active, steps):
@@ -232,14 +228,15 @@ def _case_state_loop_against_single_steps(cfg, params):
     state["tokens"] = state["tokens"].at[jnp.asarray(slots)].set(first)
     state["active"] = jnp.asarray(active)
     state["budget"] = jnp.full((n_slots,), 100, jnp.int32)
-    loop_cache, _, emitted = dec.decode_state_loop(
-        params, cache, state, steps, cfg, compute_dtype=jnp.float32)
+    loop_cache, _, emitted = jax.jit(lambda p, c, st: dec.decode_state_loop(
+        p, c, st, steps, cfg, compute_dtype=jnp.float32))(params, cache,
+                                                          state)
 
+    import kinds
     toks, single = state["tokens"], []
     for _ in range(steps):
-        cache, logits = dec.decode_step(params, cache, toks,
-                                        jnp.asarray(active), cfg,
-                                        compute_dtype=jnp.float32)
+        cache, logits = kinds.programs(cfg).step(params, cache, toks,
+                                                 jnp.asarray(active))
         toks = jnp.where(active, jnp.argmax(logits, axis=-1).astype(jnp.int32),
                          toks)
         single.append(np.asarray(toks))

@@ -6,11 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import kinds
 from ray_tpu.models import (ParallelContext, TransformerConfig, apply,
                             causal_lm_loss, init_params, tiny)
 from ray_tpu.ops.attention import attend
 from ray_tpu.parallel import (MeshSpec, init_sharded_state, make_mesh,
                               make_optimizer, make_train_step)
+
+_apply = jax.jit(apply, static_argnums=(2,))
 
 
 def test_forward_shapes_gpt2_style():
@@ -18,18 +21,18 @@ def test_forward_shapes_gpt2_style():
     cfg = TransformerConfig(**{**cfg.__dict__, "use_rope": False,
                                "use_rmsnorm": False, "use_swiglu": False,
                                "tied_embeddings": True})
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    params = kinds.init(init_params, cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
-    logits, _ = apply(params, toks, cfg)
+    logits, _ = _apply(params, toks, cfg)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert logits.dtype == jnp.float32
 
 
 def test_forward_llama_style():
     cfg = tiny()
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    params = kinds.init(init_params, cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
-    logits, _ = apply(params, toks, cfg)
+    logits, _ = _apply(params, toks, cfg)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())
 
@@ -39,9 +42,9 @@ def test_forward_gemma_style():
     cfg = tiny()
     cfg = TransformerConfig(**{**cfg.__dict__, "attn_logit_softcap": 30.0,
                                "tied_embeddings": True})
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    params = kinds.init(init_params, cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
-    logits, _ = apply(params, toks, cfg)
+    logits, _ = _apply(params, toks, cfg)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())
 
@@ -50,22 +53,22 @@ def test_forward_qwen_style():
     """Qwen-2 family marker: QKV biases on an otherwise Llama-style net."""
     cfg = tiny()
     cfg = TransformerConfig(**{**cfg.__dict__, "use_qkv_bias": True})
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    params = kinds.init(init_params, cfg)
     assert "bq" in params["blocks"]["attn"]
     assert "bo" not in params["blocks"]["attn"]  # qkv-only, unlike GPT-2
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
-    logits, _ = apply(params, toks, cfg)
+    logits, _ = _apply(params, toks, cfg)
     assert bool(jnp.isfinite(logits).all())
 
 
 def test_causal_masking():
     """Changing future tokens must not change current logits."""
     cfg = tiny()
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    params = kinds.init(init_params, cfg)
     t1 = jnp.zeros((1, 16), jnp.int32)
     t2 = t1.at[0, 10:].set(5)
-    l1, _ = apply(params, t1, cfg)
-    l2, _ = apply(params, t2, cfg)
+    l1, _ = _apply(params, t1, cfg)
+    l2, _ = _apply(params, t2, cfg)
     np.testing.assert_allclose(l1[0, :10], l2[0, :10], atol=1e-5)
 
 
@@ -176,7 +179,7 @@ LOSS_MESHES = {"fsdp4": dict(fsdp=4), "dp2-fsdp2": dict(dp=2, fsdp=2),
 
 def _loss_case(tied):
     cfg = TransformerConfig(**{**tiny().__dict__, "tied_embeddings": tied})
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    params = kinds.init(init_params, cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0,
                               cfg.vocab_size)
     return cfg, params, {"tokens": toks[:, :-1], "targets": toks[:, 1:]}

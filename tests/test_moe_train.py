@@ -7,7 +7,6 @@ uncut layer, the direct query projection, and the refusals.  Tiny sizes, the
 CPU: numerics and control flow, never speeds."""
 
 import dataclasses
-import json
 import os
 
 import jax
@@ -15,31 +14,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import kinds
 from ray_tpu.models import latent, transformer
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.ops import moe
 from ray_tpu.parallel import MeshSpec, make_optimizer, make_train_step
 from ray_tpu.parallel.train_step import TrainState, state_shardings
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TINY = os.path.join(REPO, "benchmark", "tests", "tiny", "configs",
-                    "tiny-kimi.json")
-F32 = jnp.float32
+ROW, F32 = kinds.KINDS["kimi_vl"], jnp.float32
 
 
 @pytest.fixture(scope="module")
-def kind():
-    from benchmark.lib.manifest import load_model
-    return load_model(os.path.join(REPO, "benchmark", "models", "kimi_vl.py"))
-
-
-@pytest.fixture(scope="module")
-def tiny(kind):
+def tiny(tiny_doc):
     """(the tiny configuration's file, the program's configuration, seeded
     float32 parameters)."""
-    doc = json.load(open(TINY))
-    cfg = kind.program_config(doc)
-    return doc, cfg, kind.init_params(jax.random.PRNGKey(3), cfg, F32)
+    return (tiny_doc, *kinds.tiny(ROW.name))
 
 
 # ------------------------------------------- the grouped matmul's backward
@@ -94,8 +83,10 @@ def test_gmm_custom_vjp_is_ragged_dots_derivative(tile, gated):
         return (jnp.einsum("tkh,tk->th", picked, gates) ** 2).sum()
 
     with jax.default_matmul_precision("highest"):
-        got, g_got = jax.value_and_grad(kernels, argnums=(0, 1, 2, 3))(x, *ws)
-        want, g_want = jax.value_and_grad(twin, argnums=(0, 1, 2, 3))(x, *ws)
+        got, g_got = jax.jit(jax.value_and_grad(
+            kernels, argnums=(0, 1, 2, 3)))(x, *ws)
+        want, g_want = jax.jit(jax.value_and_grad(
+            twin, argnums=(0, 1, 2, 3)))(x, *ws)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for a, b in zip(g_got, g_want):
         np.testing.assert_allclose(a, b, rtol=1e-4,
@@ -123,7 +114,7 @@ def test_a_layer_without_one_held_assignment_has_zero_gradients():
         return moe._combine(ys, jnp.zeros((T, TOPK)),
                             jnp.zeros((T, TOPK), bool), source, dest).sum()
 
-    out, grads = jax.value_and_grad(f, argnums=(0, 1, 2, 3))(x, *ws)
+    out, grads = jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3)))(x, *ws)
     assert float(out) == 0.0
     for g in grads:
         assert np.isfinite(np.asarray(g)).all() and not np.asarray(g).any()
@@ -170,8 +161,8 @@ def test_loss_and_gradients_are_the_references(kind, tiny):
         return jax.vmap(lambda s: kind.loss(p, s, doc))(toks).mean()
 
     with jax.default_matmul_precision("highest"):
-        got, g_got = jax.value_and_grad(program)(params)
-        want, g_want = jax.value_and_grad(reference)(params)
+        got, g_got = jax.jit(jax.value_and_grad(program))(params)
+        want, g_want = jax.jit(jax.value_and_grad(reference))(params)
     np.testing.assert_allclose(got, want, rtol=2e-6)
     flat_got = dict(jax.tree_util.tree_leaves_with_path(g_got))
     for path, b in jax.tree_util.tree_leaves_with_path(g_want):
@@ -204,8 +195,8 @@ def test_bf16_step_reads_the_references_loss_and_falls(kind, tiny):
     step = make_train_step(cfg, mesh, opt, sh, remat=tr["remat"])
     assert step.opt_state_bytes == 2 * cfg.num_params() * 4 + 8
     toks = _batch(doc, 1)
-    want = float(jax.vmap(lambda s: kind.loss(state.params, s, doc))(
-        toks).mean())
+    want = float(jax.jit(lambda p: jax.vmap(
+        lambda s: kind.loss(p, s, doc))(toks).mean())(state.params))
     losses = []
     for _ in range(6):
         state, m = step(state, {"tokens": toks[:, :-1],
@@ -253,13 +244,14 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(kind, tiny):
     uncut_doc = dict(doc, n_routed_experts=16, share={"expert_start": 0})
     uncut = lambda x: kind._expert_layer(x, mp, uncut_doc)   # noqa: E731
     with jax.default_matmul_precision("highest"):
-        (got, assignments), want = summed(x), uncut(x)
+        (got, assignments), want = jax.jit(summed)(x), jax.jit(uncut)(x)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
         # every assignment was some holder's, and one holder's alone
         assert int(assignments) == 40 * cfg.experts_per_token
         weights = jax.random.normal(jax.random.PRNGKey(9), want.shape, F32)
-        g_got = jax.grad(lambda x: (summed(x)[0] * weights).sum())(x)
-        g_want = jax.grad(lambda x: (uncut(x) * weights).sum())(x)
+        g_got = jax.jit(jax.grad(
+            lambda x: (summed(x)[0] * weights).sum()))(x)
+        g_want = jax.jit(jax.grad(lambda x: (uncut(x) * weights).sum()))(x)
     np.testing.assert_allclose(g_got, g_want, rtol=1e-3, atol=1e-4)
     # the reference's own share is the program's share
     fixed = dict(doc, share=dict(doc["share"], by_position=False))
@@ -304,16 +296,18 @@ def test_shares_by_position_add_up_and_keep_their_share_of_any_routing(
     tiled = dict(small, **{k: jnp.tile(v, (4, 1, 1)) for k, v in four.items()})
     uncut = lambda x: kind._expert_layer(x, tiled, uncut_doc)  # noqa: E731
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(summed(x), uncut(x), rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(jax.jit(summed)(x), jax.jit(uncut)(x),
+                                   rtol=1e-4, atol=1e-5)
         weights = jax.random.normal(jax.random.PRNGKey(9), x.shape, F32)
         np.testing.assert_allclose(
-            jax.grad(lambda x: (summed(x) * weights).sum())(x),
-            jax.grad(lambda x: (uncut(x) * weights).sum())(x),
+            jax.jit(jax.grad(lambda x: (summed(x) * weights).sum()))(x),
+            jax.jit(jax.grad(lambda x: (uncut(x) * weights).sum()))(x),
             rtol=1e-3, atol=1e-4)
         # the reference's share by position is the program's
-        one = kind._expert_layer(x, dict(small, **four), doc, shared=False)
-        np.testing.assert_allclose(share(x, small, 4)[0], one, rtol=1e-4,
-                                   atol=1e-5)
+        one = jax.jit(lambda x: kind._expert_layer(
+            x, dict(small, **four), doc, shared=False))(x)
+        np.testing.assert_allclose(jax.jit(lambda x: share(x, small, 4)[0])(x),
+                                   one, rtol=1e-4, atol=1e-5)
     # a router that has left group 0 for group 2 (its bias says so)
     gone = dict(small, bias=jnp.where(jnp.arange(16) // 4 == 2, 10.0, -10.0))
     k = cfg.experts_per_token
@@ -333,8 +327,9 @@ def test_the_direct_query_projection_is_the_references(kind, tiny):
                               cfg.num_heads * cfg.qk_head_dim)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 48, cfg.hidden_size))
     with jax.default_matmul_precision("highest"):
-        got = latent.attention(x, ap, cfg, jnp.arange(48)[None])
-        want = jax.vmap(lambda s: kind._attention(s, ap, doc))(x)
+        got = jax.jit(lambda x: latent.attention(
+            x, ap, cfg, jnp.arange(48)[None]))(x)
+        want = jax.jit(jax.vmap(lambda s: kind._attention(s, ap, doc)))(x)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
@@ -412,7 +407,7 @@ def test_the_chips_gradient_check_runs_at_the_tiny_size():
     (one held expert left out of the float32 layer) far over it."""
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "chip_moe_grad_check", os.path.join(REPO, "tests",
+        "chip_moe_grad_check", os.path.join(kinds.REPO, "tests",
                                             "chip_moe_grad_check.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)

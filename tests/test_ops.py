@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import kinds
 from ray_tpu.models import transformer, tiny
 from ray_tpu.ops.attention import attend
 from ray_tpu.ops.flash_attention import flash_attention
@@ -50,8 +51,8 @@ def test_flash_gradients_match_plain(causal):
     def lr(q, k, v):
         return (attend(q, k, v, causal=causal) ** 2).sum()
 
-    gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(lf, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(lr, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         scale = float(jnp.max(jnp.abs(b))) + 1e-9
         assert float(jnp.max(jnp.abs(a - b))) / scale < 1e-4
@@ -71,8 +72,8 @@ def test_flash_pallas_backward_matches_plain(causal, kv_heads):
     def lr(q, k, v):
         return (attend(q, k, v, causal=causal) * gup).sum()
 
-    gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(lf, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(lr, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         scale = float(jnp.max(jnp.abs(b))) + 1e-9
         assert float(jnp.max(jnp.abs(a - b))) / scale < 1e-4
@@ -101,17 +102,16 @@ def test_flash_uneven_seq_raises_and_auto_takes_plain(monkeypatch):
 
 def test_chunked_cross_entropy_matches_full():
     cfg = tiny(vocab=512, layers=2, hidden=64, heads=4, seq=128)
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    params = kinds.init(transformer.init_params, cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0, 512)
     batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
-    l1, _ = transformer.causal_lm_loss(params, batch, cfg, loss_chunk=None)
-    l2, _ = transformer.causal_lm_loss(params, batch, cfg, loss_chunk=32)
+    whole, chunked = (jax.jit(jax.value_and_grad(
+        lambda p: transformer.causal_lm_loss(p, batch, cfg,
+                                             loss_chunk=chunk)[0]))
+        for chunk in (None, 32))
+    (l1, g1), (l2, g2) = whole(params), chunked(params)
     assert abs(float(l1) - float(l2)) < 1e-4
 
-    g1 = jax.grad(lambda p: transformer.causal_lm_loss(
-        p, batch, cfg, loss_chunk=None)[0])(params)
-    g2 = jax.grad(lambda p: transformer.causal_lm_loss(
-        p, batch, cfg, loss_chunk=32)[0])(params)
     # bf16 compute: reduction-order differences are ~bf16 eps on O(1) grads
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         assert float(jnp.max(jnp.abs(a - b))) < 6e-3
@@ -119,11 +119,11 @@ def test_chunked_cross_entropy_matches_full():
 
 def test_chunked_cross_entropy_with_mask():
     cfg = tiny(vocab=512, layers=2, hidden=64, heads=4, seq=128)
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    params = kinds.init(transformer.init_params, cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0, 512)
     mask = (jax.random.uniform(jax.random.PRNGKey(2), (2, 128)) > 0.3)
     mask = mask.astype(jnp.float32)
     batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:], "loss_mask": mask}
-    l1, _ = transformer.causal_lm_loss(params, batch, cfg, loss_chunk=None)
-    l2, _ = transformer.causal_lm_loss(params, batch, cfg, loss_chunk=64)
+    l1, l2 = (jax.jit(lambda p: transformer.causal_lm_loss(
+        p, batch, cfg, loss_chunk=chunk)[0])(params) for chunk in (None, 64))
     assert abs(float(l1) - float(l2)) < 5e-4

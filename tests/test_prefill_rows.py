@@ -16,7 +16,6 @@ kind's own mixer, a chunk as long as the experts ask (``EXPERT_TILE``, 2
 here: 8 experts, 2 a token, so 8 positions, twice the shortest chunk of 4).
 Results, never speed."""
 
-import os
 from unittest import mock
 
 import jax
@@ -24,14 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import kinds
 from ray_tpu.models import decode, paged_decode, transformer
 from ray_tpu.models.config import TransformerConfig
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-KIND = os.path.join(REPO, "benchmark", "models", "olmo_hybrid.py")
-LATENT_KIND = os.path.join(REPO, "benchmark", "models", "xing4_0.py")
-LATENT_DOC = os.path.join(REPO, "benchmark", "tests", "tiny", "configs",
-                          "tiny-latent.json")
 
 SLOTS, SCRATCH, MAX_LEN, BUCKET, BATCH = 7, 6, 64, 32, 4
 CHUNK = BUCKET // 4                    # the "chunked" tree's; 19, 5, 32 and
@@ -69,33 +63,27 @@ class Tree:
             name, decode.PREFILL_CHUNK)
         self.tile = TILE if name == "latent" else decode.EXPERT_TILE
         self.rows = decode.LATENT if name == "latent" else ("k", "v")
+        # the plain reference of a prompt's last-token logits, under jit (a
+        # compile a length)
         if name == "hybrid":
-            from benchmark.lib.manifest import load_model
-            kind = load_model(KIND)
+            kind = kinds.load("olmo_hybrid")
             self.cfg = kind.program_config(HYBRID_DOC)
-            self.params = kind.init_params(jax.random.PRNGKey(3), self.cfg,
-                                           jnp.float32)
-            self.reference = lambda toks: kind.logits(
-                self.params, toks, HYBRID_DOC, jnp.array([len(toks) - 1]))[0]
+            self.params = kinds.init(kind.init_params, self.cfg, seed=3)
+            last = lambda p, t: kind.logits(  # noqa: E731
+                p, t, HYBRID_DOC, jnp.array([t.shape[0] - 1]))[0]
         elif name == "latent":
-            import json
-            from benchmark.lib.manifest import load_model
-            kind = load_model(LATENT_KIND)
-            with open(LATENT_DOC) as f:
-                doc = json.load(f)
-            self.cfg = kind.program_config(doc)
-            self.params = kind.init_params(jax.random.PRNGKey(3), self.cfg,
-                                           jnp.float32)
-            self.reference = lambda toks: kind.logits(
-                self.params, np.asarray(toks), doc,
-                jnp.array([len(toks) - 1]), follow=None)[0]
+            kind, doc = kinds.load("xing4_0"), kinds.doc("xing4_0")
+            self.cfg, self.params = kinds.tiny("xing4_0")
+            last = lambda p, t: kind.logits(  # noqa: E731
+                p, t, doc, jnp.array([t.shape[0] - 1]), follow=None)[0]
         else:
             self.cfg = DENSE
-            self.params = transformer.init_params(
-                jax.random.PRNGKey(0), DENSE, dtype=jnp.float32)
-            self.reference = lambda toks: transformer.apply(
-                self.params, jnp.asarray(toks)[None], DENSE,
-                compute_dtype=jnp.float32)[0][0, -1]
+            self.params = kinds.init(transformer.init_params, DENSE)
+            last = lambda p, t: transformer.apply(  # noqa: E731
+                p, t[None], DENSE, compute_dtype=jnp.float32)[0][0, -1]
+        last = jax.jit(last)
+        self.reference = lambda toks: last(self.params,
+                                           jnp.asarray(toks, jnp.int32))
         if self.paged:
             cache = paged_decode.init_paged_cache(
                 self.cfg, NUM_PAGES, PAGE, SLOTS, MAX_PAGES, jnp.float32)
@@ -255,11 +243,11 @@ def test_no_count_walks_every_row(tree):
     if tree.paged:
         cache = dict(cache, block_table=cache["block_table"].at[slots].set(
             tree.arrays([0, 1, 2, 3])[-1]))
-    with_count, lg_count = tree.prefill(
-        tree.params, cache, toks, lengths, slots, tree.cfg, jnp.float32,
-        rows=jnp.int32(BATCH))
-    without, lg = tree.prefill(tree.params, cache, toks, lengths, slots,
-                               tree.cfg, jnp.float32)
+    with_count, lg_count = jax.jit(lambda *a, rows: tree.prefill(
+        *a, tree.cfg, jnp.float32, rows=rows))(
+            tree.params, cache, toks, lengths, slots, rows=jnp.int32(BATCH))
+    without, lg = jax.jit(lambda *a: tree.prefill(
+        *a, tree.cfg, jnp.float32))(tree.params, cache, toks, lengths, slots)
     for name, a in without.items():
         np.testing.assert_array_equal(a, with_count[name], err_msg=name)
     np.testing.assert_array_equal(lg, lg_count)

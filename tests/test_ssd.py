@@ -9,45 +9,22 @@ recurrence one token at a time, no chunks, no cache, nothing imported from
 ray_tpu.models or ray_tpu.ops), ``ssd.ssd_recurrence`` or
 ``jax.lax.ragged_dot``.  Numbers here are about results, never speed."""
 
-import copy
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import decode, transformer
-from ray_tpu.models.config import TransformerConfig
+import contract
+import kinds
+from ray_tpu.models import decode
 from ray_tpu.ops import moe, ssd
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-KIND = os.path.join(REPO, "benchmark", "models", "nemotron_h.py")
-L9 = os.path.join(REPO, "benchmark", "configs",
-                  "nemotron-3-nano-30b-a3b-serve-l9-e64.json")
-TINY = os.path.join(REPO, "benchmark", "tests", "tiny", "configs",
-                    "tiny-nemotron.json")
+ROW = kinds.KINDS["nemotron_h"]
 NINE = ("ssm", "mlp", "ssm", "mlp", "ssm", "full", "mlp", "ssm", "mlp")
 
 
-@pytest.fixture(scope="module")
-def kind():
-    from benchmark.lib.manifest import load_model
-    return load_model(KIND)
-
-
-@pytest.fixture(scope="module")
-def tiny_doc():
-    with open(TINY) as f:
-        return json.load(f)
-
-
-@pytest.fixture(scope="module")
-def tiny(kind, tiny_doc):
-    cfg = kind.program_config(tiny_doc)
-    params = kind.init_params(jax.random.PRNGKey(3), cfg, jnp.float32)
-    return cfg, params
+class TestNemotronH(contract.OnlyServed, contract.Shares):
+    row = ROW
 
 
 def _inputs(b, t, nh, p, g, n, seed, decay=None):
@@ -167,71 +144,7 @@ def test_the_kernel_refuses_to_differentiate_an_epilogue():
         jax.grad(f)(jnp.ones((16, 16)))
 
 
-# --------------------------------------- the model against its reference
-
-def test_prefill_then_decode_equals_the_reference(kind, tiny, tiny_doc):
-    """Two rows of other lengths into slots 2 and 0, then decode steps with
-    an idle slot between: logits against the reference's full forward."""
-    cfg, params = tiny
-    toks = np.random.default_rng(1).integers(1, 256, (2, 40)).astype(np.int32)
-    lens = np.array([29, 18], np.int32)
-    cache = decode.init_kv_cache(cfg, 3, 64, jnp.float32)
-    cache, lg = decode.prefill(params, cache, np.pad(toks[:, :32], (
-        (0, 0), (0, 0))), lens, np.array([2, 0], np.int32), cfg, jnp.float32)
-    got = [[lg[0]], [lg[1]]]
-    for i in range(8):
-        cache, lg = decode.decode_step(
-            params, cache, np.array([toks[1, 18 + i], 0, toks[0, 29 + i]],
-                                    np.int32),
-            np.array([True, False, True]), cfg, jnp.float32)
-        got[0].append(lg[2])
-        got[1].append(lg[0])
-    for row, n in enumerate(lens):
-        want = kind.logits(params, jnp.asarray(toks[row, :n + 8]), tiny_doc,
-                           jnp.arange(n - 1, n + 8))
-        assert float(want.std()) > 0.5
-        np.testing.assert_allclose(jnp.stack(got[row]), want, atol=2e-4)
-    # the idle slot kept its (zero) state and tail
-    assert not bool(cache["state"][:, 1].any() or cache["conv"][:, 1].any())
-    assert cache["length"].tolist() == [26, 0, 37]
-
-
-def test_the_shares_add_up_to_the_whole_layer(kind, tiny_doc):
-    """16 experts in 2 shares of 8: the two shares' routed parts plus the
-    shared expert counted once equal the uncut reference's layer, in the
-    reference and in the program (``decode._experts``) alike."""
-    whole = copy.deepcopy(tiny_doc)
-    whole["n_routed_experts"] = 16
-    del whole["reduced"], whole["share"]
-    cfg = kind.program_config(whole)
-    params = kind.init_params(jax.random.PRNGKey(5), cfg, jnp.float32)
-    lp = jax.tree.map(lambda a: a[0, 2], params["blocks"]["mlp"]["moe"])
-    stacks = params["blocks"]["experts"]
-    assert sorted(stacks) == ["w_out", "w_up"] and "shared_gate" not in lp
-    x = jax.random.normal(jax.random.PRNGKey(6), (24, 64))
-    with jax.default_matmul_precision("highest"):
-        want, _ = kind.expert_layer(x, lp, stacks, 2, whole)
-        routed, _ = kind.expert_layer(x, lp, stacks, 2, whole, shared=False)
-        shared = want - routed
-        parts, program = [], []
-        for share in range(2):
-            doc = copy.deepcopy(tiny_doc)
-            doc["share"]["expert_start"] = 8 * share
-            held = jax.tree.map(lambda a: a[:, 8 * share:8 * share + 8],
-                                stacks)
-            parts.append(kind.expert_layer(x, lp, held, 2, doc,
-                                           shared=False)[0])
-            out, (counts, chosen) = decode._experts(
-                x[None], {"moe": lp}, kind.program_config(doc), None,
-                jnp.float32, 2, held)
-            program.append(out[0] - shared)
-            assert chosen.shape == (1, 24, 3)
-    assert float(jnp.abs(want).mean()) > 0.1
-    np.testing.assert_allclose(sum(parts) + shared, want, atol=1e-5)
-    np.testing.assert_allclose(sum(program) + shared, want, atol=1e-4)
-    # a share is a part, not the whole
-    assert float(jnp.abs(parts[0] + shared - want).max()) > 0.05
-
+# ------------------------------------------------ the kind's reference
 
 @pytest.mark.parametrize("case", ["tie-break", "another-set", "nothing"])
 def test_the_reference_takes_a_recorded_choice_only_as_a_tie_break(
@@ -273,49 +186,7 @@ def test_the_reference_takes_a_recorded_choice_only_as_a_tie_break(
         * tiny_doc["routed_scaling_factor"], rtol=1e-6)
 
 
-def test_engine_generates_the_references_greedy_tokens(kind, tiny, tiny_doc):
-    """Through ``LLMEngine``'s three calls, with its gauges."""
-    from ray_tpu.serve.llm import LLMEngine
-    cfg, params = tiny
-    eng = LLMEngine(cfg, params=params, num_slots=4, max_len=64,
-                    buckets=(32, 64), compute_dtype=jnp.float32,
-                    steps_per_dispatch=2)
-    prompt = [int(t) for t in np.random.default_rng(1).integers(1, 256, 11)]
-    try:
-        out = eng.generate(prompt, max_tokens=6)
-        stats = {**eng.counters(), **eng.breakdown()}
-    finally:
-        eng.shutdown()
-    toks = list(prompt)
-    for _ in range(6):
-        lg = kind.logits(params, jnp.asarray(toks, jnp.int32), tiny_doc,
-                         follow=None)
-        toks.append(int(jnp.argmax(lg[-1])))
-    assert list(out) == toks[len(prompt):]
-    state = 4 * 5 * (4 * 16 * 32 * 4 + 3 * (4 * 16 + 2 * 2 * 32) * 4)
-    assert {k: stats[k] for k in (
-        "experts_held", "expert_layers", "linear_layers", "ssm_layers",
-        "full_layers", "cache_state_bytes", "cache_kv_bytes",
-        "cache_latent_bytes")} == {
-        "experts_held": 8, "expert_layers": 4, "linear_layers": 0,
-        "ssm_layers": 4, "full_layers": 1, "cache_state_bytes": state,
-        "cache_kv_bytes": 2 * 1 * 5 * 64 * 2 * 32 * 4,
-        "cache_latent_bytes": 0}
-    assert stats["moe_assignments"] > 0 and stats["moe_experts_touched"] > 0
-    # an admit's assignments: 11 tokens x 3 x 4 expert layers (of which the
-    # held half is computed)
-    assert stats["moe_assignments_prefill"] == 11 * 3 * 4 // 2
-
-
 # ------------------------ one walk, whichever sublayers a kind has
-
-def _scans(jaxpr):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _scans(sub)
-
 
 WALKS = {
     "sublayers-alone-with-experts": dict(
@@ -332,32 +203,8 @@ WALKS = {
 }
 
 
-@pytest.mark.parametrize("name", list(WALKS))
-def test_every_tree_walks_the_same_layer_stack(name):
-    """A pattern of sublayers alone (with experts under its "mlp" layers,
-    or a dense MLP), a pattern with an MLP under every mixer and a model
-    without a pattern each trace to one scan over their periods (three
-    here) whose body holds no scan over layers; an expert layer's index is
-    its rank among the expert layers."""
-    kw = dict(vocab_size=64, hidden_size=32, num_heads=2, num_kv_heads=1,
-              mlp_size=48, max_seq_len=64, use_rope=False, no_positions=True,
-              linear_num_heads=2, linear_key_dim=8, linear_value_dim=8)
-    if name == "without-a-pattern":
-        kw = {k: v for k, v in kw.items() if not k.startswith("linear_")}
-        kw.update(no_positions=False)
-    cfg = TransformerConfig(**{**kw, **WALKS[name]})
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg,
-                                     dtype=jnp.float32)
-    cache = decode.init_kv_cache(cfg, 2, 32, jnp.float32,
-                                 expert_choices=cfg.moe_dropless)
-    step = lambda p, c: decode.decode_step(  # noqa: E731
-        p, c, jnp.ones((2,), jnp.int32), jnp.ones((2,), bool), cfg,
-        jnp.float32)
-    over_layers = [e for e in _scans(jax.make_jaxpr(step)(params, cache).jaxpr)
-                   if e.params["length"] == 3]
-    assert len(over_layers) == 1
-    new, logits = jax.jit(step)(params, cache)
-    assert bool(jnp.isfinite(logits).all())
+def _walked(cfg, params, new):
+    """An expert layer's index is its rank among the expert layers."""
     if cfg.ssm_layers:
         assert new["state"].shape[0] == cfg.ssm_layers == 3
         assert bool((jnp.abs(new["state"]).sum((1, 2, 3, 4)) > 0).all())
@@ -371,113 +218,41 @@ def test_every_tree_walks_the_same_layer_stack(name):
         assert int(new["moe_counts"][0]) == 2 * 2 * 6
 
 
-# ------------------------------------------------------------- refusals
-
-BASE = dict(vocab_size=8, hidden_size=8, num_heads=1, num_kv_heads=1,
-            mlp_size=8, max_seq_len=8, num_layers=4, linear_num_heads=2,
-            linear_key_dim=4, linear_value_dim=4, ssm_groups=1,
-            layer_pattern=("ssm", "full"))
-
-
-@pytest.mark.parametrize("kw,match", [
-    (dict(layer_pattern=("ssm", "mlp", "full")), "whole periods"),
-    (dict(layer_pattern=("ssm", "moe")), "kinds are"),
-    (dict(layer_pattern=("ssm", "linear")), "one recurrent kind"),
-    (dict(ssm_groups=0), "ssm_groups"),
-    (dict(linear_num_heads=3, ssm_groups=2), "ssm_groups"),
-    (dict(layer_pattern=("linear", "full")), "ssm_groups"),
-    (dict(mlp_act="gelu"), "mlp_act"),
-    (dict(layer_pattern=(), ssm_groups=0, mlp_act="relu2"),
-     "layer_pattern only"),
-    (dict(moe_dropless=True, num_experts=4, experts_per_token=2,
-          expert_mlp_size=8, use_swiglu=False), "SwiGLU"),
-    (dict(dense_prefix_layers=1, moe_dropless=True, num_experts=4,
-          experts_per_token=2, expert_mlp_size=8), "same MLP"),
-], ids=["layers-not-whole-periods", "a-kind-it-does-not-know",
-        "two-recurrent-kinds", "no-groups", "heads-not-whole-groups",
-        "groups-without-ssm", "another-activation", "relu2-without-a-pattern",
-        "ungated-experts-of-no-activation", "a-dense-prefix"])
-def test_config_refuses_what_it_cannot_wire(kw, match):
-    TransformerConfig(**BASE)
-    with pytest.raises(ValueError, match=match):
-        TransformerConfig(**{**BASE, **kw})
+def _base(name):
+    kw = dict(vocab_size=64, hidden_size=32, num_heads=2, num_kv_heads=1,
+              mlp_size=48, max_seq_len=64, use_rope=False, no_positions=True,
+              linear_num_heads=2, linear_key_dim=8, linear_value_dim=8)
+    if name == "without-a-pattern":
+        kw = {k: v for k, v in kw.items() if not k.startswith("linear_")}
+        kw.update(no_positions=False)
+    return kw
 
 
-@pytest.mark.parametrize("kw,match", [
-    (dict(paged=True), "page arena"),
-    (dict(spec_decode_enabled=True), "rolled out"),
-    (dict(tp=2), "sharding rule"),
-], ids=["paged", "speculative", "tp"])
-def test_the_engine_refuses_what_a_recurrent_state_cannot_do(tiny, kw, match):
-    from ray_tpu.serve.llm import LLMEngine
-    cfg, params = tiny
-    with pytest.raises(ValueError, match=match):
-        LLMEngine(cfg, params=params, num_slots=2, max_len=32, **kw)
-
-
-@pytest.mark.parametrize("what", ["apply_trunk", "make_train_step"])
-def test_training_refuses_the_pattern(tiny, what):
-    cfg, params = tiny
-    with pytest.raises(NotImplementedError, match="layer_pattern"):
-        if what == "apply_trunk":
-            transformer.apply_trunk(params, jnp.ones((1, 8), jnp.int32), cfg)
-        else:
-            from ray_tpu.parallel import MeshSpec, make_optimizer, \
-                make_train_step
-            mesh = MeshSpec(fsdp=2).build(jax.devices()[:2])
-            make_train_step(cfg, mesh, make_optimizer(), None)
-
-
-@pytest.mark.parametrize("change,match", [
-    (dict(hybrid_override_pattern="MEMEM-EME"), "fifth kind"),
-    (dict(hybrid_override_pattern="MEMEM*EM"), "num_hidden_layers"),
-    (dict(mlp_hidden_act="silu"), "mlp_hidden_act"),
-    (dict(mamba_hidden_act="gelu"), "mamba_hidden_act"),
-    (dict(use_conv_bias=False), "use_conv_bias"),
-    (dict(mlp_bias=True), "mlp_bias"),
-    (dict(norm_topk_prob=False), "norm_topk_prob"),
-    (dict(n_group=2), "n_group"),
-    (dict(tie_word_embeddings=True), "tie_word_embeddings"),
-    (dict(sliding_window=128), "sliding_window"),
-    (dict(moe_shared_expert_intermediate_size=40), "whole multiple"),
-    (dict(share=dict(expert_start=14)), "past the router"),
-], ids=["a-dense-mlp-layer", "a-pattern-of-another-depth", "gated-experts",
-        "another-mixer-activation", "no-conv-bias", "biases",
-        "unnormalised-gates", "router-groups", "tied-head", "a-window",
-        "a-shared-width-between", "a-share-past-the-end"])
-def test_the_kind_refuses_what_the_block_cannot_express(kind, tiny_doc,
-                                                        change, match):
-    kind.program_config(tiny_doc)
-    with pytest.raises(ValueError, match=match):
-        kind.program_config({**tiny_doc, **change})
+# a pattern of sublayers alone (with experts under its "mlp" layers, or a
+# dense MLP), a pattern with an MLP under every mixer and a model without a
+# pattern
+test_every_tree_walks_the_same_layer_stack = contract.walks(WALKS, _base,
+                                                            _walked)
 
 
 # --------------------------------------- the kind's counts (l9-e64 file)
 
-def test_counts_of_the_l9_e64_configuration(kind):
-    """``num_params`` is the program's tree to the parameter (3.17B held);
-    ISSUE 46's check of the reading against the published count; the decode
-    step's four byte terms; the kernels' counts against their own loads and
-    stores."""
-    with open(L9) as f:
-        doc = json.load(f)
-    cfg = kind.program_config(doc)
+def test_counts_of_the_l9_e64_configurations_step_and_kernels(kind):
+    """(The tree, the matrices a layer and the cache's gauges: the
+    contract's.)  ISSUE 46's check of the reading against the published
+    count; the decode step's four byte terms; the kernels' counts against
+    their own loads and stores."""
+    doc, cfg = kinds.cell_doc(ROW.name), kinds.cell_cfg(ROW.name)
     assert cfg.layer_pattern == NINE and cfg.sublayers_alone
     assert (cfg.num_experts, cfg.experts_held, cfg.expert_start,
             cfg.shared_experts, cfg.mlp_act) == (128, 64, 0, 2, "relu2")
     assert (cfg.expert_layers, cfg.linear_layers, cfg.full_layers,
             cfg.ssm_layers) == (4, 0, 1, 4)
-    tree = jax.eval_shape(lambda k: kind.init_params(k, cfg, jnp.bfloat16),
-                          jax.random.PRNGKey(0))
-    leaves = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
     per = kind.layer_matrix_params(doc)
-    assert per == {"mamba": 38_707_200, "attention": 23_396_352,
-                   "expert": 9_977_856, "shared": 19_955_712,
-                   "router": 344_064}
     matrices = (4 * (per["mamba"] + 64 * per["expert"] + per["shared"]
                      + per["router"]) + per["attention"] + 2 * 65536 * 2688)
     assert cfg.num_params() == matrices == 3_166_076_928
-    assert kind.num_params(doc) == leaves == matrices + 4 * (
+    assert kind.num_params(doc) == matrices + 4 * (
         5 * 6144 + 3 * 64 + 4096) + 9 * 2688 + 4 * 128 + 2688
     # the published model by the same reading: 31.6B, 3.2B a token
     full = 23 * (per["mamba"] + 128 * per["expert"] + per["shared"]
@@ -517,12 +292,3 @@ def test_counts_of_the_l9_e64_configuration(kind):
     assert kind.moe_gmm_bytes(doc, 10, 3) == (
         3 * 9_977_856 + 10 * 2 * (2688 + 1856)) * 2
     assert kind.decode_attn_bytes(doc, 7) == 7 * 1024
-    # the cache the engine would hold for this file: 64 + 1 rows
-    cache = jax.eval_shape(lambda: decode.init_kv_cache(cfg, 65, 8192,
-                                                        jnp.bfloat16))
-    assert decode.cache_gauges(cfg, cache) == {
-        "cache_kv_bytes": 65 * 8192 * 1024,
-        "cache_state_bytes": 65 * (kind.state_bytes_per_slot(doc)
-                                   + 4 * 3 * 6144 * 2),
-        "linear_layers": 0, "ssm_layers": 4, "full_layers": 1,
-        "cache_latent_bytes": 0, "expert_layers": 4, "experts_held": 64}

@@ -14,6 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import kinds
 from ray_tpu.util import profiler
 
 
@@ -313,92 +314,12 @@ def test_stage_spans_carry_the_wait_account(tiny_cfg, monkeypatch):
         c["first_token_own_row_s"])
 
 
-# ------------------------------------------------ layers of two kinds (PR 29)
-
-@pytest.fixture(scope="module")
-def hybrid_cfg():
-    from ray_tpu.models.config import TransformerConfig
-    return TransformerConfig(
-        vocab_size=256, num_layers=4, hidden_size=64, num_heads=4,
-        num_kv_heads=4, mlp_size=192, max_seq_len=64, use_rope=False,
-        no_positions=True, qk_norm=True, norm_on_output=True,
-        layer_pattern=("linear", "linear", "linear", "full"),
-        linear_num_heads=4, linear_key_dim=8, linear_value_dim=16,
-        linear_neg_eigval=True)
-
-
-def test_the_gdn_kernels_are_named():
-    """The names a device trace shows (``gdn_chunk_fwd [pallas]``,
-    ``gdn_recurrent_step [pallas]``), which the benchmark's gdn_* readers
-    spell out for themselves."""
-    import importlib.util
-    import os
-
-    from ray_tpu.ops import gated_delta as gd
-
-    assert gd.KERNEL_CHUNK_FWD == "gdn_chunk_fwd"
-    assert gd.KERNEL_RECURRENT_STEP == "gdn_recurrent_step"
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "layer_metrics", "_gdn.py")
-    spec = importlib.util.spec_from_file_location("_gdn_readers", path)
-    readers = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(readers)
-    assert (readers.CHUNK_FWD, readers.RECURRENT_STEP) == (
-        gd.KERNEL_CHUNK_FWD, gd.KERNEL_RECURRENT_STEP)
-    q = jnp.ones((1, 64, 2, 8), jnp.float32)
-    v = jnp.ones((1, 64, 2, 16), jnp.float32)
-    g = jnp.zeros((1, 64, 2), jnp.float32)
-    chunk = jax.make_jaxpr(lambda *a: gd.gdn_chunk_fwd(
-        *a, interpret=True))(q, q, v, g, g)
-    assert "gdn_chunk_fwd" in str(chunk)
-    step = jax.make_jaxpr(lambda *a: gd.gdn_recurrent_step(
-        *a, interpret=True))(jnp.zeros((2, 1, 2, 8, 16)), jnp.int32(1),
-                             q[:, 0], q[:, 0], v[:, 0], g[:, 0], g[:, 0])
-    assert "gdn_recurrent_step" in str(step)
-
-
-def test_hybrid_serve_programs_carry_their_scopes(hybrid_cfg):
-    eng = _engine(hybrid_cfg, prefill_batch=2)
-    try:
-        decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
-        assert _module_name(decode) == "jit_engine_decode"
-        assert {"attn", "mlp", "norm", "lm_head", "kv_write", "kv_read",
-                "gdn", "gdn_conv", "state_read",
-                "state_write"} <= _scopes(decode)
-        admit = eng._prefill_fn(16).lower(
-            eng.params, eng.cache, eng._state, *eng._admit_arrays([], 16, []))
-        assert _module_name(admit) == "jit_admit_fn"
-        assert {"attn", "mlp", "norm", "lm_head", "kv_write", "gdn",
-                "gdn_conv", "state_write"} <= _scopes(admit)
-        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
-        stats = eng.breakdown()
-        assert {"cache_kv_bytes", "cache_state_bytes", "linear_layers",
-                "full_layers"} <= set(stats)
-    finally:
-        eng.shutdown()
-
-
-# ------- latent attention, dropless experts, residual streams (PR 35)
-
-@pytest.fixture(scope="module")
-def latent_cfg():
-    from ray_tpu.models.config import TransformerConfig
-    return TransformerConfig(
-        vocab_size=256, num_layers=3, hidden_size=64, num_heads=4,
-        num_kv_heads=4, mlp_size=128, max_seq_len=64, rope_theta=10000.0,
-        norm_eps=1e-6, q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
-        qk_rope_head_dim=8, v_head_dim=16, rope_yarn_factor=64.0,
-        rope_yarn_original_max=16, rope_yarn_mscale_all_dim=1.0,
-        moe_dropless=True, num_experts=8, experts_per_token=2,
-        expert_mlp_size=32, shared_experts=1, routed_scaling_factor=2.0,
-        dense_prefix_layers=1, hc_mult=4)
-
+# ------------------- the kinds of model the benchmark serves (tests/kinds.py)
 
 def _reader(name):
     import importlib.util
     import os
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "layer_metrics", name)
+    path = os.path.join(kinds.BENCH, "layer_metrics", name)
     spec = importlib.util.spec_from_file_location(
         "_reader_" + name.split(".")[0], path)
     mod = importlib.util.module_from_spec(spec)
@@ -406,82 +327,113 @@ def _reader(name):
     return mod
 
 
-def test_the_moe_and_latent_kernels_are_named():
-    """The names a device trace shows (``moe_gmm [pallas]``,
-    ``mla_decode_attn [pallas]``), which the benchmark's readers spell out
-    for themselves."""
+def _traced():
+    """A call of each kernel a kind names, interpreted, as a jaxpr's text."""
     from ray_tpu.ops import decode_attention as da
-    from ray_tpu.ops import moe
+    from ray_tpu.ops import gated_delta as gd
+    from ray_tpu.ops import kda, moe, ssd
+    f32 = jnp.float32
+    q, v = jnp.ones((1, 64, 2, 8), f32), jnp.ones((1, 64, 2, 16), f32)
+    g, state = jnp.zeros((1, 64, 2, 8), f32), jnp.zeros((2, 1, 2, 8, 16))
+    x, b = jnp.ones((1, 128, 2, 8), f32), jnp.ones((1, 128, 1, 16), f32)
+    a = jnp.zeros((2,), f32)
+    rows = jnp.ones((32, 64), f32)
+    plan = (jnp.zeros((2,), jnp.int32), jnp.int32(2), 16)
 
-    assert moe.KERNEL_MOE_GMM == "moe_gmm"
-    assert da.KERNEL_MLA_DECODE_ATTN == "mla_decode_attn"
-    assert _reader("moe_gmm_roofline.py").MOE_GMM == moe.KERNEL_MOE_GMM
-    assert (_reader("mla_decode_attn_roofline.py").MLA_DECODE_ATTN
-            == da.KERNEL_MLA_DECODE_ATTN)
-    assert _reader("moe_mla_kernels_device_share.py").KERNELS == (
-        moe.KERNEL_MOE_GMM, da.KERNEL_MLA_DECODE_ATTN)
-    x = jnp.ones((32, 64), jnp.float32)
-    w = jnp.ones((2, 4, 64, 32), jnp.float32)
-    gmm = jax.make_jaxpr(lambda x, w: moe.moe_gmm(
-        x, (w, w), jnp.int32(1), jnp.zeros((2,), jnp.int32), jnp.int32(2),
-        16, interpret=True))(x, w)
-    assert "moe_gmm" in str(gmm)
-    attn = jax.make_jaxpr(lambda q, r, c, k: da.mla_decode_attn(
-        q, r, c, k, jnp.int32(0), jnp.array([3, 0]), 0.1, interpret=True))(
-            jnp.ones((2, 4, 32)), jnp.ones((2, 4, 8)),
-            jnp.ones((1, 2, 16, 32)), jnp.ones((1, 2, 8, 16)))
-    assert "mla_decode_attn" in str(attn)
+    def gmm(x, w, layer):
+        return moe.moe_gmm(x, (w, w), jnp.int32(layer), *plan,
+                           interpret=True)
+
+    return {
+        "gdn_chunk_fwd": lambda: jax.make_jaxpr(lambda *a: gd.gdn_chunk_fwd(
+            *a, interpret=True))(q, q, v, g[..., 0], g[..., 0]),
+        "gdn_recurrent_step": lambda: jax.make_jaxpr(
+            lambda *a: gd.gdn_recurrent_step(*a, interpret=True))(
+                state, jnp.int32(1), q[:, 0], q[:, 0], v[:, 0], g[:, 0, :, 0],
+                g[:, 0, :, 0]),
+        "kda_chunk_fwd": lambda: jax.make_jaxpr(lambda *a: kda.kda_chunk_fwd(
+            *a, interpret=True))(q, q, v, g, g[..., 0]),
+        "kda_recurrent_step": lambda: jax.make_jaxpr(
+            lambda *a: kda.kda_recurrent_step(*a, interpret=True))(
+                state, jnp.int32(1), q[:, 0], q[:, 0], v[:, 0], g[:, 0],
+                g[:, 0, :, 0]),
+        "ssd_chunk_fwd": lambda: jax.make_jaxpr(lambda *a: ssd.ssd_chunk_fwd(
+            *a, interpret=True))(x, x[..., 0], a, b, b, a),
+        "ssd_recurrent_step": lambda: jax.make_jaxpr(
+            lambda *a: ssd.ssd_recurrent_step(*a, interpret=True))(
+                state, jnp.int32(1), x[:, 0], x[:, 0, :, 0], a, b[:, 0],
+                b[:, 0], a),
+        "moe_gmm": lambda: jax.make_jaxpr(lambda x, w: gmm(x, w, 1))(
+            rows, jnp.ones((2, 4, 64, 32), f32)),
+        "mla_decode_attn": lambda: jax.make_jaxpr(
+            lambda q, r, c, k: da.mla_decode_attn(
+                q, r, c, k, jnp.int32(0), jnp.array([3, 0]), 0.1,
+                interpret=True))(
+                    jnp.ones((2, 4, 32)), jnp.ones((2, 4, 8)),
+                    jnp.ones((1, 2, 16, 32)), jnp.ones((1, 2, 8, 16))),
+        # the backward's two kernels beside the forward's
+        "moe_gmm_dx": lambda: jax.make_jaxpr(jax.grad(
+            lambda x, w: gmm(x, w, 0).sum(), argnums=(0, 1)))(
+                rows, jnp.ones((1, 4, 64, 32), f32)),
+    }
 
 
-def test_latent_serve_programs_carry_their_scopes(latent_cfg):
-    eng = _engine(latent_cfg)
+@pytest.mark.parametrize("name", [k.name for k in kinds.KINDS.values()
+                                  if k.kernels])
+def test_the_kinds_kernels_are_named(name):
+    """The names a device trace shows (``gdn_chunk_fwd [pallas]``, ...),
+    which the benchmark's readers spell out for themselves."""
+    import importlib
+    row, traced = kinds.KINDS[name], _traced()
+    for module, const, kernel in row.kernels:
+        ops = importlib.import_module("ray_tpu.ops." + module)
+        assert getattr(ops, const) == kernel
+        # a kind that trains names its kernels in one derivative's trace
+        text = str(traced.get(kernel, traced["moe_gmm_dx"])())
+        assert re.search(r"\b" + kernel + r"\b", text), kernel
+    for reader, const, value in row.readers:
+        assert getattr(_reader(reader), const) == value
+
+
+@pytest.mark.parametrize("name", [k.name for k in kinds.KINDS.values()
+                                  if k.scopes])
+def test_the_kinds_serve_programs_carry_their_scopes(name):
+    """The engine's two programs of the kind's tiny configuration: the
+    scopes of its mixers, caches and experts (the row's), and the gauges and
+    counters its engine reports."""
+    from ray_tpu.models import transformer
+    row = kinds.KINDS[name]
+    sc, cfg = row.scopes, kinds.load(name).program_config(kinds.doc(name))
+    # (any weights do: the program's own initialiser, as the engine's)
+    params = kinds.init(transformer.init_params, cfg, jnp.bfloat16)
+    eng = _engine(cfg, params=params,
+                  **{k: v for k, v in row.engine["kw"].items()
+                     if k == "prefill_batch"})
     try:
         decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
         assert _module_name(decode) == "jit_engine_decode"
-        shared = {"attn", "mlp", "norm", "lm_head", "mla_down", "mla_up",
-                  "latent_write", "moe_route", "moe_sort", "moe_experts",
-                  "moe_shared", "moe_combine", "hc_coeff", "hc_mix"}
-        assert shared | {"latent_read"} <= _scopes(decode)
+        assert sc["both"] | sc["decode"] <= _scopes(decode)
+        assert not sc["neither"] & _scopes(decode)
         admit = eng._prefill_fn(16).lower(
             eng.params, eng.cache, eng._state, *eng._admit_arrays([], 16, []))
         assert _module_name(admit) == "jit_admit_fn"
-        assert shared <= _scopes(admit)
+        assert sc["both"] <= _scopes(admit)
+        if "decode_text" in sc:
+            assert re.search(sc["decode_text"],
+                             decode.as_text(debug_info=True))
         assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
-        assert {"cache_latent_bytes", "experts_held", "expert_layers",
-                "moe_assignments", "moe_experts_touched",
-                "moe_expert_layer_steps", "moe_assignments_prefill"} <= set(
-                    {**eng.breakdown(), **eng.counters()})
+        stats = {**eng.counters(), **eng.breakdown()}
     finally:
         eng.shutdown()
+    assert set(sc["stats"]) <= set(stats)
+    layers = {k: v for k, v in row.engine["gauges"].items()
+              if not k.endswith("_bytes")}
+    assert {k: stats[k] for k in layers} == layers
 
 
 # ---------- latent attention and dropless experts in the train step (PR 39)
 
-def test_the_train_steps_moe_kernels_are_named():
-    """The backward's two kernels beside the forward's, as a device trace
-    shows them (``moe_gmm_dx [pallas]``, ``moe_gmm_dw [pallas]``), which the
-    benchmark's train readers spell out for themselves."""
-    from ray_tpu.ops import moe
-
-    assert (moe.KERNEL_MOE_GMM, moe.KERNEL_MOE_GMM_DX,
-            moe.KERNEL_MOE_GMM_DW) == ("moe_gmm", "moe_gmm_dx", "moe_gmm_dw")
-    shared = _reader("_moe_train.py")
-    assert shared.MOE_GMM_TRAIN == (
-        moe.KERNEL_MOE_GMM, moe.KERNEL_MOE_GMM_DX, moe.KERNEL_MOE_GMM_DW)
-    # the reader's list still holds ``flash_dq``, a kernel that is gone
-    # since PR 48 (the backward is ``flash_dkv`` alone); it sums what it
-    # finds, and the entry is a ``benchmark`` PR's to drop
-    assert shared.FLASH_TRAIN == ("flash_fwd", "flash_dq", "flash_dkv")
-    x = jnp.ones((32, 64), jnp.float32)
-    w = jnp.ones((1, 4, 64, 32), jnp.float32)
-    grad = jax.make_jaxpr(jax.grad(lambda x, w: moe.moe_gmm(
-        x, (w, w), jnp.int32(0), jnp.zeros((2,), jnp.int32), jnp.int32(2),
-        16, interpret=True).sum(), argnums=(0, 1)))(x, w)
-    for name in shared.MOE_GMM_TRAIN:
-        assert re.search(r"\b" + name + r"\b", str(grad)), name
-
-
-def test_the_train_step_carries_the_latent_and_expert_scopes(latent_cfg):
+def test_the_train_step_carries_the_latent_and_expert_scopes():
     """``jit_train_step`` of a configuration with latent attention, a dense
     prefix and dropless experts: the same scopes as its serve programs have
     (no cache write or read), forward and backward."""
@@ -489,8 +441,8 @@ def test_the_train_step_carries_the_latent_and_expert_scopes(latent_cfg):
     from ray_tpu.parallel.train_step import TrainState, state_shardings
     from ray_tpu.models import transformer
 
-    cfg = dataclasses.replace(latent_cfg, hc_mult=0, q_lora_rank=0,
-                              experts_held=4, expert_start=4)
+    cfg = dataclasses.replace(kinds.tiny("xing4_0")[0], hc_mult=0,
+                              q_lora_rank=0, experts_held=4, expert_start=4)
     mesh = MeshSpec(fsdp=-1).build(jax.devices()[:1])
     opt = make_optimizer()
 
@@ -508,153 +460,3 @@ def test_the_train_step_carries_the_latent_and_expert_scopes(latent_cfg):
     assert {"attn", "mlp", "norm", "loss", "optimizer", "mla_down", "mla_up",
             "moe_route", "moe_sort", "moe_experts", "moe_shared",
             "moe_combine", "transpose", "jvp"} <= _scopes(lowered)
-
-
-# ---- a decay a channel, a gated full layer, experts under a pattern (PR 44)
-
-@pytest.fixture(scope="module")
-def kda_cfg():
-    from ray_tpu.models.config import TransformerConfig
-    return TransformerConfig(
-        vocab_size=256, num_layers=4, hidden_size=64, num_heads=4,
-        num_kv_heads=2, mlp_size=128, max_seq_len=64, use_rope=False,
-        no_positions=True, attn_head_dim=32, attn_output_gate=True,
-        layer_pattern=("full", "linear", "linear", "linear"),
-        linear_num_heads=4, linear_key_dim=16, linear_value_dim=16,
-        linear_neg_eigval=True, linear_decay_per_channel=True,
-        linear_gate_rank=16, moe_dropless=True,
-        num_experts=16, experts_per_token=4, expert_mlp_size=32,
-        shared_experts=1, expert_start=4, experts_held=4)
-
-
-def test_the_kda_kernels_are_named():
-    """The names a device trace shows (``kda_chunk_fwd [pallas]``,
-    ``kda_recurrent_step [pallas]``), which the benchmark's kda_* readers
-    spell out for themselves."""
-    from ray_tpu.ops import kda
-
-    assert kda.KERNEL_KDA_CHUNK_FWD == "kda_chunk_fwd"
-    assert kda.KERNEL_KDA_RECURRENT_STEP == "kda_recurrent_step"
-    readers = _reader("_kda.py")
-    assert (readers.CHUNK_FWD, readers.RECURRENT_STEP, readers.MOE_GMM) == (
-        kda.KERNEL_KDA_CHUNK_FWD, kda.KERNEL_KDA_RECURRENT_STEP, "moe_gmm")
-    assert _reader("kda_moe_kernels_device_share.py").KERNELS == (
-        "kda_chunk_fwd", "kda_recurrent_step", "moe_gmm")
-    q = jnp.ones((1, 64, 2, 8), jnp.float32)
-    v = jnp.ones((1, 64, 2, 16), jnp.float32)
-    g = jnp.zeros((1, 64, 2, 8), jnp.float32)
-    chunk = jax.make_jaxpr(lambda *a: kda.kda_chunk_fwd(
-        *a, interpret=True))(q, q, v, g, g[..., 0])
-    assert "kda_chunk_fwd" in str(chunk)
-    step = jax.make_jaxpr(lambda *a: kda.kda_recurrent_step(
-        *a, interpret=True))(jnp.zeros((2, 1, 2, 8, 16)), jnp.int32(1),
-                             q[:, 0], q[:, 0], v[:, 0], g[:, 0], g[:, 0, :, 0])
-    assert "kda_recurrent_step" in str(step)
-
-
-def test_kda_serve_programs_carry_their_scopes(kda_cfg):
-    """The KDA mixer's pieces under ``kda`` / ``kda_conv`` / ``kda_gate``
-    (state reads and writes keep ``state_read`` / ``state_write``), the
-    gated full layer's gate under ``attn``, the experts' under ``moe_*``;
-    none of the scalar-decay mixer's ``gdn`` scopes."""
-    eng = _engine(kda_cfg)
-    try:
-        decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
-        assert _module_name(decode) == "jit_engine_decode"
-        shared = {"attn", "norm", "lm_head", "kv_write", "kda", "kda_conv",
-                  "kda_gate", "state_write", "moe_route", "moe_sort",
-                  "moe_experts", "moe_shared", "moe_combine"}
-        assert shared | {"kv_read", "state_read"} <= _scopes(decode)
-        assert not {"gdn", "gdn_conv"} & _scopes(decode)
-        admit = eng._prefill_fn(16).lower(
-            eng.params, eng.cache, eng._state, *eng._admit_arrays([], 16, []))
-        assert _module_name(admit) == "jit_admit_fn"
-        assert shared <= _scopes(admit)
-        # the gate's sigmoid sits under the full layer's ``attn``
-        assert re.search(r'attn/logistic', decode.as_text(debug_info=True))
-        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
-        stats = {**eng.counters(), **eng.breakdown()}
-        assert {"cache_kv_bytes", "cache_state_bytes", "linear_layers",
-                "full_layers", "experts_held", "expert_layers",
-                "moe_assignments", "moe_experts_touched",
-                "moe_expert_layer_steps", "moe_assignments_prefill"} <= set(
-                    stats)
-        assert (stats["experts_held"], stats["expert_layers"],
-                stats["linear_layers"], stats["full_layers"]) == (4, 4, 3, 1)
-    finally:
-        eng.shutdown()
-
-
-# ---- a state-space mixer, layers that are one sublayer alone, experts of
-# ---- two matrices (PR 46)
-
-@pytest.fixture(scope="module")
-def ssm_cfg():
-    from ray_tpu.models.config import TransformerConfig
-    return TransformerConfig(
-        vocab_size=256, num_layers=9, hidden_size=64, num_heads=4,
-        num_kv_heads=2, mlp_size=24, max_seq_len=64, use_rope=False,
-        no_positions=True, attn_head_dim=32,
-        layer_pattern=("ssm", "mlp", "ssm", "mlp", "ssm", "full", "mlp",
-                       "ssm", "mlp"),
-        mlp_act="relu2", linear_num_heads=4,
-        linear_key_dim=32, linear_value_dim=16, ssm_groups=2,
-        moe_dropless=True, num_experts=16, experts_per_token=3,
-        expert_mlp_size=24, shared_experts=2, routed_scaling_factor=2.5,
-        expert_start=8, experts_held=8)
-
-
-def test_the_ssd_kernels_are_named():
-    """The names a device trace shows (``ssd_chunk_fwd [pallas]``,
-    ``ssd_recurrent_step [pallas]``), which the benchmark's ssd_* readers
-    spell out for themselves."""
-    from ray_tpu.ops import ssd
-
-    assert ssd.KERNEL_SSD_CHUNK_FWD == "ssd_chunk_fwd"
-    assert ssd.KERNEL_SSD_RECURRENT_STEP == "ssd_recurrent_step"
-    readers = _reader("_ssd.py")
-    assert (readers.CHUNK_FWD, readers.RECURRENT_STEP, readers.MOE_GMM) == (
-        ssd.KERNEL_SSD_CHUNK_FWD, ssd.KERNEL_SSD_RECURRENT_STEP, "moe_gmm")
-    assert _reader("ssm_moe_kernels_device_share.py").KERNELS == (
-        "ssd_chunk_fwd", "ssd_recurrent_step", "moe_gmm")
-    x = jnp.ones((1, 128, 2, 8), jnp.float32)
-    b = jnp.ones((1, 128, 1, 16), jnp.float32)
-    a = jnp.zeros((2,), jnp.float32)
-    chunk = jax.make_jaxpr(lambda *args: ssd.ssd_chunk_fwd(
-        *args, interpret=True))(x, x[..., 0], a, b, b, a)
-    assert "ssd_chunk_fwd" in str(chunk)
-    step = jax.make_jaxpr(lambda *args: ssd.ssd_recurrent_step(
-        *args, interpret=True))(jnp.zeros((2, 1, 2, 8, 16)), jnp.int32(1),
-                                x[:, 0], x[:, 0, :, 0], a, b[:, 0], b[:, 0],
-                                a)
-    assert "ssd_recurrent_step" in str(step)
-
-
-def test_ssm_serve_programs_carry_their_scopes(ssm_cfg):
-    """The state-space mixer's pieces under ``ssm`` / ``ssm_conv`` (state
-    reads and writes keep ``state_read`` / ``state_write``), the expert
-    layer's under ``moe_*`` as under any layer."""
-    eng = _engine(ssm_cfg)
-    try:
-        decode = eng._decode_fn.lower(eng.params, eng.cache, eng._state)
-        assert _module_name(decode) == "jit_engine_decode"
-        shared = {"attn", "norm", "lm_head", "kv_write", "ssm", "ssm_conv",
-                  "state_write", "moe_route", "moe_sort", "moe_experts",
-                  "moe_shared", "moe_combine"}
-        assert shared | {"kv_read", "state_read"} <= _scopes(decode)
-        admit = eng._prefill_fn(16).lower(
-            eng.params, eng.cache, eng._state, *eng._admit_arrays([], 16, []))
-        assert _module_name(admit) == "jit_admit_fn"
-        assert shared <= _scopes(admit)
-        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
-        stats = {**eng.counters(), **eng.breakdown()}
-        assert {"cache_kv_bytes", "cache_state_bytes", "linear_layers",
-                "ssm_layers", "full_layers", "experts_held", "expert_layers",
-                "moe_assignments", "moe_experts_touched",
-                "moe_expert_layer_steps", "moe_assignments_prefill"} <= set(
-                    stats)
-        assert (stats["experts_held"], stats["expert_layers"],
-                stats["linear_layers"], stats["ssm_layers"],
-                stats["full_layers"]) == (8, 4, 0, 4, 1)
-    finally:
-        eng.shutdown()

@@ -15,19 +15,16 @@ in the order the suite is handed to its workers, so that those two keep the
 seconds they took: CHANGES.md, PR 45, says why.
 """
 
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import kinds
 from ray_tpu.models import latent
 from ray_tpu.ops.attention import attend, mha
 from ray_tpu.ops.flash_attention import flash_attention
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: (query and key width, value width): one width, and a latent head's two
 WIDTHS = pytest.mark.parametrize("widths", [(64, 64), (24, 16)],
@@ -50,8 +47,8 @@ def _assert_same_gradients(causal, operands, **blocks):
     g = jax.random.normal(jax.random.PRNGKey(7), q.shape[:3] + v.shape[3:])
 
     def through(fn):
-        return jax.grad(lambda *a: (fn(*a) * g).sum(), argnums=(0, 1, 2))(
-            *operands)
+        return jax.jit(jax.grad(lambda *a: (fn(*a) * g).sum(),
+                                argnums=(0, 1, 2)))(*operands)
 
     got = through(lambda q, k, v: flash_attention(q, k, v, causal=causal,
                                                   **blocks))
@@ -118,13 +115,8 @@ def tiny_latent():
     """(the block kind, its tiny configuration file, the program's
     configuration, one attention layer's float32 parameters): 4 heads of
     16 + 8 lanes for queries and keys and 16 for values."""
-    from benchmark.lib.manifest import load_model
-    kind = load_model(os.path.join(REPO, "benchmark", "models", "xing4_0.py"))
-    with open(os.path.join(REPO, "benchmark", "tests", "tiny", "configs",
-                           "tiny-latent.json")) as f:
-        doc = json.load(f)
-    cfg = kind.program_config(doc)
-    params = kind.init_params(jax.random.PRNGKey(3), cfg, jnp.float32)
+    kind, doc = kinds.load("xing4_0"), kinds.doc("xing4_0")
+    cfg, params = kinds.tiny("xing4_0")
     return kind, doc, cfg, jax.tree.map(lambda a: a[0],
                                         params["prefix"]["attn"])
 
@@ -162,8 +154,10 @@ def test_expanded_attention_and_its_gradient_are_the_references(
                 * weights).sum()
 
     with jax.default_matmul_precision("highest"):
-        got, got_grads = jax.value_and_grad(program, argnums=(0, 1))(x, ap)
-        want, want_grads = jax.value_and_grad(reference, argnums=(0, 1))(x, ap)
+        got, got_grads = jax.jit(jax.value_and_grad(
+            program, argnums=(0, 1)))(x, ap)
+        want, want_grads = jax.jit(jax.value_and_grad(
+            reference, argnums=(0, 1)))(x, ap)
     np.testing.assert_allclose(got, want, rtol=1e-4)
     for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
         np.testing.assert_allclose(a, b, rtol=2e-3,

@@ -37,7 +37,8 @@ def _operands(widths, reps, B=1, S=384, KV=1):
 
 
 def _grads(fn, q, k, v, g):
-    return jax.grad(lambda *a: (fn(*a) * g).sum(), argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(jax.grad(lambda *a: (fn(*a) * g).sum(),
+                            argnums=(0, 1, 2)))(q, k, v)
 
 
 def _flash(causal):
