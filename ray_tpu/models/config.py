@@ -77,7 +77,9 @@ class TransformerConfig:
     # layers of several kinds (models/hybrid.py).  ``layer_pattern`` is one
     # period of kinds: "linear" (a gated-delta-rule mixer with a recurrent
     # state per sequence), "ssm" (a state-space mixer, Mamba-2's, with one
-    # too), "full" (the dense attention above) and "mlp" (the feed-forward
+    # too), "full" (the dense attention above), "window" (the same attention
+    # over the last ``sliding_window`` positions, its K/V on a ring a slot:
+    # models/decode.py) and "mlp" (the feed-forward
     # alone: dense, or the dropless experts where ``moe_dropless``); the
     # model is ``num_layers / len(layer_pattern)`` such periods.  Empty:
     # every layer is a dense block, the path of every other preset.
@@ -86,6 +88,8 @@ class TransformerConfig:
     # no MLP beneath and the MLPs are the pattern's "mlp" layers; without
     # one an MLP lies under every mixer.
     layer_pattern: Tuple[str, ...] = ()
+    # positions a "window" layer's query reads, its own among them
+    sliding_window: int = 0
     # (the "ssm" kind reads the same four: its heads, the state's width N
     # as the key's, the head's width P as the value's, its convolution)
     linear_num_heads: int = 0       # key heads = value heads of the mixer
@@ -102,7 +106,12 @@ class TransformerConfig:
     # of the published rank
     linear_decay_per_channel: bool = False
     linear_gate_rank: int = 0
-    qk_norm: bool = False           # RMSNorm over the whole q and k rows
+    # an RMSNorm on q and k before the heads attend: ``qk_norm`` over the
+    # whole row (all heads' channels one vector, a scale a channel);
+    # ``qk_head_norm`` over each head's channels alone, one scale vector of
+    # ``head_dim`` shared by the heads
+    qk_norm: bool = False
+    qk_head_norm: bool = False
     norm_on_output: bool = False    # x + norm(f(x)); else x + f(norm(x))
     # the full kind under a pattern: the published width of a head where it
     # is not hidden / heads (0: it is), and an elementwise sigmoid gate on
@@ -110,6 +119,15 @@ class TransformerConfig:
     attn_head_dim: int = 0
     attn_output_gate: bool = False
     no_positions: bool = False      # neither rotary nor learned positions
+    # rotary positions by kind: the "window" layers rotate q and k, the
+    # "full" layers add none (``use_rope`` alone: every attention layer does)
+    rope_window_only: bool = False
+    # multi-token-prediction blocks after the last layer (DeepSeek-V3's
+    # ``num_nextn_predict_layers``; models/speculative.py drafts with one):
+    # a block is ``W_eh [norm(E x_{t+1}); norm(h_t)]``, one "full" layer of
+    # the model's own form with K/V rows of its own, a norm, and the
+    # model's head
+    mtp_layers: int = 0
     # latent attention (models/latent.py; DeepSeek-V2's MLA, whose keys these
     # are): ``kv_lora_rank`` > 0 turns it on.  Queries and keys have heads
     # of ``qk_nope_head_dim + qk_rope_head_dim``, values of ``v_head_dim``;
@@ -173,15 +191,18 @@ class TransformerConfig:
     SERVED_ONLY = ("hc_mult",)
 
     #: a pattern's kinds of layer
-    KINDS = ("linear", "ssm", "full", "mlp")
+    KINDS = ("linear", "ssm", "full", "window", "mlp")
 
     def __post_init__(self):
         pat = self.layer_pattern
         self._check_latent_tree()
         if not pat:
-            only = [f for f in ("qk_norm", "norm_on_output", "attn_head_dim",
-                                "attn_output_gate", "linear_decay_per_channel",
-                                "linear_gate_rank", "ssm_groups", "mlp_act")
+            only = [f for f in ("qk_norm", "qk_head_norm", "norm_on_output",
+                                "attn_head_dim", "attn_output_gate",
+                                "linear_decay_per_channel",
+                                "linear_gate_rank", "ssm_groups", "mlp_act",
+                                "sliding_window", "rope_window_only",
+                                "mtp_layers")
                     if getattr(self, f)]
             if only:
                 raise ValueError(f"{only} are wired for a layer_pattern only "
@@ -210,6 +231,25 @@ class TransformerConfig:
                 f"ssm_groups {self.ssm_groups}: the groups of an 'ssm' "
                 f"layer's {self.linear_num_heads} heads, whole heads a "
                 "group, and no other kind's")
+        if ("window" in pat) != (self.sliding_window > 0):
+            raise ValueError(
+                f"sliding_window {self.sliding_window}: the positions a "
+                "'window' layer reads, 1 or more, and no other kind's")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm (the whole row) or qk_head_norm (a "
+                             "head), not both")
+        if self.rope_window_only and not (self.use_rope and "window" in pat):
+            raise ValueError("rope_window_only: rotary 'window' layers "
+                             "beside 'full' layers without positions needs "
+                             "use_rope and a 'window' kind")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(f"mtp_layers {self.mtp_layers}: models/"
+                             "speculative.py drafts one token with one block")
+        if self.mtp_layers and set(pat) - {"full", "window"}:
+            raise ValueError(
+                f"mtp_layers under {pat}: a rejected draft is rolled out of "
+                "rows and rings by their length, not out of a recurrent "
+                "state, and the block is a layer with its MLP beneath")
         if self.mlp_act not in ("", "relu2"):
             raise ValueError(f"mlp_act {self.mlp_act!r}: '' or 'relu2'")
         if self.linear_gate_rank < 0 or self.attn_head_dim < 0:
@@ -228,14 +268,21 @@ class TransformerConfig:
             # over K/V rows and the recurrent mixers, one residual stream,
             # with a dense MLP or dropless experts under every mixer or as
             # layers of their own
-            on = [f for f in ("kv_lora_rank", "hc_mult",
-                              "dense_prefix_layers") if getattr(self, f)]
+            on = [f for f in ("kv_lora_rank", "hc_mult") if getattr(self, f)]
             if on:
                 raise ValueError(
                     f"{on} do not combine with a layer_pattern: "
                     "models/hybrid.py's full layers cache K/V rows, not "
-                    "latent ones, its blocks carry one residual stream, "
-                    "and the layers that have one all have the same MLP")
+                    "latent ones, and its blocks carry one residual stream")
+            if self.dense_prefix_layers and (
+                    self.sublayers_alone
+                    or self.dense_prefix_layers > len(self.layer_pattern)):
+                raise ValueError(
+                    f"dense_prefix_layers {self.dense_prefix_layers} under "
+                    f"{self.layer_pattern}: the dense layers lie in the "
+                    "first period, which models/decode.py walks ahead of "
+                    "its scan, and every layer has its MLP beneath (no "
+                    "'mlp' kind)")
         if self.kv_lora_rank:
             if not (self.qk_nope_head_dim and self.qk_rope_head_dim
                     and self.v_head_dim) or self.q_lora_rank < 0:
@@ -377,6 +424,22 @@ class TransformerConfig:
                 else self.num_layers)
 
     @property
+    def window_layers(self) -> int:
+        return self._layers_of("window")
+
+    @property
+    def mtp_cfg(self) -> "TransformerConfig":
+        """The multi-token-prediction block as a model of one "full" layer
+        of this model's form (its experts where the model has them), which
+        ``decode.layer_stack`` walks on the block's own K/V rows."""
+        return dataclasses.replace(
+            self, num_layers=1, layer_pattern=("full",), sliding_window=0,
+            rope_window_only=False,
+            no_positions=self.no_positions or self.rope_window_only,
+            use_rope=self.use_rope and not self.rope_window_only,
+            dense_prefix_layers=0, mtp_layers=0)
+
+    @property
     def ssm_channels(self) -> Tuple[int, int]:
         """(inner channels heads x head width, channels the convolution
         mixes: those and B and C of every group) of the "ssm" kind."""
@@ -419,8 +482,14 @@ class TransformerConfig:
         if self.moe_dropless:
             mlp = (mats * h * self.expert_mlp_size
                    * (routed + self.shared_experts) + h * self.num_experts)
+        dense = mats * h * self.mlp_size
+        # a multi-token-prediction block: its projection of [embedding;
+        # hidden state] and one layer with the experts' MLP
+        block = self.mtp_layers * (2 * h * h + attn + mlp)
         return (self.linear_layers * mixer + self.ssm_layers * ssm
-                + self.full_layers * attn + self.mlp_layers * mlp
+                + (self.full_layers + self.window_layers) * attn
+                + (self.mlp_layers - self.dense_prefix_layers) * mlp
+                + self.dense_prefix_layers * dense + block
                 + 2 * self.vocab_size * h)
 
     def _latent_tree_params(self, routed: float) -> float:

@@ -25,6 +25,15 @@ says which mixers apply (``prefill``, ``window_step``):
   configuration has a ``layer_pattern`` (``hybrid.py``):
   ``hybrid.linear_prefill`` / ``linear_step`` for its "linear" layers or
   ``hybrid.ssm_prefill`` / ``ssm_step`` for its "ssm" layers;
+* rings, ``wk`` and ``wv`` [window layers, slots, ring, KV * D], beside the
+  rows, where the pattern has "window" layers: a position's row is
+  ``position mod ring``, keys stored rotated, so a row needs no position
+  beside what the slot's ``length`` says (``ring_len``).  A prefill writes a
+  row's last ``min(length, ring)`` positions (``_prefill_row``), a window of
+  W tokens its W (``ring_attention``); the full layers keep their rows;
+* ``mtp_k`` and ``mtp_v``, one layer's rows of its own for a
+  multi-token-prediction block (``cfg.mtp_layers``; ``mtp_walk``,
+  ``mtp_step``), and the token it drafted last a slot, ``draft``;
 * ``latent`` rows and ``rope_key`` columns, one compressed row a token a
   layer shared by all heads, where the configuration has latent attention
   (``latent.py``): ``latent.prefill_attention`` / ``decode_attention``.
@@ -52,10 +61,12 @@ TPU-first design:
   padding beyond a sequence's length is never *read* because decode masks by
   per-slot length (causality makes the writes at pad positions harmless:
   real positions never attend to them).
-* **Decode** is one token per active slot: q at position `len`, attention
+* **Decode** is one token per active slot (a speculative verify step: W):
+  q at position `len`, attention
   over the slot's rows up to it, read where they lie in the stack by one
   kernel that takes the layer index and the live lengths
-  (``ops.decode_attention.decode_attn``): no layer's slab is sliced out,
+  (``ops.decode_attention.decode_attn``; ``window_decode_attn`` over a
+  ring): no layer's slab is sliced out,
   and blocks past a slot's length, or of an inactive slot, are not fetched.
 
 No torch, no dynamic shapes, no per-request Python in the hot loop.
@@ -74,12 +85,29 @@ from .transformer import Params, _norm, lm_head_logits
 KVCache = Dict[str, jnp.ndarray]
 #: the two arrays of a latent cache (``latent.py``)
 LATENT = ("latent", "rope_key")
+#: the two arrays of the window layers' rings, and of a
+#: multi-token-prediction block's rows
+RING = ("wk", "wv")
+MTP_ROWS = ("mtp_k", "mtp_v")
 #: the optional record of the routers' choices (``init_kv_cache``)
 CHOICES = "expert_choices"
 
 
+def ring_len(cfg: TransformerConfig, tokens: int = 1) -> int:
+    """Rows of a window layer's ring that serve steps of ``tokens`` new
+    tokens a slot: ``sliding_window + tokens - 1`` rounded up to what the
+    kernel tiles (whole 128s; 16s under a window of 128).  The margin: in a
+    step of W tokens the last token's row lands where position ``t - window
+    + W - 1`` lay, which the first token still reads; with it a rejected
+    draft is rolled back by resetting ``length``, as on rows and pages."""
+    need = cfg.sliding_window + tokens - 1
+    tile = 128 if need >= 128 else 16
+    return -(-need // tile) * tile
+
+
 def init_kv_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
-                  dtype=jnp.bfloat16, expert_choices: bool = False) -> KVCache:
+                  dtype=jnp.bfloat16, expert_choices: bool = False,
+                  ring: Optional[int] = None) -> KVCache:
     """Allocate the HBM cache: K/V per full-attention layer per slot, plus
     per-slot lengths.  A model with recurrent layers keeps a state and a
     convolution tail for those beside it (``hybrid.init_state``); a model
@@ -91,7 +119,9 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
     ``prefill`` and ``window_step`` write it where the tree has it, as they
     write the token's row.  No engine asks for it; a comparison with a
     reference does, because a choice between two experts that score alike is
-    the one thing here a rounding can turn over."""
+    the one thing here a rounding can turn over.  ``ring``: the rows of a
+    window layer's ring (``ring_len(cfg)``, a step of one token, where not
+    given)."""
     length = jnp.zeros((num_slots,), jnp.int32)
     if cfg.kv_lora_rank:
         from . import latent
@@ -99,6 +129,15 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int, max_len: int,
                      length=length)
     else:
         cache = _init_rows(cfg, num_slots, max_len, dtype, length)
+    if cfg.window_layers:
+        rows = (cfg.window_layers, num_slots, ring or ring_len(cfg),
+                cache["k"].shape[-1])
+        cache.update(wk=jnp.zeros(rows, dtype), wv=jnp.zeros(rows, dtype))
+    if cfg.mtp_layers:
+        rows = (cfg.mtp_layers,) + cache["k"].shape[1:]
+        cache.update(mtp_k=jnp.zeros(rows, dtype),
+                     mtp_v=jnp.zeros(rows, dtype),
+                     draft=jnp.zeros((num_slots,), jnp.int32))
     if cfg.moe_dropless:
         cache["moe_counts"] = jnp.zeros((2,), jnp.int32)
     if expert_choices:
@@ -133,19 +172,24 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
     """What a cache tree holds, by kind of state: bytes of keys and values
     and of latent rows (per token) and of everything else a slot keeps (per
     sequence: a recurrent state, a convolution tail), the layers of each
-    kind (``ssm_layers`` where the model has them), and the experts a layer
-    holds."""
+    kind (``ssm_layers``, and ``window_layers`` with their rings' bytes,
+    where the model has them; a multi-token-prediction block's rows count
+    among the keys and values), and the experts a layer holds."""
     def nbytes(*names):
         return sum(int(a.size) * jnp.dtype(a.dtype).itemsize
                    for n, a in cache.items() if n in names)
 
-    per_token = ("k", "v") + LATENT
-    control = ("length", "block_table", "moe_counts", CHOICES)
+    per_token = ("k", "v") + MTP_ROWS + RING + LATENT
+    control = ("length", "block_table", "moe_counts", "draft", CHOICES)
     state = nbytes(*(n for n in cache if n not in per_token + control))
-    return {"cache_kv_bytes": nbytes("k", "v"), "cache_state_bytes": state,
+    return {"cache_kv_bytes": nbytes("k", "v", *MTP_ROWS),
+            "cache_state_bytes": state,
             "cache_latent_bytes": nbytes(*LATENT),
             "linear_layers": cfg.linear_layers,
             **({"ssm_layers": cfg.ssm_layers} if cfg.ssm_layers else {}),
+            **({"window_layers": cfg.window_layers,
+                "cache_ring_bytes": nbytes(*RING)}
+               if cfg.window_layers else {}),
             "full_layers": cfg.full_layers,
             "expert_layers": cfg.expert_layers,
             "experts_held": cfg.experts_held if cfg.moe_dropless else 0}
@@ -155,9 +199,15 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
 # Shared per-layer pieces
 # ---------------------------------------------------------------------------
 
+def _rotary(cfg: TransformerConfig, kind: str) -> bool:
+    """Whether a layer of ``kind`` rotates its queries and keys."""
+    return cfg.use_rope and (kind == "window" or not cfg.rope_window_only)
+
+
 @jax.named_scope("attn")
-def _qkv(x, p, cfg: TransformerConfig, positions):
-    """x: [B, S, H] -> q [B,S,NH,D], k/v [B,S,NKV,D] with RoPE applied."""
+def _qkv(x, p, cfg: TransformerConfig, positions, kind: str = "full"):
+    """x: [B, S, H] -> q [B,S,NH,D], k/v [B,S,NKV,D] with RoPE applied
+    where a layer of ``kind`` has it."""
     b, s, _ = x.shape
     cast = x.dtype
     q = x @ p["wq"].astype(cast)
@@ -173,7 +223,10 @@ def _qkv(x, p, cfg: TransformerConfig, positions):
     q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.use_rope:
+    if cfg.qk_head_norm:            # over a head, one scale for all heads
+        q = _norm(q, p["q_norm"], cfg)
+        k = _norm(k, p["k_norm"], cfg)
+    if _rotary(cfg, kind):
         q = _rope_per_row(q, positions, cfg.rope_theta)
         k = _rope_per_row(k, positions, cfg.rope_theta)
     return q, k, v
@@ -246,7 +299,8 @@ def _proj_out(attn, p, cast, x=None):
 
 def masked_attention(q, k, v, positions, cfg: TransformerConfig):
     """Plain float32 attention of W queries a row over the row's whole span:
-    no kernel reads a window of several tokens, or pages, yet.  q: [R, W,
+    no kernel reads pages yet (rows and rings have theirs,
+    ``decode_attention``, ``ring_attention``).  q: [R, W,
     NH, D] at absolute ``positions`` [R, W]; k, v: [R, span, NKV, D], row
     ``m`` holding position ``m``; query j reads positions <= its own.
     Returns [R, W, NH * D] float32."""
@@ -272,8 +326,8 @@ def masked_attention(q, k, v, positions, cfg: TransformerConfig):
 Mixer = Callable[..., Tuple[jnp.ndarray, Any, Any]]
 # the norm of a kind's mixer branch, among its layer's weights (an "mlp"
 # layer has no mixer)
-_BRANCH_NORM = {"full": "attn_norm", "linear": "mixer_norm",
-                "ssm": "mixer_norm"}
+_BRANCH_NORM = {"full": "attn_norm", "window": "attn_norm",
+                "linear": "mixer_norm", "ssm": "mixer_norm"}
 
 
 def _layer_weights(stack: Params, index, lead: int) -> Params:
@@ -291,8 +345,8 @@ def _layer_weights(stack: Params, index, lead: int) -> Params:
 
 def _kv_mixer(attention, cfg: TransformerConfig, *closed) -> Mixer:
     """``attention(y, attn_weights, cfg, k_all, v_all, layer, *closed) ->
-    (out, k_all, v_all)`` as the mixer of the "full" layers, on the carried
-    pair ``(k_all, v_all)``."""
+    (out, k_all, v_all)`` as the mixer of the "full" layers (or, over a
+    ring, of the "window" layers), on the carried pair ``(k_all, v_all)``."""
     def mixer(y, lp, i, kv):
         out, *kv = attention(y, lp["attn"], cfg, *kv, i, *closed)
         return out, tuple(kv), None
@@ -304,7 +358,9 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
                 cfg: TransformerConfig, compute_dtype,
                 pick: Optional[jnp.ndarray] = None,
                 live: Optional[jnp.ndarray] = None, head: bool = True):
-    """The serving forward pass: ``tokens`` [rows, W] at absolute
+    """The serving forward pass: ``tokens`` [rows, W] (or, floating, what
+    stands for their embeddings [rows, W, H]: a multi-token-prediction
+    block's input) at absolute
     ``positions`` [rows, W] through every layer, each layer's mixing done by
     its kind's entry of ``mixers`` on its kind's entry of ``carry`` (the
     cache arrays a mixer updates in place; never scan xs/ys).
@@ -318,7 +374,9 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     one of the two alone (an "mlp" layer is the feed-forward, every other
     kind its mixer), each behind its own norm.  A dense prefix
     (``cfg.dense_prefix_layers``) is walked before the scan, which is then
-    over the expert layers; a mixer is handed the layer's index among its
+    over the expert layers (under a pattern: the first period, its first
+    layers with a dense MLP, is walked before the scan over the others); a
+    mixer is handed the layer's index among its
     kind's cache rows, an expert layer its rank among the expert layers.  A
     block is wired
     ``x + f(norm(x))``, ``x + norm(f(x))`` under ``cfg.norm_on_output``, or
@@ -334,13 +392,16 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     caller that walks a row in pieces runs the head once); ys maps a kind
     to what its mixer returned a layer, stacked [layers of the kind, ...]
     (None where it returns none), ``"moe"`` to the expert layers' counts
-    [expert layers, 2] and ``"experts"`` to their routers' choices [expert
-    layers, rows, W, k]."""
+    [expert layers, 2], ``"experts"`` to their routers' choices [expert
+    layers, rows, W, k] and, where the model has a multi-token-prediction
+    block, ``"hidden"`` to the last layer's output [rows, W, H], before the
+    final norm."""
     cast = compute_dtype
     pattern, blocks = cfg.layer_pattern or ("full",), params["blocks"]
     per_period = {kind: pattern.count(kind) for kind in mixers}
     prefix = cfg.dense_prefix_layers
-    x = params["embed"]["tokens"][tokens].astype(cast)
+    x = (tokens if jnp.issubdtype(tokens.dtype, jnp.floating)
+         else params["embed"]["tokens"][tokens]).astype(cast)
     if cfg.learned_positions:
         x = x + params["embed"]["pos"][
             jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
@@ -422,6 +483,11 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
         return _layer_weights(small[kind] if by_kind else small, index,
                               1 + by_kind)
 
+    # a pattern's dense prefix lies in its first period, whose layers are
+    # counted with their kind's like every other's; without a pattern the
+    # dense layers are walked apart and come first among the cache's rows
+    ahead = prefix if cfg.layer_pattern else 0
+
     def period(walk, step):
         """One period of the pattern, its kinds unrolled; a layer's index
         is the one in its kind's stack of weights (a mixer's in its stack
@@ -433,40 +499,59 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
         for j, kind in enumerate(pattern):
             index = p * pattern.count(kind) + at[kind]
             at[kind] += 1
+            lp = weights(kind, index) if dense is None else dense
+            experts = None
+            if stacks and has_mlp[j]:
+                rank = p * sum(has_mlp) + sum(has_mlp[:j]) - ahead
+                # (the period walked ahead of the scan, and it alone, has a
+                # Python index)
+                if ahead and isinstance(p, int) and j < ahead:
+                    lp = dict(lp, mlp=_layer_weights(blocks["dense"], j, 1))
+                else:
+                    experts = (rank, stacks)
+                    if ahead:       # the small weights lie by layer too
+                        lp = dict(lp, moe=_layer_weights(blocks["moe"],
+                                                         rank, 1))
             x, state, rows, said = layer(
-                x, carry.get(kind), kind,
-                weights(kind, index) if dense is None else dense,
-                prefix + index,
-                (p * sum(has_mlp) + sum(has_mlp[:j]), stacks)
-                if stacks and has_mlp[j] else None)
+                x, carry.get(kind), kind, lp,
+                index + (0 if ahead else prefix), experts)
             if kind in per_period:
                 carry[kind] = state
                 ys[kind].append(rows)
             if said is not None:
                 chosen.append(said)
-        if stacks:
+        if chosen:
             ys["moe"], ys["experts"] = zip(*chosen)
         return (x, carry), {kind: jax.tree.map(lambda *a: jnp.stack(a), *outs)
                             for kind, outs in ys.items() if outs}
 
-    carry, first = dict(carry), []
-    for j in range(prefix):         # the dense layers before the experts
+    carry, first, began = dict(carry), [], None
+    for j in range(prefix - ahead):     # the dense layers before the experts
         x, carry["full"], rows, _ = layer(
             x, carry["full"], "full",
             _layer_weights(params["prefix"], j, 1), j)
         first.append(rows)
+    if ahead:                       # the period that holds the dense layers
+        (x, carry), began = period((x, carry), (0, None))
     (x, carry), ys = jax.lax.scan(
         period, (x, carry),
-        (jnp.arange((cfg.num_layers - prefix) // len(pattern)),
+        (jnp.arange(bool(ahead), (cfg.num_layers - prefix + ahead)
+                    // len(pattern)),
          blocks if as_xs else None))
     # [periods, layers a period, ...] -> [layers, ...]
     ys = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+    if began:
+        ys = {k: jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                              began[k], v) if k in began else v
+              for k, v in ys.items()}
     if "full" in ys and first:
         ys["full"] = jax.tree.map(
             lambda *a: jnp.concatenate([jnp.stack(a[:-1]), a[-1]]),
             *first, ys["full"])
     if cfg.hc_mult:                  # the streams are summed before the norm
         x = x.sum(axis=2)
+    if cfg.mtp_layers:
+        ys["hidden"] = x
     x = _norm(x, params["final_norm"], cfg).astype(cast)
     if pick is not None:
         x = jnp.take_along_axis(x, pick[:, None, None], axis=1)[:, 0]
@@ -477,15 +562,19 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
 # Prefill
 # ---------------------------------------------------------------------------
 
-def prefill_attention(y, ap, cfg: TransformerConfig, positions):
-    """One layer's causal attention over whole right-padded rows.  y: [B, S,
+def prefill_attention(y, ap, cfg: TransformerConfig, positions,
+                      kind: str = "full"):
+    """One layer's causal attention over whole right-padded rows, a "window"
+    layer's over each query's last ``cfg.sliding_window`` positions (the
+    flash forward with a band).  y: [B, S,
     H] -> (attention after its output projection [B, S, H], this layer's
     k and v [B, S, NKV, D] for the cache)."""
     from ..ops.attention import mha
     b, s, _ = y.shape
-    q, k, v = _qkv(y, ap, cfg, positions)
-    with jax.named_scope("attn"):
-        attn = mha(q, k, v, causal=True, logit_softcap=cfg.attn_logit_softcap)
+    q, k, v = _qkv(y, ap, cfg, positions, kind)
+    with jax.named_scope("window_attn" if kind == "window" else "attn"):
+        attn = mha(q, k, v, causal=True, logit_softcap=cfg.attn_logit_softcap,
+                   window=cfg.sliding_window if kind == "window" else 0)
     return _proj_out(attn.reshape(b, s, -1), ap, y.dtype, y), k, v
 
 
@@ -507,9 +596,9 @@ EXPERT_TILE = 128
 def _rows_alone(cache: KVCache) -> bool:
     """Rows a prefill may start after, K/V or latent: what a position left
     in its slot is all a later one needs of it.  A paged tree starts after
-    a prefix its own way (``page_attention``); a recurrent state takes no
-    start yet."""
-    return not any(n in cache for n in ("block_table", "state"))
+    a prefix its own way (``page_attention``); a recurrent state and a ring
+    take no start yet."""
+    return not any(n in cache for n in ("block_table", "state", "wk"))
 
 
 def prefill_width(cache: KVCache, bucket: int, cfg: TransformerConfig,
@@ -671,6 +760,21 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
                             v.reshape(1, s, -1).astype(cache["v"].dtype))
 
     mixers, kind = {"full": rows}, None
+    if "wk" in cache:
+        from ..ops.decode_attention import ring_positions
+        # of a row, the ring keeps the newest position congruent to each of
+        # its rows: the last min(length, ring) positions (a row nothing has
+        # reached yet takes position 0's and is masked by what it would hold)
+        held = jnp.maximum(ring_positions(last, cache["wk"].shape[2]), 0)
+
+        def band(y, lp, i, carry):
+            out, k, v = prefill_attention(y, lp["attn"], cfg, positions,
+                                          "window")
+            return out, carry, tuple(
+                jnp.take_along_axis(a.reshape(1, s, -1), held[..., None], 1)
+                .astype(cache["wk"].dtype) for a in (k, v))
+
+        mixers["window"] = band
     if "state" in cache:
         from . import hybrid
         kind, whole_rows, _ = hybrid.recurrent(cfg)
@@ -694,11 +798,53 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
     if "full" in ys:
         with jax.named_scope("kv_write"):
             new["k"], new["v"] = put("k", ys["full"][0]), put("v", ys["full"][1])
+    if "window" in ys:
+        with jax.named_scope("ring_write"):
+            new["wk"] = put("wk", ys["window"][0])
+            new["wv"] = put("wv", ys["window"][1])
     if kind in ys:
         with jax.named_scope("state_write"):
             new["state"] = put("state", ys[kind][0])
             new["conv"] = put("conv", ys[kind][1])
+    if "hidden" in ys:
+        # the block's pass over the row: position t pairs the model's
+        # hidden state with token t + 1, the prompt's last with the token
+        # the model's own logits choose (a sampled slot's draft is never
+        # accepted, ``speculative.spec_state_round``)
+        nxt = jnp.where(jnp.arange(s)[None] == last[:, None],
+                        jnp.argmax(logits, -1).astype(tokens.dtype)[:, None],
+                        jnp.roll(tokens, -1, 1))
+        block_logits, _, block_ys = mtp_walk(
+            params, ys["hidden"], nxt, positions, {"full": rows},
+            {"full": None}, cfg, compute_dtype, last, live)
+        with jax.named_scope("kv_write"):
+            new["mtp_k"] = put("mtp_k", block_ys["full"][0])
+            new["mtp_v"] = put("mtp_v", block_ys["full"][1])
+        new["draft"] = cache["draft"].at[slot].set(
+            jnp.argmax(block_logits[0], -1).astype(jnp.int32))
     return choices(ys), logits
+
+
+def mtp_walk(params: Params, hidden, next_tokens, positions, mixers, carry,
+             cfg: TransformerConfig, compute_dtype, pick=None, live=None):
+    """The multi-token-prediction block on ``hidden`` [rows, W, H], the
+    model's last hidden states (before its final norm), each paired with the
+    token after it, ``next_tokens`` [rows, W]: ``W_eh [norm(E x_{t+1});
+    norm(h_t)]`` through one "full" layer of the model's form on K/V rows of
+    the block's own (``mixers``, ``carry``: ``layer_stack``'s), the block's
+    norm and the model's head.  Returns ``layer_stack``'s three: logits for
+    the token after ``next_tokens``."""
+    mp = params["mtp"]
+    with jax.named_scope("mtp_proj"):
+        emb = params["embed"]["tokens"][next_tokens].astype(compute_dtype)
+        u = jnp.concatenate(
+            [_norm(emb, mp["embed_norm"], cfg),
+             _norm(hidden.astype(compute_dtype), mp["hidden_norm"], cfg)],
+            -1) @ mp["proj"].astype(compute_dtype)
+    view = {k: v for k, v in params.items() if k != "mtp"}
+    view.update(blocks=mp["blocks"], final_norm=mp["final_norm"])
+    return layer_stack(view, u, positions, mixers, carry, cfg.mtp_cfg,
+                       compute_dtype, pick, live)
 
 
 def prefill(params: Params, cache: KVCache, tokens: jnp.ndarray,
@@ -770,49 +916,77 @@ def decode_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
     """One layer's attention for W new tokens a slot.  y: [slots, W, H];
     k_all, v_all: the stacked cache [layers, slots, max_len, NKV * D], of
     which this is layer ``i``.  Appends the tokens' K/V at ``[i, slot,
-    length + j]`` in place and attends over the layer's rows up to each.
-    One token (the shape says) reads through the ``decode_attn`` kernel, of
+    length + j]`` in place and attends over the layer's rows up to each,
+    through the ``decode_attn`` kernel (the W tokens' queries its ``W * NH``
+    query rows), of
     the ``active`` slots only (an inactive slot keeps a stale length; it is
-    read as length 0 and its output is zeros); several read the layer's slab
-    indexed out of the stack (``masked_attention``).  Returns (attention
+    read as length 0 and its output is zeros).  Returns (attention
     after its output projection [slots, W, H], k_all, v_all)."""
     from ..ops.decode_attention import decode_attn
+    return _step_attention(
+        y, ap, cfg, k_all, v_all, i, lengths, active, "full",
+        lambda q, k_all, v_all, live, w: decode_attn(
+            q, k_all, v_all, i, live, cfg.num_kv_heads,
+            cfg.attn_logit_softcap, tokens=w))
+
+
+def ring_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
+                   active):
+    """``decode_attention`` for a "window" layer: k_all, v_all are the
+    stacked rings [window layers, slots, ring, NKV * D]; token ``j``'s K/V
+    land in row ``(length + j) mod ring`` and its query reads the
+    ``cfg.sliding_window`` positions up to its own, each ring row masked by
+    the position it holds (``ops.decode_attention.window_decode_attn``).
+    The ring has to have the step's margin (``ring_len``: ``sliding_window +
+    W - 1`` rows at least), which who allocates it sees to."""
+    from ..ops.decode_attention import window_decode_attn
+    return _step_attention(
+        y, ap, cfg, k_all, v_all, i, lengths, active, "window",
+        lambda q, k_all, v_all, live, w: window_decode_attn(
+            q, k_all, v_all, i, live, cfg.num_kv_heads, cfg.sliding_window,
+            w))
+
+
+def _step_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
+                    active, kind: str, attend):
+    """What rows ("full") and rings ("window") share of a step of W tokens a
+    slot: the projections, the tokens' rows written at ``[i, slot, length +
+    j]`` (on a ring: modulo its rows), ``attend(q [slots, W * NH, D], k_all,
+    v_all, live [slots], W)`` over them, the output projection."""
     n_slots, w, _ = y.shape
-    cast, max_len = y.dtype, k_all.shape[2]
+    cast, span, ring = y.dtype, k_all.shape[2], kind == "window"
     positions = lengths[:, None] + jnp.arange(w)[None]           # [slots, W]
-    q, k, v = _qkv(y, ap, cfg, positions)   # q:[S,W,NH,D] k/v:[S,W,NKV,D]
+    q, k, v = _qkv(y, ap, cfg, positions, kind)  # q:[S,W,NH,D] k/v:[S,W,NKV,D]
     # one row a token, slot-major: [i, slot, length + j]
     slot, at = jnp.repeat(jnp.arange(n_slots), w), positions.reshape(-1)
-    with jax.named_scope("kv_write"):
+    if ring:
+        at = at % span
+    with jax.named_scope("ring_write" if ring else "kv_write"):
         k_all = k_all.at[i, slot, at].set(
             k.reshape(n_slots * w, -1).astype(k_all.dtype))
         v_all = v_all.at[i, slot, at].set(
             v.reshape(n_slots * w, -1).astype(v_all.dtype))
-    with jax.named_scope("kv_read"):
-        if w == 1:
-            # positions that count: up to and with the new token's
-            live = jnp.where(active, jnp.minimum(lengths + 1, max_len), 0)
-            attn = decode_attn(q[:, 0], k_all, v_all, i, live,
-                               cfg.num_kv_heads, cfg.attn_logit_softcap)
-        else:
-            heads = (n_slots, max_len, cfg.num_kv_heads, cfg.head_dim)
-            attn = masked_attention(
-                q, *(jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-                     .reshape(heads) for a in (k_all, v_all)), positions, cfg)
+    with jax.named_scope("ring_read" if ring else "kv_read"):
+        # positions that count: up to and with the new tokens'
+        live = lengths + w if ring else jnp.minimum(lengths + w, span)
+        attn = attend(q.reshape(n_slots, w * cfg.num_heads, cfg.head_dim),
+                      k_all, v_all, jnp.where(active, live, 0), w)
     attn = attn.reshape(n_slots, w, cfg.num_heads * cfg.head_dim)
     return _proj_out(attn.astype(cast), ap, cast, y), k_all, v_all
 
 
 def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
                 active: jnp.ndarray, cfg: TransformerConfig,
-                compute_dtype=jnp.bfloat16) -> Tuple[KVCache, jnp.ndarray]:
+                compute_dtype=jnp.bfloat16, hidden: bool = False):
     """W new tokens for every slot in one forward, on any cache tree.
 
     tokens: [slots, W] int32 — token j sits at position ``length + j``
     active: [slots] bool — inactive slots compute garbage that is masked
     out; their lengths (and recurrent states) stay as they were
     Returns (cache, logits [slots, W, V] f32): K/V of all W positions are
-    appended and ``length`` advances by W for active slots.
+    appended and ``length`` advances by W for active slots.  ``hidden``: a
+    third result, the last layer's output [slots, W, H] (a model with a
+    multi-token-prediction block).
     """
     w = tokens.shape[1]
     lengths = cache["length"]
@@ -831,6 +1005,9 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
         attend = _kv_mixer(decode_attention, cfg, lengths, active)
     rows = LATENT if "latent" in cache else ("k", "v")
     mixers, carry = {"full": attend}, {"full": tuple(cache[n] for n in rows)}
+    if "wk" in cache:
+        mixers["window"] = _kv_mixer(ring_attention, cfg, lengths, active)
+        carry["window"] = tuple(cache[n] for n in RING)
     kind = None
     if "state" in cache:
         from . import hybrid
@@ -852,6 +1029,8 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
     new = dict(cache, length=jnp.where(
         active, jnp.minimum(lengths + w, span), lengths))
     new.update(zip(rows, carry["full"]))
+    if "wk" in cache:
+        new.update(zip(RING, carry["window"]))
     if "moe_counts" in cache:   # what the experts did, over layers and steps
         new["moe_counts"] = cache["moe_counts"] + ys["moe"].sum(axis=0)
     if CHOICES in cache:    # each token's at its position; an idle slot's
@@ -862,6 +1041,27 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
                 ys["experts"], mode="drop")
     if kind:
         new["state"], new["conv"] = carry[kind]
+    return (new, logits, ys["hidden"]) if hidden else (new, logits)
+
+
+def mtp_step(params: Params, cache: KVCache, hidden, next_tokens, lengths,
+             active, cfg: TransformerConfig, compute_dtype=jnp.bfloat16):
+    """The multi-token-prediction block for W positions a slot, ``lengths``
+    [slots] on: ``hidden`` [slots, W, H] of the model's ``window_step`` over
+    them, each paired with the token after it, ``next_tokens`` [slots, W].
+    Writes the block's K/V rows of those positions; ``length`` is the
+    caller's to set.  Returns (cache, logits [slots, W, V] f32: for the
+    token after each ``next_tokens``)."""
+    w = hidden.shape[1]
+    logits, carry, ys = mtp_walk(
+        params, hidden, next_tokens, lengths[:, None] + jnp.arange(w)[None],
+        {"full": _kv_mixer(decode_attention, cfg.mtp_cfg, lengths, active)},
+        {"full": tuple(cache[n] for n in MTP_ROWS)}, cfg, compute_dtype,
+        live=jnp.broadcast_to(active[:, None], next_tokens.shape)
+        if cfg.moe_dropless else None)
+    new = dict(cache, **dict(zip(MTP_ROWS, carry["full"])))
+    if "moe_counts" in cache:
+        new["moe_counts"] = cache["moe_counts"] + ys["moe"].sum(axis=0)
     return new, logits
 
 

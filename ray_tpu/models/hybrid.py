@@ -95,7 +95,8 @@ def _channels(cfg: TransformerConfig) -> Tuple[int, int]:
 
 def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
     """``params["blocks"]`` of a model with a layer pattern: an entry a kind
-    of its pattern (``"linear"``, ``"ssm"``, ``"full"``, ``"mlp"``), leaves
+    of its pattern (``"linear"``, ``"ssm"``, ``"full"``, ``"window"``,
+    ``"mlp"``), leaves
     [periods, layers of the kind a period, ...], and with dropless experts
     ``"experts"``, the routed experts of every expert layer in layer order
     [expert layers, experts held, ...] (a layer then has the router and the
@@ -131,13 +132,17 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
 
     gated = not cfg.mlp_act
 
-    def mlp(lead, under_mixer=True):
+    def mlp(lead, under_mixer=True, sparse=cfg.moe_dropless):
         """The feed-forward of the layers ``lead`` and its norm; nothing
-        under a mixer whose layer is that sublayer alone."""
+        under a mixer whose layer is that sublayer alone, and the norm alone
+        where a dense prefix makes the layers of a kind differ: their MLPs
+        then lie by layer (``"dense"``, ``"moe"`` below)."""
         if cfg.sublayers_alone and under_mixer:
             return {}
         norm = {"mlp_norm": ones(lead, h)}
-        if not cfg.moe_dropless:
+        if cfg.dense_prefix_layers and under_mixer:
+            return norm
+        if not sparse:
             if gated:
                 ws = {"w_gate": dense(lead, (h, m), h),
                       "w_in": dense(lead, (h, m), h),
@@ -208,23 +213,38 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
                 "o_norm": ones(lead, inner),
                 "w_out": dense(lead, (inner, h), inner, keys=more)},
             "mixer_norm": ones(lead, h), **mlp(lead)}
-    if "full" in pattern:
-        lead = (periods, pattern.count("full"))
+    for kind in ("full", "window"):     # one attention, two spans
+        if kind not in pattern:
+            continue
+        lead = (periods, pattern.count(kind))
+        # (the window kind's draws are its own: a model of full layers
+        # alone keeps the weights it had)
+        draw = keys if kind == "full" else iter(
+            jax.random.split(jax.random.fold_in(key, 2), 4))
         attn = {
-            "wq": dense(lead, (h, nh * hd), h),
-            "wk": dense(lead, (h, nkv * hd), h),
-            "wv": dense(lead, (h, nkv * hd), h),
-            "wo": dense(lead, (nh * hd, h), nh * hd),
+            "wq": dense(lead, (h, nh * hd), h, keys=draw),
+            "wk": dense(lead, (h, nkv * hd), h, keys=draw),
+            "wv": dense(lead, (h, nkv * hd), h, keys=draw),
+            "wo": dense(lead, (nh * hd, h), nh * hd, keys=draw),
         }
         if cfg.attn_output_gate:
             attn["w_gate"] = dense(lead, (h, nh * hd), h, keys=more)
         if cfg.qk_norm:
             attn["q_norm"] = ones(lead, nh * hd)
             attn["k_norm"] = ones(lead, nkv * hd)
-        blocks["full"] = {"attn": attn, "attn_norm": ones(lead, h),
-                          **mlp(lead)}
+        if cfg.qk_head_norm:        # one scale vector, shared by the heads
+            attn["q_norm"] = ones(lead, hd)
+            attn["k_norm"] = ones(lead, hd)
+        blocks[kind] = {"attn": attn, "attn_norm": ones(lead, h),
+                        **mlp(lead)}
     if "mlp" in pattern:
         blocks["mlp"] = mlp((periods, pattern.count("mlp")), False)
+    if cfg.dense_prefix_layers:
+        # the MLPs by layer, where a kind's layers do not all have the same:
+        # the dense ones of the first layers, then each expert layer's
+        # router and shared expert in layer order, as its routed experts lie
+        blocks["dense"] = mlp((cfg.dense_prefix_layers,), False, False)["mlp"]
+        blocks["moe"] = mlp((cfg.expert_layers,), False)["moe"]
     if cfg.moe_dropless:
         from .latent import expert_stack
         em = cfg.expert_mlp_size

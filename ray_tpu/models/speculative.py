@@ -1,4 +1,9 @@
-"""Speculative decoding: draft-model lookahead + one-shot target verify.
+"""Speculative decoding: a drafter's lookahead + one-shot target verify.  The
+drafter is a cut-out model (``model_drafter``: the target's first layers,
+with a cache of its own) or the target's own multi-token-prediction block
+(``block_drafter``: it reads the target's last hidden state, shares its
+embedding and head, keeps one layer's K/V rows in the target's cache tree
+and drafts one token); one round function serves both.
 
 Beyond-reference TPU-native addition (the reference serves LLMs by
 pairing with an external engine; our serve stack owns its engine —
@@ -21,29 +26,33 @@ Everything is fixed-shape and jittable: the multi-round driver
 (``spec_decode_state_loop``) is a ``lax.scan`` whose carry holds both
 caches, the engine's device-resident decode state and per-slot emit buffers
 — no host round-trip between rounds (cf. ``decode.decode_state_loop``).
-The target's cache may be rows or pages: ``verify_window`` is the same
+The target's cache may be rows, pages, or rows beside rings (a pattern of
+"full" and "window" layers): ``verify_window`` is the same
 walk over the layers as every other serving forward (``decode.layer_stack``)
-and the cache tree says which attention it gets.
+and the cache tree says which attention it gets; on rows and rings the W
+tokens of a window ride the decode kernels (``ops/decode_attention.py``).  A
+recurrent state and latent rows take no window of several tokens.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
 from .config import TransformerConfig
-from .decode import (KVCache, Params, decode_step, sample_per_slot,
+from .decode import (KVCache, Params, decode_step, mtp_step, sample_per_slot,
                      window_step)
 
 __all__ = ["verify_window", "spec_state_round", "spec_decode_state_loop",
-           "make_draft_params", "damp_block_outputs"]
+           "make_draft_params", "damp_block_outputs", "Drafter",
+           "model_drafter", "block_drafter"]
 
 
 def verify_window(params: Params, cache: KVCache, tokens: jnp.ndarray,
                   active: jnp.ndarray, cfg: TransformerConfig,
-                  compute_dtype=jnp.bfloat16
+                  compute_dtype=jnp.bfloat16, hidden: bool = False
                   ) -> Tuple[KVCache, jnp.ndarray]:
     """Process a k-token window per slot in one forward, over rows or pages:
     ``decode_step`` at window k (``decode.window_step``; with k=1 it
@@ -58,9 +67,92 @@ def verify_window(params: Params, cache: KVCache, tokens: jnp.ndarray,
     (same contract as prefill's padded tail) and the next round overwrites
     it.  On pages that is exact by construction too: every window position
     lands in a page the slot's block table already owns (private pages at
-    index >= the shared-prefix boundary).
+    index >= the shared-prefix boundary).  On a ring it is exact where the
+    ring has the window's margin (``decode.ring_len``): no row a kept
+    position still reads was replaced.
     """
-    return window_step(params, cache, tokens, active, cfg, compute_dtype)
+    return window_step(params, cache, tokens, active, cfg, compute_dtype,
+                       hidden)
+
+
+# ---------------------------------------------------------------------------
+# Drafters
+# ---------------------------------------------------------------------------
+
+class Drafter(NamedTuple):
+    """What a round asks of whatever drafts for it.
+
+    ``propose(target_params, target_cache, draft_params, draft_cache, last,
+    active, k) -> (draft_cache, drafts [slots, k - 1])`` before the verify
+    step; ``settle(target_params, target_cache, draft_params, draft_cache,
+    seen) -> (target_cache, draft_cache)`` after it, with ``seen`` what the
+    round learned: ``len0`` and ``new_len`` [slots], ``active``, ``emitted``
+    [slots, k], ``emit_count`` [slots] and, where ``hidden`` is set, the
+    target's last hidden states of the window, ``hidden`` [slots, k, H]."""
+    propose: Callable
+    settle: Callable
+    hidden: bool = False
+
+
+def model_drafter(draft_cfg: TransformerConfig,
+                  compute_dtype=jnp.bfloat16) -> Drafter:
+    """A cut-out model (``make_draft_params``) on a dense cache of its own:
+    k - 1 greedy steps, and its rows rolled back with the target's."""
+    def propose(_tp, _tc, draft_params, draft_cache, last, active, k):
+        def draft_body(carry, _):
+            dc, tok = carry
+            dc, logits = decode_step(draft_params, dc, tok, active,
+                                     draft_cfg, compute_dtype)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (dc, nxt), nxt
+
+        (draft_cache, last_d), drafts = jax.lax.scan(
+            draft_body, (draft_cache, last), None, length=k - 1)
+        drafts = (drafts.T if k > 1
+                  else jnp.zeros((last.shape[0], 0), jnp.int32))
+        # one extra KV-only draft step: when every draft is accepted the
+        # next round needs d_{k-1}'s row in the draft cache too (its logits
+        # are discarded — this is the fixed price of fixed shapes)
+        draft_cache, _ = decode_step(draft_params, draft_cache, last_d,
+                                     active, draft_cfg, compute_dtype)
+        return draft_cache, drafts
+
+    def settle(_tp, target_cache, _dp, draft_cache, seen):
+        return target_cache, dict(draft_cache, length=jnp.where(
+            seen["active"], seen["new_len"], draft_cache["length"]))
+
+    return Drafter(propose, settle)
+
+
+def block_drafter(cfg: TransformerConfig,
+                  compute_dtype=jnp.bfloat16) -> Drafter:
+    """The target's own multi-token-prediction block (``cfg.mtp_layers``),
+    one drafted token a round (k = 2).  Its state lies in the target's cache
+    tree (``mtp_k`` / ``mtp_v`` rows, one ``length`` with the model's, and
+    ``draft``, the token it proposed for the position after each slot's
+    last); ``draft_params`` and ``draft_cache`` are empty.  After the verify
+    step the block runs over the window's two positions, each hidden state
+    paired with the token the round emitted after it: that fills the block's
+    rows for every kept position, and its logits at the last kept position
+    are the next round's draft.  A rejected draft's row lies past ``length``
+    like the target's."""
+    def propose(_tp, target_cache, _dp, draft_cache, _last, _active, k):
+        if k != 2:
+            raise ValueError(f"the block drafts one token a round: k={k}")
+        return draft_cache, target_cache["draft"][:, None]
+
+    def settle(target_params, target_cache, _dp, draft_cache, seen):
+        active, count = seen["active"], seen["emit_count"]
+        target_cache, logits = mtp_step(
+            target_params, target_cache, seen["hidden"], seen["emitted"],
+            seen["len0"], active, cfg, compute_dtype)
+        kept = jnp.maximum(count - 1, 0)[:, None, None]
+        nxt = jnp.argmax(jnp.take_along_axis(logits, kept, 1)[:, 0], -1)
+        return dict(target_cache, draft=jnp.where(
+            active & (count > 0), nxt.astype(jnp.int32),
+            target_cache["draft"])), draft_cache
+
+    return Drafter(propose, settle, hidden=True)
 
 
 # ---------------------------------------------------------------------------
@@ -70,11 +162,13 @@ def verify_window(params: Params, cache: KVCache, tokens: jnp.ndarray,
 def spec_state_round(target_params: Params, target_cache, draft_params:
                      Params, draft_cache: KVCache, state: Dict[str, Any],
                      k: int, target_cfg: TransformerConfig,
-                     draft_cfg: TransformerConfig, top_k: int = 0,
-                     compute_dtype=jnp.bfloat16):
+                     drafter: Union[Drafter, TransformerConfig],
+                     top_k: int = 0, compute_dtype=jnp.bfloat16):
     """One draft→verify→accept round for every slot, against the engine's
     device-resident decode state (``decode.init_decode_state`` layout), run
-    inside LLMEngine's scheduler thread.
+    inside LLMEngine's scheduler thread.  ``drafter``: who drafts (a
+    ``Drafter``; a cut-out model's configuration stands for
+    ``model_drafter`` of it).
 
     Greedy acceptance: with drafts d_1..d_{k-1} and target logits
     l_0..l_{k-1} over window [last, d_1..d_{k-1}], accept d_{j+1} while
@@ -97,40 +191,30 @@ def spec_state_round(target_params: Params, target_cache, draft_params:
       to ``len0 + emit_count`` (the cache then covers ``last,
       e_1..e_{cnt-1}`` and ``e_cnt`` is fed back next round).
 
-    The draft cache is always DENSE (the paged HBM win matters for the
-    big target; the draft is layers-sliced and small).  Returns
-    (target_cache, draft_cache, state, emitted [slots, k],
+    A cut-out model's draft cache is always DENSE (the paged HBM win
+    matters for the big target; the draft is layers-sliced and small).
+    Returns (target_cache, draft_cache, state, emitted [slots, k],
     emit_count [slots]).
     """
+    if isinstance(drafter, TransformerConfig):
+        drafter = model_drafter(drafter, compute_dtype)
     n_slots = state["tokens"].shape[0]
     last = state["tokens"]
     active = state["active"]
     temps = state["temps"]
     key = state["key"]
 
-    # -- draft rollout: k-1 small-model greedy steps -----------------------
-    def draft_body(carry, _):
-        dc, tok = carry
-        dc, logits = decode_step(draft_params, dc, tok, active, draft_cfg,
-                                 compute_dtype)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (dc, nxt), nxt
-
-    (draft_cache, last_d), drafts = jax.lax.scan(
-        draft_body, (draft_cache, last), None, length=k - 1)
-    drafts = drafts.T if k > 1 else jnp.zeros((n_slots, 0), jnp.int32)
-    # one extra KV-only draft step: when every draft is accepted the next
-    # round needs d_{k-1}'s row in the draft cache too (its logits are
-    # discarded — this is the fixed price of fixed shapes)
-    draft_cache, _ = decode_step(draft_params, draft_cache, last_d, active,
-                                 draft_cfg, compute_dtype)
+    # -- draft rollout: k-1 proposed tokens --------------------------------
+    draft_cache, drafts = drafter.propose(
+        target_params, target_cache, draft_params, draft_cache, last, active,
+        k)
 
     # -- target verify: ONE k-token window ---------------------------------
     window = jnp.concatenate([last[:, None], drafts], axis=1)
     t_len0 = target_cache["length"]
-    target_cache, logits = verify_window(target_params, target_cache,
-                                         window, active, target_cfg,
-                                         compute_dtype)
+    target_cache, logits, *hidden = verify_window(
+        target_params, target_cache, window, active, target_cfg,
+        compute_dtype, drafter.hidden)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [slots, k]
 
     # -- acceptance --------------------------------------------------------
@@ -163,11 +247,12 @@ def spec_state_round(target_params: Params, target_cache, draft_params:
     # cache now ends with ...last, e_1..e_{cnt-1}; the last emitted token
     # (correction or budget-cut draft) is fed next round
     new_len = t_len0 + emit_count
+    target_cache, draft_cache = drafter.settle(
+        target_params, target_cache, draft_params, draft_cache,
+        dict(len0=t_len0, new_len=new_len, active=active, emitted=emitted,
+             emit_count=emit_count, hidden=hidden[0] if hidden else None))
     target_cache = dict(target_cache,
                         length=jnp.where(active, new_len, t_len0))
-    draft_cache = dict(draft_cache,
-                       length=jnp.where(active, new_len,
-                                        draft_cache["length"]))
 
     new_last = jnp.take_along_axis(
         emitted, jnp.maximum(emit_count - 1, 0)[:, None], 1)[:, 0]
@@ -185,7 +270,8 @@ def spec_decode_state_loop(target_params: Params, target_cache,
                            draft_params: Params, draft_cache: KVCache,
                            state: Dict[str, Any], k: int, num_rounds: int,
                            target_cfg: TransformerConfig,
-                           draft_cfg: TransformerConfig, top_k: int = 0,
+                           drafter: Union[Drafter, TransformerConfig],
+                           top_k: int = 0,
                            compute_dtype=jnp.bfloat16) -> Dict[str, Any]:
     """``num_rounds`` decode-state spec rounds under one ``lax.scan`` —
     the engine's speculative twin of ``decode_state_loop`` (one dispatch,
@@ -195,8 +281,13 @@ def spec_decode_state_loop(target_params: Params, target_cache,
     beyond counts are garbage), counts: [slots], emit_counts:
     [num_rounds, slots] (per-round acceptance accounting — the host
     derives drafted/accepted/rollback tallies from these alone),
-    target_cache, draft_cache, state}.
+    target_cache, draft_cache, state} and, for a target with dropless
+    experts, moe_counts: [2], what they did over these rounds (assignments,
+    experts touched: ``decode.decode_state_loop``'s).
     """
+    if "moe_counts" in target_cache:
+        target_cache = dict(target_cache, moe_counts=jnp.zeros_like(
+            target_cache["moe_counts"]))
     n_slots = state["tokens"].shape[0]
     out = jnp.zeros((n_slots, num_rounds * k), jnp.int32)
     counts = jnp.zeros((n_slots,), jnp.int32)
@@ -206,7 +297,7 @@ def spec_decode_state_loop(target_params: Params, target_cache,
         tc, dc, st, out, counts = carry
         tc, dc, st, emitted, n_emit = spec_state_round(
             target_params, tc, draft_params, dc, st, k, target_cfg,
-            draft_cfg, top_k, compute_dtype)
+            drafter, top_k, compute_dtype)
         idx = jnp.minimum(counts[:, None] + jnp.arange(k)[None],
                           out.shape[1] - 1)
         keep = jnp.arange(k)[None] < n_emit[:, None]
@@ -219,7 +310,9 @@ def spec_decode_state_loop(target_params: Params, target_cache,
         length=num_rounds)
     return {"tokens": out, "counts": counts, "emit_counts": emits,
             "target_cache": target_cache, "draft_cache": draft_cache,
-            "state": state}
+            "state": state,
+            **({"moe_counts": target_cache["moe_counts"]}
+               if "moe_counts" in target_cache else {})}
 
 
 # ---------------------------------------------------------------------------
@@ -241,11 +334,13 @@ def make_draft_params(params: Params, num_layers: int) -> Params:
 
 
 def damp_block_outputs(params: Params, scale: float = 0.05,
-                       from_layer: int = 0) -> Params:
+                       from_layer: int = 0, output_norms: bool = False
+                       ) -> Params:
     """Benchmark/test param surgery for SYNTHETIC (randomly initialized)
     weights: scale the output projections (attention ``wo``, MLP
     ``w_out`` + their biases) of every block with index >= ``from_layer``
-    by ``scale``.  With ``from_layer = draft_layers`` the target's deep
+    by ``scale`` (a pattern's blocks are stacked by kind: ``from_layer``
+    then counts periods).  With ``from_layer = draft_layers`` the target's deep
     tail contributes only a small residual perturbation on top of the
     layers a sliced draft shares, so the pair agrees at the acceptance
     rates a TRAINED draft/target pair exhibits — while the target still
@@ -261,7 +356,13 @@ def damp_block_outputs(params: Params, scale: float = 0.05,
     def _scale(keypath, leaf):
         path = "/".join(str(getattr(p, "key", p)) for p in keypath)
         tail = path.rsplit("/", 1)[-1]
-        if tail in ("wo", "bo", "w_out", "b_out"):
+        # (a block that norms its sublayers' OUTPUTS, ``norm_on_output``,
+        # undoes a scaled projection: ``output_norms`` scales those norms'
+        # scales instead)
+        if path.endswith(("attn_norm/scale", "mlp_norm/scale",
+                          "mixer_norm/scale")
+                         if output_norms else
+                         ("wo", "bo", "w_out", "b_out")):
             # stacked block params carry the leading layer dim
             mult = _jnp.where(_jnp.arange(leaf.shape[0]) >= from_layer,
                               _jnp.asarray(scale, leaf.dtype),

@@ -118,6 +118,16 @@ def init_params(key: jax.Array, cfg: TransformerConfig,
         if not cfg.tied_embeddings:
             hybrid_params["lm_head"] = dense(next(keys),
                                              (h, cfg.vocab_size), h)
+        if cfg.mtp_layers:
+            # the multi-token-prediction block (``cfg.mtp_cfg``): the
+            # embedding and the head are the model's
+            ones = lambda: {"scale": jnp.ones((h,), dtype)}    # noqa: E731
+            mtp_keys = jax.random.split(jax.random.fold_in(key, 3), 2)
+            hybrid_params["mtp"] = {
+                "proj": dense(mtp_keys[0], (2 * h, h), 2 * h),
+                "embed_norm": ones(), "hidden_norm": ones(),
+                "blocks": hybrid.init_blocks(mtp_keys[1], cfg.mtp_cfg, dtype),
+                "final_norm": ones()}
         return hybrid_params
 
     def norm_p():

@@ -32,11 +32,12 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
            causal: bool = True,
            q_offset: int | jnp.ndarray = 0,
            kv_offset: int | jnp.ndarray = 0,
-           logit_softcap: float = 0.0) -> jnp.ndarray:
+           logit_softcap: float = 0.0, window: int = 0) -> jnp.ndarray:
     """Plain attention. q: [B, Sq, H, D], k/v: [B, Skv, KV, D] -> [B, Sq, H, D].
 
     ``q_offset``/``kv_offset`` are the global positions of the first query/key —
     used by ring attention where each device holds a sequence shard.
+    ``window`` > 0 (causal): a query reads its last ``window`` positions.
     """
     num_heads = q.shape[2]
     k = repeat_kv(k, num_heads)
@@ -49,6 +50,8 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = kv_offset + jnp.arange(k.shape[1])
         mask = q_pos[:, None] >= k_pos[None, :]
+        if window:
+            mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -93,9 +96,11 @@ def finalize_blockwise(m, l, o):
 
 def mha(q, k, v, causal: bool = True, logit_softcap: float = 0.0,
         use_flash: Optional[bool] = None, mesh=None,
-        batch_axes: Tuple[str, ...] = ("dp", "fsdp")):
+        batch_axes: Tuple[str, ...] = ("dp", "fsdp"), window: int = 0):
     """Dispatch between the Pallas flash kernel (TPU, long seq) and plain XLA.
     q, k: [B, S, heads, Dqk]; v: [B, S, KV, Dv], as wide as q or not.
+    ``window`` > 0: a query reads its last ``window`` positions (the
+    kernel's banded forward, or the plain path's mask).
 
     ``use_flash=None`` chooses from what it can observe: the backend and the
     shape.  ``mesh``/``batch_axes`` go to the kernel, which must be
@@ -117,5 +122,6 @@ def mha(q, k, v, causal: bool = True, logit_softcap: float = 0.0,
                              " let the dispatcher choose)")
         from .flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, mesh=mesh,
-                               batch_axes=batch_axes)
-    return attend(q, k, v, causal=causal, logit_softcap=logit_softcap)
+                               batch_axes=batch_axes, window=window)
+    return attend(q, k, v, causal=causal, logit_softcap=logit_softcap,
+                  window=window)

@@ -66,13 +66,23 @@ BWD_VMEM_REACH = 64 << 20
 #: the forward kernel where its queries start after what a slot of the
 #: stacked cache already holds (``flash_attention_rows``), as a trace shows it
 KERNEL_FLASH_ROWS = "flash_fwd_rows"
+#: the forward kernel with a band (``flash_attention(window=...)``): a
+#: query reads its last ``window`` positions and a query block the KV
+#: blocks its band touches
+KERNEL_FLASH_WINDOW = "flash_window_prefill"
+#: KV positions a step of the banded kernel takes: a band of 128 under a
+#: query block of 512 touches 5 such blocks (640 positions), 2 of 512 (1,024)
+WINDOW_BLOCK_KV = 128
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
-                seq_kv: int, causal: bool, scale: float, q_start=None):
+                seq_kv: int, causal: bool, scale: float, q_start=None,
+                window: int = 0):
     """One (batch, head, q-block) program: stream KV blocks, online softmax.
     ``q_start``: the position of the first query among the keys, a scalar
-    that is data (``_fwd_rows_kernel``); None where query i sits at key i."""
+    that is data (``_fwd_rows_kernel``); None where query i sits at key i.
+    ``window`` > 0 (causal): a query reads its last ``window`` positions,
+    and the loop starts at the first KV block the q-block's band touches."""
     qi = pl.program_id(2)
     block_q = q_ref.shape[2]
     q = q_ref[0, 0]                                   # [block_q, d_qk]
@@ -106,7 +116,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
         if causal:
             k_pos = j * block_kv + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            seen = q_pos >= k_pos
+            if window:
+                seen = seen & (q_pos - k_pos < window)
+            s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -116,7 +129,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, num_kv, body, (m0, l0, acc0))
+    # KV blocks wholly before the band of this q-block's first query
+    lo = jnp.maximum(first() - (window - 1), 0) // block_kv if window else 0
+    m, l, acc = jax.lax.fori_loop(lo, num_kv, body, (m0, l0, acc0))
     l = jnp.maximum(l, 1e-30)
     o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
     # TPU tiling wants the last two block dims (8, 128)-aligned; a [block_q]
@@ -140,9 +155,10 @@ def _fwd_vmem(kv_len: int, d_qk: int, d_v: int, dtype) -> dict:
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
-               interpret: bool):
+               interpret: bool, window: int = 0):
     """q: [B, H, S, Dqk], k: [B, KV, S, Dqk], v: [B, KV, S, Dv] -> (out [B,
-    H, S, Dv], lse [B, H, S])."""
+    H, S, Dv], lse [B, H, S]).  ``window``: the band (``_fwd_kernel``), a
+    kernel of its own name."""
     b, h, s, d = q.shape
     d_v = v.shape[3]
     kv_heads = k.shape[1]
@@ -153,7 +169,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
 
     grid = (b, h, s // block_q)
     kernel = functools.partial(_fwd_kernel, block_kv=block_kv, seq_kv=s,
-                               causal=causal, scale=scale)
+                               causal=causal, scale=scale, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -174,7 +190,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
             jax.ShapeDtypeStruct((b, h, 8, s), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name=KERNEL_FLASH_WINDOW if window else "flash_fwd",
     )(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -571,8 +587,8 @@ def kernel_batch_spec(mesh, batch_axes) -> Optional[P]:
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
                     block_kv: int = 512,
                     interpret: Optional[bool] = None, mesh=None,
-                    batch_axes: Tuple[str, ...] = ("dp", "fsdp")
-                    ) -> jnp.ndarray:
+                    batch_axes: Tuple[str, ...] = ("dp", "fsdp"),
+                    window: int = 0) -> jnp.ndarray:
     """Flash attention. q: [B, Sq, H, Dqk], k: [B, Skv, KV, Dqk], v: [B, Skv,
     KV, Dv] -> [B, Sq, H, Dv].
 
@@ -587,8 +603,17 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     With a ``mesh`` of more than one device the call is wrapped in a
     shard_map over ``batch_axes``, each device on its local batch shard.
     Pass no mesh from inside a manual region (a shard_map body).
+
+    ``window`` > 0 (causal): a query reads its last ``window`` positions,
+    its own among them; KV blocks (of ``WINDOW_BLOCK_KV`` at most) that a
+    query block's band does not touch are not computed.  The forward alone
+    (the serving path's): it takes no gradient.
     """
     b, sq, h, d = q.shape
+    if window:
+        if not causal:
+            raise ValueError("a window is causal")
+        block_kv = min(block_kv, WINDOW_BLOCK_KV)
     reason = flash_supported(sq, k.shape[1], h, k.shape[2], block_q, block_kv)
     if reason is None and (k.shape[-1] != d or v.shape[:-1] != k.shape[:-1]):
         reason = (f"keys {k.shape} are not as wide as queries {q.shape} or "
@@ -600,8 +625,12 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     block_kv = min(block_kv, k.shape[1])
 
     def local(q, k, v):
-        out = _flash(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-                     causal, block_q, block_kv, interpret)
+        heads_first = (q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2))
+        if window:
+            out, _ = _flash_fwd(*heads_first, True, block_q, block_kv,
+                                interpret, window)
+        else:
+            out = _flash(*heads_first, causal, block_q, block_kv, interpret)
         return out.swapaxes(1, 2)
 
     spec = kernel_batch_spec(mesh, batch_axes)

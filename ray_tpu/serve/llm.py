@@ -163,16 +163,26 @@ class LLMEngine:
 
         self.cfg = cfg
         if cfg.layer_pattern:
-            # a cache of two kinds of state (models/hybrid.py) is dense,
-            # unsharded and decoded one token a step, whatever MLP lies
-            # under its layers
+            # a cache of several kinds of state (models/hybrid.py, the
+            # rings of models/decode.py) is dense and unsharded, whatever
+            # MLP lies under its layers; a recurrent state is decoded one
+            # token a step, rows and rings take a verify step's window, and
+            # who drafts for it is the model's own block
+            recurrent = cfg.linear_layers or cfg.ssm_layers
             for on, what in ((paged, "paged=True: the page arena holds keys "
-                              "and values only"),
-                             (spec_decode_enabled, "spec_decode_enabled: a "
+                              "and values only, in no ring"),
+                             (spec_decode_enabled and recurrent,
+                              "spec_decode_enabled: a "
                               "rejected draft cannot be rolled out of a "
-                              "recurrent state"),
+                              "recurrent state ('linear', 'ssm')"),
+                             (spec_decode_enabled and not cfg.mtp_layers,
+                              "spec_decode_enabled: no draft model is cut "
+                              "out of a pattern's stacks by kind; a pattern "
+                              "drafts with its own multi-token-prediction "
+                              "block (mtp_layers)"),
                              (tp > 1, f"tp={tp}: no sharding rule covers the "
-                              "recurrent state or its kernels")):
+                              "recurrent state, the rings or their "
+                              "kernels")):
                 if on:
                     raise ValueError(
                         f"layer_pattern {cfg.layer_pattern} does not run "
@@ -243,8 +253,12 @@ class LLMEngine:
             self.prefix = (pdec.PrefixCache(self.allocator, page_size)
                            if prefix_cache else None)
         else:
-            self.cache = dec.init_kv_cache(cfg, num_slots + 1, self.max_len,
-                                           self.compute_dtype)
+            # a ring has the margin of the verify step's window where the
+            # model's own block drafts (one token: a window of two)
+            self.cache = dec.init_kv_cache(
+                cfg, num_slots + 1, self.max_len, self.compute_dtype,
+                ring=dec.ring_len(cfg, 2 if spec_decode_enabled else 1)
+                if cfg.window_layers else None)
         # bytes by kind of per-slot state and layers by kind: shapes, so
         # read once (the cache's arrays are donated at every dispatch)
         self._cache_gauges = dec.cache_gauges(cfg, self.cache)
@@ -305,6 +319,11 @@ class LLMEngine:
         # occupancy — speculation pays when slots are idle, so k shrinks
         # as the batch fills (min k=2 rather than a plain-decode fallback,
         # which would let the draft cache diverge from the target's).
+        # A model with a multi-token-prediction block (cfg.mtp_layers)
+        # drafts with it instead: one token a round, k fixed at 2 (the
+        # step's shape does not follow occupancy), its rows in the target's
+        # own cache tree and filled by the target's admit program, so there
+        # is no draft model, cache or prefill.
         self.spec_enabled = bool(spec_decode_enabled)
         if self.spec_enabled:
             if tp > 1:
@@ -314,15 +333,22 @@ class LLMEngine:
 
             from ray_tpu.models import speculative as spec_mod
             self._spec = spec_mod
+            self._spec_self = bool(cfg.mtp_layers)
             d = max(1, min(int(spec_draft_layers), cfg.num_layers - 1))
-            self.spec_k = max(2, int(spec_k))
-            self.spec_adaptive = bool(spec_adaptive)
-            self.spec_draft_layers = d
-            self._spec_draft_cfg = _dc.replace(cfg, num_layers=d)
-            self._draft_params = spec_mod.make_draft_params(self.params, d)
-            self._draft_cache = dec.init_kv_cache(
-                self._spec_draft_cfg, num_slots + 1, self.max_len,
-                self.compute_dtype)
+            self.spec_k = 2 if self._spec_self else max(2, int(spec_k))
+            self.spec_adaptive = bool(spec_adaptive) and not self._spec_self
+            self.spec_draft_layers = 0 if self._spec_self else d
+            if self._spec_self:
+                self._drafter = spec_mod.block_drafter(cfg,
+                                                       self.compute_dtype)
+                self._draft_params, self._draft_cache = {}, {}
+            else:
+                self._drafter = _dc.replace(cfg, num_layers=d)
+                self._draft_params = spec_mod.make_draft_params(
+                    self.params, d)
+                self._draft_cache = dec.init_kv_cache(
+                    self._drafter, num_slots + 1, self.max_len,
+                    self.compute_dtype)
             self._spec_fns: Dict[int, Any] = {}
             self._draft_prefill_fns: Dict[int, Any] = {}
             self._spec_ks = sorted({self.spec_k,
@@ -558,6 +584,14 @@ class LLMEngine:
                 moe_experts_touched=self.moe_experts_touched,
                 moe_expert_layer_steps=self.moe_expert_layer_steps,
                 moe_assignments_prefill=self.moe_assignments_prefill)
+        if self.spec_enabled:
+            # rounds a live slot ran, tokens drafted for them and accepted,
+            # and the rows a rejected draft left to be rolled back (one a
+            # rejected token, in every kind of cache the model keeps)
+            out.update(
+                spec_rounds=self.spec_rounds, spec_drafted=self.spec_drafted,
+                spec_accepted=self.spec_accepted,
+                spec_rolled_back_rows=self.spec_drafted - self.spec_accepted)
         for ph in ENGINE_PHASES:
             out[f"loop_{ph}_s"] = loop_s[ph]
             out[f"loop_{ph}_n"] = self.loop_n[ph]
@@ -770,7 +804,7 @@ class LLMEngine:
         position 0 — one small compiled program per length bucket."""
         fn = self._draft_prefill_fns.get(bucket)
         if fn is None:
-            dcfg, dt = self._spec_draft_cfg, self.compute_dtype
+            dcfg, dt = self._drafter, self.compute_dtype
             dec = self._dec
 
             def f(p, c, t, ln, sl, n):
@@ -827,7 +861,7 @@ class LLMEngine:
         ent = self._spec_fns.get(k)
         if ent is None:
             rounds = max(1, self.steps_per_dispatch // k)
-            spec, cfg, dcfg = self._spec, self.cfg, self._spec_draft_cfg
+            spec, cfg, dcfg = self._spec, self.cfg, self._drafter
             tk, dt = self.top_k, self.compute_dtype
 
             def run(tp, tc, dp, dc, st):
@@ -988,7 +1022,7 @@ class LLMEngine:
         for r, s in zip(reqs, slots):
             r.slot = s
             self._active[s] = r
-        if self._spec is not None:
+        if self._spec is not None and not self._spec_self:
             self._draft_prefill(reqs, slots)
         self.steps += 1
         chunks, walk = self._admit_walk(reqs, bucket)
@@ -1122,7 +1156,8 @@ class LLMEngine:
             self._draft_cache = res["draft_cache"]
             self._state = res["state"]
             self._in_flight(_Program(
-                "spec", (res["tokens"], res["counts"], res["emit_counts"]),
+                "spec", (res["tokens"], res["counts"], res["emit_counts"])
+                + ((res["moe_counts"],) if "moe_counts" in res else ()),
                 time.monotonic(), dict(self._active), k=k))
             self.steps += rounds
             self.spec_dispatch_k[k] = self.spec_dispatch_k.get(k, 0) + 1
@@ -1177,6 +1212,13 @@ class LLMEngine:
         self.spec_tokens += d_tok
         self.spec_drafted += d_draft
         self.spec_accepted += d_acc
+        # the rounds that found a live slot, times the expert layers (the
+        # drafting block's among them): what decode's experts' counts are
+        # set beside
+        self.moe_expert_layer_steps += sum(
+            bool((row > 0).any()) for row in rounds) * (
+                self.cfg.expert_layers + (self.cfg.mtp_layers
+                                          if self._spec_self else 0))
         if d_round:
             obs.record_spec_dispatch(self._obs_dep, d_round, d_tok,
                                      d_draft, d_acc)
@@ -1200,7 +1242,12 @@ class LLMEngine:
         with _Phase(self, "emit") as span:
             before = self.tokens_out
             if prog.kind == "spec":
-                tokens, counts, rounds = fetched  # rounds [num_rounds, slots]
+                # rounds [num_rounds, slots]; the experts' counts where the
+                # model has them
+                tokens, counts, rounds, *moe = fetched
+                if moe:
+                    self.moe_assignments += int(moe[0][0])
+                    self.moe_experts_touched += int(moe[0][1])
                 self._emit_spec(tokens, counts, rounds, prog.k,
                                 prog.snapshot)
             elif prog.kind == "admit":

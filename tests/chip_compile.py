@@ -88,8 +88,11 @@ def _serve_shapes(one_chip, cfg, paged, slots, max_len):
         cache = jax.eval_shape(lambda: paged_decode.init_paged_cache(
             cfg, slots * (max_len // 64) // 2, 64, slots, max_len // 64))
     else:
+        # (a ring with the margin of the verify step that the model's own
+        # block drafts for, as the engine allocates it)
         cache = jax.eval_shape(lambda: decode.init_kv_cache(
-            cfg, slots, max_len))
+            cfg, slots, max_len,
+            ring=decode.ring_len(cfg, 2) if cfg.mtp_layers else None))
     state = jax.eval_shape(lambda: decode.init_decode_state(
         slots, jax.random.PRNGKey(1)))
     return _on(one_chip, params), _on(one_chip, cache), _on(one_chip, state)
@@ -106,13 +109,19 @@ def _admit_rows(one_chip, bucket, b=8):
 
 
 def serve_program(one_chip, cfg, program, slots, max_len, rows=8):
-    """One of the engine's programs ("decode", or "prefill-<bucket>" with
+    """One of the engine's programs ("decode", "spec": the rounds of a
+    dispatch with the model's own block drafting, or "prefill-<bucket>" with
     ``rows`` rows an admit), cache and state donated as the engine donates
     them: (compiled, its text)."""
     args = _serve_shapes(one_chip, cfg, False, slots, max_len)
     if program == "decode":
         fn = lambda p, c, st: decode.decode_state_loop(  # noqa: E731
             p, c, st, STEPS, cfg, 0, jnp.bfloat16)
+    elif program == "spec":
+        from ray_tpu.models import speculative
+        fn = lambda p, c, st: speculative.spec_decode_state_loop(  # noqa: E731
+            p, c, {}, {}, st, 2, STEPS // 2, cfg,
+            speculative.block_drafter(cfg), 0, jnp.bfloat16)
     else:
         args += _admit_rows(one_chip, int(program.split("-")[1]), rows)
         fn = lambda p, c, st, *a: decode.prefill_admit(  # noqa: E731

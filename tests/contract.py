@@ -199,12 +199,15 @@ class Shares:
         kind, s = self.kind, self.row.shares
         n, held = s["n"], s["held"]
         whole = self.doc
-        whole["n_routed_experts"] = n * held
+        whole[s.get("key", "n_routed_experts")] = n * held
         del whole["reduced"], whole["share"]
         cfg = kind.program_config(whole)
         params = kinds.init(kind.init_params, cfg, seed=5)
+        # a layer's router and shared expert: under its kind's blocks, or
+        # (group None) in a stack by layer of their own
         group, at = s["moe_at"]
-        lp = jax.tree.map(lambda a: a[at], params["blocks"][group]["moe"])
+        lp = jax.tree.map(lambda a: a[at], (
+            params["blocks"][group] if group else params["blocks"])["moe"])
         stacks = params["blocks"]["experts"]
         x = jax.random.normal(jax.random.PRNGKey(6), (24, 64))
         docs = []

@@ -172,7 +172,7 @@ KINDS = {k.name: k for k in (
                               + ["sliding_attention"]), "sliding_attention")),
         config_refusals=(dict(_PATTERN_BASE, norm_on_output=True), (
             ("half-a-period", dict(num_layers=5), "whole periods"),
-            ("unknown-kind", dict(layer_pattern=("linear", "window")),
+            ("unknown-kind", dict(layer_pattern=("linear", "sink")),
              "kinds"),
             ("decay-a-channel-without-its-rank",
              dict(linear_decay_per_channel=True), "linear_gate_rank"),
@@ -343,8 +343,8 @@ KINDS = {k.name: k for k in (
                                       qk_rope_head_dim=2, v_head_dim=4),
              "latent"),
             ("residual-streams", dict(hc_mult=2), "residual stream"),
-            ("a-dense-prefix", dict(**_EXPERTS, dense_prefix_layers=1),
-             "same MLP"),
+            ("a-dense-prefix-past-the-first-period",
+             dict(**_EXPERTS, dense_prefix_layers=3), "first period"),
             ("a-decay-a-channel-without-its-rank",
              dict(linear_decay_per_channel=True), "linear_gate_rank"),
             ("a-rank-without-a-decay-a-channel", dict(linear_gate_rank=4),
@@ -466,8 +466,8 @@ KINDS = {k.name: k for k in (
              "layer_pattern only"),
             ("ungated-experts-of-no-activation",
              dict(**_EXPERTS, use_swiglu=False), "SwiGLU"),
-            ("a-dense-prefix", dict(**_EXPERTS, dense_prefix_layers=1),
-             "same MLP"))),
+            ("a-dense-prefix-past-the-first-period",
+             dict(**_EXPERTS, dense_prefix_layers=3), "first period"))),
         # under 15.0 GiB, as ISSUE 46 asks of the largest program (readings
         # 0.004 and 0.92 GB; PERF.md section 4).  decode: a recurrent step a
         # state-space layer, decode_attn, two grouped matmuls an expert
@@ -495,6 +495,118 @@ KINDS = {k.name: k for k in (
                         "linear_layers": 0, "ssm_layers": 4,
                         "full_layers": 1, "cache_latent_bytes": 0,
                         "expert_layers": 4, "experts_held": 64})),
+    Kind(
+        name="exaone_moe", tiny="tiny-exaone.json",
+        cell="k-exaone-236b-a23b-serve-l8-e8",
+        # window 8 on a ring of 16: prompts on both sides of the window and
+        # of the ring, then decode past two wraps of the ring
+        parity=dict(seed=2,
+                    draw=lambda rng: [rng.integers(1, 256, n + 40)
+                                      for n in (5, 8, 9, 23)],
+                    lens=[5, 8, 9, 23], slots=[3, 0, 4, 1], n_slots=5,
+                    max_len=128, bucket=32, steps=36, atol=2e-4, ref_kw={}),
+        engine=dict(kw=dict(num_slots=3, max_len=64, buckets=(16, 32),
+                            steps_per_dispatch=2),
+                    seed=1, lens=(11,), max_tokens=6,
+                    # 4 cache rows (3 slots + scratch), float32: K and V of 2
+                    # full layers and of the block's one, rings of 16 rows
+                    # (window 8, a step of one token) for 6 window layers
+                    gauges={"experts_held": 8, "expert_layers": 7,
+                            "linear_layers": 0, "window_layers": 6,
+                            "full_layers": 2, "cache_state_bytes": 0,
+                            "cache_kv_bytes": 2 * 3 * 4 * 64 * 2 * 16 * 4,
+                            "cache_ring_bytes": 2 * 6 * 4 * 16 * 2 * 16 * 4,
+                            "cache_latent_bytes": 0},
+                    # an admit's assignments: 11 tokens x 3 x 7 expert
+                    # layers (of which the held half is computed)
+                    admitted=11 * 3 * 7 // 2),
+        shares=dict(n=2, held=8, moe_at=(None, 2), key="num_experts"),
+        # the window layers' band under ``window_attn``, their rings under
+        # ``ring_write`` / ``ring_read``; the dense layer's MLP under
+        # ``mlp``, the experts' under ``moe_*``; the block's projection
+        # (``mtp_proj``) is the admit's and the speculative program's
+        scopes=dict(
+            both={"attn", "norm", "lm_head", "mlp", "kv_write", "ring_write",
+                  "moe_route", "moe_sort", "moe_experts", "moe_shared",
+                  "moe_combine"},
+            decode={"kv_read", "ring_read"}, neither=set(),
+            stats=("cache_kv_bytes", "cache_ring_bytes", "window_layers",
+                   "full_layers", "experts_held", "expert_layers")
+            + _MOE_COUNTERS),
+        train_refusal="layer_pattern",
+        engine_refusals=(("paged", dict(paged=True), "no ring"),
+                         ("tp", dict(tp=2), "sharding rule")),
+        kernels=(("decode_attention", "KERNEL_WINDOW_DECODE_ATTN",
+                  "window_decode_attn"),
+                 ("flash_attention", "KERNEL_FLASH_WINDOW",
+                  "flash_window_prefill"),
+                 ("decode_attention", "KERNEL_DECODE_ATTN", "decode_attn")),
+        readers=(("window_decode_attn_roofline.py", "WINDOW_DECODE_ATTN",
+                  "window_decode_attn"),
+                 ("flash_window_prefill_roofline.py", "FLASH_WINDOW",
+                  "flash_window_prefill"),
+                 ("swa_moe_kernels_device_share.py", "KERNELS",
+                  ("window_decode_attn", "flash_window_prefill",
+                   "decode_attn", "moe_gmm")),
+                 ("spec_step_device_ms.batch.py", "SPEC_PROGRAM",
+                  "engine_spec_decode")),
+        kind_refusals=(
+            ("softmax-scores", dict(scoring_func="softmax"), "sigmoid"),
+            ("router-groups", dict(n_group=2), "n_group"),
+            ("unnormalised-gates", dict(norm_topk_prob=False),
+             "norm_topk_prob"),
+            ("tied-head", dict(tie_word_embeddings=True),
+             "tie_word_embeddings"),
+            ("another-activation", dict(hidden_act="gelu"), "hidden_act"),
+            ("scaled-rotary", dict(rope_parameters={
+                "rope_theta": 1e6, "rope_type": "yarn"}), "rope_type"),
+            ("two-blocks", dict(num_nextn_predict_layers=2,
+                                mtp_layer_types=["full_attention"] * 2),
+             "one multi-token-prediction block"),
+            ("a-sliding-block", dict(mtp_layer_types=["sliding_attention"]),
+             "mtp_layer_types"),
+            ("a-sparse-layer-first", dict(
+                mlp_layer_types=["sparse"] + ["dense"] + ["sparse"] * 6),
+             "mlp_layer_types"),
+            ("windows-that-disagree", dict(sliding_windows=[8] * 8),
+             "sliding_windows"),
+            ("a-kind-it-does-not-know", dict(
+                layer_types=["chunked_attention"] * 8), "layer_types"),
+            ("a-share-past-the-end", dict(share=dict(expert_start=14)),
+             "past the router")),
+        config_refusals=(None, (
+            ("a-window-without-its-kind", dict(layer_pattern=("full",)),
+             "sliding_window"),
+            ("a-kind-without-its-window", dict(sliding_window=0),
+             "sliding_window"),
+            ("both-norms", dict(qk_norm=True), "not both"),
+            ("rotary-by-kind-without-rotary", dict(use_rope=False),
+             "rope_window_only"),
+            ("two-blocks", dict(mtp_layers=2), "one block"),
+            ("a-block-over-a-recurrent-state",
+             dict(layer_pattern=("linear", "window", "window", "full"),
+                  linear_num_heads=2, linear_key_dim=8, linear_value_dim=8),
+             "rolled out"),
+            ("a-prefix-past-the-first-period", dict(dense_prefix_layers=5),
+             "first period"))),
+        stacks=("bf16[2,49,6144,1024]", "bf16[6,49,256,1024]",
+                "bf16[1,49,6144,1024]"),
+        # no layer's experts leave their stack: [8, 6144, 2048] is 0.2 GB
+        held_in_place=(r"= bf16\[(1,)?8,(6144,2048|2048,6144)\]\S* "
+                       r"(dynamic-slice|copy)\(",),
+        counts=dict(slots=49, num_params=4_394_720_512,
+                    per={"attention": 113_246_208, "dense": 339_738_624,
+                         "expert": 37_748_736, "shared": 37_748_736,
+                         "router": 786_432, "mtp_proj": 75_497_472},
+                    gauges=lambda kind, doc: {
+                        "cache_kv_bytes": 49 * 6144 * kind.kv_bytes_per_token(
+                            doc),
+                        "cache_ring_bytes": 49 * kind.ring_bytes_per_slot(
+                            doc, 256),
+                        "cache_state_bytes": 0,
+                        "linear_layers": 0, "window_layers": 6,
+                        "full_layers": 2, "cache_latent_bytes": 0,
+                        "expert_layers": 7, "experts_held": 8})),
     # trained, not served: the share train cell's kind.  The backward's two
     # grouped kernels beside the forward's; the reader's list still holds
     # ``flash_dq``, a kernel that is gone since PR 48 (the backward is

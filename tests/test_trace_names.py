@@ -206,9 +206,24 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
     from jax.profiler import ProfileData
 
     eng = _engine(tiny_cfg)
+
+    def drained():
+        """Wait until the loop has fetched all it dispatched and sits idle
+        (a fixed nap is too short on a machine six workers share)."""
+        busy = ("loop_admit_n", "loop_dispatch_n", "loop_fetch_n",
+                "loop_emit_n")
+        last = None
+        for _ in range(100):
+            time.sleep(0.1)
+            now = eng.counters()
+            if last and all(now[k] == last[k] for k in busy):
+                return now
+            last = now
+        return last
+
     try:
         eng.warmup(16)
-        time.sleep(0.1)      # the warm-up's last dispatches drain outside it
+        drained()            # the warm-up's last dispatches drain outside it
         with jax.profiler.trace(str(tmp_path)):
             c0 = eng.counters()
             outs = [eng.generate([1, 2, 3 + i], max_tokens=9)
@@ -216,8 +231,7 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
             # idle passes inside the capture; the dispatch the engine bound
             # ahead of the last answer drains before the second snapshot
             # (its fetch would be the test's whole tolerance)
-            time.sleep(0.1)
-            c1 = eng.counters()
+            c1 = drained()
         assert [len(o) for o in outs] == [9, 9, 9]
     finally:
         eng.shutdown()
@@ -330,6 +344,7 @@ def _reader(name):
 def _traced():
     """A call of each kernel a kind names, interpreted, as a jaxpr's text."""
     from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops import flash_attention as fa
     from ray_tpu.ops import gated_delta as gd
     from ray_tpu.ops import kda, moe, ssd
     f32 = jnp.float32
@@ -363,6 +378,18 @@ def _traced():
             lambda *a: ssd.ssd_recurrent_step(*a, interpret=True))(
                 state, jnp.int32(1), x[:, 0], x[:, 0, :, 0], a, b[:, 0],
                 b[:, 0], a),
+        "decode_attn": lambda: jax.make_jaxpr(lambda q, k: da.decode_attn(
+            q, k, k, jnp.int32(0), jnp.array([3, 0]), 2, interpret=True,
+            tokens=2))(jnp.ones((2, 8, 8)), jnp.ones((1, 2, 16, 16))),
+        "window_decode_attn": lambda: jax.make_jaxpr(
+            lambda q, k: da.window_decode_attn(
+                q, k, k, jnp.int32(0), jnp.array([3, 0]), 2, 8, 2,
+                interpret=True))(jnp.ones((2, 8, 8)),
+                                 jnp.ones((1, 2, 16, 16))),
+        "flash_window_prefill": lambda: jax.make_jaxpr(
+            lambda q, k: fa.flash_attention(q, k, k, window=8,
+                                            interpret=True))(
+                jnp.ones((1, 128, 4, 8)), jnp.ones((1, 128, 2, 8))),
         "moe_gmm": lambda: jax.make_jaxpr(lambda x, w: gmm(x, w, 1))(
             rows, jnp.ones((2, 4, 64, 32), f32)),
         "mla_decode_attn": lambda: jax.make_jaxpr(
