@@ -103,6 +103,10 @@ class Cluster:
         return api.init(address=self.gcs.address, **kwargs)
 
     def shutdown(self):
+        """Every wait has its bound: 5 s a node for its exit, then SIGKILL;
+        5 s for the GCS's stop, which is asked only after the agents are
+        reaped through their ``Popen`` handles, so they are gone whether or
+        not it returns."""
         for node in self.nodes:
             if node.alive:
                 node.proc.terminate()
@@ -111,4 +115,5 @@ class Cluster:
                 node.proc.wait(timeout=5)
             except subprocess.TimeoutExpired:
                 node.proc.kill()
+                node.proc.wait(timeout=5)
         run_async(self.gcs.stop(), timeout=5)

@@ -704,6 +704,14 @@ class LeasePool:
         self._decision("queued", node=node or addr)
 
     def _pump(self):
+        if self.w._shutdown:
+            # Nothing is dispatched or leased after shutdown.  Without this
+            # a pool that still holds a queued task spins for the life of
+            # the process: ``_acquire_leases`` leaves its loop at once, its
+            # ``finally`` pumps, the deficit asks for a lease again: one
+            # core and half the GIL gone on the IO loop (PR 51: every later
+            # test of that xdist worker ran 10-40 times slower).
+            return
         # Dispatch queued tasks to idle leased workers.  Multiple queued
         # tasks ride one push RPC (up to max_tasks_in_flight_per_worker),
         # split evenly across idle workers so batching never costs

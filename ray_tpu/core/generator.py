@@ -27,7 +27,7 @@ import asyncio
 from typing import List, Optional
 
 from .ids import ObjectID, TaskID
-from .object_ref import ObjectRef
+from .object_ref import ObjectRef, finalize
 
 
 class StreamState:
@@ -168,27 +168,33 @@ class ObjectRefGenerator:
                               and st.next_read >= st.total)
 
     def __del__(self):
-        # Dropping the generator abandons unconsumed items: build-and-drop a
-        # ref for each stored-but-unread yield so refcounting frees them, and
-        # hand the producer an unbounded backpressure credit so a generator
-        # parked in wait_capacity doesn't stall until its 600s timeout (e.g.
-        # an HTTP client that disconnected mid-stream).
         try:
-            st = self._w.streams.pop(self.task_id, None)
-            if st is None:
-                return
-            st.abandoned = True
-            for i in range(st.next_read, st.available):
-                ObjectRef(ObjectID.for_task_return(self.task_id, i),
-                          owner=self._w.address)
-            if st.backpressure and st.worker_addr:
-                from .rpc import get_loop
-                client = self._w.worker_clients.get(st.worker_addr)
-                asyncio.run_coroutine_threadsafe(
-                    client.notify("generator_ack", task_id=self.task_id,
-                                  consumed=1 << 62), get_loop())
+            finalize(_abandon, self._w, self.task_id)
         except Exception:
             pass
+
+
+def _abandon(w, task_id) -> None:
+    """Dropping the generator abandons unconsumed items: build-and-drop a
+    ref for each stored-but-unread yield so refcounting frees them, and hand
+    the producer an unbounded backpressure credit so a generator parked in
+    wait_capacity doesn't stall until its 600s timeout (e.g. an HTTP client
+    that disconnected mid-stream)."""
+    try:
+        st = w.streams.pop(task_id, None)
+        if st is None:
+            return
+        st.abandoned = True
+        for i in range(st.next_read, st.available):
+            ObjectRef(ObjectID.for_task_return(task_id, i), owner=w.address)
+        if st.backpressure and st.worker_addr:
+            from .rpc import get_loop
+            client = w.worker_clients.get(st.worker_addr)
+            asyncio.run_coroutine_threadsafe(
+                client.notify("generator_ack", task_id=task_id,
+                              consumed=1 << 62), get_loop())
+    except Exception:
+        pass
 
 
 __all__ = ["ObjectRefGenerator", "StreamState"]

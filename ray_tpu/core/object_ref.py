@@ -10,9 +10,41 @@ ReferenceCounter (reference ``src/ray/core_worker/reference_count.h:61``).
 
 from __future__ import annotations
 
+import gc
 from typing import Optional
 
 from .ids import ObjectID
+
+# A cyclic collection starts at an eval-breaker check between any two
+# bytecodes of any thread, also inside a ``with lock:`` of the reference
+# counter, of the free buffer or of an IO lane's creation.  A finalizer that
+# then takes the same lock on the same thread waits for itself, and every
+# thread that wants the lock after it waits too (PERF.md section 7, PR 34: a
+# caller's IO thread; PR 51: the storm test's 256 submitters and, behind
+# them, ``shutdown``).  So a finalizer the collector calls hands its work to
+# the IO loop, which runs it between callbacks, inside none of those
+# sections.  Work handed on comes late, never early: no object is freed
+# before its time.
+_collecting = False
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _collecting
+    _collecting = phase == "start"
+
+
+gc.callbacks.append(_on_gc)
+
+
+def finalize(fn, *args) -> None:
+    """Run a finalizer's work ``fn(*args)``: at once, or on the IO loop
+    when it is the collector that called the finalizer.  ``args`` must not
+    hold the dying object."""
+    if _collecting:
+        from .rpc import get_loop
+        get_loop().call_soon_threadsafe(fn, *args)
+    else:
+        fn(*args)
 
 
 class ObjectRef:
@@ -65,7 +97,7 @@ class ObjectRef:
         if not getattr(self, "_registered", False):
             return
         try:
-            _ref_deleted(self)
+            finalize(_ref_deleted, self.id, self.owner)
         except Exception:
             pass
 
@@ -91,9 +123,9 @@ def _ref_created(ref: ObjectRef):
         w.reference_counter.add_local_ref(ref.id, ref.owner)
 
 
-def _ref_deleted(ref: ObjectRef):
+def _ref_deleted(oid: ObjectID, owner: str):
     # binds the lookup too: the submit path registers a task's return refs
     # without passing through ``_ref_created``
     w = _worker()
     if w is not None:
-        w.reference_counter.remove_local_ref(ref.id, ref.owner)
+        w.reference_counter.remove_local_ref(oid, owner)

@@ -29,7 +29,13 @@ from . import chaos
 from .chaos import ChaosFault
 from .config import get_config
 
+# Guards the CREATION of a lane, nothing else: a lane that exists is read
+# without it (one dict read), so no caller's ``timeout=`` waits behind
+# another thread's creation or behind a holder that never lets go.
 _loop_lock = threading.Lock()
+# A lane's thread starts in milliseconds; past this the creation raises
+# instead of holding ``_loop_lock`` (and every other creator) for ever.
+_LANE_START_TIMEOUT_S = 30.0
 # IO-loop LANES: lane 0 is the process's default background loop (the
 # historical single "raytpu-io" thread every component shares); additional
 # lanes are extra loop threads that carry their own subset of connections —
@@ -44,7 +50,14 @@ def get_loop(lane: Any = 0) -> asyncio.AbstractEventLoop:
     """The process-wide background event loop for ``lane`` (started
     lazily).  ``get_loop()`` is the default lane every existing caller
     uses; other lanes are opt-in via the lane-aware clients."""
-    with _loop_lock:
+    ent = _lanes.get(lane)
+    if ent is not None and not ent[0].is_closed():
+        return ent[0]
+    if not _loop_lock.acquire(timeout=_LANE_START_TIMEOUT_S):
+        raise RuntimeError(
+            f"IO lane {lane!r}: _loop_lock not free after "
+            f"{_LANE_START_TIMEOUT_S}s (another thread is creating a lane)")
+    try:
         ent = _lanes.get(lane)
         if ent is None or ent[0].is_closed():
             loop = asyncio.new_event_loop()
@@ -53,19 +66,30 @@ def get_loop(lane: Any = 0) -> asyncio.AbstractEventLoop:
             def _run():
                 asyncio.set_event_loop(loop)
                 loop.call_soon(started.set)
-                loop.run_forever()
+                try:
+                    loop.run_forever()
+                finally:
+                    loop.close()
 
             name = "raytpu-io" if lane == 0 else f"raytpu-io-{lane}"
             t = threading.Thread(target=_run, name=name, daemon=True)
             t.start()
-            started.wait()
-            _lanes[lane] = (loop, t)
-        return _lanes[lane][0]
+            if not started.wait(_LANE_START_TIMEOUT_S):
+                # should the thread run after all, it stops at once
+                loop.call_soon_threadsafe(loop.stop)
+                raise RuntimeError(
+                    f"IO lane {lane!r}: thread {name} did not start within "
+                    f"{_LANE_START_TIMEOUT_S}s")
+            ent = _lanes[lane] = (loop, t)
+        return ent[0]
+    finally:
+        _loop_lock.release()
 
 
 def run_async(coro, timeout: float | None = None, lane: Any = 0):
     """Run a coroutine on the IO loop of ``lane`` from a synchronous
-    caller."""
+    caller; ``timeout`` bounds the whole call (a lane that exists is
+    reached without a wait)."""
     loop = get_loop(lane)
     if threading.current_thread() is _lanes[lane][1]:
         raise RuntimeError("run_async called from the IO loop thread (would deadlock)")

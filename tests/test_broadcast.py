@@ -8,7 +8,7 @@ import pytest
 import ray_tpu
 
 
-@pytest.mark.timeout(300)
+@pytest.mark.timeout(120)
 def test_broadcast_tree_and_dedup(ray_start_cluster):
     cluster = ray_start_cluster
     nids = []

@@ -192,7 +192,7 @@ def test_call_during_teardown_fails_fast():
 
 
 @pytest.mark.chaos
-@pytest.mark.timeout(240)
+@pytest.mark.timeout(120)
 def test_chaos_smoke_drop_frames_and_worker_kill():
     """Tier-1 chaos smoke (seeded, deterministic spec): 5% of frames
     dropped on every link plus one scheduled worker kill, over a real task
@@ -232,7 +232,7 @@ def test_chaos_smoke_drop_frames_and_worker_kill():
 
 
 @pytest.mark.chaos
-@pytest.mark.timeout(240)
+@pytest.mark.timeout(120)
 def test_chaos_sharded_control_plane_shard_restart():
     """The PR-13 horizontal-control-plane chaos arm: seeded frame drops +
     one scheduled worker kill over a real workload on a SHARDED GCS
@@ -315,7 +315,7 @@ def test_chaos_sharded_control_plane_shard_restart():
 
 
 @pytest.mark.chaos
-@pytest.mark.timeout(280)
+@pytest.mark.timeout(120)
 def test_chaos_acceptance_drops_kill_and_gcs_restart(tmp_path):
     """The acceptance run: a seeded chaos spec (5% frame drop + 1 scheduled
     worker kill) over a 200-task workload WITH a GCS stop/restart in the

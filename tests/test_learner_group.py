@@ -25,7 +25,7 @@ def _ppo_rollout(rng, T, B):
     }
 
 
-@pytest.mark.timeout(300)
+@pytest.mark.timeout(120)
 def test_distributed_group_matches_local_update(ray_start_regular):
     """2 learner processes x 2 devices (dp=4) == single-device learner,
     same seed, same batch: proves the cross-process psum computes the same
@@ -87,7 +87,7 @@ def test_ppo_learns_cartpole_with_learner_actors(ray_start_regular):
     assert best >= 100.0, f"best return {best} < 100 within budget"
 
 
-@pytest.mark.timeout(300)
+@pytest.mark.timeout(120)
 def test_impala_with_learner_actors_smoke(ray_start_regular):
     """IMPALA's async loop with a remote V-trace learner group: a couple of
     iterations run, metrics flow back, and the version-lag diagnostic is

@@ -114,7 +114,7 @@ def test_log_action_and_weights():
         srv.stop()
 
 
-@pytest.mark.timeout(300)
+@pytest.mark.timeout(240)
 def test_external_ppo_trains(ray_start_regular):
     """End-to-end: PPO in external mode learns from a CartPole simulator
     that lives in the test process and talks HTTP only (reference:

@@ -49,7 +49,7 @@ def test_impala_learns_cartpole_decoupled(ray_start_regular):
         algo.stop()
 
 
-@pytest.mark.timeout(600)
+@pytest.mark.timeout(120)
 def test_appo_clipped_surrogate_runs(ray_start_regular):
     from ray_tpu.rllib import APPOConfig
 
@@ -82,7 +82,7 @@ def test_multi_agent_env_contract():
     assert obs["player_0"][1] == 1.0  # opponent played paper(1)
 
 
-@pytest.mark.timeout(600)
+@pytest.mark.timeout(240)
 def test_multi_agent_ppo_two_policies(ray_start_regular):
     """Two independent policies train against each other on RPS; per-policy
     batches, per-policy learners, dict env stepping end to end."""

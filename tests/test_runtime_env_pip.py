@@ -50,7 +50,7 @@ def test_pip_env_failure_fails_task(tmp_path):
         ray_tpu.shutdown()
 
 
-@pytest.mark.timeout(420)  # two venv builds on a slow box
+@pytest.mark.timeout(120)
 def test_conflicting_pip_envs_one_cluster(tmp_path):
     wheels = str(tmp_path)
     _build_wheel(wheels, "confl", "1.0", "VERSION = '1.0'\n")

@@ -48,7 +48,7 @@ def _assert_tables_drain(timeout_s: float = 15.0):
 
 
 @pytest.mark.parametrize("depth", [
-    pytest.param(10_000, id="10k", marks=pytest.mark.timeout(300)),
+    pytest.param(10_000, id="10k", marks=pytest.mark.timeout(120)),
     pytest.param(100_000, id="100k",
                  marks=[pytest.mark.slow, pytest.mark.timeout(900)]),
 ])
@@ -130,7 +130,7 @@ def test_500_actors_register(ray_start_regular):
     _assert_tables_drain()
 
 
-@pytest.mark.timeout(300)
+@pytest.mark.timeout(120)
 def test_100_placement_groups_cycle(ray_start_regular):
     """100 PGs schedule concurrently, all become ready, all remove; agent
     resources return to the starting level and the GCS table empties."""
@@ -155,7 +155,7 @@ def test_100_placement_groups_cycle(ray_start_regular):
     _assert_tables_drain()
 
 
-@pytest.mark.timeout(300)
+@pytest.mark.timeout(240)
 def test_network_delay_chaos(ray_start_cluster):
     """200 ms on every RPC link via the seeded fault-injection plane
     (RAYTPU_CHAOS_SPEC — the driver AND the agent subprocesses inherit

@@ -187,7 +187,7 @@ def test_jax_trainer_single_worker_mesh(ray_start_regular, tmp_path):
     assert result.metrics["loss"] > 0
 
 
-@pytest.mark.timeout(300)
+@pytest.mark.timeout(240)
 def test_jax_trainer_two_process_distributed(ray_start_regular, tmp_path):
     """The multi-controller seam (VERDICT r3 weak #4): TWO worker processes
     form one jax.distributed namespace (CPU backend), build a mesh spanning

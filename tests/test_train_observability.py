@@ -257,7 +257,7 @@ def _find_step_chain(evs):
     return None
 
 
-@pytest.mark.timeout(280)
+@pytest.mark.timeout(120)
 def test_two_node_run_trace_history_top_profile(ray_start_cluster,
                                                 tmp_path, capsys):
     """Acceptance: a 2-node training run yields (a) a connected step

@@ -725,7 +725,7 @@ def test_chunked_pull_timeline_schema(ray_start_cluster, tmp_path,
 # --------------------------------------------------- cluster: chaos drops
 
 @pytest.mark.chaos
-@pytest.mark.timeout(240)
+@pytest.mark.timeout(120)
 def test_broadcast_survives_frame_drops_byte_exact(tmp_path, monkeypatch):
     """Chunked broadcast through 5% frame drops on the read_chunk link
     (seeded, deterministic): every puller completes with byte-exact

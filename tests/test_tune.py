@@ -117,14 +117,26 @@ def test_trial_checkpoint_and_restart(ray_start_regular, tmp_path):
 
 
 def test_pbt_perturbs(ray_start_regular, tmp_path):
+    up = str(tmp_path / "up")
+    os.makedirs(up)
+
     def trainable(config):
-        import json, tempfile
+        import json, tempfile, time
         ck = tune.get_checkpoint()
         base = 0.0
+        lr = config["lr"]
         if ck:
             with open(os.path.join(ck.path, "w.json")) as f:
                 base = json.load(f)["w"]
-        lr = config["lr"]
+        else:
+            # both trials are up before either reports: on a loaded machine
+            # the weak one, which starts first, otherwise ends its 8 rounds
+            # while the strong one's worker is still starting, and PBT has
+            # had nobody to hold it against (a whole run of PR 51 failed so)
+            open(os.path.join(up, str(lr)), "w").close()
+            deadline = time.monotonic() + 60
+            while len(os.listdir(up)) < 2 and time.monotonic() < deadline:
+                time.sleep(0.02)
         w = base
         for i in range(8):
             w += lr

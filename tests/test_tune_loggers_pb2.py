@@ -101,8 +101,11 @@ def test_pb2_beats_random_on_quadratic(ray_start_regular, tmp_path):
     checkpoint (reference pb2.py/pbt.py contract), so a bottom-quantile
     trial resumes from the donor's cumulative progress with a new config."""
     from ray_tpu.train import Checkpoint
+    up = str(tmp_path / "up")
+    os.makedirs(up)
 
     def trainable(config):
+        import time
         lr = config["lr"]
         start, score = 0, 0.0
         ckpt = tune.get_checkpoint()
@@ -110,8 +113,20 @@ def test_pb2_beats_random_on_quadratic(ray_start_regular, tmp_path):
             with open(os.path.join(ckpt.path, "state.json")) as f:
                 st = json.load(f)
             start, score = st["i"], st["score"]
+        else:
+            # all four trials are up before any reports (a loaded machine
+            # starts their workers seconds apart) and then report in step.
+            # PB2 holds a trial at step t against the others' latest scores,
+            # so the bowl is steep enough that a weak trial (0.19 a step)
+            # ranks under a strong one (0.36) that is up to half its steps
+            # behind it: whatever order the reports arrive in, a weak trial
+            # is in the bottom half at its fourth step.
+            open(os.path.join(up, str(lr)), "w").close()
+            deadline = time.monotonic() + 60
+            while len(os.listdir(up)) < 4 and time.monotonic() < deadline:
+                time.sleep(0.02)
         for i in range(start, 8):
-            score += 1.0 - (lr - 0.5) ** 2  # optimum at lr=0.5
+            score += 1.0 - 4.0 * (lr - 0.5) ** 2  # optimum at lr=0.5
             cdir = os.path.join(tune.get_trial_dir(), f"ck_{i}")
             os.makedirs(cdir, exist_ok=True)
             with open(os.path.join(cdir, "state.json"), "w") as f:

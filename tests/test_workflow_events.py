@@ -62,7 +62,7 @@ def test_custom_event_listener(ray_start_regular):
     assert out == "ding!"
 
 
-@pytest.mark.timeout(300)
+@pytest.mark.timeout(120)
 def test_event_survives_gcs_restart(tmp_path):
     """The full VERDICT scenario: workflow blocks on an event, the GCS
     crashes and restarts from its snapshot, the event THEN posts, and the
