@@ -131,10 +131,12 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
 #: group is padded to whole tiles, so a decode step's two or three rows an
 #: expert take the smallest bf16 tile and a prefill row's hundreds the MXU's
 TILE_ROWS = (16, 32, 64, 128, 256)
-#: output channels a grid step computes (a weight block is ``[K, BLOCK_N]``)
+#: output channels of a weight block of the backward's two kernels
 BLOCK_N = 512
-#: the kernel's VMEM: two weight blocks (three where gated), double
-#: buffered, the row tile and its product; the chip has 128 MiB
+#: what a split weight block's output channels are whole multiples of
+LANES = 128
+#: the kernels' VMEM, of the chip's 128 MiB; the forward's weight block is
+#: planned against it (``gmm_block``)
 VMEM_LIMIT = 64 << 20
 
 
@@ -222,12 +224,51 @@ def _gmm_kernel(layer_ref, expert_ref, tiles_ref, x_ref, *refs, gated: bool,
         o_ref[...] = out.astype(o_ref.dtype)
 
 
+def _gmm_vmem(kdim: int, bn: int, matrices: int, tile: int,
+              itemsize: int) -> int:
+    """Bytes of VMEM a grid step of ``moe_gmm`` with weight blocks ``[K,
+    bn]`` is counted at: two buffers of each matrix's block (the next
+    expert's is fetched while this one's is multiplied), of the row tile and
+    of its output; the float32 ``[tile, bn]`` temporaries, one where a single
+    product goes out as it comes and six under the gated epilogue; and 2
+    MiB.  The least limits that compiled for a described v5e at the five
+    configurations' widths lie 1 to 6 MiB under this count (PR 52)."""
+    return (2 * (matrices * kdim * bn + tile * kdim + tile * bn) * itemsize
+            + (1 if matrices == 1 else 6) * tile * bn * 4 + (2 << 20))
+
+
+def gmm_block(kdim: int, n: int, matrices: int, tile: int,
+              itemsize: int) -> int:
+    """Output channels of the weight block ``[K, bn]`` a grid step of
+    ``moe_gmm`` fetches of an expert's ``[K, N]`` matrix (of each of the
+    ``matrices`` a call multiplies): the whole matrix where that fits
+    ``VMEM_LIMIT`` beside the row tile of ``tile`` rows (``_gmm_vmem``), else
+    the fewest equal strips of whole ``LANES`` that do.  A function of what
+    the kernel sees in its operands and of nothing else.  Why the largest
+    (the kernel alone on the chip, ``PERF.md`` section 6, PR 52): a step's
+    fetch is issued when the step before it has been waited for, so every
+    step pays a latency the two buffers cannot hide: blocks of 7 to 15 MB
+    move a decode step's experts at 735-746 GB/s where strips of 1 to 4 MB
+    moved them at 639-685, and a prefill tile's at 485-567 for 362-476.
+    Every output element is one ``dot`` over all of K whatever the strips."""
+    for strips in range(1, max(n // LANES, 1) + 1):
+        bn = n // strips
+        if strips > 1 and (n % strips or bn % LANES):
+            continue
+        if _gmm_vmem(kdim, bn, matrices, tile, itemsize) <= VMEM_LIMIT:
+            return bn
+    raise ValueError(
+        f"moe_gmm: no block of {matrices} [{kdim}, {n}] matrices of "
+        f"{itemsize}-byte elements beside a tile of {tile} rows fits "
+        f"{VMEM_LIMIT >> 20} MiB of VMEM")
+
+
 def _gmm_pallas(x, weights, layer, tile_expert, tiles, tile: int,
                 interpret: bool, activation: Optional[str] = None,
                 transposed: bool = False):
     rows, kdim = x.shape
     n = weights[0].shape[-2 if transposed else -1]
-    bn = BLOCK_N if n % BLOCK_N == 0 else n
+    bn = gmm_block(kdim, n, len(weights), tile, x.dtype.itemsize)
     num_tiles = rows // tile
 
     def x_map(ni, ti, layer, tile_expert, tiles):
@@ -245,8 +286,9 @@ def _gmm_pallas(x, weights, layer, tile_expert, tiles, tile: int,
                           activation=activation, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            # output channels outermost: a tile's neighbours are of its
-            # expert, whose weight block is then fetched once for them all
+            # one step a tile where the block is the whole matrix; where it
+            # is split, the strips outermost: a tile's neighbours are of its
+            # expert, whose strip is then fetched once for them all
             grid=(n // bn, num_tiles),
             in_specs=[pl.BlockSpec((tile, kdim), x_map)]
             + [pl.BlockSpec((1, 1, bn, kdim) if transposed

@@ -659,6 +659,20 @@ def cell_cfg(name, cell=None, **changes):
     return dataclasses.replace(cfg, **changes) if changes else cfg
 
 
+#: the kinds whose cells run ``ops/moe.py``'s dropless experts
+EXPERT_KINDS = ("xing4_0", "exaone_moe", "solar_open2", "nemotron_h",
+                "kimi_vl")
+
+
+def expert_shapes(name):
+    """Of the kind's cell at published sizes: (hidden width, expert width,
+    experts held, whether an expert is of two matrices, its up projection
+    stored transposed under a squared ReLU)."""
+    cfg = cell_cfg(name)
+    return (cfg.hidden_size, cfg.expert_mlp_size, cfg.experts_held,
+            cfg.mlp_act == "relu2")
+
+
 @functools.lru_cache(maxsize=None)
 def init(init_params, cfg, dtype=jnp.float32, seed=0):
     """``init_params(PRNGKey(seed), cfg, dtype)`` under ``jax.jit``, once a

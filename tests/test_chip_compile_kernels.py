@@ -223,3 +223,34 @@ def test_the_kinds_kernels_compile_at_published_sizes(one_chip, build, name,
         assert compiled.memory_analysis().temp_size_in_bytes < temp
     for stack in stacks:
         assert not copies_of(stack, text)
+
+
+# ------------------- the grouped matmul's block plan at every cell's widths
+
+@pytest.mark.parametrize("tile", [16, 128, 256])
+@pytest.mark.parametrize("name", kinds.EXPERT_KINDS)
+def test_moe_gmm_compiles_under_its_vmem_at_every_cells_widths(one_chip,
+                                                               name, tile):
+    """An expert's up and down calls at the smallest decode tile, at
+    K-EXAONE's decode tile of 128 and at the largest prefill tile, with the
+    block ``gmm_block`` plans from the shapes: a plan that overflows
+    ``VMEM_LIMIT`` is refused here and not at a cell's first admit.  (Kimi-VL
+    trains with gate and up apart: the gated call is the larger.)"""
+    from ray_tpu.ops import moe
+    h, m, held, relu2 = kinds.expert_shapes(name)
+    S, rows = shapes_on(one_chip), 4 * tile
+    plan = (S((), jnp.int32), S((4,), jnp.int32), S((), jnp.int32))
+
+    def up_down(x, ups, w_out, layer, tile_expert, tiles):
+        gmm = functools.partial(moe.moe_gmm, layer=layer, tiles=tiles,
+                                tile_expert=tile_expert, tile=tile,
+                                use_kernel=True, interpret=False)
+        kw = dict(activation="relu2", transposed=True) if relu2 else {}
+        return gmm(gmm(x, ups, **kw), (w_out,))
+
+    ups = (S((2, held, m, h)),) if relu2 else (S((2, held, h, m)),) * 2
+    compiled, text = _compile(up_down, S((rows, h)), ups, S((2, held, m, h)),
+                              *plan)
+    assert text.count(KERNEL) == 2
+    # the stacks are read where they lie
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6

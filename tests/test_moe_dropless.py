@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import kinds
 from ray_tpu.ops import moe
 
 H, M, E, K, L = 128, 64, 8, 2, 3
@@ -78,6 +79,106 @@ def test_grouped_matmul_equals_the_expert_loop(layer, t, dead, path):
     # the choice it reports is the router's, for dead tokens too
     np.testing.assert_array_equal(chosen, moe.route_sigmoid(
         x, small["router"], small["bias"], K, SCALING)[0])
+
+
+@pytest.mark.parametrize("tile", [16, 128, 256])
+@pytest.mark.parametrize("call", ["up", "down"])
+@pytest.mark.parametrize("name", kinds.EXPERT_KINDS)
+def test_the_weight_block_is_planned_from_the_shapes(name, call, tile):
+    """bf16 at every tile a cell runs: the block fits the budget, is the
+    whole matrix wherever that fits, else the fewest equal strips of whole
+    lanes that do, and tiles the matrix exactly."""
+    h, m, _, relu2 = kinds.expert_shapes(name)
+    kdim, n, matrices = (h, m, 1 if relu2 else 2) if call == "up" else (
+        m, h, 1)
+    bn = moe.gmm_block(kdim, n, matrices, tile, 2)
+    fits = lambda b: moe._gmm_vmem(  # noqa: E731
+        kdim, b, matrices, tile, 2) <= moe.VMEM_LIMIT
+    assert fits(bn) and n % bn == 0
+    # what the budget's arithmetic must keep (PERF.md section 6, PR 52):
+    # K-EXAONE's gate and up alone cannot be whole (2 x 25 MB x 2 buffers)
+    split = name == "exaone_moe" and call == "up"
+    assert (bn == n) == (not split) == fits(n)
+    if split:
+        strips = n // bn
+        assert bn % moe.LANES == 0 and strips == 2
+        assert not any(fits(n // s) for s in range(1, strips))
+
+
+def test_a_block_that_cannot_fit_is_refused_with_the_reason(monkeypatch):
+    monkeypatch.setattr(moe, "VMEM_LIMIT", 1 << 20)
+    with pytest.raises(ValueError, match="fits 1 MiB of VMEM"):
+        moe.gmm_block(4096, 1280, 2, 16, 2)
+
+
+def _gmm_case(form, layer_at, live_rows, seed=0):
+    """Operands of one ``moe_gmm`` call: 4 experts of [128, 256] in a stack
+    of 3 layers, 40 tokens with 2 assignments each of which the first
+    ``live_rows`` tokens count, tiles of 16; and the expert loop's result
+    for the live tiles."""
+    layers, experts, kdim, n, tile, t = 3, 4, 128, 256, 16, 40
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shape = ((layers, experts, n, kdim) if form == "relu2"
+             else (layers, experts, kdim, n))
+    weights = tuple(jax.random.normal(k, shape) * kdim ** -0.5
+                    for k in ks[:2 if form == "gated" else 1])
+    idx = jax.random.randint(ks[2], (t, 2), 0, experts)
+    held = jnp.broadcast_to((jnp.arange(t) < live_rows)[:, None], idx.shape)
+    _, source, tile_expert, tiles, _ = moe.sort_by_expert(idx, held, experts,
+                                                          tile)
+    xs = jnp.take(jax.random.normal(ks[3], (t, kdim)), source, axis=0,
+                  mode="fill", fill_value=0)
+    hi = jax.lax.Precision.HIGHEST
+    want = []
+    for i in range(int(tiles)):
+        rows, e = xs[i * tile:(i + 1) * tile], int(tile_expert[i])
+        ws = [w[layer_at, e].T if form == "relu2" else w[layer_at, e]
+              for w in weights]
+        out = jnp.dot(rows, ws[0], precision=hi)
+        if form == "gated":
+            out = jax.nn.silu(out) * jnp.dot(rows, ws[1], precision=hi)
+        elif form == "relu2":
+            out = moe.relu2(out)
+        want.append(out)
+    kw = (dict(activation="relu2", transposed=True) if form == "relu2"
+          else {})
+    return (xs, weights, layer_at, tile_expert, tiles, tile), kw, want
+
+
+@pytest.mark.parametrize("live_rows", [40, 9, 0],
+                         ids=["all-live", "dead-tiles", "no-live-tile"])
+@pytest.mark.parametrize("layer_at", [0, 2])
+@pytest.mark.parametrize("plan", ["whole", "split"])
+@pytest.mark.parametrize("form", ["gated", "one", "relu2"])
+def test_the_kernel_under_each_plan_equals_its_twin_and_the_expert_loop(
+        monkeypatch, form, plan, layer_at, live_rows):
+    """The forward kernel interpreted, fetching an expert's matrix whole or
+    in two strips of 128 (a budget that fits no more), in its three forms
+    (gated; one matrix; the squared ReLU over a stack stored transposed), on
+    the first and the last layer of the stack, with every tile live, with
+    tiles past the last live one and with none: the live rows are the
+    twin's and the loop's over the experts one at a time, and a split block
+    changes no digit (each output element is the same one ``dot``)."""
+    args, kw, want = _gmm_case(form, layer_at, live_rows)
+    xs, weights, _, tile_expert, tiles, tile = args
+    whole = moe.moe_gmm(*args, interpret=True, **kw)
+    kdim, n = xs.shape[1], whole.shape[1]
+    if plan == "split":
+        monkeypatch.setattr(moe, "VMEM_LIMIT", moe._gmm_vmem(
+            kdim, n // 2, len(weights), tile, 4))
+    assert moe.gmm_block(kdim, n, len(weights), tile, 4) == (
+        n if plan == "whole" else n // 2)
+    got = (moe.moe_gmm(*args, interpret=True, **kw) if plan == "split"
+           else whole)
+    live = int(tiles) * tile
+    assert live == len(want) * tile and (live > 0) == (live_rows > 0)
+    assert live < xs.shape[0]             # some tile is always past the last
+    np.testing.assert_array_equal(got[:live], whole[:live])
+    twin = moe.moe_gmm(*args, use_kernel=False, **kw)
+    np.testing.assert_allclose(got[:live], twin[:live], atol=5e-6)
+    if want:
+        np.testing.assert_allclose(got[:live], jnp.concatenate(want),
+                                   atol=5e-6)
 
 
 def test_the_shares_add_up_to_the_whole_layer(layer):
