@@ -128,6 +128,15 @@ class TransformerConfig:
     # the model's own form with K/V rows of its own, a norm, and the
     # model's head
     mtp_layers: int = 0
+    # four published scalars of a pattern (Granite's): on the embedding, on
+    # every sublayer's output before it joins the residual stream, the
+    # attention's scale in place of ``head_dim ** -0.5``, and what the
+    # logits are divided by.  0: absent, and skipped in Python, not
+    # multiplied by 1 (models/decode.py applies each in one place)
+    embedding_multiplier: float = 0.0
+    residual_multiplier: float = 0.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 0.0
     # latent attention (models/latent.py; DeepSeek-V2's MLA, whose keys these
     # are): ``kv_lora_rank`` > 0 turns it on.  Queries and keys have heads
     # of ``qk_nope_head_dim + qk_rope_head_dim``, values of ``v_head_dim``;
@@ -192,6 +201,9 @@ class TransformerConfig:
 
     #: a pattern's kinds of layer
     KINDS = ("linear", "ssm", "full", "window", "mlp")
+    #: the published scalars, 0 where absent
+    MULTIPLIERS = ("embedding_multiplier", "residual_multiplier",
+                   "attention_multiplier", "logits_scaling")
 
     def __post_init__(self):
         pat = self.layer_pattern
@@ -202,7 +214,7 @@ class TransformerConfig:
                                 "linear_decay_per_channel",
                                 "linear_gate_rank", "ssm_groups", "mlp_act",
                                 "sliding_window", "rope_window_only",
-                                "mtp_layers")
+                                "mtp_layers", *self.MULTIPLIERS)
                     if getattr(self, f)]
             if only:
                 raise ValueError(f"{only} are wired for a layer_pattern only "
@@ -250,6 +262,13 @@ class TransformerConfig:
                 f"mtp_layers under {pat}: a rejected draft is rolled out of "
                 "rows and rings by their length, not out of a recurrent "
                 "state, and the block is a layer with its MLP beneath")
+        if any(getattr(self, f) < 0 for f in self.MULTIPLIERS):
+            raise ValueError(f"{self.MULTIPLIERS}: 0 (absent) or more")
+        if self.residual_multiplier and self.norm_on_output:
+            raise ValueError(
+                "residual_multiplier scales a pre-norm sublayer's output "
+                "(x + m f(norm(x))), not one normed on its way out "
+                "(norm_on_output)")
         if self.mlp_act not in ("", "relu2"):
             raise ValueError(f"mlp_act {self.mlp_act!r}: '' or 'relu2'")
         if self.linear_gate_rank < 0 or self.attn_head_dim < 0:
@@ -351,6 +370,12 @@ class TransformerConfig:
                 "latent attention has no one head_dim: qk_head_dim for "
                 "queries and keys, v_head_dim for values")
         return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def attn_scale(self) -> float:
+        """What the scores ``q k^T`` are multiplied by: the published
+        ``attention_multiplier``, or ``head_dim ** -0.5``."""
+        return self.attention_multiplier or self.head_dim ** -0.5
 
     @property
     def qk_head_dim(self) -> int:
@@ -490,7 +515,7 @@ class TransformerConfig:
                 + (self.full_layers + self.window_layers) * attn
                 + (self.mlp_layers - self.dense_prefix_layers) * mlp
                 + self.dense_prefix_layers * dense + block
-                + 2 * self.vocab_size * h)
+                + self.vocab_size * h * (1 if self.tied_embeddings else 2))
 
     def _latent_tree_params(self, routed: float) -> float:
         """Matrix parameters of ``models/latent.py``'s tree (latent
@@ -553,7 +578,9 @@ class TransformerConfig:
                      * self.linear_key_dim * self.linear_value_dim)
             met = (self.experts_per_token * self.experts_held
                    / self.num_experts if self.moe_dropless else 0.0)
-            return (6.0 * (self._pattern_params(met) - self.vocab_size * h)
+            emb = self.vocab_size * h * (1 if self.tied_embeddings else 2)
+            return (6.0 * (self._pattern_params(met) - emb
+                           + self.vocab_size * h)
                     + 6.0 * self.full_layers * 2 * s
                     * self.num_heads * self.head_dim + 3.0 * state)
         attn = L * (h * h + 2 * h * self.num_kv_heads * self.head_dim + h * h)

@@ -308,7 +308,7 @@ def masked_attention(q, k, v, positions, cfg: TransformerConfig):
     reps = cfg.num_heads // cfg.num_kv_heads
     qh = q.reshape(r, w, cfg.num_kv_heads, reps, cfg.head_dim)
     scores = jnp.einsum("rwgpd,rmgd->rwgpm", qh.astype(jnp.float32),
-                        k.astype(jnp.float32)) * cfg.head_dim ** -0.5
+                        k.astype(jnp.float32)) * cfg.attn_scale
     if cfg.attn_logit_softcap:
         c = cfg.attn_logit_softcap
         scores = c * jnp.tanh(scores / c)
@@ -379,12 +379,17 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     mixer is handed the layer's index among its
     kind's cache rows, an expert layer its rank among the expert layers.  A
     block is wired
-    ``x + f(norm(x))``, ``x + norm(f(x))`` under ``cfg.norm_on_output``, or
+    ``x + f(norm(x))`` (``x + m f(norm(x))`` with ``cfg.residual_multiplier``
+    m), ``x + norm(f(x))`` under ``cfg.norm_on_output``, or
     with ``cfg.hc_mult`` residual streams, read, written and mixed around
     the sublayer by per-token coefficients (``latent.hc_coeff``), for the
     mixer and the MLP alike.  ``live`` [rows, W] are the tokens that count
     (None: all); only a dropless expert layer asks, so that a padded position
-    or an idle slot is routed nowhere.
+    or an idle slot is routed nowhere.  The configuration's other published
+    scalars are applied here too, each where it is not 0: the embedding is
+    multiplied by ``cfg.embedding_multiplier``, the head's logits are divided
+    by ``cfg.logits_scaling`` (``lm_head_logits``), and the mixers read
+    ``cfg.attn_scale``.
 
     Returns (logits float32, carry, ys): logits [rows, W, V], or [rows, V]
     of position ``pick`` [rows] of each row, or with ``head=False`` what the
@@ -400,8 +405,12 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     pattern, blocks = cfg.layer_pattern or ("full",), params["blocks"]
     per_period = {kind: pattern.count(kind) for kind in mixers}
     prefix = cfg.dense_prefix_layers
-    x = (tokens if jnp.issubdtype(tokens.dtype, jnp.floating)
-         else params["embed"]["tokens"][tokens]).astype(cast)
+    if jnp.issubdtype(tokens.dtype, jnp.floating):
+        x = tokens.astype(cast)
+    else:
+        x = params["embed"]["tokens"][tokens].astype(cast)
+        if cfg.embedding_multiplier:
+            x = x * cfg.embedding_multiplier
     if cfg.learned_positions:
         x = x + params["embed"]["pos"][
             jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
@@ -434,7 +443,8 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
                         lambda out: latent.hc_write(x, out, post, res))
             if cfg.norm_on_output:
                 return x, lambda out: x + norm(out, name)
-            return norm(x, name), lambda out: x + out
+            m = cfg.residual_multiplier     # 0: absent, and skipped
+            return norm(x, name), lambda out: x + (out * m if m else out)
 
         branch = _BRANCH_NORM.get(kind)
         rows = routed = None
@@ -574,7 +584,8 @@ def prefill_attention(y, ap, cfg: TransformerConfig, positions,
     q, k, v = _qkv(y, ap, cfg, positions, kind)
     with jax.named_scope("window_attn" if kind == "window" else "attn"):
         attn = mha(q, k, v, causal=True, logit_softcap=cfg.attn_logit_softcap,
-                   window=cfg.sliding_window if kind == "window" else 0)
+                   window=cfg.sliding_window if kind == "window" else 0,
+                   scale=cfg.attn_scale)
     return _proj_out(attn.reshape(b, s, -1), ap, y.dtype, y), k, v
 
 
@@ -640,7 +651,8 @@ def continued_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, slot,
             for a, rows in ((k_all, k), (v_all, v)))
     with jax.named_scope("attn"):
         attn = flash_attention_rows(q, k_all, v_all, i, slot, start, span,
-                                    cfg.num_kv_heads, cfg.attn_logit_softcap)
+                                    cfg.num_kv_heads, cfg.attn_logit_softcap,
+                                    scale=cfg.attn_scale)
     return _proj_out(attn, ap, y.dtype, y), k_all, v_all
 
 
@@ -927,7 +939,7 @@ def decode_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
         y, ap, cfg, k_all, v_all, i, lengths, active, "full",
         lambda q, k_all, v_all, live, w: decode_attn(
             q, k_all, v_all, i, live, cfg.num_kv_heads,
-            cfg.attn_logit_softcap, tokens=w))
+            cfg.attn_logit_softcap, tokens=w, scale=cfg.attn_scale))
 
 
 def ring_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
@@ -944,7 +956,7 @@ def ring_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
         y, ap, cfg, k_all, v_all, i, lengths, active, "window",
         lambda q, k_all, v_all, live, w: window_decode_attn(
             q, k_all, v_all, i, live, cfg.num_kv_heads, cfg.sliding_window,
-            w))
+            w, scale=cfg.attn_scale))
 
 
 def _step_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,

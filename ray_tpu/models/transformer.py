@@ -447,8 +447,10 @@ def lm_head_weight(params: Params, cfg: TransformerConfig, dtype) -> jnp.ndarray
 def lm_head_logits(params: Params, x: jnp.ndarray,
                    cfg: TransformerConfig) -> jnp.ndarray:
     """Hidden states [..., H] -> f32 logits [..., V], the head applied in
-    the hidden states' own dtype."""
-    return (x @ lm_head_weight(params, cfg, x.dtype)).astype(jnp.float32)
+    the hidden states' own dtype; divided by ``cfg.logits_scaling`` where
+    the configuration has one."""
+    logits = (x @ lm_head_weight(params, cfg, x.dtype)).astype(jnp.float32)
+    return logits / cfg.logits_scaling if cfg.logits_scaling else logits
 
 
 def apply(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,

@@ -19,6 +19,13 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def score_scale(scale: Optional[float], head_dim: int) -> float:
+    """What the scores ``q k^T`` are multiplied by: ``scale`` where a caller
+    gives one (a configuration's ``attn_scale``), else ``head_dim ** -0.5``;
+    read by every attention here and in the kernels' files."""
+    return head_dim ** -0.5 if scale is None else scale
+
+
 def repeat_kv(k: jnp.ndarray, num_heads: int) -> jnp.ndarray:
     """[B, S, KV, D] -> [B, S, H, D] by repeating kv heads (GQA)."""
     num_kv = k.shape[2]
@@ -32,8 +39,10 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
            causal: bool = True,
            q_offset: int | jnp.ndarray = 0,
            kv_offset: int | jnp.ndarray = 0,
-           logit_softcap: float = 0.0, window: int = 0) -> jnp.ndarray:
+           logit_softcap: float = 0.0, window: int = 0,
+           scale: Optional[float] = None) -> jnp.ndarray:
     """Plain attention. q: [B, Sq, H, D], k/v: [B, Skv, KV, D] -> [B, Sq, H, D].
+    ``scale`` multiplies the scores (None: ``D ** -0.5``).
 
     ``q_offset``/``kv_offset`` are the global positions of the first query/key —
     used by ring attention where each device holds a sequence shard.
@@ -42,7 +51,7 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     num_heads = q.shape[2]
     k = repeat_kv(k, num_heads)
     v = repeat_kv(v, num_heads)
-    scale = q.shape[-1] ** -0.5
+    scale = score_scale(scale, q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k.astype(q.dtype)) * scale
     if logit_softcap > 0:
         logits = jnp.tanh(logits / logit_softcap) * logit_softcap
@@ -96,11 +105,13 @@ def finalize_blockwise(m, l, o):
 
 def mha(q, k, v, causal: bool = True, logit_softcap: float = 0.0,
         use_flash: Optional[bool] = None, mesh=None,
-        batch_axes: Tuple[str, ...] = ("dp", "fsdp"), window: int = 0):
+        batch_axes: Tuple[str, ...] = ("dp", "fsdp"), window: int = 0,
+        scale: Optional[float] = None):
     """Dispatch between the Pallas flash kernel (TPU, long seq) and plain XLA.
     q, k: [B, S, heads, Dqk]; v: [B, S, KV, Dv], as wide as q or not.
     ``window`` > 0: a query reads its last ``window`` positions (the
-    kernel's banded forward, or the plain path's mask).
+    kernel's banded forward, or the plain path's mask).  ``scale``
+    multiplies the scores (None: ``Dqk ** -0.5``).
 
     ``use_flash=None`` chooses from what it can observe: the backend and the
     shape.  ``mesh``/``batch_axes`` go to the kernel, which must be
@@ -122,6 +133,7 @@ def mha(q, k, v, causal: bool = True, logit_softcap: float = 0.0,
                              " let the dispatcher choose)")
         from .flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, mesh=mesh,
-                               batch_axes=batch_axes, window=window)
+                               batch_axes=batch_axes, window=window,
+                               scale=scale)
     return attend(q, k, v, causal=causal, logit_softcap=logit_softcap,
-                  window=window)
+                  window=window, scale=scale)

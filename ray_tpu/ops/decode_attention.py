@@ -66,6 +66,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .attention import score_scale
 from .flash_attention import resolve_interpret
 
 #: the kernel's name as a device trace shows it (``decode_attn [pallas]``);
@@ -110,10 +111,11 @@ def _softcap(s, softcap: float):
 # ---------------------------------------------------------------------------
 
 def _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads: int,
-                 softcap: float, tokens: int):
+                 softcap: float, tokens: int, scale: Optional[float] = None):
     """Plain attention of ``tokens`` queries a slot over one layer's rows,
     heads apart.  q: [slots, tokens * NH, D], token-major; seen: [slots,
-    tokens, rows], the rows each query reads."""
+    tokens, rows], the rows each query reads; ``scale`` multiplies the
+    scores (None: ``D ** -0.5``, here and in every function below)."""
     slots, rows, hd = q.shape
     nh, span = rows // tokens, k_all.shape[2]
     k, v = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
@@ -121,7 +123,7 @@ def _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads: int,
             for a in (k_all, v_all))
     qh = q.reshape(slots, tokens, num_kv_heads, nh // num_kv_heads, hd)
     s = jnp.einsum("swgrd,smgd->swgrm", qh.astype(k.dtype), k,
-                   preferred_element_type=F32) * hd ** -0.5
+                   preferred_element_type=F32) * score_scale(scale, hd)
     seen = seen[:, :, None, None, :]
     s = jnp.where(seen, _softcap(s, softcap), NEG)
     p = jnp.where(seen, jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
@@ -132,13 +134,14 @@ def _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads: int,
 
 
 def decode_attn_jnp(q, k_all, v_all, layer, live, num_kv_heads: int,
-                    softcap: float = 0.0, tokens: int = 1):
+                    softcap: float = 0.0, tokens: int = 1,
+                    scale: Optional[float] = None):
     """The twin of ``decode_attn``; shapes as there."""
     # query j of a slot reads the positions before live - (tokens - 1 - j)
     edge = live[:, None] - (tokens - 1 - jnp.arange(tokens))[None]
     seen = jnp.arange(k_all.shape[2])[None, None] < edge[..., None]
     return _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads, softcap,
-                        tokens)
+                        tokens, scale)
 
 
 def ring_positions(newest, ring: int):
@@ -151,14 +154,15 @@ def ring_positions(newest, ring: int):
 
 
 def window_decode_attn_jnp(q, k_all, v_all, layer, live, num_kv_heads: int,
-                           window: int, tokens: int = 1):
+                           window: int, tokens: int = 1,
+                           scale: Optional[float] = None):
     """The twin of ``window_decode_attn``; shapes as there."""
     held = ring_positions(live - 1, k_all.shape[2])[:, None]  # [slots,1,ring]
     at = (live[:, None] - (tokens - jnp.arange(tokens))[None])[..., None]
     seen = (held >= 0) & (held <= at) & (at - held < window) \
         & (live > 0)[:, None, None]
     return _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads, 0.0,
-                        tokens)
+                        tokens, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +315,8 @@ def _query_rows(q, nh: int, num_kv_heads: int, chan: int, dtype):
 
 
 def _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads: int,
-                        softcap: float, interpret: bool, tokens: int = 1):
+                        softcap: float, interpret: bool, tokens: int = 1,
+                        scale: Optional[float] = None):
     slots, q_rows, hd = q.shape
     nh = q_rows // tokens
     max_len, chan = k_all.shape[2:]
@@ -331,8 +336,9 @@ def _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads: int,
 
     out = pl.pallas_call(
         functools.partial(_kernel, block=block, heads_a_group=reps,
-                          num_kv_heads=num_kv_heads, scale=hd ** -0.5,
-                          softcap=softcap, heads=nh, tokens=tokens),
+                          num_kv_heads=num_kv_heads,
+                          scale=score_scale(scale, hd), softcap=softcap,
+                          heads=nh, tokens=tokens),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             # as long as the work list; with nothing live, one step that
@@ -363,7 +369,8 @@ def _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads: int,
 
 def _window_decode_attn_pallas(q, k_all, v_all, layer, live,
                                num_kv_heads: int, window: int, tokens: int,
-                               interpret: bool):
+                               interpret: bool,
+                               scale: Optional[float] = None):
     slots, rows, hd = q.shape
     nh = rows // tokens
     ring, chan = k_all.shape[2:]
@@ -382,8 +389,9 @@ def _window_decode_attn_pallas(q, k_all, v_all, layer, live,
     out = pl.pallas_call(
         functools.partial(_ring_kernel, ring=ring, window=window,
                           heads_a_group=nh // num_kv_heads,
-                          num_kv_heads=num_kv_heads, scale=hd ** -0.5,
-                          heads=nh, tokens=tokens),
+                          num_kv_heads=num_kv_heads,
+                          scale=score_scale(scale, hd), heads=nh,
+                          tokens=tokens),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(jnp.maximum(total, 1),),
@@ -414,7 +422,8 @@ def _takes_kernel(use_kernel: Optional[bool], interpret: Optional[bool]):
 def window_decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
                        window: int, tokens: int = 1,
                        use_kernel: Optional[bool] = None,
-                       interpret: Optional[bool] = None):
+                       interpret: Optional[bool] = None,
+                       scale: Optional[float] = None):
     """Attention of ``tokens`` new tokens a slot over layer ``layer`` of a
     stack of rings, each query over the ``window`` positions up to its own.
 
@@ -426,15 +435,16 @@ def window_decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
     Returns [slots, tokens * NH, D] in q's dtype."""
     if not _takes_kernel(use_kernel, interpret):
         return window_decode_attn_jnp(q, k_all, v_all, layer, live,
-                                      num_kv_heads, window, tokens)
+                                      num_kv_heads, window, tokens, scale)
     return _window_decode_attn_pallas(
         q, k_all, v_all, layer, live, num_kv_heads, window, tokens,
-        resolve_interpret(interpret, "window_decode_attn"))
+        resolve_interpret(interpret, "window_decode_attn"), scale)
 
 
 def decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
                 softcap: float = 0.0, use_kernel: Optional[bool] = None,
-                interpret: Optional[bool] = None, tokens: int = 1):
+                interpret: Optional[bool] = None, tokens: int = 1,
+                scale: Optional[float] = None):
     """Attention of one new token a slot (or ``tokens``) over layer
     ``layer`` of the stacked cache.
 
@@ -443,6 +453,7 @@ def decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
     tokens' own rows already written; layer: int32 scalar (traced or not);
     live: [slots] int32, the positions of each slot that count, the new
     tokens' among them (0: the slot is inactive, its output is zeros).
+    ``scale`` multiplies the scores (None: ``D ** -0.5``).
     Returns an array like q.  Only the live blocks of ``layer``
     are read: no slab leaves the stack.
 
@@ -452,10 +463,10 @@ def decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
     ``interpret=True`` runs the kernel interpreted (tests)."""
     if not _takes_kernel(use_kernel, interpret):
         return decode_attn_jnp(q, k_all, v_all, layer, live, num_kv_heads,
-                               softcap, tokens)
+                               softcap, tokens, scale)
     interpret = resolve_interpret(interpret, "decode_attn")
     return _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads,
-                               softcap, interpret, tokens)
+                               softcap, interpret, tokens, scale)
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from .attention import score_scale
+
 NEG_INF = -1e30
 #: resident K and V bytes up to which the forward kernel fits the compiler's
 #: default scoped VMEM (16 MiB) with its blocks and products
@@ -65,6 +67,7 @@ BWD_VMEM_BLOCKS = 12 << 20
 BWD_VMEM_REACH = 64 << 20
 #: the forward kernel where its queries start after what a slot of the
 #: stacked cache already holds (``flash_attention_rows``), as a trace shows it
+KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_ROWS = "flash_fwd_rows"
 #: the forward kernel with a band (``flash_attention(window=...)``): a
 #: query reads its last ``window`` positions and a query block the KV
@@ -155,15 +158,17 @@ def _fwd_vmem(kv_len: int, d_qk: int, d_v: int, dtype) -> dict:
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
-               interpret: bool, window: int = 0):
+               interpret: bool, window: int = 0,
+               scale: Optional[float] = None):
     """q: [B, H, S, Dqk], k: [B, KV, S, Dqk], v: [B, KV, S, Dv] -> (out [B,
     H, S, Dv], lse [B, H, S]).  ``window``: the band (``_fwd_kernel``), a
-    kernel of its own name."""
+    kernel of its own name; ``scale``: what multiplies the scores (None:
+    ``Dqk ** -0.5``)."""
     b, h, s, d = q.shape
     d_v = v.shape[3]
     kv_heads = k.shape[1]
     reps = h // kv_heads
-    scale = d ** -0.5
+    scale = score_scale(scale, d)
     block_q = min(block_q, s)
     block_kv = min(block_kv, s)
 
@@ -190,7 +195,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
             jax.ShapeDtypeStruct((b, h, 8, s), jnp.float32),
         ],
         interpret=interpret,
-        name=KERNEL_FLASH_WINDOW if window else "flash_fwd",
+        name=KERNEL_FLASH_WINDOW if window else KERNEL_FLASH_FWD,
     )(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -202,7 +207,8 @@ def _fwd_rows_kernel(at_ref, *refs, **static):
 
 
 def _flash_fwd_rows(q, k_all, v_all, at, num_heads: int, kv_len: int,
-                    block_q: int, block_kv: int, interpret: bool):
+                    block_q: int, block_kv: int, interpret: bool,
+                    scale: Optional[float] = None):
     """The forward kernel on rows where they lie; at: int32 [3], (layer,
     slot, the first query's position).  Keys and queries are as wide as
     each other, values and the output as each other; both widths are read
@@ -243,7 +249,7 @@ def _flash_fwd_rows(q, k_all, v_all, at, num_heads: int, kv_len: int,
 
     out, _ = pl.pallas_call(
         functools.partial(_fwd_rows_kernel, block_kv=block_kv, seq_kv=kv_len,
-                          causal=True, scale=d ** -0.5),
+                          causal=True, scale=score_scale(scale, d)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(1, num_heads, w // block_q),
@@ -261,7 +267,8 @@ def _flash_fwd_rows(q, k_all, v_all, at, num_heads: int, kv_len: int,
     return out[0] if apart else out[0, 0]
 
 
-def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
+def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int,
+                   scale: Optional[float] = None):
     """Flash backward, recompute-based, as a scan over KV blocks.
 
     q: [B, H, S, Dqk]; out/g: [B, H, S, Dv]; k: [B, KV, S, Dqk]; v: [B, KV,
@@ -272,7 +279,7 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int):
     d_v = v.shape[3]
     kv_heads = k.shape[1]
     reps = h // kv_heads
-    scale = d ** -0.5
+    scale = score_scale(scale, d)
     block_kv = min(block_kv, s)
     n_blocks = s // block_kv
 
@@ -429,14 +436,15 @@ def _bwd_vmem(seq: int, reps: int, d_qk: int, dtype) -> dict:
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
-                      block_kv: int, interpret: bool):
+                      block_kv: int, interpret: bool,
+                      scale: Optional[float] = None):
     """Pallas flash backward: (dq, dk, dv), dk/dv in kv-head layout.  q, k
     (and dq, dk) are ``d`` lanes wide; v, out, g (and dv) ``d_v``."""
     b, h, s, d = q.shape
     d_v = v.shape[3]
     kv_heads = k.shape[1]
     reps = h // kv_heads
-    scale = d ** -0.5
+    scale = score_scale(scale, d)
     bq = min(block_q, s)
     bkv = min(block_kv, s)
 
@@ -482,15 +490,17 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
     )(q, k, v, gf, lse4, dlt4)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_kv, interpret):
-    out, _ = _flash_fwd(q, k, v, causal, block_q, block_kv, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, block_q, block_kv, interpret, scale=None):
+    out, _ = _flash_fwd(q, k, v, causal, block_q, block_kv, interpret,
+                        scale=scale)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, block_q, block_kv, interpret):
+def _flash_vjp_fwd(q, k, v, causal, block_q, block_kv, interpret, scale):
     from jax.ad_checkpoint import checkpoint_name
-    out, lse = _flash_fwd(q, k, v, causal, block_q, block_kv, interpret)
+    out, lse = _flash_fwd(q, k, v, causal, block_q, block_kv, interpret,
+                          scale=scale)
     # Under `jax.checkpoint(policy=save_only_these_names(...))` these names let
     # the remat replay keep the flash residuals instead of re-running the
     # forward kernel (models/transformer.py REMAT_SAVE_NAMES).
@@ -499,13 +509,13 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_kv, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_kv, interpret, res, g):
+def _flash_vjp_bwd(causal, block_q, block_kv, interpret, scale, res, g):
     q, k, v, out, lse = res
     if flash_bwd_supported(q.shape[2], q.shape[1], k.shape[1], q.shape[3],
                            q.dtype, block_q, block_kv) is None:
         return _flash_bwd_pallas(q, k, v, out, lse, g, causal, block_q,
-                                 block_kv, interpret)
-    return _bwd_blockwise(q, k, v, out, lse, g, causal, block_kv)
+                                 block_kv, interpret, scale)
+    return _bwd_blockwise(q, k, v, out, lse, g, causal, block_kv, scale)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -588,9 +598,11 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
                     block_kv: int = 512,
                     interpret: Optional[bool] = None, mesh=None,
                     batch_axes: Tuple[str, ...] = ("dp", "fsdp"),
-                    window: int = 0) -> jnp.ndarray:
+                    window: int = 0,
+                    scale: Optional[float] = None) -> jnp.ndarray:
     """Flash attention. q: [B, Sq, H, Dqk], k: [B, Skv, KV, Dqk], v: [B, Skv,
-    KV, Dv] -> [B, Sq, H, Dv].
+    KV, Dv] -> [B, Sq, H, Dv].  ``scale`` multiplies the scores (None: ``Dqk
+    ** -0.5``).
 
     Layout matches ``attention.attend``; internally transposed to [B, H, S, D]
     (the kernel wants the sequence on the sublane dim and a head's width on
@@ -628,9 +640,10 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
         heads_first = (q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2))
         if window:
             out, _ = _flash_fwd(*heads_first, True, block_q, block_kv,
-                                interpret, window)
+                                interpret, window, scale)
         else:
-            out = _flash(*heads_first, causal, block_q, block_kv, interpret)
+            out = _flash(*heads_first, causal, block_q, block_kv, interpret,
+                         scale)
         return out.swapaxes(1, 2)
 
     spec = kernel_batch_spec(mesh, batch_axes)
@@ -657,7 +670,8 @@ def flash_attention_rows(q, k_all, v_all, layer, slot, start, kv_len: int,
                          num_kv_heads: int, logit_softcap: float = 0.0,
                          block_q: int = 512, block_kv: int = 512,
                          use_kernel: Optional[bool] = None,
-                         interpret: Optional[bool] = None) -> jnp.ndarray:
+                         interpret: Optional[bool] = None,
+                         scale: Optional[float] = None) -> jnp.ndarray:
     """Causal attention of W queries of one sequence that sit at positions
     ``start .. start + W`` of a slot of stacked rows, over the slot's rows
     ``0 .. kv_len`` where they lie, the queries' own rows among them
@@ -671,7 +685,8 @@ def flash_attention_rows(q, k_all, v_all, layer, slot, start, kv_len: int,
     wide as they.  layer, slot, start: int32 scalars, traced or not;
     ``kv_len`` (static) bounds what the row may hold, ``start + W <=
     kv_len``; rows past a query's own position are masked, whatever they
-    hold.  Returns [1, W, NH * Dv] in q's dtype.
+    hold; ``scale`` multiplies the scores (None: ``Dqk ** -0.5``).  Returns
+    [1, W, NH * Dv] in q's dtype.
 
     ``use_kernel=None`` takes the kernel (bf16 operands as the cache has
     them, float32 scores and accumulator) on a TPU outside a mesh where the
@@ -695,7 +710,7 @@ def flash_attention_rows(q, k_all, v_all, layer, slot, start, kv_len: int,
             rows.swapaxes(0, 1) if apart else rows.reshape(w, -1), k_all,
             v_all, jnp.stack([layer, slot, start]).astype(jnp.int32),
             num_heads, kv_len, min(block_q, w), min(block_kv, kv_len),
-            resolve_interpret(interpret, "flash"))
+            resolve_interpret(interpret, "flash"), scale)
         if apart:
             out = out.swapaxes(0, 1).reshape(w, -1)
         return out[None].astype(q.dtype)
@@ -712,4 +727,5 @@ def flash_attention_rows(q, k_all, v_all, layer, slot, start, kv_len: int,
                 1, kv_len, num_kv_heads, -1)
 
     return attend(q, slab(k_all), slab(v_all), causal=True, q_offset=start,
-                  logit_softcap=logit_softcap).reshape(1, w, -1)
+                  logit_softcap=logit_softcap,
+                  scale=scale).reshape(1, w, -1)

@@ -16,9 +16,12 @@ No delta-rule correction and no normalised keys: the sibling of
   steps.  With ``G_i`` the running sum of ``-exp(A_log) dt`` inside a chunk,
   ``y_i = sum_{j <= i} exp(G_i - G_j) (C_i . B_j) dt_j x_j + exp(G_i) S C_i``
   and the state moves a chunk at a time: five matmuls a head a chunk, ``C
-  B^T`` once for the heads of a group, and only the chunk-to-chunk state is
-  sequential.  The running sums are taken inside the kernel (one product
-  with a triangle of ones for a group's heads), not by ``jnp.cumsum``
+  B^T`` once for the heads of a grid step (a group, or where a group is
+  wider than ``CHUNK_HEADS_A_STEP`` a block of its heads: one group of 64
+  heads is four steps that each read the group's B and C), and only the
+  chunk-to-chunk state is sequential.  The running sums are taken inside
+  the kernel (one product with a triangle of ones for a step's heads), not
+  by ``jnp.cumsum``
   before it: XLA may sum a window in another order from one program to the
   next, and the last bit of ``G`` then goes with the program a row was
   compiled in (one of two causes found for two compilations of one prefill
@@ -31,7 +34,8 @@ No delta-rule correction and no normalised keys: the sibling of
 * ``ssd_recurrent_step`` (decode) applies one step to every slot of one
   layer of a stacked state ``[layers, slots, heads, P, N]``, in place, taking
   the layer index itself (scalar prefetch), so that no layer slab is ever
-  sliced out of the stack.
+  sliced out of the stack; a grid step is ``STEP_HEADS_A_STEP`` heads at
+  most, whole groups or a block of one group's.
 
 Each kernel's math is one function on two-dimensional tiles (``_chunk_tile``,
 ``_step_tile``) that the kernel body calls on what it loaded and the twin
@@ -63,8 +67,21 @@ KERNEL_SSD_RECURRENT_STEP = "ssd_recurrent_step"
 #: counts of the kernel assume it
 CHUNK = 128
 
-#: heads a grid step of the decode kernel, at most (whole groups)
+#: heads a grid step, at most, of the decode kernel (whole groups, or a
+#: block of one group's heads where a group is wider) and of the prefill
+#: kernel (one group, or a block of its heads): ``_head_block``
 STEP_HEADS_A_STEP = 16
+CHUNK_HEADS_A_STEP = 16
+
+
+def _head_block(per: int, limit: int) -> int:
+    """Heads a grid step of a group of ``per``: the group where it is no
+    wider than ``limit``, else the most up to ``limit`` that divide it, so
+    that a block of heads lies in one group and reads that group's B and
+    C."""
+    if per <= limit:
+        return per
+    return max(h for h in range(1, limit + 1) if per % h == 0)
 
 
 def _mm_nt32(a, b):
@@ -159,13 +176,14 @@ def ssd_recurrence(x, dt, a_log, b, c, d, initial_state=None):
 # Prefill: chunked forward
 # ---------------------------------------------------------------------------
 
-def _chunk_inputs(x, dt, a_log, b, c, d, lengths):
+def _chunk_inputs(x, dt, a_log, b, c, d, lengths, hb=None):
     """Mask positions at or beyond ``lengths`` (dt 0), pad the time axis to
     whole chunks and lay the channels side by side (the heads of a group
-    are neighbours).  Returns (x [B, T, H P], b, c [B, T, G N], the log
-    decay a step [B, G, H / G, T], dt [B, G, T, H / G], d [1, H P])."""
+    are neighbours).  ``hb``: heads a block, a divisor of a group's (None:
+    the group's).  Returns (x [B, T, H P], b, c [B, T, G N], the log
+    decay a step [B, H / hb, hb, T], dt [B, H / hb, T, hb], d [1, H P])."""
     bsz, t, nh, p = x.shape
-    groups = b.shape[2]
+    hb = hb or nh // b.shape[2]
     dt = dt.astype(F32)
     if lengths is not None:
         dt = jnp.where((jnp.arange(t)[None, :] < lengths[:, None])[..., None],
@@ -175,12 +193,12 @@ def _chunk_inputs(x, dt, a_log, b, c, d, lengths):
         x, dt, b, c = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
                                * (a.ndim - 2)) for a in (x, dt, b, c))
     t += pad
-    by_group = lambda a: a.reshape(                               # noqa: E731
-        bsz, t, groups, nh // groups).swapaxes(1, 2)
+    by_block = lambda a: a.reshape(                               # noqa: E731
+        bsz, t, nh // hb, hb).swapaxes(1, 2)
     return (x.reshape(bsz, t, nh * p), b.reshape(bsz, t, -1),
             c.reshape(bsz, t, -1),
-            by_group(-jnp.exp(a_log.astype(F32)) * dt).swapaxes(2, 3),
-            by_group(dt), jnp.repeat(d.astype(F32), p)[None])
+            by_block(-jnp.exp(a_log.astype(F32)) * dt).swapaxes(2, 3),
+            by_block(dt), jnp.repeat(d.astype(F32), p)[None])
 
 
 def ssd_chunk_fwd_jnp(x, dt, a_log, b, c, d, lengths=None):
@@ -222,8 +240,10 @@ def ssd_chunk_fwd_jnp(x, dt, a_log, b, c, d, lengths=None):
 
 def _chunk_kernel(x_ref, b_ref, c_ref, gl_ref, dt_ref, d_ref, y_ref, s_out,
                   s_ref, *, heads: int, p: int):
-    """Grid (batch, groups, chunks), chunks innermost and sequential: a
-    group's states live in ``s_ref`` (VMEM scratch) across a row's chunks."""
+    """Grid (batch, head blocks, chunks), chunks innermost and sequential:
+    the states of a block's ``heads`` heads (one group's, or a part of one's)
+    live in ``s_ref`` (VMEM scratch) across a row's chunks; ``b_ref`` and
+    ``c_ref`` are the block's group's."""
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -231,7 +251,7 @@ def _chunk_kernel(x_ref, b_ref, c_ref, gl_ref, dt_ref, d_ref, y_ref, s_out,
         s_ref[...] = jnp.zeros_like(s_ref)
 
     b, c = b_ref[0], c_ref[0]
-    cb = _mm_nt(c, b)                       # once for the heads of the group
+    cb = _mm_nt(c, b)                       # once for the heads of the block
     g, dt = _running_sums(gl_ref[0, 0]), dt_ref[0, 0]
     for i in range(heads):
         cols = slice(i * p, (i + 1) * p)
@@ -249,32 +269,36 @@ def _chunk_fwd_pallas(x, dt, a_log, b, c, d, lengths, interpret: bool):
     bsz, t, nh, p = x.shape
     groups, n = b.shape[2:]
     per = nh // groups
+    hb = _head_block(per, CHUNK_HEADS_A_STEP)
+    blocks = per // hb              # of a group; 1: a block is its group
     x2, b2, c2, gl_t, dts, d_row = _chunk_inputs(x, dt, a_log, b, c, d,
-                                                 lengths)
+                                                 lengths, hb)
     chunks = x2.shape[1] // CHUNK
-    wide = lambda bi, gi, ci: (bi, ci, gi)          # noqa: E731
+    wide = lambda bi, hi, ci: (bi, ci, hi)          # noqa: E731
+    shared = wide if blocks == 1 else (             # the block's group's
+        lambda bi, hi, ci: (bi, ci, hi // blocks))
     y, s = pl.pallas_call(
-        functools.partial(_chunk_kernel, heads=per, p=p),
-        grid=(bsz, groups, chunks),
+        functools.partial(_chunk_kernel, heads=hb, p=p),
+        grid=(bsz, nh // hb, chunks),
         in_specs=[
-            pl.BlockSpec((1, CHUNK, per * p), wide),
-            pl.BlockSpec((1, CHUNK, n), wide),
-            pl.BlockSpec((1, CHUNK, n), wide),
-            pl.BlockSpec((1, 1, per, CHUNK),
-                         lambda bi, gi, ci: (bi, gi, 0, ci)),
-            pl.BlockSpec((1, 1, CHUNK, per),
-                         lambda bi, gi, ci: (bi, gi, ci, 0)),
-            pl.BlockSpec((1, per * p), lambda bi, gi, ci: (0, gi)),
+            pl.BlockSpec((1, CHUNK, hb * p), wide),
+            pl.BlockSpec((1, CHUNK, n), shared),
+            pl.BlockSpec((1, CHUNK, n), shared),
+            pl.BlockSpec((1, 1, hb, CHUNK),
+                         lambda bi, hi, ci: (bi, hi, 0, ci)),
+            pl.BlockSpec((1, 1, CHUNK, hb),
+                         lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, hb * p), lambda bi, hi, ci: (0, hi)),
         ],
         out_specs=[
-            pl.BlockSpec((1, CHUNK, per * p), wide),
-            pl.BlockSpec((1, per, p, n), lambda bi, gi, ci: (bi, gi, 0, 0)),
+            pl.BlockSpec((1, CHUNK, hb * p), wide),
+            pl.BlockSpec((1, hb, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(x2.shape, x.dtype),
             jax.ShapeDtypeStruct((bsz, nh, p, n), F32),
         ],
-        scratch_shapes=[pltpu.VMEM((per, p, n), F32)],
+        scratch_shapes=[pltpu.VMEM((hb, p, n), F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -343,9 +367,12 @@ def _step_kernel(layer_ref, a_ref, dt_ref, d_ref, s_in, x_ref, b_ref, c_ref,
 
 def _heads_a_step(nh: int, per: int) -> int:
     """Heads a grid step: whole groups, the most up to the limit that
-    divide the heads."""
-    limit = max(STEP_HEADS_A_STEP, per)
-    return max(h for h in range(per, limit + 1, per) if nh % h == 0)
+    divide the heads, or of a group wider than the limit a block of its
+    heads (``_head_block``)."""
+    if per > STEP_HEADS_A_STEP:
+        return _head_block(per, STEP_HEADS_A_STEP)
+    return max(h for h in range(per, STEP_HEADS_A_STEP + 1, per)
+               if nh % h == 0)
 
 
 def _recurrent_step_pallas(state, layer, x, dt, a_log, b, c, d,
@@ -354,9 +381,13 @@ def _recurrent_step_pallas(state, layer, x, dt, a_log, b, c, d,
     per = nh // b.shape[1]
     hb = _heads_a_step(nh, per)
     ng = nh // hb
+    gs = max(hb // per, 1)          # groups a step; 1 where a step is a
+    blocks = max(per // hb, 1)      # block of a group of ``blocks`` blocks
     x = x.reshape(slots, ng, hb, p)
-    b, c = (a.reshape(slots, ng, hb // per, n) for a in (b, c))
+    b, c = (a.reshape(slots, -1, gs, n) for a in (b, c))
     small = lambda si, gi, lyr: (si, gi, 0, 0)          # noqa: E731
+    shared = small if blocks == 1 else (                # the block's group's
+        lambda si, gi, lyr: (si, gi // blocks, 0, 0))
     big = lambda si, gi, lyr: (lyr[0], si, gi, 0, 0)    # noqa: E731
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     state, y = pl.pallas_call(
@@ -368,8 +399,8 @@ def _recurrent_step_pallas(state, layer, x, dt, a_log, b, c, d,
                 smem, smem, smem,
                 pl.BlockSpec((1, 1, hb, p, n), big),
                 pl.BlockSpec((1, 1, hb, p), small),
-                pl.BlockSpec((1, 1, hb // per, n), small),
-                pl.BlockSpec((1, 1, hb // per, n), small),
+                pl.BlockSpec((1, 1, gs, n), shared),
+                pl.BlockSpec((1, 1, gs, n), shared),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, hb, p, n), big),

@@ -607,6 +607,108 @@ KINDS = {k.name: k for k in (
                         "linear_layers": 0, "window_layers": 6,
                         "full_layers": 2, "cache_latent_bytes": 0,
                         "expert_layers": 7, "experts_held": 8})),
+    Kind(
+        name="granitemoehybrid", tiny="tiny-granite.json",
+        cell="granite-4.0-h-micro-serve-l40",
+        # two rows of other lengths into slots 2 and 0, then decode steps
+        # with an idle slot between; float32 both sides, the four
+        # multipliers no powers of two
+        parity=dict(seed=1,
+                    draw=lambda rng: list(rng.integers(1, 256, (2, 40))),
+                    lens=[29, 18], slots=[2, 0], n_slots=3, max_len=64,
+                    bucket=32, steps=8, atol=2e-4, ref_kw={}),
+        # 5 cache rows (4 slots + scratch), float32: the state and the
+        # convolution tail of 6 state-space layers (one group of 8 heads),
+        # K and V of 2 attention layers of 2 heads of 16
+        engine=dict(kw=dict(num_slots=4, max_len=64, buckets=(32, 64),
+                            steps_per_dispatch=2),
+                    seed=1, lens=(11,), max_tokens=6,
+                    gauges={"experts_held": 0, "expert_layers": 0,
+                            "linear_layers": 0, "ssm_layers": 6,
+                            "full_layers": 2,
+                            "cache_state_bytes": 6 * 5 * (
+                                8 * 16 * 32 * 4
+                                + 3 * (8 * 16 + 2 * 1 * 32) * 4),
+                            "cache_kv_bytes": 2 * 2 * 5 * 64 * 2 * 16 * 4,
+                            "cache_latent_bytes": 0}),
+        # the state-space mixer's pieces under ``ssm`` / ``ssm_conv``, the
+        # dense MLP beneath every mixer under ``mlp``; no expert's scope
+        scopes=dict(
+            both={"attn", "norm", "lm_head", "mlp", "kv_write", "ssm",
+                  "ssm_conv", "state_write"},
+            decode={"kv_read", "state_read"},
+            neither={"moe_route", "moe_experts"},
+            stats=("cache_kv_bytes", "cache_state_bytes", "linear_layers",
+                   "ssm_layers", "full_layers")),
+        train_refusal="layer_pattern",
+        engine_refusals=_PAGED_SPEC_TP("page arena", "rolled out",
+                                       "sharding rule"),
+        kernels=(("ssd", "KERNEL_SSD_CHUNK_FWD", "ssd_chunk_fwd"),
+                 ("ssd", "KERNEL_SSD_RECURRENT_STEP", "ssd_recurrent_step"),
+                 ("decode_attention", "KERNEL_DECODE_ATTN", "decode_attn"),
+                 ("flash_attention", "KERNEL_FLASH_FWD", "flash_fwd")),
+        readers=(("_ssd.py", "CHUNK_FWD", "ssd_chunk_fwd"),
+                 ("_ssd.py", "RECURRENT_STEP", "ssd_recurrent_step"),
+                 ("decode_attn_roofline.py", "DECODE_ATTN", "decode_attn"),
+                 ("ssm_dense_kernels_device_share.py", "KERNELS",
+                  ("ssd_chunk_fwd", "ssd_recurrent_step", "decode_attn",
+                   "flash_fwd"))),
+        kind_refusals=(
+            ("routed-experts", dict(num_local_experts=4,
+                                    num_experts_per_tok=2),
+             "no routed experts"),
+            ("another-activation", dict(hidden_act="gelu"), "hidden_act"),
+            ("no-conv-bias", dict(mamba_conv_bias=False), "mamba_conv_bias"),
+            ("biases", dict(attention_bias=True), "attention_bias"),
+            ("an-untied-head", dict(tie_word_embeddings=False),
+             "tie_word_embeddings"),
+            ("rotary", dict(position_embedding_type="rope"),
+             "position_embedding_type"),
+            ("scaled-rotary", dict(rope_scaling={"type": "linear"}),
+             "rope_scaling"),
+            ("a-kind-it-does-not-know", dict(
+                layer_types=["mamba", "sliding"] * 4), "layer_types"),
+            ("layers-of-another-depth", dict(num_hidden_layers=9),
+             "num_hidden_layers"),
+            ("heads-not-whole-groups", dict(mamba_n_groups=3),
+             "whole groups"),
+            ("an-inner-width-that-is-not-the-heads", dict(mamba_expand=3),
+             "mamba_expand"),
+            ("a-multiplier-of-zero", dict(residual_multiplier=0.0),
+             "positive")),
+        config_refusals=(None, (
+            ("a-negative-multiplier", dict(logits_scaling=-1.0),
+             "or more"),
+            ("a-residual-multiplier-on-a-normed-output",
+             dict(norm_on_output=True), "norm_on_output"),
+            ("multipliers-without-a-pattern", dict(layer_pattern=()),
+             "layer_pattern only"),
+            ("two-recurrent-kinds",
+             dict(layer_pattern=("ssm", "linear", "full", "ssm")),
+             "one recurrent kind"))),
+        # under 15.0 GiB (readings 13.83 and 14.29 GiB in place).  The
+        # temporaries' readings 1.308 and 1.800 GB, of which 1.255 GB is
+        # the chip's copy of the ``W_in`` stack [4, 9, 2048, 8512], which
+        # it lays out with the 2,048 rows minor (8,512 is no multiple of
+        # 128 lanes).  decode: a recurrent step a state-space layer of the
+        # period and decode_attn; prefill: a chunked forward each and
+        # flash_fwd
+        cell_programs=(("decode", 1.5, 9 + 1), ("prefill-4096", 2.0, 9 + 1)),
+        stacks=("bf16[4,65,4096,512]", "f32[36,65,64,64,128]"),
+        # no layer's [slots, 64, 64, 128] slab is sliced out of the state
+        held_in_place=(r"= f32\[(1,)?65,64,64,128\]\S* "
+                       r"(dynamic-slice|copy)\(",),
+        counts=dict(slots=65, num_params=3_191_396_096,
+                    per={"mamba": 25_821_184, "attention": 10_485_760,
+                         "mlp": 50_331_648},
+                    gauges=lambda kind, doc: {
+                        "cache_kv_bytes": 65 * 4096 * 8192,
+                        "cache_state_bytes": 65 * (
+                            kind.state_bytes_per_slot(doc)
+                            + 36 * 3 * 4352 * 2),
+                        "linear_layers": 0, "ssm_layers": 36,
+                        "full_layers": 4, "cache_latent_bytes": 0,
+                        "expert_layers": 0, "experts_held": 0})),
     # trained, not served: the share train cell's kind.  The backward's two
     # grouped kernels beside the forward's; the reader's list still holds
     # ``flash_dq``, a kernel that is gone since PR 48 (the backward is

@@ -21,6 +21,13 @@ programs' limits and what may not leave its stacks).
   experts of two matrices, 1 attention layer of 2 KV heads), 64 slots of
   8,192: the state [4, 65, 64, 64, 128] float32 and the K/V rows updated in
   place, the experts never out of their stack.
+- ``granitemoehybrid`` (no experts; here because its row has
+  ``cell_programs`` and its check's prefill is Nemotron's sibling):
+  granite-4.0-h-micro whole, 40 layers in four periods of nine state-space
+  layers of one 64-head group and one attention layer, a dense MLP under
+  each, 64 slots of 4,096: 6.38 GB of weights, the state [36, 65, 64, 64,
+  128] float32 (4.9 GB) carried through the scan over periods and updated
+  in place by nine kernel calls a period, 2.2 GB of K/V rows.
 """
 
 import re
@@ -94,17 +101,22 @@ def test_latent_8192_program_walks_a_row_in_chunks_of_2048(one_chip, as_tpu):
                          r"(copy|transpose)\(", text)
 
 
-def test_the_nano_checks_prefill_runs_a_buckets_kernels(one_chip, as_tpu):
+@pytest.mark.parametrize("name,calls,kernels", [
+    ("nemotron_h", 4 + 1 + 2 * 4, ("flash_fwd", "ssd_chunk_fwd", "moe_gmm")),
+    ("granitemoehybrid", 9 + 1, ("flash_fwd", "ssd_chunk_fwd"))])
+def test_the_state_space_checks_prefill_runs_a_buckets_kernels(
+        one_chip, as_tpu, name, calls, kernels):
     """The configuration's ``check`` compares a prefill and decode steps
     through the kind's entry points with the reference on its own.  Its
     prompt's length is no multiple of a chunk, and the kind's ``prefill``
     pads the row to whole blocks as the engine's admits are padded to its
     buckets: the compared prefill is the 2,048 bucket's row, with the flash
     kernel (from 1,024 positions up, whole blocks of 512), a chunked scan a
-    state-space layer and two grouped products an expert layer in it; a row
-    of the prompt's own length would run plain attention."""
-    kind, doc = kinds.load("nemotron_h"), kinds.cell_doc("nemotron_h")
-    cfg, chk = kinds.cell_cfg("nemotron_h"), doc["serve"]["check"]
+    state-space layer (of a period's: the scan over periods traces one) and
+    two grouped products an expert layer in it; a row of the prompt's own
+    length would run plain attention."""
+    kind, doc = kinds.load(name), kinds.cell_doc(name)
+    cfg, chk = kinds.cell_cfg(name), doc["serve"]["check"]
     n_prompt, n_dec = chk["prompt_len"], chk["decode_steps"]
     bucket = -(-n_prompt // kind.ROW_BLOCK) * kind.ROW_BLOCK
     cache_len = -(-(n_prompt + n_dec + 1) // 128) * 128
@@ -121,9 +133,9 @@ def test_the_nano_checks_prefill_runs_a_buckets_kernels(one_chip, as_tpu):
         lambda p, c, t, ln, sl: kind.prefill(p, c, t, ln, sl, cfg),
         params, cache, S((1, n_prompt), jnp.int32), S((1,), jnp.int32),
         S((1,), jnp.int32))
-    assert text.count(KERNEL) == 4 + 1 + 2 * 4
-    for name in ("flash_fwd", "ssd_chunk_fwd", "moe_gmm"):
-        assert name in text, name
+    assert text.count(KERNEL) == calls
+    for kernel in kernels:
+        assert kernel in text, kernel
     assert f"s32[1,{bucket}]" in text
 
 
@@ -137,7 +149,12 @@ def test_the_nano_checks_prefill_runs_a_buckets_kernels(one_chip, as_tpu):
 # left this list at PR 47, whose rows are walked in chunks of 2,048 (its own
 # test above).
 
+# Granite's two were pinned at PR 53, which added them (of each, 1,255,145,472
+# are the chip's copy of the ``W_in`` stack: ``kinds.py``, the row's note).
+
 test_whole_row_programs_are_the_parents = whole_row_programs({
     ("xing4_0", "decode", None): (163378176, 4, 6),
     ("xing4_0", "prefill-2048", None): (459842048, 4, 3),
+    ("granitemoehybrid", "decode", None): (1307552768, 10, 4),
+    ("granitemoehybrid", "prefill-4096", None): (1799708160, 10, 2),
 })

@@ -173,6 +173,46 @@ def _ssd(S, kernel):
                       S((), jnp.int32)), (), 2, None, ())
 
 
+def _granite(S, kernel):
+    """64 state-space heads of 64 in ONE group of 128, a group wider than a
+    grid step: four steps of 16 heads in either kernel, each reading the
+    group's B and C, on a stack of 36 layers in place; and attention heads
+    of 64 lanes (32 over 8 K/V heads, rows of 512 lanes, two heads to a
+    128-lane tile) through ``decode_attn`` and the flash forward at the
+    published scale, 1 / 64."""
+    from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.ops import ssd
+    nh, p, g, n, f32, i32, slots = 64, 64, 1, 128, jnp.float32, jnp.int32, 65
+    assert ssd._heads_a_step(nh, nh // g) == 16
+    assert ssd._head_block(nh // g, ssd.CHUNK_HEADS_A_STEP) == 16
+    if kernel == "chunk_fwd":
+        b, t = 1, 4096
+        return (lambda *a: ssd.ssd_chunk_fwd(*a, use_kernel=True,
+                                             interpret=False),
+                (S((b, t, nh, p)), S((b, t, nh), f32), S((nh,)),
+                 S((b, t, g, n)), S((b, t, g, n)), S((nh,)),
+                 S((b,), i32)), (), None, None, ())
+    if kernel == "recurrent_step":
+        return (lambda *a: ssd.ssd_recurrent_step(*a, use_kernel=True,
+                                                  interpret=False),
+                (S((36, slots, nh, p, n), f32), S((), i32),
+                 S((slots, nh, p)), S((slots, nh), f32), S((nh,)),
+                 S((slots, g, n)), S((slots, g, n)), S((nh,))), (0,), None,
+                1e6, ("f32[36,65,64,64,128]",))
+    if kernel == "decode_attn":
+        stack = S((4, slots, 4096, 512))
+        return (lambda q, k, v, i, live: da.decode_attn(
+            q, k, v, i, live, 8, use_kernel=True, interpret=False,
+            scale=1 / 64),
+                (S((slots, 32, 64)), stack, stack, S((), i32),
+                 S((slots,), i32)), (), 1, 16e6, ("bf16[4,65,4096,512]",))
+    return (lambda q, k, v: fa.flash_attention(q, k, v, interpret=False,
+                                               scale=1 / 64),
+            (S((1, 4096, 32, 64)), S((1, 4096, 8, 64)),
+             S((1, 4096, 8, 64))), (), 1, None, ())
+
+
 def _moe_and_latent(S, kernel):
     """The grouped matmul over [layers, 64, 3584, 1024] stacks at a decode
     step's tiles of 16 rows and a prefill row's of 256, gated and plain; the
@@ -208,6 +248,8 @@ KINDS_KERNELS = [
     *((_kda, "solar_open2", k) for k in ("chunk_fwd", "recurrent_step")),
     *((_ssd, "nemotron_h", k) for k in ("chunk_fwd", "recurrent_step",
                                         "moe_gmm-relu2")),
+    *((_granite, "granitemoehybrid", k) for k in (
+        "chunk_fwd", "recurrent_step", "decode_attn", "flash_fwd")),
 ]
 
 

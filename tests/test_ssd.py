@@ -42,23 +42,45 @@ def _inputs(b, t, nh, p, g, n, seed, decay=None):
 
 
 FORMS = {"twin": dict(use_kernel=False), "kernel": dict(interpret=True)}
+#: (heads, groups, head width P, state width N): Nemotron's 8 groups of 8
+#: (two groups a decode grid step, one a prefill step), and Granite's one
+#: group wider than a grid step: 64 heads (four blocks of 16) and 40 (no
+#: multiple of 16: four blocks of 10)
+HEADS = {"two-groups": (4, 2, 16, 32), "eight-groups-of-8": (64, 8, 8, 16),
+         "a-group-of-64": (64, 1, 8, 16), "a-group-of-40": (40, 1, 8, 16)}
 
 
 # ------------------------------------------- the kernels and the recurrence
 
-@pytest.mark.parametrize("form", list(FORMS))
-@pytest.mark.parametrize("t", [128, 300], ids=["a-chunk", "ragged-300"])
-def test_chunked_form_equals_the_recurrence(t, form):
+@pytest.mark.parametrize("heads,per,step,chunk", [
+    (64, 8, 16, 8), (64, 64, 16, 16), (40, 40, 10, 10), (4, 2, 4, 2),
+    (34, 17, 1, 1)])
+def test_a_grid_step_is_whole_groups_or_a_block_of_one_group(heads, per,
+                                                             step, chunk):
+    """Heads a grid step of the decode and of the prefill kernel: Nemotron's
+    plan is what it was (16 = two groups; one group), a group wider than
+    ``STEP_HEADS_A_STEP`` / ``CHUNK_HEADS_A_STEP`` goes in the widest blocks
+    that divide it."""
+    assert ssd._heads_a_step(heads, per) == step
+    assert ssd._head_block(per, ssd.CHUNK_HEADS_A_STEP) == chunk
+    assert per % chunk == 0 and heads % step == 0
+
+
+@pytest.mark.parametrize("t,form,heads", [
+    *((t, form, "two-groups") for form in FORMS for t in (128, 300)),
+    *((300, form, heads) for form in FORMS for heads in list(HEADS)[1:])])
+def test_chunked_form_equals_the_recurrence(t, form, heads):
     """Whole rows and rows that stop early, a length that is no multiple of
     the chunk of 128: outputs up to each row's length and the state as of
-    it."""
-    x, dt, a_log, b, c, d = _inputs(2, t, 4, 16, 2, 32, seed=t)
+    it; groups of heads that are a grid step and a group that is several."""
+    nh, g, p, n = HEADS[heads]
+    x, dt, a_log, b, c, d = _inputs(2, t, nh, p, g, n, seed=t)
     lengths = jnp.array([t, t * 4 // 7])
     live = (jnp.arange(t)[None] < lengths[:, None])[..., None]
     want, state = ssd.ssd_recurrence(x, jnp.where(live, dt, 0.0), a_log, b,
                                      c, d)
     got, s = ssd.ssd_chunk_fwd(x, dt, a_log, b, c, d, lengths, **FORMS[form])
-    assert got.shape == x.shape and s.shape == (2, 4, 16, 32)
+    assert got.shape == x.shape and s.shape == (2, nh, p, n)
     np.testing.assert_allclose(jnp.where(live[..., None], got, 0.0),
                                jnp.where(live[..., None], want, 0.0),
                                atol=2e-4)
@@ -83,11 +105,12 @@ def test_a_head_that_forgets_at_once_stays_finite_and_equal(form):
     np.testing.assert_allclose(s, state, atol=2e-5)
 
 
+@pytest.mark.parametrize("heads", list(HEADS))
 @pytest.mark.parametrize("form", list(FORMS))
-def test_recurrent_step_equals_one_step_and_touches_one_layer(form):
+def test_recurrent_step_equals_one_step_and_touches_one_layer(form, heads):
     """One step of the recurrence on layer 1 of a stack of 3; an idle slot
     (dt 0) keeps its state to the bit, and so do the other layers."""
-    layers, slots, nh, p, g, n = 3, 5, 4, 16, 2, 32
+    layers, slots, (nh, g, p, n) = 3, 5, HEADS[heads]
     x, dt, a_log, b, c, d = _inputs(slots, 1, nh, p, g, n, seed=11)
     x, dt, b, c = x[:, 0], dt[:, 0].at[2].set(0.0), b[:, 0], c[:, 0]
     state = jax.random.normal(jax.random.PRNGKey(12),

@@ -386,6 +386,10 @@ def _traced():
                 q, k, k, jnp.int32(0), jnp.array([3, 0]), 2, 8, 2,
                 interpret=True))(jnp.ones((2, 8, 8)),
                                  jnp.ones((1, 2, 16, 16))),
+        "flash_fwd": lambda: jax.make_jaxpr(
+            lambda q, k: fa.flash_attention(q, k, k, interpret=True,
+                                            scale=0.1))(
+                jnp.ones((1, 128, 4, 8)), jnp.ones((1, 128, 2, 8))),
         "flash_window_prefill": lambda: jax.make_jaxpr(
             lambda q, k: fa.flash_attention(q, k, k, window=8,
                                             interpret=True))(
