@@ -34,8 +34,13 @@ No delta-rule correction and no normalised keys: the sibling of
 * ``ssd_recurrent_step`` (decode) applies one step to every slot of one
   layer of a stacked state ``[layers, slots, heads, P, N]``, in place, taking
   the layer index itself (scalar prefetch), so that no layer slab is ever
-  sliced out of the stack; a grid step is ``STEP_HEADS_A_STEP`` heads at
-  most, whole groups or a block of one group's.
+  sliced out of the stack.  A grid step moves a block of the state planned
+  from its shape (``step_block``): whole slots of the layer, as many as fit
+  ``STEP_STATE_VMEM`` in and out and double-buffered, the last block
+  partial where the slots are no whole blocks; where one slot alone does
+  not fit, the widest block of its heads that is whole groups or divides a
+  group.  x, B, C and y follow the state's block, and the body walks it in
+  a rolled loop over slots and chunks of ``STEP_UNROLL`` heads at most.
 
 Each kernel's math is one function on two-dimensional tiles (``_chunk_tile``,
 ``_step_tile``) that the kernel body calls on what it loaded and the twin
@@ -67,11 +72,30 @@ KERNEL_SSD_RECURRENT_STEP = "ssd_recurrent_step"
 #: counts of the kernel assume it
 CHUNK = 128
 
-#: heads a grid step, at most, of the decode kernel (whole groups, or a
-#: block of one group's heads where a group is wider) and of the prefill
-#: kernel (one group, or a block of its heads): ``_head_block``
-STEP_HEADS_A_STEP = 16
+#: heads a grid step, at most, of the prefill kernel (one group, or a block
+#: of its heads): ``_head_block``
 CHUNK_HEADS_A_STEP = 16
+#: heads the decode kernel's body unrolls, at most: a chunk of its block
+#: (whole groups, or a block of one group's heads), ``_unrolled_heads``
+STEP_UNROLL = 16
+#: what the state's blocks of a grid step of the decode kernel may take of
+#: VMEM, in and out and two buffers each (``step_block``): blocks of 4 MiB,
+#: two slots' [64, 64, 128] float32 at both cells' shapes.  The kernel alone
+#: on the chip (``PERF.md`` section 6, PR 55; ``chiprun_out/pr55a/``, the
+#: script beside its output), us a call over 65 slots and GB/s moved of the
+#: HBM's 819, Granite's one group of 64 heads / Nemotron's 8 groups of 8:
+#: PR 53's 16 heads of one slot (0.5 MiB) 472 / 479 us, 577 / 569 GB/s; 32
+#: heads 423 / 424; **one slot 419.7 / 420.2, two slots 420.0 / 420.3 (649
+#: GB/s)**; three 420.8 / 421.8; four 426.7 / 427.7; five 433.6 / 434.4;
+#: eight 439.4 / 440.0; thirteen 458.3 / 459.1.  A block of any size that is
+#: only copied moves at 413-417 us (655-660 GB/s, read and write mixed), and
+#: the arithmetic with nothing copied takes 352-358 us: small blocks leave a
+#: step's copies and its arithmetic one after the other, large ones leave the
+#: call's first fetch and last write with nothing beside them.
+STEP_STATE_VMEM = 16 << 20
+#: the decode kernel's VMEM, of the chip's 128 MiB: the state's blocks, x, B,
+#: C and y of the block's slots twice, and the body's tiles
+STEP_VMEM_LIMIT = 32 << 20
 
 
 def _head_block(per: int, limit: int) -> int:
@@ -353,68 +377,116 @@ def ssd_recurrent_step_jnp(state, layer, x, dt, a_log, b, c, d):
 
 
 def _step_kernel(layer_ref, a_ref, dt_ref, d_ref, s_in, x_ref, b_ref, c_ref,
-                 s_out, y_ref, *, heads: int, per: int):
+                 s_out, y_ref, *, slots: int, hu: int, per: int):
+    """Grid (slot blocks, head blocks).  s_in, s_out [1, sb, hb, P, N]; x_ref,
+    y_ref [sb, hb / hu, hu, P]: the block's heads in chunks of ``hu``, which
+    the body unrolls; b_ref, c_ref [sb, entries, rows, N]: an entry serves
+    ``max(hu, per)`` heads, a chunk's groups or the group a chunk lies in.
+    The walk is a rolled loop over the slots the block holds (the last block
+    may hold fewer than ``sb``) and their chunks."""
     del layer_ref                     # used by the index maps only
-    si, gi = pl.program_id(0), pl.program_id(1)
-    for i in range(heads):
-        hd, grp = gi * heads + i, i // per
-        y, s = _step_tile(s_in[0, 0, i], x_ref[0, 0, i:i + 1],
-                          b_ref[0, 0, grp:grp + 1], c_ref[0, 0, grp:grp + 1],
-                          a_ref[si, hd], dt_ref[si, hd], d_ref[hd])
-        s_out[0, 0, i] = s
-        y_ref[0, 0, i:i + 1] = y.astype(y_ref.dtype)
+    sb, hb = s_in.shape[1:3]
+    chunks, shared = hb // hu, max(per // hu, 1)    # chunks an entry serves
+    first, head0 = pl.program_id(0) * sb, pl.program_id(1) * hb
+
+    def chunk(at, carry):
+        # everything that is traced is once a chunk; a head adds one index
+        si, g = (at, 0) if chunks == 1 else (at // chunks, at % chunks)
+        slot, base = first + si, head0 + g * hu
+        s_new = s_out.at[0, si, pl.ds(g * hu, hu)]
+        s_old = s_in.at[0, si, pl.ds(g * hu, hu)]
+        entry = g if shared == 1 else g // shared
+        for i in range(hu):
+            hd, row = base + i, (si, entry, slice(i // per, i // per + 1))
+            y, s = _step_tile(s_old[i], x_ref[si, g, i:i + 1], b_ref[row],
+                              c_ref[row], a_ref[slot, hd], dt_ref[slot, hd],
+                              d_ref[hd])
+            s_new[i] = s
+            y_ref[si, g, i:i + 1] = y.astype(y_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(sb, slots - first) * chunks, chunk, 0)
 
 
-def _heads_a_step(nh: int, per: int) -> int:
-    """Heads a grid step: whole groups, the most up to the limit that
-    divide the heads, or of a group wider than the limit a block of its
-    heads (``_head_block``)."""
-    if per > STEP_HEADS_A_STEP:
-        return _head_block(per, STEP_HEADS_A_STEP)
-    return max(h for h in range(per, STEP_HEADS_A_STEP + 1, per)
-               if nh % h == 0)
+def _head_bytes(p: int, n: int) -> int:
+    """A head's float32 state [P, N] as VMEM holds it, in whole (8, 128)
+    tiles."""
+    return (-(-p // 8) * 8) * (-(-n // 128) * 128) * 4
+
+
+def step_block(slots: int, nh: int, per: int, p: int, n: int):
+    """(slots, heads) of the block of the state ``[layers, slots, H, P, N]``
+    that a grid step of ``ssd_recurrent_step`` moves, ``per`` heads a group:
+    whole slots (all heads of a slot lie together in the stack: one copy),
+    the most whose block in and out, two buffers each, fits
+    ``STEP_STATE_VMEM``; where one slot does not fit, of one slot the most
+    whole groups that fit and divide the heads, or of a group wider than
+    that the widest block that divides it (``_head_block``: a block of heads
+    reads one group's B and C).  A function of what the kernel sees in its
+    operands and of nothing else."""
+    fit = STEP_STATE_VMEM // (4 * _head_bytes(p, n))    # heads, four buffers
+    if fit >= nh:
+        return min(slots, fit // nh), nh
+    if fit < 1:
+        raise ValueError(
+            f"ssd_recurrent_step: four buffers of one head's [{p}, {n}] "
+            f"float32 state do not fit {STEP_STATE_VMEM >> 20} MiB of VMEM")
+    if per > fit:
+        return 1, _head_block(per, fit)
+    return 1, max(h for h in range(per, fit + 1, per) if nh % h == 0)
+
+
+def _unrolled_heads(hb: int, per: int) -> int:
+    """Heads of a chunk of a block of ``hb``, which the body unrolls: the
+    most up to ``STEP_UNROLL`` that divide the block and are whole groups or
+    divide a group."""
+    return max(h for h in range(1, min(hb, STEP_UNROLL) + 1)
+               if hb % h == 0 and (h % per == 0 or per % h == 0))
 
 
 def _recurrent_step_pallas(state, layer, x, dt, a_log, b, c, d,
                            interpret: bool):
     _, slots, nh, p, n = state.shape
-    per = nh // b.shape[1]
-    hb = _heads_a_step(nh, per)
-    ng = nh // hb
-    gs = max(hb // per, 1)          # groups a step; 1 where a step is a
-    blocks = max(per // hb, 1)      # block of a group of ``blocks`` blocks
-    x = x.reshape(slots, ng, hb, p)
-    b, c = (a.reshape(slots, -1, gs, n) for a in (b, c))
-    small = lambda si, gi, lyr: (si, gi, 0, 0)          # noqa: E731
-    shared = small if blocks == 1 else (                # the block's group's
-        lambda si, gi, lyr: (si, gi // blocks, 0, 0))
-    big = lambda si, gi, lyr: (lyr[0], si, gi, 0, 0)    # noqa: E731
+    groups = b.shape[1]
+    per = nh // groups
+    sb, hb = step_block(slots, nh, per, p, n)
+    hu = _unrolled_heads(hb, per)
+    wide = max(hu, per)             # heads an entry of B and C serves:
+    gs = max(hu // per, 1)          # the ``gs`` groups of a chunk, or the
+    entries = max(hb // wide, 1)    # group of ``wide / hu`` chunks
+    x = x.reshape(slots, nh // hu, hu, p)
+    b, c = (a.reshape(slots, groups // gs, gs, n) for a in (b, c))
+    small = lambda si, hi, lyr: (si, hi, 0, 0)          # noqa: E731
+    shared = small if hb >= wide else (                 # the block's group's
+        lambda si, hi, lyr: (si, hi // (wide // hb), 0, 0))
+    big = lambda si, hi, lyr: (lyr[0], si, hi, 0, 0)    # noqa: E731
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     state, y = pl.pallas_call(
-        functools.partial(_step_kernel, heads=hb, per=per),
+        functools.partial(_step_kernel, slots=slots, hu=hu, per=per),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(slots, ng),
+            grid=(pl.cdiv(slots, sb), nh // hb),
             in_specs=[
                 smem, smem, smem,
-                pl.BlockSpec((1, 1, hb, p, n), big),
-                pl.BlockSpec((1, 1, hb, p), small),
-                pl.BlockSpec((1, 1, gs, n), shared),
-                pl.BlockSpec((1, 1, gs, n), shared),
+                pl.BlockSpec((1, sb, hb, p, n), big),
+                pl.BlockSpec((sb, hb // hu, hu, p), small),
+                pl.BlockSpec((sb, entries, gs, n), shared),
+                pl.BlockSpec((sb, entries, gs, n), shared),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, hb, p, n), big),
-                pl.BlockSpec((1, 1, hb, p), small),
+                pl.BlockSpec((1, sb, hb, p, n), big),
+                pl.BlockSpec((sb, hb // hu, hu, p), small),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(state.shape, state.dtype),
-            jax.ShapeDtypeStruct((slots, ng, hb, p), x.dtype),
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
         ],
         # operands count from the scalar-prefetch argument: 4 is the state
         input_output_aliases={4: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=STEP_VMEM_LIMIT),
         interpret=interpret,
         name=KERNEL_SSD_RECURRENT_STEP,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), *_decay(dt, a_log),

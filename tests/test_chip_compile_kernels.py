@@ -174,9 +174,10 @@ def _ssd(S, kernel):
 
 
 def _granite(S, kernel):
-    """64 state-space heads of 64 in ONE group of 128, a group wider than a
-    grid step: four steps of 16 heads in either kernel, each reading the
-    group's B and C, on a stack of 36 layers in place; and attention heads
+    """64 state-space heads of 64 in ONE group of 128: four steps of 16 heads
+    a chunk in the prefill kernel, each reading the group's B and C, and
+    the decode kernel's planned block (the test after this one) on a stack
+    of 36 layers in place; and attention heads
     of 64 lanes (32 over 8 K/V heads, rows of 512 lanes, two heads to a
     128-lane tile) through ``decode_attn`` and the flash forward at the
     published scale, 1 / 64."""
@@ -184,7 +185,6 @@ def _granite(S, kernel):
     from ray_tpu.ops import flash_attention as fa
     from ray_tpu.ops import ssd
     nh, p, g, n, f32, i32, slots = 64, 64, 1, 128, jnp.float32, jnp.int32, 65
-    assert ssd._heads_a_step(nh, nh // g) == 16
     assert ssd._head_block(nh // g, ssd.CHUNK_HEADS_A_STEP) == 16
     if kernel == "chunk_fwd":
         b, t = 1, 4096
@@ -265,6 +265,46 @@ def test_the_kinds_kernels_compile_at_published_sizes(one_chip, build, name,
         assert compiled.memory_analysis().temp_size_in_bytes < temp
     for stack in stacks:
         assert not copies_of(stack, text)
+
+
+# -------------- the recurrent step's block plan at both cells' shapes
+
+@pytest.mark.parametrize("layers,groups", [
+    pytest.param(4, 8, id="nemotron_h"),
+    pytest.param(36, 1, id="granitemoehybrid")])
+def test_ssd_recurrent_step_compiles_under_its_vmem_in_place(
+        one_chip, monkeypatch, layers, groups):
+    """The decode kernel at the plan ``step_block`` makes from each cell's
+    shapes, two whole slots a grid step over 65 (the last block holds one):
+    the state's four buffers lie under ``STEP_STATE_VMEM``, Mosaic accepts
+    the kernel under ``STEP_VMEM_LIMIT`` and refuses it under a limit that
+    the blocks alone pass (so a plan that overflowed would be refused here
+    and not at a cell's first step), and the stack is updated where it
+    lies: aliased whole, nothing of a layer's size temporary, no copy."""
+    from ray_tpu.ops import ssd
+    slots, nh, p, n, f32 = 65, 64, 64, 128, jnp.float32
+    sb, hb = ssd.step_block(slots, nh, nh // groups, p, n)
+    assert (sb, hb) == (2, 64)
+    blocks = 4 * sb * hb * ssd._head_bytes(p, n)
+    assert blocks == 16 << 20 <= ssd.STEP_STATE_VMEM < ssd.STEP_VMEM_LIMIT
+    S = shapes_on(one_chip)
+    args = (S((layers, slots, nh, p, n), f32), S((), jnp.int32),
+            S((slots, nh, p)), S((slots, nh), f32), S((nh,)),
+            S((slots, groups, n)), S((slots, groups, n)), S((nh,)))
+
+    def compile_step():     # a function of its own each time: a new trace
+        return _compile(lambda *a: ssd.ssd_recurrent_step(
+            *a, use_kernel=True, interpret=False), *args, donate_argnums=(0,))
+
+    compiled, text = compile_step()
+    mem = compiled.memory_analysis()
+    assert text.count(KERNEL) == 1
+    assert mem.alias_size_in_bytes == layers * slots * nh * p * n * 4
+    assert mem.temp_size_in_bytes < 1e6
+    assert not copies_of(f"f32[{layers},{slots},{nh},{p},{n}]", text)
+    monkeypatch.setattr(ssd, "STEP_VMEM_LIMIT", blocks - (1 << 20))
+    with pytest.raises(Exception, match="(?i)vmem|memory"):
+        compile_step()
 
 
 # ------------------- the grouped matmul's block plan at every cell's widths

@@ -52,18 +52,51 @@ HEADS = {"two-groups": (4, 2, 16, 32), "eight-groups-of-8": (64, 8, 8, 16),
 
 # ------------------------------------------- the kernels and the recurrence
 
-@pytest.mark.parametrize("heads,per,step,chunk", [
-    (64, 8, 16, 8), (64, 64, 16, 16), (40, 40, 10, 10), (4, 2, 4, 2),
-    (34, 17, 1, 1)])
-def test_a_grid_step_is_whole_groups_or_a_block_of_one_group(heads, per,
-                                                             step, chunk):
-    """Heads a grid step of the decode and of the prefill kernel: Nemotron's
-    plan is what it was (16 = two groups; one group), a group wider than
-    ``STEP_HEADS_A_STEP`` / ``CHUNK_HEADS_A_STEP`` goes in the widest blocks
-    that divide it."""
-    assert ssd._heads_a_step(heads, per) == step
+@pytest.mark.parametrize("slots,heads,per,p,n,block,unrolled,chunk", [
+    # Nemotron's and Granite's cells: whole slots of 2 MiB, the two whose
+    # blocks fit ``STEP_STATE_VMEM`` in and out and double-buffered
+    pytest.param(65, 64, 8, 64, 128, (2, 64), 16, 8, id="nemotron"),
+    pytest.param(65, 64, 64, 64, 128, (2, 64), 16, 16, id="granite"),
+    # toy states: every slot there is in one block ([8, 16] float32 is one
+    # (8, 128) tile in VMEM)
+    pytest.param(5, 40, 40, 8, 16, (5, 40), 10, 10, id="40-heads"),
+    pytest.param(5, 4, 2, 16, 32, (5, 4), 4, 2, id="4-heads"),
+    pytest.param(5, 34, 17, 8, 16, (5, 34), 1, 1, id="34-heads"),
+    # a state too large for a whole slot (512 KiB a head: eight heads fit):
+    # of 8 groups of 8 one whole group, of 16 groups of 4 two, of one group
+    # of 64 a block of 8 of its heads, of 2 groups of 24 a block of 8
+    pytest.param(65, 64, 8, 256, 512, (1, 8), 8, 8, id="no-slot-fits-8x8"),
+    pytest.param(65, 64, 4, 256, 512, (1, 8), 8, 4, id="no-slot-fits-16x4"),
+    pytest.param(65, 64, 64, 256, 512, (1, 8), 8, 16,
+                 id="no-slot-fits-1x64"),
+    pytest.param(65, 48, 24, 256, 512, (1, 8), 8, 12,
+                 id="no-slot-fits-2x24"),
+    # fewer slots than would fit are one block; one slot of half the heads
+    # and four of them a block
+    pytest.param(1, 64, 64, 64, 128, (1, 64), 16, 16, id="one-slot"),
+    pytest.param(65, 32, 8, 64, 128, (4, 32), 16, 8, id="32-heads"),
+])
+def test_a_grid_step_is_whole_slots_or_a_block_of_one_slots_heads(
+        slots, heads, per, p, n, block, unrolled, chunk):
+    """What a grid step of the decode kernel moves of the state, planned from
+    its shape and ``STEP_STATE_VMEM`` alone (``step_block``): whole slots,
+    or of one slot whole groups or a block of one group's heads; the heads
+    the body unrolls (whole groups or a block of one group's, ``STEP_UNROLL``
+    at most); and the prefill kernel's heads a step, as they were."""
+    sb, hb = ssd.step_block(slots, heads, per, p, n)
+    assert (sb, hb) == block and heads % hb == 0
+    assert hb % per == 0 or per % hb == 0
+    assert 4 * sb * hb * ssd._head_bytes(p, n) <= ssd.STEP_STATE_VMEM
+    assert ssd.STEP_STATE_VMEM < ssd.STEP_VMEM_LIMIT
+    hu = ssd._unrolled_heads(hb, per)
+    assert hu == unrolled and hb % hu == 0
     assert ssd._head_block(per, ssd.CHUNK_HEADS_A_STEP) == chunk
-    assert per % chunk == 0 and heads % step == 0
+    assert per % chunk == 0
+
+
+def test_a_head_too_large_for_the_kernels_vmem_is_refused():
+    with pytest.raises(ValueError, match="do not fit"):
+        ssd.step_block(4, 8, 8, 2048, 2048)
 
 
 @pytest.mark.parametrize("t,form,heads", [
@@ -123,6 +156,44 @@ def test_recurrent_step_equals_one_step_and_touches_one_layer(form, heads):
     np.testing.assert_allclose(new[1], after, atol=1e-5)
     assert bool((new[0] == state[0]).all() and (new[2] == state[2]).all())
     assert bool((new[1, 2] == state[1, 2]).all())
+
+
+@pytest.mark.parametrize("heads", ["eight-groups-of-8", "a-group-of-64"])
+@pytest.mark.parametrize("slots,fit", [(5, 4), (9, 4), (65, 16), (9, 0.25)])
+def test_recurrent_step_walks_a_last_block_that_is_not_full(monkeypatch,
+                                                            slots, fit,
+                                                            heads):
+    """A slot count the plan's block does not divide: blocks of 4 slots over
+    5 (4 + 1) and 9 (4 + 4 + 1), of 16 over 65 (4 x 16 + 1), and a budget
+    that a whole slot does not fit (blocks of 16 heads of one slot, the
+    grid of PR 53's kernel).  The last slot, alone in the last block, is
+    idle (dt 0) and keeps its state to the bit beside an idle slot in a full
+    block; the neighbouring layers are untouched.  The kernel (interpreted)
+    is held to the twin, which ``vmap``s the same tile function over slots
+    and heads, to the last place or two: every head's arithmetic is
+    ``_step_tile`` whatever the block, but the CPU's compiler fuses ``s a +
+    x B`` into one rounding or two and sums a row in its own order as it
+    sees fit in each program (on the chip every plan read the parent
+    kernel's bits: ``PERF.md`` section 6, PR 55)."""
+    nh, g, p, n = HEADS[heads]
+    x, dt, a_log, b, c, d = _inputs(slots, 1, nh, p, g, n, seed=slots)
+    x, b, c = x[:, 0], b[:, 0], c[:, 0]
+    dt = dt[:, 0].at[slots - 1].set(0.0).at[1].set(0.0)
+    state = jax.random.normal(jax.random.PRNGKey(13), (3, slots, nh, p, n))
+    args = (state, jnp.int32(1), x, dt, a_log, b, c, d)
+    want, y_want = ssd.ssd_recurrent_step(*args, use_kernel=False)
+    monkeypatch.setattr(ssd, "STEP_STATE_VMEM",         # ``fit`` slots' worth
+                        int(fit * 4 * nh * ssd._head_bytes(p, n)))
+    sb, hb = ssd.step_block(slots, nh, nh // g, p, n)
+    assert (sb, hb) == ((fit, nh) if fit >= 1 else (1, 16))
+    assert slots % sb or sb == 1
+    new, y = ssd.ssd_recurrent_step(*args, interpret=True)
+    np.testing.assert_allclose(y, y_want, atol=2e-6)
+    np.testing.assert_allclose(new[1], want[1], atol=1e-6)
+    assert float(jnp.abs(new[1, 0] - state[1, 0]).max()) > 0.01
+    assert bool((new[0] == state[0]).all() and (new[2] == state[2]).all())
+    for idle in (1, slots - 1):
+        assert bool((new[1, idle] == state[1, idle]).all())
 
 
 # ----------------------------------------------- experts of two matrices
