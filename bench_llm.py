@@ -46,7 +46,7 @@ REQUEST_KEYS = frozenset({
 })
 #: engine-side breakdown keys (LLMEngine.breakdown(), via LLMServer.stats)
 ENGINE_KEYS = frozenset({
-    "admit_batches", "batch_occupancy",
+    "admit_batches",
 })
 
 

@@ -1257,6 +1257,9 @@ class CoreWorker:
         self.actor_spec: Optional[TaskSpec] = None
         self._actor_threadpool = None
         self._actor_async_loop: Optional[asyncio.AbstractEventLoop] = None
+        #: actor calls taken off the wire and not yet started: when each
+        #: arrived (wall clock), for the task context (_task_ctx)
+        self._received_at: Dict[TaskID, float] = {}
         self._shutdown = False
         self._blocked_depth = 0
 
@@ -3025,7 +3028,9 @@ class CoreWorker:
         specs = spec_cache.decode_many(specs)  # raises before any dispatch
         loop = asyncio.get_event_loop()
         futs = []
+        received_at = time.time()
         for spec in specs:
+            self._received_at[spec.task_id] = received_at
             self.register_gen_emitter(spec, _writer, loop)
             if self.actor_spec is not None and self.actor_spec.is_async_actor:
                 fut = asyncio.ensure_future(self._run_async_actor_task(spec))
@@ -3050,6 +3055,7 @@ class CoreWorker:
 
     async def handle_actor_task(self, spec):
         spec = spec_cache.decode(spec)
+        self._received_at[spec.task_id] = time.time()
         if self.actor_spec is not None and self.actor_spec.is_async_actor:
             return await self._run_async_actor_task(spec)
         fut = asyncio.get_event_loop().create_future()
@@ -3213,6 +3219,24 @@ class CoreWorker:
             stages["dep_fetch"] = [t1, time.time()]
         return out
 
+    def _task_ctx(self, spec: TaskSpec) -> dict:
+        """What ``get_runtime_context()`` shows the running task, on the
+        executor's thread and on an async actor's loop alike.  An actor
+        call also finds ``received_at``: the wall-clock moment its spec
+        reached this process (``handle_actor_task`` / ``_batch``), ahead of
+        the actor's ordered queue and its loop's turn."""
+        ctx = {"task_id": spec.task_id, "job_id": spec.job_id,
+               "actor_id": spec.actor_id, "name": spec.name}
+        if spec.resources:
+            # actor METHOD specs carry no resources — leaving the key out
+            # lets get_assigned_resources fall through to the actor's
+            # creation spec instead of reporting a bogus default
+            ctx["resources"] = dict(spec.resources)
+        received_at = self._received_at.pop(spec.task_id, None)
+        if received_at is not None:
+            ctx["received_at"] = received_at
+        return ctx
+
     def _execute_task(self, spec: TaskSpec):
         if spec.is_actor_task:
             if self.actor_instance is None:
@@ -3223,14 +3247,7 @@ class CoreWorker:
             fn = self._load_function(spec.fn_id, spec.job_id)
         stages: Dict[str, list] = {}
         args, kwargs = self._resolve_args(spec, stages)
-        ctx = {"task_id": spec.task_id, "job_id": spec.job_id,
-               "actor_id": spec.actor_id, "name": spec.name}
-        if spec.resources:
-            # actor METHOD specs carry no resources — leaving the key out
-            # lets get_assigned_resources fall through to the actor's
-            # creation spec instead of reporting a bogus default
-            ctx["resources"] = dict(spec.resources)
-        token = _task_context.set(ctx)
+        token = _task_context.set(self._task_ctx(spec))
         # Execution joins the submitter's trace: spans opened by the task and
         # any remote calls it makes chain under the task's span id.
         trace_id = (spec.trace_ctx[0] if spec.trace_ctx
@@ -3446,14 +3463,7 @@ class CoreWorker:
     def _execute_actor_creation(self, spec: TaskSpec):
         cls = self._load_function(spec.fn_id, spec.job_id)
         args, kwargs = self._resolve_args(spec)
-        ctx = {"task_id": spec.task_id, "job_id": spec.job_id,
-               "actor_id": spec.actor_id, "name": spec.name}
-        if spec.resources:
-            # actor METHOD specs carry no resources — leaving the key out
-            # lets get_assigned_resources fall through to the actor's
-            # creation spec instead of reporting a bogus default
-            ctx["resources"] = dict(spec.resources)
-        token = _task_context.set(ctx)
+        token = _task_context.set(self._task_ctx(spec))
         try:
             self.actor_instance = cls(*args, **kwargs)
         finally:
@@ -3480,6 +3490,7 @@ class CoreWorker:
         async def runner():
             # getattr inside the per-spec error scope: a missing method must
             # fail only ITS call, not every call batched with it.
+            ctx = self._task_ctx(spec)
             method = getattr(self.actor_instance, spec.actor_method)
             stages: Dict[str, list] = {}
             args, kwargs = self._resolve_args(spec, stages)
@@ -3492,6 +3503,7 @@ class CoreWorker:
                         else spec.task_id.hex()[:12])
             trace_token = _tracing.set_context((trace_id,
                                                 spec.task_id.hex()[:12]))
+            ctx_token = _task_context.set(ctx)
             try:
                 t_exec = time.time()
                 res = method(*args, **kwargs)
@@ -3506,6 +3518,7 @@ class CoreWorker:
                 stages["execute"] = [t_exec, t_put]
                 results = self._package_returns(spec, res)
             finally:
+                _task_context.reset(ctx_token)
                 _tracing.reset_context(trace_token)
             stages["result_put"] = [t_put, time.time()]
             self.flush_borrower_notes()  # see _execute_task

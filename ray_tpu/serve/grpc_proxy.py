@@ -17,6 +17,7 @@ streaming generator — every chunk ships as a separate gRPC message.
 from __future__ import annotations
 
 import json
+import time
 from typing import Any, AsyncIterator
 
 from .http_proxy import AsyncRouter
@@ -171,11 +172,12 @@ class GrpcProxyActor:
         deployment, method, md = await self._target(context)
         req = Request(method="GRPC", path="/", headers=md, body=payload)
         try:
+            sent_at = time.time()   # before the choice (ingress_transit_s)
             name = await self.router.choose(deployment)
             h = self.router._handle_for(name)
             gen = h.handle_request_gen.options(
                 num_returns="streaming", generator_backpressure=256).remote(
-                (req,), {}, method)
+                (req,), {}, method, sent_at)
             from .asgi import ASGIStart
             async for ref in gen:
                 chunk = await self.router._aget(ref)

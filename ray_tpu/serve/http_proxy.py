@@ -177,7 +177,8 @@ class AsyncRouter:
             try:
                 h = self._handle_for(name)
                 ref = self._traced_submit(
-                    lambda: h.handle_request.remote(args, kwargs, method),
+                    lambda: h.handle_request.remote(args, kwargs, method,
+                                                    t_route),
                     deployment, t_route)
             except Exception as e:  # noqa: BLE001 — dead name
                 last = e
@@ -340,7 +341,7 @@ class HTTPProxyActor:
         gen = self.router._traced_submit(
             lambda: h.handle_request_gen.options(
                 num_returns="streaming", generator_backpressure=256).remote(
-                (req,), {}, None),
+                (req,), {}, None, t_route),    # sent_at: before the choice
             deployment, t_route)
         # long-lived streams must load BOTH the queue-depth gauge and the
         # per-replica p2c count — otherwise choose() assigns multi-minute

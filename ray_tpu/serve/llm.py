@@ -49,9 +49,9 @@ _FLUSH = object()
 class GenRequest:
     __slots__ = ("tokens", "max_tokens", "temperature", "top_k", "eos_id",
                  "out", "slot", "generated", "submitted_at", "seen_at",
-                 "admitted_at", "emit_times", "held_by", "prefill_attrs",
-                 "pages", "prompt_len", "cache_len",
-                 "deployment", "trace_ctx", "span_parent")
+                 "admitted_at", "emit_times", "retired_at", "held_by",
+                 "prefill_attrs", "pages", "prompt_len", "cache_len",
+                 "deployment", "trace_ctx", "span_parent", "track")
 
     def __init__(self, tokens: List[int], max_tokens: int,
                  temperature: float, top_k: int, eos_id: Optional[int]):
@@ -70,14 +70,15 @@ class GenRequest:
         self.cache_len = len(tokens)
         # one monotonic stamp per stage: submit (the caller's thread), the
         # first look of the engine's loop that found the request pending,
-        # the dispatch of the admit that carries it, and every token's
-        # _emit (engine thread; emit_times[0] is the first token's).  The
-        # stage counters difference them; the task-event spans get their
+        # the dispatch of the admit that carries it, every token's _emit
+        # (engine thread; emit_times[0] is the first token's) and _retire.
+        # The stage counters difference them; the task-event spans get their
         # wall time from the engine's one offset (LLMEngine._wall).
         self.submitted_at = time.monotonic()
         self.seen_at: Optional[float] = None
         self.admitted_at: Optional[float] = None
         self.emit_times: List[float] = []
+        self.retired_at: Optional[float] = None
         #: why the last look that saw the request left it pending: "bucket"
         #: (the admit took another bucket), "batch" (prefill_batch was
         #: full), "slot" (no free slot), "pages" (the page arena was full)
@@ -92,6 +93,9 @@ class GenRequest:
         self.trace_ctx: Optional[tuple] = None
         #: previous stage's span id — batch_wait -> prefill -> decode chain
         self.span_parent: Optional[str] = None
+        #: the replica's record of the call that brought the request
+        #: (observability.RequestTrack); None for a caller of the engine
+        self.track = None
 
 
 class _Phase:
@@ -121,7 +125,7 @@ class _Phase:
 class _Program:
     """One program the engine thread has dispatched and not yet fetched."""
     __slots__ = ("kind", "seq", "out", "snapshot", "dispatched", "streams",
-                 "rows", "chunks", "k")
+                 "rows", "chunks", "k", "unbound", "start", "done", "steps")
 
     def __init__(self, kind: str, out: tuple, dispatched: float,
                  snapshot=None, rows=(), chunks: int = 0, k: int = 0):
@@ -137,8 +141,84 @@ class _Program:
         #: chunks they made up; a speculative dispatch's window
         self.rows, self.chunks, self.k = rows, chunks, k
         #: ordinal since the engine started and the streams live at the
-        #: dispatch (LLMEngine._in_flight)
+        #: dispatch, and how long the chip had stood without a program
+        #: when it came (LLMEngine._in_flight)
         self.seq = self.streams = 0
+        self.unbound = 0.0
+        #: its run on the chip as the host knows it (_program_done) and the
+        #: decode steps or speculative rounds it holds (an admit: one)
+        self.start = self.done = 0.0
+        self.steps = 1
+
+
+#: what a slot is doing, every second of it: taken with no first token yet,
+#: decoding, ended on the chip with the host yet to know, free with the
+#: request that will take it already submitted, free with nobody asking
+SLOT_STATES = ("prefill", "live", "tail", "queued", "unfed")
+
+
+class _SlotAccount:
+    """Every slot-second since the engine started, in one of SLOT_STATES.
+    The engine thread books at an admit, a first token and a retire; a
+    snapshot closes every slot's open interval at its own instant, so the
+    five sums of any snapshot add up to slots x the time since the start.
+    One lock, taken at those three events of a request and at a snapshot:
+    a reader on another thread never sees an interval booked and open."""
+
+    def __init__(self, num_slots: int):
+        self.lock = threading.Lock()
+        self.sums = dict.fromkeys(SLOT_STATES, 0.0)
+        now = time.monotonic()
+        #: slot -> (what it is in now: "free", "prefill" or "live"; since)
+        self.open = {s: ("free", now) for s in range(num_slots)}
+
+    def take(self, slot: int, submitted_at: float, now: float) -> float:
+        """An admit dispatched at ``now`` takes ``slot`` for a request
+        submitted at ``submitted_at``: the vacancy up to the submit was
+        unfed (returned), the rest queued."""
+        with self.lock:
+            since = self.open[slot][1]
+            asked = min(max(submitted_at, since), now)
+            self.sums["unfed"] += asked - since
+            self.sums["queued"] += now - asked
+            self.open[slot] = ("prefill", now)
+        return asked - since
+
+    def first_token(self, slot: int, now: float):
+        with self.lock:
+            self.sums["prefill"] += now - self.open[slot][1]
+            self.open[slot] = ("live", now)
+
+    def retire(self, slot: int, ended: float, now: float) -> float:
+        """The host retires ``slot``'s request at ``now``; on the chip it
+        had ended at ``ended``.  Returns the tail between the two."""
+        with self.lock:
+            since = self.open[slot][1]
+            ended = min(max(ended, since), now)
+            self.sums["live"] += ended - since
+            self.sums["tail"] += now - ended
+            self.open[slot] = ("free", now)
+        return now - ended
+
+    def snapshot(self, now: float, pending: List[float]) -> dict:
+        """The five sums with every open interval closed at ``now``.  A
+        request that has ended on the chip and not yet on the host still
+        reads live (its tail is booked at the retire); a free slot reads
+        queued from the submit of the pending request that will take it
+        (``pending``: their submit stamps, oldest first, as slots free
+        oldest first), unfed before it and where nobody is pending."""
+        with self.lock:
+            out, open_ = dict(self.sums), sorted(
+                self.open.values(), key=lambda o: o[1])
+        waiting = iter(pending)
+        for what, since in open_:
+            if what != "free":
+                out[what] += now - since
+                continue
+            asked = min(max(next(waiting, now), since), now)
+            out["unfed"] += asked - since
+            out["queued"] += now - asked
+        return {f"slot_{k}_s": v for k, v in out.items()}
 
 
 class LLMEngine:
@@ -384,11 +464,9 @@ class LLMEngine:
         # the same per token: prompt tokens prefilled, and every other
         # position the program walked (a row is rounded up to its bucket,
         # or to whole chunks where the program walks it in chunks:
-        # decode.prefill_width); the chunks walked and the rows they made up
+        # decode.prefill_width)
         self.admit_tokens_real = 0
         self.admit_tokens_padded = 0
-        self.admit_chunks = 0
-        self.admit_rows_chunked = 0
         # what decode attention reads of the cache it holds (dense cache,
         # plain decode): per step the live positions of the active slots,
         # rounded up to the kernel's blocks, against every slot's max_len;
@@ -432,6 +510,22 @@ class LLMEngine:
         # token, not retired), and the same for the admits alone
         self.stream_s = 0.0
         self.stream_admit_s = 0.0
+        # a slot's time by what it was doing (_SlotAccount) and the requests
+        # retired; the program whose tokens the emit phase is handing out,
+        # the step (or round) of it that made them, from which _retire
+        # reads when the request ended on the chip, and the requests that
+        # ended in this phase, for its span
+        self._slots = _SlotAccount(num_slots)
+        self.retired_requests = 0
+        self._emitting: Optional[_Program] = None
+        self._emit_step = 0
+        self._ended: List[str] = []
+        # the chip without a program while a stream was live, as the loop
+        # sees it at a dispatch (_in_flight), and a decode or speculative
+        # program's run as a fetch that had to wait last measured it
+        self.chip_unbound_s = 0.0
+        self.chip_unbound_n = 0
+        self._ran: Dict[tuple, float] = {}
         # the look of the pass under way, programs dispatched since the
         # engine started, and the return of the last blocking fetch: the
         # engine is the chip's only submitter, so a program starts at the
@@ -459,7 +553,11 @@ class LLMEngine:
 
     def submit(self, tokens: List[int], max_tokens: int = 64,
                temperature: float = 0.0, top_k: int = 0,
-               eos_id: Optional[int] = None) -> GenRequest:
+               eos_id: Optional[int] = None, track=None) -> GenRequest:
+        """``track``: the replica's record of the call that brings the
+        request (``observability.RequestTrack``): it books the call's way
+        from the replica's method to this submit and becomes the parent of
+        the request's stage spans."""
         if len(tokens) >= self.max_len:
             raise ValueError(f"prompt length {len(tokens)} >= max_len "
                              f"{self.max_len}")
@@ -481,6 +579,9 @@ class LLMEngine:
             if req.deployment != "-":
                 self._obs_dep = req.deployment
             obs.add_tokens(req.deployment, "in", req.prompt_len)
+        if track is not None:
+            req.track = track
+            req.trace_ctx = track.submitted(req.submitted_at, req.trace_ctx)
         self._pending.put(req)
         self._wake.set()
         return req
@@ -506,12 +607,10 @@ class LLMEngine:
 
     def breakdown(self) -> dict:
         """Serving-picture rollup (bench_llm records this next to the
-        per-request percentiles): admission batch occupancy + padding
-        waste, KV page utilization, prefix-cache hit rate."""
+        per-request percentiles): admit batches, slots in use, KV page
+        utilization, prefix-cache hit rate."""
         out = {
             "admit_batches": self.admit_batches,
-            # of the rows the chip walked, those that held a request: all
-            "batch_occupancy": 1.0 if self.admit_rows_real else 0.0,
             "active_slots": len(self._active),
             "num_slots": self.num_slots,
             **self._cache_gauges,
@@ -551,11 +650,14 @@ class LLMEngine:
         stages, and the engine thread's seconds and intervals per phase.
         The interval open at the call counts up to ``t_mono``, so the five
         ``loop_*_s`` add up to the thread's wall time but for the moments
-        between phases."""
+        between phases, and the five ``slot_*_s`` to ``num_slots`` times
+        the time since the engine started (``_SlotAccount.snapshot``)."""
         now = time.monotonic()
         loop_s, open_ = dict(self.loop_s), self._phase_open
         if open_ is not None:
             loop_s[open_[0]] += max(0.0, now - open_[1])
+        with self._pending.mutex:
+            pending = [r.submitted_at for r in self._pending.queue]
         out = {
             "t_mono": now,
             "loop_iterations": self.loop_iterations,
@@ -572,8 +674,10 @@ class LLMEngine:
             "stream_admit_s": self.stream_admit_s,
             "admit_tokens_real": self.admit_tokens_real,
             "admit_tokens_padded": self.admit_tokens_padded,
-            "admit_chunks": self.admit_chunks,
-            "admit_rows_chunked": self.admit_rows_chunked,
+            "retired_requests": self.retired_requests,
+            "chip_unbound_s": self.chip_unbound_s,
+            "chip_unbound_n": self.chip_unbound_n,
+            **self._slots.snapshot(now, pending),
             "kv_positions_read": self.kv_positions_read,
             "kv_positions_held": self.kv_positions_held,
             "kv_positions_live": self.kv_positions_live,
@@ -676,7 +780,9 @@ class LLMEngine:
 
     def _obs_admit(self, prog: _Program, tokens_real: int):
         """One admit batch, just dispatched: padding accounting (rows and
-        tokens), queue wait per request and its two causes; then, behind
+        tokens), queue wait per request and its two causes, the vacancy of
+        the slot it takes (``unfed_s``: free before the request asked);
+        then, behind
         one enabled() check, occupancy + queue-wait metrics, batch_wait
         span per request (chained under the request's trace), KV/slot
         gauges.  Engine-thread side; every metric call is a
@@ -686,8 +792,6 @@ class LLMEngine:
         self.admit_batches += 1
         self.admit_rows_real += len(reqs)
         self.admit_tokens_real += tokens_real
-        self.admit_chunks += prog.chunks
-        self.admit_rows_chunked += len(reqs) if prog.chunks else 0
         self.admit_tokens_padded += sum(n for _r, n in prog.rows) - tokens_real
         # (of a share of the experts, the part that lands on those held
         # under uniform routing: the admit program returns no count)
@@ -707,21 +811,22 @@ class LLMEngine:
             held_s = max(0.0, look - r.seen_at)
             self.queue_look_s += look_s
             self.queue_held_s += held_s
-            causes.append((look_s, held_s))
+            causes.append((look_s, held_s,
+                           self._slots.take(r.slot, r.submitted_at, now)))
         if not obs.enabled():
             return
         dep = self._obs_dep
         obs.record_batch(dep, len(reqs), self.prefill_batch,
                          waits_s=[now - r.submitted_at for r in reqs])
         self._obs_gauges()
-        for r, (look_s, held_s) in zip(reqs, causes):
+        for r, (look_s, held_s, unfed_s) in zip(reqs, causes):
             r.span_parent = obs.stamp_span(
                 "batch_wait", self._wall + r.submitted_at,
                 now - r.submitted_at,
                 trace_id=r.trace_ctx[0] if r.trace_ctx else None,
                 parent_id=r.trace_ctx[1] if r.trace_ctx else None,
                 deployment=r.deployment, look_s=look_s, held_s=held_s,
-                held_by=r.held_by)
+                held_by=r.held_by, unfed_s=unfed_s)
 
     def _obs_first_token(self, r: GenRequest, now: float):
         """One request's first token is being emitted (``now``): the stage
@@ -730,6 +835,7 @@ class LLMEngine:
         ``prefill`` span, chained under batch_wait."""
         self.first_tokens += 1
         self.first_token_wait_s += now - r.admitted_at
+        self._slots.first_token(r.slot, now)
         if not obs.enabled():
             return
         obs.observe_ttft(r.deployment, now - r.submitted_at,
@@ -741,18 +847,25 @@ class LLMEngine:
             deployment=r.deployment, prompt_len=r.prompt_len,
             **r.prefill_attrs)
 
-    def _obs_retire(self, r: GenRequest):
-        """Generation done: decode span (first token -> last), TPOT, token
-        counters, refreshed slot/KV gauges."""
+    def _obs_retire(self, r: GenRequest, tail_s: float):
+        """Generation done: decode span (first token -> last; ``tail_s``:
+        how long the request had been over on the chip when the host
+        retired it), TPOT, token counters, refreshed slot/KV gauges.  A
+        buffered stream's span is the replica's to stamp, once the caller
+        has taken the stream's end (``RequestTrack.decode_span``)."""
         if not obs.enabled():
             return
         obs.add_tokens(r.deployment, "out", r.generated)
         first, last = r.emit_times[0], r.emit_times[-1]
-        obs.stamp_span(
-            "decode", self._wall + first, last - first,
+        span = dict(
+            name="decode", t0=self._wall + first, dur=last - first,
             trace_id=r.trace_ctx[0] if r.trace_ctx else None,
             parent_id=r.span_parent,
-            deployment=r.deployment, tokens=r.generated)
+            deployment=r.deployment, tokens=r.generated, tail_s=tail_s)
+        if r.track is not None and r.track.buffered:
+            r.track.decode_span = span
+        else:
+            obs.stamp_span(**span)
         if r.generated > 1:
             obs.observe_tpot(r.deployment, (last - first) / (r.generated - 1))
         self._obs_gauges()
@@ -918,14 +1031,16 @@ class LLMEngine:
                         chunks=self._admit_walk(admits, bucket)[0],
                         ahead=len(self._unfetched))
                     self._admit(admits, bucket)
+                    self._say_unbound(span)
                     if 2 * len(admits) > self.prefill_batch:
                         keep = 2    # a burst's admit: see the fetch below
                 did_work = True
             elif pending:
                 self._hold("slot")
             if self._active:
-                with _Phase(self, "dispatch"):
+                with _Phase(self, "dispatch") as span:
                     self._dispatch_step()
+                    self._say_unbound(span)
                 did_work = True
             # Fetch all but the program dispatched last, the decode dispatch
             # behind this pass's admit: when the fetch before it returns the
@@ -948,6 +1063,13 @@ class LLMEngine:
                 with _Phase(self, "idle"):
                     self._wake.wait(timeout=0.02)
                     self._wake.clear()
+
+    def _say_unbound(self, span):
+        """``unbound_s`` on the phase's span where the program it has just
+        dispatched found the chip standing (``_in_flight``)."""
+        last = self._unfetched[-1] if self._unfetched else None
+        if last is not None and last.unbound and last.seq == self._seq:
+            span.set_metadata(unbound_s=last.unbound)
 
     def _look(self):
         """One look at the queue, at the top of a pass that finds anything
@@ -1038,10 +1160,38 @@ class LLMEngine:
         self._seq += 1
         prog.seq = self._seq
         prog.streams = sum(1 for r in self._active.values() if r.emit_times)
+        if prog.streams:
+            self._count_unbound(prog)
         self._unfetched.append(prog)
 
-    def _program_done(self, prog: _Program, done: float):
-        """``prog``'s blocking fetch returned at ``done``.  With a stream
+    def _count_unbound(self, prog: _Program):
+        """Did ``prog``, dispatched with a stream live, find the chip
+        without a program, and since when?  With nothing in flight the
+        host has seen the last program end (``_done_at``): the chip has
+        stood since.  With one decode or speculative program in flight
+        whose output is there already the chip stands too, since that
+        program's start plus its run as a fetch that had to wait last
+        measured it (an estimate: the host never saw this one end).  In
+        the loop's steady state neither holds: the program in flight is
+        still running when the next is bound behind it."""
+        if not self._unfetched:
+            since = self._done_at
+        elif len(self._unfetched) == 1:
+            last = self._unfetched[0]
+            ran = self._ran.get((last.kind, last.k))
+            if ran is None or not last.out[0].is_ready():
+                return
+            since = max(last.dispatched, self._done_at) + ran
+        else:
+            return
+        if prog.dispatched > since:
+            prog.unbound = prog.dispatched - since
+            self.chip_unbound_s += prog.unbound
+            self.chip_unbound_n += 1
+
+    def _program_done(self, prog: _Program, done: float, waited: float):
+        """``prog``'s blocking fetch returned at ``done`` after ``waited``
+        seconds.  With a stream
         live the loop has bound what follows ``prog`` before it comes here
         (``_loop`` leaves one program in flight at its look, two behind a
         burst's admit), and comes here within the host's few milliseconds a
@@ -1055,8 +1205,12 @@ class LLMEngine:
         start = max(prog.dispatched, self._done_at)
         self._done_at = done
         ran = done - start
+        prog.start, prog.done = start, done
         self.stream_s += prog.streams * ran
         if prog.kind != "admit":
+            if waited > 1e-3:
+                # the fetch stood waiting: the host saw the program end
+                self._ran[prog.kind, prog.k] = ran
             return
         self.stream_admit_s += prog.streams * ran
         walked = sum(n for _r, n in prog.rows)
@@ -1193,8 +1347,9 @@ class LLMEngine:
         # the steps of this dispatch that find a live slot
         self.moe_expert_layer_steps += longest * self.cfg.expert_layers
 
-    def _emit_spec(self, tokens, counts, rounds, k: int, snapshot):
-        """Emit each slot's accepted window and fold the per-round emit
+    def _emit_spec(self, tokens, rounds, k: int, snapshot):
+        """Emit each slot's accepted window (``tokens[s]``: what its rounds
+        emitted, ``rounds[:, s]`` of them each) and fold the per-round emit
         counts into the acceptance tallies (a round's emit_count e in 1..k
         means e-1 drafts accepted + one verified correction; the k-1-e
         rejected drafts are the rollback)."""
@@ -1222,13 +1377,18 @@ class LLMEngine:
         if d_round:
             obs.record_spec_dispatch(self._obs_dep, d_round, d_tok,
                                      d_draft, d_acc)
+        # a slot's window holds what its rounds emitted, in their order:
+        # walked round by round, so that a request that ends knows the
+        # round that made its last token (_retire)
         for s, r in snapshot.items():
             if r.slot != s or self._active.get(s) is not r:
                 continue
-            for j in range(int(counts[s])):
-                if self._active.get(s) is not r:
-                    break
-                self._emit(r, int(tokens[s, j]))
+            window = iter(tokens[s])
+            for self._emit_step, row in enumerate(rounds):
+                for _ in range(int(row[s])):
+                    if self._active.get(s) is not r:
+                        break
+                    self._emit(r, int(next(window)))
 
     def _drain_one(self):
         """Fetch the oldest program in flight, then emit what it made."""
@@ -1238,18 +1398,19 @@ class LLMEngine:
         with fetch:
             # blocks until the program has run
             fetched = [np.asarray(a) for a in prog.out]
-        self._program_done(prog, fetch.t1)
+        self._program_done(prog, fetch.t1, fetch.t1 - fetch.t0)
         with _Phase(self, "emit") as span:
             before = self.tokens_out
+            self._emitting, self._emit_step, self._ended = prog, 0, []
             if prog.kind == "spec":
                 # rounds [num_rounds, slots]; the experts' counts where the
                 # model has them
-                tokens, counts, rounds, *moe = fetched
+                tokens, _counts, rounds, *moe = fetched
                 if moe:
                     self.moe_assignments += int(moe[0][0])
                     self.moe_experts_touched += int(moe[0][1])
-                self._emit_spec(tokens, counts, rounds, prog.k,
-                                prog.snapshot)
+                prog.steps = len(rounds)
+                self._emit_spec(tokens, rounds, prog.k, prog.snapshot)
             elif prog.kind == "admit":
                 # tokens is [prefill_batch], the real rows first
                 for (r, _n), token in zip(prog.rows, fetched[0]):
@@ -1263,11 +1424,19 @@ class LLMEngine:
                         tokens)
                     self.moe_assignments += ran
                     self.moe_experts_touched += touched
-                for k in range(tokens.shape[0]):
+                prog.steps = tokens.shape[0]
+                for k in range(prog.steps):
+                    self._emit_step = k
                     for s, r in prog.snapshot.items():
                         if r.slot == s and self._active.get(s) is r:
                             self._emit(r, int(tokens[k, s]))
             span.set_metadata(tokens=self.tokens_out - before)
+            if self._ended:
+                # the requests that ended with this program, each as
+                # "step:ms before the fetch's return": the engine's estimate
+                # of its end on the chip, to set beside the device's own
+                # event of the program the fetch span before this one names
+                span.set_metadata(ended=",".join(self._ended))
 
     def _emit(self, r: GenRequest, token: int):
         r.tokens.append(token)
@@ -1295,7 +1464,17 @@ class LLMEngine:
         if r.slot in self._active and self._active[r.slot] is r:
             del self._active[r.slot]
             self._free_slots.append(r.slot)
-            self._obs_retire(r)
+            # on the chip the request ended with the step (or round) that
+            # made its last token: that share of the program's run, the
+            # steps of one program taking the same time each
+            prog, r.retired_at = self._emitting, time.monotonic()
+            ended = prog.start + (prog.done - prog.start) * (
+                self._emit_step + 1) / prog.steps
+            self.retired_requests += 1
+            self._ended.append(
+                f"{self._emit_step}:{1e3 * (prog.done - ended):.3f}")
+            self._obs_retire(r, self._slots.retire(r.slot, ended,
+                                                   r.retired_at))
             if self.paged and r.pages:
                 # refcounted: shared prefix pages survive on the prefix
                 # cache's refs; private pages return to the free list.
@@ -1329,11 +1508,23 @@ class LLMServer:
 
     #: tokens yielded to callers and, summed over them, the seconds from a
     #: token's _emit on the engine thread to its yield on the replica's
-    #: loop (the queue, the executor thread's wake-up, the loop's turn);
-    #: written by the replica's loop alone.  Class attributes, so that a
-    #: subclass with its own constructor counts from zero too.
+    #: loop, in its two parts: to the return of ``req.out.get`` on the
+    #: executor thread (the queue, that thread's wake-up) and from there to
+    #: the loop resuming the generator (the hand-over, the loop's turn);
+    #: then how long the ``yield`` held the generator (what is downstream
+    #: of it: the actor's streaming reply and its back-pressure, or the
+    #: replica's buffer).  Written by the replica's loop alone.  Class
+    #: attributes, so that a subclass with its own constructor counts from
+    #: zero too.
     delivered_tokens = 0
     deliver_lag_s = 0.0
+    deliver_thread_s = 0.0
+    deliver_loop_s = 0.0
+    yield_hold_s = 0.0
+    #: the replica's account of a request's way in and out
+    #: (observability.RequestAccount), set by the replica actor that holds
+    #: this deployment; None for a server nobody serves
+    request_account = None
 
     async def __call__(self, request):
         """Async generator: polls the engine's token queue off-loop so one
@@ -1343,25 +1534,36 @@ class LLMServer:
 
         body = request.json() if hasattr(request, "json") else request
         tokens = body["tokens"]
+        track = obs.current_request()
         req = self.engine.submit(
             tokens, max_tokens=int(body.get("max_tokens", 64)),
             temperature=float(body.get("temperature", 0.0)),
-            eos_id=body.get("eos_id"))
+            eos_id=body.get("eos_id"), track=track)
         loop = asyncio.get_event_loop()
         delivered = 0
+
+        def take():
+            return req.out.get(), time.monotonic()
+
         while True:
-            item = await loop.run_in_executor(None, req.out.get)
+            item, got = await loop.run_in_executor(None, take)
             if not isinstance(item, int):
                 if isinstance(item, BaseException):
                     raise item
+                if track is not None:
+                    track.ended_at = req.retired_at
                 return  # _FLUSH
             # each token against its own emit stamp: one "last emit" stamp
             # would under-read exactly when delivery falls a dispatch behind
-            self.deliver_lag_s += (time.monotonic()
-                                   - req.emit_times[delivered])
+            now = time.monotonic()
+            thread, turn = got - req.emit_times[delivered], now - got
+            self.deliver_thread_s += thread
+            self.deliver_loop_s += turn
+            self.deliver_lag_s += thread + turn
             self.delivered_tokens += 1
             delivered += 1
             yield item
+            self.yield_hold_s += time.monotonic() - now
 
     def stats(self) -> dict:
         """Cumulative counters and the serving picture.  Two calls give a
@@ -1379,6 +1581,11 @@ class LLMServer:
                 "prefill_buckets": sorted(self.engine._prefill_fns),
                 "delivered_tokens": self.delivered_tokens,
                 "deliver_lag_s": self.deliver_lag_s,
+                "deliver_thread_s": self.deliver_thread_s,
+                "deliver_loop_s": self.deliver_loop_s,
+                "yield_hold_s": self.yield_hold_s,
+                **(self.request_account.snapshot()
+                   if self.request_account is not None else {}),
                 **self.engine.counters(),
                 **self.engine.breakdown()}
 

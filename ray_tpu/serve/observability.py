@@ -347,6 +347,167 @@ def stamp_span(name: str, t0: float, dur: float, *,
                                **attributes)
 
 
+# --------------------------------------- a request's way in and out
+
+class RequestAccount:
+    """Where a user request's time goes on either side of the engine, as a
+    replica sees it: cumulative and always on (plain numbers:
+    ``serve_metrics_enabled`` sheds the operator's spans and series, not
+    these).  The replica actor owns one, writes it on its loop (and on the
+    thread that calls ``LLMEngine.submit``) and hands it to a deployment
+    that declares a ``request_account`` attribute, as ``LLMServer`` does to
+    show it through ``stats()``.  A user request is a call with ``method is
+    None``; ``stats``, ``next_chunks`` and other named methods are none.
+
+    The way in: ``ingress_transit_s`` / ``_n`` (the caller's ``sent_at`` to
+    the call's arrival in this process, for calls that carry one),
+    ``ingress_queue_s`` (arrival to the first line of the replica's
+    method), ``ingress_submit_s`` (that line to the engine's submit stamp),
+    over ``ingress_requests``; ``ingress_clock_skew_n``: differences of
+    two processes' (or two readings of the wall) clocks that came out
+    negative and were booked as 0.  The buffered stream
+    (``handle_request_streaming`` / ``next_chunks``): ``buffer_wait_s`` over
+    ``buffered_chunks`` (a chunk's append to the poll that took it),
+    ``first_chunk_wait_s`` over ``first_chunks`` (the same for a stream's
+    first chunk), ``polls``, ``polls_empty`` (long-polls that timed out
+    with nothing), ``polls_before_end`` (polls that arrived while their
+    stream was still being generated).  A stream's end:
+    ``finish_deliver_s`` over ``finished_streams`` (the request's end, the
+    engine's retire where the deployment says it, to the reply that
+    carries the end of the stream leaving the replica)."""
+    KEYS = ("ingress_requests", "ingress_transit_n", "ingress_clock_skew_n",
+            "buffered_chunks", "first_chunks", "polls", "polls_empty",
+            "polls_before_end", "finished_streams")
+    SECONDS = ("ingress_transit_s", "ingress_queue_s", "ingress_submit_s",
+               "buffer_wait_s", "first_chunk_wait_s", "finish_deliver_s")
+
+    def __init__(self):
+        for k in self.KEYS:
+            setattr(self, k, 0)
+        for k in self.SECONDS:
+            setattr(self, k, 0.0)
+
+    def snapshot(self) -> dict:
+        return {k: getattr(self, k) for k in self.KEYS + self.SECONDS}
+
+    def since(self, t0: float, t1: float) -> float:
+        """``t1 - t0`` of stamps from two clocks' readings, never negative:
+        a negative difference is counted and booked as nothing."""
+        if t1 < t0:
+            self.ingress_clock_skew_n += 1
+            return 0.0
+        return t1 - t0
+
+
+class RequestTrack:
+    """One user request's stamps on its way through this process, made at
+    the first line of the replica's method.  ``sent_at`` and
+    ``received_at`` are readings of the wall clock (``time.time()``) in the
+    caller's and in this process, two processes of one machine; everything
+    after is this process's monotonic clock, and ``entered`` was read on
+    both, so that a monotonic stamp can be put on the wall (the stage
+    spans want wall time)."""
+    __slots__ = ("account", "sent_at", "received_at", "entered",
+                 "entered_wall",
+                 "transit_s", "queue_s", "ended_at", "buffered", "appended",
+                 "polls_before_end", "first_chunk_wait_s", "decode_span")
+
+    def __init__(self, account: RequestAccount, sent_at: Optional[float],
+                 received_at: Optional[float], buffered: bool = False):
+        self.entered, self.entered_wall = time.monotonic(), time.time()
+        self.account = acct = account
+        self.sent_at = sent_at
+        self.received_at = (received_at if received_at is not None
+                            else self.entered_wall)
+        acct.ingress_requests += 1
+        self.queue_s = acct.since(self.received_at, self.entered_wall)
+        acct.ingress_queue_s += self.queue_s
+        self.transit_s: Optional[float] = None
+        if sent_at is not None:
+            self.transit_s = acct.since(sent_at, self.received_at)
+            acct.ingress_transit_s += self.transit_s
+            acct.ingress_transit_n += 1
+        #: when the request was over (monotonic): the engine's retire where
+        #: the deployment says it, else the end of its generator
+        self.ended_at: Optional[float] = None
+        #: a buffered stream: the append stamp of every chunk, the polls
+        #: that arrived before the generator ended, what the first chunk
+        #: waited to be taken, and the decode span the engine left to be
+        #: stamped with them once the caller has the stream's end
+        self.buffered = buffered
+        self.appended: list = []
+        self.polls_before_end = 0
+        self.first_chunk_wait_s: Optional[float] = None
+        self.decode_span: Optional[dict] = None
+
+    def submitted(self, submitted_at: float, trace_ctx: Optional[tuple]):
+        """``LLMEngine.submit`` stamped the request at ``submitted_at``
+        (monotonic): the third leg, and the ``ingress`` span, from the
+        caller's send where known, else the arrival, to the submit.
+        Returns the trace context the request's stage spans chain under:
+        the ingress span as parent of ``batch_wait``."""
+        submit_s = max(0.0, submitted_at - self.entered)
+        self.account.ingress_submit_s += submit_s
+        if trace_ctx is None:
+            return None
+        t0 = self.sent_at if self.transit_s is not None else self.received_at
+        span_id = stamp_span(
+            "ingress", t0, (self.transit_s or 0.0) + self.queue_s + submit_s,
+            trace_id=trace_ctx[0], parent_id=trace_ctx[1],
+            deployment=current_deployment(), transit_s=self.transit_s,
+            queue_s=self.queue_s, submit_s=submit_s)
+        return (trace_ctx[0], span_id) if span_id else trace_ctx
+
+    def append(self):
+        """A chunk went into the stream's buffer."""
+        self.appended.append(time.monotonic())
+
+    def taken(self, cursor: int, nxt: int):
+        """A poll takes chunks ``cursor:nxt`` of the buffer."""
+        now, acct = time.monotonic(), self.account
+        acct.buffered_chunks += nxt - cursor
+        acct.buffer_wait_s += (nxt - cursor) * now - sum(
+            self.appended[cursor:nxt])
+        if cursor == 0:
+            self.first_chunk_wait_s = now - self.appended[0]
+            acct.first_chunks += 1
+            acct.first_chunk_wait_s += self.first_chunk_wait_s
+
+    def finished(self, delivered: bool = True):
+        """The reply that carries the end of the stream is leaving
+        (``delivered``), or the caller gave the stream up."""
+        now, acct = time.monotonic(), self.account
+        if delivered and self.ended_at is not None:
+            acct.finished_streams += 1
+            acct.finish_deliver_s += max(0.0, now - self.ended_at)
+        if self.decode_span is not None:
+            span, self.decode_span = self.decode_span, None
+            stamp_span(**span, first_chunk_wait_s=self.first_chunk_wait_s,
+                       polls_before_end=self.polls_before_end)
+
+
+#: the replica's record of the user request being handled on this
+#: task/coroutine (set by the replica around user code, like the
+#: deployment tag above); None for a named method and outside a replica
+_request_ctx: "contextvars.ContextVar[Optional[RequestTrack]]" = \
+    contextvars.ContextVar("raytpu_serve_request", default=None)
+
+
+def current_request() -> Optional[RequestTrack]:
+    return _request_ctx.get()
+
+
+def set_current_request(track: Optional[RequestTrack]):
+    return _request_ctx.set(track)
+
+
+def reset_current_request(token):
+    try:
+        _request_ctx.reset(token)
+    except ValueError:     # a foreign context: see reset_current_deployment
+        _request_ctx.set(None)
+
+
 # ------------------------------------------------------------ SLO window
 
 class SLOWindow:

@@ -16,6 +16,8 @@ from typing import Any, Dict, Optional
 
 import cloudpickle
 
+from ray_tpu.core.runtime_context import _task_context
+
 from . import observability as obs
 
 
@@ -55,12 +57,19 @@ class ReplicaActor:
         else:
             self.callable = None
             self._fn = func_or_class
+        #: a user request's way in and out, by leg; a deployment that
+        #: declares ``request_account`` gets it to show (LLMServer.stats())
+        self.account = obs.RequestAccount()
+        if hasattr(self.callable, "request_account"):
+            self.callable.request_account = self.account
         self.num_ongoing = 0
         self.num_processed = 0
         self._draining = False
         self.started_at = time.time()
         self._streams: Dict[str, list] = {}
         self._stream_done: Dict[str, bool] = {}
+        #: a user request's stream -> its stamps (obs.RequestTrack)
+        self._stream_tracks: Dict[str, obs.RequestTrack] = {}
         #: streams the CLIENT abandoned (stream timeout) -> cancel ts:
         #: the generator stops buffering and the finally path must not
         #: resurrect the done-flag entry — an unclaimed buffer would
@@ -74,17 +83,34 @@ class ReplicaActor:
 
     # ------------------------------------------------------- observability
 
-    def _obs_begin(self):
+    def _arrived(self, method: Optional[str], sent_at: Optional[float],
+                 buffered: bool = False) -> Optional[obs.RequestTrack]:
+        """The first line of the three serving methods: a user request
+        (``method is None``) gets its stamps: the caller's ``sent_at``
+        where it sent one, the call's arrival in this process from the
+        task context (``core_worker._task_ctx``), and now.
+        ``obs.RequestTrack`` books the way in from them.  ``stats``,
+        ``next_chunks`` and other named methods are no requests."""
+        if method is not None:
+            return None
+        task = _task_context.get()
+        return obs.RequestTrack(
+            self.account, sent_at,
+            task.get("received_at") if task else None, buffered)
+
+    def _obs_begin(self, track: Optional[obs.RequestTrack]):
         """Per-request instrumentation entry: install the event-loop stall
         monitor once (this runs ON the actor loop — __init__ does not),
         publish queue depth, and tag downstream instrumentation
         (@serve.batch, the LLM engine) with this deployment's config
-        name.  Returns (t0, ctx token) for _obs_end."""
+        name and the request's stamps.  Returns (t0, ctx tokens) for
+        _obs_end."""
         obs.ensure_loop_monitor(
             self, f"serve_replica:{self.deployment_name}")
         obs.set_replica_queue_depth(self.deployment_name, self.num_ongoing)
-        return time.monotonic(), obs.set_current_deployment(
-            self.deployment_name)
+        return (time.monotonic(),
+                obs.set_current_deployment(self.deployment_name),
+                obs.set_current_request(track))
 
     def _obs_end(self, begin, first_token_at: Optional[float] = None,
                  ok: bool = True, window: bool = True):
@@ -98,16 +124,17 @@ class ReplicaActor:
         control routes) skip the WINDOW so fast non-inference polls can't
         mask real serving degradation — they still land in the TTFT
         histogram under the same deployment tag."""
-        t0, token = begin
+        t0, token, request_token = begin
         obs.set_replica_queue_depth(self.deployment_name, self.num_ongoing)
         if ok:
             obs.observe_ttft(self.deployment_name,
                              (first_token_at if first_token_at is not None
                               else time.monotonic()) - t0,
                              window=window)
-        # last: the ctx reset is the one step that can be running inside
+        # last: the ctx resets are the one step that can be running inside
         # asyncgen finalization (foreign context) — nothing may depend on it
         obs.reset_current_deployment(token)
+        obs.reset_current_request(request_token)
 
     # ------------------------------------------------------------- serving
 
@@ -122,11 +149,16 @@ class ReplicaActor:
         raise AttributeError(f"{type(target)} is not callable; specify method")
 
     async def handle_request(self, args: tuple, kwargs: dict,
-                             method: Optional[str] = None) -> Any:
+                             method: Optional[str] = None,
+                             sent_at: Optional[float] = None) -> Any:
+        """``sent_at`` (here and on the two streaming methods): the
+        caller's wall clock before it chose a replica, where program code
+        makes the call (the router, the proxies)."""
+        track = self._arrived(method, sent_at)
         if self._draining:
             raise RuntimeError(f"replica {self.replica_id} is draining")
         self.num_ongoing += 1
-        begin = self._obs_begin()
+        begin = self._obs_begin(track)
         ok = False
         try:
             if args and isinstance(args[0], Request):
@@ -148,9 +180,14 @@ class ReplicaActor:
 
     async def handle_request_streaming(self, stream_id: str, args: tuple,
                                        kwargs: dict,
-                                       method: Optional[str] = None) -> None:
+                                       method: Optional[str] = None,
+                                       sent_at: Optional[float] = None
+                                       ) -> None:
         """Run a (async) generator endpoint, buffering chunks for the caller
-        to drain via next_chunks() — streaming over the actor RPC plane."""
+        to drain via next_chunks() — streaming over the actor RPC plane.
+        Every chunk's append is stamped on the request's track; the poll
+        that takes it books what it waited."""
+        track = self._arrived(method, sent_at, buffered=True)
         if stream_id in self._cancelled_streams:
             # cancel raced ahead of a queued start: never register (and
             # consume the tombstone BEFORE the draining check — either
@@ -162,49 +199,54 @@ class ReplicaActor:
         self.num_ongoing += 1
         self._streams[stream_id] = []
         self._stream_done[stream_id] = False
-        begin = self._obs_begin()
+        if track is not None:
+            self._stream_tracks[stream_id] = track
+        begin = self._obs_begin(track)
         first_at: Optional[float] = None
         ok = False
         try:
             fn = self._resolve(method)
             out = fn(*args, **kwargs)
 
-            def buf():
-                # None once the client cancelled (stream timeout): stop
+            def put(chunk) -> bool:
+                # False once the client cancelled (stream timeout): stop
                 # generating instead of appending into a popped buffer
-                return self._streams.get(stream_id)
+                b = self._streams.get(stream_id)
+                if b is None:
+                    return False
+                b.append(chunk)
+                if track is not None:
+                    track.append()
+                return True
 
             if inspect.isasyncgen(out):
                 async for chunk in out:
                     if first_at is None:
                         first_at = time.monotonic()
-                    b = buf()
-                    if b is None:
+                    if not put(chunk):
                         break
-                    b.append(chunk)
             elif inspect.isgenerator(out):
                 for chunk in out:
                     if first_at is None:
                         first_at = time.monotonic()
-                    b = buf()
-                    if b is None:
+                    if not put(chunk):
                         break
-                    b.append(chunk)
                     await asyncio.sleep(0)  # let pollers interleave
             else:
                 if inspect.iscoroutine(out):
                     out = await out
-                b = buf()
-                if b is not None:
-                    b.append(out)
+                put(out)
             ok = True
         finally:
+            if track is not None and track.ended_at is None:
+                # a deployment that does not say when its request was over
+                # (LLMServer does: the engine's retire): the generator's end
+                track.ended_at = time.monotonic()
             if stream_id in self._cancelled_streams:
                 # abandoned: every trace of the stream is already gone —
                 # resurrecting the done flag would leak an entry forever
                 self._cancelled_streams.pop(stream_id, None)
-                self._streams.pop(stream_id, None)
-                self._stream_done.pop(stream_id, None)
+                self._forget(stream_id)
             else:
                 self._stream_done[stream_id] = True
             self.num_ongoing -= 1
@@ -213,16 +255,18 @@ class ReplicaActor:
                           window=method is None)
 
     async def handle_request_gen(self, args: tuple, kwargs: dict,
-                                 method: Optional[str] = None):
+                                 method: Optional[str] = None,
+                                 sent_at: Optional[float] = None):
         """Streaming endpoint as a native streaming-generator actor method
         (called with ``num_returns="streaming"``): each chunk ships to the
         caller the moment it is yielded — no next_chunks long-poll round
         trips (that path remains for deployment handles that want the
         buffered protocol)."""
+        track = self._arrived(method, sent_at)
         if self._draining:
             raise RuntimeError(f"replica {self.replica_id} is draining")
         self.num_ongoing += 1
-        begin = self._obs_begin()
+        begin = self._obs_begin(track)
         first_at: Optional[float] = None
         ok = False
         try:
@@ -245,6 +289,11 @@ class ReplicaActor:
                 first_at = time.monotonic()
                 yield out
             ok = True
+            if track is not None:
+                # this return is the reply that carries the stream's end
+                if track.ended_at is None:
+                    track.ended_at = time.monotonic()
+                track.finished()
         finally:
             self.num_ongoing -= 1
             self.num_processed += 1
@@ -267,14 +316,28 @@ class ReplicaActor:
             if now - ts > 120.0:
                 self._cancelled_streams.pop(sid, None)
         done = self._stream_done.get(stream_id)
-        self._streams.pop(stream_id, None)
-        self._stream_done.pop(stream_id, None)
+        self._forget(stream_id)
         if done is not True:
             self._cancelled_streams[stream_id] = now
         return True
 
+    def _forget(self, stream_id: str, delivered: bool = False):
+        """Drop every trace of a stream: its end was ``delivered``, or its
+        caller gave it up.  Either way nothing more will be taken: the
+        track books the end's delivery and stamps what it was left to."""
+        self._streams.pop(stream_id, None)
+        self._stream_done.pop(stream_id, None)
+        track = self._stream_tracks.pop(stream_id, None)
+        if track is not None:
+            track.finished(delivered)
+
     async def next_chunks(self, stream_id: str, cursor: int) -> tuple:
         """Poll a stream: returns (new_chunks, next_cursor, done)."""
+        acct, track = self.account, self._stream_tracks.get(stream_id)
+        acct.polls += 1
+        if track is not None and not self._stream_done.get(stream_id, True):
+            acct.polls_before_end += 1
+            track.polls_before_end += 1
         for _ in range(200):  # long-poll up to ~2s per call
             buf = self._streams.get(stream_id)
             if buf is None:
@@ -283,15 +346,16 @@ class ReplicaActor:
                 chunks = buf[cursor:]
                 done = self._stream_done.get(stream_id, False)
                 nxt = cursor + len(chunks)
+                if track is not None:
+                    track.taken(cursor, nxt)
                 if done and nxt == len(buf):
-                    self._streams.pop(stream_id, None)
-                    self._stream_done.pop(stream_id, None)
+                    self._forget(stream_id, delivered=True)
                 return chunks, nxt, done
             if self._stream_done.get(stream_id, False):
-                self._streams.pop(stream_id, None)
-                self._stream_done.pop(stream_id, None)
+                self._forget(stream_id, delivered=True)
                 return [], cursor, True
             await asyncio.sleep(0.01)
+        acct.polls_empty += 1
         return [], cursor, False
 
     # ------------------------------------------------------------ lifecycle

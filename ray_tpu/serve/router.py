@@ -251,14 +251,20 @@ class Router:
         """Route one request; returns (replica_name, result ObjectRef).
 
         A replica whose name no longer resolves (actor died and was
-        deregistered) is evicted and the request re-routed."""
+        deregistered) is evicted and the request re-routed.  ``sent_at``,
+        this process's wall clock before a replica is chosen, rides the
+        call: the replica books what lay between it and the call's arrival
+        (``ingress_transit_s``: the choice, a ``_refresh`` that asks the
+        controller, this process's flush window, outbox and pump, the
+        RPC)."""
         last_err: Optional[Exception] = None
         hint = _hint_tokens(args, kwargs)
+        sent_at = time.time()
         for _ in range(5):
             name = self.choose_replica(deployment, hint_tokens=hint)
             try:
                 h = self._replica_handle(name)
-                ref = h.handle_request.remote(args, kwargs, method)
+                ref = h.handle_request.remote(args, kwargs, method, sent_at)
             except Exception as e:  # noqa: BLE001 — dead name, submit fail
                 last_err = e
                 self._evict(deployment, name)
@@ -284,16 +290,17 @@ class Router:
     def start_stream(self, deployment: str, args: tuple, kwargs: dict,
                      method: Optional[str] = None) -> tuple:
         """Kick off a streaming request; returns (replica_name, stream_id,
-        completion ref)."""
+        completion ref).  ``sent_at`` rides the call as in ``assign``."""
         last: Optional[Exception] = None
         hint = _hint_tokens(args, kwargs)
+        sent_at = time.time()
         for _ in range(5):
             name = self.choose_replica(deployment, hint_tokens=hint)
             stream_id = uuid.uuid4().hex
             try:
                 h = self._replica_handle(name)
-                ref = h.handle_request_streaming.remote(stream_id, args,
-                                                        kwargs, method)
+                ref = h.handle_request_streaming.remote(
+                    stream_id, args, kwargs, method, sent_at)
                 # streams count toward p2c load + the queue-depth gauge
                 # like unary calls — long-lived LLM streams are exactly
                 # the traffic the SLO signal must see; the completion ref

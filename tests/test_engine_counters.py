@@ -54,13 +54,13 @@ def test_admitted_tokens_are_counted_real_and_padded(tiny_cfg, paged):
     kw = dict(paged=True, page_size=8, num_pages=96) if paged else {}
     eng = _engine(tiny_cfg, **kw)
     try:
-        seen = _spy_admits(eng)
+        seen, programs = _spy_admits(eng), _spy_programs(eng)
         prompts = [[1 + (i + j) % 50 for j in range(n)]
                    for i, n in enumerate(PROMPT_LENS)]
         _run(eng, prompts)
         c = eng.counters()
         # buckets this short are walked whole: no chunks
-        assert c["admit_chunks"] == c["admit_rows_chunked"] == 0
+        assert not any(p.chunks for p, _ in programs)
         assert c["admit_tokens_real"] == sum(PROMPT_LENS)
         assert c["admit_tokens_real"] == sum(real for _, _, real in seen)
         # every position the chip walked is counted once, as a prompt token
@@ -110,8 +110,9 @@ def test_chunked_rows_count_the_chunks_they_walked(tiny_cfg, kind):
     walked in the chunks a prompt fills (``decode.prefill_width``; the
     latent tree's chunk is the one its experts ask for, not the shortest):
     the padding counted is what the chip walked less the prompt, a row
-    rounded up to whole chunks and not to its bucket, and ``admit_chunks`` /
-    ``admit_rows_chunked`` say how often."""
+    rounded up to whole chunks and not to its bucket, and the admit programs
+    (their ``chunks``, which ride the ``raytpu:engine.admit`` span) say how
+    often."""
     from ray_tpu.models import decode
     if kind == "latent":
         chunk, bucket, short = 8, 32, 16
@@ -124,7 +125,7 @@ def test_chunked_rows_count_the_chunks_they_walked(tiny_cfg, kind):
     try:
         assert decode.prefill_width(eng.cache, bucket, eng.cfg) == chunk
         assert decode.prefill_width(eng.cache, short, eng.cfg) == short
-        seen = _spy_admits(eng)
+        seen, programs = _spy_admits(eng), _spy_programs(eng)
         # (3, whole, 4, 4 and 3 chunks; the second sits in the short bucket)
         lens = (chunk * 2 + chunk // 8, short * 5 // 8,
                 chunk * 3 + chunk // 4, bucket, chunk * 3)
@@ -132,8 +133,9 @@ def test_chunked_rows_count_the_chunks_they_walked(tiny_cfg, kind):
                    for i, n in enumerate(lens)], max_tokens=2)
         c = eng.counters()
         assert c["admit_tokens_real"] == sum(lens)
-        assert c["admit_chunks"] == 3 + 4 + 4 + 3
-        assert c["admit_rows_chunked"] == 4
+        chunked = [p for p, _ in programs if p.chunks]
+        assert sum(p.chunks for p in chunked) == 3 + 4 + 4 + 3
+        assert sum(len(p.rows) for p in chunked) == 4
         assert c["admit_tokens_padded"] == (
             (chunk - chunk // 8) + (short - short * 5 // 8)
             + (chunk - chunk // 4) + 0 + 0)
@@ -199,8 +201,8 @@ def test_gen_request_keeps_one_clock_per_stage():
     from ray_tpu.serve.llm import GenRequest
     clocks = [s for s in GenRequest.__slots__
               if s.endswith(("_at", "_wall", "_times"))]
-    assert sorted(clocks) == ["admitted_at", "emit_times", "seen_at",
-                              "submitted_at"]
+    assert sorted(clocks) == ["admitted_at", "emit_times", "retired_at",
+                              "seen_at", "submitted_at"]
 
 
 def test_phases_partition_the_engine_threads_time(tiny_cfg):
@@ -368,6 +370,177 @@ def test_an_admit_stalls_the_streams_live_at_its_dispatch(tiny_cfg):
         _finish([long])
         both = eng.counters()
         assert 0 < both["stream_admit_s"] <= both["stream_s"]
+    finally:
+        eng.shutdown()
+
+
+# ------------------------------- a slot's time, by what it was doing (PR 56)
+
+SLOT_KEYS = tuple(f"slot_{k}_s" for k in
+                  ("prefill", "live", "tail", "queued", "unfed"))
+
+
+def _slot_seconds(c0, c1):
+    return sum(c1[k] - c0[k] for k in SLOT_KEYS)
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_the_five_slot_states_add_up_between_any_two_snapshots(tiny_cfg,
+                                                               kind):
+    """Every slot-second belongs to one state: between ANY two snapshots,
+    with requests queued, in prefill, mid-answer or just retired, the five
+    sums grow by slots x the time between them, to the millisecond."""
+    eng = _engine(_two_layers(tiny_cfg), num_slots=3, **ENGINES[kind])
+    try:
+        snaps = [eng.counters()]                       # nothing asked yet
+        reqs = [eng.submit([1 + i, 2, 3, 4 + i], max_tokens=12 + 9 * i)
+                for i in range(5)]                     # two have to queue
+        while any(r.retired_at is None for r in reqs):
+            snaps.append(eng.counters())               # mid-request
+            time.sleep(0.003)
+        _finish(reqs)
+        time.sleep(0.05)
+        snaps.append(eng.counters())                   # every slot free
+        assert len(snaps) > 5
+        for c0, c1 in zip(snaps, snaps[1:]):
+            want = eng.num_slots * (c1["t_mono"] - c0["t_mono"])
+            assert abs(_slot_seconds(c0, c1) - want) < 1e-3
+        want = eng.num_slots * (snaps[-1]["t_mono"] - snaps[0]["t_mono"])
+        assert abs(_slot_seconds(snaps[0], snaps[-1]) - want) < 1e-3
+        last = snaps[-1]
+        assert all(last[k] > 0 for k in SLOT_KEYS), last
+        assert last["retired_requests"] == 5
+        # a free slot waits for a request no longer than that request waits
+        # for anything: the queued seconds lie inside the queue wait
+        assert last["slot_queued_s"] <= last["queue_wait_s"] + EPS
+    finally:
+        eng.shutdown()
+
+
+def _spy_slots(eng):
+    """What the engine thread books: every ``take`` as (request's submit,
+    the admit's dispatch, the unfed seconds returned) and every ``retire``
+    as (end on the chip, retire, the run of the program whose tokens were
+    being emitted)."""
+    takes, retires, acct = [], [], eng._slots
+    take, retire = acct.take, acct.retire
+
+    def spy_take(slot, submitted_at, now):
+        unfed = take(slot, submitted_at, now)
+        takes.append((submitted_at, now, unfed))
+        return unfed
+
+    def spy_retire(slot, ended, now):
+        prog = eng._emitting
+        retires.append((ended, now, prog.start, prog.done))
+        return retire(slot, ended, now)
+
+    acct.take, acct.retire = spy_take, spy_retire
+    return takes, retires
+
+
+def test_a_vacancy_is_queued_or_unfed_by_the_submit(tiny_cfg):
+    """One slot.  A request submitted while the slot is taken finds it free
+    later than its own submit: the vacancy is all queued, none unfed.  One
+    submitted after the slot has stood free books the time up to its
+    submit as unfed and the rest, to its admit, as queued."""
+    eng = _engine(tiny_cfg, num_slots=1)
+    try:
+        _run(eng, [[1, 2, 3]], max_tokens=2)      # compiled, slot free again
+        takes, _retires = _spy_slots(eng)
+        first = eng.submit([1, 2, 3, 4], max_tokens=30)
+        behind = eng.submit([2, 3, 4, 5], max_tokens=4)   # the slot is taken
+        _finish([first, behind])
+        c0 = eng.counters()
+        time.sleep(0.25)
+        late = eng.submit([3, 4, 5, 6], max_tokens=4)
+        _finish([late])
+        c1 = eng.counters()
+        assert [t[0] for t in takes] == [first.submitted_at,
+                                         behind.submitted_at,
+                                         late.submitted_at]
+        assert behind.submitted_at < first.retired_at
+        assert takes[1][2] == 0.0                          # none unfed
+        assert takes[2][2] == pytest.approx(
+            late.submitted_at - behind.retired_at, abs=EPS)
+        assert takes[2][2] >= 0.25
+        # the counters grew by just that: unfed up to the submit, and after
+        # the last retire up to the second snapshot
+        unfed = c1["slot_unfed_s"] - c0["slot_unfed_s"]
+        assert unfed == pytest.approx(
+            (late.submitted_at - c0["t_mono"])
+            + (c1["t_mono"] - late.retired_at), abs=1e-6)
+        queued = c1["slot_queued_s"] - c0["slot_queued_s"]
+        assert queued == pytest.approx(
+            late.admitted_at - late.submitted_at, abs=1e-6)
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_the_tail_is_what_was_left_of_the_dispatch(tiny_cfg, kind):
+    """A request ends on the chip with the step (a speculative engine's
+    round) that made its last token.  Where that is the last of its
+    dispatch, nothing of the program's run is tail; where it is not, the
+    steps after it are."""
+    eng = _engine(_two_layers(tiny_cfg), steps_per_dispatch=8,
+                  **ENGINES[kind])
+    try:
+        _run(eng, [[1, 2, 3]], max_tokens=2)
+        _takes, retires = _spy_slots(eng)
+        c0 = eng.counters()
+        # the admit makes a token; a dispatch makes 8 (4 rounds of up to 2)
+        whole = _run(eng, [[1, 2, 3, 4]], max_tokens=9 if kind != "spec"
+                     else 64)[0]
+        (ended, now, start, done), = retires
+        if kind != "spec":
+            assert whole.generated == 9
+            assert ended == pytest.approx(done, abs=EPS)
+        assert start < ended <= done + EPS <= now + EPS
+        del retires[:]
+        part = _run(eng, [[1, 2, 3, 4]], max_tokens=5 if kind != "spec"
+                    else 3)[0]
+        (ended, now, start, done), = retires
+        assert part.retired_at == now
+        if kind != "spec":
+            # tokens 2-5 are steps 0-3 of 8: half the run was left
+            assert done - ended == pytest.approx((done - start) / 2, abs=EPS)
+        else:
+            # the first round makes tokens 2 and perhaps 3, the second
+            # the third at the latest: of four rounds two or three were left
+            assert done - ended >= (done - start) / 2 - EPS
+        c1 = eng.counters()
+        assert c1["slot_tail_s"] - c0["slot_tail_s"] >= done - ended
+        assert c1["retired_requests"] - c0["retired_requests"] == 2
+    finally:
+        eng.shutdown()
+
+
+def test_an_unbound_chip_is_counted_where_a_stream_is_live(tiny_cfg):
+    """With a stream live and nothing in flight, the next program's
+    dispatch finds the chip standing since the last program's end."""
+    from ray_tpu.serve.llm import _Program
+    eng = _engine(tiny_cfg)
+    try:
+        _run(eng, [[1, 2, 3]], max_tokens=20)
+        c = eng.counters()
+        assert c["chip_unbound_n"] >= 0 and c["chip_unbound_s"] >= 0.0
+        eng.shutdown()                       # the thread gone: by hand
+        n, s = eng.chip_unbound_n, eng.chip_unbound_s
+        assert not eng._unfetched
+        eng._done_at = 10.0
+        idle = _Program("decode", (), 10.5)
+        eng._in_flight(idle)                 # no stream live: not counted
+        assert (eng.chip_unbound_n, idle.unbound) == (n, 0.0)
+        del eng._unfetched[:]
+        live = eng.submit([1, 2], max_tokens=4)
+        live.emit_times.append(9.0)
+        eng._active[0] = live
+        prog = _Program("decode", (), 10.5)
+        eng._in_flight(prog)
+        assert prog.unbound == pytest.approx(0.5)
+        assert eng.chip_unbound_n == n + 1
+        assert eng.chip_unbound_s == pytest.approx(s + 0.5)
     finally:
         eng.shutdown()
 
@@ -569,15 +742,14 @@ def test_server_counts_delivered_tokens_and_their_lag():
         assert st["delivered_tokens"] == st["tokens_out"] == 18
         assert 0 <= st["deliver_lag_s"] < 18 * 5.0
         # what the committed readers difference keeps its names
-        for key in ("steps", "tokens_out", "admit_batches",
-                    "batch_occupancy", "num_slots",
+        for key in ("steps", "tokens_out", "admit_batches", "num_slots",
                     "active", "free_slots", "prefill_buckets"):
             assert key in st
         # and the new keys ride along
         for key in ["t_mono", "loop_iterations", "admitted_requests",
                     "queue_wait_s", "first_tokens", "first_token_wait_s",
                     "admit_tokens_real", "admit_tokens_padded",
-                    "admit_chunks", "admit_rows_chunked", *WAIT_KEYS] + [
+                    *WAIT_KEYS] + [
                         f"loop_{ph}_{k}" for ph in ENGINE_PHASES
                         for k in ("s", "n")]:
             assert key in st, key

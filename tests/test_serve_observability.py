@@ -210,8 +210,8 @@ def _span_index(evs):
 
 
 def _find_chain(evs):
-    """proxy_recv -> router_queue -> replica task -> batch_wait ->
-    prefill -> decode, linked by (trace_id, parent_id)."""
+    """proxy_recv -> router_queue -> replica task -> ingress -> batch_wait
+    -> prefill -> decode, linked by (trace_id, parent_id)."""
     spans = _span_index(evs)
 
     def child(name, trace_id, parent_span):
@@ -234,7 +234,10 @@ def _find_chain(evs):
              and "handle_request" in (e.get("name") or "")), None)
         if replica is None:
             continue
-        batch = child("batch_wait", tid, replica.get("span_id"))
+        ingress = child("ingress", tid, replica.get("span_id"))
+        if ingress is None:
+            continue
+        batch = child("batch_wait", tid, ingress["span_id"])
         if batch is None:
             continue
         prefill = child("prefill", tid, batch["span_id"])
@@ -243,14 +246,14 @@ def _find_chain(evs):
         decode = child("decode", tid, prefill["span_id"])
         if decode is None:
             continue
-        return [proxy, router, replica, batch, prefill, decode]
+        return [proxy, router, replica, ingress, batch, prefill, decode]
     return None
 
 
 def test_traced_request_renders_one_connected_chain(llm_http):
     """Acceptance: ONE traced HTTP request = ONE connected cross-process
-    trace with proxy → router → replica → batch_wait → prefill → decode,
-    and chrome_trace() renders every link as a slice with flow arrows."""
+    trace with proxy → router → replica → ingress → batch_wait → prefill →
+    decode, and chrome_trace() renders every link as a slice with flow arrows."""
     from ray_tpu.util.tracing import chrome_trace
 
     _h, base = llm_http
@@ -265,7 +268,10 @@ def test_traced_request_renders_one_connected_chain(llm_http):
     assert chain is not None, (
         f"no connected chain in {len(evs)} events; spans seen: "
         f"{sorted(_span_index(evs))}")
-    proxy, router, replica, batch, prefill, decode = chain
+    proxy, router, replica, ingress, batch, prefill, decode = chain
+    # the proxy's stamp rode the call: the way in is known from its send
+    assert ingress["attributes"]["transit_s"] >= 0
+    assert ingress["attributes"]["queue_s"] >= 0
     # the whole chain shares ONE trace id
     assert len({e.get("trace_id") for e in chain}) == 1
     # stage spans carry the deployment tag from config
@@ -275,12 +281,12 @@ def test_traced_request_renders_one_connected_chain(llm_http):
     # Perfetto draws the arrows across process rows
     trace = chrome_trace(evs)
     slice_names = {t.get("name") for t in trace if t.get("ph") == "X"}
-    for name in ("proxy_recv", "router_queue", "batch_wait", "prefill",
-                 "decode"):
+    for name in ("proxy_recv", "router_queue", "ingress", "batch_wait",
+                 "prefill", "decode"):
         assert name in slice_names, f"no slice for {name}"
     flow_ids = {t.get("id") for t in trace if t.get("ph") == "s"}
     fin_ids = {t.get("id") for t in trace if t.get("ph") == "f"}
-    for e in (router, batch, prefill, decode):
+    for e in (router, ingress, batch, prefill, decode):
         assert e["parent_id"] in flow_ids, f"no flow start for {e['name']}"
         assert e["parent_id"] in fin_ids, f"no flow finish into {e['name']}"
 
@@ -366,4 +372,4 @@ def test_slo_signal_surface(llm_http):
     # engine-side breakdown reaches the handle path with the bench schema
     stats = h.stats.remote().result(timeout_s=60)
     assert _load_bench_llm().ENGINE_KEYS <= set(stats), stats
-    assert 0.0 < stats["batch_occupancy"] <= 1.0
+    assert stats["admit_batches"] > 0

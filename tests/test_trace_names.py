@@ -264,6 +264,14 @@ def test_engine_spans_land_in_a_profiler_capture(tiny_cfg, tmp_path):
     assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
     assert sum(st["tokens"] for _d, st in by_name["raytpu:engine.emit"]) \
         == 27
+    # a request that ended with a program rides its emit span as
+    # "step:ms before the fetch's return" (PR 56): 9 tokens are the admit's
+    # one and a whole dispatch's eight, so each ended with step 7, at the
+    # program's end
+    ended = [st["ended"] for _d, st in by_name["raytpu:engine.emit"]
+             if "ended" in st]
+    assert [e.split(":")[0] for e in ended] == ["7", "7", "7"]
+    assert all(float(e.split(":")[1]) == 0.0 for e in ended)
     # the capture brackets the two snapshots, so it holds at least the
     # intervals the counters counted between them, and about their seconds
     for ph in ("admit", "dispatch", "fetch", "emit"):
@@ -280,7 +288,10 @@ def test_stage_spans_carry_the_wait_account(tiny_cfg, monkeypatch):
     """The task-event spans of a request say what its counters sum: a
     ``batch_wait`` its wait for a look, the looks that left it and why, a
     ``prefill`` the programs ahead of its admit, its own row's part of the
-    admit's run, and the admit's rows and chunks."""
+    admit's run, and the admit's rows and chunks; since PR 56 a
+    ``batch_wait`` also how long the slot it took had stood free with
+    nobody asking (``unfed_s``) and a ``decode`` how long the request had
+    been over on the chip when the host retired it (``tail_s``)."""
     from ray_tpu.core.config import Config, reset_config, set_config
     from ray_tpu.serve.llm import _FLUSH, GenRequest
     from ray_tpu.util import tracing
@@ -309,9 +320,19 @@ def test_stage_spans_carry_the_wait_account(tiny_cfg, monkeypatch):
     finally:
         reset_config()
     waits, prefills = spans["batch_wait"], spans["prefill"]
-    assert len(waits) == len(prefills) == 2
+    assert len(waits) == len(prefills) == len(spans["decode"]) == 2
+    assert "ingress" not in spans        # the replica's span, not the engine's
+    for sp in spans["decode"]:
+        assert {"tokens", "tail_s"} <= set(sp) and sp["tail_s"] >= 0
+        # an engine's own caller has no buffered stream to account
+        assert not {"first_chunk_wait_s", "polls_before_end"} & set(sp)
+    assert sum(sp["tail_s"] for sp in spans["decode"]) == pytest.approx(
+        c["slot_tail_s"])
+    # both slots had stood free since the engine started
+    assert sum(sp["unfed_s"] for sp in waits) <= c["slot_unfed_s"]
     for sp in waits:
-        assert {"look_s", "held_s", "held_by"} <= set(sp)
+        assert {"look_s", "held_s", "held_by", "unfed_s"} <= set(sp)
+        assert sp["unfed_s"] > 0
         assert sp["look_s"] >= 0 and sp["held_s"] >= 0
         assert sp["look_s"] + sp["held_s"] <= sp["dur"] + 1e-9
     assert [sp["held_by"] for sp in waits] == [None, "bucket"]
@@ -326,6 +347,57 @@ def test_stage_spans_carry_the_wait_account(tiny_cfg, monkeypatch):
         c["queue_held_s"])
     assert sum(sp["own_row_s"] for sp in prefills) == pytest.approx(
         c["first_token_own_row_s"])
+
+
+def test_the_ingress_span_heads_the_requests_chain(monkeypatch):
+    """``ingress`` (transit, queue, submit) is the parent of ``batch_wait``;
+    a buffered stream's ``decode`` is stamped once its end is taken, with
+    what its first chunk waited and the polls that came before its end."""
+    import asyncio
+
+    import cloudpickle
+
+    from ray_tpu.core.config import Config, reset_config, set_config
+    from ray_tpu.serve.llm import LLMServer
+    from ray_tpu.serve.replica import ReplicaActor
+    from ray_tpu.util import tracing
+
+    spans = []
+    rep = ReplicaActor("spandep", "serve:spandep:1", cloudpickle.dumps((
+        LLMServer, ("tiny",), dict(num_slots=4, max_len=64,
+                                   engine_kwargs={"buckets": (16, 32)}))))
+    monkeypatch.setattr(
+        tracing, "record_span",
+        lambda name, t0, dur, **kw: spans.append(
+            dict(kw, name=name, t0=t0, dur=dur)) or f"sid-{len(spans)}")
+    body = {"tokens": [1, 2, 3, 4], "max_tokens": 5}
+
+    async def run():
+        sent_at = time.time() - 0.125
+        await rep.handle_request_streaming("sp", (body,), {}, None, sent_at)
+        assert [s["name"] for s in spans] == ["ingress", "batch_wait",
+                                              "prefill"]
+        return sent_at, await rep.next_chunks("sp", 0)
+
+    try:
+        set_config(Config(serve_metrics_enabled=True))
+        sent_at, (chunks, cursor, done) = asyncio.run(run())
+    finally:
+        reset_config()
+        rep.callable.engine.shutdown()
+    assert (len(chunks), cursor, done) == (5, 5, True)
+    ingress, wait, _prefill, decode = spans
+    assert decode["name"] == "decode"
+    assert ingress["t0"] == sent_at
+    assert ingress["transit_s"] == pytest.approx(0.125, abs=0.05)
+    assert ingress["queue_s"] >= 0 and ingress["submit_s"] > 0
+    assert ingress["dur"] == pytest.approx(
+        ingress["transit_s"] + ingress["queue_s"] + ingress["submit_s"])
+    assert wait["parent_id"] == "sid-1" and wait["unfed_s"] > 0
+    assert wait["trace_id"] == ingress["trace_id"] == decode["trace_id"]
+    assert decode["tail_s"] >= 0 and decode["tokens"] == 5
+    assert decode["polls_before_end"] == 0
+    assert decode["first_chunk_wait_s"] > 0
 
 
 # ------------------- the kinds of model the benchmark serves (tests/kinds.py)
