@@ -74,6 +74,7 @@ No torch, no dynamic shapes, no per-request Python in the hot loop.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -174,16 +175,23 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
     sequence: a recurrent state, a convolution tail), the layers of each
     kind (``ssm_layers``, and ``window_layers`` with their rings' bytes,
     where the model has them; a multi-token-prediction block's rows count
-    among the keys and values), and the experts a layer holds."""
-    def nbytes(*names):
-        return sum(int(a.size) * jnp.dtype(a.dtype).itemsize
+    among the keys and values), and the experts a layer holds.
+    ``cache_state_hbm_bytes`` is ``cache_state_bytes`` with every array's
+    minor dimension in whole tiles of 128 lanes, as the chip stores it: the
+    two are equal where no lane holds nothing (a delta-rule state of 192
+    lanes a head is stored in 256 unless ``gated_delta.pack_state`` packs
+    two heads a tile)."""
+    def nbytes(*names, lanes=1):    # the minor dimension in whole ``lanes``
+        return sum(math.prod(a.shape[:-1]) * -(-a.shape[-1] // lanes) * lanes
+                   * jnp.dtype(a.dtype).itemsize
                    for n, a in cache.items() if n in names)
 
     per_token = ("k", "v") + MTP_ROWS + RING + LATENT
     control = ("length", "block_table", "moe_counts", "draft", CHOICES)
-    state = nbytes(*(n for n in cache if n not in per_token + control))
+    state = tuple(n for n in cache if n not in per_token + control)
     return {"cache_kv_bytes": nbytes("k", "v", *MTP_ROWS),
-            "cache_state_bytes": state,
+            "cache_state_bytes": nbytes(*state),
+            "cache_state_hbm_bytes": nbytes(*state, lanes=128),
             "cache_latent_bytes": nbytes(*LATENT),
             "linear_layers": cfg.linear_layers,
             **({"ssm_layers": cfg.ssm_layers} if cfg.ssm_layers else {}),

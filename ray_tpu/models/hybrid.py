@@ -15,9 +15,12 @@ or as "mlp" layers of their own where ``cfg.sublayers_alone``) are
 * ``k``, ``v``: [full_layers, slots, max_len, NKV * D], the dense cache of
   ``decode.py`` with rows for the full-attention layers only, written and
   read by ``decode.prefill_attention`` / ``decode_attention``;
-* ``state``: [linear_layers, slots, heads, key_dim, value_dim] float32, the
-  delta rule's state (``ops/gated_delta.py``, ``ops/kda.py``), or
-  [ssm_layers, slots, heads, head width P, state width N] float32, the
+* ``state``: [linear_layers, slots, heads / p, key_dim, p * value_dim]
+  float32, the delta rule's state (``ops/gated_delta.py``, ``ops/kda.py``)
+  with ``p`` neighbouring heads side by side along the lanes, the fewest
+  that fill whole 128-lane tiles (``gated_delta.pack_state``: 2 at a
+  value_dim of 192, 1, the plain [.., heads, key_dim, value_dim], at 128),
+  or [ssm_layers, slots, heads, head width P, state width N] float32, the
   state-space one (``ops/ssd.py``): constant in the context;
 * ``conv``: [recurrent layers, slots, conv_width - 1, channels], the last
   inputs of the mixer's causal convolution;
@@ -271,7 +274,8 @@ def init_state(cfg: TransformerConfig, num_slots: int,
     else:
         kd, vd = _channels(cfg)
         layers, channels = cfg.linear_layers, 2 * kd + vd
-        state = (lh, cfg.linear_key_dim, cfg.linear_value_dim)
+        state = gated_delta.packed_shape(lh, cfg.linear_key_dim,
+                                         cfg.linear_value_dim)
     return {
         "state": jnp.zeros((layers, num_slots) + state, jnp.float32),
         "conv": jnp.zeros((layers, num_slots, width - 1, channels), dtype),
@@ -348,10 +352,20 @@ def _mixer_out(o, x, mp, cfg: TransformerConfig):
 
 
 def _rule(cfg: TransformerConfig):
-    """The delta rule's two kernels for this configuration's decay."""
+    """The delta rule's two kernels for this configuration's decay: whole
+    rows to a plain state [B, heads, dk, dv], and one step on the stack of
+    packed states (``gated_delta.pack_state``)."""
     if cfg.linear_decay_per_channel:
         from ..ops import kda
-        return kda.kda_chunk_fwd, kda.kda_recurrent_step
+
+        def kda_step(state, li, q, k, *rest):
+            # ``ops/kda.py`` steps a plain stack, which the packed one is
+            # at its published heads of 128 lanes (both are the identity)
+            state, o = kda.kda_recurrent_step(
+                gated_delta.unpack_state(state, q.shape[1]), li, q, k, *rest)
+            return gated_delta.pack_state(state), o
+
+        return kda.kda_chunk_fwd, kda_step
     return gated_delta.gdn_chunk_fwd, gated_delta.gdn_recurrent_step
 
 
@@ -403,8 +417,9 @@ def _state_io(li, conv, live, mix):
 
 def linear_prefill(x, mp, cfg: TransformerConfig, lengths):
     """One linear layer's mixer over whole right-padded rows.  x: [B, S, H]
-    (any S) -> (mixer output [B, S, H], the state [B, heads, dk, dv] and
-    the convolution tail [B, width - 1, C] each row leaves at its length)."""
+    (any S) -> (mixer output [B, S, H], the state [B, heads / p, dk, p dv]
+    as the cache holds it and the convolution tail [B, width - 1, C] each
+    row leaves at its length)."""
     with jax.named_scope(_scope(cfg)):
         proj = x @ mp["w_qkv"].astype(x.dtype)                  # [B, S, C]
     with jax.named_scope(_scope(cfg, "_conv")):
@@ -414,6 +429,8 @@ def linear_prefill(x, mp, cfg: TransformerConfig, lengths):
     with jax.named_scope(_scope(cfg)):
         # positions at or beyond a row's length leave its state alone
         o, state = _rule(cfg)[0](q, k, v, g, beta, lengths)
+    with jax.named_scope("state_write"):
+        state = gated_delta.pack_state(state)
     return _mixer_out(o, x, mp, cfg), state, tail
 
 

@@ -16,9 +16,14 @@ Per head, with a state ``h`` of shape [key_dim, value_dim] held in float32
   ``beta = 0, g = 0`` leaves the state untouched, which is how right padding
   is made harmless: the state returned is each row's as of its true length.
 * ``gdn_recurrent_step`` (decode) applies one step to every slot of one
-  layer of a stacked state ``[layers, slots, heads, key_dim, value_dim]``,
-  in place, taking the layer index itself (scalar prefetch), so that no
-  layer slab is ever sliced out of the stack.
+  layer of a stacked state, in place, taking the layer index itself (scalar
+  prefetch), so that no layer slab is ever sliced out of the stack.  The
+  stack holds the state packed (``pack_state``): ``[layers, slots, heads /
+  p, key_dim, p * value_dim]``, ``p`` neighbouring heads side by side along
+  the lanes, the fewest that fill whole 128-lane tiles (``packed_heads``: 2
+  at a value_dim of 192, 1 at 128), so that the chip, which stores a
+  float32 array's minor dimension in tiles of 128 lanes, stores and moves
+  no lane that holds nothing.
 
 Each kernel's math is one function on two-dimensional tiles
 (``_chunk_tile``, ``_step_tile``) that the kernel body calls on what it
@@ -29,13 +34,21 @@ time.
 
 Head sizes need not be multiples of the 128 lanes: the prefill wrapper pads
 key and value dims to whole lane tiles on the way in and slices on the way
-out (padded key lanes are zero, so they add nothing to any product); the
-decode kernel's blocks span the whole [key_dim, value_dim] state.
+out (padded key lanes are zero, so they add nothing to any product), and
+returns a plain state ``[B, heads, key_dim, value_dim]`` that its caller
+packs.  The decode kernel moves whole slots of the packed stack, as many a
+grid step as ``step_block`` plans from the state's shape, and steps a packed
+tile as it lies: the step is element-wise on ``h`` but for two sums over
+sublanes, so the ``p`` heads of a tile are stepped at once, each head's
+column and scalars spread over its own lanes (``_step_lanes``), and every
+element sees the operations of the plain step in their order: the packed
+kernel's results are the plain step's to the bit.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -141,14 +154,39 @@ def _chunk_tile(q, k, v, gc_row, b_row, h):
     return o, h * keep + _mm(k_dec.T, v_new)
 
 
+def _step_lanes(h, q_cols, k_cols, v_row, alphas, betas):
+    """One decode step on a tile of heads side by side along the lanes.  h
+    [dk, p dv] float32; q_cols, k_cols, alphas, betas: a sequence the tile's
+    ``p`` heads, a float32 column [dk, 1] and two scalars (or ``alpha`` a
+    column: a decay a channel) a head; v_row [1, p dv] float32.  Each head's
+    column and scalars are spread over its own lanes (a ``where`` on a lane
+    iota, where the tile holds more than one head) and the step is the plain
+    one, element for element: the sums run over sublanes, which no head
+    shares.  Returns (o [1, p dv], h)."""
+    width, heads = h.shape[1], len(q_cols)
+    dv = width // heads
+
+    def spread(per_head):
+        out = per_head[-1]
+        if heads > 1:
+            rows = out.shape[0] if jnp.ndim(out) else 1     # column or scalar
+            lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+            for j in reversed(range(heads - 1)):
+                out = jnp.where(lane < (j + 1) * dv, per_head[j], out)
+        return out
+
+    k, q = spread(k_cols), spread(q_cols)
+    h = h * spread(alphas)
+    pred = jnp.sum(h * k, axis=0, keepdims=True)
+    h = h + k * ((v_row - pred) * spread(betas))
+    return jnp.sum(h * q, axis=0, keepdims=True), h
+
+
 def _step_tile(h, q_row, k_row, v_row, alpha, beta):
     """One decode step of one head.  h [dk, dv] float32; q_row, k_row
     [1, dk]; v_row [1, dv]; alpha, beta scalars.  Returns (o [1, dv], h)."""
-    k_col, q_col = _col(k_row.astype(F32)), _col(q_row.astype(F32))
-    h = h * alpha
-    pred = jnp.sum(h * k_col, axis=0, keepdims=True)
-    h = h + k_col * ((v_row.astype(F32) - pred) * beta)
-    return jnp.sum(h * q_col, axis=0, keepdims=True), h
+    return _step_lanes(h, [_col(q_row.astype(F32))], [_col(k_row.astype(F32))],
+                       v_row.astype(F32), [alpha], [beta])
 
 
 # ---------------------------------------------------------------------------
@@ -255,9 +293,27 @@ def _head_group(nh: int, limit: int) -> int:
     return max(d for d in range(1, limit + 1) if nh % d == 0)
 
 
-#: heads a grid step of the decode kernel, at most.  The prefill kernel
-#: takes one: 2-10 bought 5% on the chip (PERF.md, PR 29)
-STEP_HEADS_A_STEP = 6
+#: what the state's blocks of a grid step of the decode kernel may take of
+#: VMEM, in and out and two buffers each (``step_block``): blocks of up to 4
+#: MiB, ``ops/ssd.py``'s budget for a state streamed through such a kernel
+#: (``ssd.STEP_STATE_VMEM``, PERF.md section 6, PR 55); one slot of the
+#: hybrid's [15, 96, 384] float32, 2.1 MiB.  The kernel alone on the chip
+#: (PERF.md section 6, PR 57; ``chiprun_out/pr57a/``, the script beside its
+#: output), us a call over 25 slots and GB/s moved of the state's own 221 MB:
+#: the parent's 6 plain heads of one slot a step (0.56 MB of which a quarter
+#: padding) 225.2 us, 491 GB/s; **one packed slot 168.2, 658**; two 166.9;
+#: three 166.2; five 164.8, 671; a plain copy of one slot's blocks 168.6, of
+#: two 167.4: the body is hidden behind the copies at every plan, and what a
+#: larger block buys (2% at five slots, 44 MB of VMEM) is the fewer grid steps
+STEP_STATE_VMEM = 16 << 20
+#: the decode kernel's VMEM, of the chip's 128 MiB: the state's blocks, q, k,
+#: v and o of the block's slots twice, and the body's tiles
+STEP_VMEM_LIMIT = 32 << 20
+#: packed tiles the decode kernel's body unrolls, at most, as the float32
+#: bytes they hold: a chunk of a slot's tiles, the rest a rolled loop (5 of
+#: the hybrid's 15; 1, 3, 5 or 15 unrolled read 169.5 / 168.2 / 168.2 / 169.6
+#: us a call there)
+STEP_UNROLL_BYTES = 768 << 10
 
 
 def _chunk_fwd_pallas(q, k, v, g, beta, lengths, interpret: bool):
@@ -323,68 +379,156 @@ def gdn_chunk_fwd(q, k, v, g, beta, lengths=None,
 # Decode: one step, in place on the stacked state
 # ---------------------------------------------------------------------------
 
+def packed_heads(nh: int, dv: int) -> int:
+    """Heads of ``dv`` value lanes that the stacked state holds side by side
+    along the lanes: the fewest that fill whole tiles of 128 lanes, where
+    that many divide the ``nh`` heads, else one (the plain layout, whose
+    lanes the chip pads).  A function of the state's shape and of nothing
+    else."""
+    p = 128 // math.gcd(dv, 128)
+    return p if nh % p == 0 else 1
+
+
+def packed_shape(nh: int, dk: int, dv: int) -> Tuple[int, int, int]:
+    """[heads, key_dim, value_dim] of a slot's state as the stack holds it."""
+    p = packed_heads(nh, dv)
+    return nh // p, dk, p * dv
+
+
+def pack_state(h):
+    """[..., H, dk, dv] -> [..., H / p, dk, p * dv]: head ``i p + j``'s
+    columns in lanes ``j dv .. (j + 1) dv`` of tile ``i``."""
+    *lead, nh, dk, dv = h.shape
+    p = packed_heads(nh, dv)
+    if p == 1:
+        return h
+    h = h.reshape(*lead, nh // p, p, dk, dv).swapaxes(-3, -2)
+    return h.reshape(*lead, *packed_shape(nh, dk, dv))
+
+
+def unpack_state(h, nh: int):
+    """``pack_state``'s inverse for a state of ``nh`` heads: [..., H / p, dk,
+    p * dv] -> [..., H, dk, dv]."""
+    *lead, tiles, dk, width = h.shape
+    p = nh // tiles
+    if p == 1:
+        return h
+    h = h.reshape(*lead, tiles, dk, p, width // p).swapaxes(-3, -2)
+    return h.reshape(*lead, nh, dk, width // p)
+
+
 def gdn_recurrent_step_jnp(state, layer, q, k, v, g, beta):
-    """The twin of ``gdn_recurrent_step``; shapes as there."""
-    h = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    """The twin of ``gdn_recurrent_step``; shapes as there: the layer's
+    state unpacked, stepped a head at a time and packed again."""
+    nh = q.shape[1]
+    h = unpack_state(
+        jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False), nh)
     tile = jax.vmap(jax.vmap(_step_tile))
     o, h = tile(h, q[:, :, None], k[:, :, None], v[:, :, None],
                 jnp.exp(g.astype(F32)), beta.astype(F32))
-    state = jax.lax.dynamic_update_index_in_dim(state, h, layer, 0)
+    state = jax.lax.dynamic_update_index_in_dim(state, pack_state(h), layer,
+                                                0)
     return state, o[:, :, 0].astype(v.dtype)
 
 
+def _tile_bytes(dk: int, width: int) -> int:
+    """A packed tile's float32 [dk, width] as VMEM holds it, in whole (8,
+    128) tiles."""
+    return (-(-dk // 8) * 8) * _lanes(width) * 4
+
+
+def step_block(slots: int, tiles: int, dk: int, width: int):
+    """(slots, packed tiles) of the block of the state ``[layers, slots,
+    tiles, dk, width]`` that a grid step of ``gdn_recurrent_step`` moves:
+    whole slots (all tiles of a slot lie together in the stack: one copy),
+    the most whose block in and out, two buffers each, fits
+    ``STEP_STATE_VMEM``; where one slot does not fit, of one slot the most
+    tiles that fit and divide it.  A function of what the kernel sees in its
+    operands and of nothing else."""
+    fit = STEP_STATE_VMEM // (4 * _tile_bytes(dk, width))   # four buffers
+    if fit >= tiles:
+        return min(slots, fit // tiles), tiles
+    if fit < 1:
+        raise ValueError(
+            f"gdn_recurrent_step: four buffers of one [{dk}, {width}] "
+            f"float32 tile do not fit {STEP_STATE_VMEM >> 20} MiB of VMEM")
+    return 1, _head_group(tiles, fit)
+
+
 def _step_kernel(layer_ref, a_ref, b_ref, s_in, q_ref, k_ref, v_ref,
-                 s_out, o_ref, *, heads: int):
+                 s_out, o_ref, *, slots: int, p: int):
+    """Grid (slot blocks, tile blocks).  s_in, s_out [1, sb, tb, dk, p dv];
+    q_ref, k_ref [sb, tb / tu, tu p, dk], v_ref, o_ref [sb, tb / tu, tu, p
+    dv]: the block's tiles in chunks of ``tu``, which the body unrolls.  The
+    walk is a rolled loop over the slots the block holds (the last block may
+    hold fewer than ``sb``) and their chunks."""
     del layer_ref                     # used by the index maps only
-    si, gi = pl.program_id(0), pl.program_id(1)
-    for i in range(heads):
-        hd = gi * heads + i
-        o, h = _step_tile(s_in[0, 0, i], q_ref[0, 0, i:i + 1],
-                          k_ref[0, 0, i:i + 1], v_ref[0, 0, i:i + 1],
-                          a_ref[si, hd], b_ref[si, hd])
-        s_out[0, 0, i] = h
-        o_ref[0, 0, i:i + 1] = o.astype(o_ref.dtype)
+    sb, tb = s_in.shape[1:3]
+    chunks, tu = v_ref.shape[1:3]
+    first, tile0 = pl.program_id(0) * sb, pl.program_id(1) * tb
+
+    def chunk(at, carry):
+        # everything that is traced is once a chunk; a tile adds one index
+        si, c = (at, 0) if chunks == 1 else (at // chunks, at % chunks)
+        slot, base = first + si, (tile0 + c * tu) * p
+        for i in range(tu):
+            mine = range(i * p, (i + 1) * p)
+            o, h = _step_lanes(
+                s_in[0, si, c * tu + i],
+                [_col(q_ref[si, c, r:r + 1].astype(F32)) for r in mine],
+                [_col(k_ref[si, c, r:r + 1].astype(F32)) for r in mine],
+                v_ref[si, c, i:i + 1].astype(F32),
+                [a_ref[slot, base + r] for r in mine],
+                [b_ref[slot, base + r] for r in mine])
+            s_out[0, si, c * tu + i] = h
+            o_ref[si, c, i:i + 1] = o.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(sb, slots - first) * chunks, chunk, 0)
 
 
 def _recurrent_step_pallas(state, layer, q, k, v, g, beta, interpret: bool):
-    _, slots, nh, dk, dv = state.shape
-    hb = _head_group(nh, STEP_HEADS_A_STEP)
-    ng = nh // hb
-    q, k, v = (a.reshape(slots, ng, hb, a.shape[-1]) for a in (q, k, v))
-    alpha = jnp.exp(g.astype(F32))
-    small = lambda si, gi, lyr: (si, gi, 0, 0)          # noqa: E731
-    big = lambda si, gi, lyr: (lyr[0], si, gi, 0, 0)    # noqa: E731
+    _, slots, tiles, dk, width = state.shape
+    nh = q.shape[1]
+    p = nh // tiles
+    sb, tb = step_block(slots, tiles, dk, width)
+    tu = _head_group(tb, max(STEP_UNROLL_BYTES // _tile_bytes(dk, width), 1))
+    q, k = (a.reshape(slots, tiles // tu, tu * p, dk) for a in (q, k))
+    v = v.reshape(slots, tiles // tu, tu, width)
+    small = lambda si, ti, lyr: (si, ti, 0, 0)          # noqa: E731
+    big = lambda si, ti, lyr: (lyr[0], si, ti, 0, 0)    # noqa: E731
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     state, o = pl.pallas_call(
-        functools.partial(_step_kernel, heads=hb),
+        functools.partial(_step_kernel, slots=slots, p=p),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(slots, ng),
+            grid=(pl.cdiv(slots, sb), tiles // tb),
             in_specs=[
                 smem, smem,
-                pl.BlockSpec((1, 1, hb, dk, dv), big),
-                pl.BlockSpec((1, 1, hb, dk), small),
-                pl.BlockSpec((1, 1, hb, dk), small),
-                pl.BlockSpec((1, 1, hb, dv), small),
+                pl.BlockSpec((1, sb, tb, dk, width), big),
+                pl.BlockSpec((sb, tb // tu, tu * p, dk), small),
+                pl.BlockSpec((sb, tb // tu, tu * p, dk), small),
+                pl.BlockSpec((sb, tb // tu, tu, width), small),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, hb, dk, dv), big),
-                pl.BlockSpec((1, 1, hb, dv), small),
+                pl.BlockSpec((1, sb, tb, dk, width), big),
+                pl.BlockSpec((sb, tb // tu, tu, width), small),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(state.shape, state.dtype),
-            jax.ShapeDtypeStruct((slots, ng, hb, dv), v.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         # operands count from the scalar-prefetch argument: 3 is the state
         input_output_aliases={3: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=STEP_VMEM_LIMIT),
         interpret=interpret,
         name=KERNEL_RECURRENT_STEP,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), alpha, beta.astype(F32),
-      state, q, k, v)
-    return state, o.reshape(slots, nh, dv)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), jnp.exp(g.astype(F32)),
+      beta.astype(F32), state, q, k, v)
+    return state, o.reshape(slots, nh, width // p)
 
 
 def gdn_recurrent_step(state, layer, q, k, v, g, beta,
@@ -392,12 +536,13 @@ def gdn_recurrent_step(state, layer, q, k, v, g, beta,
                        interpret: Optional[bool] = None):
     """One gated-delta-rule step for every slot of layer ``layer``.
 
-    state: [layers, slots, H, dk, dv] float32, updated in place (donate it);
-    layer: int32 scalar (traced or not); q, k: [slots, H, dk]; v: [slots, H,
-    dv]; g, beta: [slots, H] (``g = 0, beta = 0`` leaves a slot's state as
-    it was).  Returns (state, o [slots, H, dv] in v's dtype).  Only the
-    blocks of ``layer`` are read and written: the index maps take the layer
-    from scalar prefetch, no slab leaves the stack."""
+    state: [layers, slots, H / p, dk, p * dv] float32, ``p`` heads a tile
+    (``pack_state``), updated in place (donate it); layer: int32 scalar
+    (traced or not); q, k: [slots, H, dk]; v: [slots, H, dv]; g, beta:
+    [slots, H] (``g = 0, beta = 0`` leaves a slot's state as it was).
+    Returns (state, o [slots, H, dv] in v's dtype).  Only the blocks of
+    ``layer`` are read and written: the index maps take the layer from
+    scalar prefetch, no slab leaves the stack."""
     if use_kernel is None:
         use_kernel = bool(interpret) or jax.default_backend() == "tpu"
     if not use_kernel:

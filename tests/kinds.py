@@ -86,6 +86,13 @@ def _PAGED_SPEC_TP(paged, spec, tp):
             ("tp", dict(tp=2), tp))
 
 
+def _whole_tiles(gauges):
+    """A cell's cache as the chip stores it: every published state fills
+    whole tiles of 128 lanes (the hybrid's heads of 192 packed two a tile
+    since PR 57), so no lane of it holds nothing."""
+    return dict(gauges, cache_state_hbm_bytes=gauges["cache_state_bytes"])
+
+
 _PATTERN_BASE = dict(vocab_size=8, hidden_size=8, num_heads=1, num_kv_heads=1,
                      mlp_size=8, max_seq_len=8, num_layers=4,
                      linear_num_heads=1, linear_key_dim=4, linear_value_dim=4,
@@ -182,10 +189,10 @@ KINDS = {k.name: k for k in (
         # the hybrid's largest bucket (PR 32): K/V of the three full layers,
         # 2.36 GB each, and the float32 state, carried through the loop over
         # the admit's rows (4.7 + 0.7 GB: a copy would not fit)
-        stacks=("bf16[3,25,4096,3840]", "f32[9,25,30,96,192]"),
+        stacks=("bf16[3,25,4096,3840]", "f32[9,25,15,96,384]"),
         counts=dict(slots=25, num_params=3_268_268_508,
                     per={"linear": 215_516_160, "full": 185_794_560},
-                    gauges=lambda kind, doc: {
+                    gauges=lambda kind, doc: _whole_tiles({
                         "cache_kv_bytes": 25 * 4096 * 46_080,
                         "cache_state_bytes": 25 * (
                             kind.state_bytes_per_slot(doc)
@@ -193,7 +200,7 @@ KINDS = {k.name: k for k in (
                         "linear_layers": 9, "full_layers": 3,
                         # no latent rows and no experts here (PR 35's gauges)
                         "cache_latent_bytes": 0, "expert_layers": 0,
-                        "experts_held": 0})),
+                        "experts_held": 0}))),
     Kind(
         name="xing4_0", tiny="tiny-latent.json",
         cell="xing4.0-29b-a4b-serve-l7",
@@ -373,14 +380,14 @@ KINDS = {k.name: k for k in (
                     per={"kda": 137_625_600, "gqa": 109_051_904,
                          "expert": 15_728_640, "shared": 15_728_640,
                          "router": 1_310_720},
-                    gauges=lambda kind, doc: {
+                    gauges=lambda kind, doc: _whole_tiles({
                         "cache_kv_bytes": 65 * 4096 * 4096,
                         "cache_state_bytes": 65 * (
                             kind.state_bytes_per_slot(doc)
                             + 3 * 3 * 24576 * 2),
                         "linear_layers": 3, "full_layers": 1,
                         "cache_latent_bytes": 0, "expert_layers": 4,
-                        "experts_held": 40})),
+                        "experts_held": 40}))),
     Kind(
         name="nemotron_h", tiny="tiny-nemotron.json",
         cell="nemotron-3-nano-30b-a3b-serve-l9-e64",
@@ -487,14 +494,14 @@ KINDS = {k.name: k for k in (
                     per={"mamba": 38_707_200, "attention": 23_396_352,
                          "expert": 9_977_856, "shared": 19_955_712,
                          "router": 344_064},
-                    gauges=lambda kind, doc: {
+                    gauges=lambda kind, doc: _whole_tiles({
                         "cache_kv_bytes": 65 * 8192 * 1024,
                         "cache_state_bytes": 65 * (
                             kind.state_bytes_per_slot(doc)
                             + 4 * 3 * 6144 * 2),
                         "linear_layers": 0, "ssm_layers": 4,
                         "full_layers": 1, "cache_latent_bytes": 0,
-                        "expert_layers": 4, "experts_held": 64})),
+                        "expert_layers": 4, "experts_held": 64}))),
     Kind(
         name="exaone_moe", tiny="tiny-exaone.json",
         cell="k-exaone-236b-a23b-serve-l8-e8",
@@ -598,7 +605,7 @@ KINDS = {k.name: k for k in (
                     per={"attention": 113_246_208, "dense": 339_738_624,
                          "expert": 37_748_736, "shared": 37_748_736,
                          "router": 786_432, "mtp_proj": 75_497_472},
-                    gauges=lambda kind, doc: {
+                    gauges=lambda kind, doc: _whole_tiles({
                         "cache_kv_bytes": 49 * 6144 * kind.kv_bytes_per_token(
                             doc),
                         "cache_ring_bytes": 49 * kind.ring_bytes_per_slot(
@@ -606,7 +613,7 @@ KINDS = {k.name: k for k in (
                         "cache_state_bytes": 0,
                         "linear_layers": 0, "window_layers": 6,
                         "full_layers": 2, "cache_latent_bytes": 0,
-                        "expert_layers": 7, "experts_held": 8})),
+                        "expert_layers": 7, "experts_held": 8}))),
     Kind(
         name="granitemoehybrid", tiny="tiny-granite.json",
         cell="granite-4.0-h-micro-serve-l40",
@@ -701,14 +708,14 @@ KINDS = {k.name: k for k in (
         counts=dict(slots=65, num_params=3_191_396_096,
                     per={"mamba": 25_821_184, "attention": 10_485_760,
                          "mlp": 50_331_648},
-                    gauges=lambda kind, doc: {
+                    gauges=lambda kind, doc: _whole_tiles({
                         "cache_kv_bytes": 65 * 4096 * 8192,
                         "cache_state_bytes": 65 * (
                             kind.state_bytes_per_slot(doc)
                             + 36 * 3 * 4352 * 2),
                         "linear_layers": 0, "ssm_layers": 36,
                         "full_layers": 4, "cache_latent_bytes": 0,
-                        "expert_layers": 0, "experts_held": 0})),
+                        "expert_layers": 0, "experts_held": 0}))),
     # trained, not served: the share train cell's kind.  The backward's two
     # grouped kernels beside the forward's; the reader's list still holds
     # ``flash_dq``, a kernel that is gone since PR 48 (the backward is

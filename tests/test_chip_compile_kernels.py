@@ -10,6 +10,7 @@ import pytest
 import kinds
 from chip_compile import (KERNEL, _compile, _slab_ops, copies_of,  # noqa: F401
                           one_chip, shapes_on, topo)
+from ray_tpu.models import decode
 
 
 def _qkv(sharding, b, s, h, kv, d, d_v=None):
@@ -103,8 +104,11 @@ def test_decode_attn_kernel_compiles_in_place(one_chip, shape):
 def _delta_rule(S, kernel, ops, name, nh, dk, dv, chunk, layers, slots,
                 per_channel):
     """A delta-rule mixer's two kernels: the chunked forward over ``chunk``
-    (rows, positions) and a step of ``slots`` slots on a stack of ``layers``,
-    in place (the donated stack is the output, nothing beside it)."""
+    (rows, positions) and a step of ``slots`` slots on a stack of ``layers``
+    as the cache holds it (``gated_delta.packed_shape``: the heads' own
+    shape where they are 128 lanes wide), in place (the donated stack is the
+    output, nothing beside it)."""
+    from ray_tpu.ops.gated_delta import packed_shape
     f32 = jnp.float32
 
     def decay(*lead):
@@ -119,20 +123,23 @@ def _delta_rule(S, kernel, ops, name, nh, dk, dv, chunk, layers, slots,
                 (), None, None, ())
     return (lambda *a: getattr(ops, name + "_recurrent_step")(
         *a, use_kernel=True, interpret=False),
-            (S((layers, slots, nh, dk, dv), f32), S((), jnp.int32),
-             S((slots, nh, dk)), S((slots, nh, dk)), S((slots, nh, dv)),
-             decay(slots, nh), S((slots, nh), f32)), (0,), None, 1e6, ())
+            (S((layers, slots) + packed_shape(nh, dk, dv), f32),
+             S((), jnp.int32), S((slots, nh, dk)), S((slots, nh, dk)),
+             S((slots, nh, dv)), decay(slots, nh), S((slots, nh), f32)),
+            (0,), None, 1e6, ())
 
 
 def _gdn(S, kernel):
-    """30 heads of 96 / 192: neither a multiple of the 128 lanes."""
+    """30 heads of 96 / 192: neither a multiple of the 128 lanes; the step's
+    stack holds them two a tile, [9, 25, 15, 96, 384]."""
     from ray_tpu.ops import gated_delta
     return _delta_rule(S, kernel, gated_delta, "gdn", 30, 96, 192, (2, 2048),
                        9, 25, False)
 
 
 def _kda(S, kernel):
-    """64 heads of 128 / 128, the decay a [.., 128] float32 row a head."""
+    """64 heads of 128 / 128, the decay a [.., 128] float32 row a head: one
+    head a tile, the stack [3, 65, 64, 128, 128] as it was."""
     from ray_tpu.ops import kda
     return _delta_rule(S, kernel, kda, "kda", 64, 128, 128, (1, 1024), 3, 65,
                        True)
@@ -305,6 +312,56 @@ def test_ssd_recurrent_step_compiles_under_its_vmem_in_place(
     monkeypatch.setattr(ssd, "STEP_VMEM_LIMIT", blocks - (1 << 20))
     with pytest.raises(Exception, match="(?i)vmem|memory"):
         compile_step()
+
+
+# ------- the delta rule's packed state at the hybrid cell's shape (PR 57)
+
+def test_gdn_recurrent_step_compiles_packed_under_its_vmem_in_place(
+        one_chip, monkeypatch):
+    """The hybrid's 30 heads of 96 x 192 lie two a tile, [9, 25, 15, 96,
+    384]: by the compile the stack is its own 0.498e9 bytes, where the plain
+    [9, 25, 30, 96, 192] is 0.664e9 on the chip (every head's 192 lanes in
+    256), which is what ``cache_state_hbm_bytes`` reckons of each; the
+    kernel takes a whole slot a grid step (``step_block``), Mosaic accepts
+    it under ``STEP_VMEM_LIMIT`` and refuses it under a limit that the
+    blocks alone pass, and the stack is updated where it lies.  The KDA
+    kind's heads of 128 lanes pack one a tile: its stack and its case above
+    are as they were."""
+    from ray_tpu.ops import gated_delta as gd
+    layers, slots, nh, dk, dv, f32 = 9, 25, 30, 96, 192, jnp.float32
+    own = layers * slots * nh * dk * dv * 4
+    assert gd.packed_shape(nh, dk, dv) == (15, 96, 384)
+    assert gd.packed_shape(64, 128, 128) == (64, 128, 128)
+    assert gd.step_block(slots, 15, dk, 384) == (1, 15)
+    blocks = 4 * 15 * gd._tile_bytes(dk, 384)
+    assert blocks == 4 * 15 * 96 * 384 * 4 <= gd.STEP_STATE_VMEM
+    assert gd.STEP_STATE_VMEM < gd.STEP_VMEM_LIMIT
+    S = shapes_on(one_chip)
+
+    def compile_step(*state):   # a function of its own each time: a new trace
+        return _compile(lambda *a: gd.gdn_recurrent_step(
+            *a, use_kernel=True, interpret=False),
+            S((layers, slots) + state, f32), S((), jnp.int32),
+            S((slots, nh, dk)), S((slots, nh, dk)), S((slots, nh, dv)),
+            S((slots, nh), f32), S((slots, nh), f32), donate_argnums=(0,))
+
+    def stored(*state):         # the gauge's reckoning of that stack
+        cache = {"state": jax.ShapeDtypeStruct((layers, slots) + state, f32)}
+        return decode.cache_gauges(kinds.cell_cfg("olmo_hybrid"), cache)[
+            "cache_state_hbm_bytes"]
+
+    compiled, text = compile_step(15, 96, 384)
+    mem = compiled.memory_analysis()
+    assert text.count(KERNEL) == 1
+    assert mem.alias_size_in_bytes == own == 497_664_000 == stored(15, 96, 384)
+    assert mem.argument_size_in_bytes - own < 1e6
+    assert mem.temp_size_in_bytes < 1e6
+    assert not copies_of("f32[9,25,15,96,384]", text)
+    plain = compile_step(nh, dk, dv)[0].memory_analysis()
+    assert plain.alias_size_in_bytes == own * 256 // 192 == stored(nh, dk, dv)
+    monkeypatch.setattr(gd, "STEP_VMEM_LIMIT", blocks - (1 << 20))
+    with pytest.raises(Exception, match="(?i)vmem|memory"):
+        compile_step(15, 96, 384)
 
 
 # ------------------- the grouped matmul's block plan at every cell's widths

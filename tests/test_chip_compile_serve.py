@@ -156,9 +156,10 @@ def test_cell_program_fits_at_16_layers(one_chip, as_tpu, program):
 #
 # Olmo-Hybrid-7B widths, 12 layers (9 gated-delta-rule + 3 full attention),
 # 24 slots + the scratch slot x 4096: what ``serve-hybrid-longgen-closed``
-# runs.  K and V are 2.36 GB each and the float32 state 0.5 GB (0.66 GB with
-# 192 lanes padded to 256); none of them may be copied, and the state may not
-# be sliced a layer at a time either.
+# runs.  K and V are 2.36 GB each and the float32 state 0.5 GB (two heads of
+# 192 lanes a tile since PR 57; 0.66 GB before, every head padded to 256);
+# none of them may be copied, and the state may not be sliced a layer at a
+# time either.
 
 HYBRID_SLOTS, HYBRID_MAX_LEN = 25, 4096
 
@@ -176,9 +177,9 @@ def test_hybrid_decode_program_holds_both_states_in_place(one_chip, as_tpu):
     assert text.count(KERNEL) == 4
     for stack in kinds.KINDS["olmo_hybrid"].stacks:
         assert stack in text and not copies_of(stack, text)
-    # no layer's [slots, 30, 96, 192] slab is sliced out of the state stack
+    # no layer's [slots, 15, 96, 384] slab is sliced out of the state stack
     assert not re.search(
-        rf"= f32\[(1,)?{HYBRID_SLOTS},30,96,192\]\S* (dynamic-slice|copy)\(",
+        rf"= f32\[(1,)?{HYBRID_SLOTS},15,96,384\]\S* (dynamic-slice|copy)\(",
         text)
 
 
@@ -198,7 +199,10 @@ test_whole_row_programs_are_the_parents = whole_row_programs({
     ("mistral", "prefill-256", 14): (589478400, 0, 2),
     ("mistral", "prefill-512", 14): (589478400, 0, 2),
     ("mistral", "prefill-1024", 14): (651342848, 1, 2),
-    ("olmo_hybrid", "prefill-256", None): (88294912, 3, 2),
-    ("olmo_hybrid", "prefill-2048", None): (275977216, 4, 2),
-    ("olmo_hybrid", "prefill-4096", None): (731474432, 4, 2),
+    # (the hybrid's since PR 57, whose row leaves its nine states packed two
+    # heads a tile: 88294912 / 275977216 / 731474432 with every head's 192
+    # lanes padded to 256 among the temporaries; kernels and loops as before)
+    ("olmo_hybrid", "prefill-256", None): (58740224, 3, 2),
+    ("olmo_hybrid", "prefill-2048", None): (253629440, 4, 2),
+    ("olmo_hybrid", "prefill-4096", None): (678406656, 4, 2),
 })

@@ -434,9 +434,11 @@ def _traced():
     return {
         "gdn_chunk_fwd": lambda: jax.make_jaxpr(lambda *a: gd.gdn_chunk_fwd(
             *a, interpret=True))(q, q, v, g[..., 0], g[..., 0]),
+        # (the packed stack: two heads of 64 lanes side by side in one tile)
         "gdn_recurrent_step": lambda: jax.make_jaxpr(
             lambda *a: gd.gdn_recurrent_step(*a, interpret=True))(
-                state, jnp.int32(1), q[:, 0], q[:, 0], v[:, 0], g[:, 0, :, 0],
+                gd.pack_state(jnp.zeros((2, 1, 2, 8, 64))), jnp.int32(1),
+                q[:, 0], q[:, 0], jnp.ones((1, 2, 64), f32), g[:, 0, :, 0],
                 g[:, 0, :, 0]),
         "kda_chunk_fwd": lambda: jax.make_jaxpr(lambda *a: kda.kda_chunk_fwd(
             *a, interpret=True))(q, q, v, g, g[..., 0]),
