@@ -122,6 +122,11 @@ class TransformerConfig:
     # rotary positions by kind: the "window" layers rotate q and k, the
     # "full" layers add none (``use_rope`` alone: every attention layer does)
     rope_window_only: bool = False
+    # YaRN by kind: the kinds of a pattern whose rotary table is YaRN's
+    # (``rope_yarn_*`` below; Mellum's "full" layers), the other rotary
+    # kinds plain at ``rope_theta``.  Static: it applies at every length.
+    # The train step's (``transformer.rope_table``); serving refuses it
+    rope_yarn_kinds: Tuple[str, ...] = ()
     # multi-token-prediction blocks after the last layer (DeepSeek-V3's
     # ``num_nextn_predict_layers``; models/speculative.py drafts with one):
     # a block is ``W_eh [norm(E x_{t+1}); norm(h_t)]``, one "full" layer of
@@ -162,6 +167,14 @@ class TransformerConfig:
     # selection bias, ``shared_experts`` more on every token; the first
     # ``dense_prefix_layers`` layers keep a dense MLP of ``mlp_size``.
     moe_dropless: bool = False
+    # the dropless layer's router: "sigmoid" (``noaux_tc``: sigmoid scores,
+    # a selection bias, the chosen scores over their sum times
+    # ``routed_scaling_factor``) or "softmax" (Qwen3-MoE's: softmax over all
+    # experts in float32, the top k, the chosen probabilities over their sum;
+    # no bias, no scaling), and the weight of the softmax router's balance
+    # term in the train step's total (``ops.moe.balance_term``; 0: none)
+    moe_router: str = "sigmoid"
+    moe_balance_weight: float = 0.0
     expert_mlp_size: int = 0
     shared_experts: int = 0
     routed_scaling_factor: float = 1.0
@@ -201,6 +214,16 @@ class TransformerConfig:
 
     #: a pattern's kinds of layer
     KINDS = ("linear", "ssm", "full", "window", "mlp")
+    #: of them, the kinds the train step walks (``transformer.apply_trunk``):
+    #: attention layers that differ in their span and their rotary table
+    TRAINED_KINDS = ("full", "window")
+    #: what a pattern may not switch on to be trained: each is wired by the
+    #: serving path's block alone (models/decode.py ``layer_stack``)
+    PATTERN_SERVED_ONLY = ("qk_norm", "norm_on_output", "attn_output_gate",
+                           "no_positions", "rope_window_only", "mtp_layers",
+                           "mlp_act", "dense_prefix_layers",
+                           "embedding_multiplier", "residual_multiplier",
+                           "attention_multiplier", "logits_scaling")
     #: the published scalars, 0 where absent
     MULTIPLIERS = ("embedding_multiplier", "residual_multiplier",
                    "attention_multiplier", "logits_scaling")
@@ -214,7 +237,8 @@ class TransformerConfig:
                                 "linear_decay_per_channel",
                                 "linear_gate_rank", "ssm_groups", "mlp_act",
                                 "sliding_window", "rope_window_only",
-                                "mtp_layers", *self.MULTIPLIERS)
+                                "rope_yarn_kinds", "mtp_layers",
+                                *self.MULTIPLIERS)
                     if getattr(self, f)]
             if only:
                 raise ValueError(f"{only} are wired for a layer_pattern only "
@@ -254,6 +278,14 @@ class TransformerConfig:
             raise ValueError("rope_window_only: rotary 'window' layers "
                              "beside 'full' layers without positions needs "
                              "use_rope and a 'window' kind")
+        rotary = {k for k in ("window", "full") if k in pat and self.use_rope
+                  and (k == "window" or not self.rope_window_only)}
+        if set(self.rope_yarn_kinds) - rotary or bool(
+                self.rope_yarn_kinds) != bool(self.rope_yarn_factor):
+            raise ValueError(
+                f"rope_yarn_kinds {self.rope_yarn_kinds}: the rotary kinds "
+                f"of {pat} ({sorted(rotary)}) whose table is YaRN's, given "
+                "with rope_yarn_factor and not without")
         if self.mtp_layers not in (0, 1):
             raise ValueError(f"mtp_layers {self.mtp_layers}: models/"
                              "speculative.py drafts one token with one block")
@@ -319,9 +351,12 @@ class TransformerConfig:
                                  "without biases or softcap")
             if self.qk_rope_head_dim % 2:
                 raise ValueError("qk_rope_head_dim must be even")
-        elif self.rope_yarn_factor or self.q_lora_rank:
-            raise ValueError("rope_yarn_factor and q_lora_rank are read by "
-                             "latent attention only (models/latent.py)")
+        elif self.q_lora_rank or (self.rope_yarn_factor
+                                  and not self.rope_yarn_kinds):
+            raise ValueError(
+                "q_lora_rank is read by latent attention only (models/"
+                "latent.py), rope_yarn_factor by it or by a pattern's "
+                "rope_yarn_kinds")
         if self.rope_yarn_factor and not self.rope_yarn_original_max:
             raise ValueError("rope_yarn_factor needs rope_yarn_original_max")
         if self.moe_dropless:
@@ -330,6 +365,14 @@ class TransformerConfig:
                 raise ValueError("moe_dropless needs num_experts > 1, "
                                  "expert_mlp_size and a SwiGLU MLP (or "
                                  "mlp_act 'relu2' under a layer_pattern)")
+            if self.moe_router not in ("sigmoid", "softmax"):
+                raise ValueError(f"moe_router {self.moe_router!r}: "
+                                 "'sigmoid' or 'softmax'")
+            if self.moe_balance_weight and self.moe_router != "softmax":
+                raise ValueError(
+                    "moe_balance_weight weighs the softmax router's balance "
+                    "term; the sigmoid router balances by its selection "
+                    "bias, which no rule here moves")
             if not 0 < self.experts_per_token <= self.num_experts:
                 raise ValueError(
                     f"experts_per_token {self.experts_per_token} of "
@@ -355,10 +398,12 @@ class TransformerConfig:
                     f", expert_start {self.expert_start} the first of one")
         elif self.dense_prefix_layers or self.shared_experts \
                 or self.expert_mlp_size or self.expert_start \
-                or self.experts_held or self.share_by_position:
+                or self.experts_held or self.share_by_position \
+                or self.moe_router != "sigmoid" or self.moe_balance_weight:
             raise ValueError("dense_prefix_layers, shared_experts, "
-                             "expert_mlp_size, expert_start, experts_held "
-                             "and share_by_position belong to moe_dropless")
+                             "expert_mlp_size, expert_start, experts_held, "
+                             "share_by_position, moe_router and "
+                             "moe_balance_weight belong to moe_dropless")
         if self.hc_mult == 1 or self.hc_mult < 0:
             raise ValueError(f"hc_mult {self.hc_mult}: 0 (one residual "
                              "stream) or at least 2")
@@ -403,6 +448,17 @@ class TransformerConfig:
         """The mechanisms of this configuration that only the serving path
         runs (the train step has no block for them)."""
         return tuple(f for f in self.SERVED_ONLY if getattr(self, f))
+
+    @property
+    def pattern_untrained(self) -> Tuple[str, ...]:
+        """What of this configuration's pattern the train step has no
+        backward or no wiring for: the recurrent kinds (their kernels are
+        forward only), "mlp" layers of their own, and the fields of
+        ``PATTERN_SERVED_ONLY``.  Empty: ``apply_trunk`` walks it."""
+        kinds = tuple(k for k in dict.fromkeys(self.layer_pattern)
+                      if k not in self.TRAINED_KINDS)
+        return kinds + tuple(f for f in self.PATTERN_SERVED_ONLY
+                             if self.layer_pattern and getattr(self, f))
 
     @property
     def sublayers_alone(self) -> bool:

@@ -265,7 +265,7 @@ def _experts(y, lp, cfg: TransformerConfig, live, cast, layer, stacks):
         experts_per_token=cfg.experts_per_token,
         scaling=cfg.routed_scaling_factor, compute_dtype=cast,
         live=None if live is None else live.reshape(-1),
-        expert_start=cfg.expert_start)
+        expert_start=cfg.expert_start, router=cfg.moe_router)
     return out.reshape(y.shape), (counts, idx.reshape(y.shape[:2] + (-1,)))
 
 

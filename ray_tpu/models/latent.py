@@ -51,7 +51,6 @@ kernel itself.
 
 from __future__ import annotations
 
-import math
 from typing import Dict, Tuple
 
 import jax
@@ -61,7 +60,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from .config import TransformerConfig
-from .transformer import Params, _norm
+from .transformer import (Params, _norm, plain_inv_freq, yarn_inv_freq,
+                          yarn_mscale)
 
 F32 = jnp.float32
 #: a prefill row is padded to whole flash blocks (causality keeps the
@@ -75,27 +75,11 @@ FLASH_BLOCK = 512
 
 def rope_inv_freq(cfg: TransformerConfig) -> np.ndarray:
     """Inverse frequencies of the ``qk_rope_head_dim`` rotary dimensions
-    [R / 2], float32: plain, or YaRN's blend of the plain ones and the ones
-    divided by ``factor``, by a linear ramp between the dimensions that turn
-    ``beta_fast`` and ``beta_slow`` times over the original context."""
-    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
-    plain = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    [R / 2], float32: plain, or YaRN's (``transformer.yarn_inv_freq``)."""
+    dim = cfg.qk_rope_head_dim
     if not cfg.rope_yarn_factor:
-        return plain.astype(np.float32)
-
-    def turns_at(n_rot):        # the dimension that turns n_rot times
-        return dim * math.log(cfg.rope_yarn_original_max
-                              / (n_rot * 2 * math.pi)) / (2 * math.log(base))
-
-    low = max(math.floor(turns_at(cfg.rope_yarn_beta_fast)), 0)
-    high = min(math.ceil(turns_at(cfg.rope_yarn_beta_slow)), dim - 1)
-    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
-    return (plain / cfg.rope_yarn_factor * ramp
-            + plain * (1 - ramp)).astype(np.float32)
-
-
-def _yarn_mscale(factor: float, mscale: float) -> float:
-    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+        return plain_inv_freq(cfg.rope_theta, dim).astype(np.float32)
+    return yarn_inv_freq(cfg, dim)
 
 
 def rope_magnitude(cfg: TransformerConfig) -> float:
@@ -104,15 +88,15 @@ def rope_magnitude(cfg: TransformerConfig) -> float:
     f = cfg.rope_yarn_factor
     if not f:
         return 1.0
-    return (_yarn_mscale(f, cfg.rope_yarn_mscale)
-            / _yarn_mscale(f, cfg.rope_yarn_mscale_all_dim))
+    return (yarn_mscale(f, cfg.rope_yarn_mscale)
+            / yarn_mscale(f, cfg.rope_yarn_mscale_all_dim))
 
 
 def softmax_scale(cfg: TransformerConfig) -> float:
     scale = cfg.qk_head_dim ** -0.5
     if cfg.rope_yarn_factor and cfg.rope_yarn_mscale_all_dim:
-        scale *= _yarn_mscale(cfg.rope_yarn_factor,
-                              cfg.rope_yarn_mscale_all_dim) ** 2
+        scale *= yarn_mscale(cfg.rope_yarn_factor,
+                             cfg.rope_yarn_mscale_all_dim) ** 2
     return scale
 
 

@@ -211,20 +211,74 @@ def _norm(x, p, cfg: TransformerConfig):
     return (x32 * p["scale"] + p["bias"]).astype(x.dtype)
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: [B, S, H, D]; positions: [S]."""
+def plain_inv_freq(theta: float, dim: int) -> np.ndarray:
+    """The plain rotary table's inverse frequencies [dim / 2], float64."""
+    return 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+
+def yarn_inv_freq(cfg: TransformerConfig, dim: int) -> np.ndarray:
+    """Inverse frequencies of ``dim`` rotary dimensions [dim / 2], float32,
+    under the configuration's YaRN (``rope_yarn_*``): a blend of the plain
+    ones at ``rope_theta`` and the ones divided by ``factor``, by a linear
+    ramp between the dimensions that turn ``beta_fast`` and ``beta_slow``
+    times over the original context."""
+    base = cfg.rope_theta
+    plain = plain_inv_freq(base, dim)
+
+    def turns_at(n_rot):        # the dimension that turns n_rot times
+        return dim * math.log(cfg.rope_yarn_original_max
+                              / (n_rot * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(turns_at(cfg.rope_yarn_beta_fast)), 0)
+    high = min(math.ceil(turns_at(cfg.rope_yarn_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain / cfg.rope_yarn_factor * ramp
+            + plain * (1 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_table(cfg: TransformerConfig, kind: str):
+    """The rotary table of a pattern's layer of ``kind``: (inverse
+    frequencies [head_dim / 2] float32, what cos and sin are multiplied by),
+    YaRN's for the kinds of ``cfg.rope_yarn_kinds`` (the published
+    ``attention_factor``: ``0.1 ln(factor) + 1``), plain for the others;
+    None where the kind adds no positions."""
+    if not cfg.use_rope or (cfg.rope_window_only and kind != "window"):
+        return None
+    dim = cfg.head_dim
+    if kind in cfg.rope_yarn_kinds:
+        return yarn_inv_freq(cfg, dim), (
+            yarn_mscale(cfg.rope_yarn_factor, cfg.rope_yarn_mscale)
+            / yarn_mscale(cfg.rope_yarn_factor, cfg.rope_yarn_mscale_all_dim))
+    return plain_inv_freq(cfg.rope_theta, dim).astype(np.float32), 1.0
+
+
+def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+          table=None) -> jnp.ndarray:
+    """x: [B, S, H, D]; positions: [S]; ``table``: a kind's own
+    (``rope_table``) in place of the plain one at ``theta``."""
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    mag = 1.0
+    if table is not None:
+        freqs, mag = jnp.asarray(table[0]), table[1]
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, D/2]
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
+    cos = (jnp.cos(angles) * mag)[None, :, None, :]
+    sin = (jnp.sin(angles) * mag)[None, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
 
 @jax.named_scope("attn")
-def _attention_block(x, p, cfg: TransformerConfig, positions, pctx: ParallelContext):
+def _attention_block(x, p, cfg: TransformerConfig, positions,
+                     pctx: ParallelContext, kind: Optional[str] = None):
+    """``kind``: the layer's place in a pattern ("full", "window"), which
+    says its span (``cfg.sliding_window``) and its rotary table
+    (``rope_table``); None: a dense model's layer."""
     b, s, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     cast = x.dtype
@@ -236,9 +290,23 @@ def _attention_block(x, p, cfg: TransformerConfig, positions, pctx: ParallelCont
     q = q.reshape(b, s, nh, hd)
     k = k.reshape(b, s, nkv, hd)
     v = v.reshape(b, s, nkv, hd)
-    if cfg.use_rope:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+    window, scale, table = 0, None, None
+    rotary = cfg.use_rope
+    if kind is not None:
+        if cfg.qk_head_norm:        # over a head, one scale for all heads
+            q, k = _norm(q, p["q_norm"], cfg), _norm(k, p["k_norm"], cfg)
+        table = rope_table(cfg, kind)
+        rotary = table is not None
+        window = cfg.sliding_window if kind == "window" else 0
+        scale = cfg.attn_scale
+        if window and (pctx.use_ring or cfg.attention_impl == "splash"):
+            raise NotImplementedError(
+                "a 'window' layer trains through the flash kernel's band "
+                "or the plain mask: ring attention and the splash kernel "
+                "have neither")
+    if rotary:
+        q = _rope(q, positions, cfg.rope_theta, table)
+        k = _rope(k, positions, cfg.rope_theta, table)
     q = checkpoint_name(q, "attn_q")
     k = checkpoint_name(k, "attn_k")
     v = checkpoint_name(v, "attn_v")
@@ -263,14 +331,16 @@ def _attention_block(x, p, cfg: TransformerConfig, positions, pctx: ParallelCont
                              mesh=kmesh, batch_axes=pctx.batch_axes)
         elif impl == "plain":
             out = attend(q, k, v, causal=cfg.causal,
-                         logit_softcap=cfg.attn_logit_softcap)
+                         logit_softcap=cfg.attn_logit_softcap, window=window,
+                         scale=scale)
         elif impl in ("flash", "auto"):
             # explicit "flash" raises where the kernel cannot run (softcap,
             # a shape that does not tile); only "auto" chooses
             out = mha(q, k, v, causal=cfg.causal,
                       logit_softcap=cfg.attn_logit_softcap,
                       use_flash=True if impl == "flash" else None,
-                      mesh=kmesh, batch_axes=pctx.batch_axes)
+                      mesh=kmesh, batch_axes=pctx.batch_axes, window=window,
+                      scale=scale)
         else:
             raise ValueError(f"unknown attention_impl {impl!r}")
     out = checkpoint_name(out, "attn_out")
@@ -326,7 +396,8 @@ def _block(x: jnp.ndarray, layer_params: Params, cfg: TransformerConfig,
     if "mlp" in layer_params:
         out = _mlp_block(y, layer_params["mlp"], cfg)
     elif cfg.moe_dropless:
-        out, aux["moe_load"] = _dropless_block(y, layer_params["moe"], cfg)
+        out, more = _dropless_block(y, layer_params["moe"], cfg, pctx)
+        aux.update(more)
     else:
         out, aux["moe_aux_loss"] = moe_ops.moe_mlp(
             y, layer_params["moe"]["router"], layer_params["moe"]["w_gate"],
@@ -335,13 +406,115 @@ def _block(x: jnp.ndarray, layer_params: Params, cfg: TransformerConfig,
     return x + out, aux
 
 
-def _dropless_block(y, mp: Params, cfg: TransformerConfig):
+def _pattern_block(x, lp: Params, experts: Optional[Params],
+                   cfg: TransformerConfig, positions, pctx: ParallelContext,
+                   kind: str):
+    """One layer of a pattern that trains (``cfg.pattern_untrained`` empty):
+    ``_block``'s wiring with the attention of the layer's ``kind``; ``lp``
+    the layer's leaves of ``models/hybrid.py``'s tree, ``experts`` its routed
+    experts' three matrices [held, ...] where the model has them."""
+    with jax.named_scope(f"layer_{kind}"):
+        y = _norm(x, lp["attn_norm"], cfg)
+        x = x + _attention_block(y, lp["attn"], cfg, positions, pctx, kind)
+        y = _norm(x, lp["mlp_norm"], cfg)
+        if "mlp" in lp:
+            return x + _mlp_block(y, lp["mlp"], cfg), {}
+        out, aux = _dropless_block(y, {**lp["moe"], **experts}, cfg, pctx)
+        return x + out, aux
+
+
+def _pattern_trunk(blocks: Params, x, cfg: TransformerConfig, positions,
+                   pctx: ParallelContext, remat):
+    """The layers of a pattern, a ``lax.scan`` over its periods: one period's
+    layers are traced once, in the pattern's order, each with the span and
+    the rotary table of its place (static: data of the place, not a second
+    block).  ``blocks``: ``models/hybrid.py``'s tree, a kind's leaves
+    [periods, layers of the kind a period, ...] and the routed experts
+    [layers, held, ...] in layer order.  Returns (x, aux stacked
+    [layers, ...])."""
+    pattern, periods = cfg.layer_pattern, cfg.num_periods
+    enabled, policy = remat_policy(remat)
+    xs = {k: blocks[k] for k in dict.fromkeys(pattern)}
+    if "experts" in blocks:
+        xs["experts"] = jax.tree.map(
+            lambda a: a.reshape((periods, len(pattern)) + a.shape[1:]),
+            blocks["experts"])
+
+    def period(x, pp):
+        auxes, seen = [], {}
+        for j, kind in enumerate(pattern):
+            n = seen[kind] = seen.get(kind, -1) + 1
+            layer = functools.partial(_pattern_block, cfg=cfg,
+                                      positions=positions, pctx=pctx,
+                                      kind=kind)
+            if enabled:
+                layer = jax.checkpoint(layer, policy=policy)
+            x, aux = layer(x, jax.tree.map(lambda a: a[n], pp[kind]),
+                           jax.tree.map(lambda a: a[j], pp["experts"])
+                           if "experts" in pp else None)
+            auxes.append(aux)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+
+    x, aux = jax.lax.scan(period, x, xs)
+    return x, jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), aux)
+
+
+def _dropless_block(y, mp: Params, cfg: TransformerConfig,
+                    pctx: ParallelContext = ParallelContext()):
     """The dropless expert layer (``ops.moe.moe_dropless``) on y [B, S, H]
     with one layer's parameters ``mp``: the experts this holder has, cast to
     y's dtype and handed over as a stack of one layer; the router scores y
-    in float32 with its float32 master weights.  Returns (out, load
-    [experts held])."""
+    in float32 with its float32 master weights.  On a mesh of more than one
+    device the experts lie ``num_experts / ep`` a holder and the layer is
+    ``moe_dropless_ep`` under a ``shard_map``: each holder routes its own
+    sequences and the exchange over ``ep`` does the rest (the mesh decides;
+    there is no switch).  Returns (out, aux): ``moe_load``
+    [experts] int32, the assignments each expert computed (of the experts
+    held here; all of them over ``ep``), ``moe_chip_load`` [holders], their
+    sum a holder, and ``moe_balance``, the softmax router's balance term, a
+    mean over the sequences (0 where the configuration weighs none)."""
     routed = ("w_gate", "w_in", "w_out")
+    small = {k: v for k, v in mp.items() if k not in routed}
+    kw = dict(experts_per_token=cfg.experts_per_token,
+              scaling=cfg.routed_scaling_factor, router=cfg.moe_router)
+
+    def balance(y, idx):
+        """[B]: the balance term of each sequence's tokens."""
+        if not cfg.moe_balance_weight:
+            return jnp.zeros(y.shape[:1], jnp.float32)
+        return jax.vmap(lambda t, i: moe_ops.balance_term(
+            t, small["router"], i))(y, idx.reshape(y.shape[:2] + (-1,)))
+
+    mesh = None if pctx.manual_collectives else pctx.mesh
+    if mesh is not None and mesh.size > 1:
+        # (whatever ``ep`` is: the kernels under the layer want a manual
+        # region, and with one holder the walk is one step and no hop)
+        if cfg.experts_held != cfg.num_experts or cfg.share_by_position:
+            raise NotImplementedError(
+                "a share of the experts (experts_held, share_by_position) "
+                "is one chip's cut of a layer with no exchange; on a mesh "
+                "with an ep axis the holders have every expert between them")
+        P = jax.sharding.PartitionSpec
+        over = tuple(a for a in pctx.batch_axes if a in mesh.axis_names)
+        batch = P(over)
+        groups = tuple(a for a in over if a != "ep")
+
+        def holder(y, small, stacks):
+            out, load, idx = moe_ops.moe_dropless_ep(
+                y.reshape(-1, y.shape[-1]), small,
+                {k: jax.lax.optimization_barrier(v)[None].astype(y.dtype)
+                 for k, v in stacks.items()}, 0, axis="ep", **kw)
+            # (a holder's experts have a copy in every data-parallel group)
+            load = jax.lax.psum(load, groups) if groups else load
+            return out.reshape(y.shape), load, load.sum()[None], \
+                balance(y, idx), idx.reshape(y.shape[:2] + (-1,))
+
+        out, load, chips, bal, idx = jax.shard_map(
+            holder, mesh=mesh, in_specs=(batch, P(), P("ep")),
+            out_specs=(batch, P("ep"), P("ep"), batch, batch),
+            check_vma=False)(y, small, {k: mp[k] for k in routed})
+        return out, {"moe_load": load, "moe_chip_load": chips,
+                     "moe_balance": bal.mean(), "moe_choices": idx}
     start = cfg.expert_start
     if cfg.share_by_position:
         # the held weights stand for another group of the router's outputs
@@ -349,16 +522,16 @@ def _dropless_block(y, mp: Params, cfg: TransformerConfig):
         group = (start // cfg.experts_held + jnp.arange(y.shape[1])) \
             % (cfg.num_experts // cfg.experts_held)
         start = jnp.tile(group * cfg.experts_held, y.shape[0])
-    out, _, _, load = moe_ops.moe_dropless(
-        y.reshape(-1, y.shape[-1]),
-        {k: v for k, v in mp.items() if k not in routed},
+    out, _, idx, load = moe_ops.moe_dropless(
+        y.reshape(-1, y.shape[-1]), small,
         # (the barrier keeps the cast in the layer: hoisted out of the scan
         # it is a second copy of every layer's experts)
         {k: jax.lax.optimization_barrier(mp[k])[None].astype(y.dtype)
-         for k in routed}, 0,
-        experts_per_token=cfg.experts_per_token,
-        scaling=cfg.routed_scaling_factor, expert_start=start)
-    return out.reshape(y.shape), load
+         for k in routed}, 0, expert_start=start, **kw)
+    return out.reshape(y.shape), {
+        "moe_load": load, "moe_chip_load": load.sum()[None],
+        "moe_balance": balance(y, idx).mean(),
+        "moe_choices": idx.reshape(y.shape[:2] + (-1,))}
 
 
 def block_forward(x: jnp.ndarray, layer_params: Params, cfg: TransformerConfig,
@@ -382,14 +555,22 @@ def embed_tokens(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
 
 
 def refuse_layer_pattern(cfg: TransformerConfig, what: str):
-    """The training path has no layers of two kinds (the gated-delta-rule
-    kernels have no backward and ``apply_trunk`` scans one block kind) and
-    none of what ``cfg.served_only`` names (several residual streams)."""
-    if cfg.layer_pattern:
+    """What the training path still cannot run.  A pattern of "full" and
+    "window" attention layers trains (``_pattern_trunk``: the band has a
+    backward, ``ops/flash_attention.py``); a pattern with a recurrent kind
+    ("linear", "ssm": their chunk kernels are forward only), with "mlp"
+    layers of their own, or with anything only the serving path's block
+    wires (``cfg.pattern_untrained``) does not, and neither does what
+    ``cfg.served_only`` names (several residual streams)."""
+    if cfg.pattern_untrained:
         raise NotImplementedError(
-            f"{what}: layer_pattern {cfg.layer_pattern} is served "
-            "(models/hybrid.py: prefill, decode_step), not trained: the "
-            "linear-attention kernels have no backward")
+            f"{what}: layer_pattern {cfg.layer_pattern} with "
+            f"{', '.join(cfg.pattern_untrained)} is served (models/hybrid.py,"
+            " models/decode.py: prefill, decode_step), not trained: the "
+            "gated-delta-rule and state-space kernels have no backward, and "
+            "the train step's block wires a pre-norm attention layer "
+            "('full' or 'window') with its MLP or experts beneath and no "
+            "more")
     if cfg.served_only:
         raise NotImplementedError(
             f"{what}: {', '.join(cfg.served_only)} is served "
@@ -414,6 +595,11 @@ def apply_trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
     # batch XLA partitions this computation (only ring attention, which runs in
     # shard_map, handles per-shard offsets itself).
     positions = jnp.arange(s)
+    if cfg.layer_pattern:
+        x, aux = _pattern_trunk(params["blocks"], x, cfg, positions, pctx,
+                                remat)
+        aux.setdefault("moe_aux_loss", jnp.zeros((1,), jnp.float32))
+        return _norm(x, params["final_norm"], cfg), _trunk_aux(aux)
 
     def scan_body(x, layer_params):
         return _block(x, layer_params, cfg, positions, pctx)
@@ -433,7 +619,14 @@ def apply_trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         x, _ = scan_body(x, jax.tree.map(lambda a: a[j], params["prefix"]))
     x, aux = jax.lax.scan(scan_body, x, params["blocks"])
     x = _norm(x, params["final_norm"], cfg)
-    return x, dict(aux, moe_aux_loss=aux["moe_aux_loss"].mean())
+    return x, _trunk_aux(aux)
+
+
+def _trunk_aux(aux):
+    """The layers' stacked aux with its two terms of the loss as means over
+    the layers."""
+    return dict(aux, **{k: aux[k].mean()
+                        for k in ("moe_aux_loss", "moe_balance") if k in aux})
 
 
 def lm_head_weight(params: Params, cfg: TransformerConfig, dtype) -> jnp.ndarray:
@@ -662,9 +855,40 @@ def causal_lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
     if "moe_load" in aux:
         # what a trainer logs of dropless expert layers: the assignments
         # the experts held here computed, summed over the expert layers,
-        # and the most and the fewest any one of them saw in a layer
-        load = aux["moe_load"]
+        # and the most and the fewest any one of them saw in a layer; the
+        # same of a holder's experts together (over ``ep``: of a chip, the
+        # slowest of which sets the step's pace); the balance term, which
+        # joins the total and not ``loss``; and what a holder sends a step
+        # for the exchange
+        load, chips = aux["moe_load"], aux["moe_chip_load"]
+        total = total + cfg.moe_balance_weight * aux["moe_balance"]
         metrics.update(moe_assignments_held=load.sum(),
                        moe_expert_load_max=load.max(),
-                       moe_expert_load_min=load.min())
+                       moe_expert_load_min=load.min(),
+                       moe_chip_load_max=chips.max(),
+                       moe_chip_load_min=chips.min(),
+                       moe_balance=aux["moe_balance"],
+                       moe_exchange_bytes=_exchange_bytes(
+                           cfg, pctx, tokens.shape, compute_dtype,
+                           remat_policy(remat)[0]))
     return total, metrics
+
+
+def _exchange_bytes(cfg: TransformerConfig, pctx: ParallelContext, shape,
+                    dtype, replayed: bool) -> float:
+    """Bytes one holder sends a train step for the exchange of its expert
+    layers over ``ep`` (``ops.moe.exchange_bytes``): the forward's two
+    walks, their transposes in the backward, and the tokens' walk again
+    where the layer is replayed.  0 without an ``ep`` axis."""
+    mesh = None if pctx.manual_collectives else pctx.mesh
+    ep = mesh.shape.get("ep", 1) if mesh is not None else 1
+    if ep == 1:
+        return 0.0
+    shards = math.prod(mesh.shape[a] for a in pctx.batch_axes
+                       if a in mesh.axis_names)
+    tokens = shape[0] * shape[1] // shards
+    args = (tokens, cfg.hidden_size, cfg.experts_per_token, ep,
+            jnp.dtype(dtype).itemsize)
+    once = moe_ops.exchange_bytes(*args)
+    again = once - (ep - 1) * tokens * cfg.hidden_size * 4 if replayed else 0
+    return float(cfg.expert_layers * (2 * once + again))   # past int32

@@ -1,4 +1,5 @@
-"""Pallas TPU flash attention: a forward kernel and one backward kernel.
+"""Pallas TPU flash attention: a forward kernel and one backward kernel, each
+with a twin for a band (a sliding window).
 
 The reference has no TPU kernels at all (its attention lives in external torch
 models); this is greenfield TPU-first code (SURVEY §5.7, §7 stance).
@@ -76,6 +77,8 @@ KERNEL_FLASH_WINDOW = "flash_window_prefill"
 #: KV positions a step of the banded kernel takes: a band of 128 under a
 #: query block of 512 touches 5 such blocks (640 positions), 2 of 512 (1,024)
 WINDOW_BLOCK_KV = 128
+#: the backward of the band (``_bwd_window_kernel``), a kernel of its own
+KERNEL_FLASH_WINDOW_BWD = "flash_window_bwd"
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_kv: int,
@@ -268,7 +271,7 @@ def _flash_fwd_rows(q, k_all, v_all, at, num_heads: int, kv_len: int,
 
 
 def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int,
-                   scale: Optional[float] = None):
+                   scale: Optional[float] = None, window: int = 0):
     """Flash backward, recompute-based, as a scan over KV blocks.
 
     q: [B, H, S, Dqk]; out/g: [B, H, S, Dv]; k: [B, KV, S, Dqk]; v: [B, KV,
@@ -299,8 +302,10 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal: bool, block_kv: int,
         sj = jnp.einsum("bhqd,bhkd->bhqk", qf, kjh.astype(jnp.float32)) * scale
         if causal:
             k_pos = j * block_kv + jnp.arange(block_kv)
-            sj = jnp.where((q_pos[:, None] >= k_pos[None, :])[None, None],
-                           sj, NEG_INF)
+            seen = q_pos[:, None] >= k_pos[None, :]
+            if window:
+                seen = seen & (q_pos[:, None] - k_pos[None, :] < window)
+            sj = jnp.where(seen[None, None], sj, NEG_INF)
         p = jnp.exp(sj - lse[..., None])                        # [B,H,S,bkv]
         dv_h = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
         dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vjh.astype(jnp.float32))
@@ -490,6 +495,173 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal: bool, block_q: int,
     )(q, k, v, gf, lse4, dlt4)
 
 
+def window_band_blocks(window: int, block: int) -> int:
+    """Query blocks of ``block`` positions that the band of a KV block of as
+    many touches: a key at ``p`` is read by the queries ``p .. p + window -
+    1``.  3 for a band of 1,024 under blocks of 512."""
+    return (block + window - 2) // block + 1
+
+
+def _bwd_window_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
+                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                       scale: float, window: int, n_q: int):
+    """``_bwd_kernel`` for a band: grid (b, kv_heads, n_kv, reps, n_band),
+    the q blocks a kv block's band touches and no others, the farthest
+    first and the diagonal's last (blocks of one size, so q block ``ki`` is
+    kv block ``ki``'s diagonal).  dk / dv accumulate over the two innermost
+    dims as there.  dq of q block ``qj`` gathers from the kv blocks ``qj -
+    n_band + 1 .. qj``, which the grid reaches in that order with other q
+    blocks between: its running sum lies in slot ``qj % n_band`` of
+    ``dq_acc`` [reps, n_band, block, d_qk] and goes out at every visit, the
+    last of which (the diagonal's) leaves the whole sum."""
+    ki, r, j = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    n_rep, n_band = pl.num_programs(3), pl.num_programs(4)
+    block = k_ref.shape[2]
+    ahead = n_band - 1 - j                  # q blocks past the diagonal
+    qj = ki + ahead
+    slot = qj % n_band
+
+    @pl.when((r == 0) & (j == 0))
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    # past the last q block the index maps repeat it: nothing to do there
+    @pl.when(qj < n_q)
+    def _compute():
+        q = q_ref[0, 0]                               # [bq, d_qk]
+        g = g_ref[0, 0]                               # [bq, d_v]
+        k = k_ref[0, 0]                               # [bkv, d_qk]
+        v = v_ref[0, 0]                               # [bkv, d_v]
+        lse = lse_ref[0, 0]                           # [1, bq] f32
+        dlt = dlt_ref[0, 0]
+        s_t = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # [bkv, bq]
+        k_pos = ki * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 0)
+        q_pos = qj * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 1)
+        seen = (q_pos >= k_pos) & (q_pos - k_pos < window)
+        p_t = jnp.where(seen, jnp.exp(jnp.where(seen, s_t, NEG_INF) - lse),
+                        0.0)
+        dv_acc[...] += jax.lax.dot_general(
+            p_t.astype(g.dtype), g, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bkv, d_v]
+        dp_t = jax.lax.dot_general(
+            v, g, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds_t = (p_t * (dp_t - dlt) * scale).astype(q.dtype)
+        dk_acc[...] += jax.lax.dot_general(
+            ds_t, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bkv, d_qk]
+        dq = jax.lax.dot_general(
+            ds_t, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bq, d_qk]
+        # the q block's first kv block: the farthest, or the sequence's first
+        first = (j == 0) | (ki == 0)
+
+        @pl.when(first)
+        def _():
+            dq_acc[r, slot] = dq
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            dq_acc[r, slot] += dq
+
+        dq_ref[0, 0] = dq_acc[r, slot].astype(dq_ref.dtype)
+
+    @pl.when((r == n_rep - 1) & (j == n_band - 1))
+    def _flush():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _flash_window_bwd_pallas(q, k, v, out, lse, g, block: int, window: int,
+                             interpret: bool, scale: Optional[float] = None):
+    """The band's backward: (dq, dk, dv) as ``_flash_bwd_pallas`` gives
+    them, over the (kv block, q block) pairs the band touches."""
+    b, h, s, d = q.shape
+    d_v = v.shape[3]
+    kv_heads = k.shape[1]
+    reps = h // kv_heads
+    n_q = s // block
+    n_band = window_band_blocks(window, block)
+    gf = g.astype(q.dtype)
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    lse4 = lse[:, :, None, :]
+    dlt4 = delta[:, :, None, :]
+
+    def q_at(ki, j):
+        return jnp.minimum(ki + n_band - 1 - j, n_q - 1)
+
+    def q_rows(width):              # a q block of a query head: q, g, dq
+        return pl.BlockSpec(
+            (1, 1, block, width),
+            lambda bi, gi, ki, r, j: (bi, gi * reps + r, q_at(ki, j), 0))
+
+    def kv_rows(width):             # a kv block of a kv head: k, v, dk, dv
+        return pl.BlockSpec((1, 1, block, width),
+                            lambda bi, gi, ki, r, j: (bi, gi, ki, 0))
+
+    stat = pl.BlockSpec(
+        (1, 1, 1, block),
+        lambda bi, gi, ki, r, j: (bi, gi * reps + r, 0, q_at(ki, j)))
+    return pl.pallas_call(
+        functools.partial(_bwd_window_kernel, scale=score_scale(scale, d),
+                          window=window, n_q=n_q),
+        grid=(b, kv_heads, s // block, reps, n_band),
+        in_specs=[q_rows(d), kv_rows(d), kv_rows(d_v), q_rows(d_v), stat,
+                  stat],
+        out_specs=[q_rows(d), kv_rows(d), kv_rows(d_v)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b, kv_heads, s, d), k.dtype),
+            jax.ShapeDtypeStruct((b, kv_heads, s, d_v), v.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((reps, n_band, block, d), jnp.float32),
+                        pltpu.VMEM((block, d), jnp.float32),
+                        pltpu.VMEM((block, d_v), jnp.float32)],
+        interpret=interpret,
+        name=KERNEL_FLASH_WINDOW_BWD,
+    )(q, k, v, gf, lse4, dlt4)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_window(q, k, v, block_q, block_kv, interpret, window, scale=None):
+    """The banded forward with its backward: a query reads its last
+    ``window`` positions."""
+    out, _ = _flash_fwd(q, k, v, True, block_q, block_kv, interpret, window,
+                        scale)
+    return out
+
+
+def _flash_window_vjp_fwd(q, k, v, block_q, block_kv, interpret, window,
+                          scale):
+    from jax.ad_checkpoint import checkpoint_name
+    out, lse = _flash_fwd(q, k, v, True, block_q, block_kv, interpret,
+                          window, scale)
+    out = checkpoint_name(out, "attn_out")      # as ``_flash_vjp_fwd``
+    lse = checkpoint_name(lse, "attn_lse")
+    return out, (q, k, v, out, lse)
+
+
+def _flash_window_vjp_bwd(block_q, block_kv, interpret, window, scale, res,
+                          g):
+    q, k, v, out, lse = res
+    s = q.shape[2]
+    # blocks of one size, whole 128s (``flash_bwd_supported``'s tiling rule;
+    # nothing stays resident for the whole sequence here)
+    if block_q % 128 == 0 and s % block_q == 0:
+        return _flash_window_bwd_pallas(q, k, v, out, lse, g, block_q,
+                                        window, interpret, scale)
+    return _bwd_blockwise(q, k, v, out, lse, g, True, block_kv, scale,
+                          window)
+
+
+_flash_window.defvjp(_flash_window_vjp_fwd, _flash_window_vjp_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, block_q, block_kv, interpret, scale=None):
     out, _ = _flash_fwd(q, k, v, causal, block_q, block_kv, interpret,
@@ -618,8 +790,12 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
 
     ``window`` > 0 (causal): a query reads its last ``window`` positions,
     its own among them; KV blocks (of ``WINDOW_BLOCK_KV`` at most) that a
-    query block's band does not touch are not computed.  The forward alone
-    (the serving path's): it takes no gradient.
+    query block's band does not touch are not computed.  Differentiable:
+    the forward keeps ``lse`` and the backward is a kernel of its own
+    (``flash_window_bwd``) over the (KV block, query block) pairs the band
+    touches, as the forward skips the others; where the blocks do not tile
+    for it (under 128 positions: the CPU tests' small shapes) the scan in
+    plain JAX with the band's mask.
     """
     b, sq, h, d = q.shape
     if window:
@@ -639,8 +815,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     def local(q, k, v):
         heads_first = (q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2))
         if window:
-            out, _ = _flash_fwd(*heads_first, True, block_q, block_kv,
-                                interpret, window, scale)
+            out = _flash_window(*heads_first, block_q, block_kv, interpret,
+                                window, scale)
         else:
             out = _flash(*heads_first, causal, block_q, block_kv, interpret,
                          scale)

@@ -11,8 +11,11 @@ batch it came in, prefill-then-decode cannot match a full forward, and the
 one-hot tensors are ``T x E x C`` floats (8 GB at 8,192 tokens, 64 experts).
 
 ``moe_dropless``: the layer that serves, and that trains without a capacity.
-Sigmoid scores in float32, the top k of score + selection bias, gates the
-chosen scores normalised and scaled; the ``T x k`` assignments sorted by
+A router by its kind (``route``): sigmoid scores in float32, the top k of
+score + selection bias, gates the chosen scores normalised and scaled
+(``route_sigmoid``), or a softmax over all experts in float32, its top k, the
+chosen probabilities over their sum (``route_softmax``, with its balance
+term for the train step's total, ``balance_term``); the ``T x k`` assignments sorted by
 expert, one grouped matmul over the experts that have a token (``moe_gmm``:
 a Pallas kernel, group sizes by scalar prefetch, an expert with no token
 neither fetched nor computed; its twin ``jax.lax.ragged_dot`` on the CPU),
@@ -31,8 +34,14 @@ scatter-adds autodiff would write.  The layer is
 told which experts it holds (``expert_start`` and the leading dimension of
 the weights it is given) and routes over all of them: assignments to experts
 it does not hold are left out of its part of the result, as they would be
-computed on the chips that hold those (on one chip the range is every expert
-and there is no exchange; nothing here stands in for absent chips).  The
+computed on the chips that hold those (on one chip the range is every
+expert; a share's cell runs one holder's part and nothing stands in for the
+absent chips).  ``moe_dropless_ep`` is the same layer with the experts on
+the holders of a mesh axis and their **exchange**: the router scores a
+token where it lives, a block of tokens walks the ring of holders by
+``ppermute``, each holder computes its experts' part for the block it holds
+and sends it straight back in float32; explicit collectives, no capacity,
+and a sorted layout sized for every assignment of one block.  The
 expert weights stay where they lie in the layer stack ``[layers, experts,
 ...]``: the kernel takes the layer index by scalar prefetch as
 ``decode_attn`` does.
@@ -155,6 +164,43 @@ def route_sigmoid(x, router_w, bias, k: int, scaling: float):
     gates = jnp.take_along_axis(scores, idx, axis=-1)
     gates = gates / (gates.sum(-1, keepdims=True) + 1e-20) * scaling
     return idx.astype(jnp.int32), gates
+
+
+def route_softmax(x, router_w, k: int):
+    """Qwen3-MoE's router (``norm_topk_prob`` true).  x: [T, H]; router_w:
+    [H, E].  Probabilities ``softmax(x W_r)`` over all E in float32; the
+    experts are the ``k`` most probable; the gates are the chosen
+    probabilities over their sum.  No selection bias and no scaling.
+    Returns (experts [T, k] int32, gates [T, k] float32)."""
+    probs = jax.nn.softmax(jnp.dot(x.astype(F32), router_w.astype(F32),
+                                   precision=jax.lax.Precision.HIGHEST), -1)
+    # as ``route_sigmoid``: the choice is no function to differentiate
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(probs), k)
+    gates = jnp.take_along_axis(probs, idx, axis=-1)
+    return idx.astype(jnp.int32), gates / (gates.sum(-1, keepdims=True)
+                                           + 1e-20)
+
+
+def balance_term(x, router_w, idx):
+    """The softmax router's load-balancing term over the tokens x [T, H]
+    whose chosen experts are idx [T, k] (Switch Transformer's, as the
+    family's ``load_balancing_loss_func`` has it): ``E sum_e f_e P_e``, f_e
+    the assignments expert e got over T (they sum to k, and carry no
+    gradient), P_e the mean probability the router gave it.  k where both
+    are uniform."""
+    probs = jax.nn.softmax(jnp.dot(x.astype(F32), router_w.astype(F32),
+                                   precision=jax.lax.Precision.HIGHEST), -1)
+    e = probs.shape[-1]
+    f = jnp.zeros((e,), F32).at[idx.reshape(-1)].add(1.0) / idx.shape[0]
+    return e * jnp.sum(f * probs.mean(0))
+
+
+def route(x, small, k: int, scaling: float, router: str):
+    """The layer's router by its kind (``TransformerConfig.moe_router``):
+    (experts [T, k] int32, gates [T, k] float32)."""
+    if router == "softmax":
+        return route_softmax(x, small["router"], k)
+    return route_sigmoid(x, small["router"], small["bias"], k, scaling)
 
 
 def tile_rows(assignments: int, experts: int) -> int:
@@ -620,9 +666,57 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _held_part(x, idx, gates, live, stacks, layer, expert_start, k: int,
+               use_kernel, interpret):
+    """What the experts held here add for the tokens x [T, H] (in the
+    dtype they are multiplied in) under the router's choice idx [T, k] and
+    gates [T, k]: the assignments to experts ``expert_start`` and on,
+    sorted, through the grouped products, combined.  Returns (out [T, H]
+    float32, sizes [held] int32: the assignments each held expert
+    computed)."""
+    t, _ = x.shape
+    held = stacks["w_out"].shape[1]
+    if jnp.ndim(expert_start):
+        expert_start = expert_start[:, None]
+    mine = (idx >= expert_start) & (idx < expert_start + held)
+    if live is not None:
+        mine = mine & live[:, None]
+    with jax.named_scope("moe_sort"):
+        tile = tile_rows(t * k, held)
+        dest, source, tile_expert, tiles, sizes = sort_by_expert(
+            idx - expert_start, mine, held, tile)
+        xs = _rows_in(x, source, dest)
+    with jax.named_scope("moe_experts"):
+        gmm = functools.partial(moe_gmm, layer=layer, tile_expert=tile_expert,
+                                tiles=tiles, tile=tile, use_kernel=use_kernel,
+                                interpret=interpret)
+        if "w_gate" in stacks:
+            act = gmm(xs, (stacks["w_gate"], stacks["w_in"]))
+        else:
+            act = gmm(xs, (stacks["w_up"],), activation="relu2",
+                      transposed=True)
+        ys = gmm(act, (stacks["w_out"],))
+    with jax.named_scope("moe_combine"):
+        # an assignment that is not this layer's reads a row past the end
+        out = _combine(ys, gates, mine, source, dest)
+    return out, sizes
+
+
+def _shared_expert(x, small):
+    """The shared expert on every token x [T, H], float32."""
+    if "shared_gate" in small:
+        up = (jax.nn.silu(x @ small["shared_gate"].astype(x.dtype))
+              * (x @ small["shared_in"].astype(x.dtype)))
+    else:
+        # squared in float32 before it is rounded, as the kernel's
+        up = relu2(jnp.dot(x, small["shared_in"].astype(x.dtype),
+                            preferred_element_type=F32)).astype(x.dtype)
+    return (up @ small["shared_out"].astype(x.dtype)).astype(F32)
+
+
 def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
                  scaling: float, compute_dtype=None, live=None,
-                 expert_start=0,
+                 expert_start=0, router: str = "sigmoid",
                  shared: bool = True, use_kernel: Optional[bool] = None,
                  interpret: Optional[bool] = None):
     """The dropless expert layer of one layer of a stack.  x: [T, H];
@@ -643,6 +737,7 @@ def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
     the experts from there on: ``TransformerConfig.share_by_position``);
     live: [T] bool, the tokens that count (a padded position, an idle slot:
     routed nowhere, their output is the shared expert's alone).
+    ``router``: "sigmoid" or "softmax" (``route``).
     ``shared=False`` leaves the shared expert to another holder of this
     layer.  The router scores x as it comes (float32 where the caller has
     it); the experts multiply it in ``compute_dtype`` (x's own where none is
@@ -652,45 +747,95 @@ def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
     layer computed, experts it touched; experts [T, k] int32: the router's
     choice for every token, live or not, among all E; load [held] int32:
     the assignments each held expert computed)."""
-    t, _ = x.shape
-    held = stacks["w_out"].shape[1]
     with jax.named_scope("moe_route"):
-        idx, gates = route_sigmoid(x, small["router"], small["bias"],
-                                   experts_per_token, scaling)
-        if jnp.ndim(expert_start):
-            expert_start = expert_start[:, None]
-        mine = (idx >= expert_start) & (idx < expert_start + held)
-        if live is not None:
-            mine = mine & live[:, None]
+        idx, gates = route(x, small, experts_per_token, scaling, router)
     x = x.astype(compute_dtype or x.dtype)
-    with jax.named_scope("moe_sort"):
-        tile = tile_rows(t * experts_per_token, held)
-        dest, source, tile_expert, tiles, sizes = sort_by_expert(
-            idx - expert_start, mine, held, tile)
-        xs = _rows_in(x, source, dest)
-    with jax.named_scope("moe_experts"):
-        gmm = functools.partial(moe_gmm, layer=layer, tile_expert=tile_expert,
-                                tiles=tiles, tile=tile, use_kernel=use_kernel,
-                                interpret=interpret)
-        if "w_gate" in stacks:
-            act = gmm(xs, (stacks["w_gate"], stacks["w_in"]))
-        else:
-            act = gmm(xs, (stacks["w_up"],), activation="relu2",
-                      transposed=True)
-        ys = gmm(act, (stacks["w_out"],))
-    with jax.named_scope("moe_combine"):
-        # an assignment that is not this layer's reads a row past the end
-        out = _combine(ys, gates, mine, source, dest)
+    out, sizes = _held_part(x, idx, gates, live, stacks, layer, expert_start,
+                            experts_per_token, use_kernel, interpret)
     if shared and "shared_in" in small:
         with jax.named_scope("moe_shared"):
-            if "shared_gate" in small:
-                up = (jax.nn.silu(x @ small["shared_gate"].astype(x.dtype))
-                      * (x @ small["shared_in"].astype(x.dtype)))
-            else:
-                # squared in float32 before it is rounded, as the kernel's
-                up = relu2(jnp.dot(x, small["shared_in"].astype(x.dtype),
-                                    preferred_element_type=F32)
-                            ).astype(x.dtype)
-            out = out + (up @ small["shared_out"].astype(x.dtype)).astype(F32)
+            out = out + _shared_expert(x, small)
     counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
     return out.astype(x.dtype), counts, idx, sizes
+
+
+#: the scopes around the exchange's two collectives, for a profiler's view
+#: (pinned by tests/test_trace_names.py): tokens out to the next holder,
+#: results back to the tokens' owner
+SCOPE_EXCHANGE_OUT = "moe_exchange_tokens_out"
+SCOPE_EXCHANGE_BACK = "moe_exchange_results_back"
+
+
+def exchange_bytes(tokens: int, hidden: int, k: int, holders: int,
+                   itemsize: int) -> int:
+    """Bytes one holder sends in ``moe_dropless_ep``'s forward for its
+    ``tokens`` tokens: a block of tokens with its choices and gates on each
+    of ``holders - 1`` hops, and a float32 block of results back from each
+    of as many steps.  (The backward sends the same again, transposed.)"""
+    block = tokens * (hidden * itemsize + k * 8)
+    return (holders - 1) * (block + tokens * hidden * 4)
+
+
+def moe_dropless_ep(x, small, stacks, layer, *, axis: str,
+                    experts_per_token: int, scaling: float,
+                    router: str = "sigmoid", compute_dtype=None,
+                    use_kernel: Optional[bool] = None,
+                    interpret: Optional[bool] = None):
+    """``moe_dropless`` where the experts lie on the ``n`` holders of the
+    mesh axis ``axis``, ``held = E / n`` each, holder ``i`` the experts ``i
+    held`` and on: the body of a ``shard_map`` in which ``axis`` is manual.
+    x: [T, H], this holder's own tokens; ``small``: the layer's router
+    (whole on every holder); ``stacks``: this holder's experts [layers,
+    held, ...] in the dtype they multiply in.
+
+    **The exchange**, explicit collectives and no capacity: the router
+    scores a token on the holder that owns it; a block (the tokens, their
+    chosen experts, their gates) then walks the ring of holders, one hop a
+    step (``ppermute``; ``SCOPE_EXCHANGE_OUT``), and at step ``s`` a holder
+    computes what ITS experts add for the block of holder ``i - s``:
+    the block's assignments that land here, sorted, through the grouped
+    products, combined under the block's gates (``_held_part``; the sorted
+    layout is sized for every assignment of ONE block landing here, ``T k``
+    rows, not of all ``n``), and sends that float32 partial result straight
+    back to the block's owner (``ppermute`` by ``-s``;
+    ``SCOPE_EXCHANGE_BACK``), which sums the ``n`` parts in float32.  A hop
+    has no consumer before the next step, so it can run under this step's
+    grouped products.  Each step's part is a ``jax.checkpoint``: the
+    backward keeps a block, not its sorted rows, and replays the step.
+    The transposes are ``ppermute``'s own: a gate's gradient comes home to
+    the router that made it.
+
+    Returns (out [T, H] in x's dtype; load [held] int32, the assignments
+    each expert held here computed over the ``n`` blocks; experts [T, k]
+    int32, the router's choice for this holder's tokens)."""
+    n = jax.lax.axis_size(axis)
+    held = stacks["w_out"].shape[1]
+    start = jax.lax.axis_index(axis) * held
+    with jax.named_scope("moe_route"):
+        idx, gates = route(x, small, experts_per_token, scaling, router)
+    x = x.astype(compute_dtype or x.dtype)
+
+    @jax.checkpoint
+    def part(block, stacks):
+        return _held_part(*block, None, stacks, layer, start,
+                          experts_per_token, use_kernel, interpret)
+
+    block, out, load = (x, idx, gates), None, jnp.zeros((held,), jnp.int32)
+    for s in range(n):
+        if s < n - 1:
+            with jax.named_scope(SCOPE_EXCHANGE_OUT):
+                onward = jax.lax.ppermute(
+                    block, axis, [(j, (j + 1) % n) for j in range(n)])
+        mine, sizes = part(block, stacks)
+        load = load + sizes
+        if s:
+            with jax.named_scope(SCOPE_EXCHANGE_BACK):
+                mine = jax.lax.ppermute(
+                    mine, axis, [(j, (j - s) % n) for j in range(n)])
+        out = mine if out is None else out + mine
+        if s < n - 1:
+            block = onward
+    if "shared_in" in small:
+        with jax.named_scope("moe_shared"):
+            out = out + _shared_expert(x, small)
+    return out.astype(x.dtype), load, idx

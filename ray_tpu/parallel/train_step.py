@@ -124,6 +124,11 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh,
     transformer.refuse_layer_pattern(cfg, "make_train_step")
     shard_rules.refuse_mesh(cfg, mesh, "make_train_step")
     if grad_quant_enabled or zero_sharded_update:
+        if shard_rules.expert_parallel(cfg):
+            raise NotImplementedError(
+                "grad_quant_enabled / zero_sharded_update build a dp-manual "
+                "schedule (parallel/zero.py) that knows no ep axis: experts "
+                "exchanged over ep train through the default step")
         from . import zero
         return zero.make_dp_train_step(
             cfg, mesh, optimizer, state_sh, compute_dtype=compute_dtype,
@@ -132,8 +137,8 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh,
             quant_stochastic=quant_stochastic,
             zero_update=zero_sharded_update, opt_spec=opt_spec)
     pctx = ParallelContext(mesh=mesh, sp_axis=sp_axis,
-                           batch_axes=shard_rules.BATCH_AXES)
-    batch_sh = NamedSharding(mesh, shard_rules.batch_spec())
+                           batch_axes=shard_rules.batch_axes(cfg))
+    batch_sh = named_sharding(mesh, shard_rules.batch_spec(cfg))
 
     loss_fn = functools.partial(transformer.causal_lm_loss, cfg=cfg, pctx=pctx,
                                 compute_dtype=compute_dtype, remat=remat)
@@ -180,7 +185,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh,
     # phase + all-gather phase) — counted as such so the number is
     # comparable with the explicit RS/AG schedule of parallel/zero.py.
     dp = 1
-    for ax in ("dp", "fsdp"):
+    for ax in shard_rules.batch_axes(cfg):
         dp *= mesh.shape.get(ax, 1)
     n_params = cfg.num_params()      # what this holder has of the model
     step.collective_bytes = (
@@ -192,8 +197,8 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh,
 def make_eval_step(cfg: TransformerConfig, mesh: Mesh, state_sh: TrainState,
                    compute_dtype=jnp.bfloat16, sp_axis: Optional[str] = None):
     pctx = ParallelContext(mesh=mesh, sp_axis=sp_axis,
-                           batch_axes=shard_rules.BATCH_AXES)
-    batch_sh = NamedSharding(mesh, shard_rules.batch_spec())
+                           batch_axes=shard_rules.batch_axes(cfg))
+    batch_sh = named_sharding(mesh, shard_rules.batch_spec(cfg))
 
     def eval_fn(params, batch):
         loss, metrics = transformer.causal_lm_loss(params, batch, cfg=cfg,

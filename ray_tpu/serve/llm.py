@@ -288,6 +288,12 @@ class LLMEngine:
             raise ValueError(
                 "share_by_position is the train step's: a served "
                 "share holds the experts from expert_start on")
+        if cfg.rope_yarn_kinds:
+            raise ValueError(
+                f"rope_yarn_kinds {cfg.rope_yarn_kinds} is the train "
+                "step's (models/transformer.py rope_table): the serving "
+                "path's rotary table by kind (models/decode.py _qkv) is "
+                "plain")
         self.max_len = max_len or cfg.max_seq_len
         self.num_slots = num_slots
         self.buckets = tuple(b for b in buckets if b <= self.max_len)

@@ -730,6 +730,28 @@ KINDS = {k.name: k for k in (
                    ("moe_gmm", "moe_gmm_dx", "moe_gmm_dw")),
                   ("_moe_train.py", "FLASH_TRAIN",
                    ("flash_fwd", "flash_dq", "flash_dkv")))),
+    # trained over four chips that share each layer (PR 58): the band's
+    # forward and its backward beside the full layer's two and the grouped
+    # products' three, and the kind of collective the exchange is told by
+    Kind(name="mellum", tiny="tiny-mellum.json",
+         cell="mellum2-12b-a2.5b-train-l4",
+         kernels=(("moe", "KERNEL_MOE_GMM", "moe_gmm"),
+                  ("moe", "KERNEL_MOE_GMM_DX", "moe_gmm_dx"),
+                  ("moe", "KERNEL_MOE_GMM_DW", "moe_gmm_dw"),
+                  ("flash_attention", "KERNEL_FLASH_FWD", "flash_fwd"),
+                  ("flash_attention", "KERNEL_FLASH_WINDOW",
+                   "flash_window_prefill"),
+                  ("flash_attention", "KERNEL_FLASH_WINDOW_BWD",
+                   "flash_window_bwd")),
+         readers=(("flash_full_train_roofline.py", "FLASH_FULL_TRAIN",
+                   ("flash_fwd", "flash_dkv")),
+                  ("flash_window_train_roofline.py", "FLASH_WINDOW_TRAIN",
+                   ("flash_window_prefill", "flash_window_bwd")),
+                  ("swa_attn_train_kernels_device_share.py", "SWA_ATTN_TRAIN",
+                   ("flash_fwd", "flash_dkv", "flash_window_prefill",
+                    "flash_window_bwd")),
+                  ("moe_ep_exchange_device_share.py", "EXCHANGE",
+                   r"^collective-permute(-start|-done)?$"))),
 )}
 
 
@@ -770,7 +792,7 @@ def cell_cfg(name, cell=None, **changes):
 
 #: the kinds whose cells run ``ops/moe.py``'s dropless experts
 EXPERT_KINDS = ("xing4_0", "exaone_moe", "solar_open2", "nemotron_h",
-                "kimi_vl")
+                "kimi_vl", "mellum")
 
 
 def expert_shapes(name):

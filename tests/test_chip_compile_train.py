@@ -177,8 +177,10 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
     # 11_878_587_904 PR 39 left: the flash kernels' operands, results and
     # saved ``attn_out`` at 192 and 128 lanes where all were 256 (PR 45);
     # 0.45e6 under PR 45's 10_939_999_232 with the backward one kernel
-    # (PR 48: dq leaves the call that writes dk and dv)
-    assert mem.temp_size_in_bytes == 10_939_547_648
+    # (PR 48: dq leaves the call that writes dk and dv); 0.13e6 over that
+    # since PR 58: the step's new counters (a holder's load, the balance
+    # term's zeros), 36 scalar instructions beside the parent's 8,669
+    assert mem.temp_size_in_bytes == 10_939_676_672
     # the dense layer's pass is unrolled, the expert layers' a scan's body:
     # a kernel's calls in the text are its calls a layer, forward plus
     # backward, once for each
@@ -215,3 +217,45 @@ def test_share_train_step_fits_one_chip_and_runs_the_counted_kernels(
     backward = [line for line in flash if "%flash_dkv" in line]
     assert len(backward) == 2 and all(
         '"size":"%d"' % asked in line for line in backward)
+
+
+# ---- a window / full pattern with its experts exchanged over ep (PR 58)
+
+@pytest.mark.timeout(240)
+def test_ep_cell_step_fits_four_chips_with_its_exchange_counted(topo, as_tpu):
+    """``mellum2-12b-a2.5b-train-l4`` as its cell runs it: 4 x 8,192 tokens
+    over the four chips, 16 of 64 experts a chip and a quarter of every
+    other leaf.  Reading 13.17e9 bytes at the program's peak, arguments
+    included (sandbox compile, PR 58).  The kernel calls are the ones the
+    block kind counts FLOPs for, the band's two among them by name; the
+    exchange is collective-permutes (three hops of a block's tokens, choices
+    and gates and three results back a layer, their transposes, the tokens'
+    walk again in the replay) and nothing is an all-to-all; the head is
+    gathered once a pass."""
+    kind = kinds.load("mellum")
+    doc, cfg = kinds.cell_doc("mellum"), kinds.cell_cfg("mellum")
+    compiled, _, sh = _compiled_train_step(topo.devices, cfg,
+                                           kind.init_params, doc["train"])
+    mem = compiled.memory_analysis()
+    per = kind.layer_matrix_params(doc)
+    whole_a_chip = 4 * 16 * per["expert"]
+    quartered = kind.num_params(doc) - 4 * whole_a_chip
+    assert mem.argument_size_in_bytes == pytest.approx(
+        12 * (whole_a_chip + quartered / 4), rel=2e-3)
+    assert mem.alias_size_in_bytes > 0.999 * mem.output_size_in_bytes
+    assert mem.peak_memory_in_bytes < 14.0e9, mem.peak_memory_in_bytes
+    assert sh.params["blocks"]["experts"]["w_gate"].spec == P(
+        None, "ep", None, None)
+    text = compiled.as_text()
+    calls = {name: len(re.findall(
+        "%" + name + r"(?:\.\d+)? = [^\n]*" + KERNEL, text)) for name in (
+            "moe_gmm", "moe_gmm_dx", "moe_gmm_dw", "flash_fwd", "flash_dkv",
+            "flash_window_prefill", "flash_window_bwd")}
+    want = {k: 4 * v for k, v in kind.moe_gmm_train_calls(doc).items()}
+    want.update(flash_fwd=1, flash_dkv=1, flash_window_prefill=3,
+                flash_window_bwd=3)
+    assert calls == want and text.count(KERNEL) == sum(want.values())
+    permutes = len(re.findall(r" collective-permute(?:-start)?\(", text))
+    assert 4 * (12 + 9 + 9) <= permutes <= 4 * 32, permutes
+    assert not re.findall(r" all-to-all(?:-start)?\(", text)
+    _assert_head_gathered_once_a_pass(text, cfg, batch=4)

@@ -468,6 +468,10 @@ def _traced():
             lambda q, k: fa.flash_attention(q, k, k, window=8,
                                             interpret=True))(
                 jnp.ones((1, 128, 4, 8)), jnp.ones((1, 128, 2, 8))),
+        "flash_window_bwd": lambda: jax.make_jaxpr(jax.grad(
+            lambda q, k: fa.flash_attention(q, k, k, window=8,
+                                            interpret=True).sum()))(
+                jnp.ones((1, 128, 4, 8)), jnp.ones((1, 128, 2, 8))),
         "moe_gmm": lambda: jax.make_jaxpr(lambda x, w: gmm(x, w, 1))(
             rows, jnp.ones((2, 4, 64, 32), f32)),
         "mla_decode_attn": lambda: jax.make_jaxpr(
