@@ -471,7 +471,10 @@ def _dropless_block(y, mp: Params, cfg: TransformerConfig,
     there is no switch).  Returns (out, aux): ``moe_load``
     [experts] int32, the assignments each expert computed (of the experts
     held here; all of them over ``ep``), ``moe_chip_load`` [holders], their
-    sum a holder, and ``moe_balance``, the softmax router's balance term, a
+    sum a holder, ``moe_rows_live`` [holders], the share of a holder's
+    sorted layout that lay in tiles holding an assignment
+    (``ops.moe.rows_live_share``; over ``ep`` a mean over the ring's steps),
+    and ``moe_balance``, the softmax router's balance term, a
     mean over the sequences (0 where the configuration weighs none)."""
     routed = ("w_gate", "w_in", "w_out")
     small = {k: v for k, v in mp.items() if k not in routed}
@@ -500,20 +503,22 @@ def _dropless_block(y, mp: Params, cfg: TransformerConfig,
         groups = tuple(a for a in over if a != "ep")
 
         def holder(y, small, stacks):
-            out, load, idx = moe_ops.moe_dropless_ep(
+            out, load, idx, rows_live = moe_ops.moe_dropless_ep(
                 y.reshape(-1, y.shape[-1]), small,
                 {k: jax.lax.optimization_barrier(v)[None].astype(y.dtype)
                  for k, v in stacks.items()}, 0, axis="ep", **kw)
             # (a holder's experts have a copy in every data-parallel group)
             load = jax.lax.psum(load, groups) if groups else load
             return out.reshape(y.shape), load, load.sum()[None], \
-                balance(y, idx), idx.reshape(y.shape[:2] + (-1,))
+                rows_live[None], balance(y, idx), \
+                idx.reshape(y.shape[:2] + (-1,))
 
-        out, load, chips, bal, idx = jax.shard_map(
+        out, load, chips, rows_live, bal, idx = jax.shard_map(
             holder, mesh=mesh, in_specs=(batch, P(), P("ep")),
-            out_specs=(batch, P("ep"), P("ep"), batch, batch),
+            out_specs=(batch, P("ep"), P("ep"), P("ep"), batch, batch),
             check_vma=False)(y, small, {k: mp[k] for k in routed})
         return out, {"moe_load": load, "moe_chip_load": chips,
+                     "moe_rows_live": rows_live,
                      "moe_balance": bal.mean(), "moe_choices": idx}
     start = cfg.expert_start
     if cfg.share_by_position:
@@ -530,6 +535,8 @@ def _dropless_block(y, mp: Params, cfg: TransformerConfig,
          for k in routed}, 0, expert_start=start, **kw)
     return out.reshape(y.shape), {
         "moe_load": load, "moe_chip_load": load.sum()[None],
+        "moe_rows_live": moe_ops.rows_live_share(
+            load, y.shape[0] * y.shape[1] * cfg.experts_per_token)[None],
         "moe_balance": balance(y, idx).mean(),
         "moe_choices": idx.reshape(y.shape[:2] + (-1,))}
 
@@ -857,9 +864,12 @@ def causal_lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
         # the experts held here computed, summed over the expert layers,
         # and the most and the fewest any one of them saw in a layer; the
         # same of a holder's experts together (over ``ep``: of a chip, the
-        # slowest of which sets the step's pace); the balance term, which
-        # joins the total and not ``loss``; and what a holder sends a step
-        # for the exchange
+        # slowest of which sets the step's pace); the share of the sorted
+        # layouts' rows that lay in tiles holding an assignment, a mean
+        # over layers, holders and the ring's steps (what the row movements
+        # walk of what the layout is sized for: ``ops.moe.walks``); the
+        # balance term, which joins the total and not ``loss``; and what a
+        # holder sends a step for the exchange
         load, chips = aux["moe_load"], aux["moe_chip_load"]
         total = total + cfg.moe_balance_weight * aux["moe_balance"]
         metrics.update(moe_assignments_held=load.sum(),
@@ -867,6 +877,7 @@ def causal_lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
                        moe_expert_load_min=load.min(),
                        moe_chip_load_max=chips.max(),
                        moe_chip_load_min=chips.min(),
+                       moe_rows_live_share=aux["moe_rows_live"].mean(),
                        moe_balance=aux["moe_balance"],
                        moe_exchange_bytes=_exchange_bytes(
                            cfg, pctx, tokens.shape, compute_dtype,

@@ -30,7 +30,26 @@ derivative is ``ragged_dot``'s own.  The gates' gradient reaches the router
 through its float32 scores; the top-k choice and the selection bias carry
 none (no rule here moves the bias).  The sort's gather and the combine's
 gather have gathers for transposes (``_rows_in``, ``_combine``), not the
-scatter-adds autodiff would write.  The layer is
+scatter-adds autodiff would write.  **What the sorted layout is sized for,
+and what is moved**: the layout has a row for every assignment of the
+tokens it is given and a tile's padding an expert, because the layer has no
+capacity and every assignment may land on the experts held here; where they
+are a share of the router's experts most of it is empty, and the rows that
+are moved are the rows that hold an assignment (PR 59): tokens are gathered
+into the tiles that hold anything and no further (``_rows_in_live``: loops
+whose trip count is data, ``tiles``, over the gathers XLA emits; the rest of
+the buffer is never written, and every reader clamps to ``tiles`` or reads
+by an assignment's row), the experts' rows are fetched back for the
+assignments that are held and for no other (``_combine_live``,
+``_by_token``: tokens in the order of how many they hold, so that a trip's
+tokens need the same few fetches, one gather by token to put the sums
+back), a row's gradient and a gate's are taken on the live rows from one
+gather of the tokens' gradient.  The sums are the plain gathers' to the bit:
+a term left out is an exact zero, and the order over a token's assignments
+is kept.  **The rule that chooses** (``walks``, from the shapes alone): the
+walk where a level load leaves it half the rows to move or fewer and the
+layout is large enough for trips; a decode step's layout, a holder of half
+the experts or of all, is the plain program.  The layer is
 told which experts it holds (``expert_start`` and the leading dimension of
 the weights it is given) and routes over all of them: assignments to experts
 it does not hold are left out of its part of the result, as they would be
@@ -41,7 +60,8 @@ the holders of a mesh axis and their **exchange**: the router scores a
 token where it lives, a block of tokens walks the ring of holders by
 ``ppermute``, each holder computes its experts' part for the block it holds
 and sends it straight back in float32; explicit collectives, no capacity,
-and a sorted layout sized for every assignment of one block.  The
+and a sorted layout sized for every assignment of one block, of which the
+rows that hold one are walked.  The
 expert weights stay where they lie in the layer stack ``[layers, experts,
 ...]``: the kernel takes the layer index by scalar prefetch as
 ``decode_attn`` does.
@@ -50,6 +70,7 @@ expert weights stay where they lie in the layer stack ``[layers, experts,
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -69,6 +90,10 @@ KERNEL_MOE_GMM = "moe_gmm"
 #: by the benchmark's ``moe_gmm_train_roofline``
 KERNEL_MOE_GMM_DX = "moe_gmm_dx"
 KERNEL_MOE_GMM_DW = "moe_gmm_dw"
+#: the call that hands a walk its buffer, not initialised and nothing
+#: written (``_blank``): three a layer's part, under ``moe_sort`` and
+#: ``moe_combine``
+KERNEL_MOE_ROWS_BLANK = "moe_rows_blank"
 F32 = jnp.float32
 
 
@@ -209,6 +234,12 @@ def tile_rows(assignments: int, experts: int) -> int:
     return next((t for t in TILE_ROWS if t >= mean), TILE_ROWS[-1])
 
 
+def layout_rows(assignments: int, experts: int, tile: int) -> int:
+    """Rows of the sorted layout: every assignment and a tile's padding an
+    expert, in whole tiles."""
+    return -(-(assignments + experts * (tile - 1)) // tile) * tile
+
+
 def sort_by_expert(idx, held, experts: int, tile: int):
     """Where each assignment goes in the expert-sorted, tile-padded layout.
 
@@ -222,7 +253,7 @@ def sort_by_expert(idx, held, experts: int, tile: int):
     tiles: how many tiles hold anything; sizes [experts]: assignments an
     expert)."""
     t, k = idx.shape
-    rows = -(-(t * k + experts * (tile - 1)) // tile) * tile
+    rows = layout_rows(t * k, experts, tile)
     flat = jnp.where(held, idx, experts).reshape(-1)
     sizes = jnp.zeros((experts + 1,), jnp.int32).at[flat].add(1)[:experts]
     padded = -(-sizes // tile) * tile
@@ -557,6 +588,14 @@ def _gmm_vjp_bwd(tile, interpret, activation, transposed, res, dy):
 _gmm_kernel_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
 
 
+def _uses_kernel(use_kernel: Optional[bool], interpret: Optional[bool]):
+    """``use_kernel=None``: the Pallas kernels on a TPU and where they are
+    interpreted, the twin elsewhere."""
+    if use_kernel is None:
+        return bool(interpret) or jax.default_backend() == "tpu"
+    return use_kernel
+
+
 def moe_gmm(x, weights, layer, tile_expert, tiles, tile: int,
             use_kernel: Optional[bool] = None,
             interpret: Optional[bool] = None,
@@ -582,8 +621,7 @@ def moe_gmm(x, weights, layer, tile_expert, tiles, tile: int,
 
     ``use_kernel=None`` takes the Pallas kernel on a TPU and the twin
     elsewhere; ``interpret=True`` runs the kernel interpreted (tests)."""
-    if use_kernel is None:
-        use_kernel = bool(interpret) or jax.default_backend() == "tpu"
+    use_kernel = _uses_kernel(use_kernel, interpret)
     if activation not in (None, "relu2") or (
             (activation or transposed) and len(weights) != 1):
         raise ValueError(f"moe_gmm: activation {activation!r} (None or "
@@ -597,12 +635,156 @@ def moe_gmm(x, weights, layer, tile_expert, tiles, tile: int,
                            activation, transposed)
 
 
+#: rows of the sorted layout one trip of a walk gathers, at most (``_walk``);
+#: the chip, the movements alone at the two train cells' shapes (PR 59): 512
+#: rows a trip move a block's 17,920 live rows in 673 us, 1,024 in 800, 4,096
+#: in 877 (the trip's gather is written once more into the layout, and a
+#: shorter one is still near when it is)
+WALK_ROWS = 512
+#: tokens one trip of a by-token walk sums (128 / 256 / 512 within 6% of one
+#: another at both train cells' shapes; at an admit's 4,096 tokens 256 is
+#: the best by a sixth)
+WALK_TOKENS = 256
+
+
+def walks(rows: int, tokens: int, k: int, held: int, experts: int,
+          tile: int) -> bool:
+    """Whether the layout's row movements walk what is live (``_rows_in_live``,
+    ``_combine_live``) or move the layout as it stands (``_rows_in``,
+    ``_combine``): from the shapes alone.  ``rows`` of the layout for
+    ``tokens`` x ``k`` assignments over the ``held`` of the router's
+    ``experts``.  Under a level load a walk moves the live tiles' rows in
+    (the held share of the assignments and half a tile of padding an
+    expert), the held assignments' rows out and one row a token to put the
+    sums back in order; the plain program moves the whole layout in and
+    ``k`` rows a token out.  The walk is taken where that is half the rows
+    or fewer (a row costs a trip's short gather half as much again as a long
+    one's: the chip, PR 59) and the layout is large enough for trips: a
+    decode step's one or two thousand rows, a holder of half the experts or
+    of all of them, is the plain program, which has no data-dependent loop
+    to pay for."""
+    assignments = tokens * k
+    held_here = assignments * held // experts
+    walk = held_here + held * (tile // 2) + held_here + tokens
+    return (rows >= 4 * WALK_ROWS and tokens >= 2 * WALK_TOKENS
+            and 2 * walk <= rows + assignments)
+
+
+def rows_live_share(sizes, assignments: int):
+    """The share of the sorted layout's rows that lie in tiles holding
+    anything, for ``assignments`` (T x k) laid out over ``sizes.shape[-1]``
+    experts of which expert e got ``sizes[..., e]``: what a walk moves over
+    what the layout is sized for.  float32."""
+    held = sizes.shape[-1]
+    tile = tile_rows(assignments, held)
+    return ((-(-sizes // tile)).sum(-1) * tile).astype(F32) / layout_rows(
+        assignments, held, tile)
+
+
+def _blank(shape, dtype, after, kernel: bool):
+    """A buffer a walk writes its live rows into.  Under the compiled
+    kernels, which clamp to ``tiles``, it is not initialised: no pass over a
+    layout sized for the worst case.  It comes from a Pallas call that
+    writes nothing (``KERNEL_MOE_ROWS_BLANK``) and takes ``after``, an array
+    the walk reads anyway, so that the buffer begins to exist where the walk
+    begins (``jax.lax.empty``'s ``AllocateBuffer`` has no operand, and the
+    four-chip step's schedule put all 48 of them hundreds of instructions
+    ahead of their loops: 2.4e9 bytes more at the peak; sandbox compile, PR
+    59).  Zeros for the twin, whose ``ragged_dot`` multiplies every row, and
+    where the kernels are interpreted."""
+    if not kernel or jax.default_backend() != "tpu":
+        return jnp.zeros(shape, dtype)
+    return pl.pallas_call(
+        lambda after_ref, o_ref: None,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        name=KERNEL_MOE_ROWS_BLANK)(after)
+
+
+def _walk(rows: int, upto, body, init):
+    """``body(at, stretch, carry)`` for every stretch of rows from ``at``
+    that holds a row under ``upto`` (traced): ``WALK_ROWS`` rows, or the
+    most of them that divide the layout's ``rows`` (whole tiles): no stretch
+    laps another, so a body may write over what it read."""
+    stretch = math.gcd(rows, WALK_ROWS)
+    return jax.lax.fori_loop(
+        0, -(-upto // stretch),
+        lambda c, carry: body(c * stretch, stretch, carry), init)
+
+
+def _take(x, at):
+    """Rows ``at`` of x, zeros for an index past its end."""
+    return jnp.take(x, at, axis=0, mode="fill", fill_value=0)
+
+
+def _back(x, order):
+    """Rows of x, which stand in the order ``order`` (a permutation), back
+    in place: a gather that needs no zeros for an index out of range, which
+    are a pass over its result."""
+    _, back = jax.lax.sort((order, jnp.arange(
+        order.shape[0], dtype=order.dtype)), num_keys=1)
+    return x.at[back].get(mode="promise_in_bounds", unique_indices=True)
+
+
+def _by_token(ys, dest, mine, gates):
+    """``out[t] = sum_j gates[t, j] ys[dest[t, j]]`` in float32 over the
+    assignments that are ``mine``, in the order j = 0 .. k-1, fetching no
+    row for an assignment that is not (``gates`` None: unit gates).  The
+    tokens are walked with those that hold most first, ``WALK_TOKENS`` a
+    trip, a trip's tokens summed over as many of their held assignments as
+    its first token has.  Returns (the sums in that order [T, H] float32,
+    the order [T] int32): ``_back`` puts them in place, one gather by token.
+    Leaving out a term that is an exact zero leaves a float32 sum as it
+    was."""
+    t, k = dest.shape
+    rows, h = ys.shape
+    count = mine.sum(-1).astype(jnp.int32)
+    # a token's held assignments first, in their order, then the tokens by
+    # how many they hold: two stable sorts that carry the rows and the gates
+    # with their keys (an index taken and applied is a gather by element,
+    # which costs the chip more than the rows it steers: PR 59's trace)
+    held = (jnp.where(mine, dest, rows),) + (
+        () if gates is None else (jnp.where(mine, gates, 0.0),))
+    _, *held = jax.lax.sort((jnp.logical_not(mine).astype(jnp.int32), *held),
+                            dimension=1, is_stable=True, num_keys=1)
+    _, *held = jax.lax.sort((jnp.broadcast_to(-count[:, None], (t, k)), *held),
+                            dimension=0, is_stable=True, num_keys=1)
+    dest, gates = held if gates is not None else (held[0], None)
+    count, order = jax.lax.sort((-count, jnp.arange(t, dtype=jnp.int32)),
+                                is_stable=True, num_keys=1)
+    count = -count
+
+    def tokens(c, out):
+        at = jnp.minimum(c * WALK_TOKENS, t - WALK_TOKENS)
+        d = jax.lax.dynamic_slice_in_dim(dest, at, WALK_TOKENS)
+        g = (None if gates is None
+             else jax.lax.dynamic_slice_in_dim(gates, at, WALK_TOKENS))
+
+        def column(i, acc):
+            term = _take(ys, jax.lax.dynamic_index_in_dim(
+                d, i, 1, keepdims=False)).astype(F32)
+            if g is not None:
+                term = jax.lax.dynamic_index_in_dim(g, i, 1) * term
+            return acc + term
+
+        acc = jax.lax.fori_loop(0, count[at], column,
+                                jnp.zeros((WALK_TOKENS, h), F32))
+        return jax.lax.dynamic_update_slice_in_dim(out, acc, at, 0)
+
+    some = (count > 0).sum()
+    out = jax.lax.fori_loop(0, -(-some // WALK_TOKENS), tokens,
+                            jnp.zeros((t, h), F32))
+    return out, order
+
+
 @jax.custom_vjp
 def _rows_in(x, source, dest):
     """The rows of x [T, H] in the sorted layout: row r is token
-    ``source[r]`` (T: padding, zeros).  Its transpose is a gather too: a
-    token's gradient is the sum of its assignments' rows, at ``dest``."""
-    return jnp.take(x, source, axis=0, mode="fill", fill_value=0)
+    ``source[r]`` (T: padding, zeros); every row of the layout is defined
+    on return.  Its transpose is a gather too: a token's gradient is the
+    sum of its assignments' rows, at ``dest``."""
+    return _take(x, source)
 
 
 def _rows_in_fwd(x, source, dest):
@@ -612,20 +794,51 @@ def _rows_in_fwd(x, source, dest):
 def _rows_in_bwd(res, g):
     source, dest = res
     # an assignment at a time: [T, k, H] at once is k copies of the tokens
-    dx = sum(jnp.take(g, dest[:, j], axis=0, mode="fill",
-                      fill_value=0).astype(F32)
-             for j in range(dest.shape[1]))
+    dx = sum(_take(g, dest[:, j]).astype(F32) for j in range(dest.shape[1]))
     return dx.astype(g.dtype), _no_grad(source), _no_grad(dest)
 
 
 _rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rows_in_live(x, source, dest, mine, live, kernel):
+    """``_rows_in`` over the first ``live`` rows of the layout alone (whole
+    tiles: ``tiles * tile``): the rows of the tiles that hold an assignment
+    are defined on return, padding rows among them zeros (``moe_gmm_dw``
+    sums over a whole tile); the rows past them are whatever ``_blank``
+    left there, and no reader looks: the grouped kernels clamp to ``tiles``
+    and the transposes read by ``dest``, which points at assignments.  Its
+    transpose sums a token's held rows (``_by_token``)."""
+    return _walk(source.shape[0], live, lambda at, n, xs: (
+        jax.lax.dynamic_update_slice_in_dim(xs, _take(
+            x, jax.lax.dynamic_slice_in_dim(source, at, n)), at, 0)),
+        _blank((source.shape[0], x.shape[1]), x.dtype, source, kernel))
+
+
+def _rows_in_live_fwd(x, source, dest, mine, live, kernel):
+    return (_rows_in_live(x, source, dest, mine, live, kernel),
+            (source, dest, mine, live))
+
+
+def _rows_in_live_bwd(kernel, res, g):
+    dx, order = _by_token(g, res[1], res[2], None)
+    return (_back(dx.astype(g.dtype), order), *(_no_grad(a) for a in res))
+
+
+_rows_in_live.defvjp(_rows_in_live_fwd, _rows_in_live_bwd)
+
+
 def _picked(ys, dest, j):
     """Assignment j's row of ys for every token [T, H], float32; zeros
     where it is not held (``dest`` past the end)."""
-    return jnp.take(ys, dest[:, j], axis=0, mode="fill",
-                    fill_value=0).astype(F32)
+    return _take(ys, dest[:, j]).astype(F32)
+
+
+def _gate_row(rows, gates, dest):
+    """The gate of the assignment that sits in each row (0: padding)."""
+    return jnp.zeros((rows,), F32).at[dest.reshape(-1)].set(
+        gates.reshape(-1), mode="drop")
 
 
 @jax.custom_vjp
@@ -635,7 +848,8 @@ def _combine(ys, gates, mine, source, dest):
     weighted.  ys: [rows, H]; gates: [T, k] float32; mine: [T, k] bool;
     source [rows], dest [T, k]: ``sort_by_expert``'s.  Its transposes are
     gathers too: a row's gradient is its token's, times the gate of the
-    assignment that sits there; a gate's is its row times its token's."""
+    assignment that sits there (every row of the layout defined); a gate's
+    is its row times its token's."""
     # an assignment at a time: [T, k, H] at once is k copies of the tokens
     gates = jnp.where(mine, gates, 0.0)
     return sum(gates[:, j, None] * _picked(ys, dest, j)
@@ -649,14 +863,10 @@ def _combine_fwd(ys, gates, mine, source, dest):
 
 def _combine_bwd(res, g):
     ys, gates, mine, source, dest = res
-    flat = dest.reshape(-1)
-    # the gate of the assignment that sits in each row (0: padding)
-    gate_row = jnp.zeros((ys.shape[0],), F32).at[flat].set(
-        gates.reshape(-1), mode="drop")
     # (the token's gradient rounded to the rows' type before it is spread
     # over them, as a dense layer's backward rounds it)
-    d_ys = (jnp.take(g.astype(ys.dtype), source, axis=0, mode="fill",
-                     fill_value=0) * gate_row[:, None]).astype(ys.dtype)
+    d_ys = (_take(g.astype(ys.dtype), source)
+            * _gate_row(ys.shape[0], gates, dest)[:, None]).astype(ys.dtype)
     d_gates = jnp.stack([(_picked(ys, dest, j) * g).sum(-1)
                          for j in range(dest.shape[1])], axis=1)
     return (d_ys, jnp.where(mine, d_gates, 0.0), _no_grad(mine),
@@ -666,12 +876,62 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _combine_live(ys, gates, mine, source, dest, live, kernel):
+    """``_combine`` that fetches the rows of held assignments alone
+    (``_by_token``); ``live``: the layout's rows in tiles that hold
+    anything.  Its transposes walk those rows: a row's gradient as
+    ``_combine``'s, defined in the live tiles (padding rows zeros); a gate's
+    gradient is taken where its row lies, the row times its token's
+    gradient summed over H in float32, and fetched by ``dest`` as a scalar:
+    one gather of the tokens' float32 gradient a stretch serves both, and
+    under the kernels a stretch of the rows' gradient is written over the
+    stretch of ``ys`` it was made from (``ys`` has no reader after this; its
+    rows past the live tiles stay what they were, which no reader looks at:
+    ``_rows_in_live``)."""
+    return _back(*_by_token(ys, dest, mine, gates))
+
+
+def _combine_live_fwd(ys, gates, mine, source, dest, live, kernel):
+    return (_combine_live(ys, gates, mine, source, dest, live, kernel),
+            (ys, jnp.where(mine, gates, 0.0), mine, source, dest, live))
+
+
+def _combine_live_bwd(kernel, res, g):
+    ys, gates, mine, source, dest, live = res
+    rows = ys.shape[0]
+    gate_row = _gate_row(rows, gates, dest)
+
+    def stretch(at, n, carry):
+        d_ys, dots = carry
+        g_rows = _take(g, jax.lax.dynamic_slice_in_dim(source, at, n))
+        # (rounded to the rows' type before it is spread, as ``_combine``'s)
+        d = (g_rows.astype(ys.dtype) * jax.lax.dynamic_slice_in_dim(
+            gate_row, at, n)[:, None]).astype(ys.dtype)
+        dot = (jax.lax.dynamic_slice_in_dim(d_ys if kernel else ys, at,
+                                            n).astype(F32) * g_rows).sum(-1)
+        return (jax.lax.dynamic_update_slice_in_dim(d_ys, d, at, 0),
+                jax.lax.dynamic_update_slice_in_dim(dots, dot, at, 0))
+
+    # (the twin's ``ragged_dot`` multiplies every row: zeros past the live)
+    d_ys, dots = _walk(rows, live, stretch, (
+        ys if kernel else jnp.zeros_like(ys), jnp.zeros((rows,), F32)))
+    d_gates = jnp.where(mine, _take(dots, dest.reshape(-1)).reshape(
+        dest.shape), 0.0)
+    return (d_ys, d_gates, *(_no_grad(a) for a in res[2:]))
+
+
+_combine_live.defvjp(_combine_live_fwd, _combine_live_bwd)
+
+
 def _held_part(x, idx, gates, live, stacks, layer, expert_start, k: int,
-               use_kernel, interpret):
+               use_kernel, interpret, experts: int):
     """What the experts held here add for the tokens x [T, H] (in the
     dtype they are multiplied in) under the router's choice idx [T, k] and
     gates [T, k]: the assignments to experts ``expert_start`` and on,
-    sorted, through the grouped products, combined.  Returns (out [T, H]
+    sorted, through the grouped products, combined.  ``experts``: the
+    router's width, which with the shapes chooses how the rows are moved
+    (``walks``).  Returns (out [T, H]
     float32, sizes [held] int32: the assignments each held expert
     computed)."""
     t, _ = x.shape
@@ -685,7 +945,10 @@ def _held_part(x, idx, gates, live, stacks, layer, expert_start, k: int,
         tile = tile_rows(t * k, held)
         dest, source, tile_expert, tiles, sizes = sort_by_expert(
             idx - expert_start, mine, held, tile)
-        xs = _rows_in(x, source, dest)
+        walk = walks(source.shape[0], t, k, held, experts, tile)
+        kernel = _uses_kernel(use_kernel, interpret)
+        xs = (_rows_in_live(x, source, dest, mine, tiles * tile, kernel)
+              if walk else _rows_in(x, source, dest))
     with jax.named_scope("moe_experts"):
         gmm = functools.partial(moe_gmm, layer=layer, tile_expert=tile_expert,
                                 tiles=tiles, tile=tile, use_kernel=use_kernel,
@@ -698,7 +961,9 @@ def _held_part(x, idx, gates, live, stacks, layer, expert_start, k: int,
         ys = gmm(act, (stacks["w_out"],))
     with jax.named_scope("moe_combine"):
         # an assignment that is not this layer's reads a row past the end
-        out = _combine(ys, gates, mine, source, dest)
+        out = (_combine_live(ys, gates, mine, source, dest, tiles * tile,
+                             kernel)
+               if walk else _combine(ys, gates, mine, source, dest))
     return out, sizes
 
 
@@ -751,7 +1016,8 @@ def moe_dropless(x, small, stacks, layer, *, experts_per_token: int,
         idx, gates = route(x, small, experts_per_token, scaling, router)
     x = x.astype(compute_dtype or x.dtype)
     out, sizes = _held_part(x, idx, gates, live, stacks, layer, expert_start,
-                            experts_per_token, use_kernel, interpret)
+                            experts_per_token, use_kernel, interpret,
+                            small["router"].shape[-1])
     if shared and "shared_in" in small:
         with jax.named_scope("moe_shared"):
             out = out + _shared_expert(x, small)
@@ -796,7 +1062,10 @@ def moe_dropless_ep(x, small, stacks, layer, *, axis: str,
     the block's assignments that land here, sorted, through the grouped
     products, combined under the block's gates (``_held_part``; the sorted
     layout is sized for every assignment of ONE block landing here, ``T k``
-    rows, not of all ``n``), and sends that float32 partial result straight
+    rows, not of all ``n``: nothing is dropped however the router leans;
+    under a level load a holder gets ``1 / n`` of them, and the rows moved
+    in and out of the layout are those that hold an assignment, chosen from
+    the shapes: ``walks``), and sends that float32 partial result straight
     back to the block's owner (``ppermute`` by ``-s``;
     ``SCOPE_EXCHANGE_BACK``), which sums the ``n`` parts in float32.  A hop
     has no consumer before the next step, so it can run under this step's
@@ -807,7 +1076,9 @@ def moe_dropless_ep(x, small, stacks, layer, *, axis: str,
 
     Returns (out [T, H] in x's dtype; load [held] int32, the assignments
     each expert held here computed over the ``n`` blocks; experts [T, k]
-    int32, the router's choice for this holder's tokens)."""
+    int32, the router's choice for this holder's tokens; the share of the
+    sorted layout's rows that lay in tiles holding an assignment, float32,
+    a mean over the ``n`` steps: ``rows_live_share``)."""
     n = jax.lax.axis_size(axis)
     held = stacks["w_out"].shape[1]
     start = jax.lax.axis_index(axis) * held
@@ -818,9 +1089,10 @@ def moe_dropless_ep(x, small, stacks, layer, *, axis: str,
     @jax.checkpoint
     def part(block, stacks):
         return _held_part(*block, None, stacks, layer, start,
-                          experts_per_token, use_kernel, interpret)
+                          experts_per_token, use_kernel, interpret, n * held)
 
     block, out, load = (x, idx, gates), None, jnp.zeros((held,), jnp.int32)
+    rows_live = 0.0
     for s in range(n):
         if s < n - 1:
             with jax.named_scope(SCOPE_EXCHANGE_OUT):
@@ -828,6 +1100,7 @@ def moe_dropless_ep(x, small, stacks, layer, *, axis: str,
                     block, axis, [(j, (j + 1) % n) for j in range(n)])
         mine, sizes = part(block, stacks)
         load = load + sizes
+        rows_live += rows_live_share(sizes, idx.size) / n
         if s:
             with jax.named_scope(SCOPE_EXCHANGE_BACK):
                 mine = jax.lax.ppermute(
@@ -838,4 +1111,4 @@ def moe_dropless_ep(x, small, stacks, layer, *, axis: str,
     if "shared_in" in small:
         with jax.named_scope("moe_shared"):
             out = out + _shared_expert(x, small)
-    return out.astype(x.dtype), load, idx
+    return out.astype(x.dtype), load, idx, rows_live

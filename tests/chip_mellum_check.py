@@ -155,7 +155,12 @@ def combine_bf16(moe):
     rounded = jax.custom_vjp(fwd)
     rounded.defvjp(lambda *a: (fwd(*a), moe._combine_fwd(*a)[1]),
                    moe._combine_bwd)
-    return rounded
+    # the same rounding where the layer walks its layout's live rows (PR
+    # 59: the cell's blocks do), the walk's transposes its own
+    walked = jax.custom_vjp(lambda *a: fwd(*a[:5]), nondiff_argnums=(6,))
+    walked.defvjp(lambda *a: (fwd(*a[:5]), moe._combine_live_fwd(*a)[1]),
+                  moe._combine_live_bwd)
+    return rounded, walked
 
 
 def gmm_bf16(moe, parts=8):
@@ -201,7 +206,9 @@ def patched(name, cfg):
         with mock.patch.object(moe, "_held_part", part_left_out(moe)):
             yield cfg
     elif name == "layer_combine_bf16":
-        with mock.patch.object(moe, "_combine", combine_bf16(moe)):
+        rounded, walked = combine_bf16(moe)
+        with mock.patch.object(moe, "_combine", rounded), \
+                mock.patch.object(moe, "_combine_live", walked):
             yield cfg
     elif name == "layer_gmm_bf16":
         with mock.patch.object(moe, "_gmm_pallas", gmm_bf16(moe)):

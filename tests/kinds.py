@@ -366,8 +366,12 @@ KINDS = {k.name: k for k in (
         # in PERF.md section 4).  decode: decode_attn, three recurrent steps
         # and two grouped matmuls a layer; prefill: flash_fwd, three chunked
         # forwards and two grouped matmuls a layer
+        # (a chunk's 1,024 tokens lay 8,192 assignments out for the 40 of
+        # 320 experts held: the rows are walked, PR 59, and each expert
+        # layer's walk in takes its buffer from ``moe_rows_blank``; a decode
+        # step's layout is the plain gathers')
         cell_programs=(("decode", 0.3, 1 + 3 + 2 * 4),
-                       ("prefill-1024", 0.6, 1 + 3 + 2 * 4)),
+                       ("prefill-1024", 0.6, 1 + 3 + 2 * 4 + 4)),
         stacks=("bf16[1,65,4096,1024]", "f32[3,65,64,128,128]"),
         # no layer's experts leave their stack ([40, 4096, 1280] is 0.42
         # GB), no layer's [slots, 64, 128, 128] slab is sliced out of the

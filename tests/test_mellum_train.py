@@ -102,6 +102,7 @@ def test_bf16_step_on_four_devices_reads_the_references_loss_and_falls(
     assert int(m["moe_chip_load_min"]) <= every // 16 <= int(
         m["moe_chip_load_max"])
     assert int(m["moe_expert_load_min"]) <= int(m["moe_expert_load_max"])
+    assert 0 < float(m["moe_rows_live_share"]) <= 1
     assert float(m["moe_aux_loss"]) == 0.0 and float(m["moe_balance"]) > 1.9
     assert float(m["total_loss"]) == pytest.approx(
         float(m["loss"]) + cfg.moe_balance_weight * float(m["moe_balance"]))
@@ -112,16 +113,28 @@ def test_bf16_step_on_four_devices_reads_the_references_loss_and_falls(
 
 # ------------------------------------- the holders' parts through the exchange
 
+@pytest.mark.parametrize("walked", [False, True], ids=["plain", "walked"])
 @pytest.mark.parametrize("kernels", [False, True], ids=["twin", "kernels"])
-def test_the_four_holders_parts_add_up_through_the_exchange(kind, tiny,
-                                                            kernels):
+def test_the_four_holders_parts_add_up_through_the_exchange(
+        kind, tiny, kernels, walked, monkeypatch):
     """``moe_dropless_ep`` over four holders of two experts each, a block of
     tokens a holder: its output is the one-device layer's (all eight experts
     on one holder) and the uncut reference's, forward and the gradients of
     the tokens, the router and the experts; every assignment was one
-    holder's."""
+    holder's.  ``walked``: with trips short enough for a block of 24 tokens,
+    so that every holder's part walks its layout's live rows (PR 59; the
+    one-device layer holds every expert and stays the plain gathers).  The
+    share of the layout that was live, a holder, against a count made in
+    numpy from the routers' choices."""
     doc, cfg, params = tiny
     mesh = _mesh(ep=4)
+    if walked:
+        monkeypatch.setattr(moe, "WALK_ROWS", 16)
+        monkeypatch.setattr(moe, "WALK_TOKENS", 8)
+    k, held, block = cfg.experts_per_token, 2, 24
+    tile = moe.tile_rows(block * k, held)
+    rows = moe.layout_rows(block * k, held, tile)
+    assert moe.walks(rows, block, k, held, 8, tile) == walked
     mp = jax.tree.map(lambda a: a[0, 0], params["blocks"]["window"]["moe"])
     stacks = jax.tree.map(lambda a: a[1], params["blocks"]["experts"])
     x = jax.random.normal(jax.random.PRNGKey(8), (4 * 24, cfg.hidden_size),
@@ -131,15 +144,15 @@ def test_the_four_holders_parts_add_up_through_the_exchange(kind, tiny,
               router="softmax", interpret=True if kernels else None)
 
     def holder(x, small, stacks):
-        out, load, idx = moe.moe_dropless_ep(
+        out, load, idx, rows_live = moe.moe_dropless_ep(
             x, small, jax.tree.map(lambda a: a[None], stacks), 0, axis="ep",
             **kw)
-        return out, load
+        return out, load, idx, rows_live[None]
 
     def exchanged(x, mp, stacks):
         return jax.shard_map(
             holder, mesh=mesh, in_specs=(P("ep"), P(), P("ep")),
-            out_specs=(P("ep"), P("ep")), check_vma=False)(x, mp, stacks)
+            out_specs=(P("ep"),) * 4, check_vma=False)(x, mp, stacks)
 
     def one_device(x, mp, stacks):
         out, _, _, load = moe.moe_dropless(
@@ -153,7 +166,15 @@ def test_the_four_holders_parts_add_up_through_the_exchange(kind, tiny,
         return lambda *a: (f(*a)[0] * weights).sum()
 
     with jax.default_matmul_precision("highest"):
-        got, load = jax.jit(exchanged)(x, mp, stacks)
+        got, load, idx, rows_live = jax.jit(exchanged)(x, mp, stacks)
+        # holder h lays out the block of holder h - s at step s: the rows in
+        # tiles that hold one of the block's assignments to its two experts
+        blocks = np.asarray(idx).reshape(4, block, k)
+        want_live = [np.mean([sum(
+            -(-(blocks[(h - s) % 4] == e).sum() // tile) * tile
+            for e in (2 * h, 2 * h + 1)) / rows for s in range(4)])
+            for h in range(4)]
+        np.testing.assert_allclose(rows_live, want_live, rtol=1e-6)
         one, load_one = jax.jit(one_device)(x, mp, stacks)
         want = jax.jit(uncut)(x, mp, stacks)
         np.testing.assert_allclose(got, one, rtol=1e-4, atol=1e-5)
@@ -161,6 +182,14 @@ def test_the_four_holders_parts_add_up_through_the_exchange(kind, tiny,
         np.testing.assert_array_equal(load, load_one)
         assert int(load.sum()) == x.shape[0] * cfg.experts_per_token
         g_got = jax.jit(jax.grad(scalar(exchanged), (0, 1, 2)))(x, mp, stacks)
+        if walked:
+            # the walk drops and rounds nothing the plain gathers keep
+            monkeypatch.setattr(moe, "walks", lambda *a: False)
+            g_plain = jax.jit(jax.grad(scalar(exchanged), (0, 1, 2)))(
+                x, mp, stacks)
+            for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_plain)):
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6 * float(
+                    jnp.abs(b).max()) + 1e-12)
         g_want = jax.jit(jax.grad(
             lambda *a: (uncut(*a) * weights).sum(), (0, 1, 2)))(x, mp, stacks)
     for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):

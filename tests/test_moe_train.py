@@ -134,6 +134,234 @@ def test_the_gates_gradient_reaches_the_router_and_not_the_bias():
     assert dx.any() and dr.any() and not db.any()
 
 
+# ------------------------------ the row movements walk what is live (PR 59)
+
+def _plain_moves():
+    """The layer's row movements as the layout-sized gathers they were to
+    PR 58 (``jnp.take`` by ``source`` over every row, by ``dest`` for every
+    assignment, mine or not), with their transposes: the oracle."""
+    take = lambda a, at: jnp.take(a, at, axis=0, mode="fill",  # noqa: E731
+                                  fill_value=0)
+
+    @jax.custom_vjp
+    def rows_in(x, source, dest):
+        return take(x, source)
+
+    def rows_in_bwd(res, g):
+        source, dest = res
+        dx = sum(take(g, dest[:, j]).astype(F32)
+                 for j in range(dest.shape[1]))
+        return dx.astype(g.dtype), moe._no_grad(source), moe._no_grad(dest)
+
+    rows_in.defvjp(lambda x, s, d: (rows_in(x, s, d), (s, d)), rows_in_bwd)
+
+    @jax.custom_vjp
+    def combine(ys, gates, mine, source, dest):
+        gates = jnp.where(mine, gates, 0.0)
+        return sum(gates[:, j, None] * take(ys, dest[:, j]).astype(F32)
+                   for j in range(dest.shape[1]))
+
+    def combine_bwd(res, g):
+        ys, gates, mine, source, dest = res
+        gate_row = jnp.zeros((ys.shape[0],), F32).at[dest.reshape(-1)].set(
+            gates.reshape(-1), mode="drop")
+        d_ys = (take(g.astype(ys.dtype), source)
+                * gate_row[:, None]).astype(ys.dtype)
+        d_gates = jnp.stack([(take(ys, dest[:, j]).astype(F32) * g).sum(-1)
+                             for j in range(dest.shape[1])], axis=1)
+        return (d_ys, jnp.where(mine, d_gates, 0.0), moe._no_grad(mine),
+                moe._no_grad(source), moe._no_grad(dest))
+
+    combine.defvjp(lambda ys, gates, mine, s, d: (
+        combine(ys, gates, mine, s, d),
+        (ys, jnp.where(mine, gates, 0.0), mine, s, d)), combine_bwd)
+    return rows_in, combine
+
+
+WT, WK, WH, WM, WE, WHELD = 96, 4, 32, 48, 16, 2
+#: how the router's choice falls on the two experts held here, by name
+SHARES = ("all", "quarter", "eighth", "none", "every_one_here")
+
+
+def _walk_case(share, by_token, masked, dtype):
+    """(x, idx, gates, live, stacks, expert_start, experts): 96 tokens x 4
+    choices among the router's 16 experts (2 where the layer holds them
+    all), 2 held from ``expert_start`` on."""
+    rng = np.random.default_rng(len(share) + 2 * by_token + masked)
+    experts = WHELD if share == "all" else WE
+    start = 0 if share == "all" else 6
+    if share in ("all", "every_one_here"):
+        # the case the layout is sized for: T x k rows of it filled
+        idx = start + rng.integers(0, WHELD, size=(WT, WK))
+    elif share == "none":
+        idx = (start + WHELD + rng.integers(0, WE - WHELD, size=(WT, WK))) % WE
+    else:
+        pool = WE if share == "eighth" else 2 * WK     # 2 of 16, 2 of 8
+        idx = np.stack([rng.permutation(pool)[:WK] for _ in range(WT)])
+        idx = (idx + start) % WE
+    if by_token:
+        # every token its own first expert, its choices moved along with it
+        shift = rng.integers(0, WE // WHELD, size=(WT,)) * WHELD
+        idx = (idx + shift[:, None]) % experts
+        start = jnp.asarray((start + shift) % experts, jnp.int32)
+    w = lambda *shape: jnp.asarray(                       # noqa: E731
+        rng.normal(size=shape) * 0.3, dtype)
+    # gates that are powers of two: a gate times a row is then exact, and
+    # the CPU compiler's choice of where to fuse a product into a sum (one
+    # rounding for two) cannot tell two programs apart that add in one order
+    gates = jnp.asarray(2.0 ** -rng.integers(0, 4, size=(WT, WK)), F32)
+    live = jnp.asarray(rng.random(WT) < 0.8) if masked else None
+    stacks = {"w_gate": w(2, WHELD, WH, WM), "w_in": w(2, WHELD, WH, WM),
+              "w_out": w(2, WHELD, WM, WH)}
+    return (w(WT, WH), jnp.asarray(idx, jnp.int32), gates, live, stacks,
+            start, experts)
+
+
+def _held_part_grads(case, kernels, monkeypatch, plain):
+    """``_held_part``'s value and its gradients in x, the gates and the three
+    stacks, weighted so that every element counts; ``plain``: through the
+    oracle's movements."""
+    x, idx, gates, live, stacks, start, experts = case
+    if plain:
+        rows_in, combine = _plain_moves()
+        monkeypatch.setattr(moe, "walks", lambda *a: False)
+        monkeypatch.setattr(moe, "_rows_in", rows_in)
+        monkeypatch.setattr(moe, "_combine", combine)
+    weights = jnp.cos(jnp.arange(WT * WH, dtype=F32)).reshape(WT, WH)
+
+    def f(x, gates, stacks):
+        out, sizes = moe._held_part(
+            x, idx, gates, live, stacks, jnp.int32(1), start, WK,
+            True if kernels else None, True if kernels else None, experts)
+        return (out * weights).sum(), (out, sizes)
+
+    return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True))(
+        x, gates, stacks)
+
+
+@pytest.fixture
+def short_trips(monkeypatch):
+    """The walk's trips at a size the tiny layout has several of."""
+    monkeypatch.setattr(moe, "WALK_ROWS", 64)
+    monkeypatch.setattr(moe, "WALK_TOKENS", 16)
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["twin", "kernels"])
+@pytest.mark.parametrize("by_token,masked", [
+    (False, False), (True, False), (False, True), (True, True)],
+    ids=["start_int", "start_by_token", "start_int-live",
+         "start_by_token-live"])
+@pytest.mark.parametrize("share", SHARES)
+def test_held_part_walking_live_rows_is_the_plain_gathers_bit_for_bit(
+        share, by_token, masked, kernels, short_trips, monkeypatch):
+    """The walk over live tiles and held assignments against the
+    layout-sized gathers: the layer's part, its sizes and every gradient
+    bit for bit (the gates' gradient, whose sum over H is taken where the
+    row lies and not at the token, to 1e-6 of its largest), at every share
+    of the block's assignments held here, the whole block among them: the
+    layout has no capacity and the walk drops nothing."""
+    case = _walk_case(share, by_token, masked, F32)
+    tile = moe.tile_rows(WT * WK, WHELD)
+    rows = moe.layout_rows(WT * WK, WHELD, tile)
+    assert moe.walks(rows, WT, WK, WHELD, case[-1], tile) == (share != "all")
+    got = _held_part_grads(case, kernels, monkeypatch, plain=False)
+    want = _held_part_grads(case, kernels, monkeypatch, plain=True)
+    (_, (out, sizes)), (dx, dgates, dws) = got
+    (_, (out_w, sizes_w)), (dx_w, dgates_w, dws_w) = want
+    held = int(sizes.sum())
+    assert {"none": held == 0, "all": held >= 0.75 * WT * WK,
+            "every_one_here": held >= 0.75 * WT * WK}.get(share, held > 0)
+    if share in ("all", "every_one_here") and not masked:
+        assert held == WT * WK
+    np.testing.assert_array_equal(sizes, sizes_w)
+    np.testing.assert_array_equal(out, out_w)
+    np.testing.assert_array_equal(dx, dx_w)
+    for name in dws:
+        np.testing.assert_array_equal(dws[name], dws_w[name])
+    np.testing.assert_allclose(dgates, dgates_w, rtol=0, atol=1e-6 * max(
+        float(jnp.abs(dgates_w).max()), 1e-30))
+    assert bool(out.any()) == (held > 0)
+
+
+def test_rows_no_assignment_sits_in_are_never_read(short_trips, monkeypatch):
+    """Under the kernels: NaN in every row of ``xs`` and of the rows'
+    gradient past the live tiles (what ``_blank`` leaves there on the chip
+    is anything), NaN in ``ys`` wherever no assignment sits (the padding
+    rows of live tiles too): the part and every gradient come out finite
+    and as they were.  And the padding rows INSIDE a live tile of ``xs`` are
+    zeros: ``moe_gmm_dw`` sums ``x^T dy`` over the whole tile."""
+    case = _walk_case("quarter", False, False, F32)
+    clean = _held_part_grads(case, True, monkeypatch, plain=False)
+    seen = {}
+
+    def sort(idx, held, experts, tile, real=moe.sort_by_expert):
+        seen["plan"] = plan = real(idx, held, experts, tile)
+        return plan
+
+    def blank(shape, dtype, after, kernel):
+        assert kernel
+        return jnp.full(shape, jnp.nan, dtype)
+
+    def gmm(x, weights, real=moe.moe_gmm, **kw):
+        out = real(x, weights, **kw)
+        if x.shape[1] == WH:
+            seen["xs"] = x
+            return out
+        # (the poison is no function of ys: its gradient passes through)
+        poison = jax.custom_vjp(lambda a: jnp.where(
+            (seen["plan"][1] == WT)[:, None], jnp.nan, a))
+        poison.defvjp(lambda a: (poison(a), None), lambda _, g: (g,))
+        return poison(out)
+
+    monkeypatch.setattr(moe, "sort_by_expert", sort)
+    monkeypatch.setattr(moe, "_blank", blank)
+    monkeypatch.setattr(moe, "moe_gmm", gmm)
+    x, idx, gates, live, stacks, start, experts = case
+    with jax.disable_jit():
+        moe._held_part(x, idx, gates, live, stacks, jnp.int32(1), start, WK,
+                       True, True, experts)
+    _, source, _, tiles, _ = seen["plan"]
+    tile = moe.tile_rows(WT * WK, WHELD)
+    upto = int(tiles) * tile
+    xs, source = np.asarray(seen["xs"]), np.asarray(source)
+    assert 0 < upto < len(source) and np.isnan(xs[upto:]).all()
+    padding = source[:upto] == WT
+    assert padding.any() and not xs[:upto][padding].any()
+    np.testing.assert_array_equal(xs[:upto][~padding],
+                                  np.asarray(x)[source[:upto][~padding]])
+    poisoned = _held_part_grads(case, True, monkeypatch, plain=False)
+    for a, b in zip(jax.tree.leaves(poisoned), jax.tree.leaves(clean)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("rows,tokens,k,held,experts,want", [
+    (69632, 8192, 8, 16, 64, True),      # the four-chip train cell's block
+    (100352, 16384, 6, 8, 64, True),     # the share train cell's
+    (34816, 4096, 8, 8, 128, True),      # K-EXAONE's admit, a sixteenth
+    (24576, 2048, 6, 64, 128, False),    # a half held: the plain gathers
+    (34816, 4096, 8, 8, 8, False),       # every expert held
+    (1792, 128, 8, 8, 128, False),       # a decode step
+], ids=["ep4", "share", "exaone_admit", "half", "whole", "decode"])
+def test_the_walk_is_chosen_from_the_shapes(rows, tokens, k, held, experts,
+                                            want):
+    tile = moe.tile_rows(tokens * k, held)
+    assert moe.walks(rows, tokens, k, held, experts, tile) is want
+
+
+def test_rows_live_share_is_the_live_tiles_over_the_layout():
+    rng = np.random.default_rng(3)
+    for held, assignments in ((16, 65536), (8, 98304), (2, 384)):
+        sizes = rng.multinomial(assignments // 4, np.ones(held) / held)
+        tile = moe.tile_rows(assignments, held)
+        rows = moe.layout_rows(assignments, held, tile)
+        want = (-(-sizes // tile)).sum() * tile / rows
+        got = moe.rows_live_share(jnp.asarray(sizes, jnp.int32), assignments)
+        assert got.dtype == F32 and float(got) == pytest.approx(want,
+                                                                rel=1e-6)
+    assert float(moe.rows_live_share(jnp.zeros((4,), jnp.int32), 64)) == 0.0
+
+
 # ------------------------------- the train step against the kind's reference
 
 def _batch(doc, seed=0):
@@ -211,6 +439,43 @@ def test_bf16_step_reads_the_references_loss_and_falls(kind, tiny):
     assert float(m["moe_aux_loss"]) == 0.0
     # the selection bias: no gradient reaches it and no rule moves it
     assert not np.asarray(state.params["blocks"]["moe"]["bias"]).any()
+
+
+def test_the_steps_rows_live_share_is_a_count_made_in_numpy(tiny):
+    """``moe_rows_live_share`` of ``causal_lm_loss``'s metrics, a mean over
+    the expert layers of the sorted layout's rows in tiles that hold an
+    assignment over the rows it is sized for, against the same count made
+    from the routers' choices (a share by position: the held experts stand
+    for another group of the router's outputs at each position)."""
+    from unittest import mock
+    doc, cfg, params = tiny
+    toks = _batch(doc, 3)
+    seen = []
+
+    def spy(aux, real=transformer._trunk_aux):
+        seen.append(np.asarray(aux["moe_choices"]))      # [layers, B, S, k]
+        return real(aux)
+
+    with mock.patch.object(transformer, "_trunk_aux", spy):
+        metrics = transformer.causal_lm_loss(
+            params, {"tokens": toks[:, :-1], "targets": toks[:, 1:]}, cfg,
+            compute_dtype=F32)[1]
+    choices, = seen
+    held, k = cfg.experts_held, cfg.experts_per_token
+    b, s = choices.shape[1:3]
+    assert cfg.share_by_position
+    start = ((cfg.expert_start // held + np.arange(s))
+             % (cfg.num_experts // held) * held)[None, :, None]
+    tile = moe.tile_rows(b * s * k, held)
+    rows = moe.layout_rows(b * s * k, held, tile)
+    shares = []
+    for layer in choices:
+        local = layer - start
+        sizes = np.array([(local == e).sum() for e in range(held)])
+        shares.append((-(-sizes // tile)).sum() * tile / rows)
+    assert 0 < np.mean(shares) < 1
+    assert float(metrics["moe_rows_live_share"]) == pytest.approx(
+        np.mean(shares), rel=1e-6)
 
 
 # ------------------------------------------------------ the shares add up

@@ -504,6 +504,52 @@ def test_the_kinds_kernels_are_named(name):
         assert getattr(_reader(reader), const) == value
 
 
+def test_the_walks_buffer_call_is_named_under_the_sorts_scope(monkeypatch):
+    """``moe_rows_blank``, the call that hands a walk over the sorted layout
+    its buffer and writes nothing (``ops.moe._blank``; compiled kernels
+    only, so the trace is of a program bound for the chip): its name, which
+    ``tests/test_chip_compile_*.py`` count calls by, once a layer's part
+    under ``moe_sort``, beside the grouped products' three under
+    ``moe_experts``."""
+    from ray_tpu.ops import moe
+    assert moe.KERNEL_MOE_ROWS_BLANK == "moe_rows_blank"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "WALK_ROWS", 64)
+    monkeypatch.setattr(moe, "WALK_TOKENS", 16)
+    tokens, k, hidden, mid, experts, held = 96, 4, 32, 48, 16, 2
+    idx = (jnp.arange(tokens * k, dtype=jnp.int32) * 7 % experts).reshape(
+        tokens, k)
+    stacks = {name: jnp.ones((1, held) + shape) for name, shape in (
+        ("w_gate", (hidden, mid)), ("w_in", (hidden, mid)),
+        ("w_out", (mid, hidden)))}
+
+    def part(x, gates, stacks):
+        return moe._held_part(x, idx, gates, None, stacks, 0, 6, k, True,
+                              False, experts)[0].sum()
+
+    calls = []
+
+    def walk(jaxpr, above):
+        for eqn in jaxpr.eqns:
+            stack = f"{above}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                calls.append((eqn.params["name"], stack))
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else [v]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, stack)
+
+    walk(jax.make_jaxpr(jax.grad(part, argnums=(0, 1, 2)))(
+        jnp.ones((tokens, hidden)), jnp.ones((tokens, k)), stacks).jaxpr, "")
+    blank = [stack for name, stack in calls if name == "moe_rows_blank"]
+    assert len(blank) == 1 and "moe_sort" in blank[0]
+    assert {name for name, _ in calls} == {
+        "moe_rows_blank", "moe_gmm", "moe_gmm_dx", "moe_gmm_dw"}
+    assert all("moe_experts" in stack for name, stack in calls
+               if name != "moe_rows_blank")
+
+
 @pytest.mark.parametrize("name", [k.name for k in kinds.KINDS.values()
                                   if k.scopes])
 def test_the_kinds_serve_programs_carry_their_scopes(name):
