@@ -88,8 +88,33 @@ class TransformerConfig:
     # no MLP beneath and the MLPs are the pattern's "mlp" layers; without
     # one an MLP lies under every mixer.
     layer_pattern: Tuple[str, ...] = ()
+    # a stack of more than one pattern: ``((pattern, periods), ...)``, each
+    # segment whole periods of its own pattern, one after another (SambaY's
+    # ``(("ssm1", "window"), 8), (("ssm1", "full"), 1), (("gmu", "cross"),
+    # 7)``).  Given in place of ``layer_pattern``, which then reads every
+    # layer's kind in order (one period, the whole stack); empty: the one
+    # segment ``(layer_pattern, num_periods)``.  The kinds that need it:
+    # "ssm1" (Mamba-1's selective scan: a decay a channel AND a state
+    # column, ``ssm1_*`` below; the last one before a "gmu" hands its scan's
+    # output, before the gate, down the stack as the memory), "gmu" (a gated
+    # memory unit, ``W_out(memory * silu(W_in x))``: no state of its own)
+    # and "cross" (attention with a query and an output projection alone,
+    # over the K/V rows of the model's one "full" layer)
+    layer_segments: Tuple[Tuple[Tuple[str, ...], int], ...] = ()
     # positions a "window" layer's query reads, its own among them
     sliding_window: int = 0
+    # the "ssm1" kind: inner channels, state columns a channel, and the rank
+    # its step ``dt`` is projected through (the convolution's width is
+    # ``linear_conv_width``)
+    ssm1_inner: int = 0
+    ssm1_state: int = 0
+    ssm1_dt_rank: int = 0
+    # differential attention (arXiv 2410.05258) in every attention layer
+    # ("full", "window", "cross"): heads in pairs, two softmax maps over
+    # values two heads wide, ``(1 - lam0) rmsnorm([A1 v1 | A1 v2] - lam [A2
+    # v1 | A2 v2])`` with ``lam0 = 0.8 - 0.6 exp(-0.3 depth)``
+    # (models/decode.py ``diff_combine``)
+    diff_attn: bool = False
     # (the "ssm" kind reads the same four: its heads, the state's width N
     # as the key's, the head's width P as the value's, its convolution)
     linear_num_heads: int = 0       # key heads = value heads of the mixer
@@ -213,13 +238,17 @@ class TransformerConfig:
     SERVED_ONLY = ("hc_mult",)
 
     #: a pattern's kinds of layer
-    KINDS = ("linear", "ssm", "full", "window", "mlp")
+    KINDS = ("linear", "ssm", "ssm1", "gmu", "full", "window", "cross",
+             "mlp")
+    #: of them, the kinds with a recurrent state a slot (one a model)
+    RECURRENT_KINDS = ("linear", "ssm", "ssm1")
     #: of them, the kinds the train step walks (``transformer.apply_trunk``):
     #: attention layers that differ in their span and their rotary table
     TRAINED_KINDS = ("full", "window")
     #: what a pattern may not switch on to be trained: each is wired by the
     #: serving path's block alone (models/decode.py ``layer_stack``)
-    PATTERN_SERVED_ONLY = ("qk_norm", "norm_on_output", "attn_output_gate",
+    PATTERN_SERVED_ONLY = ("layer_segments", "diff_attn",
+                           "qk_norm", "norm_on_output", "attn_output_gate",
                            "no_positions", "rope_window_only", "mtp_layers",
                            "mlp_act", "dense_prefix_layers",
                            "embedding_multiplier", "residual_multiplier",
@@ -229,6 +258,16 @@ class TransformerConfig:
                    "attention_multiplier", "logits_scaling")
 
     def __post_init__(self):
+        if self.layer_segments:
+            flat = tuple(k for seg, n in self.layer_segments
+                         for k in tuple(seg) * n)
+            if self.layer_pattern not in ((), flat) or not all(
+                    seg and n > 0 for seg, n in self.layer_segments):
+                raise ValueError(
+                    f"layer_segments {self.layer_segments}: whole periods "
+                    "of one pattern a segment, given in place of "
+                    "layer_pattern (which then reads the whole stack)")
+            object.__setattr__(self, "layer_pattern", flat)
         pat = self.layer_pattern
         self._check_latent_tree()
         if not pat:
@@ -238,7 +277,8 @@ class TransformerConfig:
                                 "linear_gate_rank", "ssm_groups", "mlp_act",
                                 "sliding_window", "rope_window_only",
                                 "rope_yarn_kinds", "mtp_layers",
-                                *self.MULTIPLIERS)
+                                "ssm1_inner", "ssm1_state", "ssm1_dt_rank",
+                                "diff_attn", *self.MULTIPLIERS)
                     if getattr(self, f)]
             if only:
                 raise ValueError(f"{only} are wired for a layer_pattern only "
@@ -250,11 +290,12 @@ class TransformerConfig:
         if self.num_layers % len(pat):
             raise ValueError(f"num_layers {self.num_layers} is not whole "
                              f"periods of {pat}")
-        if "linear" in pat and "ssm" in pat:
+        if len(set(pat) & set(self.RECURRENT_KINDS)) > 1:
             raise ValueError(
-                f"layer_pattern {pat}: one recurrent kind a model, 'linear' "
-                "or 'ssm' (they read the same linear_* sizes and keep the "
-                "cache tree's one state)")
+                f"layer_pattern {pat}: one recurrent kind a model, 'linear', "
+                "'ssm' or 'ssm1' (they keep the cache tree's one state, the "
+                "first two by the same linear_* sizes)")
+        self._check_segment_kinds(pat)
         if ("linear" in pat or "ssm" in pat) and not (
                 self.linear_num_heads and self.linear_key_dim
                 and self.linear_value_dim):
@@ -312,6 +353,55 @@ class TransformerConfig:
                 "through linear_gate_rank (models/hybrid.py has no full "
                 "[hidden, heads x key_dim] projection of either, and no "
                 "bottleneck for the mixer with a decay a head)")
+
+    def _check_segment_kinds(self, pat):
+        """The kinds a stack of segments brings ("ssm1", "gmu", "cross") and
+        the differential form of its attention."""
+        new = [k for k in ("ssm1", "gmu", "cross") if k in pat]
+        if new and not self.layer_segments:
+            raise ValueError(
+                f"layer_pattern {pat}: {new} are kinds of a stack given as "
+                "layer_segments")
+        sizes = (self.ssm1_inner, self.ssm1_state, self.ssm1_dt_rank)
+        if ("ssm1" in pat) != all(sizes) or (
+                "ssm1" not in pat and any(sizes)) or self.ssm1_inner % 128:
+            raise ValueError(
+                f"ssm1_inner {self.ssm1_inner}, ssm1_state "
+                f"{self.ssm1_state}, ssm1_dt_rank {self.ssm1_dt_rank}: the "
+                "sizes of an 'ssm1' layer, all three and no other kind's, "
+                "the inner channels whole tiles of 128 lanes (the state "
+                "lies [columns, channels / 128, 128]: ops/selective_scan.py)")
+        for kind, source in (("gmu", "ssm1"), ("cross", "full")):
+            if kind in pat and source not in pat[:pat.index(kind)]:
+                raise ValueError(
+                    f"layer_pattern {pat}: a {kind!r} layer reads what a "
+                    f"{source!r} layer above it leaves (the memory, the K/V "
+                    "rows)")
+        if "cross" in pat and (pat.count("full") != 1 or "gmu" not in pat
+                               or not self.no_positions or self.qk_norm
+                               or self.qk_head_norm or self.use_qkv_bias):
+            raise ValueError(
+                f"layer_pattern {pat}: 'cross' layers read the rows of the "
+                "model's ONE 'full' layer beside 'gmu' layers (a "
+                "cross-decoder), with a query projection alone: no "
+                "positions, no bias and no norm on it")
+        if self.layer_segments and (self.moe_dropless or self.mtp_layers
+                                    or self.sublayers_alone):
+            raise ValueError(
+                "layer_segments: every layer a mixer with its dense MLP "
+                "beneath (no experts, no 'mlp' layers, no "
+                "multi-token-prediction block: models/decode.py layer_stack "
+                "counts those through one pattern)")
+        if self.diff_attn and (
+                self.num_kv_heads % 2
+                or self.num_heads % (2 * self.num_kv_heads)
+                or not set(pat) & {"full", "window", "cross"}
+                or self.attn_output_gate or self.attn_logit_softcap):
+            raise ValueError(
+                f"diff_attn: heads in pairs ({self.num_heads} query heads "
+                f"over {self.num_kv_heads} K/V heads: whole pairs of both, "
+                "as many query pairs a K/V pair as query heads a K/V head) "
+                "in an attention kind, with no output gate and no softcap")
 
     def _check_latent_tree(self):
         if self.layer_pattern:
@@ -487,6 +577,28 @@ class TransformerConfig:
     def num_periods(self) -> int:
         return self.num_layers // len(self.layer_pattern)
 
+    @property
+    def segments(self) -> Tuple[Tuple[Tuple[str, ...], int], ...]:
+        """The stack as ``decode.layer_stack`` walks it: ``(pattern,
+        periods)`` a segment; one where the model has one pattern (without
+        a pattern: full layers, one a period)."""
+        return self.layer_segments or (
+            (self.layer_pattern or ("full",),
+             self.num_periods if self.layer_pattern else self.num_layers),)
+
+    @property
+    def cross_segment(self) -> int:
+        """The first segment with a layer that reads what the layers above
+        it left ("gmu", "cross"): from it on a prefill walks a prompt's last
+        token alone (the cross-decoder).  0: no such segment."""
+        return next((j for j, (seg, _) in enumerate(self.segments)
+                     if set(seg) & {"gmu", "cross"}), 0)
+
+    def depths(self, kind: str) -> Tuple[int, ...]:
+        """The depth in the stack of each layer of ``kind``, in the order
+        its stack of weights holds them."""
+        return tuple(j for j, k in enumerate(self.layer_pattern) if k == kind)
+
     def _layers_of(self, kind: str) -> int:
         return (self.num_periods * self.layer_pattern.count(kind)
                 if self.layer_pattern else 0)
@@ -498,6 +610,18 @@ class TransformerConfig:
     @property
     def ssm_layers(self) -> int:
         return self._layers_of("ssm")
+
+    @property
+    def ssm1_layers(self) -> int:
+        return self._layers_of("ssm1")
+
+    @property
+    def cross_layers(self) -> int:
+        return self._layers_of("cross")
+
+    @property
+    def recurrent_layers(self) -> int:
+        return sum(self._layers_of(k) for k in self.RECURRENT_KINDS)
 
     @property
     def full_layers(self) -> int:
@@ -558,6 +682,9 @@ class TransformerConfig:
         mixer = h * (2 * kd + vd) + vd * h + decay + gate + h * lh
         inner, mixed = self.ssm_channels
         ssm = h * (inner + mixed + lh) + inner * h
+        c1 = self.ssm1_inner
+        ssm1 = (3 * h * c1 + c1 * (self.ssm1_dt_rank + 2 * self.ssm1_state)
+                + self.ssm1_dt_rank * c1)
         mats = 2 if self.mlp_act else 3
         mlp = mats * h * self.mlp_size
         if self.moe_dropless:
@@ -568,6 +695,9 @@ class TransformerConfig:
         # hidden state] and one layer with the experts' MLP
         block = self.mtp_layers * (2 * h * h + attn + mlp)
         return (self.linear_layers * mixer + self.ssm_layers * ssm
+                + self.ssm1_layers * ssm1
+                + self._layers_of("gmu") * 2 * h * c1
+                + self.cross_layers * 2 * h * wide
                 + (self.full_layers + self.window_layers) * attn
                 + (self.mlp_layers - self.dense_prefix_layers) * mlp
                 + self.dense_prefix_layers * dense + block

@@ -23,8 +23,18 @@ says which mixers apply (``prefill``, ``window_step``):
   ``paged_decode.page_attention`` for both;
 * a recurrent ``state`` and a ``conv`` tail beside the rows, where the
   configuration has a ``layer_pattern`` (``hybrid.py``):
-  ``hybrid.linear_prefill`` / ``linear_step`` for its "linear" layers or
-  ``hybrid.ssm_prefill`` / ``ssm_step`` for its "ssm" layers;
+  ``hybrid.linear_prefill`` / ``linear_step`` for its "linear" layers,
+  ``hybrid.ssm_prefill`` / ``ssm_step`` for its "ssm" layers or
+  ``hybrid.ssm1_prefill`` / ``ssm1_step`` for its "ssm1" layers (Mamba-1's
+  selective scan, the state ``[layers, slots, N, inner / 128, 128]``);
+* under a cross-decoder (``cfg.cross_segment``: a stack of segments whose
+  last layers are "gmu" and "cross") the rows are those of ONE "full"
+  layer, which every "cross" layer reads too (``cross_attention``), and the
+  memory the last "ssm1" layer hands the "gmu" layers is an activation of
+  the pass, carried with that kind's state and no cache.  A prefill walks
+  the segments before it over the prompt and the cross-decoder over the
+  prompt's last token alone (``_prefill_row``, ``cross_row``): nothing
+  below the full layer's rows and the memory at a position is kept of it;
 * rings, ``wk`` and ``wv`` [window layers, slots, ring, KV * D], beside the
   rows, where the pattern has "window" layers: a position's row is
   ``position mod ring``, keys stored rotated, so a row needs no position
@@ -74,6 +84,7 @@ No torch, no dynamic shapes, no per-request Python in the hot loop.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -157,7 +168,7 @@ def _init_rows(cfg: TransformerConfig, num_slots: int, max_len: int, dtype,
         "v": jnp.zeros(shape, dtype),
         "length": length,
     }
-    if cfg.linear_layers or cfg.ssm_layers:
+    if cfg.recurrent_layers:
         from . import hybrid
         cache.update(hybrid.init_state(cfg, num_slots, dtype))
     return cache
@@ -165,7 +176,10 @@ def _init_rows(cfg: TransformerConfig, num_slots: int, max_len: int, dtype,
 
 def cache_bytes(cfg: TransformerConfig, num_slots: int, max_len: int,
                 dtype_bytes: int = 2) -> int:
-    return (2 * cfg.num_layers * num_slots * max_len * cfg.num_kv_heads
+    """Bytes of the K/V rows ``init_kv_cache`` allocates: a row a layer
+    that keeps rows (every layer without a pattern, the "full" layers under
+    one; a "cross" layer keeps none, it reads the "full" layer's)."""
+    return (2 * cfg.full_layers * num_slots * max_len * cfg.num_kv_heads
             * cfg.head_dim * dtype_bytes)
 
 
@@ -175,7 +189,10 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
     sequence: a recurrent state, a convolution tail), the layers of each
     kind (``ssm_layers``, and ``window_layers`` with their rings' bytes,
     where the model has them; a multi-token-prediction block's rows count
-    among the keys and values), and the experts a layer holds.
+    among the keys and values; under a cross-decoder ``ssm1_layers``,
+    ``cross_layers`` and ``cache_shared_kv_bytes``, the rows of the one
+    "full" layer, which every "cross" layer reads besides), and the experts
+    a layer holds.
     ``cache_state_hbm_bytes`` is ``cache_state_bytes`` with every array's
     minor dimension in whole tiles of 128 lanes, as the chip stores it: the
     two are equal where no lane holds nothing (a delta-rule state of 192
@@ -195,6 +212,10 @@ def cache_gauges(cfg: TransformerConfig, cache: KVCache) -> Dict[str, int]:
             "cache_latent_bytes": nbytes(*LATENT),
             "linear_layers": cfg.linear_layers,
             **({"ssm_layers": cfg.ssm_layers} if cfg.ssm_layers else {}),
+            **({"ssm1_layers": cfg.ssm1_layers,
+                "cross_layers": cfg.cross_layers,
+                "cache_shared_kv_bytes": nbytes("k", "v")}
+               if cfg.cross_segment else {}),
             **({"window_layers": cfg.window_layers,
                 "cache_ring_bytes": nbytes(*RING)}
                if cfg.window_layers else {}),
@@ -305,6 +326,51 @@ def _proj_out(attn, p, cast, x=None):
     return out
 
 
+def _wide(cfg: TransformerConfig) -> int:
+    """The K/V heads whose values a query head weighs side by side: 2 under
+    differential attention (a pair's ``[v1 | v2]``), else its own."""
+    return 2 if cfg.diff_attn else 1
+
+
+def _depth(cfg: TransformerConfig, kind: str, index):
+    """The depth in the stack of layer ``index`` (traced or not) of
+    ``kind``, float32: what ``lam0`` is a function of."""
+    return jnp.asarray(cfg.depths(kind), jnp.float32)[index]
+
+
+@jax.named_scope("diff_attn")
+def diff_combine(o, ap, cfg: TransformerConfig, depth):
+    """Differential attention's second half.  o [..., NH, 2 D]: every query
+    head's softmax map over the two value heads of its K/V pair, the heads
+    laid ``(K/V pair g, member i of the pair, query r of the K/V head)`` so
+    that head ``n`` scores K/V head ``n // reps`` as plain grouped-query
+    attention does (which heads pair is a convention: any fixed pairing is
+    the same arithmetic on drawn weights).  Returns ``(1 - lam0) rmsnorm(o[g,
+    0, r] - lam o[g, 1, r])`` [..., NH * D], with ``lam = exp(lq1 . lk1) -
+    exp(lq2 . lk2) + lam0`` and ``lam0 = 0.8 - 0.6 exp(-0.3 depth)``, in
+    float32."""
+    f32 = lambda name: ap[name].astype(jnp.float32)             # noqa: E731
+    lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * depth)
+    lam = (jnp.exp(jnp.sum(f32("lam_q1") * f32("lam_k1")))
+           - jnp.exp(jnp.sum(f32("lam_q2") * f32("lam_k2"))) + lam0)
+    lead, width = o.shape[:-2], o.shape[-1]
+    pairs = o.astype(jnp.float32).reshape(
+        lead + (cfg.num_kv_heads // 2, 2, -1, width))
+    d = pairs[..., 0, :, :] - lam * pairs[..., 1, :, :]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + cfg.norm_eps)
+    d = (1.0 - lam0) * d * ap["sub_norm"]["scale"].astype(jnp.float32)
+    return d.reshape(lead + (-1,))
+
+
+def _query_alone(y, ap, cfg: TransformerConfig):
+    """A "cross" layer's projection, its only one before the output's: y
+    [B, S, H] -> q [B, S, NH, D] (no positions, no bias, no norm: the
+    configuration's check)."""
+    with jax.named_scope("cross"):
+        q = y @ ap["wq"].astype(y.dtype)
+    return q.reshape(y.shape[:2] + (cfg.num_heads, cfg.head_dim))
+
+
 def masked_attention(q, k, v, positions, cfg: TransformerConfig):
     """Plain float32 attention of W queries a row over the row's whole span:
     no kernel reads pages yet (rows and rings have theirs,
@@ -335,7 +401,20 @@ Mixer = Callable[..., Tuple[jnp.ndarray, Any, Any]]
 # the norm of a kind's mixer branch, among its layer's weights (an "mlp"
 # layer has no mixer)
 _BRANCH_NORM = {"full": "attn_norm", "window": "attn_norm",
-                "linear": "mixer_norm", "ssm": "mixer_norm"}
+                "cross": "attn_norm", "linear": "mixer_norm",
+                "ssm": "mixer_norm", "ssm1": "mixer_norm",
+                "gmu": "mixer_norm"}
+# the kind whose entry of the carry a kind's mixer is handed, where it is not
+# its own: a "cross" layer reads the "full" layer's rows, a "gmu" layer the
+# memory, which is the last of what the "ssm1" layers carry
+_CARRY_OF = {"cross": "full", "gmu": "ssm1"}
+
+
+def _memory_unit(y, lp, i, carry):
+    """A "gmu" layer as a mixer: the gate of ``y`` on the memory, the last
+    of what the "ssm1" kind carries."""
+    from .hybrid import gmu
+    return gmu(y, lp["mixer"], carry[-1]), carry, None
 
 
 def _layer_weights(stack: Params, index, lead: int) -> Params:
@@ -365,7 +444,8 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
                 mixers: Dict[str, Mixer], carry: Dict[str, Any],
                 cfg: TransformerConfig, compute_dtype,
                 pick: Optional[jnp.ndarray] = None,
-                live: Optional[jnp.ndarray] = None, head: bool = True):
+                live: Optional[jnp.ndarray] = None, head: bool = True,
+                through: Optional[Tuple[int, int]] = None):
     """The serving forward pass: ``tokens`` [rows, W] (or, floating, what
     stands for their embeddings [rows, W, H]: a multi-token-prediction
     block's input) at absolute
@@ -376,7 +456,15 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     One ``lax.scan`` over periods of ``cfg.layer_pattern`` with the kinds
     inside a period unrolled, so the trace is one period whatever the depth;
     a model without a pattern is the pattern ``("full",)``, one layer a
-    period.  A kind says which sublayers a layer has: a mixer and, under
+    period.  A stack of more than one pattern (``cfg.layer_segments``) is one
+    such scan a segment, one after another, a kind's layers counted through
+    them; ``through`` ``(first, after)`` walks those segments alone: from a
+    later one on ``tokens`` are the hidden states [rows, W, H] the segments
+    before left, and short of the last the result is those states (at
+    ``pick`` where given), with no final norm and no head.  A mixer is
+    handed its kind's entry of ``carry`` or, where ``_CARRY_OF`` names one,
+    another kind's: what a layer above left for it.  A kind says which
+    sublayers a layer has: a mixer and, under
     it, a dense MLP or, with ``cfg.moe_dropless``, the dropless experts,
     whose weights stay in their stacks; or, with ``cfg.sublayers_alone``,
     one of the two alone (an "mlp" layer is the feed-forward, every other
@@ -410,8 +498,9 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     block, ``"hidden"`` to the last layer's output [rows, W, H], before the
     final norm."""
     cast = compute_dtype
-    pattern, blocks = cfg.layer_pattern or ("full",), params["blocks"]
-    per_period = {kind: pattern.count(kind) for kind in mixers}
+    blocks = params["blocks"]
+    segments = cfg.segments
+    lo, hi = through or (0, len(segments))
     prefix = cfg.dense_prefix_layers
     if jnp.issubdtype(tokens.dtype, jnp.floating):
         x = tokens.astype(cast)
@@ -419,7 +508,7 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
         x = params["embed"]["tokens"][tokens].astype(cast)
         if cfg.embedding_multiplier:
             x = x * cfg.embedding_multiplier
-    if cfg.learned_positions:
+    if cfg.learned_positions and not lo:
         x = x + params["embed"]["pos"][
             jnp.minimum(positions, cfg.max_seq_len - 1)].astype(cast)
     if cfg.hc_mult:
@@ -485,9 +574,6 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     # chip, four pairs of four (PERF.md, PR 31).
     routed = ("w_gate", "w_in", "w_out")
     stacks, small = None, blocks
-    # the layers of a period that have an MLP, the experts where the model
-    # has them: the "mlp" layers, or every layer
-    has_mlp = [kind == "mlp" or not cfg.sublayers_alone for kind in pattern]
     if cfg.moe_dropless and cfg.layer_pattern:
         stacks = blocks["experts"]
     elif cfg.moe_dropless:
@@ -506,16 +592,22 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
     # dense layers are walked apart and come first among the cache's rows
     ahead = prefix if cfg.layer_pattern else 0
 
-    def period(walk, step):
-        """One period of the pattern, its kinds unrolled; a layer's index
+    def period(walk, step, pattern, base):
+        """One period of ``pattern``, its kinds unrolled; a layer's index
         is the one in its kind's stack of weights (a mixer's in its stack
-        of cache rows: the layers before the scan come first there)."""
+        of cache rows: the layers before the scan come first there), past
+        the ``base`` layers of its kind in the segments before."""
         (x, carry), (p, dense) = walk, step
         carry, at = dict(carry), dict.fromkeys(pattern, 0)
+        per_period = [kind for kind in mixers if kind in pattern]
         ys = {kind: [] for kind in per_period}
         chosen = []
+        # the layers of a period that have an MLP, the experts where the
+        # model has them: the "mlp" layers, or every layer
+        has_mlp = [kind == "mlp" or not cfg.sublayers_alone
+                   for kind in pattern]
         for j, kind in enumerate(pattern):
-            index = p * pattern.count(kind) + at[kind]
+            index = base.get(kind, 0) + p * pattern.count(kind) + at[kind]
             at[kind] += 1
             lp = weights(kind, index) if dense is None else dense
             experts = None
@@ -530,11 +622,12 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
                     if ahead:       # the small weights lie by layer too
                         lp = dict(lp, moe=_layer_weights(blocks["moe"],
                                                          rank, 1))
+            held = _CARRY_OF.get(kind, kind)
             x, state, rows, said = layer(
-                x, carry.get(kind), kind, lp,
+                x, carry.get(held), kind, lp,
                 index + (0 if ahead else prefix), experts)
             if kind in per_period:
-                carry[kind] = state
+                carry[held] = state
                 ys[kind].append(rows)
             if said is not None:
                 chosen.append(said)
@@ -549,15 +642,28 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
             x, carry["full"], "full",
             _layer_weights(params["prefix"], j, 1), j)
         first.append(rows)
+    pattern = segments[0][0]
     if ahead:                       # the period that holds the dense layers
-        (x, carry), began = period((x, carry), (0, None))
-    (x, carry), ys = jax.lax.scan(
-        period, (x, carry),
-        (jnp.arange(bool(ahead), (cfg.num_layers - prefix + ahead)
-                    // len(pattern)),
-         blocks if as_xs else None))
-    # [periods, layers a period, ...] -> [layers, ...]
-    ys = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+        (x, carry), began = period((x, carry), (0, None), pattern, {})
+    parts, base = [], {}
+    for j, (pattern, periods) in enumerate(segments):
+        if lo <= j < hi:
+            # (less the dense layers walked apart, which no stack of
+            # several segments has)
+            (x, carry), ys = jax.lax.scan(
+                functools.partial(period, pattern=pattern, base=dict(base)),
+                (x, carry),
+                (jnp.arange(bool(ahead), periods - (prefix - ahead)),
+                 blocks if as_xs else None))
+            # [periods, layers a period, ...] -> [layers, ...]
+            parts.append(jax.tree.map(
+                lambda a: a.reshape((-1,) + a.shape[2:]), ys))
+        for kind in pattern:
+            base[kind] = base.get(kind, 0) + periods
+    ys = parts[0] if len(parts) == 1 else {
+        kind: jax.tree.map(lambda *a: jnp.concatenate(a),
+                           *(p[kind] for p in parts if kind in p))
+        for kind in dict.fromkeys(k for p in parts for k in p)}
     if began:
         ys = {k: jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
                               began[k], v) if k in began else v
@@ -570,10 +676,12 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
         x = x.sum(axis=2)
     if cfg.mtp_layers:
         ys["hidden"] = x
-    x = _norm(x, params["final_norm"], cfg).astype(cast)
+    if hi == len(segments):
+        x = _norm(x, params["final_norm"], cfg).astype(cast)
     if pick is not None:
         x = jnp.take_along_axis(x, pick[:, None, None], axis=1)[:, 0]
-    return (lm_head_logits(params, x, cfg) if head else x), carry, ys
+    return (lm_head_logits(params, x, cfg)
+            if head and hi == len(segments) else x), carry, ys
 
 
 # ---------------------------------------------------------------------------
@@ -581,20 +689,28 @@ def layer_stack(params: Params, tokens: jnp.ndarray, positions: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def prefill_attention(y, ap, cfg: TransformerConfig, positions,
-                      kind: str = "full"):
+                      kind: str = "full", layer=None):
     """One layer's causal attention over whole right-padded rows, a "window"
     layer's over each query's last ``cfg.sliding_window`` positions (the
     flash forward with a band).  y: [B, S,
     H] -> (attention after its output projection [B, S, H], this layer's
-    k and v [B, S, NKV, D] for the cache)."""
+    k and v [B, S, NKV, D] for the cache).  ``layer``: its index among its
+    kind's, which differential attention's ``lam0`` goes by."""
     from ..ops.attention import mha
     b, s, _ = y.shape
     q, k, v = _qkv(y, ap, cfg, positions, kind)
+    # (under differential attention a pair's two value heads side by side:
+    # the same rows, read as half as many heads twice as wide)
+    wide = v.reshape(b, s, -1, _wide(cfg) * cfg.head_dim)
     with jax.named_scope("window_attn" if kind == "window" else "attn"):
-        attn = mha(q, k, v, causal=True, logit_softcap=cfg.attn_logit_softcap,
+        attn = mha(q, k, wide, causal=True,
+                   logit_softcap=cfg.attn_logit_softcap,
                    window=cfg.sliding_window if kind == "window" else 0,
                    scale=cfg.attn_scale)
-    return _proj_out(attn.reshape(b, s, -1), ap, y.dtype, y), k, v
+    if cfg.diff_attn:
+        attn = diff_combine(attn, ap, cfg, _depth(cfg, kind, layer))
+    return _proj_out(attn.reshape(b, s, -1).astype(y.dtype), ap, y.dtype,
+                     y), k, v
 
 
 #: Positions a pass of the admit program walks of a row it walks in chunks.
@@ -649,6 +765,10 @@ def continued_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, slot,
     (``ops.flash_attention.flash_attention_rows``).  Returns (attention
     after its output projection [1, W, H], k_all, v_all)."""
     from ..ops.flash_attention import flash_attention_rows
+    if cfg.diff_attn:
+        raise NotImplementedError(
+            "diff_attn: a row that continues what its slot holds has no "
+            "kernel for values two heads wide (flash_attention_rows)")
     w = y.shape[1]
     q, k, v = _qkv(y, ap, cfg, start + jnp.arange(w)[None])
     with jax.named_scope("kv_write"):
@@ -775,7 +895,7 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
         return choices(ys), logits
 
     def rows(y, lp, i, carry):
-        out, k, v = prefill_attention(y, lp["attn"], cfg, positions)
+        out, k, v = prefill_attention(y, lp["attn"], cfg, positions, layer=i)
         return out, carry, (k.reshape(1, s, -1).astype(cache["k"].dtype),
                             v.reshape(1, s, -1).astype(cache["v"].dtype))
 
@@ -789,7 +909,7 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
 
         def band(y, lp, i, carry):
             out, k, v = prefill_attention(y, lp["attn"], cfg, positions,
-                                          "window")
+                                          "window", i)
             return out, carry, tuple(
                 jnp.take_along_axis(a.reshape(1, s, -1), held[..., None], 1)
                 .astype(cache["wk"].dtype) for a in (k, v))
@@ -800,13 +920,38 @@ def _prefill_row(params: Params, cache: KVCache, tokens: jnp.ndarray,
         kind, whole_rows, _ = hybrid.recurrent(cfg)
 
         def recurrent(y, lp, i, carry):
-            out, state, tail = whole_rows(y, lp["mixer"], cfg, length)
-            return out, carry, (state, tail.astype(cache["conv"].dtype))
+            # (an "ssm1" layer also hands on its memory: all it carries)
+            out, state, tail, *handed = whole_rows(y, lp["mixer"], cfg,
+                                                   length)
+            return (out, tuple(handed) or carry,
+                    (state, tail.astype(cache["conv"].dtype)))
 
         mixers[kind] = recurrent
-    logits, _, ys = layer_stack(params, tokens, positions, mixers,
-                                dict.fromkeys(mixers), cfg, compute_dtype,
-                                last, live)
+    split, carry = cfg.cross_segment, dict.fromkeys(mixers)
+    if split:
+        carry[kind] = (jnp.zeros((1, s, cfg.ssm1_inner), compute_dtype),)
+    logits, handed, ys = layer_stack(
+        params, tokens, positions, mixers, carry, cfg, compute_dtype, last,
+        live, through=(0, split) if split else None)
+    if split:
+        # the cross-decoder on the prompt's last token alone: what the
+        # self-decoder left of it (``logits`` are its hidden state there),
+        # the memory at that position and the full layer's keys and values
+        # of the row, which are all its layers read of the positions before
+        memory = jnp.take_along_axis(handed["ssm1"][-1],
+                                     last[:, None, None], axis=1)
+        own = tuple(a[0] for a in ys["full"])
+
+        def cross(y, lp, i, carry):
+            return (cross_row(y, lp["attn"], cfg, *carry, i, length), carry,
+                    None)
+
+        logits, _, _ = layer_stack(
+            params, logits[:, None], last[:, None],
+            {"gmu": _memory_unit, "cross": cross},
+            {"ssm1": (memory,), "full": own},
+            cfg, compute_dtype, jnp.zeros_like(last),
+            through=(split, len(cfg.segments)))
 
     # every layer's rows [layers of the kind, 1, ...] into the slot, in place
     # on the donated cache (the K/V of the padded tail included; decode's
@@ -947,7 +1092,8 @@ def decode_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
         y, ap, cfg, k_all, v_all, i, lengths, active, "full",
         lambda q, k_all, v_all, live, w: decode_attn(
             q, k_all, v_all, i, live, cfg.num_kv_heads,
-            cfg.attn_logit_softcap, tokens=w, scale=cfg.attn_scale))
+            cfg.attn_logit_softcap, tokens=w, scale=cfg.attn_scale,
+            wide=_wide(cfg)))
 
 
 def ring_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
@@ -964,7 +1110,7 @@ def ring_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
         y, ap, cfg, k_all, v_all, i, lengths, active, "window",
         lambda q, k_all, v_all, live, w: window_decode_attn(
             q, k_all, v_all, i, live, cfg.num_kv_heads, cfg.sliding_window,
-            w, scale=cfg.attn_scale))
+            w, scale=cfg.attn_scale, wide=_wide(cfg)))
 
 
 def _step_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
@@ -976,23 +1122,71 @@ def _step_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
     n_slots, w, _ = y.shape
     cast, span, ring = y.dtype, k_all.shape[2], kind == "window"
     positions = lengths[:, None] + jnp.arange(w)[None]           # [slots, W]
-    q, k, v = _qkv(y, ap, cfg, positions, kind)  # q:[S,W,NH,D] k/v:[S,W,NKV,D]
-    # one row a token, slot-major: [i, slot, length + j]
-    slot, at = jnp.repeat(jnp.arange(n_slots), w), positions.reshape(-1)
-    if ring:
-        at = at % span
-    with jax.named_scope("ring_write" if ring else "kv_write"):
-        k_all = k_all.at[i, slot, at].set(
-            k.reshape(n_slots * w, -1).astype(k_all.dtype))
-        v_all = v_all.at[i, slot, at].set(
-            v.reshape(n_slots * w, -1).astype(v_all.dtype))
+    if kind == "cross":     # the rows are another layer's: nothing to write
+        q = _query_alone(y, ap, cfg)
+    else:
+        q, k, v = _qkv(y, ap, cfg, positions, kind)
+        # q: [S, W, NH, D], k/v: [S, W, NKV, D]; one row a token,
+        # slot-major: [i, slot, length + j]
+        slot, at = jnp.repeat(jnp.arange(n_slots), w), positions.reshape(-1)
+        if ring:
+            at = at % span
+        with jax.named_scope("ring_write" if ring else "kv_write"):
+            k_all = k_all.at[i, slot, at].set(
+                k.reshape(n_slots * w, -1).astype(k_all.dtype))
+            v_all = v_all.at[i, slot, at].set(
+                v.reshape(n_slots * w, -1).astype(v_all.dtype))
     with jax.named_scope("ring_read" if ring else "kv_read"):
         # positions that count: up to and with the new tokens'
         live = lengths + w if ring else jnp.minimum(lengths + w, span)
         attn = attend(q.reshape(n_slots, w * cfg.num_heads, cfg.head_dim),
                       k_all, v_all, jnp.where(active, live, 0), w)
+    if cfg.diff_attn:
+        attn = diff_combine(attn.reshape(n_slots, w, cfg.num_heads, -1), ap,
+                            cfg, _depth(cfg, kind, i))
     attn = attn.reshape(n_slots, w, cfg.num_heads * cfg.head_dim)
     return _proj_out(attn.astype(cast), ap, cast, y), k_all, v_all
+
+
+def cross_attention(y, ap, cfg: TransformerConfig, k_all, v_all, i, lengths,
+                    active):
+    """``decode_attention`` for a "cross" layer, the ``i``-th: k_all, v_all
+    are the rows of the model's one "full" layer, which wrote the new
+    tokens' own above; the layer has a query and an output projection of its
+    own and writes nothing."""
+    from ..ops.decode_attention import decode_attn
+    return _step_attention(
+        y, ap, cfg, k_all, v_all, i, lengths, active, "cross",
+        lambda q, k_all, v_all, live, w: decode_attn(
+            q, k_all, v_all, 0, live, cfg.num_kv_heads, tokens=w,
+            scale=cfg.attn_scale, wide=_wide(cfg)))
+
+
+def cross_row(y, ap, cfg: TransformerConfig, k, v, i, length):
+    """A "cross" layer, the ``i``-th, for the LAST token of each whole row a
+    prefill walked: y [B, 1, H] at position ``length - 1``; k, v [B, S, NKV
+    * D], the "full" layer's keys and values of the row, of which the first
+    ``length`` [B] count.  Plain attention: one query a row."""
+    b, s = k.shape[:2]
+    q = _query_alone(y, ap, cfg)
+    wide = _wide(cfg) * cfg.head_dim
+    with jax.named_scope("cross"):
+        reps = cfg.num_heads // cfg.num_kv_heads
+        scores = jnp.einsum(
+            "bgrd,bmgd->bgrm",
+            q.reshape(b, cfg.num_kv_heads, reps, -1).astype(jnp.float32),
+            k.reshape(b, s, cfg.num_kv_heads, -1).astype(jnp.float32)
+        ) * cfg.attn_scale
+        seen = (jnp.arange(s)[None] < length[:, None])[:, None, None]
+        probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+        # a K/V head's queries weigh the values of its pair (or its own)
+        values = jnp.repeat(v.reshape(b, s, -1, wide).astype(jnp.float32),
+                            wide // cfg.head_dim, axis=2)
+        attn = jnp.einsum("bgrm,bmgd->bgrd", probs, values)
+    if cfg.diff_attn:
+        attn = diff_combine(attn.reshape(b, 1, cfg.num_heads, wide), ap, cfg,
+                            _depth(cfg, "cross", i))
+    return _proj_out(attn.reshape(b, 1, -1).astype(y.dtype), ap, y.dtype, y)
 
 
 def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
@@ -1042,6 +1236,13 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
 
         mixers[kind] = recurrent
         carry[kind] = (cache["state"], cache["conv"])
+    if cfg.cross_segment:
+        # an activation of the pass, no cache: the memory the last "ssm1"
+        # layer hands the "gmu" layers rides with what that kind carries
+        carry[kind] += (jnp.zeros((tokens.shape[0], w, cfg.ssm1_inner),
+                                  compute_dtype),)
+        mixers["gmu"] = _memory_unit
+        mixers["cross"] = _kv_mixer(cross_attention, cfg, lengths, active)
     logits, carry, ys = layer_stack(
         params, tokens, positions, mixers, carry, cfg, compute_dtype,
         live=jnp.broadcast_to(active[:, None], tokens.shape)
@@ -1060,7 +1261,7 @@ def window_step(params: Params, cache: KVCache, tokens: jnp.ndarray,
             jnp.where(active[:, None], positions, span)].set(
                 ys["experts"], mode="drop")
     if kind:
-        new["state"], new["conv"] = carry[kind]
+        new["state"], new["conv"] = carry[kind][:2]
     return (new, logits, ys["hidden"]) if hidden else (new, logits)
 
 

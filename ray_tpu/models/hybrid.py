@@ -1,12 +1,15 @@
 """Layers of several kinds in one model: layers with a recurrent state
 beside full-attention layers (``TransformerConfig.layer_pattern``), on the
-serving path.  This file is what is particular to the two recurrent kinds,
-"linear" (the gated delta rule) and "ssm" (Mamba-2's state-space mixer; a
-model has one of the two): their weights, their per-slot state and their
-mixers, for whole rows (``linear_prefill``, ``ssm_prefill``) and for one
-token a slot (``linear_step``, ``ssm_step``), and the pattern's parameter
-tree (``init_blocks``).  The walk over the layers, the block's wiring, the
-full-attention layers and the MLP or dropless experts (under every mixer,
+serving path.  This file is what is particular to the three recurrent
+kinds, "linear" (the gated delta rule), "ssm" (Mamba-2's state-space mixer)
+and "ssm1" (Mamba-1's selective scan; a model has one of the three): their
+weights, their per-slot state and their mixers, for whole rows
+(``linear_prefill``, ``ssm_prefill``, ``ssm1_prefill``) and for one token a
+slot (``linear_step``, ``ssm_step``, ``ssm1_step``), the gated memory unit
+that reads what an "ssm1" layer hands down the stack (``gmu``), and the
+pattern's parameter tree (``init_blocks``).  The walk over the layers, the
+block's wiring, the full-attention layers and the MLP or dropless experts
+(under every mixer,
 or as "mlp" layers of their own where ``cfg.sublayers_alone``) are
 ``decode.py``'s, which hands a kind's two (``recurrent``) to
 ``decode.layer_stack`` where the cache tree has a ``state``;
@@ -21,7 +24,10 @@ or as "mlp" layers of their own where ``cfg.sublayers_alone``) are
   that fill whole 128-lane tiles (``gated_delta.pack_state``: 2 at a
   value_dim of 192, 1, the plain [.., heads, key_dim, value_dim], at 128),
   or [ssm_layers, slots, heads, head width P, state width N] float32, the
-  state-space one (``ops/ssd.py``): constant in the context;
+  state-space one (``ops/ssd.py``), or [ssm1_layers, slots, state columns
+  N, inner / 128, 128] float32, the selective scan's
+  (``ops/selective_scan.py``: channels on the lanes and the sublanes, so
+  that 16 columns leave no lane empty): constant in the context;
 * ``conv``: [recurrent layers, slots, conv_width - 1, channels], the last
   inputs of the mixer's causal convolution;
 * ``length``: [slots].
@@ -51,6 +57,22 @@ P, ``ssm_groups`` G groups of ``linear_key_dim`` N, head ``h`` reads group
     S_t = a_t S_{t-1} + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
     out = W_out [ rmsnorm_group(y * silu(z)) ]    groups of H P / G channels
 
+An ssm1 layer's mixer (``ssm1_inner`` C channels, ``ssm1_state`` N columns,
+``ssm1_dt_rank`` R; no heads, no groups, no norm inside)::
+
+    [u | z] = W_in x;  u = silu(causal_conv(u) + b_conv)
+    [dt~ | B | C] = W_x u;  dt = softplus(W_dt dt~ + dt_bias)  in R^C, float32
+    S_t = exp(dt_t A) * S_{t-1} + (dt_t u_t) B_t^T     A = -exp(A_log) [N, C]
+    y_t = S_t C_t + D u_t;  out = W_out (y * silu(z))
+
+and ``y``, before the gate, is the memory the layer hands down the stack: a
+"gmu" layer below is ``W_out(memory * silu(W_in x))`` at the same position,
+with no state and no convolution of its own (a cross-decoder's,
+``decode.py``: its "cross" layers read the one "full" layer's K/V rows).
+Where the configuration has ``diff_attn`` an attention layer carries four
+vectors for ``lam`` and the scale of the norm over a pair's two value heads
+(``decode.diff_combine``).
+
 Blocks are wired ``h = x + norm(mixer(x)); out = h + norm(mlp(h))``
 (``norm_on_output``) or pre-norm, or each layer is one of the two
 (``sublayers_alone``), and nothing adds positions
@@ -72,7 +94,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import gated_delta, ssd
+from ..ops import gated_delta, selective_scan, ssd
 from .config import TransformerConfig
 from .transformer import Params, _norm
 
@@ -131,6 +153,9 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
                 * (gain * fan_in ** -0.5)).astype(dtype)
 
     def ones(lead, n):
+        if not cfg.use_rmsnorm:     # a LayerNorm: a scale and a bias
+            return {"scale": jnp.ones(lead + (n,), dtype),
+                    "bias": jnp.zeros(lead + (n,), dtype)}
         return {"scale": jnp.ones(lead + (n,), dtype)}
 
     gated = not cfg.mlp_act
@@ -216,6 +241,59 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
                 "o_norm": ones(lead, inner),
                 "w_out": dense(lead, (inner, h), inner, keys=more)},
             "mixer_norm": ones(lead, h), **mlp(lead)}
+    # the kinds of a stack of segments draw from keys of their own
+    late = iter(jax.random.split(jax.random.fold_in(key, 3), 24))
+    if "ssm1" in pattern:
+        lead = (periods, pattern.count("ssm1"))
+        inner, n, rank = cfg.ssm1_inner, cfg.ssm1_state, cfg.ssm1_dt_rank
+        width = cfg.linear_conv_width
+        dt = jnp.exp(jax.random.uniform(
+            next(late), lead + (inner,), jnp.float32,
+            *(jnp.log(v) for v in SSM_DT_RANGE)))
+        blocks["ssm1"] = {
+            "mixer": {
+                "w_in": dense(lead, (h, 2 * inner), h, keys=late),  # [u | z]
+                "conv_w": dense(lead, (width, inner), width, keys=late),
+                "conv_b": jnp.zeros(lead + (inner,), dtype),
+                # [dt~ | B | C]
+                "w_x": dense(lead, (inner, rank + 2 * n), inner, keys=late),
+                "w_dt": dense(lead, (rank, inner), rank, keys=late),
+                # softplus^-1(dt), so that the step is dt at w_dt dt~ = 0
+                "dt_bias": jnp.log(jnp.expm1(dt)).astype(dtype),
+                # the published draw: A = 1 .. N down a channel's columns;
+                # stored [N, inner], as the state lies
+                "A_log": jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None],
+                    lead + (n, inner)).astype(dtype),
+                "D": jnp.ones(lead + (inner,), dtype),
+                "w_out": dense(lead, (inner, h), inner, keys=late)},
+            "mixer_norm": ones(lead, h), **mlp(lead)}
+    if "gmu" in pattern:
+        lead = (periods, pattern.count("gmu"))
+        inner = cfg.ssm1_inner
+        blocks["gmu"] = {
+            "mixer": {"w_in": dense(lead, (h, inner), h, keys=late),
+                      "w_out": dense(lead, (inner, h), inner, keys=late)},
+            "mixer_norm": ones(lead, h), **mlp(lead)}
+
+    def differential(lead):
+        """What differential attention adds to an attention layer: four
+        vectors of a head's width for ``lam`` (N(0, 0.1), the published
+        draw) and the scale of the norm over a pair's two value heads."""
+        if not cfg.diff_attn:
+            return {}
+        return {**{n: (jax.random.normal(next(late), lead + (hd,), dtype)
+                       * 0.1).astype(dtype)
+                   for n in ("lam_q1", "lam_k1", "lam_q2", "lam_k2")},
+                "sub_norm": {"scale": jnp.ones(lead + (2 * hd,), dtype)}}
+
+    if "cross" in pattern:      # a query and an output projection alone
+        lead = (periods, pattern.count("cross"))
+        blocks["cross"] = {
+            "attn": {"wq": dense(lead, (h, nh * hd), h, keys=late),
+                     "wo": dense(lead, (nh * hd, h), nh * hd, keys=late),
+                     **differential(lead)},
+            "attn_norm": ones(lead, h), **mlp(lead)}
     for kind in ("full", "window"):     # one attention, two spans
         if kind not in pattern:
             continue
@@ -229,6 +307,7 @@ def init_blocks(key: jax.Array, cfg: TransformerConfig, dtype) -> Params:
             "wk": dense(lead, (h, nkv * hd), h, keys=draw),
             "wv": dense(lead, (h, nkv * hd), h, keys=draw),
             "wo": dense(lead, (nh * hd, h), nh * hd, keys=draw),
+            **differential(lead),
         }
         if cfg.attn_output_gate:
             attn["w_gate"] = dense(lead, (h, nh * hd), h, keys=more)
@@ -268,7 +347,12 @@ def init_state(cfg: TransformerConfig, num_slots: int,
     """What a slot keeps for the recurrent layers, beside the rows of K/V
     that ``decode.init_kv_cache`` allocates for the full-attention layers."""
     lh, width = cfg.linear_num_heads, cfg.linear_conv_width
-    if cfg.ssm_layers:
+    if cfg.ssm1_layers:
+        # channels on the lanes and the sublanes, a state column a leading
+        # index: whole tiles whatever the 16 columns (ops/selective_scan.py)
+        layers, channels = cfg.ssm1_layers, cfg.ssm1_inner
+        state = selective_scan.state_shape(cfg.ssm1_inner, cfg.ssm1_state)
+    elif cfg.ssm_layers:
         layers, channels = cfg.ssm_layers, cfg.ssm_channels[1]
         state = (lh, cfg.linear_value_dim, cfg.linear_key_dim)
     else:
@@ -534,9 +618,96 @@ def ssm_step(x, mp, cfg: TransformerConfig, li, state, conv, active):
     return _ssm_out(o, z, mp, cfg)[:, None], state, conv
 
 
+# ---------------------------------------------------------------------------
+# Mamba-1's mixer ("ssm1") and the gated memory unit that reads its memory
+# ---------------------------------------------------------------------------
+
+def _ssm1_in(x, mp):
+    """x [..., H] -> (u, z) [..., inner] of ``W_in x``."""
+    with jax.named_scope("selective_scan"):
+        proj = x @ mp["w_in"].astype(x.dtype)
+    return jnp.split(proj, 2, axis=-1)
+
+
+def _ssm1_controls(u, mp, cfg: TransformerConfig, live=None):
+    """The convolved u [..., inner] -> (dt [..., inner] float32 after its
+    softplus, 0 where ``live`` is given and false: the identity on the
+    state; the rates A [N, inner] float32; B, C [..., N])."""
+    rank, n = cfg.ssm1_dt_rank, cfg.ssm1_state
+    with jax.named_scope("selective_scan"):
+        dbc = u @ mp["w_x"].astype(u.dtype)
+        dt = jax.nn.softplus(
+            (dbc[..., :rank] @ mp["w_dt"].astype(u.dtype)).astype(jnp.float32)
+            + mp["dt_bias"].astype(jnp.float32))
+        if live is not None:
+            dt = jnp.where(live, dt, 0.0)
+        return (dt, -jnp.exp(mp["A_log"].astype(jnp.float32)),
+                dbc[..., rank:rank + n], dbc[..., rank + n:])
+
+
+def _ssm1_out(y, u, z, mp):
+    """The scan's y [..., inner] float32 -> (the mixer's output [..., H],
+    the memory: ``y + D u`` before the gate, what a "gmu" layer reads)."""
+    cast = z.dtype
+    with jax.named_scope("selective_scan"):
+        memory = (y + mp["D"].astype(jnp.float32)
+                  * u.astype(jnp.float32)).astype(cast)
+        return (memory * jax.nn.silu(z)) @ mp["w_out"].astype(cast), memory
+
+
+def ssm1_prefill(x, mp, cfg: TransformerConfig, lengths):
+    """One ssm1 layer's mixer over whole right-padded rows.  x: [B, S, H]
+    (any S) -> (mixer output [B, S, H], the state [B, N, inner / 128, 128]
+    and the convolution tail [B, width - 1, inner] each row leaves at its
+    length, the memory [B, S, inner])."""
+    u, z = _ssm1_in(x, mp)
+    with jax.named_scope("selective_scan_conv"):
+        u, tail = _conv_rows(u, mp, cfg.linear_conv_width, lengths)
+    dt, a, b, c = _ssm1_controls(u, mp, cfg)
+    with jax.named_scope("selective_scan"):
+        # positions at or beyond a row's length leave its state alone
+        y, state = selective_scan.selective_scan_chunk_fwd(u, dt, a, b, c,
+                                                           lengths)
+    out, memory = _ssm1_out(y, u, z, mp)
+    return out, state, tail, memory
+
+
+def ssm1_step(x, mp, cfg: TransformerConfig, li, state, conv, memory,
+              active):
+    """One ssm1 layer's mixer for one new token a slot; arguments and
+    results as ``linear_step``'s, with the memory [slots, 1, inner] this
+    layer hands on in place of the one it was handed."""
+    del memory
+    y, live = x[:, 0], active[:, None]                 # [slots, H], [slots, 1]
+    u, z = _ssm1_in(y, mp)
+
+    def mix(tail):
+        with jax.named_scope("selective_scan_conv"):
+            return _conv_step(u, mp, cfg.linear_conv_width, tail)
+
+    u, conv = _state_io(li, conv, live, mix)
+    dt, a, b, c = _ssm1_controls(u, mp, cfg, live)
+    with jax.named_scope("selective_scan"):
+        state, o = selective_scan.selective_scan_step(state, li, u, dt, a, b,
+                                                      c)
+    out, memory = _ssm1_out(o, u, z, mp)
+    return out[:, None], state, conv, memory[:, None]
+
+
+@jax.named_scope("gmu")
+def gmu(x, mp, memory):
+    """A gated memory unit: ``W_out(memory * silu(W_in x))``, x [..., H] and
+    the memory [..., inner] of the same positions."""
+    cast = x.dtype
+    gate = jax.nn.silu(x @ mp["w_in"].astype(cast))
+    return (memory.astype(cast) * gate) @ mp["w_out"].astype(cast)
+
+
 def recurrent(cfg: TransformerConfig):
     """(kind, its mixer over whole rows, its mixer for one token a slot) of
     the pattern's recurrent kind."""
+    if cfg.ssm1_layers:
+        return "ssm1", ssm1_prefill, ssm1_step
     if cfg.ssm_layers:
         return "ssm", ssm_prefill, ssm_step
     return "linear", linear_prefill, linear_step

@@ -115,6 +115,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig,
             "blocks": hybrid.init_blocks(next(keys), cfg, dtype),
             "final_norm": {"scale": jnp.ones((h,), dtype)},
         }
+        if not cfg.use_rmsnorm:     # a LayerNorm: a scale and a bias
+            hybrid_params["final_norm"]["bias"] = jnp.zeros((h,), dtype)
         if not cfg.tied_embeddings:
             hybrid_params["lm_head"] = dense(next(keys),
                                              (h, cfg.vocab_size), h)
@@ -574,10 +576,11 @@ def refuse_layer_pattern(cfg: TransformerConfig, what: str):
             f"{what}: layer_pattern {cfg.layer_pattern} with "
             f"{', '.join(cfg.pattern_untrained)} is served (models/hybrid.py,"
             " models/decode.py: prefill, decode_step), not trained: the "
-            "gated-delta-rule and state-space kernels have no backward, and "
-            "the train step's block wires a pre-norm attention layer "
-            "('full' or 'window') with its MLP or experts beneath and no "
-            "more")
+            "gated-delta-rule, state-space and selective-scan kernels have "
+            "no backward, and the train step's block wires a pre-norm "
+            "attention layer ('full' or 'window') with its MLP or experts "
+            "beneath and no more (no 'gmu', no 'cross', no differential "
+            "attention, no stack of segments)")
     if cfg.served_only:
         raise NotImplementedError(
             f"{what}: {', '.join(cfg.served_only)} is served "

@@ -34,6 +34,14 @@ tokens' queries ride the same blocks as ``W * NH`` query rows, token-major,
 each masked at its own position (query ``j`` of ``W`` reads ``live - (W - 1 -
 j)`` positions); at ``W = 1`` the kernel is what it was.
 
+**Values wider than keys** (``wide`` 2: differential attention's two score
+maps over the values of a PAIR of K/V heads): query head ``h`` scores key
+head ``h // reps`` as ever and weighs the ``wide * D`` lanes of the value
+heads ``wide * (h // (wide * reps)) ..``.  The accumulator keeps whole rows
+either way, so all that changes is which channels a head picks at the end
+(``_own_channels``), and the output is ``wide * D`` lanes a head: a
+position's K and V are fetched once whatever the maps.
+
 ``window_decode_attn`` is the sibling over a **ring**: ``[layers, slots,
 ring, NKV * D]``, a position's row ``position mod ring``, for layers that
 read their last ``window`` positions alone.  A slot is one block (the whole
@@ -111,16 +119,20 @@ def _softcap(s, softcap: float):
 # ---------------------------------------------------------------------------
 
 def _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads: int,
-                 softcap: float, tokens: int, scale: Optional[float] = None):
+                 softcap: float, tokens: int, scale: Optional[float] = None,
+                 wide: int = 1):
     """Plain attention of ``tokens`` queries a slot over one layer's rows,
     heads apart.  q: [slots, tokens * NH, D], token-major; seen: [slots,
     tokens, rows], the rows each query reads; ``scale`` multiplies the
-    scores (None: ``D ** -0.5``, here and in every function below)."""
+    scores (None: ``D ** -0.5``, here and in every function below);
+    ``wide``: the value heads a key head's queries weigh, side by side."""
     slots, rows, hd = q.shape
     nh, span = rows // tokens, k_all.shape[2]
     k, v = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
-            .reshape(slots, span, num_kv_heads, hd)
             for a in (k_all, v_all))
+    k = k.reshape(slots, span, num_kv_heads, hd)
+    v = jnp.repeat(v.reshape(slots, span, num_kv_heads // wide, wide * hd),
+                   wide, axis=2)
     qh = q.reshape(slots, tokens, num_kv_heads, nh // num_kv_heads, hd)
     s = jnp.einsum("swgrd,smgd->swgrm", qh.astype(k.dtype), k,
                    preferred_element_type=F32) * score_scale(scale, hd)
@@ -130,18 +142,18 @@ def _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads: int,
     o = jnp.einsum("swgrm,smgd->swgrd", p.astype(v.dtype), v,
                    preferred_element_type=F32)
     o = o / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
-    return o.reshape(slots, rows, hd).astype(q.dtype)
+    return o.reshape(slots, rows, wide * hd).astype(q.dtype)
 
 
 def decode_attn_jnp(q, k_all, v_all, layer, live, num_kv_heads: int,
                     softcap: float = 0.0, tokens: int = 1,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None, wide: int = 1):
     """The twin of ``decode_attn``; shapes as there."""
     # query j of a slot reads the positions before live - (tokens - 1 - j)
     edge = live[:, None] - (tokens - 1 - jnp.arange(tokens))[None]
     seen = jnp.arange(k_all.shape[2])[None, None] < edge[..., None]
     return _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads, softcap,
-                        tokens, scale)
+                        tokens, scale, wide)
 
 
 def ring_positions(newest, ring: int):
@@ -155,14 +167,14 @@ def ring_positions(newest, ring: int):
 
 def window_decode_attn_jnp(q, k_all, v_all, layer, live, num_kv_heads: int,
                            window: int, tokens: int = 1,
-                           scale: Optional[float] = None):
+                           scale: Optional[float] = None, wide: int = 1):
     """The twin of ``window_decode_attn``; shapes as there."""
     held = ring_positions(live - 1, k_all.shape[2])[:, None]  # [slots,1,ring]
     at = (live[:, None] - (tokens - jnp.arange(tokens))[None])[..., None]
     seen = (held >= 0) & (held <= at) & (at - held < window) \
         & (live > 0)[:, None, None]
     return _attend_rows(q, k_all, v_all, layer, seen, num_kv_heads, 0.0,
-                        tokens, scale)
+                        tokens, scale, wide)
 
 
 # ---------------------------------------------------------------------------
@@ -316,7 +328,7 @@ def _query_rows(q, nh: int, num_kv_heads: int, chan: int, dtype):
 
 def _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads: int,
                         softcap: float, interpret: bool, tokens: int = 1,
-                        scale: Optional[float] = None):
+                        scale: Optional[float] = None, wide: int = 1):
     slots, q_rows, hd = q.shape
     nh = q_rows // tokens
     max_len, chan = k_all.shape[2:]
@@ -335,8 +347,9 @@ def _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads: int,
         return (slot_of[ti], 0, 0)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, block=block, heads_a_group=reps,
-                          num_kv_heads=num_kv_heads,
+        # (a head's own channels at the end: ``wide`` K/V heads' worth)
+        functools.partial(_kernel, block=block, heads_a_group=wide * reps,
+                          num_kv_heads=num_kv_heads // wide,
                           scale=score_scale(scale, hd), softcap=softcap,
                           heads=nh, tokens=tokens),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -349,12 +362,12 @@ def _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads: int,
                 pl.BlockSpec((1, 1, block, chan), rows),
                 pl.BlockSpec((1, 1, block, chan), rows),
             ],
-            out_specs=pl.BlockSpec((1, nhp, hd), per_slot),
+            out_specs=pl.BlockSpec((1, nhp, wide * hd), per_slot),
             scratch_shapes=[pltpu.VMEM((nhp, 1), F32),
                             pltpu.VMEM((nhp, 1), F32),
                             pltpu.VMEM((nhp, chan), F32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((slots, nhp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, nhp, wide * hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
@@ -370,7 +383,7 @@ def _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads: int,
 def _window_decode_attn_pallas(q, k_all, v_all, layer, live,
                                num_kv_heads: int, window: int, tokens: int,
                                interpret: bool,
-                               scale: Optional[float] = None):
+                               scale: Optional[float] = None, wide: int = 1):
     slots, rows, hd = q.shape
     nh = rows // tokens
     ring, chan = k_all.shape[2:]
@@ -388,8 +401,8 @@ def _window_decode_attn_pallas(q, k_all, v_all, layer, live,
 
     out = pl.pallas_call(
         functools.partial(_ring_kernel, ring=ring, window=window,
-                          heads_a_group=nh // num_kv_heads,
-                          num_kv_heads=num_kv_heads,
+                          heads_a_group=wide * nh // num_kv_heads,
+                          num_kv_heads=num_kv_heads // wide,
                           scale=score_scale(scale, hd), heads=nh,
                           tokens=tokens),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -398,9 +411,9 @@ def _window_decode_attn_pallas(q, k_all, v_all, layer, live,
             in_specs=[pl.BlockSpec((1, nhp, chan), per_slot),
                       pl.BlockSpec((1, 1, ring, chan), held),
                       pl.BlockSpec((1, 1, ring, chan), held)],
-            out_specs=pl.BlockSpec((1, nhp, hd), per_slot),
+            out_specs=pl.BlockSpec((1, nhp, wide * hd), per_slot),
         ),
-        out_shape=jax.ShapeDtypeStruct((slots, nhp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, nhp, wide * hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
@@ -423,7 +436,7 @@ def window_decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
                        window: int, tokens: int = 1,
                        use_kernel: Optional[bool] = None,
                        interpret: Optional[bool] = None,
-                       scale: Optional[float] = None):
+                       scale: Optional[float] = None, wide: int = 1):
     """Attention of ``tokens`` new tokens a slot over layer ``layer`` of a
     stack of rings, each query over the ``window`` positions up to its own.
 
@@ -432,19 +445,21 @@ def window_decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
     rows already written, ``ring >= window + tokens - 1`` (else a later
     token's row has replaced one an earlier token reads); live: [slots]
     int32, each slot's length with the new tokens (0: inactive, zeros).
-    Returns [slots, tokens * NH, D] in q's dtype."""
+    Returns [slots, tokens * NH, wide * D] in q's dtype (``wide``: the value
+    heads a head weighs, the module's docstring)."""
     if not _takes_kernel(use_kernel, interpret):
         return window_decode_attn_jnp(q, k_all, v_all, layer, live,
-                                      num_kv_heads, window, tokens, scale)
+                                      num_kv_heads, window, tokens, scale,
+                                      wide)
     return _window_decode_attn_pallas(
         q, k_all, v_all, layer, live, num_kv_heads, window, tokens,
-        resolve_interpret(interpret, "window_decode_attn"), scale)
+        resolve_interpret(interpret, "window_decode_attn"), scale, wide)
 
 
 def decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
                 softcap: float = 0.0, use_kernel: Optional[bool] = None,
                 interpret: Optional[bool] = None, tokens: int = 1,
-                scale: Optional[float] = None):
+                scale: Optional[float] = None, wide: int = 1):
     """Attention of one new token a slot (or ``tokens``) over layer
     ``layer`` of the stacked cache.
 
@@ -453,9 +468,10 @@ def decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
     tokens' own rows already written; layer: int32 scalar (traced or not);
     live: [slots] int32, the positions of each slot that count, the new
     tokens' among them (0: the slot is inactive, its output is zeros).
-    ``scale`` multiplies the scores (None: ``D ** -0.5``).
-    Returns an array like q.  Only the live blocks of ``layer``
-    are read: no slab leaves the stack.
+    ``scale`` multiplies the scores (None: ``D ** -0.5``); ``wide``: the
+    value heads a head weighs (the module's docstring).
+    Returns an array like q, ``wide * D`` lanes a head.  Only the live
+    blocks of ``layer`` are read: no slab leaves the stack.
 
     ``use_kernel=None`` takes the Pallas kernel on a TPU and the twin
     elsewhere and under a mesh of more than one device (``LLMEngine`` with
@@ -463,10 +479,10 @@ def decode_attn(q, k_all, v_all, layer, live, num_kv_heads: int,
     ``interpret=True`` runs the kernel interpreted (tests)."""
     if not _takes_kernel(use_kernel, interpret):
         return decode_attn_jnp(q, k_all, v_all, layer, live, num_kv_heads,
-                               softcap, tokens, scale)
+                               softcap, tokens, scale, wide)
     interpret = resolve_interpret(interpret, "decode_attn")
     return _decode_attn_pallas(q, k_all, v_all, layer, live, num_kv_heads,
-                               softcap, interpret, tokens, scale)
+                               softcap, interpret, tokens, scale, wide)
 
 
 # ---------------------------------------------------------------------------

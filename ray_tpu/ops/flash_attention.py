@@ -166,11 +166,14 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
     """q: [B, H, S, Dqk], k: [B, KV, S, Dqk], v: [B, KV, S, Dv] -> (out [B,
     H, S, Dv], lse [B, H, S]).  ``window``: the band (``_fwd_kernel``), a
     kernel of its own name; ``scale``: what multiplies the scores (None:
-    ``Dqk ** -0.5``)."""
+    ``Dqk ** -0.5``).  v may have fewer heads than k, each as wide as
+    several of k's side by side ([B, KV / w, S, w Dqk]: differential
+    attention's pairs): head ``h`` then reads value head ``h // (H / (KV /
+    w))``, a block fetched once for the query heads under it."""
     b, h, s, d = q.shape
     d_v = v.shape[3]
     kv_heads = k.shape[1]
-    reps = h // kv_heads
+    reps, v_reps = h // kv_heads, h // v.shape[1]
     scale = score_scale(scale, d)
     block_q = min(block_q, s)
     block_kv = min(block_kv, s)
@@ -186,7 +189,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_kv: int,
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // reps, 0, 0)),
             pl.BlockSpec((1, 1, s, d_v),
-                         lambda bi, hi, qi: (bi, hi // reps, 0, 0)),
+                         lambda bi, hi, qi: (bi, hi // v_reps, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d_v),
@@ -803,9 +806,11 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
             raise ValueError("a window is causal")
         block_kv = min(block_kv, WINDOW_BLOCK_KV)
     reason = flash_supported(sq, k.shape[1], h, k.shape[2], block_q, block_kv)
-    if reason is None and (k.shape[-1] != d or v.shape[:-1] != k.shape[:-1]):
+    if reason is None and (k.shape[-1] != d or v.shape[:2] != k.shape[:2]
+                           or k.shape[2] % v.shape[2]):
         reason = (f"keys {k.shape} are not as wide as queries {q.shape} or "
-                  f"not as many as values {v.shape}")
+                  f"not as many as values {v.shape}, nor a whole number of "
+                  "them a value head")
     if reason is not None:
         raise ValueError(f"flash attention cannot run this shape: {reason}")
     interpret = resolve_interpret(interpret, "flash")

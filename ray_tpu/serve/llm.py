@@ -248,21 +248,23 @@ class LLMEngine:
             # MLP lies under its layers; a recurrent state is decoded one
             # token a step, rows and rings take a verify step's window, and
             # who drafts for it is the model's own block
-            recurrent = cfg.linear_layers or cfg.ssm_layers
+            recurrent = cfg.recurrent_layers
             for on, what in ((paged, "paged=True: the page arena holds keys "
-                              "and values only, in no ring"),
+                              "and values only, in no ring, and no page is "
+                              "read by a 'cross' layer or two value heads "
+                              "wide"),
                              (spec_decode_enabled and recurrent,
                               "spec_decode_enabled: a "
                               "rejected draft cannot be rolled out of a "
-                              "recurrent state ('linear', 'ssm')"),
+                              "recurrent state ('linear', 'ssm', 'ssm1')"),
                              (spec_decode_enabled and not cfg.mtp_layers,
                               "spec_decode_enabled: no draft model is cut "
                               "out of a pattern's stacks by kind; a pattern "
                               "drafts with its own multi-token-prediction "
                               "block (mtp_layers)"),
                              (tp > 1, f"tp={tp}: no sharding rule covers the "
-                              "recurrent state, the rings or their "
-                              "kernels")):
+                              "recurrent state, the rings, the rows a "
+                              "'cross' layer shares or their kernels")):
                 if on:
                     raise ValueError(
                         f"layer_pattern {cfg.layer_pattern} does not run "
@@ -480,6 +482,16 @@ class LLMEngine:
         self.kv_positions_read = 0
         self.kv_positions_held = 0
         self.kv_positions_live = 0
+        # under a cross-decoder (cfg.cross_segment): prompt tokens taken
+        # through the self-decoder (every one) and through the cross-decoder
+        # (a row's last alone), and the shared rows a decode step reads:
+        # live positions x the layers that read the one full layer's rows
+        self.prefill_self_tokens = 0
+        self.prefill_cross_tokens = 0
+        self.shared_kv_positions_read = 0
+        self._shared_kv_readers = (
+            cfg.full_layers + cfg.cross_layers
+            if cfg.cross_segment else 0)
         # what the dropless expert layers did (cfg.moe_dropless): decode's
         # assignments (live tokens x experts a token x expert layers) and
         # experts touched (those with a live token, summed over expert
@@ -567,6 +579,14 @@ class LLMEngine:
         if len(tokens) >= self.max_len:
             raise ValueError(f"prompt length {len(tokens)} >= max_len "
                              f"{self.max_len}")
+        if self.cfg.cross_segment and len(tokens) > self.buckets[-1]:
+            raise ValueError(
+                f"prompt length {len(tokens)} is past the largest bucket "
+                f"{self.buckets[-1]}: a row of these kinds ('ssm1', "
+                "'window', 'cross') is walked whole by one admit program, "
+                "its self-decoder over the row (a selective-scan state and "
+                "a ring take no start to continue after), and none is "
+                "compiled past the buckets")
         req = GenRequest(list(map(int, tokens)), max_tokens, temperature,
                          top_k, eos_id)
         if obs.enabled():
@@ -688,6 +708,11 @@ class LLMEngine:
             "kv_positions_held": self.kv_positions_held,
             "kv_positions_live": self.kv_positions_live,
         }
+        if self.cfg.cross_segment:
+            out.update(
+                prefill_self_tokens=self.prefill_self_tokens,
+                prefill_cross_tokens=self.prefill_cross_tokens,
+                shared_kv_positions_read=self.shared_kv_positions_read)
         if self.cfg.moe_dropless:
             out.update(
                 moe_assignments=self.moe_assignments,
@@ -799,6 +824,9 @@ class LLMEngine:
         self.admit_rows_real += len(reqs)
         self.admit_tokens_real += tokens_real
         self.admit_tokens_padded += sum(n for _r, n in prog.rows) - tokens_real
+        if self._shared_kv_readers:
+            self.prefill_self_tokens += tokens_real
+            self.prefill_cross_tokens += len(reqs)
         # (of a share of the experts, the part that lands on those held
         # under uniform routing: the admit program returns no count)
         self.moe_assignments_prefill += (
@@ -1345,8 +1373,9 @@ class LLMEngine:
                 -(-(r.cache_len + j + 1) // block) * block
                 for j in range(run))
             if run > 0:
-                self.kv_positions_live += (run * r.cache_len
-                                           + run * (run + 1) // 2)
+                live = run * r.cache_len + run * (run + 1) // 2
+                self.kv_positions_live += live
+                self.shared_kv_positions_read += live * self._shared_kv_readers
             r.cache_len += max(run, 0)
             longest = max(longest, run)
         self.kv_positions_held += steps * (self.num_slots + 1) * self.max_len

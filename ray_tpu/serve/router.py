@@ -443,7 +443,14 @@ class DeploymentHandle:
                         pass
                     raise TimeoutError(f"stream from {self.deployment!r} "
                                        f"exceeded {timeout_s}s")
-                poll_timeout = min(poll_timeout, remaining + 1.0)
+                # what is left of the stream's own budget, not a minute: a
+                # poll sent behind the start rides the caller's pump until
+                # the request is over (``core_worker._actor_pump`` awaits a
+                # whole batch of calls), so with a minute's bound an answer
+                # that takes longer failed at its second poll however sound
+                # the replica (12% of a closed loop's answers of 2,000-3,000
+                # tokens: PERF.md section 6, PR 60)
+                poll_timeout = remaining + 1.0
             try:
                 chunks, cursor, done = ray_tpu.get(
                     h.next_chunks.remote(stream_id, cursor),

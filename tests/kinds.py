@@ -720,6 +720,142 @@ KINDS = {k.name: k for k in (
                         "linear_layers": 0, "ssm_layers": 36,
                         "full_layers": 4, "cache_latent_bytes": 0,
                         "expert_layers": 0, "experts_held": 0}))),
+    # SambaY (PR 60): a stack of three segments, the float32 selective-scan
+    # state [layers, slots, 16, 40, 128], rows of ONE full layer that seven
+    # cross layers read, rings for eight window layers
+    Kind(
+        name="phi4flash", tiny="tiny-phi4flash.json",
+        cell="phi-4-mini-flash-reasoning-serve-l32",
+        # two rows of other lengths into slots 2 and 0, then decode steps
+        # with an idle slot between, past the window of 16: float32 both
+        # sides
+        parity=dict(seed=1,
+                    draw=lambda rng: list(rng.integers(1, 256, (2, 48))),
+                    lens=[29, 18], slots=[2, 0], n_slots=3, max_len=64,
+                    bucket=32, steps=10, atol=2e-4, ref_kw={}),
+        # 5 cache rows (4 slots + scratch), float32: the state [16, 1, 128]
+        # and the tail [3, 128] of 4 Mamba layers, K and V of ONE
+        # full layer of 4 heads of 8, rings of 16 rows for 3 window layers
+        engine=dict(kw=dict(num_slots=4, max_len=64, buckets=(32, 64),
+                            steps_per_dispatch=2),
+                    seed=1, lens=(11,), max_tokens=6,
+                    gauges={"experts_held": 0, "expert_layers": 0,
+                            "linear_layers": 0, "ssm1_layers": 4,
+                            "cross_layers": 2, "window_layers": 3,
+                            "full_layers": 1,
+                            "cache_state_bytes": 4 * 5 * (
+                                16 * 128 * 4 + 3 * 128 * 4),
+                            "cache_state_hbm_bytes": 4 * 5 * (
+                                16 * 128 * 4 + 3 * 128 * 4),
+                            "cache_kv_bytes": 2 * 1 * 5 * 64 * 4 * 8 * 4,
+                            "cache_shared_kv_bytes":
+                                2 * 1 * 5 * 64 * 4 * 8 * 4,
+                            "cache_ring_bytes": 2 * 3 * 5 * 16 * 4 * 8 * 4,
+                            "cache_latent_bytes": 0}),
+        scopes=dict(
+            both={"attn", "norm", "lm_head", "mlp", "kv_write",
+                  "selective_scan", "selective_scan_conv", "gmu",
+                  "diff_attn", "cross", "state_write", "ring_write"},
+            decode={"kv_read", "ring_read", "state_read"},
+            neither={"moe_route", "moe_experts", "ssm", "gdn"},
+            stats=("cache_kv_bytes", "cache_state_bytes",
+                   "cache_shared_kv_bytes", "cache_ring_bytes",
+                   "ssm1_layers", "cross_layers", "window_layers",
+                   "full_layers", "prefill_self_tokens",
+                   "prefill_cross_tokens", "shared_kv_positions_read")),
+        train_refusal="layer_pattern",
+        engine_refusals=_PAGED_SPEC_TP("page arena", "rolled out",
+                                       "sharding rule"),
+        engine_refusal_names=("ssm1", "cross"),
+        kernels=(("selective_scan", "KERNEL_CHUNK_FWD",
+                  "selective_scan_chunk_fwd"),
+                 ("selective_scan", "KERNEL_STEP", "selective_scan_step"),
+                 ("decode_attention", "KERNEL_DECODE_ATTN", "decode_attn"),
+                 ("decode_attention", "KERNEL_WINDOW_DECODE_ATTN",
+                  "window_decode_attn"),
+                 ("flash_attention", "KERNEL_FLASH_WINDOW",
+                  "flash_window_prefill"),
+                 ("flash_attention", "KERNEL_FLASH_FWD", "flash_fwd")),
+        readers=(("_sambay.py", "CHUNK_FWD", "selective_scan_chunk_fwd"),
+                 ("_sambay.py", "STEP", "selective_scan_step"),
+                 ("_sambay.py", "WINDOW_DECODE_ATTN", "window_decode_attn"),
+                 ("sambay_kernels_device_share.py", "KERNELS",
+                  ("selective_scan_chunk_fwd", "selective_scan_step",
+                   "decode_attn", "window_decode_attn",
+                   "flash_window_prefill", "flash_fwd"))),
+        kind_refusals=(
+            ("another-activation", dict(hidden_act="gelu"), "hidden_act"),
+            ("a-mamba-layer-every-third", dict(mb_per_layer=3),
+             "mb_per_layer"),
+            ("biases", dict(mlp_bias=True), "mlp_bias"),
+            ("an-untied-head", dict(tie_word_embeddings=False),
+             "tie_word_embeddings"),
+            ("no-window", dict(sliding_window=0), "sliding_window"),
+            ("layers-not-whole-pairs", dict(num_hidden_layers=10),
+             "num_hidden_layers"),
+            ("heads-not-in-pairs", dict(num_key_value_heads=1),
+             "whole pairs"),
+            ("channels-not-whole-tiles", dict(hidden_size=72,
+                                              num_attention_heads=4,
+                                              num_key_value_heads=2),
+             "128 lanes"),
+            ("dropout", dict(resid_pdrop=0.1), "resid_pdrop")),
+        config_refusals=(None, (
+            ("segments-beside-a-pattern",
+             dict(layer_pattern=("ssm1", "full")), "in place of"),
+            # (the tiny configuration's fields carry its stack flattened too)
+            ("a-cross-layer-above-the-full-layer", dict(layer_segments=(
+                (("ssm1", "window"), 1), (("gmu", "cross"), 5)),
+                layer_pattern=()), "reads what"),
+            ("two-full-layers", dict(layer_segments=(
+                (("ssm1", "full"), 2), (("gmu", "cross"), 4)),
+                layer_pattern=(), sliding_window=0), "ONE 'full' layer"),
+            ("a-scan-without-its-sizes", dict(ssm1_dt_rank=0),
+             "ssm1_inner"),
+            ("channels-that-are-no-whole-tiles", dict(ssm1_inner=96),
+             "128 lanes"),
+            ("two-recurrent-kinds", dict(layer_segments=(
+                (("ssm1", "window"), 3), (("ssm1", "full"), 1),
+                (("ssm", "full"), 2)), layer_pattern=(), ssm_groups=1,
+                linear_num_heads=2, linear_key_dim=4, linear_value_dim=4),
+             "one recurrent kind"),
+            ("differential-attention-with-odd-heads",
+             dict(num_kv_heads=1), "pairs"),
+            ("experts-under-segments", dict(
+                moe_dropless=True, num_experts=4, experts_per_token=2,
+                expert_mlp_size=8), "layer_segments"),
+            ("segments-kinds-without-segments", dict(
+                layer_segments=(), layer_pattern=("ssm1", "full", "gmu",
+                                                  "cross") * 3),
+             "layer_segments"))),
+        # decode 0.238 GB of temporaries, the 4,096 admit 0.632 GB (sandbox
+        # compile, PR 60); decode: a selective-scan step a segment with a
+        # Mamba layer, the ring's kernel, decode_attn for the full layer and
+        # for a cross layer; the 4,096 admit: a chunked scan a segment, the
+        # banded flash forward and the full layer's
+        cell_programs=(("decode", 0.3, 5), ("prefill-512", 0.25, 2),
+                       ("prefill-1024", 0.35, 4), ("prefill-2048", 0.5, 4),
+                       ("prefill-4096", 0.75, 4)),
+        stacks=("bf16[1,65,8192,1280]", "bf16[8,65,512,1280]",
+                "f32[9,65,16,40,128]"),
+        # no layer's [slots, 16, 40, 128] slab is sliced out of the state
+        held_in_place=(r"= f32\[(1,)?65,16,40,128\]\S* "
+                       r"(dynamic-slice|copy)\(",),
+        counts=dict(slots=65, num_params=3_852_457_984,
+                    per={"mamba": 41_123_840, "attention": 19_660_800,
+                         "gmu": 26_214_400, "cross": 13_107_200,
+                         "mlp": 78_643_200},
+                    gauges=lambda kind, doc: _whole_tiles({
+                        "cache_kv_bytes": 65 * 8192 * 5120,
+                        "cache_shared_kv_bytes": 65 * 8192 * 5120,
+                        "cache_ring_bytes": 65 * 512 * 5120 * 8,
+                        "cache_state_bytes": 65 * (
+                            kind.state_bytes_per_slot(doc)
+                            + kind.conv_bytes_per_slot(doc)),
+                        "linear_layers": 0, "ssm1_layers": 9,
+                        "cross_layers": 7, "window_layers": 8,
+                        "full_layers": 1, "cache_latent_bytes": 0,
+                        "expert_layers": 0, "experts_held": 0}))),
     # trained, not served: the share train cell's kind.  The backward's two
     # grouped kernels beside the forward's; the reader's list still holds
     # ``flash_dq``, a kernel that is gone since PR 48 (the backward is

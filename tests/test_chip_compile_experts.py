@@ -21,6 +21,11 @@ programs' limits and what may not leave its stacks).
   experts of two matrices, 1 attention layer of 2 KV heads), 64 slots of
   8,192: the state [4, 65, 64, 64, 128] float32 and the K/V rows updated in
   place, the experts never out of their stack.
+- ``phi4flash`` (no experts; here for the same reasons as Granite's): Phi-4-
+  mini-flash-reasoning whole, 32 layers in three segments, 64 slots of
+  8,192: 7.70 GB of weights, rows of ONE layer (2.7 GB), rings of eight, the
+  state [9, 65, 16, 40, 128] float32 updated in place by a kernel call a
+  segment.
 - ``granitemoehybrid`` (no experts; here because its row has
   ``cell_programs`` and its check's prefill is Nemotron's sibling):
   granite-4.0-h-micro whole, 40 layers in four periods of nine state-space
@@ -103,7 +108,11 @@ def test_latent_8192_program_walks_a_row_in_chunks_of_2048(one_chip, as_tpu):
 
 @pytest.mark.parametrize("name,calls,kernels", [
     ("nemotron_h", 4 + 1 + 2 * 4, ("flash_fwd", "ssd_chunk_fwd", "moe_gmm")),
-    ("granitemoehybrid", 9 + 1, ("flash_fwd", "ssd_chunk_fwd"))])
+    ("granitemoehybrid", 9 + 1, ("flash_fwd", "ssd_chunk_fwd")),
+    # (a chunked scan a segment with a Mamba layer, the band's forward, the
+    # full layer's; the cross-decoder's one token takes no kernel)
+    ("phi4flash", 2 + 1 + 1, ("flash_fwd", "flash_window_prefill",
+                               "selective_scan_chunk_fwd"))])
 def test_the_state_space_checks_prefill_runs_a_buckets_kernels(
         one_chip, as_tpu, name, calls, kernels):
     """The configuration's ``check`` compares a prefill and decode steps
@@ -159,4 +168,32 @@ test_whole_row_programs_are_the_parents = whole_row_programs({
     ("xing4_0", "prefill-2048", None): (459842048, 4, 3),
     ("granitemoehybrid", "decode", None): (1308068864, 10, 4),
     ("granitemoehybrid", "prefill-4096", None): (1799708160, 10, 2),
+    # SambaY's five, pinned at PR 60, which added them: decode's kernels are
+    # a selective-scan step a segment with a Mamba layer, the ring's, and
+    # decode_attn for the full layer and for a cross layer, its loops the
+    # steps and the three segments' (and the kernels' own); an admit's are a
+    # chunked scan a segment, the band's forward and the full layer's from
+    # 1,024 positions up, its loops the rows and the two segments of the
+    # self-decoder (the cross-decoder's one segment of one token beside)
+    ("phi4flash", "decode", None): (238315520, 5, 7),
+    ("phi4flash", "prefill-512", None): (182522880, 2, 3),
+    ("phi4flash", "prefill-1024", None): (282081792, 4, 3),
+    ("phi4flash", "prefill-2048", None): (418019328, 4, 3),
+    ("phi4flash", "prefill-4096", None): (631769600, 4, 3),
 })
+
+
+def test_sambays_programs_hold_the_cells_arguments(one_chip, as_tpu):
+    """PR 60: the decode program and the four admits hold the same 12.0 GB
+    of arguments (7.70 GB of weights, 2.73 of rows, 1.36 of rings, 0.21 of
+    state and tails; an admit its rows of tokens besides), 75% of the chip's
+    16e9, and none copies the convolution tails' stack either in decode."""
+    sizes = {p: _cell_program(one_chip, "phi4flash", p)[0].memory_analysis()
+             .argument_size_in_bytes
+             for p, *_ in kinds.KINDS["phi4flash"].cell_programs}
+    assert sizes["decode"] == 12_006_397_952
+    assert all(0 < v - sizes["decode"] < 1 << 20 for p, v in sizes.items()
+               if p != "decode")
+    assert 0.74 < sizes["decode"] / 16e9 < 0.76
+    _, text = _cell_program(one_chip, "phi4flash", "decode")
+    assert not copies_of("bf16[9,65,3,5120]", text)

@@ -326,3 +326,41 @@ def test_multiplexed_model_loading(serve_clean):
     assert h.remote("c").result(timeout_s=30) == "model:c"
     assert h.remote("b").result(timeout_s=30) == "model:b"  # re-load
     assert h.load_log.remote().result(timeout_s=30) == ["a", "b", "c", "b"]
+
+
+def test_a_streams_poll_may_wait_for_what_is_left_of_the_streams_budget(
+        monkeypatch):
+    """PR 60: a poll sent behind a stream's start rides the caller's pump
+    until the request is over, so under a bound of a minute a poll an answer
+    of more than a minute failed at its second poll, however sound the
+    replica.  A stream with a budget gives each poll what is left of it; one
+    without keeps the minute."""
+    from ray_tpu.serve import router as r
+
+    class Replica:
+        class next_chunks:
+            remote = staticmethod(lambda stream_id, cursor: ("poll", cursor))
+
+        class cancel_stream:
+            remote = staticmethod(lambda stream_id: None)
+
+    class Router:
+        def start_stream(self, *a):
+            return "replica", "stream-1", "ref"
+
+        def _replica_handle(self, name):
+            return Replica
+
+    waited = []
+
+    def get(ref, timeout=None):
+        waited.append(timeout)
+        return (["a", "b"], 2, True) if isinstance(ref, tuple) else None
+
+    monkeypatch.setattr(r, "get_router", lambda: Router())
+    monkeypatch.setattr(r.ray_tpu, "get", get)
+    assert list(r.DeploymentHandle("d").stream(1, timeout_s=300)) == ["a", "b"]
+    assert 299.0 < waited[0] <= 301.0
+    waited.clear()
+    assert list(r.DeploymentHandle("d").stream(1)) == ["a", "b"]
+    assert waited[0] == 60.0

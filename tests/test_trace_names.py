@@ -418,8 +418,9 @@ def _traced():
     from ray_tpu.ops import decode_attention as da
     from ray_tpu.ops import flash_attention as fa
     from ray_tpu.ops import gated_delta as gd
-    from ray_tpu.ops import kda, moe, ssd
+    from ray_tpu.ops import kda, moe, selective_scan, ssd
     f32 = jnp.float32
+    u, bc = jnp.ones((1, 128, 128), f32), jnp.ones((1, 128, 2), f32)
     q, v = jnp.ones((1, 64, 2, 8), f32), jnp.ones((1, 64, 2, 16), f32)
     g, state = jnp.zeros((1, 64, 2, 8), f32), jnp.zeros((2, 1, 2, 8, 16))
     x, b = jnp.ones((1, 128, 2, 8), f32), jnp.ones((1, 128, 1, 16), f32)
@@ -452,6 +453,14 @@ def _traced():
             lambda *a: ssd.ssd_recurrent_step(*a, interpret=True))(
                 state, jnp.int32(1), x[:, 0], x[:, 0, :, 0], a, b[:, 0],
                 b[:, 0], a),
+        "selective_scan_chunk_fwd": lambda: jax.make_jaxpr(
+            lambda *a: selective_scan.selective_scan_chunk_fwd(
+                *a, interpret=True))(u, u, -u[0, :2], bc, bc),
+        "selective_scan_step": lambda: jax.make_jaxpr(
+            lambda *a: selective_scan.selective_scan_step(
+                *a, interpret=True))(
+                    jnp.zeros((2, 1, 2, 1, 128)), jnp.int32(1), u[:, 0],
+                    u[:, 0], -u[0, :2], bc[:, 0], bc[:, 0]),
         "decode_attn": lambda: jax.make_jaxpr(lambda q, k: da.decode_attn(
             q, k, k, jnp.int32(0), jnp.array([3, 0]), 2, interpret=True,
             tokens=2))(jnp.ones((2, 8, 8)), jnp.ones((1, 2, 16, 16))),
